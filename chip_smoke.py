@@ -1,0 +1,1027 @@
+#!/usr/bin/env python3
+"""chip_smoke.py — the quickest proof that the system still starts on the chip.
+
+    python3 chip_smoke.py
+
+drives the main path once, on ONE chip, at the full width of the
+GPT-2-small-shape LM (``long_seq_transformer``: 12 layers x 768, 12 heads,
+32k vocabulary, seq 2048, 8 sequences per chip — the one zoo model that
+runs the Pallas flash kernels in both directions):
+
+1. ``train``   ``elasticdl_tpu.client train --distribution_strategy Local``
+               from EDLIO shards generated here from a seed: a few tens of
+               steps with a mid-run and a final evaluation.
+2. ``kernel``  compiled ``flash_attention`` forward and gradients against
+               ``mha_reference`` at the two full-width shapes.
+3. ``cache``   the same command as 1 in a second process: every compile
+               request is served by the persistent compile cache.
+
+It exits 0 — and prints, as the LAST line of stdout,
+``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": ...}}`` —
+only if every run passed: platform ``tpu``, kernels compiled (not
+interpreted), native EDLIO codec, finite eval loss below its starting value,
+no compile inside the steady window.  It exits non-zero and prints no result
+when JAX finds no accelerator, when any run fails, or when the rest of the
+repository is not beside it.
+
+The parent never imports JAX (a process that has touched JAX holds the chip):
+each run is its own child process, one after another, and the child calls
+the normal entry point.  The children's platform is pinned here
+(``JAX_PLATFORMS`` + ``--jax_platform``), whatever the environment says.
+
+``--size tiny`` REHEARSES the same control flow on the CPU backend at a tiny
+width (kernels interpreted) before chip time is spent.  A rehearsal is not a
+chip result: it prints no result line.
+
+``--runs bind,dp4,workers4,kill`` are the builder's runs on a four-chip host:
+a bare probe of the one-chip-per-process binding, the same model as one
+process over ``dp=4``, as four one-chip lockstep workers under
+``AllreduceStrategy``, and the latter with one worker SIGKILLed mid-run
+(skipped when ``bind`` ran and failed: they could only time out).
+"""
+
+from __future__ import annotations
+
+import argparse
+import glob
+import json
+import math
+import os
+import re
+import shutil
+import signal
+import subprocess
+import sys
+import tempfile
+import time
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+
+DEFAULT_RUNS = ("train", "kernel", "cache")
+ALL_RUNS = DEFAULT_RUNS + ("bind", "dp4", "workers4", "kill")
+
+# the whole smoke must end inside the driver's 1200 s, compilation included
+TOTAL_BUDGET_SECS = 1100.0
+RUN_BUDGET_SECS = {
+    "train": 600.0,
+    "kernel": 300.0,
+    "cache": 400.0,
+    "bind": 400.0,
+    "dp4": 400.0,
+    "workers4": 400.0,
+    "kill": 500.0,
+}
+
+EXIT_FAILED = 1
+EXIT_NO_REPO = 2
+EXIT_NO_DEVICE = 3
+
+MODEL_DEF = "long_seq_transformer.long_seq_transformer.custom_model"
+# gen_sequence's alphabet: under a larger model vocabulary the loss falls
+# well below ln(vocab) within tens of steps
+DATA_ALPHABET = 256
+STEPS_PER_TASK = 4
+
+SIZES = {
+    # transformer_gpt2s_seq2048 (bench.py): the full width of a model the
+    # repo supports; depth is the published 12 layers
+    "full": dict(
+        platform="tpu",
+        model_params=(
+            "vocab_size=32768;embed_dim=768;num_heads=12;num_layers=12;"
+            "dtype=bfloat16"
+        ),
+        vocab=32768,
+        heads=12,
+        seq_len=2048,
+        per_chip_batch=8,
+        steps=40,
+        evaluation_steps=8,
+        four_chip_steps=20,
+        # hosts dispatch ahead of the devices and the chief reports a task
+        # when its steps are ENQUEUED: the kill run is long enough that
+        # tasks are still unleased when the worker dies
+        kill_run_steps=48,
+        kill_step=6,
+        kernel_shapes=((8, 2048, 12, 64), (1, 8192, 8, 64)),
+    ),
+    # the rehearsal: same control flow, CPU backend, interpreted kernels
+    "tiny": dict(
+        platform="cpu",
+        model_params=(
+            "vocab_size=512;embed_dim=64;num_heads=2;num_layers=2;"
+            "dtype=bfloat16"
+        ),
+        vocab=512,
+        heads=2,
+        seq_len=128,
+        per_chip_batch=2,
+        steps=12,
+        evaluation_steps=4,
+        four_chip_steps=8,
+        kill_run_steps=24,
+        kill_step=2,
+        kernel_shapes=((2, 256, 2, 32), (1, 512, 2, 32)),
+    ),
+}
+
+# flash_attention vs mha_reference (float32 math at HIGHEST matmul
+# precision), bf16 inputs.  bf16 keeps 8 mantissa bits (eps = 2^-8 ~ 3.9e-3):
+# outputs and gradients are rounded to bf16 once on each side, the kernel's
+# in-block matmuls run at the MXU's default precision, and the backward
+# re-reads a bf16 ``out``.  Agreement to a few eps of the largest magnitude
+# is what "the same function" means here:
+#   max|flash - ref| <= KERNEL_TOL * max(1, max|ref|)
+KERNEL_TOL = 2e-2
+
+
+# ---- parent -----------------------------------------------------------------
+
+
+def _say(msg: str):
+    print(f"chip_smoke: {msg}", file=sys.stderr, flush=True)
+
+
+def _child_env(size: str, run: str, workdir: str) -> dict:
+    cfg = SIZES[size]
+    env = dict(os.environ)
+    # the platform is pinned HERE, whatever the environment says
+    env["JAX_PLATFORMS"] = cfg["platform"]
+    env["PYTHONPATH"] = os.pathsep.join(
+        p for p in (HERE, env.get("PYTHONPATH", "")) if p
+    )
+    xla_flags = [
+        flag
+        for flag in env.get("XLA_FLAGS", "").split()
+        if "xla_force_host_platform_device_count" not in flag
+    ]
+    if cfg["platform"] == "cpu":
+        # the rehearsal's stand-in for "one chip per process" (dp4: four)
+        n = 4 if run == "dp4" else 1
+        xla_flags.append(f"--xla_force_host_platform_device_count={n}")
+    if run == "dp4":
+        # the compiled step is read back from XLA's own dump; the
+        # persistent cache would skip the compile that writes it
+        xla_flags += [
+            f"--xla_dump_to={os.path.join(workdir, 'dp4_hlo')}",
+            "--xla_dump_hlo_as_text",
+            "--xla_dump_hlo_module_re=.*train_step.*",
+        ]
+        env["JAX_ENABLE_COMPILATION_CACHE"] = "false"
+    env["XLA_FLAGS"] = " ".join(xla_flags)
+    return env
+
+
+def _run_child(run: str, size: str, workdir: str, budget_secs: float):
+    """One run = one child process in its own session.  Returns ``(exit
+    code, report)``: ``(0, report)`` when the child ran to its end, else
+    the code this script should exit with and ``None``.  Every process
+    the child started dies with it."""
+    report_path = os.path.join(workdir, f"{run}.report.json")
+    log_path = os.path.join(workdir, f"{run}.log")
+    argv = [
+        sys.executable,
+        os.path.abspath(__file__),
+        "--child",
+        run,
+        "--size",
+        size,
+        "--workdir",
+        workdir,
+        "--report",
+        report_path,
+    ]
+    _say(f"run {run!r} ({size}) starting")
+    t0 = time.monotonic()
+    with open(log_path, "w") as log:
+        proc = subprocess.Popen(
+            argv,
+            env=_child_env(size, run, workdir),
+            stdout=log,
+            stderr=subprocess.STDOUT,
+            start_new_session=True,
+        )
+        try:
+            rc = proc.wait(timeout=budget_secs)
+        except subprocess.TimeoutExpired:
+            rc = None
+        finally:
+            # the child's whole session: master, workers, standbys
+            try:
+                os.killpg(proc.pid, signal.SIGKILL)
+            except ProcessLookupError:
+                pass
+            proc.wait()
+    wall = time.monotonic() - t0
+    if rc != 0:
+        with open(log_path, errors="replace") as log:
+            tail = log.readlines()[-60:]
+        sys.stderr.write("".join(tail))
+        if rc == EXIT_NO_DEVICE:
+            _say(
+                f"JAX found no {SIZES[size]['platform']!r} device "
+                "(platform pinned by this script); nothing ran"
+            )
+            return EXIT_NO_DEVICE, None
+        _say(
+            f"run {run!r} "
+            + (
+                f"did not finish within {budget_secs:.0f}s"
+                if rc is None
+                else f"exited {rc}"
+            )
+        )
+        return EXIT_FAILED, None
+    with open(report_path) as f:
+        report = json.load(f)
+    report["wall_secs"] = round(wall, 1)
+    return 0, report
+
+
+def _keep(workdir: str, dest: str):
+    """Copy what explains a run — logs, reports, telemetry, the compiled
+    dp4 module — out of the tempdir; never the data or the exports."""
+    os.makedirs(dest, exist_ok=True)
+    for name in sorted(os.listdir(workdir)):
+        src = os.path.join(workdir, name)
+        if name.endswith("_telemetry"):
+            shutil.copytree(src, os.path.join(dest, name), dirs_exist_ok=True)
+        elif name.endswith((".log", ".json", ".jsonl")):
+            shutil.copy(src, dest)
+    for src in glob.glob(
+        os.path.join(workdir, "dp4_hlo", "*train_step*after_optimizations.txt")
+    ):
+        shutil.copy(src, dest)
+
+
+def _parent(args) -> int:
+    if not os.path.isdir(os.path.join(HERE, "elasticdl_tpu")):
+        _say(
+            "the elasticdl_tpu package is not beside this script; "
+            "nothing to smoke"
+        )
+        return EXIT_NO_REPO
+    runs = [r for r in args.runs.split(",") if r]
+    unknown = [r for r in runs if r not in ALL_RUNS]
+    if unknown or not runs:
+        _say(f"unknown runs {unknown}; valid: {', '.join(ALL_RUNS)}")
+        return EXIT_FAILED
+    # data, exports and XLA dumps are bulky: always a removed tempdir
+    workdir = tempfile.mkdtemp(prefix="chip_smoke_")
+    deadline = time.monotonic() + TOTAL_BUDGET_SECS
+    failed = []
+    device = None
+    try:
+        for run in runs:
+            if run in ("workers4", "kill") and "bind" in failed:
+                _say(f"skipping run {run!r}: the binding probe failed")
+                failed.append(run)
+                continue
+            budget = min(RUN_BUDGET_SECS[run], deadline - time.monotonic())
+            if budget <= 0:
+                _say(f"out of time before run {run!r}")
+                return EXIT_FAILED
+            code, report = _run_child(run, args.size, workdir, budget)
+            if report is None:
+                if code == EXIT_NO_DEVICE or not args.keep_going:
+                    return code
+                failed.append(run)
+                continue
+            print(json.dumps(report), flush=True)
+            device = device or report.get("device")
+            if report["failures"]:
+                _say(f"run {run!r} FAILED: " + "; ".join(report["failures"]))
+                failed.append(run)
+                if not args.keep_going:
+                    return EXIT_FAILED
+    finally:
+        if args.keep:
+            _keep(workdir, args.keep)
+        shutil.rmtree(workdir, ignore_errors=True)
+    if failed:
+        _say(f"FAILED runs: {', '.join(failed)}")
+        return EXIT_FAILED
+    if args.size != "full":
+        _say(
+            f"rehearsal of {', '.join(runs)} passed on the CPU backend — "
+            "not a chip result, no result line"
+        )
+        return 0
+    print(json.dumps({"ok": True, "device": device}), flush=True)
+    return 0
+
+
+# ---- children ---------------------------------------------------------------
+# Everything below runs in a child process; only here is JAX imported.
+
+
+def _devices_or_exit(platform: str):
+    import jax
+
+    try:
+        return jax.devices()
+    except RuntimeError as ex:
+        # the one failure with its own exit code: no accelerator
+        print(
+            f"chip_smoke: no {platform!r} backend: {ex}",
+            file=sys.stderr,
+            flush=True,
+        )
+        sys.exit(EXIT_NO_DEVICE)
+
+
+def _ensure_data(workdir, cfg, name, num_records, seed):
+    """EDLIO shards generated from a seed (the chip machine has no
+    network and no checkout to fetch from); reused by later runs."""
+    from elasticdl_tpu.data import recordio
+    from elasticdl_tpu.data.recordio_gen import synthetic
+
+    # a fresh checkout has no _native.so: build it before the first byte
+    # is written, so no part of the smoke goes through the Python codec
+    recordio.ensure_native_codec()
+    out = os.path.join(workdir, "data", f"{name}_{num_records}")
+    if not os.path.isdir(out):
+        synthetic.gen_sequence(
+            out,
+            num_records=num_records,
+            num_shards=2,
+            seed=seed,
+            seq_len=cfg["seq_len"],
+            vocab=DATA_ALPHABET,
+        )
+    return out
+
+
+class _CacheEvents:
+    """jax.monitoring counts of persistent-compile-cache traffic."""
+
+    PREFIX = "/jax/compilation_cache/"
+
+    def __init__(self):
+        from jax import monitoring
+
+        self.counts = {
+            "compile_requests_use_cache": 0,
+            "cache_hits": 0,
+            "cache_misses": 0,
+        }
+        monitoring.register_event_listener(self._on_event)
+
+    def _on_event(self, event: str, **_kwargs):
+        if event.startswith(self.PREFIX):
+            key = event[len(self.PREFIX) :]
+            if key in self.counts:
+                self.counts[key] += 1
+
+
+def _telemetry(tdir):
+    from elasticdl_tpu.telemetry.events import EVENTS_FILENAME, read_events
+    from elasticdl_tpu.telemetry.tracing import SPANS_FILENAME, read_spans
+
+    return (
+        read_events(os.path.join(tdir, EVENTS_FILENAME)),
+        read_spans(os.path.join(tdir, SPANS_FILENAME)),
+    )
+
+
+def _steady_window_compiles(events, spans, warm_step, until_last_step=False):
+    """Compile spans ending inside each process's steady window: from
+    the start of step ``warm_step`` (every program kind has had its first
+    dispatch by then) to the end of the run (``until_last_step``: to the
+    last step, leaving out a multi-process job's epilogue programs)."""
+    inside = 0
+    procs = {e.get("process_id", 0) for e in events if e["event"] == "step"}
+    for proc in procs:
+        steps = [
+            e
+            for e in events
+            if e["event"] == "step"
+            and e.get("process_id", 0) == proc
+            and e.get("generation", 0) == 0
+        ]
+        warm = [e["monotonic"] for e in steps if e["step"] >= warm_step]
+        if not warm:
+            raise RuntimeError(
+                f"process {proc} recorded no step >= {warm_step}"
+            )
+        start = min(warm)
+        end = max(e["monotonic"] for e in steps) if until_last_step else None
+        for span in spans:
+            if (
+                span["span"] == "compile"
+                and span.get("process_id", 0) == proc
+                and span.get("generation", 0) == 0
+                and span["end"] > start
+                and (end is None or span["end"] < end)
+            ):
+                inside += 1
+    return inside
+
+
+def _peak_bytes(devices):
+    stats = [d.memory_stats() for d in devices]
+    return [s.get("peak_bytes_in_use") if s else None for s in stats]
+
+
+def _common_report(run, cfg, device, failures):
+    """The fields and checks every run shares; ``device`` is
+    ``{"platform", "kind", "count"}`` as JAX reported it to the process
+    that ran on it."""
+    import jax
+
+    from elasticdl_tpu.data import recordio
+    from elasticdl_tpu.ops.attention import kernel_interpret
+    from elasticdl_tpu.telemetry import compile_tracker
+
+    codec = "native" if recordio.native_available() else "python"
+    interpret = None
+    if device["platform"] != cfg["platform"]:
+        failures.append(
+            f"ran on platform {device['platform']!r}, pinned "
+            f"{cfg['platform']!r}"
+        )
+    else:
+        interpret = kernel_interpret(device["platform"])
+    if codec != "native":
+        failures.append("EDLIO decoded by the Python codec")
+    if cfg["platform"] == "tpu":
+        if interpret:
+            failures.append("pallas kernels ran interpreted")
+        if not re.search(r"v5 ?(lite|e)", str(device["kind"]), re.IGNORECASE):
+            failures.append(f"device kind {device['kind']!r} is not a v5e")
+    return {
+        "run": run,
+        "device": device,
+        "jax": jax.__version__,
+        "codec": codec,
+        "kernel_interpret": interpret,
+        "compile_count": compile_tracker.compile_count(),
+        "compile_secs": round(compile_tracker.compile_secs_total(), 2),
+        "failures": failures,
+    }
+
+
+def _train_argv(cfg, workdir, tdir, batch, steps, mesh_shape=""):
+    train = _ensure_data(workdir, cfg, "train", batch * steps, seed=0)
+    evald = _ensure_data(workdir, cfg, "eval", batch * 2, seed=1)
+    argv = [
+        "train",
+        "--model_def",
+        MODEL_DEF,
+        "--model_params",
+        cfg["model_params"],
+        "--training_data",
+        train,
+        "--validation_data",
+        evald,
+        "--minibatch_size",
+        str(batch),
+        "--records_per_task",
+        str(batch * STEPS_PER_TASK),
+        "--num_epochs",
+        "1",
+        "--evaluation_steps",
+        str(cfg["evaluation_steps"]),
+        "--distribution_strategy",
+        "Local",
+        "--jax_platform",
+        cfg["platform"],
+        "--telemetry_dir",
+        tdir,
+        "--trace_sample_rate",
+        "1.0",
+    ]
+    if mesh_shape:
+        argv += ["--mesh_shape", mesh_shape]
+    return argv
+
+
+def _child_train(run, cfg, workdir):
+    """Runs 1 (train), 3 (cache: the same command again) and 4 (dp4)."""
+    from elasticdl_tpu import client
+
+    devices = _devices_or_exit(cfg["platform"])
+    failures = []
+    if run == "dp4":
+        if len(devices) < 4:
+            raise RuntimeError(f"dp4 needs 4 devices, found {len(devices)}")
+        n, mesh_shape, steps = 4, "dp=4", cfg["four_chip_steps"]
+        devices = devices[:4]
+    else:
+        n, mesh_shape, steps = len(devices), "", cfg["steps"]
+    batch = cfg["per_chip_batch"] * n
+    tdir = os.path.join(workdir, f"{run}_telemetry")
+    shutil.rmtree(tdir, ignore_errors=True)
+    cache_events = _CacheEvents()
+    result = client.run(_train_argv(cfg, workdir, tdir, batch, steps, mesh_shape))
+
+    from elasticdl_tpu.parallel.elastic import describe_devices
+
+    report = _common_report(run, cfg, describe_devices(devices), failures)
+    report["peak_bytes_in_use"] = _peak_bytes(devices)
+    loss = float(result["loss"])
+    ceiling = math.log(cfg["vocab"])
+    if not math.isfinite(loss):
+        failures.append(f"eval loss {loss} is not finite")
+    elif loss >= ceiling:
+        failures.append(
+            f"eval loss {loss:.3f} did not fall below its starting value "
+            f"ln(vocab) = {ceiling:.3f}"
+        )
+    if result["steps"] != steps:
+        failures.append(f"took {result['steps']} steps, expected {steps}")
+    if result["device"] != report["device"]:
+        failures.append(
+            f"the run's own result names {result['device']}, "
+            f"JAX reports {report['device']}"
+        )
+    events, spans = _telemetry(tdir)
+    steady = _steady_window_compiles(
+        events, spans, warm_step=cfg["evaluation_steps"] + 1
+    )
+    if steady:
+        failures.append(f"{steady} compile(s) inside the steady window")
+    report.update(
+        steps=result["steps"],
+        eval_loss=round(loss, 4),
+        eval_accuracy=round(float(result.get("accuracy", float("nan"))), 4),
+        loss_ceiling=round(ceiling, 4),
+        steady_window_compiles=steady,
+        cache=cache_events.counts,
+    )
+    if cfg["platform"] == "tpu" and run == "train":
+        # the chip's device_kind must be in both peak tables (neither
+        # assumes a peak for an unknown kind)
+        import bench
+        from elasticdl_tpu.telemetry import anatomy
+
+        report["peak_flops_known"] = {
+            "anatomy": anatomy.peak_flops_per_chip() is not None,
+            "bench": bench._peak_flops(devices[0]) is not None,
+        }
+        if not all(report["peak_flops_known"].values()):
+            failures.append(
+                f"device kind missing from a peak table: "
+                f"{report['peak_flops_known']}"
+            )
+    if run == "cache":
+        counts = cache_events.counts
+        if counts["cache_misses"] or not counts["cache_hits"]:
+            failures.append(
+                f"second process compiled instead of hitting the "
+                f"persistent cache: {counts}"
+            )
+    if run == "dp4":
+        report["dp4"] = _check_dp4(cfg, workdir, report, failures)
+    return report
+
+
+def _check_dp4(cfg, workdir, report, failures) -> dict:
+    """What the compiled four-chip step sees, from XLA's own dump, and
+    what the four chips held."""
+    dump_dir = os.path.join(workdir, "dp4_hlo")
+    names = sorted(os.listdir(dump_dir)) if os.path.isdir(dump_dir) else []
+    dumps = [
+        n
+        for n in names
+        if "train_step" in n
+        and n.endswith("after_optimizations.txt")
+    ]
+    if not dumps:
+        failures.append(
+            f"no compiled train_step module among XLA's dumps: {names[:20]}"
+        )
+        return {"dumps": names[:20]}
+    with open(os.path.join(dump_dir, dumps[-1])) as f:
+        hlo = f.read()
+    per_chip, whole = cfg["per_chip_batch"], cfg["per_chip_batch"] * 4
+    seq = cfg["seq_len"]
+    out = {
+        "hlo": dumps[-1],
+        "batch_param_per_chip": f"s32[{per_chip},{seq}]" in hlo,
+        "batch_param_global": f"s32[{whole},{seq}]" in hlo,
+    }
+    if not out["batch_param_per_chip"] or out["batch_param_global"]:
+        failures.append(f"the batch is not split four ways: {out}")
+    if cfg["platform"] == "tpu":
+        # the attention custom call's folded (batch*heads, seq, d) operand
+        calls = re.findall(
+            r"= \(?bf16\[(\d+),(\d+),(\d+)\][^\n]*custom_call_target="
+            r'"tpu_custom_call"',
+            hlo,
+        )
+        lead = sorted({int(c[0]) for c in calls})
+        out["attention_call_batch_x_heads"] = lead
+        if lead != [per_chip * cfg["heads"]]:
+            failures.append(
+                f"attention custom calls see batch*heads {lead}, expected "
+                f"the per-chip {per_chip * cfg['heads']} "
+                f"(global would be {whole * cfg['heads']})"
+            )
+        peaks = report["peak_bytes_in_use"]
+        if not all(peaks) or max(peaks) > 1.5 * min(peaks):
+            failures.append(f"uneven or missing per-chip memory: {peaks}")
+    return out
+
+
+def _child_kernel(run, cfg, workdir):
+    """Run 2: the compiled kernels against the reference."""
+    import jax
+    import jax.numpy as jnp
+
+    from elasticdl_tpu.ops.attention import flash_attention, mha_reference
+    from elasticdl_tpu.parallel.elastic import configure_compilation_cache
+    from elasticdl_tpu.telemetry import compile_tracker
+
+    devices = _devices_or_exit(cfg["platform"])
+    configure_compilation_cache()
+    compile_tracker.install()
+    failures = []
+    shapes = {}
+
+    def weighted(fn, w):
+        return lambda q, k, v: jnp.sum(fn(q, k, v).astype(jnp.float32) * w)
+
+    def reference(q, k, v):
+        with jax.default_matmul_precision("highest"):
+            return mha_reference(q, k, v, causal=True)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=True)
+
+    for shape in cfg["kernel_shapes"]:
+        keys = jax.random.split(jax.random.PRNGKey(sum(shape)), 4)
+        q, k, v = (
+            jax.random.normal(key, shape, jnp.float32).astype(jnp.bfloat16)
+            for key in keys[:3]
+        )
+        w = jax.random.normal(keys[3], shape, jnp.float32)
+        lowered = jax.jit(flash).lower(q, k, v)
+        if cfg["platform"] == "tpu" and "tpu_custom_call" not in (
+            lowered.as_text()
+        ):
+            failures.append(f"{shape}: no compiled Mosaic call in the HLO")
+        got = (
+            lowered.compile()(q, k, v),
+            *jax.jit(jax.grad(weighted(flash, w), argnums=(0, 1, 2)))(q, k, v),
+        )
+        want = (
+            jax.jit(reference)(q, k, v),
+            *jax.jit(jax.grad(weighted(reference, w), argnums=(0, 1, 2)))(
+                q, k, v
+            ),
+        )
+        errs = {}
+        for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
+            a = jnp.asarray(a, jnp.float32)
+            b = jnp.asarray(b, jnp.float32)
+            err = float(jnp.max(jnp.abs(a - b)))
+            scale = max(1.0, float(jnp.max(jnp.abs(b))))
+            errs[name] = round(err / scale, 5)
+            if not math.isfinite(err) or err > KERNEL_TOL * scale:
+                failures.append(
+                    f"{shape} {name}: max|flash-ref| = {err:.4g} > "
+                    f"{KERNEL_TOL} * {scale:.3g}"
+                )
+        shapes["x".join(map(str, shape))] = errs
+    from elasticdl_tpu.parallel.elastic import describe_devices
+
+    report = _common_report(run, cfg, describe_devices(devices), failures)
+    report.update(
+        peak_bytes_in_use=_peak_bytes(devices),
+        tolerance=KERNEL_TOL,
+        scaled_max_abs_err=shapes,
+    )
+    return report
+
+
+def _child_workers(run, cfg, workdir):
+    """Runs 5 (workers4) and the kill: master, task dispatch, four
+    one-chip lockstep workers in one jax.distributed world.  THIS
+    process is the master: it must never initialize a backend."""
+    from elasticdl_tpu import client
+
+    failures = []
+    workers = 4
+    batch = cfg["per_chip_batch"] * workers
+    steps = cfg["kill_run_steps" if run == "kill" else "four_chip_steps"]
+    records = batch * steps
+    tdir = os.path.join(workdir, f"{run}_telemetry")
+    export = os.path.join(workdir, f"{run}_export")
+    shutil.rmtree(tdir, ignore_errors=True)
+    shutil.rmtree(export, ignore_errors=True)
+    argv = [
+        "train",
+        "--model_def",
+        MODEL_DEF,
+        "--model_params",
+        cfg["model_params"],
+        "--training_data",
+        _ensure_data(workdir, cfg, "train", records, seed=0),
+        # the final SAVE_MODEL task gathers the state off the devices: the
+        # exported model_version is the number of steps the world really
+        # EXECUTED (hosts enqueue ahead, and the chief reports a task
+        # when its steps are enqueued)
+        "--output",
+        export,
+        "--minibatch_size",
+        str(batch),
+        "--records_per_task",
+        str(batch * STEPS_PER_TASK),
+        "--num_epochs",
+        "1",
+        "--distribution_strategy",
+        "AllreduceStrategy",
+        "--num_workers",
+        str(workers),
+        "--jax_platform",
+        cfg["platform"],
+        "--port",
+        "0",
+        "--telemetry_dir",
+        tdir,
+        "--trace_sample_rate",
+        "1.0",
+    ]
+    if run == "kill":
+        plan = os.path.join(workdir, "kill_plan.json")
+        with open(plan, "w") as f:
+            json.dump(
+                {
+                    "name": "chip_smoke_kill",
+                    "faults": [
+                        {
+                            "kind": "preempt_worker",
+                            "fault_id": "kill_last_worker",
+                            "at_step": cfg["kill_step"],
+                            "process_id": workers - 1,
+                            "cluster_version": 0,
+                        }
+                    ],
+                },
+                f,
+            )
+        argv += [
+            "--envs",
+            f"ELASTICDL_TPU_CHAOS_PLAN={plan},"
+            f"ELASTICDL_TPU_CHAOS_EVENTS="
+            f"{os.path.join(workdir, 'kill_events.jsonl')}",
+        ]
+    try:
+        result = client.run(argv)
+    except RuntimeError as ex:
+        if run != "kill":
+            raise
+        # the kill is an experiment, not a gate: what the telemetry saw
+        # of a job that did not survive it is the finding
+        failures.append(f"the job did not complete: {ex}")
+        result = {}
+
+    from jax._src import xla_bridge
+
+    master_touched_jax = xla_bridge.backends_are_initialized()
+    if master_touched_jax:
+        failures.append("the master process initialized a JAX backend")
+    training = result.get("training", {})
+    if (
+        training.get("total_records") != records
+        or training.get("failed_records")
+    ):
+        failures.append(
+            f"records not accounted exactly once: {training}, "
+            f"expected {records}"
+        )
+    events, spans = _telemetry(tdir)
+    joins = {}
+    for span in spans:
+        if span["span"] == "world_join":
+            joins.setdefault(span.get("generation", 0), {})[
+                span["process_id"]
+            ] = {
+                k: span.get(k)
+                for k in ("platform", "kind", "count", "local_devices")
+            }
+    first = joins.get(0, {})
+    expected = {
+        "platform": cfg["platform"],
+        "count": workers,
+        "local_devices": 1,
+    }
+    if sorted(first) != list(range(workers)) or any(
+        join[key] != value
+        for join in first.values()
+        for key, value in expected.items()
+    ):
+        failures.append(
+            f"generation 0 is not {workers} one-device processes of one "
+            f"{workers}-device world: {first}"
+        )
+    from elasticdl_tpu.utils.export_utils import read_manifest
+
+    # (a kill the job did not survive leaves no export)
+    executed = (
+        read_manifest(export)["model_version"] if os.path.isdir(export) else None
+    )
+    if run == "workers4" and executed != steps:
+        failures.append(
+            f"the exported model is at step {executed}, expected {steps}"
+        )
+    steady = None
+    if run == "workers4":
+        steady = _steady_window_compiles(
+            events, spans, warm_step=3, until_last_step=True
+        )
+        if steady:
+            failures.append(f"{steady} compile(s) inside the steady window")
+    # the device as the WORKERS saw it (their world_join spans): this
+    # process is the master and stays off JAX to the end
+    chief = first.get(0, {})
+    report = _common_report(
+        run,
+        cfg,
+        {key: chief.get(key) for key in ("platform", "kind", "count")},
+        failures,
+    )
+    report.update(
+        steps=executed,
+        training=training,
+        world_joins={str(g): joins[g] for g in sorted(joins)},
+        world_sizes={str(g): len(joins[g]) for g in sorted(joins)},
+        reforms=result.get("reforms", []),
+        steady_window_compiles=steady,
+        master_initialized_backend=master_touched_jax,
+    )
+    return report
+
+
+_BIND_PROBE = """
+import json, os, sys
+mode, i, n, coordinator, platform = sys.argv[1:6]
+i, n = int(i), int(n)
+if mode == "standby":
+    # what a warm standby has done before its assignment arrives
+    import jax
+    from elasticdl_tpu.parallel.elastic import chip_binding_env
+    os.environ.update(chip_binding_env(i, n))
+from elasticdl_tpu.parallel import elastic
+elastic.initialize_world(coordinator, n, i, platform=platform, timeout_secs=90)
+import jax
+import jax.numpy as jnp
+from jax.experimental import multihost_utils
+seen = multihost_utils.process_allgather(jnp.asarray([i]))
+print("BIND " + json.dumps(dict(
+    elastic.describe_devices(),
+    process=i,
+    local_devices=jax.local_device_count(),
+    local_ids=[d.id for d in jax.local_devices()],
+    allgather=[int(x) for x in seen.ravel()],
+)), flush=True)
+elastic.shutdown_world()
+"""
+
+
+def _child_bind(run, cfg, workdir):
+    """Four one-chip processes in one jax.distributed world, without the
+    master: does the binding ``LocalInstanceManager`` hands its workers
+    give each process ONE chip of a four-chip world — set at spawn (a
+    cold worker) and set in-process after ``import jax`` (an activated
+    standby)?"""
+    from elasticdl_tpu.parallel.elastic import (
+        chip_binding_env,
+        pick_coordinator_port,
+    )
+
+    failures = []
+    workers = 4
+    variants = {}
+    for mode in ("spawn", "standby"):
+        if variants:
+            time.sleep(5)  # the previous world's chips are being released
+        coordinator = f"localhost:{pick_coordinator_port()}"
+        procs = []
+        for i in range(workers):
+            env = dict(os.environ)
+            if mode == "spawn":
+                env.update(chip_binding_env(i, workers))
+            log = open(os.path.join(workdir, f"bind_{mode}_{i}.log"), "w+")
+            procs.append(
+                (
+                    subprocess.Popen(
+                        [
+                            sys.executable,
+                            "-c",
+                            _BIND_PROBE,
+                            mode,
+                            str(i),
+                            str(workers),
+                            coordinator,
+                            cfg["platform"],
+                        ],
+                        env=env,
+                        stdout=log,
+                        stderr=subprocess.STDOUT,
+                    ),
+                    log,
+                )
+            )
+        deadline = time.monotonic() + 150
+        seen = []
+        for proc, log in procs:
+            try:
+                rc = proc.wait(timeout=max(1.0, deadline - time.monotonic()))
+            except subprocess.TimeoutExpired:
+                proc.kill()
+                proc.wait()
+                rc = "timeout"
+            log.seek(0)
+            lines = log.read().splitlines()
+            log.close()
+            found = [
+                json.loads(ln[5:]) for ln in lines if ln.startswith("BIND ")
+            ]
+            seen.append(
+                found[0]
+                if rc == 0 and found
+                else {"rc": rc, "tail": [ln[:300] for ln in lines[-8:]]}
+            )
+        variants[mode] = seen
+        good = all(
+            p.get("local_devices") == 1
+            and p.get("count") == workers
+            and p.get("platform") == cfg["platform"]
+            # (gathered in DEVICE order, which the chip does not tie to
+            # the process index)
+            and sorted(p.get("allgather", ())) == list(range(workers))
+            for p in seen
+        )
+        if not good:
+            failures.append(
+                f"{mode}: not {workers} one-device processes of one "
+                f"{workers}-device world"
+            )
+            break  # the next variant could only time out the same way
+    chief = variants["spawn"][0]
+    report = _common_report(
+        run,
+        cfg,
+        {key: chief.get(key) for key in ("platform", "kind", "count")},
+        failures,
+    )
+    report["bindings"] = variants
+    return report
+
+
+CHILDREN = {
+    "bind": _child_bind,
+    "train": _child_train,
+    "cache": _child_train,
+    "dp4": _child_train,
+    "kernel": _child_kernel,
+    "workers4": _child_workers,
+    "kill": _child_workers,
+}
+
+
+def _child(args) -> int:
+    os.makedirs(args.workdir, exist_ok=True)
+    report = CHILDREN[args.child](args.child, SIZES[args.size], args.workdir)
+    report["size"] = args.size
+    with open(args.report, "w") as f:
+        json.dump(report, f)
+    return 0
+
+
+def main(argv=None) -> int:
+    parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    parser.add_argument(
+        "--size",
+        choices=sorted(SIZES),
+        default="full",
+        help="full: the chip run; tiny: a CPU rehearsal of the control flow",
+    )
+    parser.add_argument(
+        "--runs",
+        default=",".join(DEFAULT_RUNS),
+        help=f"comma-separated, from: {', '.join(ALL_RUNS)}",
+    )
+    parser.add_argument(
+        "--keep",
+        default="",
+        help="copy the runs' logs, reports and telemetry into this directory",
+    )
+    parser.add_argument("--workdir", help=argparse.SUPPRESS)
+    parser.add_argument(
+        "--keep_going",
+        action="store_true",
+        help="run every listed run even after one failed (still exits 1)",
+    )
+    parser.add_argument("--child", choices=sorted(CHILDREN), help=argparse.SUPPRESS)
+    parser.add_argument("--report", help=argparse.SUPPRESS)
+    args = parser.parse_args(argv)
+    if args.child:
+        return _child(args)
+    return _parent(args)
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -25,26 +25,40 @@ from elasticdl_tpu.utils.log_utils import default_logger as logger
 
 
 def _run_local(args) -> dict:
+    from elasticdl_tpu.parallel.elastic import (
+        configure_compilation_cache,
+        describe_devices,
+    )
     from elasticdl_tpu.trainer.local_executor import LocalExecutor
 
-    if getattr(args, "compilation_cache_dir", ""):
-        from elasticdl_tpu.parallel.elastic import (
-            configure_compilation_cache,
-        )
-
-        configure_compilation_cache(args.compilation_cache_dir)
-    return LocalExecutor(args).run()
+    configure_compilation_cache(getattr(args, "compilation_cache_dir", ""))
+    executor = LocalExecutor(args)
+    results = executor.run()
+    # the final metrics, plus what ran and WHERE: the logged result must
+    # prove which device a run used (a CPU run exits 0 just the same)
+    return {
+        **results,
+        "steps": executor.trainer.step if executor.trainer else 0,
+        "device": describe_devices(executor.mesh.devices.flat),
+    }
 
 
 def _run_distributed(args) -> dict:
-    from elasticdl_tpu.master.main import main as master_main
-    from elasticdl_tpu.utils.args import build_arguments_from_parsed_result
+    from elasticdl_tpu.master.main import run_job
+    from elasticdl_tpu.utils.args import (
+        build_arguments_from_parsed_result,
+        parse_master_args,
+    )
 
-    argv = build_arguments_from_parsed_result(args)
-    rc = master_main(argv)
+    # the argv round trip normalizes exactly like a master pod's command
+    # line; the master itself never initializes a backend — its workers'
+    # log lines and world_join spans name their devices
+    rc, summary = run_job(
+        parse_master_args(build_arguments_from_parsed_result(args))
+    )
     if rc != 0:
         raise RuntimeError(f"master exited with {rc}")
-    return {"exit_code": rc}
+    return {"exit_code": rc, **summary}
 
 
 def _submit_k8s(args) -> dict:

@@ -1003,14 +1003,6 @@ def run_chaos_job(config: ChaosJobConfig) -> dict:
     from elasticdl_tpu.master.main import build_master
     from elasticdl_tpu.utils.constants import TaskType
 
-    if config.num_workers > 1:
-        # lockstep worlds hard-require the native codec
-        # (build_task_batches raises per worker without it): fail FAST
-        # with one actionable line instead of letting the workers
-        # crash-loop through the whole reform budget
-        from elasticdl_tpu.data.recordio import ensure_native_codec
-
-        ensure_native_codec()
     os.makedirs(config.workdir, exist_ok=True)
     plan_path = os.path.join(config.workdir, "chaos_plan.json")
     events_path = os.path.join(config.workdir, "chaos_events.jsonl")

@@ -5,14 +5,16 @@ Public API mirrors the access pattern the reference gets from the external
 ``Scanner(path, start, length)``, ``num_records(path)``.
 
 Backend selection: the C++ codec (``_native.so``, built by ``build.py``) is
-used when available; otherwise the pure-Python implementation.  Both emit
-and read the identical on-disk format.
+used when it is present AND was built from today's ``_native.cc``;
+otherwise the pure-Python implementation (README: ~40x slower per record).
+Both emit and read the identical on-disk format.  Training entry points
+do not leave the choice to chance: they call :func:`ensure_native_codec`,
+which builds the library or fails loudly.
 """
 
 from __future__ import annotations
 
 import ctypes
-import os
 
 from elasticdl_tpu.data.recordio import _pyimpl
 from elasticdl_tpu.data.recordio._pyimpl import CorruptFileError
@@ -26,15 +28,22 @@ __all__ = [
     "ensure_native_codec",
 ]
 
-_NATIVE_PATH = os.path.join(os.path.dirname(os.path.abspath(__file__)), "_native.so")
 _lib = None
 
 
 def _load_native():
     global _lib
-    if _lib is not None or not os.path.exists(_NATIVE_PATH):
+    if _lib is not None:
         return _lib
-    lib = ctypes.CDLL(_NATIVE_PATH)
+    # imported here, not at package import: ``python -m ...recordio.build``
+    # must not find its own module already loaded
+    from elasticdl_tpu.data.recordio import build as build_mod
+
+    if not build_mod.is_current():
+        # absent, or built from other source than today's _native.cc: a
+        # stale library is never loaded (ensure_native_codec rebuilds it)
+        return None
+    lib = ctypes.CDLL(build_mod.OUTPUT)
     lib.edlio_writer_open.restype = ctypes.c_void_p
     lib.edlio_writer_open.argtypes = [ctypes.c_char_p]
     lib.edlio_writer_write.restype = ctypes.c_int
@@ -66,12 +75,7 @@ def _load_native():
     lib.edlio_scanner_close.restype = None
     lib.edlio_scanner_close.argtypes = [ctypes.c_void_p]
     lib.edlio_last_error.restype = ctypes.c_char_p
-    try:
-        decode = lib.edl_decode_batch
-    except AttributeError:  # stale .so built before the batch decoder
-        decode = None
-    if decode is not None:
-        _register_decode(decode)
+    _register_decode(lib.edl_decode_batch)
     _lib = lib
     return _lib
 
@@ -101,24 +105,23 @@ def native_available() -> bool:
 
 def ensure_native_codec() -> str:
     """Make the native codec available or fail FAST with one actionable
-    line.  Lockstep worlds require it (a host missing it would silently
-    shuffle different batches than its peers — ``build_task_batches``
-    raises per-worker), so harness entry points call this BEFORE
-    spawning workers: one clear error beats a worker crash-loop that
-    burns the whole reform budget on a missing .so.  Attempts the build
-    in place first (the common case: fresh checkout, compiler
-    present)."""
-    if native_available():
-        return _NATIVE_PATH
+    line.  Every training entry point calls this (LocalExecutor, worker
+    main, the master before it spawns): without it a checkout with no
+    ``_native.so`` (``*.so`` is git-ignored) silently decodes in Python,
+    and a lockstep host missing it would shuffle different batches than
+    its peers.  Builds in place from the tracked ``_native.cc`` when the
+    library is absent or was built from other source."""
     from elasticdl_tpu.data.recordio import build as build_mod
 
+    if native_available():
+        return build_mod.OUTPUT
     built = build_mod.build(quiet=True)
     if built is not None and native_available():
         return built
     raise RuntimeError(
         "native EDLIO codec missing and unbuildable: run "
         "`python -m elasticdl_tpu.data.recordio.build` (needs g++ and "
-        "zlib) before starting lockstep jobs"
+        "zlib) before starting a training job"
     )
 
 

@@ -98,6 +98,14 @@ extern "C" {
 
 const char* edlio_last_error() { return g_last_error.c_str(); }
 
+// The digest of the source this library was built from (build.py passes
+// it; the loader compares it with today's _native.cc, so a library built
+// from other source is rebuilt instead of loaded).
+#ifndef EDLIO_SOURCE_DIGEST
+#define EDLIO_SOURCE_DIGEST "EDLIO_SOURCE_SHA256=unknown"
+#endif
+const char* edlio_source_digest() { return EDLIO_SOURCE_DIGEST; }
+
 void* edlio_writer_open(const char* path) {
   std::FILE* f = std::fopen(path, "wb");
   if (!f) {

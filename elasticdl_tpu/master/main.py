@@ -189,8 +189,8 @@ def build_master(args) -> Master:
     return Master(args, instance_manager_factory=im_factory)
 
 
-def main(argv=None) -> int:
-    args = parse_master_args(argv)
+def run_job(args) -> tuple[int, dict]:
+    """Run one job to completion; returns ``(exit code, job summary)``."""
     master = build_master(args)
     master.prepare()
     logger.info(
@@ -199,7 +199,13 @@ def main(argv=None) -> int:
         master.job_type.value,
     )
     rc = master.run()
-    logger.info("Job summary: %s", master.job_summary())
+    summary = master.job_summary()
+    logger.info("Job summary: %s", summary)
+    return rc, summary
+
+
+def main(argv=None) -> int:
+    rc, _summary = run_job(parse_master_args(argv))
     return rc
 
 

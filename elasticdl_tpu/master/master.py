@@ -1639,6 +1639,12 @@ class LocalInstanceManager:
         )
 
     def start_workers(self):
+        # build-or-fail BEFORE spawning: the workers share this checkout,
+        # and one clear error beats N workers racing to build (or a
+        # crash-loop burning the reform budget on a missing .so)
+        from elasticdl_tpu.data.recordio import ensure_native_codec
+
+        ensure_native_codec()
         if self.lockstep:
             self._start_world(cluster_version=0)
             self._replenish_standbys()
@@ -1697,6 +1703,20 @@ class LocalInstanceManager:
         )
         env = dict(os.environ)
         env.update(self._envs)
+        if "process_id" in world_kwargs:
+            # the local workers share ONE host, where a chip belongs to
+            # one process at a time: each lockstep process is bound to
+            # its own chip by world coordinates (inert on CPU).  Decided
+            # HERE, not in the worker — a k8s worker owns its whole host.
+            # A standby has no world yet: its binding rides the
+            # assignment (_activate_standby)
+            from elasticdl_tpu.parallel.elastic import chip_binding_env
+
+            env.update(
+                chip_binding_env(
+                    world_kwargs["process_id"], world_kwargs["num_processes"]
+                )
+            )
         if trace:
             from elasticdl_tpu.telemetry.tracing import TRACE_PARENT_ENV
 
@@ -1767,7 +1787,19 @@ class LocalInstanceManager:
             if proc.poll() is not None:
                 continue  # died while waiting; try the next one
             try:
-                line = json.dumps({"worker_id": worker_id, **world}) + "\n"
+                # the standby was spawned before its world existed, so
+                # its chip binding travels with the assignment (it has
+                # imported but not initialized a backend)
+                from elasticdl_tpu.parallel.elastic import chip_binding_env
+
+                assignment = {
+                    "worker_id": worker_id,
+                    **world,
+                    "env": chip_binding_env(
+                        world["process_id"], world["num_processes"]
+                    ),
+                }
+                line = json.dumps(assignment) + "\n"
                 proc.stdin.write(line.encode("utf-8"))
                 proc.stdin.flush()
             except (OSError, ValueError):

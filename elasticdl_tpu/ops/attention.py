@@ -28,22 +28,10 @@ from jax.experimental.pallas import tpu as pltpu
 # this many (all-equal) columns so stores stay tile-aligned
 _LANES = 128
 
-# JAX renamed pltpu.TPUCompilerParams -> pltpu.CompilerParams around
-# 0.5; accept either so the kernel builds across the versions this
-# framework supports (0.4.x pins the old name)
-_CompilerParams = getattr(
-    pltpu, "CompilerParams", getattr(pltpu, "TPUCompilerParams", None)
-)
-if _CompilerParams is None:
-    raise ImportError(
-        "jax.experimental.pallas.tpu exposes neither CompilerParams nor "
-        "TPUCompilerParams; update the alias above for this JAX version"
-    )
-
 # batch*heads and q/k-block dims are independent programs; only the
 # innermost (accumulation stream) dim is order-dependent — telling
 # Mosaic lets it pipeline the outer dims across cores
-_FLASH_COMPILER_PARAMS = _CompilerParams(
+_FLASH_COMPILER_PARAMS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "arbitrary")
 )
 
@@ -100,6 +88,21 @@ def attention_mesh_scope(mesh, sp_axis: str = "sp", sp_impl: str | None = None):
         yield
     finally:
         _mesh_context[:] = prev
+
+
+def kernel_interpret(platform: str) -> bool:
+    """Whether the pallas kernels run INTERPRETED on ``platform``: the
+    CPU has no Mosaic, so it interprets (tests run the same kernel code
+    the chip compiles); a TPU compiles; any other platform is an error —
+    never a silent trip through the interpreter."""
+    if platform == "cpu":
+        return True
+    if platform == "tpu":
+        return False
+    raise ValueError(
+        f"pallas flash attention runs compiled on 'tpu' and interpreted "
+        f"on 'cpu'; got platform {platform!r}"
+    )
 
 
 # ---- reference (jnp) -------------------------------------------------------
@@ -453,8 +456,8 @@ def flash_attention(
 ):
     """Blockwise flash attention, (B, S, H, D) layout.
 
-    ``interpret=None`` auto-selects the pallas interpreter off-TPU (CPU
-    tests run the same kernel code path the TPU compiles).
+    ``interpret=None`` follows the default backend through
+    :func:`kernel_interpret` (interpreted on CPU, compiled on TPU).
 
     Differentiable via custom_vjp with pallas kernels in BOTH directions
     (FlashAttention-2 structure): the forward saves (q, k, v, out, lse);
@@ -472,7 +475,7 @@ def _flash_geometry(q, k, sm_scale, block_q, block_k, interpret):
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     if interpret is None:
-        interpret = jax.devices()[0].platform != "tpu"
+        interpret = kernel_interpret(jax.default_backend())
     block_q = _pick_block(q.shape[1], block_q)
     block_k = _pick_block(k.shape[1], block_k)
     return sm_scale, block_q, block_k, interpret
@@ -675,16 +678,21 @@ flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 def attention(q, k, v, causal: bool = False, sm_scale: float | None = None):
     """Self-attention entry point for layers: sequence-parallel attention
     (ring by default, ulysses when configured) when the registered mesh
-    has an ``sp`` axis > 1, else the local flash kernel."""
-    from elasticdl_tpu.ops.ring_attention import ring_attention
+    has an ``sp`` axis > 1, else the local flash kernel — mapped over the
+    mesh's batch and head axes, because a compiled pallas kernel is an
+    opaque custom call GSPMD cannot partition: JAX refuses to lower it
+    bare inside a multi-device jitted program (interpreted, on CPU, it
+    is ordinary HLO and partitions, which hid this from every test)."""
+    from elasticdl_tpu.ops.ring_attention import (
+        ring_attention,
+        sequence_shard_spec,
+    )
     from elasticdl_tpu.ops.ulysses import ulysses_attention
 
     mesh, sp_axis, sp_impl = get_attention_mesh()
-    if (
-        mesh is not None
-        and sp_axis in mesh.axis_names
-        and mesh.shape[sp_axis] > 1
-    ):
+    if mesh is None:
+        return flash_attention(q, k, v, causal=causal, sm_scale=sm_scale)
+    if sp_axis in mesh.axis_names and mesh.shape[sp_axis] > 1:
         impl = (
             ulysses_attention if sp_impl == "ulysses" else ring_attention
         )
@@ -692,12 +700,28 @@ def attention(q, k, v, causal: bool = False, sm_scale: float | None = None):
             q, k, v, mesh=mesh, axis_name=sp_axis, causal=causal,
             sm_scale=sm_scale,
         )
-    # interpret must follow the mesh's platform, NOT the process default:
-    # a CPU mesh on a TPU-default machine (virtual-device dryrun) compiles
+    # interpret follows the MESH's platform, not the process default: a
+    # CPU mesh on a TPU-default machine (virtual-device dryrun) compiles
     # for CPU, where pallas only runs interpreted
-    interpret = None
-    if mesh is not None:
-        interpret = mesh.devices.flat[0].platform != "tpu"
-    return flash_attention(
-        q, k, v, causal=causal, sm_scale=sm_scale, interpret=interpret
+    local = functools.partial(
+        flash_attention,
+        causal=causal,
+        sm_scale=sm_scale,
+        interpret=kernel_interpret(mesh.devices.flat[0].platform),
     )
+    if mesh.devices.size == 1 or jax.sharding.get_abstract_mesh().manual_axes:
+        # one device, or already inside a caller's per-device region
+        return local(q, k, v)
+    # the layout the sp paths share, with no sequence axis: batch on the
+    # data-parallel axes, heads on tp (not under GQA — query groups must
+    # stay aligned)
+    spec = sequence_shard_spec(mesh, None, q.shape[0], q.shape[2])
+    if k.shape[2] != q.shape[2]:
+        spec = jax.sharding.PartitionSpec(spec[0], None, None, None)
+    return jax.shard_map(
+        local,
+        mesh=mesh,
+        in_specs=(spec, spec, spec),
+        out_specs=spec,
+        check_vma=False,
+    )(q, k, v)

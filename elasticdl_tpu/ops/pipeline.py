@@ -128,8 +128,6 @@ def pipeline_apply(
     mb = batch // num_microbatches
     x_mb = x.reshape(num_microbatches, mb, *x.shape[1:])
 
-    from elasticdl_tpu.ops._shard_map_compat import shard_map_compat
-
     from elasticdl_tpu.parallel.mesh import batch_divisor, data_parallel_axes
 
     dp_axes = data_parallel_axes(mesh)
@@ -147,10 +145,11 @@ def pipeline_apply(
         axis_name=axis_name,
         num_stages=num_stages,
     )
-    out = shard_map_compat(
+    out = jax.shard_map(
         body,
-        mesh,
+        mesh=mesh,
         in_specs=(param_spec, x_spec),
         out_specs=x_spec,
+        check_vma=False,
     )(stacked_params, x_mb)
     return out.reshape(batch, *x.shape[1:])

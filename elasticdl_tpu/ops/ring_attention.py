@@ -90,9 +90,10 @@ def _ring_attention_local(
 
 
 def sequence_shard_spec(
-    mesh, axis_name: str, batch: int, heads: int, head_divisor: int = 1
+    mesh, axis_name: str | None, batch: int, heads: int, head_divisor: int = 1
 ) -> P:
-    """The (B, S, H, D) PartitionSpec both sp implementations share:
+    """The (B, S, H, D) PartitionSpec every mapped attention path shares
+    (ring, ulysses, and the local kernel with ``axis_name=None``):
     batch on its data-parallel axes when divisible (replicated-batch
     fallback covers the 1-example init trace), sequence on ``axis_name``,
     heads on ``tp`` when it divides ``heads`` (and the per-device head
@@ -141,8 +142,6 @@ def ring_attention(
 
         return flash_attention(q, k, v, causal=causal, sm_scale=sm_scale)
 
-    from elasticdl_tpu.ops._shard_map_compat import shard_map_compat
-
     if q.shape[1] % axis_size:
         raise ValueError(
             f"ring attention needs seq ({q.shape[1]}) divisible by "
@@ -162,9 +161,10 @@ def ring_attention(
         causal=causal,
         sm_scale=sm_scale,
     )
-    return shard_map_compat(
+    return jax.shard_map(
         body,
-        mesh,
+        mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,
+        check_vma=False,
     )(q, k, v)

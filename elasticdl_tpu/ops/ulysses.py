@@ -86,8 +86,7 @@ def ulysses_attention(
             f"{axis_name}={sp}"
         )
 
-    from elasticdl_tpu.ops._shard_map_compat import shard_map_compat
-
+    from elasticdl_tpu.ops.attention import kernel_interpret
     from elasticdl_tpu.ops.ring_attention import sequence_shard_spec
 
     # shared layout with ring (batch on dp; head sharding over tp is
@@ -108,7 +107,7 @@ def ulysses_attention(
             f"ulysses needs the per-device head group ({local_heads}) "
             f"divisible by {axis_name}={sp}; use ring attention otherwise"
         )
-    interpret = mesh.devices.flat[0].platform != "tpu"
+    interpret = kernel_interpret(mesh.devices.flat[0].platform)
     body = functools.partial(
         _ulysses_local,
         axis_name=axis_name,
@@ -118,9 +117,10 @@ def ulysses_attention(
         group=group,
         sp=sp,
     )
-    return shard_map_compat(
+    return jax.shard_map(
         body,
-        mesh,
+        mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,
+        check_vma=False,
     )(q, k, v)

@@ -51,7 +51,7 @@ def parse_mesh_shape(mesh_shape: str) -> dict[str, int]:
 
 def detect_num_slices(devices, slice_index_fn=None) -> int:
     """Distinct TPU slices among ``devices`` (1 when the backend exposes
-    no ``slice_index`` — CPU, single slice, or older runtimes).
+    no ``slice_index`` — CPU, or a single slice).
 
     ``slice_index_fn`` overrides the attribute lookup — how the
     multichip dryrun forces a multi-slice layout onto host-platform CPU
@@ -172,14 +172,15 @@ def plan_dcn_axes(
 def order_devices_hybrid(
     devices, sizes: dict[str, int], dcn: dict[str, int], slice_index_fn=None
 ) -> np.ndarray:
-    """Fallback hybrid ordering: group devices by slice, lay each slice
+    """Hybrid ordering for devices WITHOUT a hardware topology (host
+    platform, forced slices): group devices by slice, lay each slice
     out row-major over the intra-slice (ICI) shape, and concatenate
     slices along the DCN axes — so the outer (slice) stride of a DCN axis
     crosses slices and everything else stays inside one.
 
     (``mesh_utils.create_hybrid_device_mesh`` does this with
-    topology-aware intra-slice orders; this fallback keeps the same
-    slice/axis assignment when that API is unavailable.)
+    topology-aware intra-slice orders on real multislice hardware; this
+    keeps the same slice/axis assignment.)
     """
     get_slice = slice_index_fn or (
         lambda d: getattr(d, "slice_index", 0)
@@ -193,9 +194,8 @@ def order_devices_hybrid(
     live = [a for a, deg in dcn.items() if deg > 1]
     if len(live) != 1:
         raise ValueError(
-            "fallback hybrid ordering supports exactly one DCN axis; "
-            f"got {dcn} (use a jax version with create_hybrid_device_mesh "
-            "for multi-axis DCN layouts)"
+            "row-major hybrid ordering supports exactly one DCN axis; "
+            f"got {dcn}"
         )
     ici_shape = tuple(sizes[a] // dcn.get(a, 1) for a in sizes)
     arrays = [
@@ -258,6 +258,7 @@ class MeshConfig:
         # an explicitly smaller mesh uses a device subset (useful for
         # single-device baselines on a multi-device host)
         devices = list(devices)[:total]
+        platform = devices[0].platform
         axis_names = tuple(sizes)
         shape = tuple(sizes[a] for a in axis_names)
         get_slice = slice_index_fn or (
@@ -285,23 +286,21 @@ class MeshConfig:
                 sizes[a] // dcn.get(a, 1) for a in axis_names
             )
             dcn_shape = tuple(dcn.get(a, 1) for a in axis_names)
-            if slice_index_fn is not None:
-                # forced slices: mesh_utils would re-read the (absent)
-                # device attributes — use the in-repo hybrid ordering
+            if slice_index_fn is not None or platform == "cpu":
+                # forced slices (mesh_utils would re-read the absent
+                # device attributes) and host-platform devices (no
+                # topology to exploit): the in-repo hybrid ordering
                 device_array = order_devices_hybrid(
                     devices, sizes, dcn, slice_index_fn
                 )
             else:
-                try:
-                    from jax.experimental import mesh_utils
+                # real multislice hardware: a layout mesh_utils refuses
+                # is an error to surface, not to paper over row-major
+                from jax.experimental import mesh_utils
 
-                    device_array = mesh_utils.create_hybrid_device_mesh(
-                        ici_shape, dcn_shape, devices=devices
-                    )
-                except Exception:
-                    device_array = order_devices_hybrid(
-                        devices, sizes, dcn
-                    )
+                device_array = mesh_utils.create_hybrid_device_mesh(
+                    ici_shape, dcn_shape, devices=devices
+                )
             topology = f"{n_slices} slices (DCN axes {dcn})"
         else:
             if self.dcn_axes:
@@ -314,21 +313,26 @@ class MeshConfig:
                     "flat mesh",
                     self.dcn_axes,
                 )
-            try:
+            if platform == "cpu":
+                # host-platform devices have no interconnect topology:
+                # row-major is the layout
+                device_array = np.asarray(devices).reshape(shape)
+            else:
+                # topology-aware order; on a chip a refused layout is
+                # an error to surface, never a silent row-major mesh
                 from jax.experimental import mesh_utils
 
                 device_array = mesh_utils.create_device_mesh(
                     shape, devices=devices
                 )
-            except Exception:
-                # fallback (e.g. host-platform CPU devices): row-major
-                device_array = np.asarray(devices).reshape(shape)
             topology = "1 slice"
         mesh = Mesh(device_array, axis_names)
         logger.info(
-            "Created mesh %s over %d devices, %s",
+            "Created mesh %s over %d %s devices (%s), %s",
             {a: s for a, s in sizes.items() if s > 1} or {"dp": 1},
             len(devices),
+            platform,
+            devices[0].device_kind,
             topology,
         )
         return mesh

@@ -183,9 +183,11 @@ def _install_telemetry(args):
 
 
 def run_replica(args) -> int:
+    from elasticdl_tpu.parallel.elastic import configure_compilation_cache
     from elasticdl_tpu.serving.engine import ExportDirWatcher
     from elasticdl_tpu.serving.replica import ServingReplica
 
+    configure_compilation_cache()
     _install_telemetry(args)
     replica = ServingReplica(
         args.model_dir,

@@ -1,7 +1,7 @@
 """Per-dispatch step anatomy: continuous, sum-exact time attribution.
 
-BENCH_r04 pinned ``mnist_e2e`` at ``e2e_vs_roofline 0.695`` without any
-way to say *where inside a dispatch* the missing time goes: the XLA
+An end-to-end rate below its roofline comes without any way to say
+*where inside a dispatch* the missing time goes: the XLA
 profiler is a 5-step one-shot window and the ``step`` histogram is one
 undifferentiated number.  This module is the always-on decomposition —
 every dispatch group's wall time split into named, NON-OVERLAPPING

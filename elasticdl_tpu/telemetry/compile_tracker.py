@@ -17,14 +17,9 @@ worth watching.  This module makes it observable:
 
 Mechanism: :func:`install` registers a ``jax.monitoring`` duration
 listener for the ``/jax/core/compile/backend_compile_duration`` event
-(one firing per program actually handed to XLA — cache hits and traces
-don't fire it).  When the monitoring API is unavailable the installer
-falls back to wrapping ``jax._src.compiler.compile_or_get_cached`` (the
-funnel every jitted lower/compile path goes through) — an APPROXIMATION:
-unlike the monitoring event, the wrap also counts persistent-compile-
-cache lookups that hit, so wrap-mode totals are an upper bound.  If
-neither hook exists the tracker stays disabled and
-:func:`compile_count` returns 0.
+(one firing per program handed to the backend — in-memory jit cache
+hits and traces don't fire it; a persistent-cache hit does, with the
+retrieval time as its duration).
 
 Install is idempotent and the disabled cost is zero: nothing here sits
 on the step path — compiles are the rare event being counted.
@@ -45,7 +40,6 @@ _lock = threading.Lock()
 _count = 0
 _secs_total = 0.0
 _installed = False
-_mode: str | None = None
 
 
 def _record(duration_secs: float):
@@ -70,41 +64,16 @@ def _on_event_duration(event: str, duration_secs: float, **_kwargs):
         _record(duration_secs)
 
 
-def install() -> bool:
-    """Register the compile listener once per process; returns whether a
-    hook was installed (False only on a JAX without monitoring or a
-    compile funnel to wrap)."""
-    global _installed, _mode
+def install():
+    """Register the compile listener, once per process."""
+    global _installed
     with _lock:
         if _installed:
-            return _mode is not None
+            return
         _installed = True
-    try:
-        from jax import monitoring
+    from jax import monitoring
 
-        monitoring.register_event_duration_secs_listener(_on_event_duration)
-        _mode = "monitoring"
-        return True
-    except Exception:  # noqa: BLE001 — fall through to the wrap
-        pass
-    try:  # fallback: wrap the one funnel every lower/compile path uses
-        from jax._src import compiler as _jax_compiler
-
-        wrapped = _jax_compiler.compile_or_get_cached
-
-        def counting(*args, **kwargs):
-            t0 = time.perf_counter()
-            try:
-                return wrapped(*args, **kwargs)
-            finally:
-                _record(time.perf_counter() - t0)
-
-        _jax_compiler.compile_or_get_cached = counting
-        _mode = "wrap"
-        return True
-    except Exception:  # noqa: BLE001 — tracker stays disabled
-        _mode = None
-        return False
+    monitoring.register_event_duration_secs_listener(_on_event_duration)
 
 
 def compile_count() -> int:
@@ -115,11 +84,6 @@ def compile_count() -> int:
 def compile_secs_total() -> float:
     """Total seconds this process spent in backend compiles."""
     return _secs_total
-
-
-def installed_mode() -> str | None:
-    """``'monitoring'`` / ``'wrap'`` / ``None`` (diagnostics only)."""
-    return _mode
 
 
 class ExecCounterReporter:

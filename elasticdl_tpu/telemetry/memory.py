@@ -177,13 +177,19 @@ def read_device_memory() -> dict:
     without allocator stats (CPU returns ``None`` from
     ``memory_stats()``) — the graceful-None contract.  The limit minus
     in-use is the headroom the device stager's admission control
-    budgets against."""
-    try:
-        import jax
+    budgets against.
 
-        devices = jax.local_devices()
-    except Exception:  # noqa: BLE001 — no backend is a valid state
+    Reads the backend this process ALREADY runs and never starts one:
+    ``{}`` before any backend is initialized.  A chip belongs to one
+    process at a time, so a master (its ledger samples at reform edges)
+    or a waiting standby that initialized a backend here would take
+    every chip of the host from the workers."""
+    import jax
+    from jax._src import xla_bridge
+
+    if not xla_bridge.backends_are_initialized():
         return {}
+    devices = jax.local_devices()
     in_use = peak = limit = 0
     found = False
     for device in devices:

@@ -1,10 +1,10 @@
 """Device-path pipelining: double-buffered h2d staging, batch-buffer
 donation and async retire-behind dispatch.
 
-BENCH_r04 measured every e2e config binding on ``device_path`` with
-``vs_step_only`` ~0.1: the jitted step standalone is ~10x faster than
-the end-to-end record flow, and PR 9's anatomy says the gap is
-host-side serialization — every dispatch group's batch is padded,
+The jitted step standalone runs faster than the end-to-end record flow
+(by how much on the current machine: not measured, ROADMAP A1), and
+PR 9's anatomy says the gap is host-side serialization — every
+dispatch group's batch is padded,
 stacked and placed on device ON the dispatching thread, between
 dispatches.  This module closes that gap for the canonical-shape path
 (shapes are pure functions of config since PR 5, so staging buffers

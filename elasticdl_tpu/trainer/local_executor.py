@@ -20,6 +20,7 @@ import numpy as np
 from elasticdl_tpu.data.dataset import Dataset
 from elasticdl_tpu.data.factory import create_data_reader
 from elasticdl_tpu.data.fast_pipeline import build_task_batches
+from elasticdl_tpu.data.recordio import ensure_native_codec
 from elasticdl_tpu.master.task_dispatcher import TaskDispatcher
 from elasticdl_tpu.parallel.distributed import SPMDTrainer, trim_pad
 from elasticdl_tpu.parallel.mesh import MeshConfig
@@ -52,6 +53,9 @@ def build_optimizer(spec, learning_rate=None):
 class LocalExecutor:
     def __init__(self, args):
         self._args = args
+        # build-or-fail: a checkout without _native.so must not train
+        # through the Python codec in silence
+        ensure_native_codec()
         self._spec = get_model_spec(
             args.model_zoo,
             args.model_def,
@@ -138,7 +142,7 @@ class LocalExecutor:
             getattr(args, "pipeline_depth", None)
         )
         if getattr(args, "steps_per_dispatch", 1) == "auto":
-            # measure the link overhead off the first dispatch's
+            # measure the per-dispatch overhead off the first dispatch's
             # critical path (the probe result feeds the auto-k sizing)
             warm_dispatch_overhead_async()
         self._checkpointer = PeriodicCheckpointer(
@@ -559,6 +563,10 @@ class LocalExecutor:
     @property
     def trainer(self) -> SPMDTrainer | None:
         return self._trainer
+
+    @property
+    def mesh(self):
+        return self._mesh
 
 
 def _batch_size(tree) -> int:

@@ -74,8 +74,10 @@ def _standby_wait(args) -> bool:
 
     # pin the platform BEFORE any import can initialize a backend: a
     # model-zoo module doing jnp work at import time would otherwise
-    # initialize the default (possibly plugin) backend, making the
-    # activation-time configure_platform ineffective (elastic.py:29-38)
+    # initialize the default backend, making the activation-time
+    # configure_platform ineffective.  (On a chip that import-time
+    # backend would also reach for chips the live world holds — a
+    # standby must not touch a device before its assignment.)
     elastic.configure_platform(getattr(args, "jax_platform", "") or None)
 
     from elasticdl_tpu.utils.model_utils import get_model_spec
@@ -100,6 +102,9 @@ def _standby_wait(args) -> bool:
         assignment = json.loads(line) if line.strip() else None
     if assignment is None:
         return False
+    # the local manager's per-process chip binding (a cold spawn gets it
+    # in its environment): applied before any backend initializes
+    os.environ.update(assignment.pop("env", {}))
     for key, value in assignment.items():
         setattr(args, key, value)
     args.standby = 0
@@ -186,10 +191,11 @@ def _poll_world_assignment(
 
 def main(argv=None) -> int:
     args = parse_worker_args(argv)
-    if getattr(args, "compilation_cache_dir", ""):
-        from elasticdl_tpu.parallel.elastic import configure_compilation_cache
+    from elasticdl_tpu.data.recordio import ensure_native_codec
+    from elasticdl_tpu.parallel.elastic import configure_compilation_cache
 
-        configure_compilation_cache(args.compilation_cache_dir)
+    ensure_native_codec()
+    configure_compilation_cache(getattr(args, "compilation_cache_dir", ""))
     if getattr(args, "standby", 0):
         if not _standby_wait(args):
             return 0
@@ -232,13 +238,24 @@ def main(argv=None) -> int:
                 tracing.SPAN_WORLD_JOIN,
                 trace_ctx=reform_parent,
                 coordinator=coordinator_addr,
-            ):
+            ) as join_span:
                 elastic.initialize_world(
                     coordinator_addr,
                     args.num_processes,
                     args.process_id,
                     platform=getattr(args, "jax_platform", "") or None,
                 )
+                # where this process runs, on the span and in the log:
+                # the proof that each worker holds its own chip(s)
+                import jax
+
+                where = dict(
+                    elastic.describe_devices(),
+                    local_devices=jax.local_device_count(),
+                )
+                if join_span is not None:
+                    join_span.set(**where)
+                logger.info("Worker %d devices: %s", args.worker_id, where)
             tracing.flush()
             try:
                 LockstepWorker(args, client).run()
