@@ -1,0 +1,3 @@
+"""``step_device_ms.vision``: see ``perf.layer_readers.step_device_ms``."""
+
+from perf.layer_readers import step_device_ms as read  # noqa: F401

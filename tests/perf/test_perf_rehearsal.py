@@ -1,0 +1,104 @@
+"""The command, end to end, on the CPU backend at a tiny size: the cell is
+one that only these tests add (files under tests/perf plus manifest
+entries).  A rehearsal says the control flow is right and what the program
+counts; it writes no device metric."""
+
+import json
+import os
+import subprocess
+import sys
+
+import pytest
+from perf_testlib import (
+    ROOT,
+    TINY_CELL,
+    TINY_FAMILY_CELL,
+    TINY_RESIDENT_CELL,
+    manifest_with_tiny_cell,
+)
+
+
+def rehearse(tmp_path, trace: int, seed: int, cell: str = TINY_CELL):
+    manifest = tmp_path / "BENCHMARK.json"
+    manifest.write_text(json.dumps(manifest_with_tiny_cell()))
+    env = {
+        k: v
+        for k, v in os.environ.items()
+        if k not in ("XLA_FLAGS", "JAX_PLATFORMS", "BENCH_RUN")
+    }
+    env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "cache")
+    done = subprocess.run(
+        [
+            sys.executable, os.path.join(ROOT, "perf", "run.py"),
+            "--workload", cell, "--seed", str(seed), "--seconds", "2",
+            "--trace", str(trace), "--manifest", str(manifest),
+            "--rehearse-cpu",
+        ],
+        cwd=ROOT, env=env, capture_output=True, text=True, timeout=300,
+    )
+    assert done.returncode == 0, done.stderr[-2000:]
+    lines = done.stdout.strip().splitlines()
+    return json.loads(lines[-2]), json.loads(lines[-1])
+
+
+@pytest.mark.parametrize(
+    "trace,seed,cell",
+    [
+        (0, 7, TINY_CELL),
+        (1, 2**31 + 11, TINY_CELL),
+        (1, 9, TINY_RESIDENT_CELL),
+        (0, 2**31 + 3, TINY_FAMILY_CELL),
+    ],
+)
+def test_rehearsal_on_cpu(tmp_path, trace, seed, cell):
+    info, result = rehearse(tmp_path, trace, seed, cell)
+    assert set(result) >= {"correct", "attempted", "failed", "metrics", "device"}
+    assert result["device"]["platform"] == "cpu"
+    assert result["device"]["count"] == 1
+    # no time, rate or share of a CPU run is written under a metric's name
+    assert result["metrics"] == {}
+    assert "busy_s" not in result["device"]
+    assert result["correct"] is True, info["checks"]
+    assert result["failed"] == 0 and result["attempted"] > 0
+    assert info["compiles_in_window"] == 0
+    assert info["last_loss"] < info["first_loss"]
+    assert info["readings"] >= 5
+    assert info["q1"] <= info["median"] <= info["q3"]
+    # the path holds the dispatcher's completed records to its own count;
+    # traffic mode resident has no dispatcher and claims no such check
+    if cell == TINY_RESIDENT_CELL:
+        assert "records_completed" not in info["checks"]
+    else:
+        assert info["checks"]["records_completed"]
+        assert info["checks"]["none_failed"]
+    # the rate is the window's total, with the readings' median beside it
+    assert info["total_over_window"] == info["units"] / info["window_s"]
+    if trace:
+        assert info["untraced_rate"] > 0 and info["traced_rate"] > 0
+    else:
+        assert info["window_s"] >= 2.0
+
+
+def test_without_the_program_the_command_fails_and_prints_no_result(tmp_path):
+    """A directory that holds only BENCHMARK.json and the benchmark's own
+    paths is not a checkout of the system under test."""
+    import shutil
+
+    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), tmp_path)
+    for path in manifest_with_tiny_cell()["paths"]:
+        shutil.copytree(
+            os.path.join(ROOT, path), tmp_path / path,
+            ignore=shutil.ignore_patterns("__pycache__", ".data"),
+        )
+    done = subprocess.run(
+        [
+            sys.executable, str(tmp_path / "perf" / "run.py"),
+            "--workload", "gpt2s_seq1024", "--seed", "1", "--seconds", "1",
+            "--trace", "0",
+        ],
+        cwd=tmp_path, capture_output=True, text=True, timeout=120,
+        env={k: v for k, v in os.environ.items() if k != "PYTHONPATH"},
+    )
+    assert done.returncode == 2
+    assert done.stdout == ""
+    assert "not beside the benchmark" in done.stderr
