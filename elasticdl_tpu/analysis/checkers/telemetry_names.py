@@ -130,6 +130,15 @@ REQUIRED_PHASE_NAMES = frozenset(
         "queue_wait",
         "d2h_transfer",
         "boundary_stall",
+        # the always-on timeline's span names (read by name by the
+        # benchmark's readers, perf/program_spans.py, and by the profile
+        # window's host_spans.json consumers)
+        "enqueue",
+        "ready_wait",
+        "sync",
+        "produce_next_task",
+        "produce_batch",
+        "produce_blocked",
     }
 )
 REQUIRED_METRIC_NAMES = frozenset(

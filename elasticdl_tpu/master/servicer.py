@@ -768,10 +768,16 @@ class MasterServicer:
                 num_steps = max(1, int(request.num_steps))
             except (TypeError, ValueError):
                 num_steps = 5
+            try:
+                seconds = max(0.0, float(request.seconds))
+            except (TypeError, ValueError):
+                seconds = 0.0
             self._profile_command = {
                 "window_id": self._profile_window_seq,
                 "num_steps": num_steps,
                 "out_dir": str(request.out_dir or ""),
+                # > 0: the window is sized by the clock, not in steps
+                "seconds": seconds,
                 "issued_at": now,
             }
             window_id = self._profile_window_seq

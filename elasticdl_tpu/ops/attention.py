@@ -37,6 +37,13 @@ _FLASH_COMPILER_PARAMS = pltpu.CompilerParams(
 
 _NEG_INF = -1e30
 
+# the three kernels' names: each ``pallas_call``'s ``name``, which the
+# compiled custom-call carries, so a device trace tells forward, dQ and
+# dK/dV apart by name (perf/layer_metrics/flash_*_roofline.lm.py)
+FLASH_FWD = "flash_fwd"
+FLASH_DQ = "flash_dq"
+FLASH_DKV = "flash_dkv"
+
 
 # ---- mesh context (set by the trainer, read by layers) ---------------------
 
@@ -549,6 +556,7 @@ def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret):
         ],
         compiler_params=_FLASH_COMPILER_PARAMS,
         interpret=interpret,
+        name=FLASH_FWD,
     )(qf, kf, vf)
     return _unfold_heads(out, batch, heads), lse
 
@@ -606,6 +614,7 @@ def _flash_backward(
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         compiler_params=_FLASH_COMPILER_PARAMS,
         interpret=interpret,
+        name=FLASH_DQ,
     )(qf, kf, vf, dof, lse, delta)
 
     # dK/dV are computed per q-head (the kernel never materializes
@@ -643,6 +652,7 @@ def _flash_backward(
         ],
         compiler_params=_FLASH_COMPILER_PARAMS,
         interpret=interpret,
+        name=FLASH_DKV,
     )(qf, kf, vf, dof, lse, delta)
 
     dq = _unfold_heads(dq, batch, heads)

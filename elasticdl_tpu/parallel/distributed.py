@@ -15,6 +15,7 @@ One ``SPMDTrainer`` instance per worker process; the same code runs on a
 
 from __future__ import annotations
 
+import time
 from typing import Callable, Sequence
 
 import jax
@@ -25,6 +26,7 @@ from elasticdl_tpu.ops.attention import attention_mesh_scope
 from elasticdl_tpu.parallel import elastic
 from elasticdl_tpu.parallel import sharding as sharding_lib
 from elasticdl_tpu.parallel.mesh import batch_divisor
+from elasticdl_tpu.telemetry.anatomy import PHASE_H2D_TRANSFER, TIMELINE
 from elasticdl_tpu.trainer.state import TrainState
 from elasticdl_tpu.trainer.step import (
     build_eval_step,
@@ -158,8 +160,15 @@ class SPMDTrainer:
         the rows its devices own — no cross-host copy, and the global
         Array equals the host batch.  Row-range lookups are memoized per
         shape (pure functions of the immutable mesh/sharding).
-        """
 
+        Every public ``place_*`` records ONE ``h2d_transfer`` span on the
+        host's timeline (telemetry/anatomy.py) with the bytes it placed:
+        here, where the work is, so every caller's is recorded.
+        """
+        t0 = time.perf_counter_ns()
+        return _note_placed(t0, self._place_batch(tree))
+
+    def _place_batch(self, tree):
         def _place(x):
             x = np.asarray(x)
             sh = self._batch_sharding(x.ndim)
@@ -211,8 +220,9 @@ class SPMDTrainer:
         The runtimes' hot paths use :meth:`pad_to` + :meth:`row_mask`
         instead (shape-canonical batching: ONE program shape per step
         kind, padded rows exactly zero-weighted)."""
+        t0 = time.perf_counter_ns()
         padded, _ = self.pad_batch(tree)
-        return self.place_batch(padded)
+        return _note_placed(t0, self._place_batch(padded))
 
     # ---- shape-canonical batching ------------------------------------------
     # THE canonical row count itself is a pure function of static config
@@ -253,7 +263,8 @@ class SPMDTrainer:
         diverge); outputs are trimmed back by :func:`trim_pad`, and the
         loss side carries :meth:`place_mask` weights so the padding is
         weightless."""
-        return self.place_batch(self.pad_to(tree, rows))
+        t0 = time.perf_counter_ns()
+        return _note_placed(t0, self._place_batch(self.pad_to(tree, rows)))
 
     def place_mask(self, n_real: int, rows: int):
         """:meth:`row_mask` placed like any 1-D batch leaf."""
@@ -273,10 +284,14 @@ class SPMDTrainer:
         self._step_cache = None
 
     def train_step(self, features, labels, weights=None):
+        # the timeline's ``enqueue``: mesh scope entry + the jitted call
+        # returning (the device runs on); never a block
+        t0 = time.perf_counter_ns()
         with self.mesh, attention_mesh_scope(self.mesh):
             self._state, metrics = self._train_step(
                 self._state, features, labels, weights
             )
+        TIMELINE.record_enqueue(t0, metrics)
         if self._step_cache is not None:
             self._step_cache += 1
         return metrics
@@ -323,6 +338,7 @@ class SPMDTrainer:
                 out_shardings=(self.state_shardings, None),
             )
             self._stacked_scan_cache[key] = scan_fn
+        t0 = time.perf_counter_ns()
         with self.mesh, attention_mesh_scope(self.mesh):
             if stacked_weights is None:
                 self._state, metrics = scan_fn(
@@ -335,6 +351,7 @@ class SPMDTrainer:
                     stacked_labels,
                     stacked_weights,
                 )
+        TIMELINE.record_enqueue(t0, metrics)
         if self._step_cache is not None:
             self._step_cache += int(num_steps)
         return jax.tree_util.tree_map(lambda m: m[-1], metrics)
@@ -343,6 +360,8 @@ class SPMDTrainer:
         """Place a (K, batch, ...) stacked tree: same layout as
         :meth:`place_batch` per step with a replicated leading K axis."""
         from jax.sharding import PartitionSpec as P
+
+        t0 = time.perf_counter_ns()
 
         def _place(x):
             x = np.asarray(x)
@@ -356,7 +375,7 @@ class SPMDTrainer:
                 x.shape, sh, lambda idx: x[idx]
             )
 
-        return jax.tree_util.tree_map(_place, tree)
+        return _note_placed(t0, jax.tree_util.tree_map(_place, tree))
 
     def eval_step(self, features, labels, weights=None):
         with self.mesh, attention_mesh_scope(self.mesh):
@@ -375,6 +394,14 @@ class SPMDTrainer:
         if self._step_cache is None:
             self._step_cache = int(jax.device_get(self._state.step))
         return self._step_cache
+
+
+def _note_placed(start_ns: int, placed):
+    """One ``h2d_transfer`` span for a public ``place_*`` call, counting
+    the bytes it placed; returns ``placed``."""
+    nbytes = sum(leaf.nbytes for leaf in jax.tree_util.tree_leaves(placed))
+    TIMELINE.record(PHASE_H2D_TRANSFER, start_ns, count=nbytes)
+    return placed
 
 
 def _host_slice_for_init(sample_features):

@@ -169,7 +169,7 @@ class HeartbeatResponse:
     # decode to "" — wire-compatible
     boot_id: str = ""
     # on-demand profiler command (utils/profiling.py): {"window_id",
-    # "num_steps", "out_dir"} when a request_profile window is being
+    # "num_steps", "out_dir", "seconds"} when a request_profile window is being
     # distributed; workers dedupe by window_id, so the master can keep
     # re-sending the latest command and every replay is absorbed.
     # Empty otherwise; old payloads decode to {} — wire-compatible
@@ -443,12 +443,14 @@ class RequestProfileRequest:
     master rides the command down on every heartbeat response until the
     distribution TTL lapses, and each worker opens one
     ``num_steps``-step capture into its telemetry dir (or ``out_dir``
-    when given).  Arming while a window is already being distributed is
+    when given); ``seconds`` > 0 sizes the window by the clock instead
+    (old payloads decode to 0 — wire-compatible).  Arming while a window is already being distributed is
     ABSORBED (the response carries the existing window id) — that is
     what makes a re-delivered arm safe to retry."""
 
     num_steps: int = 5
     out_dir: str = ""
+    seconds: float = 0.0
 
 
 @dataclass

@@ -19,7 +19,11 @@ Mechanism: :func:`install` registers a ``jax.monitoring`` duration
 listener for the ``/jax/core/compile/backend_compile_duration`` event
 (one firing per program handed to the backend — in-memory jit cache
 hits and traces don't fire it; a persistent-cache hit does, with the
-retrieval time as its duration).
+retrieval time as its duration).  The same listener sums the two stages
+before it, ``jaxpr_trace_duration`` and ``jaxpr_to_mlir_module_duration``
+(:func:`trace_secs_total`, :func:`lower_secs_total`): what a process
+pays before its first step even when every program comes from the
+persistent cache.
 
 Install is idempotent and the disabled cost is zero: nothing here sits
 on the step path — compiles are the rare event being counted.
@@ -35,10 +39,17 @@ import time
 COMPILE_COUNT_KEY = "compile_count"
 
 _BACKEND_COMPILE_SUFFIX = "backend_compile_duration"
+# the two stages before the backend's, which fire for every program
+# traced and lowered — a persistent-cache hit included: tracing the
+# Python into a jaxpr, and lowering the jaxpr to an MLIR module
+_TRACE_SUFFIX = "jaxpr_trace_duration"
+_LOWER_SUFFIX = "jaxpr_to_mlir_module_duration"
 
 _lock = threading.Lock()
 _count = 0
 _secs_total = 0.0
+_trace_secs_total = 0.0
+_lower_secs_total = 0.0
 _installed = False
 
 
@@ -60,8 +71,15 @@ def _record(duration_secs: float):
 
 
 def _on_event_duration(event: str, duration_secs: float, **_kwargs):
+    global _trace_secs_total, _lower_secs_total
     if event.endswith(_BACKEND_COMPILE_SUFFIX):
         _record(duration_secs)
+    elif event.endswith(_TRACE_SUFFIX):
+        with _lock:
+            _trace_secs_total += max(0.0, float(duration_secs))
+    elif event.endswith(_LOWER_SUFFIX):
+        with _lock:
+            _lower_secs_total += max(0.0, float(duration_secs))
 
 
 def install():
@@ -84,6 +102,16 @@ def compile_count() -> int:
 def compile_secs_total() -> float:
     """Total seconds this process spent in backend compiles."""
     return _secs_total
+
+
+def trace_secs_total() -> float:
+    """Total seconds this process spent tracing Python into jaxprs."""
+    return _trace_secs_total
+
+
+def lower_secs_total() -> float:
+    """Total seconds this process spent lowering jaxprs to MLIR."""
+    return _lower_secs_total
 
 
 class ExecCounterReporter:
@@ -114,7 +142,9 @@ class ExecCounterReporter:
 def _reset_for_tests():
     """Zero the totals (tests simulating a fresh process / generation;
     the listener registration itself is process-permanent)."""
-    global _count, _secs_total
+    global _count, _secs_total, _trace_secs_total, _lower_secs_total
     with _lock:
         _count = 0
         _secs_total = 0.0
+        _trace_secs_total = 0.0
+        _lower_secs_total = 0.0

@@ -86,7 +86,9 @@ from typing import Callable, Iterable
 import jax
 import numpy as np
 
+from elasticdl_tpu.telemetry.anatomy import TIMELINE
 from elasticdl_tpu.trainer.stacking import (
+    timed_hook,
     PreStacked,
     assemble_canonical_group,
     prestacked_weights,
@@ -817,13 +819,11 @@ class _DispatchEngine:
     on one cover both."""
 
     def __init__(self, get_trainer, depth, pre_batch, post_group, ctx, anatomy):
-        from elasticdl_tpu.telemetry.anatomy import timed_device_dispatch
-
-        self._timed = timed_device_dispatch
         self._get_trainer = get_trainer
         self._depth = depth
-        self._pre = pre_batch
-        self._post = post_group
+        # the timeline's step_bookkeeping spans, as on the serial path
+        self._pre = timed_hook(pre_batch)
+        self._post = timed_hook(post_group)
         self._ctx = ctx
         self._anatomy = anatomy
         self._inflight: deque = deque()
@@ -837,29 +837,24 @@ class _DispatchEngine:
         if len(self._inflight) > self._depth:
             jax.block_until_ready(self._inflight.popleft())
 
-    def _dispatch_stacked(self, trainer, placed):
+    def _dispatched(self, out):
+        # blocking mode: the dispatch retires here (ready_wait), so the
+        # window (depth 1 under anatomy) has nothing to hold
         if self._anatomy is None:
-            with self._ctx():
-                out = trainer.train_steps_stacked(*placed)
             self._retire_push(out)
-            return
+        else:
+            self._anatomy.ready_wait(out)
+
+    def _dispatch_stacked(self, trainer, placed):
         with self._ctx():
-            self._timed(
-                self._anatomy, lambda: trainer.train_steps_stacked(*placed)
-            )
+            out = trainer.train_steps_stacked(*placed)
+        self._dispatched(out)
 
     def _dispatch_singles(self, trainer, placed_list):
         for placed in placed_list:
-            if self._anatomy is None:
-                with self._ctx():
-                    out = trainer.train_step(*placed)
-                self._retire_push(out)
-            else:
-                with self._ctx():
-                    self._timed(
-                        self._anatomy,
-                        lambda placed=placed: trainer.train_step(*placed),
-                    )
+            with self._ctx():
+                out = trainer.train_step(*placed)
+            self._dispatched(out)
 
     def dispatch(self, staged: StagedGroup, run_hooks: bool = True):
         if staged.error is not None:
@@ -922,37 +917,27 @@ def run_pipelined_steps(
       DRAINS before returning, so the caller's task report never covers
       an un-retired group (exactly-once holds across the async window).
     """
-    from elasticdl_tpu.telemetry.anatomy import (
-        PHASE_ASSEMBLE,
-        PHASE_H2D_TRANSFER,
-        PHASE_HOST_FETCH,
-    )
-
     ctx = dispatch_ctx or contextlib.nullcontext
     rows = int(canonical_rows)
     depth = stage_depth(anatomy, pipeline_depth)
-    if anatomy is not None:
-        pre_batch = anatomy.wrapped_hook(pre_batch)
-        post_group = anatomy.wrapped_hook(post_group)
     engine = _DispatchEngine(
         get_trainer, depth, pre_batch, post_group, ctx, anatomy
     )
     _dispatch = engine.dispatch
+    # the warm-up group's hooks run here as its batches arrive
+    pre_batch = timed_hook(pre_batch)
 
-    it = iter(batches)
-
-    def _pull():
-        if anatomy is None:
-            return next(it, None)
-        with anatomy.phase(PHASE_HOST_FETCH):
-            return next(it, None)
+    # the batch-stream seam: the time inside next() is host_fetch on
+    # whichever thread pulls — this one for the warm-up group, the
+    # stager's afterwards
+    it = TIMELINE.timed_fetches(batches)
 
     # ---- warmup: first group on the serial path (creates the trainer) ------
     warm: list = []
     warm_prestacked = None
     ended = False
     while True:
-        item = _pull()
+        item = next(it, None)
         if item is None:
             ended = True
             break
@@ -972,19 +957,13 @@ def run_pipelined_steps(
 
     def _warm_stage(trainer, kind_assembled):
         kind, assembled = kind_assembled
-        if anatomy is None:
-            return kind, _place_assembled(trainer, kind, assembled)
-        with anatomy.phase(PHASE_H2D_TRANSFER):
-            return kind, _place_assembled(trainer, kind, assembled)
+        return kind, _place_assembled(trainer, kind, assembled)
 
     if warm:
         trainer = get_trainer()
-        if anatomy is None:
-            kind_assembled = assemble_canonical_group(trainer, warm, k, rows)
-        else:
-            with anatomy.phase(PHASE_ASSEMBLE):
-                kind_assembled = assemble_canonical_group(trainer, warm, k, rows)
-        kind, placed = _warm_stage(trainer, kind_assembled)
+        kind, placed = _warm_stage(
+            trainer, assemble_canonical_group(trainer, warm, k, rows)
+        )
         _dispatch(
             StagedGroup(
                 kind,
@@ -1108,9 +1087,6 @@ def run_pipelined_task_stream(
 
     ctx = dispatch_ctx or contextlib.nullcontext
     depth = stage_depth(anatomy, pipeline_depth)
-    if anatomy is not None:
-        pre_batch = anatomy.wrapped_hook(pre_batch)
-        post_group = anatomy.wrapped_hook(post_group)
     engine = _DispatchEngine(
         get_trainer, depth, pre_batch, post_group, ctx, anatomy
     )
@@ -1121,8 +1097,7 @@ def run_pipelined_task_stream(
         # consumer learns boundaries in exact stream order
         for tid_, task_, batches_ in it:
             yield TaskMark(TaskMark.START, tid_, task_)
-            for item in batches_:
-                yield item
+            yield from TIMELINE.timed_fetches(batches_)
             yield TaskMark(TaskMark.END, tid_, task_)
 
     # one extra queue slot vs the per-task stager: the END/START marks

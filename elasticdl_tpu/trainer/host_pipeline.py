@@ -29,11 +29,18 @@ from __future__ import annotations
 
 import queue
 import threading
+import time
 from typing import Callable, Iterator
 
 import jax
 import numpy as np
 
+from elasticdl_tpu.telemetry.anatomy import (
+    PHASE_PRODUCE_BATCH,
+    PHASE_PRODUCE_BLOCKED,
+    PHASE_PRODUCE_NEXT_TASK,
+    TIMELINE,
+)
 from elasticdl_tpu.trainer.stacking import PreStacked
 
 _TASK = "task"
@@ -111,14 +118,16 @@ class TaskPrefetcher:
         — a PreStacked group counts its steps, not 1), and marker items
         (task boundaries etc., count=0) are throttled by total queue
         depth so a stream of empty tasks cannot drain the whole
-        dispatcher into the unbounded queue."""
+        dispatcher into the unbounded queue.  A put that had to wait
+        for its budget is on the timeline as ``produce_blocked``."""
         marker_cap = 2 * self._max_batches + 8
+        blocked_from = None
         with self._credit:
             while not self._stop.is_set():
                 if count == 0:
                     if self._q.qsize() < marker_cap:
                         self._q.put(item)
-                        return True
+                        break
                 elif (
                     self._buffered_batches < self._max_batches
                     and self._buffered_bytes < self._max_bytes
@@ -126,9 +135,15 @@ class TaskPrefetcher:
                     self._buffered_batches += count
                     self._buffered_bytes += nbytes
                     self._q.put(item)
-                    return True
+                    break
+                if blocked_from is None:
+                    blocked_from = time.perf_counter_ns()
                 self._credit.wait(timeout=0.1)
-        return False
+            else:
+                return False
+        if blocked_from is not None:
+            TIMELINE.record(PHASE_PRODUCE_BLOCKED, blocked_from)
+        return True
 
     def _release(self, count: int, nbytes: int):
         with self._credit:
@@ -136,23 +151,45 @@ class TaskPrefetcher:
             self._buffered_bytes -= nbytes
             self._credit.notify()
 
+    def _timed_batches(self, task):
+        """``make_batches(task)`` with each batch's making — read,
+        decode, shuffle, stack: the time inside ``next()`` — on the
+        timeline as ``produce_batch``, with the thread's CPU time beside
+        the wall time (equal: the thread ran; CPU short of wall: it
+        waited, for the disk, the interpreter lock or a core) and the
+        batch's bytes.  Yields the batch, its bytes and the batch
+        ordinal, which the consumer's ``host_fetch`` of it carries too."""
+        it = iter(self._make_batches(task))
+        while True:
+            t0, cpu0 = time.perf_counter_ns(), time.thread_time_ns()
+            try:
+                batch = next(it)
+            except StopIteration:
+                return
+            cpu_ns = time.thread_time_ns() - cpu0
+            nbytes = max(1, self._batch_bytes(batch))
+            yield batch, nbytes, TIMELINE.record_batch(
+                PHASE_PRODUCE_BATCH, t0, cpu_ns, nbytes
+            )
+
     def _produce(self):
         try:
             while not self._stop.is_set():
+                t0 = time.perf_counter_ns()
                 tid, task = self._next_task()
+                TIMELINE.record(PHASE_PRODUCE_NEXT_TASK, t0)
                 if task is None:
                     break
                 if not self._put((_TASK, (tid, task))):
                     return
-                for batch in self._make_batches(task):
+                for batch, nbytes, ordinal in self._timed_batches(task):
                     count = (
                         batch.num_steps
                         if isinstance(batch, PreStacked)
                         else 1
                     )
-                    nbytes = max(1, self._batch_bytes(batch))
                     if not self._put(
-                        (_BATCH, (batch, count, nbytes)),
+                        (_BATCH, (batch, count, nbytes, ordinal)),
                         count=count,
                         nbytes=nbytes,
                     ):
@@ -190,8 +227,11 @@ class TaskPrefetcher:
         while True:
             kind, payload = self._q.get()
             if kind == _BATCH:
-                batch, count, nbytes = payload
+                batch, count, nbytes, ordinal = payload
                 self._release(count, nbytes)
+                # the seam above records this fetch under the number
+                # the producer made the batch under
+                TIMELINE.set_batch_ordinal(ordinal)
                 yield batch
             elif kind == _END_TASK:
                 assert payload == expect_tid

@@ -16,10 +16,17 @@ from __future__ import annotations
 import contextlib
 import os
 import threading
+import time
 from typing import Callable, Iterable
 
 import jax
 import numpy as np
+
+from elasticdl_tpu.telemetry.anatomy import (
+    PHASE_ASSEMBLE,
+    PHASE_STEP_BOOKKEEPING,
+    TIMELINE,
+)
 
 
 def _batch_size(tree) -> int:
@@ -213,7 +220,9 @@ def assemble_canonical_group(trainer, group, k, rows):
     k >= 2 padded and stacked into one scan input — or
     ``("singles", [(feats, labels, mask)])`` for anything shorter (the
     trailing-partial rule: those dispatch through the already-compiled
-    single-step program, never a new scan length)."""
+    single-step program, never a new scan length).  Recorded on the
+    timeline as ``assemble``, here, so every caller's is."""
+    t0 = time.perf_counter_ns()
     padded = [
         (
             trainer.pad_to(f, rows),
@@ -230,8 +239,28 @@ def assemble_canonical_group(trainer, group, k, rows):
             lambda *xs: np.stack(xs), *[p[1] for p in padded]
         )
         stacked_w = np.stack([p[2] for p in padded])
-        return "stacked", (stacked_f, stacked_l, stacked_w)
-    return "singles", padded
+        assembled = "stacked", (stacked_f, stacked_l, stacked_w)
+    else:
+        assembled = "singles", padded
+    TIMELINE.record(PHASE_ASSEMBLE, t0)
+    return assembled
+
+
+def timed_hook(hook):
+    """``pre_batch`` / ``post_group`` hooks (telemetry samples, the
+    profiler, milestone checks) run between the dispatch's other spans:
+    recorded as ``step_bookkeeping`` where the loops call them.  None
+    for a None hook."""
+    if hook is None:
+        return None
+
+    def timed(*args):
+        t0 = time.perf_counter_ns()
+        out = hook(*args)
+        TIMELINE.record(PHASE_STEP_BOOKKEEPING, t0)
+        return out
+
+    return timed
 
 
 def prestacked_weights(item: PreStacked) -> np.ndarray:
@@ -268,14 +297,18 @@ def run_stacked_steps(
     ``dispatch_ctx()``: context manager wrapping each device dispatch
     (timing buckets).
 
+    The host's timeline (telemetry/anatomy.py) is written on every
+    path: ``host_fetch`` at the stream seam and ``step_bookkeeping``
+    around the hooks, here; ``assemble``, ``h2d_transfer`` and
+    ``enqueue`` by the callees that do that work.  Nothing of it blocks.
+
     ``anatomy`` (an installed
     :class:`~elasticdl_tpu.telemetry.anatomy.AnatomyRecorder`, or None):
-    per-dispatch phase attribution — fetch waits, pad/stack, placement,
-    dispatch-to-ready and the post-group hooks are timed as disjoint
-    phases summing exactly to each group's wall time, and each dispatch
-    additionally blocks on its outputs so device time is measured, not
-    queued.  ``None`` (the default) keeps the uninstrumented path: ONE
-    branch per flush, no clock reads, identical dispatch behavior.
+    the blocking, sum-exact mode — each dispatch additionally blocks on
+    its outputs so device time is measured, not queued, and each group
+    commits the timeline's spans since the previous one as disjoint
+    phases summing exactly to the group's wall time.  ``None`` (the
+    default): ONE ``is None`` branch per dispatch, nothing blocks.
 
     ``canonical_rows`` (the runtimes pass
     :func:`canonical_batch_rows`): SHAPE-CANONICAL mode — every batch is
@@ -332,21 +365,11 @@ def run_stacked_steps(
     first_shape = None
     processed = 0
     canonical = canonical_rows is not None
-    if anatomy is not None:
-        # step anatomy (telemetry/anatomy.py): fetch waits are timed at
-        # the stream seam, per-step hooks are timed as bookkeeping, and
-        # the flush bodies below time assemble/placement/compute — the
-        # disabled path takes none of these wrappers (one `is None`
-        # branch per flush, no clock reads)
-        from elasticdl_tpu.telemetry.anatomy import (
-            PHASE_ASSEMBLE,
-            PHASE_H2D_TRANSFER,
-            timed_device_dispatch,
-        )
-
-        batches = anatomy.wrap_fetches(batches)
-        pre_batch = anatomy.wrapped_hook(pre_batch)
-        post_group = anatomy.wrapped_hook(post_group)
+    # the timeline's seams (always on): the wait inside next(), and the
+    # hooks; the blocking mode adds one wait per dispatch, below
+    batches = TIMELINE.timed_fetches(batches)
+    pre_batch = timed_hook(pre_batch)
+    post_group = timed_hook(post_group)
 
     def _flush_canonical():
         nonlocal processed
@@ -356,62 +379,30 @@ def run_stacked_steps(
         note_boundary_dispatch()
         steps = len(group)
         n_records = sum(n for _f, _l, n in group)
-        if anatomy is None:
-            kind, assembled = assemble_canonical_group(
-                trainer, group, k, canonical_rows
-            )
-            if kind == "stacked":
-                with ctx():
-                    trainer.train_steps_stacked(
-                        trainer.place_stacked(assembled[0]),
-                        trainer.place_stacked(assembled[1]),
-                        trainer.place_stacked(assembled[2]),
-                    )
-            else:
-                # trailing partial group: k' single weighted steps through
-                # the one compiled program — never a scan-k' compile
-                for features, labels, mask in assembled:
-                    with ctx():
-                        trainer.train_step(
-                            trainer.place_batch(features),
-                            trainer.place_batch(labels),
-                            trainer.place_batch(mask),
-                        )
-        else:
-            # same dispatch decisions, each segment attributed; the
-            # trailing block_until_ready trades a little async overlap
-            # for a measured (not queued) device_compute phase
-            with anatomy.phase(PHASE_ASSEMBLE):
-                kind, assembled = assemble_canonical_group(
-                    trainer, group, k, canonical_rows
+        kind, assembled = assemble_canonical_group(
+            trainer, group, k, canonical_rows
+        )
+        if kind == "stacked":
+            with ctx():
+                out = trainer.train_steps_stacked(
+                    trainer.place_stacked(assembled[0]),
+                    trainer.place_stacked(assembled[1]),
+                    trainer.place_stacked(assembled[2]),
                 )
-            if kind == "stacked":
-                with anatomy.phase(PHASE_H2D_TRANSFER):
-                    placed = (
-                        trainer.place_stacked(assembled[0]),
-                        trainer.place_stacked(assembled[1]),
-                        trainer.place_stacked(assembled[2]),
-                    )
+                if anatomy is not None:
+                    anatomy.ready_wait(out)
+        else:
+            # trailing partial group: k' single weighted steps through
+            # the one compiled program — never a scan-k' compile
+            for features, labels, mask in assembled:
                 with ctx():
-                    timed_device_dispatch(
-                        anatomy,
-                        lambda: trainer.train_steps_stacked(*placed),
+                    out = trainer.train_step(
+                        trainer.place_batch(features),
+                        trainer.place_batch(labels),
+                        trainer.place_batch(mask),
                     )
-            else:
-                for features, labels, mask in assembled:
-                    with anatomy.phase(PHASE_H2D_TRANSFER):
-                        placed = (
-                            trainer.place_batch(features),
-                            trainer.place_batch(labels),
-                            trainer.place_batch(mask),
-                        )
-                    with ctx():
-                        timed_device_dispatch(
-                            anatomy,
-                            lambda placed=placed: trainer.train_step(
-                                *placed
-                            ),
-                        )
+                    if anatomy is not None:
+                        anatomy.ready_wait(out)
         processed += n_records
         group.clear()
         if post_group is not None:
@@ -460,10 +451,9 @@ def run_stacked_steps(
         if post_group is not None:
             post_group()
         if anatomy is not None:
-            # the legacy dispatch body is not segment-timed (the
-            # runtimes' hot paths are canonical); commit what was
-            # measured at the seams so intervals never leak across
-            # dispatch windows — the dispatch itself lands in untracked
+            # the legacy body does not block (the runtimes' hot paths
+            # are canonical): commit the spans its callees wrote, so
+            # none leaks into the next dispatch's window
             anatomy.commit(steps=steps, records=n_records)
 
     _flush = _flush_canonical if canonical else _flush_legacy
@@ -481,40 +471,20 @@ def run_stacked_steps(
                     pre_batch(item.sample_features)
             trainer = get_trainer()
             note_boundary_dispatch()
-            if anatomy is None:
-                with ctx():
-                    if canonical:
-                        trainer.train_steps_stacked(
-                            trainer.place_stacked(item.features),
-                            trainer.place_stacked(item.labels),
-                            trainer.place_stacked(prestacked_weights(item)),
-                        )
-                    else:
-                        trainer.train_steps_stacked(
-                            trainer.place_stacked(item.features),
-                            trainer.place_stacked(item.labels),
-                        )
-            else:
-                # a ready-made group has no pad/stack assembly — its
-                # anatomy is placement + compute (+ the fetch/hook time
-                # already attributed at the seams)
-                with anatomy.phase(PHASE_H2D_TRANSFER):
-                    if canonical:
-                        placed = (
-                            trainer.place_stacked(item.features),
-                            trainer.place_stacked(item.labels),
-                            trainer.place_stacked(prestacked_weights(item)),
-                        )
-                    else:
-                        placed = (
-                            trainer.place_stacked(item.features),
-                            trainer.place_stacked(item.labels),
-                        )
-                with ctx():
-                    timed_device_dispatch(
-                        anatomy,
-                        lambda: trainer.train_steps_stacked(*placed),
+            with ctx():
+                if canonical:
+                    out = trainer.train_steps_stacked(
+                        trainer.place_stacked(item.features),
+                        trainer.place_stacked(item.labels),
+                        trainer.place_stacked(prestacked_weights(item)),
                     )
+                else:
+                    out = trainer.train_steps_stacked(
+                        trainer.place_stacked(item.features),
+                        trainer.place_stacked(item.labels),
+                    )
+                if anatomy is not None:
+                    anatomy.ready_wait(out)
             processed += item.num_records
             if post_group is not None:
                 post_group()

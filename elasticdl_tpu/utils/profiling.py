@@ -22,6 +22,21 @@ Both paths emit the same ``profile_window_open``/``profile_window_close``
 events and the ``profile_window`` span, so the capture window can be
 located on the same timeline as the distributed trace.
 
+What a window records, and what it leaves alone.  The capture is of the
+DEVICE planes only: it is always opened with ``python_tracer_level = 0``
+and ``host_tracer_level = 0``, and there is no other way to open one.
+With the host tracer on, the runtime's transfer threads trace their own
+events and a ResNet-50 job ran at a third of its rate (PERF.md, PR 23):
+the operator's tool for a degraded job must not degrade it.  The host's
+side of the window is the program's own timeline
+(``telemetry/anatomy.py``), which records whether or not a window is
+open.  At close the profiler blocks ONCE on the newest dispatch's output
+(a ``sync`` span: the instant the device finished, on the host's clock)
+and writes the timeline's spans of the window to ``host_spans.json``
+beside the ``.xplane.pb``; ``perf/program_spans.py::align_profile_window`` puts them on
+the trace's clock by that anchor.  One block at the end of a window
+changes nothing the window measured.
+
 Disabled cost: with no window pending or open, :meth:`on_step` is one
 attribute load and a ``not x`` check (``# elastic-lint: hot-path``).
 Thread model: :meth:`arm` is called from the heartbeat thread,
@@ -31,14 +46,19 @@ lock-free gate, everything behind it synchronizes on a small lock.
 
 from __future__ import annotations
 
+import glob
 import os
 import threading
+import time
 
+from elasticdl_tpu.telemetry import anatomy
 from elasticdl_tpu.utils.log_utils import default_logger as logger
 
 # subdirectory of the telemetry dir an on-demand capture lands in when
 # the request names no explicit out_dir
 PROFILE_SUBDIR = "profile"
+# the window's host spans, written beside the device trace
+HOST_SPANS_FILE = "host_spans.json"
 
 
 class StepProfiler:
@@ -64,7 +84,11 @@ class StepProfiler:
         self._tracing = False  # guarded-by: _lock (writes)
         self._out_dir = ""  # dir of the OPEN window  # guarded-by: _lock
         self._stop_at = 0  # last in-window call index  # guarded-by: _lock
+        # a window armed in seconds closes at the first step past this
+        # perf_counter_ns instant instead  # guarded-by: _lock
+        self._stop_after_ns: int | None = None
         self._opened_at = 0  # guarded-by: _lock
+        self._opened_ns = 0  # guarded-by: _lock
         self._window_id: int | None = None  # guarded-by: _lock
         self._window_span = None  # guarded-by: _lock
         # flag-armed window (never opened yet when _flag_dir non-empty)
@@ -88,8 +112,12 @@ class StepProfiler:
         out_dir: str,
         num_steps: int = 5,
         window_id: int | None = None,
+        seconds: float | None = None,
     ) -> bool:
         """Arm an on-demand window opening at the next ``on_step``.
+        ``seconds`` sizes the window by the clock instead of in steps:
+        it closes at the first step after that long (a degraded job's
+        step time is what the operator does not know).
         Returns False when absorbed (a replayed ``window_id``) or
         refused (a window is already pending/open — the caller retries
         on a later beat; an unconsumed id stays armable)."""
@@ -106,11 +134,12 @@ class StepProfiler:
                 "out_dir": out_dir,
                 "num_steps": max(1, int(num_steps)),
                 "window_id": window_id,
+                "seconds": float(seconds) if seconds else None,
             }
             self._engaged = True
         logger.info(
-            "XLA profiler: on-demand window armed (%d steps into %s)",
-            max(1, int(num_steps)),
+            "XLA profiler: on-demand window armed (%s into %s)",
+            f"{seconds} s" if seconds else f"{max(1, int(num_steps))} steps",
             out_dir,
         )
         return True
@@ -134,6 +163,7 @@ class StepProfiler:
                         pending["out_dir"],
                         self._seen + pending["num_steps"] - 1,
                         pending["window_id"],
+                        seconds=pending["seconds"],
                     )
                 elif self._flag_dir and self._seen > self._flag_start:
                     flag_dir, self._flag_dir = self._flag_dir, ""
@@ -142,7 +172,11 @@ class StepProfiler:
                         self._flag_start + self._flag_num,
                         None,
                     )
-            elif self._seen > self._stop_at:
+            elif (
+                self._seen > self._stop_at
+                if self._stop_after_ns is None
+                else time.perf_counter_ns() >= self._stop_after_ns
+            ):
                 self._close_window_locked()
             self._refresh_engaged_locked()
 
@@ -153,11 +187,18 @@ class StepProfiler:
         )
 
     # lock-holding: _lock
-    def _open_window_locked(self, out_dir: str, stop_at: int, window_id):
+    def _open_window_locked(
+        self, out_dir: str, stop_at: int, window_id, seconds=None
+    ):
         import jax
 
+        # the device planes only: with either tracer on, the capture
+        # slows the job it is meant to explain (module docstring)
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        options.host_tracer_level = 0
         try:
-            jax.profiler.start_trace(out_dir)
+            jax.profiler.start_trace(out_dir, profiler_options=options)
         except Exception:  # noqa: BLE001 — a failed capture (another
             # trace active, unwritable dir) must not kill the training
             # thread; the window is abandoned
@@ -167,6 +208,10 @@ class StepProfiler:
         self._out_dir = out_dir
         self._stop_at = stop_at
         self._opened_at = self._seen
+        self._opened_ns = time.perf_counter_ns()
+        self._stop_after_ns = (
+            self._opened_ns + int(seconds * 1e9) if seconds else None
+        )
         self._window_id = window_id
         # telemetry marker + span so the XLA profiler window can be
         # located on the SAME timeline as the distributed trace (both
@@ -185,8 +230,10 @@ class StepProfiler:
                 _trace.SPAN_PROFILE_WINDOW, out_dir=out_dir
             )
         logger.info(
-            "XLA profiler: tracing %d steps into %s",
-            self._stop_at - self._seen + 1,
+            "XLA profiler: tracing %s into %s",
+            f"{seconds} s"
+            if seconds
+            else f"{self._stop_at - self._seen + 1} steps",
             out_dir,
         )
 
@@ -195,10 +242,23 @@ class StepProfiler:
         import jax
 
         try:
+            # the one block a window costs, at its end: the device has
+            # finished the last dispatched step when `sync` ends, which
+            # puts the host's clock on the trace's (module docstring)
+            anatomy.TIMELINE.sync()
+        except Exception:  # noqa: BLE001 — a failed step surfaces where
+            # the training thread next touches it, not here
+            logger.exception("XLA profiler: sync at window close failed")
+        try:
             jax.profiler.stop_trace()
         except Exception:  # noqa: BLE001 — a torn capture must not kill
             # the training thread
             logger.exception("XLA profiler: stop_trace failed")
+        else:
+            try:
+                self._write_host_spans()
+            except OSError:
+                logger.exception("XLA profiler: host spans not written")
         self._tracing = False
         from elasticdl_tpu.telemetry import worker_hooks
         from elasticdl_tpu.telemetry.events import EVENT_PROFILE_WINDOW_CLOSE
@@ -217,6 +277,25 @@ class StepProfiler:
         logger.info("XLA profiler: trace written to %s", self._out_dir)
         self._window_id = None
         self._out_dir = ""
+
+    # lock-holding: _lock
+    def _write_host_spans(self):
+        """The timeline's spans of the window, beside the newest
+        ``.xplane.pb`` (in the window's directory where there is none)."""
+        traces = sorted(
+            glob.glob(
+                os.path.join(
+                    self._out_dir, "plugins", "profile", "*", "*.xplane.pb"
+                )
+            )
+        )
+        where = os.path.dirname(traces[-1]) if traces else self._out_dir
+        os.makedirs(where, exist_ok=True)
+        anatomy.TIMELINE.dump(
+            os.path.join(where, HOST_SPANS_FILE),
+            start_ns=self._opened_ns,
+            end_ns=time.perf_counter_ns(),
+        )
 
     def stop(self):
         """Idempotent; called at loop exit so a short run still flushes
@@ -269,6 +348,13 @@ def apply_profile_command(
         num_steps = int(command.get("num_steps", 5))
     except (TypeError, ValueError):
         num_steps = 5
+    try:
+        seconds = float(command.get("seconds") or 0) or None
+    except (TypeError, ValueError):
+        seconds = None
     return profiler.arm(
-        os.path.join(base, leaf), num_steps=num_steps, window_id=window_id
+        os.path.join(base, leaf),
+        num_steps=num_steps,
+        window_id=window_id,
+        seconds=seconds,
     )

@@ -421,7 +421,6 @@ class Worker:
         separable."""
         from elasticdl_tpu.telemetry.anatomy import (
             PHASE_ASSEMBLE,
-            PHASE_H2D_TRANSFER,
             timed_device_dispatch,
         )
 
@@ -430,12 +429,12 @@ class Worker:
             padded_f = trainer.pad_to(features, self._canonical_rows)
             padded_l = trainer.pad_to(labels, self._canonical_rows)
             mask = trainer.row_mask(n, self._canonical_rows)
-        with anat.phase(PHASE_H2D_TRANSFER):
-            placed = (
-                trainer.place_batch(padded_f),
-                trainer.place_batch(padded_l),
-                trainer.place_batch(mask),
-            )
+        # placement and enqueue record themselves (SPMDTrainer)
+        placed = (
+            trainer.place_batch(padded_f),
+            trainer.place_batch(padded_l),
+            trainer.place_batch(mask),
+        )
         timed_device_dispatch(anat, lambda: trainer.train_step(*placed))
 
     def _predict_minibatch(self, features):
@@ -863,18 +862,16 @@ class Worker:
                     )
                 else:
                     from elasticdl_tpu.telemetry.anatomy import (
-                        PHASE_H2D_TRANSFER,
                         timed_device_dispatch,
                     )
 
-                    with anat.phase(PHASE_H2D_TRANSFER):
-                        placed = (
-                            self._trainer.place_stacked(group.features),
-                            self._trainer.place_stacked(group.labels),
-                            self._trainer.place_stacked(
-                                prestacked_weights(group)
-                            ),
-                        )
+                    placed = (
+                        self._trainer.place_stacked(group.features),
+                        self._trainer.place_stacked(group.labels),
+                        self._trainer.place_stacked(
+                            prestacked_weights(group)
+                        ),
+                    )
                     timed_device_dispatch(
                         anat,
                         lambda: self._trainer.train_steps_stacked(*placed),

@@ -1,0 +1,7 @@
+"""``place_ms.lm``: mean ``h2d_transfer`` per dispatch (``perf.program_spans``)."""
+
+from perf.program_spans import mean_ms_per_dispatch
+
+
+def read(run):
+    return mean_ms_per_dispatch(run, "h2d_transfer")

@@ -7,6 +7,7 @@ from __future__ import annotations
 
 import json
 import os
+import time
 
 import numpy as np
 import pytest
@@ -69,15 +70,19 @@ def test_wrap_fetches_attributes_next_time_to_host_fetch():
     assert sum(snap[PHASE_HOST_FETCH]["buckets"].values()) == 1
 
 
-def test_wrapped_hook_times_as_bookkeeping():
+def test_timed_hook_records_bookkeeping_for_the_recorder_to_commit():
+    """The hooks' time is written by the loops themselves
+    (``stacking.timed_hook``, always on); the recorder commits it."""
+    from elasticdl_tpu.trainer.stacking import timed_hook
+
     rec = AnatomyRecorder()
     calls = []
-    hook = rec.wrapped_hook(calls.append)
+    hook = timed_hook(calls.append)
     hook("x")
     assert calls == ["x"]
     phases = rec.commit()
     assert PHASE_STEP_BOOKKEEPING in phases
-    assert rec.wrapped_hook(None) is None
+    assert timed_hook(None) is None
 
 
 def test_heartbeat_snapshot_is_monotone_across_commits():
@@ -141,17 +146,24 @@ class _Trainer:
         mask[:n] = 1.0
         return mask
 
+    # placement and enqueue record themselves on the timeline, as
+    # SPMDTrainer's do: the loops do not time them from outside
+
     def place_batch(self, tree):
+        anatomy.TIMELINE.record(
+            anatomy.PHASE_H2D_TRANSFER, time.perf_counter_ns()
+        )
         return tree
 
-    def place_stacked(self, tree):
-        return tree
+    place_stacked = place_batch
 
     def train_step(self, features, labels, weights=None):
-        return np.float32(0.0)
+        out = np.float32(0.0)
+        anatomy.TIMELINE.record_enqueue(time.perf_counter_ns(), out)
+        return out
 
     def train_steps_stacked(self, features, labels, weights=None):
-        return np.float32(0.0)
+        return self.train_step(features, labels, weights)
 
 
 def _batches(sizes):

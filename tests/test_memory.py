@@ -760,7 +760,7 @@ class _FakeJaxProfiler:
         monkeypatch.setattr(
             jax.profiler,
             "start_trace",
-            lambda d: self.calls.append(("start", d)),
+            lambda d, **_options: self.calls.append(("start", d)),
         )
         monkeypatch.setattr(
             jax.profiler, "stop_trace", lambda: self.calls.append(("stop",))

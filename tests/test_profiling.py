@@ -50,7 +50,7 @@ def test_step_profiler_window_bounds(monkeypatch, tmp_path):
 
     calls = []
     monkeypatch.setattr(
-        jax.profiler, "start_trace", lambda d: calls.append(("start", d))
+        jax.profiler, "start_trace", lambda d, **_options: calls.append(("start", d))
     )
     monkeypatch.setattr(
         jax.profiler, "stop_trace", lambda: calls.append(("stop",))

@@ -563,7 +563,7 @@ def test_step_profiler_emits_window_events_and_span(tmp_path, monkeypatch):
     import jax
 
     monkeypatch.setattr(
-        jax.profiler, "start_trace", lambda d: calls.append(("start", d))
+        jax.profiler, "start_trace", lambda d, **_options: calls.append(("start", d))
     )
     monkeypatch.setattr(
         jax.profiler, "stop_trace", lambda: calls.append(("stop", None))
