@@ -27,6 +27,9 @@ from elasticdl_tpu.layers.attention import (
     TransformerBlock,
     sinusoidal_positions,
 )
+from elasticdl_tpu.trainer.losses import (
+    softmax_cross_entropy_with_integer_labels,
+)
 from elasticdl_tpu.trainer.metrics import Accuracy
 from elasticdl_tpu.trainer.state import Modes
 
@@ -116,10 +119,7 @@ def sharding_rules(mesh):
 
 
 def loss(labels, logits):
-    labels = jnp.asarray(labels).astype(jnp.int32)
-    return optax.softmax_cross_entropy_with_integer_labels(
-        logits.astype(jnp.float32), labels
-    ).mean()
+    return softmax_cross_entropy_with_integer_labels(logits, labels).mean()
 
 
 def optimizer(lr=3e-3):

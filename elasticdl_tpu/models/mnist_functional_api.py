@@ -16,6 +16,9 @@ import numpy as np
 import optax
 
 from elasticdl_tpu.data.reader import decode_example
+from elasticdl_tpu.trainer.losses import (
+    softmax_cross_entropy_with_integer_labels,
+)
 from elasticdl_tpu.trainer.metrics import Accuracy
 from elasticdl_tpu.trainer.state import Modes
 from elasticdl_tpu.models._image_wire import (  # noqa: F401
@@ -59,7 +62,7 @@ def custom_model(**kwargs):
 
 def loss(labels, predictions):
     labels = labels.reshape(-1)
-    return optax.softmax_cross_entropy_with_integer_labels(
+    return softmax_cross_entropy_with_integer_labels(
         predictions, labels
     ).mean()
 

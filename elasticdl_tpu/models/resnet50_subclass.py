@@ -17,6 +17,7 @@ import optax
 
 from elasticdl_tpu.data.reader import decode_example
 from elasticdl_tpu.models.resnet50_model import L2_WEIGHT_DECAY, ResNet50
+from elasticdl_tpu.trainer.losses import pick_label
 from elasticdl_tpu.trainer.metrics import Accuracy
 from elasticdl_tpu.trainer.state import Modes
 from elasticdl_tpu.models._image_wire import (  # noqa: F401
@@ -36,8 +37,8 @@ def custom_model(num_classes=10, **kwargs):
 def loss(labels, predictions):
     labels = labels.reshape(-1)
     # predictions are probabilities (softmax output, like the reference)
-    logp = jnp.log(jnp.clip(predictions, 1e-8, 1.0))
-    return -jnp.take_along_axis(logp, labels[:, None], axis=-1).mean()
+    picked = pick_label(predictions, labels)
+    return -jnp.log(jnp.clip(picked, 1e-8, 1.0)).mean()
 
 
 def _decay_mask(params):

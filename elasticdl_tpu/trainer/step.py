@@ -81,6 +81,13 @@ def weighted_mean_loss(loss_fn, labels, outputs, weights):
     batch statistics (BatchNorm) — still observe the padded fill rows of
     a tail batch, as they did under the legacy divisor padding (the
     canonical shape pads further; see the design doc's limits section).
+
+    Cost rule: a ``loss_fn`` that picks the label's entry with a gather
+    (``take_along_axis``, optax's integer-label cross-entropy) transposes,
+    under this ``vmap``, into a scatter over the flattened logits plus two
+    layout-copy loops — in the GPT-2-small step a 1.65 GB flat f32 buffer
+    and 44 ms of a 109 ms step (PERF.md, PR 25).  Use the gather-free functions of
+    :mod:`elasticdl_tpu.trainer.losses`, as every zoo loss does.
     """
 
     def one_row(labels_row, outputs_row):

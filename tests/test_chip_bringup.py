@@ -350,6 +350,25 @@ def test_native_codec_unbuildable_fails_loudly(tmp_path, monkeypatch):
 # ---- the compiled four-chip step (AOT, no chip) ---------------------------------------
 
 
+def _compiled_for_v5e(script: str) -> dict:
+    """Run an AOT script of this directory in a process of its own (it
+    describes the topology at its top level) and return the JSON line it
+    prints; skip where libtpu gives no topology description."""
+    proc = subprocess.run(
+        [sys.executable, os.path.join(REPO, "tests", script)],
+        env=dict(
+            os.environ, PYTHONPATH=REPO, JAX_PLATFORMS="cpu", TPU_LOG_DIR="disabled"
+        ),
+        capture_output=True,
+        text=True,
+        timeout=900,
+    )
+    if proc.returncode == 77:
+        pytest.skip(f"no TPU topology description here: {proc.stderr[-200:]}")
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    return json.loads(proc.stdout.strip().splitlines()[-1])
+
+
 def test_four_chip_step_maps_the_kernel_over_the_per_chip_batch():
     """Compiled (not interpreted), a pallas kernel is an opaque custom
     call GSPMD cannot partition — JAX refuses to lower it bare inside a
@@ -357,20 +376,25 @@ def test_four_chip_step_maps_the_kernel_over_the_per_chip_batch():
     mesh's batch axes, so the COMPILED dp=4 train step hands each chip's
     kernel its own quarter of the batch.  Compiled here for a v5e 2x2
     from libtpu's topology description: no chip, no speed."""
-    script = os.path.join(REPO, "tests", "aot_four_chip_step.py")
-    proc = subprocess.run(
-        [sys.executable, script],
-        env=dict(os.environ, PYTHONPATH=REPO, JAX_PLATFORMS="cpu"),
-        capture_output=True,
-        text=True,
-        timeout=600,
-    )
-    if proc.returncode == 77:
-        pytest.skip(f"no TPU topology description here: {proc.stderr[-200:]}")
-    assert proc.returncode == 0, proc.stderr[-3000:]
-    seen = json.loads(proc.stdout.strip().splitlines()[-1])
+    seen = _compiled_for_v5e("aot_four_chip_step.py")
     # global batch 8 over dp=4, 2 heads: each call sees 2*2 folded rows
     assert seen["device_kind"] == "TPU v5 lite"
     assert seen["kernel_calls"] >= 3  # forward, dQ, dK/dV
     assert seen["kernel_batch_x_heads"] == [4]
     assert seen["tokens_param"] == "s32[2,256]"
+
+
+def test_gpt2_small_step_compiles_to_no_loop():
+    """The benchmark's GPT-2-small step (8 x 1,024 tokens, 50,257-wide
+    head), compiled for a v5e from libtpu's topology description, holds no
+    ``while``: with a loss that gathered by label, the per-row ``vmap`` of
+    ``weighted_mean_loss`` compiled to a scatter into a flat
+    f32[411,705,344] buffer and two layout-copy loops around it (44 ms of
+    a 109 ms step on the chip, PERF.md PR 25).  No chip, no speed."""
+    seen = _compiled_for_v5e("aot_gpt2_small_step.py")
+    assert seen["device_kind"] == "TPU v5 lite"
+    assert seen["kernel_calls"] == 36  # 12 layers x (forward, dQ, dK/dV)
+    assert seen["while_loops"] == 0
+    assert seen["dynamic_update_slices"] == 0
+    # the temporaries the flat buffer and its copies held: 7.95 GB with them
+    assert seen["temp_bytes"] < 5e9
