@@ -15,7 +15,10 @@ import math
 import time
 
 import jax
+import jax.numpy as jnp
 
+# the scope ``SPMDTrainer`` enters around every step call, whatever the model
+from elasticdl_tpu.ops.attention import attention_mesh_scope
 from elasticdl_tpu.telemetry import compile_tracker
 from elasticdl_tpu.trainer import local_executor
 
@@ -288,3 +291,42 @@ class MeasuredExecutor(local_executor.LocalExecutor):
         records = super()._train_task(task, self._probe.watch(batches))
         self._probe.after_task(records)
         return records
+
+    def model_loss_and_grads(self, features, labels):
+        """``(params, loss, grads)`` of the model this executor trains, at
+        the trainer's parameters as they stand, on one unweighted batch: the
+        model's ``apply`` in its configured dtype through its normal kernels
+        under the trainer's mesh, in training mode (BatchNorm on the batch's
+        own statistics), the model module's ``loss`` plus any sown losses, as
+        ``trainer/step.py::forward_loss`` composes them, under
+        ``jax.value_and_grad``.  What ``perf/reference.py`` compares with the
+        plain reference; no step is taken and the state is left alone."""
+        spec, trainer = self._spec, self._trainer
+        state = trainer.state
+
+        def loss_of(params, model_state, features, labels):
+            if spec.device_parse is not None:
+                features = spec.device_parse(features)
+            variables = {"params": params, **model_state}
+            rngs = {"dropout": jax.random.PRNGKey(0)}
+            sown = {}
+            if model_state:
+                outputs, sown = state.apply_fn(
+                    variables, features, training=True,
+                    mutable=list(model_state), rngs=rngs,
+                )
+            else:
+                outputs = state.apply_fn(
+                    variables, features, training=True, rngs=rngs
+                )
+            loss = spec.loss(labels, outputs)
+            for leaf in jax.tree_util.tree_leaves(sown.get("losses", {})):
+                loss = loss + jnp.sum(leaf)
+            return loss.astype(jnp.float32)
+
+        with trainer.mesh, attention_mesh_scope(trainer.mesh):
+            loss, grads = jax.jit(jax.value_and_grad(loss_of))(
+                state.params, state.model_state,
+                trainer.place_batch(features), trainer.place_batch(labels),
+            )
+        return state.params, loss, grads

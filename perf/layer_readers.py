@@ -13,11 +13,11 @@ from __future__ import annotations
 
 from perf import trace_reduce
 
-# the three Pallas flash kernels of ops/attention.py (forward, dQ, dK/dV)
-# are this model's only Mosaic kernels: on the op line each is a
-# custom-call named after the attention module's scope ("attn.41") whose
-# HLO text carries custom_call_target="tpu_custom_call"
-FLASH_KERNELS = r"tpu_custom_call"
+# the three Pallas flash kernels of ops/attention.py (forward, dQ, dK/dV),
+# by the name each ``pallas_call`` gives its compiled custom-call on the op
+# line (``flash_fwd.3``; ``kernel_rooflines.py`` tells them apart the same
+# way): another model's other Mosaic kernel is not attention
+FLASH_KERNELS = r"^flash_(fwd|dq|dkv)\b"
 
 
 def input_wait_share(run) -> float | None:

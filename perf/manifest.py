@@ -62,8 +62,8 @@ class Cell:
     def module(self, directory: str, name: str):
         """The module ``<directory>/<name>.py`` under one of ``paths``: what
         belongs to one per-layer metric, one family of FLOP arithmetic, one
-        kind of record or one way of driving the load is a file of its own,
-        found by the name a data file gives."""
+        kind of record, one way of driving the load or one plain reference
+        is a file of its own, found by the name a data file gives."""
         path = self.find(os.path.join(directory, name + ".py"))
         spec = importlib.util.spec_from_file_location(
             f"perf_{directory}_" + name.replace(".", "_"), path
@@ -84,6 +84,16 @@ class Cell:
         """``drivers/<mode>.py``: how the traffic file's load reaches the
         trainer (``path``, the default, or a named other way)."""
         return self.module("drivers", self.traffic.get("mode", "path"))
+
+    def reference(self):
+        """``references/<module>.py`` named by the configuration's
+        ``reference`` group, whose ``loss_and_grads(params, features,
+        labels)`` is the configuration in plain float32
+        (``perf/reference.py``); None for a configuration with no group."""
+        group = self.config.get("reference")
+        if group is None:
+            return None
+        return self.module("references", group["module"])
 
     def flops_per_record(self) -> dict:
         """``flop_functions/<function>.py`` applied to the configuration's

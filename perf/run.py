@@ -255,6 +255,7 @@ def main(argv=None) -> int:
         "setup_s": setup_s,
         "setup_parts": setup_parts,
         "checks": checks,
+        "reference": "traced run only",
     }
     metrics, breakdown = {}, None
     if args.trace:
@@ -262,6 +263,14 @@ def main(argv=None) -> int:
             args, cell, probe, trace_dir, peaks,
             (untraced, summarize(probe.traced_readings)), info, checks, device,
         )
+        # after the window and after every per-layer metric has been read:
+        # the comparison's compile and memory are in no metric
+        from perf import reference
+
+        compared = reference.compare(cell, executor, args.seed)
+        info["reference"] = compared or "none"
+        if compared:
+            checks["reference_agrees"] = compared["agrees"]
     elif not args.rehearse_cpu:
         values = {
             # all the work over all the time of the window

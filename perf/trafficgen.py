@@ -49,12 +49,13 @@ def units_per_record(kind, traffic: dict, unit: str) -> int:
     return int(units[unit])
 
 
-def one_batch(kind, traffic: dict, rows: int, seed: int):
+def one_batch(kind, traffic: dict, rows: int, seed: int, stream: int = 0):
     """One seeded ``(features, labels)`` batch of ``rows`` records, as the
-    zoo's parse functions hand it to the trainer."""
+    zoo's parse functions hand it to the trainer.  ``stream`` 0 is shard 0's
+    (``generate``); another stream gives records no shard holds."""
     spec = traffic["records"]
     return kind.batch(
-        kind.columns(np.random.default_rng([seed, 0]), spec, rows)
+        kind.columns(np.random.default_rng([seed, stream]), spec, rows)
     )
 
 

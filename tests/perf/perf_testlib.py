@@ -1,6 +1,11 @@
 """Shared by the tests of the benchmark: the repository's manifest plus the
-tiny rehearsal cell, added as a later PR would add a cell — new files under
-one of ``paths`` and new entries, no edit to a file that is there."""
+tiny rehearsal cells, added as a later PR would add a cell — new files under
+one of ``paths`` and new entries, no edit to a file that is there.  They
+bring what the next configuration will: token cells that are not 8,192
+tokens a step, a plain reference found by name under ``tests/perf``
+(``references/plain_lm.py``, named by ``configs/tiny_lm.json``), a family
+with no reference, and a per-layer entry under a layer no entry before it
+names."""
 
 from __future__ import annotations
 
@@ -18,6 +23,8 @@ TINY_RESIDENT_CELL = "tiny_lm_resident"
 # another model family: its record kind and FLOP arithmetic are files under
 # tests/perf too, found by the names its traffic and configuration files give
 TINY_FAMILY_CELL = "tiny_mnist_digits"
+# a layer no entry of BENCHMARK.json names
+NEW_LAYER = "rehearsal layer (tests/perf only)"
 
 
 def repo_manifest() -> dict:
@@ -88,6 +95,17 @@ def manifest_with_tiny_cell() -> dict:
             "layer": "harness",
             "moves": "tokens_per_s_chip",
             "workloads": [TINY_CELL, TINY_FAMILY_CELL],
+        }
+    )
+    manifest["per_layer"].append(
+        {
+            "name": "units_per_reading.tiny",
+            "unit": "units",
+            "better": "higher",
+            "source": "program_counter",
+            "layer": NEW_LAYER,
+            "moves": "tokens_per_s_chip",
+            "workloads": [TINY_CELL, TINY_RESIDENT_CELL],
         }
     )
     return manifest

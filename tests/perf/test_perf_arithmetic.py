@@ -119,9 +119,11 @@ def test_readings_are_per_chip_and_need_one_interval():
         meter.summarize([])
 
 
-@pytest.mark.parametrize("cell", [w["name"] for w in repo_manifest()["workloads"]])
+@pytest.mark.parametrize(
+    "cell", [w["name"] for w in manifest_with_tiny_cell()["workloads"]]
+)
 def test_traffic_plan_counts(cell):
-    resolved = manifest_lib.Cell(repo_manifest(), cell)
+    resolved = manifest_lib.Cell(manifest_with_tiny_cell(), cell)
     plan = trafficgen.plan(resolved.traffic, resolved.chips)
     assert plan["minibatch_size"] == resolved.traffic["batch_per_chip"] * resolved.chips
     assert plan["records_per_shard"] % plan["records_per_task"] == 0
@@ -130,8 +132,10 @@ def test_traffic_plan_counts(cell):
     per_record = trafficgen.units_per_record(
         resolved.record_kind(), resolved.traffic, unit
     )
-    if unit == "tokens":
-        # both LM mixes hold the same tokens per chip per step
+    assert per_record >= 1
+    if resolved.config["name"] == "gpt2_small":
+        # the GPT-2 mixes hold the same tokens per chip per step (PERF.md,
+        # section 4): a fact about these cells, not a rule for the next
         assert per_record * resolved.traffic["batch_per_chip"] == 8192
 
 

@@ -5,7 +5,14 @@ import os
 import re
 
 import pytest
-from perf_testlib import ROOT, TINY_CELL, manifest_with_tiny_cell, repo_manifest
+from perf_testlib import (
+    NEW_LAYER,
+    ROOT,
+    TINY_CELL,
+    TINY_FAMILY_CELL,
+    manifest_with_tiny_cell,
+    repo_manifest,
+)
 
 from perf import manifest as manifest_lib
 
@@ -117,10 +124,51 @@ def test_a_cell_is_added_by_files_and_entries_alone():
     assert cell.traffic["name"] == "tiny"
     assert cell.config["run"]["model_params"]["embed_dim"] == 64
     names = [m["name"] for m in cell.metrics("per_layer")]
-    assert names == ["readings_per_window.tiny"]
+    assert names == ["readings_per_window.tiny", "units_per_reading.tiny"]
     read = cell.reader("readings_per_window.tiny")
     assert read({"untraced": {"readings": 31}}) == 31.0
     assert read({}) is None
+    # a per-layer entry after all of the manifest's, under a layer none of
+    # them names, and its reader
+    entry = extended["per_layer"][-1]
+    assert entry["layer"] == NEW_LAYER
+    assert NEW_LAYER not in {m["layer"] for m in repo_manifest()["per_layer"]}
+    read = cell.reader(entry["name"])
+    assert read({"untraced": {"units": 1280, "readings": 10}}) == 128.0
+    assert read({}) is None
+    # its plain reference is a file under tests/perf, found by the name the
+    # configuration file gives; a configuration may name none
+    reference = cell.reference()
+    assert reference.__file__ == os.path.join(
+        ROOT, "tests", "perf", "references", "plain_lm.py"
+    )
+    assert callable(reference.loss_and_grads)
+    assert set(cell.config["reference"]) >= {"module", "sample", "tolerance", "why"}
+    assert manifest_lib.Cell(extended, TINY_FAMILY_CELL).reference() is None
+
+
+@pytest.mark.parametrize(
+    "config", MANIFEST["configs"], ids=lambda c: c["name"]
+)
+def test_shipped_configuration_names_a_reference_with_two_limits_or_none(config):
+    cell = manifest_lib.Cell(
+        MANIFEST,
+        next(w["name"] for w in MANIFEST["workloads"] if w["config"] == config["name"]),
+    )
+    group = cell.config.get("reference")
+    if group is None:
+        # nothing is claimed for it, and the file says why
+        assert cell.reference() is None and len(cell.config["not_compared"]) > 80
+        return
+    assert set(group) == {"module", "sample", "tolerance", "does_not_cover", "why"}
+    assert callable(cell.reference().loss_and_grads)
+    assert cell.reference().__file__.startswith(os.path.join(ROOT, "perf", "references"))
+    # each limit is a number found on the chip: none is optional
+    assert set(group["tolerance"]) == {"loss", "grad"}
+    assert 0 < group["tolerance"]["loss"] < 0.05
+    assert 0 < group["tolerance"]["grad"] < 0.5
+    assert group["does_not_cover"] and all(len(x) > 20 for x in group["does_not_cover"])
+    assert int(group["sample"]["units"]) > 0 and len(group["why"]) > 80
 
 
 def test_an_unknown_name_is_an_error():

@@ -187,20 +187,73 @@ def test_kernel_roofline_takes_a_third_of_attention_over_its_own_time(kernel):
     assert read(dict(run, trace=None)) is None
 
 
+# the fourteen per-layer entries PR 24 added, by name: entries after them,
+# and layers they never heard of, are a later PR's own business
+PR24_ENTRIES = (
+    "bookkeeping_ms.lm", "assemble_ms.lm", "place_ms.lm", "enqueue_ms.lm",
+    "enqueue_ms.vision", "fetch_wait_ms.lm", "producer_batch_ms.lm",
+    "producer_busy_share.lm", "flash_fwd_roofline.lm", "flash_dq_roofline.lm",
+    "flash_dkv_roofline.lm", "setup_trace_s", "setup_lower_s", "setup_compile_s",
+)
+PR24_LAYERS = (
+    "data plane (data/, trainer/host_pipeline.py)",
+    "dispatch (trainer/stacking.py, device_pipeline.py, local_executor.py)",
+    "SPMD step (parallel/distributed.py, trainer/step.py)",
+    "kernels (ops/attention.py)",
+)
+
+
 def test_new_entries_name_layers_as_the_manifest_spells_them():
     manifest = repo_manifest()
-    old = {m["name"]: m for m in manifest["per_layer"][:10]}
-    layers = {m["layer"] for m in old.values()}
-    new = manifest["per_layer"][10:]
-    assert len(new) == 14
-    assert all(m["layer"] in layers for m in new)
+    by_name = {m["name"]: m for m in manifest["per_layer"]}
+    new = [by_name[name] for name in PR24_ENTRIES]
+    assert all(m["layer"] in PR24_LAYERS for m in new)
+    # each of those layers is one the entries before them already named
+    older = {
+        m["layer"] for m in manifest["per_layer"] if m["name"] not in PR24_ENTRIES
+    }
+    assert set(PR24_LAYERS) <= older
     assert {m["source"] for m in new} == {
         "program_span", "device_trace", "program_counter"
     }
-    cells = [w["name"] for w in manifest["workloads"]]
+    cells = {w["name"] for w in manifest["workloads"]}
     for m in new:
         if m["name"].startswith("setup_"):
-            assert m["workloads"] == cells and m["moves"] == "setup_s"
+            # every cell reports set-up, those a later PR adds too
+            assert set(m.get("workloads", cells)) == cells
+            assert m["moves"] == "setup_s"
+
+
+def test_flash_share_counts_the_three_kernels_by_name_and_no_other_mosaic_call():
+    """A later model's grouped matmul is a Mosaic custom-call too
+    (``tpu_custom_call`` in its detail): not attention."""
+    from perf import layer_readers
+
+    reduced = {
+        "op_self_s": {
+            "flash_fwd.1": 0.010, "flash_dq.7": 0.030, "flash_dkv.9": 0.040,
+            "gmm.5": 0.5, "flash_fwd_wrapper_fusion": 0.3, "fusion.3": 1.0,
+        },
+        "details": {
+            "gmm.5": "(bf16[8192,1024]) custom-call(...) tpu_custom_call",
+            "flash_fwd.1": "(bf16[96,1024,64]) custom-call(...) tpu_custom_call",
+            "fusion.3": "fusion(... %flash_dq.7 ...)",
+        },
+        "busy_s": 2.0,
+    }
+    run = {
+        "trace": reduced, "traced_steps": 10,
+        "flops_per_step_chip": {"causal_attention": 3e11, "train": 1e13},
+        "peaks": {"bf16_flops_per_s": 1e14},
+    }
+    assert layer_readers.flash_seconds(run) == pytest.approx(0.080)
+    assert layer_readers.flash_time_share(run) == pytest.approx(4.0)
+    assert layer_readers.flash_roofline(run) == pytest.approx(
+        100 * 3e11 * 10 / 0.080 / 1e14
+    )
+    # a model with other kernels only has no flash share to report
+    run["trace"] = dict(reduced, op_self_s={"gmm.5": 0.5, "fusion.3": 1.0})
+    assert layer_readers.flash_time_share(run) is None
 
 
 def test_profile_window_spans_go_on_the_traces_clock(tmp_path):
@@ -255,5 +308,8 @@ def test_rehearsal_still_passes_with_the_new_entries(tmp_path):
         cwd=ROOT, env=env, capture_output=True, text=True, timeout=300,
     )
     assert done.returncode == 0, done.stderr[-2000:]
-    result = json.loads(done.stdout.strip().splitlines()[-1])
+    info, result = map(json.loads, done.stdout.strip().splitlines()[-2:])
     assert result["correct"] is True and result["metrics"] == {}
+    # a configuration that names no reference says so and does not fail
+    assert info["reference"] == "none"
+    assert "reference_agrees" not in info["checks"]

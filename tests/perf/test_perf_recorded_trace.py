@@ -15,6 +15,11 @@ from perf_testlib import ROOT
 from perf import layer_readers, trace_reduce as tr
 
 TESTDATA = os.path.join(ROOT, "perf", "testdata")
+# the recorded slices predate the kernels' names (PR 24): their Mosaic
+# custom-calls are ``attn.N`` on the op line and the only mark of a kernel is
+# the call target in the op's detail.  The tests on them read through this
+# pattern; ``layer_readers.FLASH_KERNELS`` finds nothing there
+UNNAMED_FLASH_KERNELS = r"tpu_custom_call"
 
 
 @pytest.fixture(scope="module")
@@ -48,13 +53,16 @@ def test_resnet_idle_gaps_are_the_host_waiting_for_input(resnet):
 
 def test_resnet_has_no_kernel_and_no_collective(resnet):
     assert tr.matching_seconds(resnet, layer_readers.FLASH_KERNELS) == 0
+    assert tr.matching_seconds(resnet, UNNAMED_FLASH_KERNELS) == 0
     assert resnet["collective_exposed_s"] == 0
     top = tr.breakdown(resnet)["device_ops"]
     assert len(top) == 10 and top[0][0] == "convert_reduce_fusion"
 
 
-def test_lm_step_and_its_flash_kernels(lm):
+def test_lm_step_and_its_flash_kernels(lm, monkeypatch):
     assert lm["busy_s"] == pytest.approx(0.215772458, rel=1e-6)
+    assert tr.matching_seconds(lm, layer_readers.FLASH_KERNELS) == 0
+    monkeypatch.setattr(layer_readers, "FLASH_KERNELS", UNNAMED_FLASH_KERNELS)
     flash = tr.matching_seconds(lm, layer_readers.FLASH_KERNELS)
     # 36 Mosaic calls a step: 12 layers x (forward, dQ, dK/dV)
     kernels = [

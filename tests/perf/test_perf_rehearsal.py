@@ -75,8 +75,19 @@ def test_rehearsal_on_cpu(tmp_path, trace, seed, cell):
     assert info["total_over_window"] == info["units"] / info["window_s"]
     if trace:
         assert info["untraced_rate"] > 0 and info["traced_rate"] > 0
+        # the comparison with the plain reference the configuration names
+        # (tests/perf/references/plain_lm.py) joins `correct`
+        compared = info["reference"]
+        assert info["checks"]["reference_agrees"] is compared["agrees"] is True
+        assert compared["loss_err"] <= compared["tolerance"]["loss"]
+        assert compared["grad_err"] <= compared["tolerance"]["grad"]
+        assert set(compared["by_block"]) >= {"tok_embed", "block_0", "lm_head"}
+        assert compared["sample"] == {"records": 4, "seed": seed}
+        assert compared["seconds"] > 0
     else:
         assert info["window_s"] >= 2.0
+        assert info["reference"] == "traced run only"
+        assert "reference_agrees" not in info["checks"]
 
 
 def test_without_the_program_the_command_fails_and_prints_no_result(tmp_path):

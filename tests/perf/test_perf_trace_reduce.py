@@ -16,7 +16,7 @@ def events_one_device():
     ops = [
         ["fusion.1", 0 * US, 300 * US],
         ["call.2", 300 * US, 200 * US],
-        ["custom-call.3", 320 * US, 100 * US],  # inside call.2
+        ["flash_fwd.3", 320 * US, 100 * US],  # inside call.2
         ["all-reduce.4", 450 * US, 150 * US],  # 450..600, call.2 ends at 500
         ["fusion.1", 700 * US, 200 * US],
     ]
@@ -27,7 +27,7 @@ def events_one_device():
         ["perf:dispatch", 650 * US, 30 * US],
         ["perf:readback", 880 * US, 120 * US],
     ]
-    details = {"custom-call.3": "(bf16[96,1024,64]) custom-call(...) tpu_custom_call"}
+    details = {"flash_fwd.3": "(bf16[96,1024,64]) custom-call(...) tpu_custom_call"}
     return {"devices": {"/device:TPU:0": ops}, "host": host, "details": details}
 
 
@@ -50,7 +50,7 @@ def test_self_time_takes_children_out_of_their_parent():
     reduced = tr.reduce(events_one_device())
     ops = reduced["op_self_s"]
     assert ops["fusion.1"] == pytest.approx(500e-6)
-    assert ops["custom-call.3"] == pytest.approx(100e-6)
+    assert ops["flash_fwd.3"] == pytest.approx(100e-6)
     assert ops["call.2"] == pytest.approx(50e-6)  # 200 - kernel 100 - overlap 50
     assert tr.matching_seconds(reduced, "tpu_custom_call") == pytest.approx(100e-6)
     assert tr.breakdown(reduced)["device_ops"][0] == ["fusion.1", pytest.approx(500e-6)]
