@@ -33,6 +33,14 @@ class MultiHeadSelfAttention(nn.Module):
     # compute dtype (e.g. bf16): projections and the attention kernel run
     # in it; parameters stay in param_dtype (f32) — mixed precision
     dtype: Any = None
+    use_bias: bool = True
+    # > 0: rotary positions on q and k with this base (``rope``); 0: the
+    # model adds its positions at the embedding
+    rope_theta: float = 0.0
+    # an RMSNorm with a learned scale over the whole q and k projections,
+    # before the split into heads (OLMoE: ``q_norm(q_proj(x))``)
+    qk_norm: bool = False
+    norm_eps: float = 1e-6
 
     @nn.compact
     def __call__(self, x, decode_pos=None):
@@ -49,14 +57,29 @@ class MultiHeadSelfAttention(nn.Module):
         head_dim = embed // self.num_heads
         kv_heads = self.num_kv_heads or self.num_heads
 
-        def _proj(name, heads):
-            return nn.DenseGeneral(
-                features=(heads, head_dim), dtype=self.dtype, name=name
+        def _proj(name, heads, norm=None):
+            y = nn.DenseGeneral(
+                features=(heads, head_dim), dtype=self.dtype, name=name,
+                use_bias=self.use_bias,
             )(x)
+            if norm is not None:
+                full = y.reshape(*y.shape[:-2], heads * head_dim)
+                y = nn.RMSNorm(
+                    epsilon=self.norm_eps, dtype=self.dtype, name=norm
+                )(full).reshape(y.shape)
+            return y
 
-        q = _proj("query", self.num_heads)
-        k = _proj("key", kv_heads)
+        q = _proj("query", self.num_heads, "q_norm" if self.qk_norm else None)
+        k = _proj("key", kv_heads, "k_norm" if self.qk_norm else None)
         v = _proj("value", kv_heads)
+        if self.rope_theta:
+            positions = (
+                jnp.arange(x.shape[1])
+                if decode_pos is None
+                else decode_pos + jnp.arange(x.shape[1])
+            )
+            q = rope(q, positions, self.rope_theta)
+            k = rope(k, positions, self.rope_theta)
         if self.decode:
             if decode_pos is None:
                 raise ValueError("decode mode needs decode_pos")
@@ -64,7 +87,8 @@ class MultiHeadSelfAttention(nn.Module):
         else:
             out = attention_ops.attention(q, k, v, causal=self.causal)
         return nn.DenseGeneral(
-            features=embed, axis=(-2, -1), dtype=self.dtype, name="out"
+            features=embed, axis=(-2, -1), dtype=self.dtype, name="out",
+            use_bias=self.use_bias,
         )(out.astype(x.dtype))
 
     def _decode_attend(self, q, k, v, pos):
@@ -119,22 +143,53 @@ class MultiHeadSelfAttention(nn.Module):
         return out.astype(q.dtype)
 
 
+NORMS = ("layernorm", "rmsnorm")
+MLPS = ("gelu", "swiglu")
+
+
+def make_norm(kind: str, epsilon: float, dtype):
+    """The block's norm, auto-named by flax (``LayerNorm_N`` / ``RMSNorm_N``)."""
+    if kind not in NORMS:
+        raise ValueError(f"unknown norm {kind!r}; valid: {NORMS}")
+    cls = nn.LayerNorm if kind == "layernorm" else nn.RMSNorm
+    return cls(epsilon=epsilon, dtype=dtype)
+
+
 class TransformerBlock(nn.Module):
+    """The one pre-norm block.  Every field defaults to what GPT-2-small
+    runs (LayerNorm, biases, a 4x GELU MLP, positions added by the model);
+    a published architecture is a choice of fields, not another block."""
+
     num_heads: int
     mlp_ratio: int = 4
     causal: bool = False
     dropout_rate: float = 0.0
-    # > 0 replaces the dense MLP with a routed expert MLP (layers.moe);
-    # shard experts over ep via moe_sharding_rules
-    num_experts: int = 0
     num_kv_heads: int = 0  # > 0: grouped-query attention
     decode: bool = False  # autoregressive decoding with a KV cache
     max_decode_len: int = 0
     dtype: Any = None  # compute dtype; params stay f32
+    norm: str = "layernorm"  # | "rmsnorm"
+    norm_eps: float = 1e-6
+    use_bias: bool = True
+    rope_theta: float = 0.0  # > 0: rotary positions inside attention
+    qk_norm: bool = False
+    mlp: str = "gelu"  # | "swiglu": down(silu(gate(x)) * up(x))
+    mlp_width: int = 0  # 0: mlp_ratio x the embedding
+    # > 0 replaces the dense MLP with routed SwiGLU experts (layers.moe);
+    # shard experts over ep via moe_sharding_rules
+    num_experts: int = 0
+    experts_per_token: int = 2
+    expert_width: int = 0  # 0: the dense MLP's width
+    norm_topk_prob: bool = False
+    router_aux_weight: float = 0.01
+    router_z_weight: float = 0.001
 
     @nn.compact
     def __call__(self, x, training: bool = False, decode_pos=None):
-        y = nn.LayerNorm(dtype=self.dtype)(x)
+        if self.mlp not in MLPS:
+            raise ValueError(f"unknown mlp {self.mlp!r}; valid: {MLPS}")
+        width = self.mlp_width or x.shape[-1] * self.mlp_ratio
+        y = make_norm(self.norm, self.norm_eps, self.dtype)(x)
         y = MultiHeadSelfAttention(
             num_heads=self.num_heads,
             causal=self.causal,
@@ -142,30 +197,60 @@ class TransformerBlock(nn.Module):
             decode=self.decode,
             max_decode_len=self.max_decode_len,
             dtype=self.dtype,
+            use_bias=self.use_bias,
+            rope_theta=self.rope_theta,
+            qk_norm=self.qk_norm,
+            norm_eps=self.norm_eps,
             name="attn",
         )(y, decode_pos=decode_pos)
         if self.dropout_rate:
             y = nn.Dropout(self.dropout_rate, deterministic=not training)(y)
         x = x + y
-        y = nn.LayerNorm(dtype=self.dtype)(x)
+        y = make_norm(self.norm, self.norm_eps, self.dtype)(x)
         if self.num_experts > 0:
             from elasticdl_tpu.layers.moe import MoEMLP
 
             y = MoEMLP(
                 num_experts=self.num_experts,
-                hidden_mult=self.mlp_ratio,
+                experts_per_token=self.experts_per_token,
+                expert_width=self.expert_width or width,
+                norm_topk_prob=self.norm_topk_prob,
+                aux_loss_weight=self.router_aux_weight,
+                z_loss_weight=self.router_z_weight,
+                dtype=self.dtype,
                 name="moe",
             )(y, training=training)
         else:
             # named for the shared megatron tp rules (default_tp_rules)
-            y = nn.Dense(x.shape[-1] * self.mlp_ratio, dtype=self.dtype,
-                         name="mlp_up")(y)
-            y = nn.gelu(y)
-            y = nn.Dense(x.shape[-1], dtype=self.dtype,
-                         name="mlp_down")(y)
+            def dense(features, name):
+                return nn.Dense(
+                    features, dtype=self.dtype, use_bias=self.use_bias,
+                    name=name,
+                )
+
+            if self.mlp == "swiglu":
+                y = nn.silu(dense(width, "mlp_gate")(y)) * dense(width, "mlp_up")(y)
+            else:
+                y = nn.gelu(dense(width, "mlp_up")(y))
+            y = dense(x.shape[-1], "mlp_down")(y)
         if self.dropout_rate:
             y = nn.Dropout(self.dropout_rate, deterministic=not training)(y)
         return x + y
+
+
+def rope(x, positions, theta: float):
+    """Rotary positions (Su et al. 2021) in the rotate-half convention over
+    the whole head, as HF ``apply_rotary_pos_emb``: ``x`` (batch, seq,
+    heads, d), ``positions`` (seq,).  Computed in float32."""
+    half = x.shape[-1] // 2
+    rate = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    angles = positions.astype(jnp.float32)[:, None] * rate[None, :]
+    cos = jnp.cos(angles)[None, :, None, :]
+    sin = jnp.sin(angles)[None, :, None, :]
+    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    return jnp.concatenate(
+        [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
+    ).astype(x.dtype)
 
 
 def sinusoidal_positions(seq_len: int, dim: int) -> jnp.ndarray:

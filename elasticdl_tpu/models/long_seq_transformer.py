@@ -25,6 +25,7 @@ import optax
 from elasticdl_tpu.data.reader import decode_example
 from elasticdl_tpu.layers.attention import (
     TransformerBlock,
+    make_norm,
     sinusoidal_positions,
 )
 from elasticdl_tpu.trainer.losses import (
@@ -42,13 +43,31 @@ class TransformerLM(nn.Module):
     num_heads: int = 4
     num_layers: int = 2
     dropout_rate: float = 0.0
-    num_experts: int = 0  # > 0: MoE MLP, experts sharded over ep
     num_kv_heads: int = 0  # > 0: grouped-query attention
     decode: bool = False  # one-token-per-call decoding with KV caches
     max_decode_len: int = 0
     # compute dtype (e.g. "bfloat16"): activations and matmuls run in it,
     # parameters stay f32; the loss casts logits back up
     dtype: Any = None
+    # the block's fields (layers/attention.py::TransformerBlock); the
+    # defaults are GPT-2-small's, a published architecture names its own
+    # (perf/configs/olmoe_1b7b.json: rmsnorm, no bias, rope, qk_norm, experts)
+    norm: str = "layernorm"  # | "rmsnorm"
+    norm_eps: float = 1e-6
+    use_bias: bool = True  # the head's too
+    positions: str = "sinusoidal"  # added at the embedding | "rope"
+    rope_theta: float = 10000.0
+    qk_norm: bool = False
+    mlp: str = "gelu"  # | "swiglu"
+    mlp_width: int = 0  # 0: four times the embedding
+    num_experts: int = 0  # > 0: routed SwiGLU experts, sharded over ep
+    experts_per_token: int = 2
+    expert_width: int = 0  # 0: the dense MLP's width
+    norm_topk_prob: bool = False
+    # weights of the load-balance loss and the router z-loss, over the
+    # mean of the layers' losses
+    router_aux_weight: float = 0.01
+    router_z_weight: float = 0.001
 
     @nn.compact
     def __call__(self, features, training: bool = False):
@@ -56,6 +75,9 @@ class TransformerLM(nn.Module):
             features["tokens"] if isinstance(features, dict) else features
         )
         tokens = jnp.asarray(tokens).astype(jnp.int32)
+        if self.positions not in ("sinusoidal", "rope"):
+            raise ValueError(f"unknown positions {self.positions!r}")
+        sinusoidal = self.positions == "sinusoidal"
         x = nn.Embed(
             self.vocab_size, self.embed_dim, dtype=self.dtype,
             name="tok_embed",
@@ -70,15 +92,16 @@ class TransformerLM(nn.Module):
                 "cache", "pos", lambda: jnp.zeros((), jnp.int32)
             )
             decode_pos = pos_var.value
-            enc = sinusoidal_positions(
-                self.max_decode_len, self.embed_dim
-            )
-            x = x + jax.lax.dynamic_slice_in_dim(
-                enc, decode_pos, 1
-            )[None, :, :].astype(x.dtype)
+            if sinusoidal:
+                enc = sinusoidal_positions(
+                    self.max_decode_len, self.embed_dim
+                )
+                x = x + jax.lax.dynamic_slice_in_dim(
+                    enc, decode_pos, 1
+                )[None, :, :].astype(x.dtype)
             if not self.is_initializing():  # init must not advance
                 pos_var.value = decode_pos + 1
-        else:
+        elif sinusoidal:
             x = x + sinusoidal_positions(tokens.shape[1], self.embed_dim)[
                 None, :, :
             ].astype(x.dtype)
@@ -87,15 +110,30 @@ class TransformerLM(nn.Module):
                 num_heads=self.num_heads,
                 causal=True,
                 dropout_rate=self.dropout_rate,
-                num_experts=self.num_experts,
                 num_kv_heads=self.num_kv_heads,
                 decode=self.decode,
                 max_decode_len=self.max_decode_len,
                 dtype=self.dtype,
+                norm=self.norm,
+                norm_eps=self.norm_eps,
+                use_bias=self.use_bias,
+                rope_theta=0.0 if sinusoidal else self.rope_theta,
+                qk_norm=self.qk_norm,
+                mlp=self.mlp,
+                mlp_width=self.mlp_width,
+                num_experts=self.num_experts,
+                experts_per_token=self.experts_per_token,
+                expert_width=self.expert_width,
+                norm_topk_prob=self.norm_topk_prob,
+                router_aux_weight=self.router_aux_weight / self.num_layers,
+                router_z_weight=self.router_z_weight / self.num_layers,
                 name=f"block_{layer}",
             )(x, training=training, decode_pos=decode_pos)
-        x = nn.LayerNorm(dtype=self.dtype)(x)
-        return nn.Dense(self.vocab_size, dtype=self.dtype, name="lm_head")(x)
+        x = make_norm(self.norm, self.norm_eps, self.dtype)(x)
+        return nn.Dense(
+            self.vocab_size, dtype=self.dtype, use_bias=self.use_bias,
+            name="lm_head",
+        )(x)
 
 
 def custom_model(**kwargs):

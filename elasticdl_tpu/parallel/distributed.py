@@ -26,6 +26,7 @@ from elasticdl_tpu.ops.attention import attention_mesh_scope
 from elasticdl_tpu.parallel import elastic
 from elasticdl_tpu.parallel import sharding as sharding_lib
 from elasticdl_tpu.parallel.mesh import batch_divisor
+from elasticdl_tpu.telemetry import router_load
 from elasticdl_tpu.telemetry.anatomy import PHASE_H2D_TRANSFER, TIMELINE
 from elasticdl_tpu.trainer.state import TrainState
 from elasticdl_tpu.trainer.step import (
@@ -114,6 +115,9 @@ class SPMDTrainer:
             self.state = jax.jit(
                 create_state, out_shardings=self.state_shardings
             )()
+        # an expert model's router counts stay in the state as device
+        # arrays; router_load.read() fetches the newest on demand
+        router_load.watch(self)
         self._batch_shardings_cache: dict = {}
         self._stacked_scan_cache: dict = {}
         # mesh topology is immutable for this trainer's lifetime: resolve

@@ -135,7 +135,10 @@ def default_tp_rules() -> list[Rule]:
     return [
         Rule(r"(query|key|value|q_proj|k_proj|v_proj)/kernel$", P(None, tp)),
         Rule(r"(out|o_proj|attn_out)/kernel$", P(tp, None)),
-        Rule(r"(mlp/up|mlp/gate|mlp_up|fc1|intermediate)/kernel$", P(None, tp)),
+        Rule(
+            r"(mlp/up|mlp/gate|mlp_up|mlp_gate|fc1|intermediate)/kernel$",
+            P(None, tp),
+        ),
         Rule(r"(mlp/down|mlp_down|fc2|output)/kernel$", P(tp, None)),
         Rule(r"embedding/embedding$", P(tp, None)),
         Rule(r"(lm_head|logits)/kernel$", P(None, tp)),

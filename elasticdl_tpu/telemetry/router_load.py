@@ -1,0 +1,73 @@
+"""The expert router's load, read on demand.
+
+``layers/moe.py`` sows, every step, each expert layer's per-expert pair
+counts and the number of (token, slot) pairs its dispatch gave a row to,
+into the ``router_stats`` collection.  They leave the step as device arrays
+inside the train state; the trainer holds them (``SPMDTrainer.state``) and
+nothing on the train path reads them.  :func:`read` fetches the newest when
+somebody asks — an evaluation milestone (``LocalExecutor.evaluate``, where
+the program reads the loss anyway), a benchmark's reader after its window.
+"""
+
+from __future__ import annotations
+
+import weakref
+
+import jax
+import numpy as np
+
+# the collection ``layers/moe.py`` sows into
+ROUTER_STATS = "router_stats"
+
+_watched = None
+
+
+def watch(trainer):
+    """Remember (weakly) the trainer whose state :func:`read` looks at."""
+    global _watched
+    _watched = weakref.ref(trainer)
+
+
+def read(model_state=None) -> dict | None:
+    """The newest step's router load, one host readback: the worst layer's
+    busiest expert over the mean load, experts that got no pair, and the
+    pairs routed less the pairs dispatched (0 by construction).  None for a
+    model without experts."""
+    if model_state is None:
+        trainer = _watched() if _watched is not None else None
+        if trainer is None:
+            return None
+        model_state = trainer.state.model_state
+    stats = (model_state or {}).get(ROUTER_STATS)
+    if not stats:
+        return None
+    flat = {
+        jax.tree_util.keystr(path): np.asarray(leaf)
+        for path, leaf in jax.tree_util.tree_leaves_with_path(
+            jax.device_get(stats)
+        )
+    }
+    counts = [v for k, v in flat.items() if "expert_counts" in k]
+    held = sum(int(v) for k, v in flat.items() if "rows_held" in k)
+    pairs = sum(int(c.sum()) for c in counts)
+    if not pairs:
+        return None  # no step has run yet
+    return {
+        "layers": len(counts),
+        "pairs": pairs,
+        "max_over_mean": max(float(c.max() / c.mean()) for c in counts),
+        "experts_without_tokens": sum(int((c == 0).sum()) for c in counts),
+        "dropped_pairs": pairs - held,
+    }
+
+
+def publish(registry, model_state=None) -> dict | None:
+    """:func:`read`, set as gauges on ``registry``."""
+    load = read(model_state)
+    if load is not None:
+        for name in ("max_over_mean", "experts_without_tokens", "dropped_pairs"):
+            registry.gauge(
+                f"elasticdl_router_{name}",
+                "expert router load of the newest train step",
+            ).set(load[name])
+    return load

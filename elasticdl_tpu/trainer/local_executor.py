@@ -24,6 +24,8 @@ from elasticdl_tpu.data.recordio import ensure_native_codec
 from elasticdl_tpu.master.task_dispatcher import TaskDispatcher
 from elasticdl_tpu.parallel.distributed import SPMDTrainer, trim_pad
 from elasticdl_tpu.parallel.mesh import MeshConfig
+from elasticdl_tpu.telemetry import router_load
+from elasticdl_tpu.telemetry import worker_hooks as telemetry_hooks
 from elasticdl_tpu.trainer import metrics as metrics_lib
 from elasticdl_tpu.trainer.checkpointing import (
     PeriodicCheckpointer,
@@ -158,7 +160,6 @@ class LocalExecutor:
         import os as _os
 
         from elasticdl_tpu.telemetry import tracing
-        from elasticdl_tpu.telemetry import worker_hooks as telemetry_hooks
 
         telemetry_dir = getattr(args, "telemetry_dir", "") or _os.environ.get(
             telemetry_hooks.TELEMETRY_DIR_ENV, ""
@@ -382,6 +383,12 @@ class LocalExecutor:
             dispatcher.report(tid, True)
         results = metrics_lib.metric_tree_results(eval_metrics)
         results["loss"] = loss_mean.result()
+        # the one place this runtime reads the loss back is where an expert
+        # model's router load is read too (telemetry/router_load.py)
+        load = router_load.read(self._trainer.state.model_state)
+        if load is not None:
+            results["router_load"] = load
+            telemetry_hooks.emit_event("router_load", **load)
         logger.info("Evaluation (%s): %s", tag, results)
         return results
 
