@@ -1,162 +1,191 @@
-"""Sweep the flash-attention kernel geometry on a bench transformer config.
+"""Time the three flash kernels alone, on the chip, at one attention shape.
 
-VERDICT r4 weak #6's alternative acceptance is taking the seq-8192
-config's exposed headroom (block size / grid / VMEM knobs in
-``ops/attention.py``).  This sweeps (``_SEQ_CHUNK``, ``block_q``,
-``block_k``) on the FULL train step of a bench config — the same
-fori_loop + data-dependent-readback timing as bench.py, so dispatch
-latency and unreliable device sync cannot inflate anything — and prints
-one JSON line of tokens/sec per geometry, best first.
+A builder's tool (nothing under ``perf/`` imports it): it runs
+``flash_attention`` forward + gradients at ``--shape B,S,H,D`` in
+``--dtype`` under the profiler and reads each kernel's device time from the
+trace by the name its ``pallas_call`` carries (``flash_fwd``, ``flash_dq``,
+``flash_dkv``), once per geometry in ``--sweep``.  A geometry is
+``block_q,block_k,chunk``: the blocks are ``flash_attention``'s own
+arguments, the rows of a staged chunk replace the module's constant
+(``_CHUNK_BYTES``; ``_SEQ_CHUNK`` in a copy from before PR 28) for the
+call.  The kernels are independent calls, so the best geometry is read per
+kernel from the table.  ``--impl path/to/attention.py`` times another copy
+of the module (the parent commit's, say) in the same process.
 
-Usage:
-  python benchmarks/attention_sweep.py [config_name] [--steps N]
-  (default config: transformer_seq8192)
+    python benchmarks/attention_sweep.py --shape 1,8192,12,64 \\
+        --sweep "512,512,2048;1024,512,2048" [--impl chip_parent/...py]
 
-Each geometry recompiles the step, so the sweep list is small and
-targeted.  The current defaults
-(chunk 2048, 512x512 blocks) are the r3-measured optimum; this exists
-to re-test them at seq 8192 where the backward's chunk-carried scratch
-changes the picture.
+One JSON line per (implementation, geometry): milliseconds a call for each
+kernel and each kernel's share of the bf16 peak, counted as
+``perf/kernel_rooflines.py`` counts it (a third of the analytic causal
+attention FLOPs a kernel; the recomputed scores are not counted).  A
+geometry Mosaic refuses is reported with its error, not skipped in silence.
+Exits 3 where JAX finds no TPU: a time from the CPU is not a kernel time.
 """
 
 from __future__ import annotations
 
+import argparse
+import glob
+import importlib.util
 import json
 import os
+import re
+import shutil
 import sys
-import time
+import tempfile
 
 sys.path.insert(
     0, os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 )
 
-# (seq_chunk, block_q, block_k)
-SWEEP = [
-    (2048, 512, 512),  # current defaults (r3 optimum at seq <= 2048)
-    (2048, 1024, 512),
-    (2048, 512, 1024),
-    (4096, 512, 512),
-    (4096, 1024, 1024),
-    (1024, 512, 512),
-]
+KERNELS = ("flash_fwd", "flash_dq", "flash_dkv")
+
+
+def kernel_ms(trace_dir: str, calls: int) -> dict:
+    """Device milliseconds a call of each flash kernel, from the op line
+    of the first device plane of the trace under ``trace_dir``."""
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(
+        os.path.join(trace_dir, "plugins", "profile", "*", "*.xplane.pb")
+    )
+    total = dict.fromkeys(KERNELS, 0)
+    for plane in ProfileData.from_file(path).planes:
+        if not re.match(r"^/device:TPU:\d+$", plane.name):
+            continue
+        for line in plane.lines:
+            if line.name != "XLA Ops":
+                continue
+            for event in line.events:
+                # "%transpose_jvp_flash_dkv__.1 = ...": under a bare jit
+                # the op's name wraps the kernel's in its transformations
+                name = event.name.partition(" = ")[0]
+                for kernel in KERNELS:
+                    if kernel in name:
+                        total[kernel] += int(event.duration_ns)
+        break
+    return {k: ns / 1e6 / calls for k, ns in total.items()}
+
+
+def load_impl(path: str | None):
+    if path is None:
+        from elasticdl_tpu.ops import attention
+
+        return attention
+    spec = importlib.util.spec_from_file_location(
+        "attention_impl_" + re.sub(r"\W", "_", path), path
+    )
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def time_geometry(module, geometry, shape, dtype, causal, calls):
+    import jax
+    import jax.numpy as jnp
+
+    kw = {}
+    chunk_name = (
+        "_CHUNK_BYTES" if hasattr(module, "_CHUNK_BYTES") else "_SEQ_CHUNK"
+    )
+    module_chunk = getattr(module, chunk_name)
+    if geometry is not None:
+        block_q, block_k, chunk = geometry
+        kw = dict(block_q=block_q, block_k=block_k)
+        if chunk_name == "_CHUNK_BYTES":
+            chunk *= shape[-1] * dtype.itemsize
+        setattr(module, chunk_name, chunk)
+        # the module's jitted wrappers cache a trace by shapes and blocks,
+        # which the chunk constant is not among
+        jax.clear_caches()
+    keys = jax.random.split(jax.random.PRNGKey(0), 4)
+    q, k, v, w = (
+        jax.random.normal(key, shape, jnp.float32).astype(dtype)
+        for key in keys
+    )
+
+    def loss(q, k, v):
+        out = module.flash_attention(q, k, v, causal=causal, **kw)
+        return jnp.sum(out.astype(jnp.float32) * w.astype(jnp.float32))
+
+    step = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
+    jax.block_until_ready(step(q, k, v))  # compiles
+    trace_dir = tempfile.mkdtemp(prefix="attention_sweep_")
+    try:
+        with jax.profiler.trace(trace_dir):
+            for _ in range(calls):
+                out = step(q, k, v)
+            jax.block_until_ready(out)
+        return kernel_ms(trace_dir, calls)
+    finally:
+        shutil.rmtree(trace_dir, ignore_errors=True)
+        setattr(module, chunk_name, module_chunk)
 
 
 def main() -> int:
-    args = sys.argv[1:]
-    steps = 10
-    positional = []
-    i = 0
-    while i < len(args):
-        a = args[i]
-        if a == "--steps":
-            i += 1
-            steps = int(args[i])
-        elif a.startswith("--steps="):
-            steps = int(a.split("=", 1)[1])
-        elif not a.startswith("--"):
-            positional.append(a)
-        i += 1
-    name = positional[0] if positional else "transformer_seq8192"
+    parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    parser.add_argument("--shape", default="1,8192,12,64", help="B,S,H,D")
+    parser.add_argument("--dtype", default="bfloat16")
+    parser.add_argument(
+        "--sweep", default="",
+        help="'block_q,block_k,chunk;...'; empty: the module's own choice",
+    )
+    parser.add_argument("--impl", default=None, help="another attention.py")
+    parser.add_argument("--label", default=None)
+    parser.add_argument("--non-causal", action="store_true")
+    parser.add_argument("--calls", type=int, default=5)
+    args = parser.parse_args()
 
     import jax
+    import jax.numpy as jnp
 
-    import bench
-    from elasticdl_tpu.ops import attention as attention_mod
-    from elasticdl_tpu.parallel.distributed import SPMDTrainer
-    from elasticdl_tpu.parallel.mesh import MeshConfig
-    from elasticdl_tpu.trainer.local_executor import build_optimizer
-    from elasticdl_tpu.utils.model_utils import get_model_spec
+    device = jax.devices()[0]
+    if device.platform != "tpu":
+        print(f"no TPU: {device.platform}", file=sys.stderr)
+        return 3
+    from perf.peaks import peaks_for  # the benchmark's one table of peaks
 
-    mesh = MeshConfig.from_string("").create()
-    cfg = bench._configs(max(1, mesh.devices.size))[name]
-    spec = get_model_spec(
-        "", cfg["model_def"], model_params=cfg.get("model_params")
-    )
-    rules = ()
-    if spec.sharding_rules is not None:
-        rules = tuple(spec.sharding_rules(mesh))
-
-    orig_flash = attention_mod.flash_attention
-    orig_chunk = attention_mod._SEQ_CHUNK
-    tokens_per_step = cfg["batch"] * cfg.get("tokens_per_sample", 1)
-    results = []
-    for seq_chunk, bq, bk in SWEEP:
-        attention_mod._SEQ_CHUNK = seq_chunk
-
-        def patched(q, k, v, **kw):
-            kw.setdefault("block_q", bq)  # noqa: B023 — rebound per loop
-            kw.setdefault("block_k", bk)  # noqa: B023
-            return orig_flash(q, k, v, **kw)
-
-        attention_mod.flash_attention = patched
+    peak = peaks_for(device.device_kind)["bf16_flops_per_s"]
+    shape = tuple(int(x) for x in args.shape.split(","))
+    batch, seq, heads, d_head = shape
+    causal = not args.non_causal
+    # forward + backward: six matmuls of 2*S*S*D a head, half of them
+    # under the diagonal; a third of that a kernel
+    kernel_flops = 12 * batch * heads * seq * seq * d_head / 3
+    if causal:
+        kernel_flops /= 2
+    module = load_impl(args.impl)
+    geometries = [
+        tuple(int(x) for x in g.split(","))
+        for g in args.sweep.split(";")
+        if g.strip()
+    ] or [None]
+    for geometry in geometries:
+        line = {
+            "impl": args.label or args.impl or "elasticdl_tpu.ops.attention",
+            "shape": list(shape),
+            "dtype": args.dtype,
+            "causal": causal,
+            "geometry": geometry,
+            "device_kind": device.device_kind,
+        }
         try:
-            trainer = SPMDTrainer(
-                mesh,
-                spec.build_model(),
-                spec.loss,
-                build_optimizer(spec, None),
-                cfg["features"],
-                rules=rules,
-                compute_dtype="bfloat16",
+            ms = time_geometry(
+                module, geometry, shape, jnp.dtype(args.dtype), causal,
+                args.calls,
             )
-            pf = trainer.place_batch(cfg["features"])
-            pl = trainer.place_batch(cfg["labels"])
-            step_fn = trainer._train_step
-
-            def many(state, f, l):
-                return jax.lax.fori_loop(
-                    0, steps, lambda _i, s: step_fn(s, f, l)[0], state
-                )
-
-            compiled = (
-                jax.jit(many, donate_argnums=(0,))
-                .lower(trainer.state, pf, pl)
-                .compile()
-            )
-            state = compiled(trainer.state, pf, pl)  # warm
-            int(jax.device_get(state.step))
-            best = float("inf")
-            for _ in range(3):
-                t0 = time.perf_counter()
-                state = compiled(state, pf, pl)
-                int(jax.device_get(state.step))
-                best = min(best, time.perf_counter() - t0)
-            rate = steps * tokens_per_step / best
-            results.append(
-                {
-                    "seq_chunk": seq_chunk,
-                    "block_q": bq,
-                    "block_k": bk,
-                    "tokens_per_sec_per_chip": round(rate),
-                }
-            )
-            print(
-                f"sweep: chunk={seq_chunk} bq={bq} bk={bk} -> "
-                f"{rate:.0f} tok/s",
-                file=sys.stderr,
-            )
-        except Exception as ex:  # noqa: BLE001 — a geometry may OOM VMEM
-            results.append(
-                {
-                    "seq_chunk": seq_chunk,
-                    "block_q": bq,
-                    "block_k": bk,
-                    "error": str(ex)[:160],
-                }
-            )
-            print(
-                f"sweep: chunk={seq_chunk} bq={bq} bk={bk} FAILED: "
-                f"{str(ex)[:160]}",
-                file=sys.stderr,
-            )
-        finally:
-            attention_mod.flash_attention = orig_flash
-            attention_mod._SEQ_CHUNK = orig_chunk
-
-    results.sort(
-        key=lambda r: -(r.get("tokens_per_sec_per_chip") or 0)
-    )
-    print(json.dumps({"config": name, "steps": steps, "sweep": results}))
+        except Exception as ex:  # noqa: BLE001 — Mosaic refuses a geometry
+            line["error"] = f"{type(ex).__name__}: {str(ex)[:300]}"
+        else:
+            line["ms"] = {k: round(v, 4) for k, v in ms.items()}
+            line["ms_total"] = round(sum(ms.values()), 4)
+            line["roofline_pct"] = {
+                k: round(100 * kernel_flops / (v / 1e3) / peak, 2)
+                for k, v in ms.items()
+                if v
+            }
+        print(json.dumps(line), flush=True)
     return 0
 
 

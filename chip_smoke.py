@@ -11,10 +11,14 @@ runs the Pallas flash kernels in both directions):
 1. ``train``   ``elasticdl_tpu.client train --distribution_strategy Local``
                from EDLIO shards generated here from a seed: a few tens of
                steps with a mid-run and a final evaluation.
-2. ``kernel``  compiled ``flash_attention`` forward and gradients against
-               ``mha_reference`` at the two full-width shapes.
-3. ``cache``   the same command as 1 in a second process: every compile
-               request is served by the persistent compile cache.
+2. ``cache``   the same command as 1 in a second process: every compile
+               request is served by the persistent compile cache.  Straight
+               after 1: where the cache directory is capped (192 MiB on the
+               chip tool's machine) the kernel run's float32 reference
+               programs, 115 MB one of them, push run 1's entries out.
+3. ``kernel``  compiled ``flash_attention`` forward and gradients against
+               ``mha_reference`` at the smoke model's shape and the
+               benchmark cells' three.
 
 It exits 0 — and prints, as the LAST line of stdout,
 ``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": ...}}`` —
@@ -57,7 +61,7 @@ import time
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 
-DEFAULT_RUNS = ("train", "kernel", "cache")
+DEFAULT_RUNS = ("train", "cache", "kernel")
 ALL_RUNS = DEFAULT_RUNS + ("bind", "dp4", "workers4", "kill")
 
 # the whole smoke must end inside the driver's 1200 s, compilation included
@@ -103,7 +107,15 @@ SIZES = {
         # tasks are still unleased when the worker dies
         kill_run_steps=48,
         kill_step=6,
-        kernel_shapes=((8, 2048, 12, 64), (1, 8192, 8, 64)),
+        # the smoke model's shape, then the benchmark cells' three: the
+        # 8,192 one at 8 heads of its 12 (the float32 reference holds a
+        # few (B, H, S, S) arrays: 2.1 GB each here, 3.2 at 12 heads)
+        kernel_shapes=(
+            (8, 2048, 12, 64),
+            (1, 8192, 8, 64),
+            (8, 1024, 12, 64),
+            (2, 4096, 16, 128),
+        ),
     ),
     # the rehearsal: same control flow, CPU backend, interpreted kernels
     "tiny": dict(
@@ -128,8 +140,9 @@ SIZES = {
 # flash_attention vs mha_reference (float32 math at HIGHEST matmul
 # precision), bf16 inputs.  bf16 keeps 8 mantissa bits (eps = 2^-8 ~ 3.9e-3):
 # outputs and gradients are rounded to bf16 once on each side, the kernel's
-# in-block matmuls run at the MXU's default precision, and the backward
-# re-reads a bf16 ``out``.  Agreement to a few eps of the largest magnitude
+# in-block matmuls take bf16 operands (the probabilities and their gradient
+# rounded once; float32 accumulation), and the backward re-reads a bf16
+# ``out``.  Agreement to a few eps of the largest magnitude
 # is what "the same function" means here:
 #   max|flash - ref| <= KERNEL_TOL * max(1, max|ref|)
 KERNEL_TOL = 2e-2
@@ -625,7 +638,7 @@ def _check_dp4(cfg, workdir, report, failures) -> dict:
 
 
 def _child_kernel(run, cfg, workdir):
-    """Run 2: the compiled kernels against the reference."""
+    """Run 3: the compiled kernels against the reference."""
     import jax
     import jax.numpy as jnp
 

@@ -169,24 +169,136 @@ def mha_reference(q, k, v, causal: bool = False, sm_scale: float | None = None):
 # ---- pallas flash kernel ---------------------------------------------------
 
 
-# the grid streams the opposite sequence in chunks of this many rows;
-# inside a chunk the original in-kernel block loop runs.  Bounds scoped
-# VMEM at any sequence length (full-seq refs OOM at 8k+) while keeping
-# the ≤2048 fast path IDENTICAL to a single staged ref — measured: pure
-# per-block grid streaming cost 13% tokens/sec on gpt2s@2048
-_SEQ_CHUNK = 2048
+# a @ b.T: the product the MXU takes natively (transposed right-hand side)
+_NT = (((1,), (1,)), ((), ()))
 
 
-def _causal_mask(s, row0, col0, block_q, block_k):
-    """Mask scores below the causal diagonal for a (block_q, block_k)
-    tile whose global top-left corner is (row0, col0)."""
-    row = row0 + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0
+def _clip(x, low, high):
+    if isinstance(x, int):
+        return min(max(x, low), high)
+    return jnp.clip(x, low, high)
+
+
+def _k_blocks_visible(row0, rows, col0, block_k, num_blocks):
+    """Causal structure of ``num_blocks`` k-blocks of ``block_k`` columns
+    from column ``col0`` on, against the q rows ``[row0, row0 + rows)``:
+    ``(full, live)`` — blocks ``[0, full)`` lie wholly on the visible side
+    of the diagonal (no mask), ``[full, live)`` are crossed by it, the
+    rest see nothing.  Python ints or traced int32."""
+    full = _clip((row0 + 1 - col0) // block_k, 0, num_blocks)
+    live = _clip(
+        (row0 + rows - col0 + block_k - 1) // block_k, 0, num_blocks
     )
-    col = col0 + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1
+    return full, live
+
+
+def _q_blocks_visible(col0, cols, row0, block_q, num_blocks):
+    """The same structure along the other axis, for dK/dV: q-blocks of
+    ``block_q`` rows from row ``row0`` on, against the k columns
+    ``[col0, col0 + cols)``: ``(first, full_from)`` — blocks
+    ``[0, first)`` see nothing, ``[first, full_from)`` are crossed by the
+    diagonal, ``[full_from, num_blocks)`` need no mask."""
+    first = _clip((col0 - row0) // block_q, 0, num_blocks)
+    full_from = _clip(
+        (col0 + cols - 1 - row0 + block_q - 1) // block_q, 0, num_blocks
     )
-    return jnp.where(row >= col, s, _NEG_INF)
+    return first, full_from
+
+
+def flash_block_plan(seq_q, seq_k, block_q, block_k, causal):
+    """``(live, masked, skipped)`` score blocks a head: the blocks the
+    kernels compute, those of them the diagonal crosses (the only ones
+    that build a mask), and the ones never touched.  Counted with the
+    kernels' own loop bounds."""
+    num_q, num_k = seq_q // block_q, seq_k // block_k
+    if not causal:
+        return num_q * num_k, 0, 0
+    live = masked = 0
+    for i in range(num_q):
+        full, upto = _k_blocks_visible(
+            i * block_q, block_q, 0, block_k, num_k
+        )
+        live += upto
+        masked += upto - full
+    return live, masked, num_q * num_k - live
+
+
+def _causal_mask(s, row0, col0, q_axis=0):
+    """Mask scores above the causal diagonal for a tile whose first query
+    row is ``row0`` and first key column ``col0``; queries run along
+    ``q_axis`` of ``s`` (1 for dK/dV's transposed scores)."""
+    ahead = jax.lax.broadcasted_iota(
+        jnp.int32, s.shape, q_axis
+    ) - jax.lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis)
+    return jnp.where(ahead >= col0 - row0, s, _NEG_INF)
+
+
+def _scores(a, b, sm_scale=None):
+    """``a @ b.T`` in float32, times the softmax scale where one is given
+    (applied to the float32 scores: 1/sqrt(128) is not a bf16 number, so
+    it never rides a rounded q)."""
+    s = jax.lax.dot_general(a, b, _NT, preferred_element_type=jnp.float32)
+    return s if sm_scale is None else s * sm_scale
+
+
+def _mxu(a, b):
+    """``a`` (float32 probabilities, or their gradient) rounded once to
+    ``b``'s dtype, times ``b``, accumulated in float32."""
+    return jax.lax.dot(
+        a.astype(b.dtype), b, preferred_element_type=jnp.float32
+    )
+
+
+def _lanes_to(x, width):
+    """A lane-replicated ``(rows, _LANES)`` column as ``(rows, width)``."""
+    if width % _LANES == 0:
+        return jnp.tile(x, (1, width // _LANES))
+    if width < _LANES:
+        return x[:, :width]
+    return jnp.broadcast_to(x[:, 0:1], (x.shape[0], width))
+
+
+def _row_to_lanes(row):
+    """A ``(1, n)`` row as an ``(n, _LANES)`` lane-replicated column: a
+    transpose of the row repeated over 128 sublanes.  (Transposing 8
+    sublanes and broadcasting the column across lanes costs dQ 20% at
+    1,024 tokens: the lane broadcast is the dear part.)"""
+    return jnp.broadcast_to(row, (_LANES, row.shape[1])).T
+
+
+def _loop(start, stop, body):
+    if isinstance(start, int) and isinstance(stop, int) and start >= stop:
+        return
+    jax.lax.fori_loop(start, stop, lambda i, _: body(i) or 0, 0)
+
+
+def _diagonal_half(block_q, block_k):
+    """Equal blocks are crossed by the diagonal only ON it (first row ==
+    first column), where the upper-right quarter sees nothing: the
+    kernels then work such a block in halves and skip that quarter.
+    Returns the half, or 0 where blocks are not halved (unequal, or a half
+    the sublanes do not tile)."""
+    half = block_q // 2
+    return half if block_q == block_k and half and half % 8 == 0 else 0
+
+
+def _block_pieces(block_q, block_k, crossed, along_q):
+    """The parts of a score block worth computing, as static
+    ``(q_from, q_to, k_from, k_to, masked)`` ranges inside it: one piece
+    for a block the diagonal does not cross; for one it does, the three
+    visible quarters (:func:`_diagonal_half`) — as two pieces split along
+    q for the kernels that accumulate by q row, as three for dK/dV, whose
+    pieces each read one saved half-row — or the whole block, masked."""
+    half = _diagonal_half(block_q, block_k)
+    if not crossed or not half:
+        return [(0, block_q, 0, block_k, crossed)]
+    if along_q:
+        return [(0, half, 0, half, True), (half, block_q, 0, block_k, True)]
+    return [
+        (0, half, 0, half, True),
+        (half, block_q, 0, half, False),
+        (half, block_q, half, block_k, True),
+    ]
 
 
 def _flash_kernel(
@@ -208,64 +320,56 @@ def _flash_kernel(
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    row_end = (i + 1) * block_q  # exclusive causal row bound
-    # chunks fully above the causal diagonal contribute nothing
-    chunk_live = c * chunk_k < row_end if causal else None
+    nb = chunk_k // block_k
+    row0, col0 = i * block_q, c * chunk_k
+    if causal:
+        full, live = _k_blocks_visible(row0, block_q, col0, block_k, nb)
+    else:
+        full = live = nb
+    d = acc_scr.shape[1]
 
     def _chunk():
-        q = q_ref[0].astype(jnp.float32) * sm_scale  # (block_q, D)
-        nb = chunk_k // block_k
-        if causal:
-            # stop at the last sub-block intersecting this q-block's rows
-            nb_live = jnp.clip(
-                (row_end - c * chunk_k + block_k - 1) // block_k, 0, nb
-            )
-        else:
-            nb_live = nb
+        q = q_ref[0]  # (block_q, D)
 
-        def body(jj, _):
-            kb = k_ref[0, pl.ds(jj * block_k, block_k), :].astype(
-                jnp.float32
-            )
-            vb = v_ref[0, pl.ds(jj * block_k, block_k), :].astype(
-                jnp.float32
-            )
-            s = jax.lax.dot_general(
-                q, kb, (((1,), (1,)), ((), ()))
-            )  # (block_q, block_k)
-            if causal:
-                s = _causal_mask(
-                    s, i * block_q, c * chunk_k + jj * block_k,
-                    block_q, block_k,
+        def body(jj, crossed):
+            start = pl.multiple_of(jj * block_k, block_k)
+            for q0, q1, k0, k1, masked in _block_pieces(
+                block_q, block_k, crossed, along_q=True
+            ):
+                rows = slice(q0, q1)
+                kb = k_ref[0, pl.ds(start + k0, k1 - k0), :]
+                vb = v_ref[0, pl.ds(start + k0, k1 - k0), :]
+                s = _scores(q[rows], kb, sm_scale)
+                if masked:
+                    s = _causal_mask(s, row0 + q0, col0 + start + k0)
+                m_prev = m_scr[rows]  # (rows, _LANES), columns all equal
+                m_next = jnp.maximum(
+                    m_prev, jnp.max(s, axis=1, keepdims=True)
                 )
-            m_prev = m_scr[...]  # (block_q, _LANES), columns all equal
-            l_prev = l_scr[...]
-            m_next = jnp.maximum(
-                m_prev, jnp.max(s, axis=1, keepdims=True)
-            )
-            alpha = jnp.exp(m_prev - m_next)
-            p = jnp.exp(s - m_next[:, 0:1])
-            l_scr[...] = alpha * l_prev + p.sum(axis=1, keepdims=True)
-            m_scr[...] = m_next
-            acc_scr[...] = (
-                acc_scr[...] * alpha[:, 0:1] + jax.lax.dot(p, vb)
-            )
-            return 0
+                alpha = jnp.exp(m_prev - m_next)
+                p = jnp.exp(s - _lanes_to(m_next, k1 - k0))
+                l_scr[rows] = alpha * l_scr[rows] + p.sum(
+                    axis=1, keepdims=True
+                )
+                m_scr[rows] = m_next
+                acc_scr[rows] = acc_scr[rows] * _lanes_to(alpha, d) + _mxu(
+                    p, vb
+                )
 
-        jax.lax.fori_loop(0, nb_live, body, 0)
+        _loop(0, full, functools.partial(body, crossed=False))
+        _loop(full, live, functools.partial(body, crossed=True))
 
     if causal:
-        pl.when(chunk_live)(_chunk)
+        pl.when(live > 0)(_chunk)  # chunks above the diagonal add nothing
     else:
         _chunk()
 
     @pl.when(c == num_ck - 1)
     def _write():
-        l = l_scr[...][:, 0:1]
-        o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
-        # (block_q, 1) trailing unit dim: TPU block shapes must tile the
-        # last two dims, and a 2-D (1, block_q) block would not
-        lse_ref[0] = m_scr[...][:, 0:1] + jnp.log(l)
+        l = l_scr[...]
+        o_ref[0] = (acc_scr[...] / _lanes_to(l, d)).astype(o_ref.dtype)
+        # one dense (1, block_q) row a block: lanes hold the sequence
+        lse_ref[0] = (m_scr[...] + jnp.log(l)).T[0:1]
 
 
 def _flash_dq_kernel(
@@ -295,45 +399,41 @@ def _flash_dq_kernel(
     def _init():
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    row_end = (i + 1) * block_q
-    chunk_live = c * chunk_k < row_end if causal else None
+    nb = chunk_k // block_k
+    row0, col0 = i * block_q, c * chunk_k
+    if causal:
+        full, live = _k_blocks_visible(row0, block_q, col0, block_k, nb)
+    else:
+        full = live = nb
 
     def _chunk():
-        q = q_ref[0].astype(jnp.float32) * sm_scale
-        do = do_ref[0].astype(jnp.float32)  # (block_q, D)
-        lse = lse_ref[0]  # (block_q, 1)
-        delta = delta_ref[0]  # (block_q, 1)
-        nb = chunk_k // block_k
-        if causal:
-            nb_live = jnp.clip(
-                (row_end - c * chunk_k + block_k - 1) // block_k, 0, nb
-            )
-        else:
-            nb_live = nb
+        q = q_ref[0]
+        do = do_ref[0]  # (block_q, D)
+        # the saved rows, turned once a chunk into lane-replicated columns
+        lse = _row_to_lanes(lse_ref[0])
+        delta = _row_to_lanes(delta_ref[0])
 
-        def body(jj, _):
-            kb = k_ref[0, pl.ds(jj * block_k, block_k), :].astype(
-                jnp.float32
-            )
-            vb = v_ref[0, pl.ds(jj * block_k, block_k), :].astype(
-                jnp.float32
-            )
-            s = jax.lax.dot_general(q, kb, (((1,), (1,)), ((), ())))
-            if causal:
-                s = _causal_mask(
-                    s, i * block_q, c * chunk_k + jj * block_k,
-                    block_q, block_k,
-                )
-            p = jnp.exp(s - lse)  # (block_q, block_k)
-            dp = jax.lax.dot_general(do, vb, (((1,), (1,)), ((), ())))
-            ds = p * (dp - delta)
-            acc_scr[...] = acc_scr[...] + jax.lax.dot(ds, kb)
-            return 0
+        def body(jj, crossed):
+            start = pl.multiple_of(jj * block_k, block_k)
+            for q0, q1, k0, k1, masked in _block_pieces(
+                block_q, block_k, crossed, along_q=True
+            ):
+                rows = slice(q0, q1)
+                kb = k_ref[0, pl.ds(start + k0, k1 - k0), :]
+                vb = v_ref[0, pl.ds(start + k0, k1 - k0), :]
+                s = _scores(q[rows], kb, sm_scale)
+                if masked:
+                    s = _causal_mask(s, row0 + q0, col0 + start + k0)
+                p = jnp.exp(s - _lanes_to(lse[rows], k1 - k0))
+                dp = _scores(do[rows], vb)
+                ds = p * (dp - _lanes_to(delta[rows], k1 - k0))
+                acc_scr[rows] = acc_scr[rows] + _mxu(ds, kb)
 
-        jax.lax.fori_loop(0, nb_live, body, 0)
+        _loop(0, full, functools.partial(body, crossed=False))
+        _loop(full, live, functools.partial(body, crossed=True))
 
     if causal:
-        pl.when(chunk_live)(_chunk)
+        pl.when(live > 0)(_chunk)
     else:
         _chunk()
 
@@ -362,8 +462,12 @@ def _flash_dkv_kernel(
     num_cq,
 ):
     """dK/dV cell per (batch*head, k-block, q-chunk): loop block_q
-    sub-blocks of the staged (1, chunk_q, d) Q/dO chunk, dv += p^T @ dO
-    and dk += ds^T @ (sm_scale * q) accumulating in VMEM scratch."""
+    sub-blocks of the staged (1, chunk_q, d) Q/dO chunk over TRANSPOSED
+    scores ``s^T = k @ q^T`` (block_k, block_q), so ``p^T`` and ``ds^T``
+    come out as the left-hand sides ``dv += p^T @ dO`` and
+    ``dk += ds^T @ q`` want, and ``lse``/``delta`` are read as rows along
+    the lanes (``lse_ref``: a head's whole (1, seq_q / n, n), a row a
+    q-block or half of one).  ``sm_scale`` meets dk once, at the write."""
     j = pl.program_id(1)
     c = pl.program_id(2)
 
@@ -372,59 +476,64 @@ def _flash_dkv_kernel(
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    col0 = j * block_k  # first causal-visible column of this k block
-    # chunks whose LAST row is still above the diagonal see nothing
-    chunk_live = (c + 1) * chunk_q > col0 if causal else None
+    nb = chunk_q // block_q
+    col0, row0 = j * block_k, c * chunk_q
+    if causal:
+        first, full_from = _q_blocks_visible(
+            col0, block_k, row0, block_q, nb
+        )
+    else:
+        first = full_from = 0
+
+    saved = lse_ref.shape[2]  # block_q, or its half (_diagonal_half)
+
+    def saved_rows(ref, ii, q0, q1):
+        """Rows ``[q0, q1)`` of q-block ``ii`` of the chunk, along lanes."""
+        first = (c * nb + ii) * (block_q // saved)
+        return jnp.concatenate(
+            [
+                ref[0, pl.ds(first + part, 1), :]
+                for part in range(q0 // saved, q1 // saved)
+            ],
+            axis=1,
+        )
 
     def _chunk():
-        kb = k_ref[0].astype(jnp.float32)  # (block_k, D)
-        vb = v_ref[0].astype(jnp.float32)
-        nb = chunk_q // block_q
-        if causal:
-            # first sub-block whose rows reach this k block's columns
-            ii0 = jnp.clip((col0 - c * chunk_q) // block_q, 0, nb)
-        else:
-            ii0 = 0
+        k = k_ref[0]  # (block_k, D)
+        v = v_ref[0]
 
-        def body(ii, _):
-            qi = (
-                q_ref[0, pl.ds(ii * block_q, block_q), :].astype(
-                    jnp.float32
-                )
-                * sm_scale
-            )
-            doi = do_ref[0, pl.ds(ii * block_q, block_q), :].astype(
-                jnp.float32
-            )
-            lse = lse_ref[0, pl.ds(ii * block_q, block_q), :]
-            delta = delta_ref[0, pl.ds(ii * block_q, block_q), :]
-            s = jax.lax.dot_general(qi, kb, (((1,), (1,)), ((), ())))
-            if causal:
-                s = _causal_mask(
-                    s, c * chunk_q + ii * block_q, col0,
-                    block_q, block_k,
-                )
-            p = jnp.exp(s - lse)  # (block_q, block_k)
-            dv_scr[...] = dv_scr[...] + jax.lax.dot_general(
-                p, doi, (((0,), (0,)), ((), ()))
-            )
-            dp = jax.lax.dot_general(doi, vb, (((1,), (1,)), ((), ())))
-            ds = p * (dp - delta)
-            dk_scr[...] = dk_scr[...] + jax.lax.dot_general(
-                ds, qi, (((0,), (0,)), ((), ()))
-            )
-            return 0
+        def body(ii, crossed):
+            start = pl.multiple_of(ii * block_q, block_q)
+            for q0, q1, k0, k1, masked in _block_pieces(
+                block_q, block_k, crossed, along_q=False
+            ):
+                cols = slice(k0, k1)
+                lse = saved_rows(lse_ref, ii, q0, q1)  # (1, q rows)
+                delta = saved_rows(delta_ref, ii, q0, q1)
+                qi = q_ref[0, pl.ds(start + q0, q1 - q0), :]
+                doi = do_ref[0, pl.ds(start + q0, q1 - q0), :]
+                st = _scores(k[cols], qi, sm_scale)  # (k rows, q rows)
+                if masked:
+                    st = _causal_mask(
+                        st, row0 + start + q0, col0 + k0, q_axis=1
+                    )
+                pt = jnp.exp(st - lse)
+                dv_scr[cols] = dv_scr[cols] + _mxu(pt, doi)
+                dpt = _scores(v[cols], doi)
+                dst = pt * (dpt - delta)
+                dk_scr[cols] = dk_scr[cols] + _mxu(dst, qi)
 
-        jax.lax.fori_loop(ii0, nb, body, 0)
+        _loop(first, full_from, functools.partial(body, crossed=True))
+        _loop(full_from, nb, functools.partial(body, crossed=False))
 
     if causal:
-        pl.when(chunk_live)(_chunk)
+        pl.when(first < nb)(_chunk)  # chunks above the diagonal see nothing
     else:
         _chunk()
 
     @pl.when(c == num_cq - 1)
     def _write():
-        dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
+        dk_ref[0] = (dk_scr[...] * sm_scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
 
 
@@ -435,14 +544,24 @@ def _pick_block(size: int, preferred: int) -> int:
     return max(block, 1)
 
 
-def _pick_chunk(seq: int, block: int) -> int:
+# the grid streams the opposite sequence in chunks; inside a chunk the
+# in-kernel block loop runs.  A chunk is the whole sequence up to this many
+# bytes an array (8,192 rows of 64 bf16), which bounds scoped VMEM at any
+# sequence length and head width.  Measured on a TPU v5e, kernels alone,
+# bf16 causal forward + gradients at (1, 8192, 12, 64): 6.81 ms with
+# chunks of 2,048 rows, 6.24 with 4,096, 6.00 with the whole 8,192
+# (benchmarks/attention_sweep.py; PERF.md section 6, PR 28)
+_CHUNK_BYTES = 1 << 20
+
+
+def _pick_chunk(seq: int, block: int, preferred: int) -> int:
     """Chunk rows for the grid stream: a multiple of ``block`` (the
     in-chunk loop runs ``chunk // block`` sub-blocks — a chunk smaller
     than the block would run ZERO and silently emit garbage) that
-    divides ``seq``, as close to ``_SEQ_CHUNK`` as those constraints
+    divides ``seq``, as close to ``preferred`` as those constraints
     allow."""
     num_blocks = seq // block  # block always divides seq (_pick_block)
-    return block * _pick_block(num_blocks, max(1, _SEQ_CHUNK // block))
+    return block * _pick_block(num_blocks, max(1, preferred // block))
 
 
 @functools.partial(
@@ -454,9 +573,14 @@ def flash_attention(
     v,
     causal: bool = False,
     sm_scale: float | None = None,
-    # 512x512 measured on v5e: 8-17x faster than 128x128 across seq
-    # 2048-8192 / head_dim 64-128 (small blocks starve the mosaic
-    # pipeline); _pick_block shrinks them for shorter sequences
+    # at most (_pick_block shrinks them to divide a sequence).  512x512
+    # measured on a TPU v5e at the benchmark cells' three shapes, kernels
+    # alone, ms a call against 256 / 512 / 1,024-square blocks:
+    # (1, 8192, 12, 64) 12.09 / 6.81 / 6.50, (8, 1024, 12, 64)
+    # 1.92 / 1.30 / 1.52, (2, 4096, 16, 128) 8.29 / 4.80 / 4.88; once a
+    # block on the diagonal skips its unseen quarter 1,024-blocks no
+    # longer fit VMEM at 8,192 and win 1% and 3% at the other two
+    # (PERF.md section 6, PR 28)
     block_q: int = 512,
     block_k: int = 512,
     interpret: bool | None = None,
@@ -479,13 +603,19 @@ def flash_attention(
 
 
 def _flash_geometry(q, k, sm_scale, block_q, block_k, interpret):
+    """Defaults filled in, blocks made to divide the sequences, and the
+    rows of a staged chunk of q (for dK/dV) and of k (for the forward and
+    dQ), from what the call can see: lengths, head width, dtype."""
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     if interpret is None:
         interpret = kernel_interpret(jax.default_backend())
     block_q = _pick_block(q.shape[1], block_q)
     block_k = _pick_block(k.shape[1], block_k)
-    return sm_scale, block_q, block_k, interpret
+    rows = _CHUNK_BYTES // (q.shape[-1] * q.dtype.itemsize)
+    chunk_q = _pick_chunk(q.shape[1], block_q, rows)
+    chunk_k = _pick_chunk(k.shape[1], block_k, rows)
+    return sm_scale, block_q, block_k, chunk_q, chunk_k, interpret
 
 
 def _fold_heads(x):
@@ -507,20 +637,30 @@ def _unfold_heads(x, batch, heads):
     return x.reshape(batch, heads, s, d).transpose(0, 2, 1, 3)
 
 
+def _last_live_chunk(i, block_q, chunk_k):
+    """The last k-chunk a causal q-block sees: the index maps stop there,
+    so the pipeline fetches no chunk the kernel would skip."""
+    return (i * block_q + block_q - 1) // chunk_k
+
+
+# jitted so that a model's layers share ONE trace and one lowering of each
+# kernel (the twelve layers of the benchmark's LM traced them 36 times: on
+# the chip's host 15 s of a 50 s set-up, PERF.md section 6, PR 28)
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7), inline=True)
 def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret):
     q, k, v = jnp.asarray(q), jnp.asarray(k), jnp.asarray(v)
-    sm_scale, block_q, block_k, interpret = _flash_geometry(
+    sm_scale, block_q, block_k, _, chunk_k, interpret = _flash_geometry(
         q, k, sm_scale, block_q, block_k, interpret
     )
     batch, seq_q, heads, d = q.shape
     group = validate_gqa_heads(q, k, v)
     kv_heads = k.shape[2]
     seq_k = k.shape[1]
-
-    chunk_k = _pick_chunk(seq_k, block_k)
     num_ck = seq_k // chunk_k
 
     def _kv_index(b, i, c):
+        if causal:
+            c = jnp.minimum(c, _last_live_chunk(i, block_q, chunk_k))
         return (_kv_head(b, heads, kv_heads, group), c, 0)
 
     qf, kf, vf = _fold_heads(q), _fold_heads(k), _fold_heads(v)
@@ -543,11 +683,12 @@ def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret):
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, c: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i, c: (b, i, 0)),
+            pl.BlockSpec((1, 1, block_q), lambda b, i, c: (b, 0, i)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((batch * heads, seq_q, d), q.dtype),
-            jax.ShapeDtypeStruct((batch * heads, seq_q, 1), jnp.float32),
+            # lane-major and compact: a row of seq_q float32 a head
+            jax.ShapeDtypeStruct((batch * heads, 1, seq_q), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, _LANES), jnp.float32),  # m
@@ -561,57 +702,55 @@ def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret):
     return _unfold_heads(out, batch, heads), lse
 
 
+@functools.partial(
+    jax.jit, static_argnums=(6, 7, 8, 9, 10), inline=True
+)
 def _flash_backward(
     q, k, v, out, lse, g, causal, sm_scale, block_q, block_k, interpret
 ):
     q, k, v = jnp.asarray(q), jnp.asarray(k), jnp.asarray(v)
     out, lse, g = jnp.asarray(out), jnp.asarray(lse), jnp.asarray(g)
-    sm_scale, block_q, block_k, interpret = _flash_geometry(
+    sm_scale, bq, bk, chunk_q, chunk_k, interpret = _flash_geometry(
         q, k, sm_scale, block_q, block_k, interpret
     )
     batch, seq_q, heads, d = q.shape
     group = validate_gqa_heads(q, k, v)
     kv_heads = k.shape[2]
     seq_k = k.shape[1]
-
-    chunk_k = _pick_chunk(seq_k, block_k)
     num_ck = seq_k // chunk_k
-    chunk_q = _pick_chunk(seq_q, block_q)
     num_cq = seq_q // chunk_q
-
-    def _kv_chunk_index(b, i, c):
-        return (_kv_head(b, heads, kv_heads, group), c, 0)
 
     qf, kf, vf = _fold_heads(q), _fold_heads(k), _fold_heads(v)
     dof = _fold_heads(g)
-    # delta_r = rowsum(dO * O): the softmax-jacobian correction term;
-    # trailing unit dim matches the lse layout (TPU block tiling)
+    # delta_r = rowsum(dO * O): the softmax-jacobian correction term, in
+    # the lse's layout
     delta = jnp.sum(
-        dof.astype(jnp.float32)
-        * _fold_heads(out).astype(jnp.float32),
+        dof.astype(jnp.float32) * _fold_heads(out).astype(jnp.float32),
         axis=-1,
-        keepdims=True,
-    )  # (B*H, S_q, 1)
+    )[:, None, :]  # (B*H, 1, S_q)
 
-    common = dict(
-        sm_scale=sm_scale, causal=causal, block_q=block_q, block_k=block_k
-    )
+    def _kv_chunk_index(b, i, c):
+        if causal:
+            c = jnp.minimum(c, _last_live_chunk(i, bq, chunk_k))
+        return (_kv_head(b, heads, kv_heads, group), c, 0)
+
     dq = pl.pallas_call(
         functools.partial(
-            _flash_dq_kernel, chunk_k=chunk_k, num_ck=num_ck, **common
+            _flash_dq_kernel, sm_scale=sm_scale, causal=causal,
+            block_q=bq, block_k=bk, chunk_k=chunk_k, num_ck=num_ck,
         ),
-        grid=(batch * heads, seq_q // block_q, num_ck),
+        grid=(batch * heads, seq_q // bq, num_ck),
         in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, c: (b, i, 0)),
+            pl.BlockSpec((1, bq, d), lambda b, i, c: (b, i, 0)),
             pl.BlockSpec((1, chunk_k, d), _kv_chunk_index),
             pl.BlockSpec((1, chunk_k, d), _kv_chunk_index),
-            pl.BlockSpec((1, block_q, d), lambda b, i, c: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i, c: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i, c: (b, i, 0)),
+            pl.BlockSpec((1, bq, d), lambda b, i, c: (b, i, 0)),
+            pl.BlockSpec((1, 1, bq), lambda b, i, c: (b, 0, i)),
+            pl.BlockSpec((1, 1, bq), lambda b, i, c: (b, 0, i)),
         ],
-        out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, c: (b, i, 0)),
+        out_specs=pl.BlockSpec((1, bq, d), lambda b, i, c: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((batch * heads, seq_q, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         compiler_params=_FLASH_COMPILER_PARAMS,
         interpret=interpret,
         name=FLASH_DQ,
@@ -621,39 +760,51 @@ def _flash_backward(
     # repeated K/V either); a GQA group then sums its q-heads' parts —
     # one (B, H, S_k, D) pass, the gradient analogue of the repeat.
     # Grid: k-block outer, q-CHUNK innermost (the accumulation stream).
+    def _q_chunk_index(b, j, c):
+        if causal:
+            # the first q-chunk whose rows reach this k-block's columns
+            c = jnp.maximum(c, (j * bk) // chunk_q)
+        return (b, c, 0)
+
+    def _k_block_index(b, j, c):
+        return (_kv_head(b, heads, kv_heads, group), j, 0)
+
+    # a head's whole row, one q-block (or half of one, where the kernel
+    # halves the blocks on the diagonal) a sublane row: the kernel picks
+    # its (1, n) by row index
+    saved = _diagonal_half(bq, bk) or bq
+    rows = (batch * heads, seq_q // saved, saved)
+    row_spec = pl.BlockSpec((1,) + rows[1:], lambda b, j, c: (b, 0, 0))
     dk_per_q, dv_per_q = pl.pallas_call(
         functools.partial(
-            _flash_dkv_kernel, chunk_q=chunk_q, num_cq=num_cq, **common
+            _flash_dkv_kernel, sm_scale=sm_scale, causal=causal,
+            block_q=bq, block_k=bk, chunk_q=chunk_q, num_cq=num_cq,
         ),
-        grid=(batch * heads, seq_k // block_k, num_cq),
+        grid=(batch * heads, seq_k // bk, num_cq),
         in_specs=[
-            pl.BlockSpec((1, chunk_q, d), lambda b, j, c: (b, c, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j, c: (
-                _kv_head(b, heads, kv_heads, group), j, 0
-            )),
-            pl.BlockSpec((1, block_k, d), lambda b, j, c: (
-                _kv_head(b, heads, kv_heads, group), j, 0
-            )),
-            pl.BlockSpec((1, chunk_q, d), lambda b, j, c: (b, c, 0)),
-            pl.BlockSpec((1, chunk_q, 1), lambda b, j, c: (b, c, 0)),
-            pl.BlockSpec((1, chunk_q, 1), lambda b, j, c: (b, c, 0)),
+            pl.BlockSpec((1, chunk_q, d), _q_chunk_index),
+            pl.BlockSpec((1, bk, d), _k_block_index),
+            pl.BlockSpec((1, bk, d), _k_block_index),
+            pl.BlockSpec((1, chunk_q, d), _q_chunk_index),
+            row_spec,
+            row_spec,
         ],
         out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda b, j, c: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j, c: (b, j, 0)),
+            pl.BlockSpec((1, bk, d), lambda b, j, c: (b, j, 0)),
+            pl.BlockSpec((1, bk, d), lambda b, j, c: (b, j, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((batch * heads, seq_k, d), k.dtype),
             jax.ShapeDtypeStruct((batch * heads, seq_k, d), v.dtype),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_k, d), jnp.float32),  # dk
-            pltpu.VMEM((block_k, d), jnp.float32),  # dv
+            pltpu.VMEM((bk, d), jnp.float32),  # dk
+            pltpu.VMEM((bk, d), jnp.float32),  # dv
         ],
         compiler_params=_FLASH_COMPILER_PARAMS,
         interpret=interpret,
         name=FLASH_DKV,
-    )(qf, kf, vf, dof, lse, delta)
+    )(qf, kf, vf, dof, lse.reshape(rows), delta.reshape(rows))
 
     dq = _unfold_heads(dq, batch, heads)
     dk = _unfold_heads(dk_per_q, batch, heads)

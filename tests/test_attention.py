@@ -494,3 +494,242 @@ def test_flash_non_power_of_two_blocks_chunking():
     ref = np.asarray(mha_reference(q, k, v, causal=True))
     assert not np.isnan(out).any()
     np.testing.assert_allclose(out, ref, atol=2e-3, rtol=2e-3)
+
+
+# ---- PR 28: the kernels' causal block structure and operand dtypes ---------
+
+# chip_smoke.py's rule for the compiled kernels on bf16 inputs:
+#   max|flash - ref| <= KERNEL_TOL * max(1, max|ref|)
+KERNEL_TOL = 2e-2
+
+
+def _weighted_grads(fn, w):
+    return jax.grad(
+        lambda q, k, v: jnp.sum(fn(q, k, v).astype(jnp.float32) * w),
+        argnums=(0, 1, 2),
+    )
+
+
+def _scaled_errors(got, want):
+    errors = []
+    for a, b in zip(got, want):
+        a = np.asarray(a, np.float32)
+        b = np.asarray(b, np.float32)
+        errors.append(np.max(np.abs(a - b)) / max(1.0, np.max(np.abs(b))))
+    return errors
+
+
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("heads,kv_heads", [(2, 2), (4, 1)])
+def test_flash_bf16_forward_and_gradients_within_the_chip_rule(
+    causal, heads, kv_heads
+):
+    """bf16 in: bf16 blocks on the MXU, float32 accumulation.  A 4 x 4
+    block grid: four blocks the diagonal crosses and six it does not."""
+    rng = np.random.RandomState(11)
+
+    def mk(h):
+        return jnp.asarray(rng.randn(2, 256, h, 32), jnp.bfloat16)
+
+    q, k, v = mk(heads), mk(kv_heads), mk(kv_heads)
+    w = jnp.asarray(rng.randn(2, 256, heads, 32), jnp.float32)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=causal, block_q=64, block_k=64)
+
+    def ref(q, k, v):
+        return mha_reference(q, k, v, causal=causal)
+
+    got = (flash(q, k, v), *_weighted_grads(flash, w)(q, k, v))
+    want = (ref(q, k, v), *_weighted_grads(ref, w)(q, k, v))
+    assert [g.dtype for g in got] == [jnp.bfloat16] * 4
+    assert [g.shape for g in got] == [w.shape for w in want]
+    for name, err in zip(("out", "dq", "dk", "dv"), _scaled_errors(got, want)):
+        assert err <= KERNEL_TOL, (name, err)
+
+
+def _eqns(jaxpr):
+    """Every equation of ``jaxpr``, those of nested jaxprs included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for param in eqn.params.values():
+            params = param if isinstance(param, (list, tuple)) else [param]
+            for one in params:
+                inner = getattr(one, "jaxpr", one)
+                if hasattr(inner, "eqns"):
+                    yield from _eqns(inner)
+
+
+def _kernel_calls(fn, *args):
+    """The ``pallas_call`` equations of ``fn``'s jaxpr, by kernel name."""
+    return {
+        eqn.params["name"]: eqn
+        for eqn in _eqns(jax.make_jaxpr(fn)(*args).jaxpr)
+        if eqn.primitive.name == "pallas_call"
+    }
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("kernel", ["flash_fwd", "flash_dq", "flash_dkv"])
+def test_flash_kernel_dtypes_follow_the_input(kernel, dtype):
+    """Matmul operands in the input's dtype (float32 in computes in
+    float32), every accumulator, the softmax statistics, ``lse`` and the
+    exponent's argument in float32."""
+    dtype = jnp.dtype(dtype)
+    x = jax.ShapeDtypeStruct((1, 256, 2, 64), dtype)
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, causal=True, interpret=False)
+        return out.astype(jnp.float32).sum()
+
+    call = _kernel_calls(jax.grad(loss, argnums=(0, 1, 2)), x, x, x)[kernel]
+    body = call.params["jaxpr"]
+    scratch = body.invars[-call.params["grid_mapping"].num_scratch_operands:]
+    assert scratch and all(
+        ref.aval.dtype == jnp.float32 for ref in scratch
+    ), scratch
+    outs = call.params["out_avals"]
+    if kernel == "flash_fwd":
+        out, lse = outs
+        assert out.dtype == dtype
+        # compact and lane-major: one row of seq_q float32 a head
+        assert (lse.dtype, lse.shape) == (jnp.float32, (2, 1, 256))
+    else:
+        assert all(o.dtype == dtype for o in outs)
+    dots = [e for e in _eqns(body) if e.primitive.name == "dot_general"]
+    # two loop bodies a kernel (blocks the diagonal crosses, blocks it does
+    # not), each with the kernel's two, three or four products a piece
+    products = {"flash_fwd": 2, "flash_dq": 3, "flash_dkv": 4}[kernel]
+    assert len(dots) >= 2 * products and len(dots) % products == 0
+    for dot in dots:
+        assert [v.aval.dtype for v in dot.invars] == [dtype, dtype], dot
+        assert dot.outvars[0].aval.dtype == jnp.float32, dot
+        # no transposed left-hand side: nothing contracts a first axis
+        (lhs_contract, _), _ = dot.params["dimension_numbers"]
+        assert lhs_contract == (1,), dot
+    exps = [e for e in _eqns(body) if e.primitive.name == "exp"]
+    assert exps and all(
+        e.invars[0].aval.dtype == jnp.float32 for e in exps
+    )
+
+
+def _brute_force_plan(seq_q, seq_k, block_q, block_k):
+    live = masked = 0
+    for r0 in range(0, seq_q, block_q):
+        for c0 in range(0, seq_k, block_k):
+            seen = [
+                r >= c
+                for r in range(r0, r0 + block_q)
+                for c in range(c0, c0 + block_k)
+            ]
+            live += any(seen)
+            masked += any(seen) and not all(seen)
+    total = (seq_q // block_q) * (seq_k // block_k)
+    return live, masked, total - live
+
+
+@pytest.mark.parametrize(
+    "seq_q,seq_k,block_q,block_k",
+    [
+        (128, 128, 16, 16),
+        (128, 128, 32, 8),   # several k-blocks on the diagonal of a q-block
+        (128, 128, 8, 32),
+        (64, 128, 16, 16),   # seq_q != seq_k
+        (128, 64, 16, 32),
+        (2304, 2304, 384, 384),
+        (96, 96, 96, 96),    # one block
+        (96, 96, 1, 1),      # every diagonal block is wholly visible
+    ],
+)
+def test_flash_block_plan_matches_a_brute_force_count(
+    seq_q, seq_k, block_q, block_k
+):
+    from elasticdl_tpu.ops.attention import (
+        _q_blocks_visible,
+        flash_block_plan,
+    )
+
+    want = _brute_force_plan(seq_q, seq_k, block_q, block_k)
+    assert flash_block_plan(seq_q, seq_k, block_q, block_k, True) == want
+    total = (seq_q // block_q) * (seq_k // block_k)
+    assert flash_block_plan(seq_q, seq_k, block_q, block_k, False) == (
+        total, 0, 0
+    )
+    # dK/dV walks the same grid by columns, with bounds of its own
+    num_q = seq_q // block_q
+    live = masked = 0
+    for c0 in range(0, seq_k, block_k):
+        first, full_from = _q_blocks_visible(c0, block_k, 0, block_q, num_q)
+        assert 0 <= first <= full_from <= num_q
+        live += num_q - first
+        masked += full_from - first
+    assert (live, masked, total - live) == want
+
+
+def test_flash_block_plan_at_the_long_cell():
+    from elasticdl_tpu.ops.attention import flash_block_plan
+
+    assert flash_block_plan(8192, 8192, 512, 512, True) == (136, 16, 120)
+
+
+@pytest.mark.parametrize(
+    "block_q,block_k", [(32, 32), (64, 16), (16, 64), (32, 128), (128, 32)]
+)
+def test_flash_masks_every_block_the_diagonal_crosses(block_q, block_k):
+    """Scores of 6 * (column - row): huge above the diagonal, so one
+    unmasked element there takes a row's whole softmax, in the forward
+    and in each backward kernel."""
+    seq = 128
+    position = np.arange(seq, dtype=np.float32)
+    q = np.zeros((1, seq, 1, 8), np.float32)
+    k = np.zeros((1, seq, 1, 8), np.float32)
+    q[0, :, 0, 0], q[0, :, 0, 1] = 1.0, -position
+    k[0, :, 0, 0], k[0, :, 0, 1] = 6.0 * position, 6.0
+    rng = np.random.RandomState(5)
+    v = rng.randn(1, seq, 1, 8).astype(np.float32)
+    w = jnp.asarray(rng.randn(1, seq, 1, 8), jnp.float32)
+
+    def flash(q, k, v):
+        return flash_attention(
+            q, k, v, True, 1.0, block_q, block_k
+        )
+
+    def ref(q, k, v):
+        return mha_reference(q, k, v, causal=True, sm_scale=1.0)
+
+    got = (flash(q, k, v), *_weighted_grads(flash, w)(q, k, v))
+    want = (ref(q, k, v), *_weighted_grads(ref, w)(q, k, v))
+    # row r sees column r with weight ~1: nothing from above leaks in
+    np.testing.assert_allclose(
+        np.asarray(got[0]), v, atol=3e-2, rtol=0
+    )
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), atol=2e-3, rtol=2e-3
+        )
+
+
+@pytest.mark.parametrize("causal", [True, False])
+def test_flash_bf16_scale_that_is_no_power_of_two(causal):
+    """``d_head`` 128: 1/sqrt(128) is not exact in bf16, so the scale
+    meets the float32 scores, never a q rounded back to bf16.  On peaked
+    softmaxes (scores of tens) the bf16 result then stays within one bf16
+    unit, at the output's scale, of float32 arithmetic on the same
+    inputs; a scaled q rounded to bf16 is five such units out."""
+    rng = np.random.RandomState(9)
+    q, k, v = (
+        jnp.asarray(3.0 * rng.randn(1, 128, 2, 128), jnp.bfloat16)
+        for _ in range(3)
+    )
+    out = flash_attention(q, k, v, causal=causal, block_q=32, block_k=64)
+    ref = np.asarray(
+        mha_reference(
+            q.astype(jnp.float32),
+            k.astype(jnp.float32),
+            v.astype(jnp.float32),
+            causal=causal,
+        )
+    )
+    assert out.dtype == jnp.bfloat16
+    err = np.max(np.abs(np.asarray(out, np.float32) - ref))
+    assert err <= 2.0 ** -8 * np.max(np.abs(ref)), err
