@@ -1,6 +1,8 @@
 """Time the three flash kernels alone, on the chip, at one attention shape.
 
-A builder's tool (nothing under ``perf/`` imports it): it runs
+A builder's tool for tuning a kernel: its times are a kernel's alone, never
+a ledger number (``perf/run.py`` is the benchmark; nothing under ``perf/``
+imports this).  It runs
 ``flash_attention`` forward + gradients at ``--shape B,S,H,D`` in
 ``--dtype`` under the profiler and reads each kernel's device time from the
 trace by the name its ``pallas_call`` carries (``flash_fwd``, ``flash_dq``,

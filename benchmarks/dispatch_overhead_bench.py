@@ -1,4 +1,8 @@
-"""Measure the host-side dispatch overhead the device plane never sees:
+"""A drive of the three dispatch disciplines side by side, the only one in
+the repo; its CPU output is a count of gaps and stalls, not a ledger number
+(``perf/run.py`` is the benchmark), and it goes or stays with ROADMAP D2.
+
+Measures the host-side dispatch overhead the device plane never sees:
 the steady-state gap between dispatches WITHIN a task and the boundary
 stall BETWEEN tasks, across the three execution disciplines — serial,
 ``--device_prefetch``, and ``--device_prefetch --boundary_fusion``.

@@ -1,5 +1,9 @@
 """Accuracy-under-preemption gate (BASELINE.md config 5, conjunctive).
 
+An elastic-recovery drive on the host CPU: its output is a pass/fail and a
+count, not a ledger number (``perf/run.py`` is the benchmark); it stays
+until ROADMAP B2's cell replaces it.
+
 The reference's elastic acceptance is not "survives a kill" OR "reaches
 accuracy" — it is both at once: a worker preempted mid-run must not cost
 records (silently lost gradients) or double-train them (double-consumed
@@ -15,9 +19,8 @@ Prints ONE JSON line (schema unchanged since r3):
   {"accuracy": A, "records_ok": true, "reform_latency_secs": R,
    "threshold": 0.8, "pass": true}
 
-Run standalone: ``python benchmarks/preemption_accuracy_bench.py``.
-``bench.py`` invokes it in a ``JAX_PLATFORMS=cpu`` subprocess (the kill
-job must never touch the chip the throughput configs are timing).
+Run: ``python benchmarks/preemption_accuracy_bench.py``.  It pins itself
+to ``JAX_PLATFORMS=cpu``: the kill job never touches a chip.
 """
 
 from __future__ import annotations

@@ -1,4 +1,9 @@
-"""Elastic re-formation latency benchmark (BASELINE.md config 5).
+"""Elastic re-formation drive (BASELINE.md config 5).
+
+An elastic-recovery drive on the host CPU: it shows that a killed worker's
+world re-forms and loses no record; its seconds are a CPU's, not a ledger
+number (``perf/run.py`` is the benchmark).  It stays until ROADMAP B2's
+cell replaces it.
 
 A thin consumer of the chaos harness (``elasticdl_tpu.chaos.harness``):
 a real 2-process lockstep job on the host CPU backend runs under the
@@ -17,9 +22,8 @@ Prints ONE JSON line (schema unchanged since r3):
 - ``kill_to_step_secs``  — SIGKILL -> first post-re-form step pull (adds
   the heartbeat detection window, like the reference's k8s watch delay).
 
-Run standalone: ``python benchmarks/reform_bench.py``.  ``bench.py``
-invokes it in a subprocess with ``JAX_PLATFORMS=cpu`` so the measurement
-never touches the TPU chip the throughput configs are using.
+Run: ``python benchmarks/reform_bench.py``.  It pins itself to
+``JAX_PLATFORMS=cpu``: the kill job never touches a chip.
 """
 
 from __future__ import annotations

@@ -87,8 +87,8 @@ DATA_ALPHABET = 256
 STEPS_PER_TASK = 4
 
 SIZES = {
-    # transformer_gpt2s_seq2048 (bench.py): the full width of a model the
-    # repo supports; depth is the published 12 layers
+    # GPT-2-small's width and depth (12 x 768, 12 heads) at seq 2048: the
+    # full width of a model the repo supports
     "full": dict(
         platform="tpu",
         model_params=(
@@ -563,19 +563,20 @@ def _child_train(run, cfg, workdir):
         cache=cache_events.counts,
     )
     if cfg["platform"] == "tpu" and run == "train":
-        # the chip's device_kind must be in both peak tables (neither
-        # assumes a peak for an unknown kind)
-        import bench
+        # the chip's device_kind must be in both peak tables, the
+        # package's (the operator's goodput report) and the benchmark's,
+        # with one peak (neither assumes a peak for an unknown kind)
         from elasticdl_tpu.telemetry import anatomy
+        from perf.peaks import peaks_for  # raises for an unknown kind
 
-        report["peak_flops_known"] = {
-            "anatomy": anatomy.peak_flops_per_chip() is not None,
-            "bench": bench._peak_flops(devices[0]) is not None,
+        report["peak_flops"] = {
+            "anatomy": anatomy.peak_flops_per_chip(),
+            "perf": peaks_for(devices[0].device_kind)["bf16_flops_per_s"],
         }
-        if not all(report["peak_flops_known"].values()):
+        if report["peak_flops"]["anatomy"] != report["peak_flops"]["perf"]:
             failures.append(
-                f"device kind missing from a peak table: "
-                f"{report['peak_flops_known']}"
+                f"the package's peak table misses the device kind or "
+                f"disagrees with perf/peaks.json: {report['peak_flops']}"
             )
     if run == "cache":
         counts = cache_events.counts

@@ -25,7 +25,6 @@ from jax.sharding import Mesh, NamedSharding
 from elasticdl_tpu.ops.attention import attention_mesh_scope
 from elasticdl_tpu.parallel import elastic
 from elasticdl_tpu.parallel import sharding as sharding_lib
-from elasticdl_tpu.parallel.mesh import batch_divisor
 from elasticdl_tpu.telemetry import router_load
 from elasticdl_tpu.telemetry.anatomy import PHASE_H2D_TRANSFER, TIMELINE
 from elasticdl_tpu.trainer.state import TrainState
@@ -200,33 +199,6 @@ class SPMDTrainer:
             )
 
         return jax.tree_util.tree_map(_place, tree)
-
-    def pad_batch(self, tree):
-        """Pad the batch's leading dim up to a multiple of the data-axis
-        size (XLA needs equal shards; padded rows get zero loss weight is
-        the caller's concern — the worker pads only the final partial
-        batch of a task)."""
-        div = batch_divisor(self.mesh)
-
-        def _pad(x):
-            x = np.asarray(x)
-            rem = x.shape[0] % div
-            if rem == 0:
-                return x
-            pad = div - rem
-            return np.concatenate([x, np.repeat(x[-1:], pad, axis=0)], axis=0)
-
-        return jax.tree_util.tree_map(_pad, tree), div
-
-    def place_padded(self, tree):
-        """pad_batch + place_batch — the legacy minimal-padding feed for
-        host batches whose leading dim may not divide the data axes.
-        The runtimes' hot paths use :meth:`pad_to` + :meth:`row_mask`
-        instead (shape-canonical batching: ONE program shape per step
-        kind, padded rows exactly zero-weighted)."""
-        t0 = time.perf_counter_ns()
-        padded, _ = self.pad_batch(tree)
-        return _note_placed(t0, self._place_batch(padded))
 
     # ---- shape-canonical batching ------------------------------------------
     # THE canonical row count itself is a pure function of static config
@@ -416,6 +388,6 @@ def _host_slice_for_init(sample_features):
 
 
 def trim_pad(outputs, n: int):
-    """Drop the rows :meth:`SPMDTrainer.pad_batch` added for shard
-    divisibility (device arrays come back as host numpy)."""
+    """Drop the rows :meth:`SPMDTrainer.pad_to` added to reach the
+    canonical shape (device arrays come back as host numpy)."""
     return jax.tree_util.tree_map(lambda x: np.asarray(x)[:n], outputs)

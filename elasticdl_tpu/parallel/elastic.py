@@ -62,7 +62,7 @@ def resolve_compilation_cache_dir(flag_value: str | None = "") -> str | None:
 def configure_compilation_cache(cache_dir: str | None = ""):
     """Enable the persistent XLA compilation cache — THE one place, called
     unconditionally by every process that compiles (CLI/Local, workers
-    and standbys, serving replicas, bench.py, chip_smoke.py): a re-formed
+    and standbys, serving replicas, chip_smoke.py): a re-formed
     world or a re-run of the same job loads its executables from disk
     instead of recompiling.  Worker children inherit the choice (the
     environment, the forwarded flag, or the same package-relative

@@ -309,9 +309,8 @@ def _goodput_generation(anat_events: list[dict]) -> dict:
         else None,
         # live e2e-vs-roofline: the binding path's busy time (host
         # fetch wait vs the device path) over end-to-end wall — 1.0
-        # means zero overlap slack, the same meaning as bench.py's
-        # budget ratio but MEASURED per dispatch instead of inferred
-        # from separate ceiling runs
+        # means zero overlap slack, MEASURED per dispatch rather than
+        # inferred from separate ceiling runs
         "e2e_vs_roofline": round(
             max(host_ms, device_path_ms) / wall_ms, 4
         )

@@ -896,14 +896,15 @@ def run_pipelined_steps(
     post_group: Callable | None = None,
     dispatch_ctx: Callable | None = None,
     deterministic_auto: bool = False,
-    canonical_rows: int | None = None,
+    *,
+    canonical_rows: int,
     anatomy=None,
     pipeline_depth: int | None = None,
 ) -> int:
     """The ``--device_prefetch`` body of
     :func:`~elasticdl_tpu.trainer.stacking.run_stacked_steps`
-    (canonical-shape mode only — staging requires shapes that are pure
-    functions of config).  Same grouping policy, same hook cadence
+    (staging stands on the canonical shape: a pure function of
+    config).  Same grouping policy, same hook cadence
     (``pre_batch`` once per step before its group dispatches — the
     PreStacked precedent — ``post_group`` after every dispatch), same
     accounting; what changes is the execution discipline:
@@ -1030,7 +1031,8 @@ def run_pipelined_task_stream(
     post_group: Callable | None = None,
     dispatch_ctx: Callable | None = None,
     deterministic_auto: bool = False,
-    canonical_rows: int | None = None,
+    *,
+    canonical_rows: int,
     anatomy=None,
     task_start: Callable | None = None,
     task_done: Callable | None = None,

@@ -1,14 +1,18 @@
 """Shared ``--steps_per_dispatch`` grouping: k minibatches -> one scanned
 dispatch.
 
-THE one implementation of the grouping/ragged-tail policy, used by both
-runtimes (LocalExecutor and the lockstep worker) so their step semantics
-cannot drift: equal-shape batches are padded (the per-step path's
-``place_padded`` policy), stacked on a leading axis and run through
-``SPMDTrainer.train_steps_stacked``; a shape change (a task's ragged tail
-batch) or fewer than k leftovers fall back to single steps.  In lockstep
-worlds every process sees the same deterministic batch stream per task,
-so all processes compute the same grouping without communication.
+THE one implementation of the grouping/ragged-tail policy, used by every
+runtime (LocalExecutor, the lockstep worker and the task-stream worker)
+so their step semantics cannot drift.  Every batch is padded to the
+canonical row count (:func:`canonical_batch_rows`) and carries a
+zero/one row mask, so a task's ragged tail batch is one more group
+member, not a new input shape.  A full group of k >= 2 is stacked on a
+leading axis and run through ``SPMDTrainer.train_steps_stacked``; a
+trailing partial group (fewer than k leftovers) runs its members as
+single steps through the one compiled single-step program, never a new
+scan length.  In lockstep worlds every process sees the same
+deterministic batch stream per task, so all processes compute the same
+grouping without communication.
 """
 
 from __future__ import annotations
@@ -94,12 +98,8 @@ def probe_dispatch_overhead(trials: int = 3) -> float:
     """Seconds per dispatch of a trivial jitted op on FRESH input
     buffers (best-of-``trials`` to shed contention), UNCACHED — the
     overhead measurement itself.  Fresh inputs are what the training
-    path ships, so fresh inputs are what is timed.  bench.py uses this
-    directly to stamp the overhead around its measurement windows;
-    runtime callers want the cached
-    :func:`measured_dispatch_overhead`."""
-    import time
-
+    path ships, so fresh inputs are what is timed.  Callers want the
+    cached :func:`measured_dispatch_overhead`."""
     f = jax.jit(lambda x: x + 1)
     jax.device_get(f(np.zeros(256, np.float32)))  # compile
     best = float("inf")
@@ -281,7 +281,8 @@ def run_stacked_steps(
     post_group: Callable | None = None,
     dispatch_ctx: Callable | None = None,
     deterministic_auto: bool = False,
-    canonical_rows: int | None = None,
+    *,
+    canonical_rows: int,
     anatomy=None,
     device_prefetch: bool = False,
     pipeline_depth: int | None = None,
@@ -297,6 +298,20 @@ def run_stacked_steps(
     ``dispatch_ctx()``: context manager wrapping each device dispatch
     (timing buckets).
 
+    ``canonical_rows`` (required; the runtimes pass
+    :func:`canonical_batch_rows`): every batch is padded to that fixed
+    row count with a per-row zero/one weight mask threaded through the
+    jitted step, so a task's ragged tail batch is just another masked
+    group member instead of a new input shape.  The group never flushes
+    on a shape change, the program cache holds exactly two entries (the
+    weighted step + one scan-k variant), and in lockstep worlds every
+    process dispatches identical shapes by construction — a tail shape
+    disagreement cannot deadlock the collectives.  A trailing partial
+    group (fewer than k leftovers) runs its members through the
+    already-compiled single-step program rather than compiling a third
+    scan length.  A :class:`PreStacked` item in the stream is a
+    ready-made full group and dispatches as one.
+
     The host's timeline (telemetry/anatomy.py) is written on every
     path: ``host_fetch`` at the stream seam and ``step_bookkeeping``
     around the hooks, here; ``assemble``, ``h2d_transfer`` and
@@ -310,35 +325,18 @@ def run_stacked_steps(
     phases summing exactly to the group's wall time.  ``None`` (the
     default): ONE ``is None`` branch per dispatch, nothing blocks.
 
-    ``canonical_rows`` (the runtimes pass
-    :func:`canonical_batch_rows`): SHAPE-CANONICAL mode — every batch is
-    padded to that fixed row count with a per-row zero/one weight mask
-    threaded through the jitted step, so a task's ragged tail batch is
-    just another masked group member instead of a new input shape.  The
-    group never flushes on a shape change, the program cache holds
-    exactly two entries (the weighted step + one scan-k variant), and in
-    lockstep worlds every process dispatches identical shapes by
-    construction — a tail shape disagreement can no longer deadlock the
-    collectives.  A trailing partial group (fewer than k leftovers) runs
-    its members through the already-compiled single-step program rather
-    than compiling a third scan length.  ``None`` preserves the legacy
-    pad-to-divisor behavior (tails flush the group early).
-
     ``device_prefetch`` (the runtimes resolve ``--device_prefetch`` /
-    its forwarded env once at build): canonical-shape groups are
-    assembled and PLACED on a background staging thread while the
-    current group computes, and dispatch outputs retire one group
-    behind in a bounded window (trainer/device_pipeline.py) — same
-    grouping policy, same hook cadence, same accounting; the window is
-    drained before this function returns, so callers report tasks only
-    over retired groups.  Requires ``canonical_rows`` (staging buffers
-    must never change shape); ignored — one boolean branch, right here
-    — on the legacy path and when off.
+    its forwarded env once at build): groups are assembled and PLACED
+    on a background staging thread while the current group computes,
+    and dispatch outputs retire one group behind in a bounded window
+    (trainer/device_pipeline.py) — same grouping policy, same hook
+    cadence, same accounting; the window is drained before this
+    function returns, so callers report tasks only over retired groups.
 
     ``pipeline_depth`` (``--pipeline_depth``, default 2): the prefetch
     path's retire window / staging bound; unused on the serial path.
     """
-    if device_prefetch and canonical_rows is not None:
+    if device_prefetch:
         from elasticdl_tpu.trainer.device_pipeline import (
             run_pipelined_steps,
         )
@@ -362,49 +360,17 @@ def run_stacked_steps(
 
     ctx = dispatch_ctx or contextlib.nullcontext
     group: list = []
-    first_shape = None
     processed = 0
-    canonical = canonical_rows is not None
     # the timeline's seams (always on): the wait inside next(), and the
     # hooks; the blocking mode adds one wait per dispatch, below
     batches = TIMELINE.timed_fetches(batches)
     pre_batch = timed_hook(pre_batch)
     post_group = timed_hook(post_group)
 
-    def _flush_canonical():
+    def _retire(trainer, steps, n_records):
+        # what follows every dispatch group, stacked or singles
         nonlocal processed
-        if not group:
-            return
-        trainer = get_trainer()
-        note_boundary_dispatch()
-        steps = len(group)
-        n_records = sum(n for _f, _l, n in group)
-        kind, assembled = assemble_canonical_group(
-            trainer, group, k, canonical_rows
-        )
-        if kind == "stacked":
-            with ctx():
-                out = trainer.train_steps_stacked(
-                    trainer.place_stacked(assembled[0]),
-                    trainer.place_stacked(assembled[1]),
-                    trainer.place_stacked(assembled[2]),
-                )
-                if anatomy is not None:
-                    anatomy.ready_wait(out)
-        else:
-            # trailing partial group: k' single weighted steps through
-            # the one compiled program — never a scan-k' compile
-            for features, labels, mask in assembled:
-                with ctx():
-                    out = trainer.train_step(
-                        trainer.place_batch(features),
-                        trainer.place_batch(labels),
-                        trainer.place_batch(mask),
-                    )
-                    if anatomy is not None:
-                        anatomy.ready_wait(out)
         processed += n_records
-        group.clear()
         if post_group is not None:
             post_group()
         if anatomy is not None:
@@ -414,56 +380,50 @@ def run_stacked_steps(
                 step=getattr(trainer, "step", None),
             )
 
-    def _flush_legacy():
-        nonlocal processed
+    def _dispatch_stacked(trainer, features, labels, weights, n_records):
+        # THE stacked dispatch: an assembled plain group or a PreStacked
+        # item, k steps in one scan
+        with ctx():
+            out = trainer.train_steps_stacked(
+                trainer.place_stacked(features),
+                trainer.place_stacked(labels),
+                trainer.place_stacked(weights),
+            )
+            if anatomy is not None:
+                anatomy.ready_wait(out)
+        _retire(trainer, len(weights), n_records)  # weights: (k, rows)
+
+    def _flush():
         if not group:
             return
         trainer = get_trainer()
         note_boundary_dispatch()
-        n_records = sum(_batch_size(g[1]) for g in group)
-        if len(group) == 1:
-            features, labels = group[0]
-            with ctx():
-                trainer.train_step(
-                    trainer.place_padded(features),
-                    trainer.place_padded(labels),
-                )
-            processed += n_records
-        else:
-            padded = [
-                (trainer.pad_batch(f)[0], trainer.pad_batch(l)[0])
-                for f, l in group
-            ]
-            stacked_f = jax.tree_util.tree_map(
-                lambda *xs: np.stack(xs), *[p[0] for p in padded]
-            )
-            stacked_l = jax.tree_util.tree_map(
-                lambda *xs: np.stack(xs), *[p[1] for p in padded]
-            )
-            with ctx():
-                trainer.train_steps_stacked(
-                    trainer.place_stacked(stacked_f),
-                    trainer.place_stacked(stacked_l),
-                )
-            processed += n_records
-        steps = len(group)
+        n_records = sum(n for _f, _l, n in group)
+        kind, assembled = assemble_canonical_group(
+            trainer, group, k, canonical_rows
+        )
         group.clear()
-        if post_group is not None:
-            post_group()
-        if anatomy is not None:
-            # the legacy body does not block (the runtimes' hot paths
-            # are canonical): commit the spans its callees wrote, so
-            # none leaks into the next dispatch's window
-            anatomy.commit(steps=steps, records=n_records)
-
-    _flush = _flush_canonical if canonical else _flush_legacy
+        if kind == "stacked":
+            _dispatch_stacked(trainer, *assembled, n_records)
+            return
+        # trailing partial group: k' single weighted steps through the
+        # one compiled program — never a scan-k' compile
+        for features, labels, mask in assembled:
+            with ctx():
+                out = trainer.train_step(
+                    trainer.place_batch(features),
+                    trainer.place_batch(labels),
+                    trainer.place_batch(mask),
+                )
+                if anatomy is not None:
+                    anatomy.ready_wait(out)
+        _retire(trainer, len(assembled), n_records)
 
     for item in batches:
         if isinstance(item, PreStacked):
             # a ready-made group: flush any pending plain batches (they
             # must dispatch in stream order), then dispatch directly
             _flush()
-            first_shape = None
             if pre_batch is not None:
                 # one call per STEP, matching the plain path's hook
                 # cadence (profiler counts calls == steps)
@@ -471,29 +431,13 @@ def run_stacked_steps(
                     pre_batch(item.sample_features)
             trainer = get_trainer()
             note_boundary_dispatch()
-            with ctx():
-                if canonical:
-                    out = trainer.train_steps_stacked(
-                        trainer.place_stacked(item.features),
-                        trainer.place_stacked(item.labels),
-                        trainer.place_stacked(prestacked_weights(item)),
-                    )
-                else:
-                    out = trainer.train_steps_stacked(
-                        trainer.place_stacked(item.features),
-                        trainer.place_stacked(item.labels),
-                    )
-                if anatomy is not None:
-                    anatomy.ready_wait(out)
-            processed += item.num_records
-            if post_group is not None:
-                post_group()
-            if anatomy is not None:
-                anatomy.commit(
-                    steps=item.num_steps,
-                    records=item.num_records,
-                    step=getattr(trainer, "step", None),
-                )
+            _dispatch_stacked(
+                trainer,
+                item.features,
+                item.labels,
+                prestacked_weights(item),
+                item.num_records,
+            )
             continue
         features, labels = item
         if pre_batch is not None:
@@ -502,19 +446,8 @@ def run_stacked_steps(
             k = resolve_steps_per_dispatch(
                 k, (features, labels), deterministic=deterministic_auto
             )
-        if canonical:
-            group.append((features, labels, _batch_size(labels)))
-        else:
-            shape = jax.tree_util.tree_leaves(features)[0].shape
-            if first_shape is None:
-                first_shape = shape
-            if shape != first_shape:
-                # ragged tail batch: flush the group, start a fresh one
-                _flush()
-                first_shape = shape
-            group.append((features, labels))
+        group.append((features, labels, _batch_size(labels)))
         if len(group) == k:
             _flush()
-            first_shape = None
     _flush()
     return processed

@@ -674,3 +674,21 @@ def test_model_flops_table_and_peak_env(monkeypatch):
     assert anatomy.model_flops_per_record("unknown_model.custom") is None
     monkeypatch.setenv(anatomy.PEAK_FLOPS_ENV, "123.5")
     assert anatomy.peak_flops_per_chip() == 123.5
+
+
+def test_peak_table_agrees_with_the_benchmarks():
+    """Two peak tables with two owners (this package's goodput report,
+    the benchmark's ``perf/peaks.json``): one peak per device kind."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "perf", "peaks.json")) as f:
+        benchmark = {
+            kind: peaks["bf16_flops_per_s"]
+            for kind, peaks in json.load(f).items()
+            if isinstance(peaks, dict)
+        }
+    assert benchmark, "perf/peaks.json names no device kind"
+    ours = {
+        kind: anatomy._PEAK_FLOPS_BY_DEVICE_KIND.get(kind)
+        for kind in benchmark
+    }
+    assert ours == benchmark

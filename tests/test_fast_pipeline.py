@@ -556,21 +556,20 @@ def test_run_stacked_steps_dispatches_prestacked():
         def place_stacked(self, tree):
             return tree
 
-        def place_padded(self, tree):
+        def place_batch(self, tree):
             return tree
 
-        def pad_batch(self, tree):
-            return tree, 1
+        def pad_to(self, tree, rows):
+            return tree
 
-        def train_step(self, f, l):
+        def row_mask(self, n_real, rows):
+            return np.ones(rows, np.float32)
+
+        def train_step(self, f, l, mask):
             self.single += 1
 
-        def train_steps_stacked(self, f, l):
-            import jax
-
-            self.stacked.append(
-                jax.tree_util.tree_leaves(f)[0].shape[:2]
-            )
+        def train_steps_stacked(self, f, l, weights):
+            self.stacked.append(weights.shape)
 
     feats = {"x": np.zeros((4, 8, 3), np.float32)}
     labels = np.zeros((4, 8), np.int32)
@@ -586,6 +585,7 @@ def test_run_stacked_steps_dispatches_prestacked():
         4,
         pre_batch=lambda f: pre.append(1),
         post_group=lambda: post.append(1),
+        canonical_rows=8,
     )
     assert n == 32 + 5
     assert trainer.stacked == [(4, 8)]

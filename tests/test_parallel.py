@@ -257,10 +257,3 @@ class TestSPMDTrainer:
         preds = tr.predict_step(tr.place_batch(feats))
         assert np.asarray(preds).shape == (16, 10)
 
-    def test_pad_batch(self):
-        tr = self._trainer("dp=8")
-        feats, labels = _make_batch(13)
-        (pf, pl), div = tr.pad_batch((feats, labels))
-        assert div == 8
-        assert pl.shape[0] == 16
-        assert pf["image"].shape[0] == 16

@@ -1,6 +1,7 @@
 """The `--steps_per_dispatch auto` sizing rule (trainer/stacking.py)."""
 
 import numpy as np
+import pytest
 
 from elasticdl_tpu.trainer import stacking
 
@@ -78,31 +79,40 @@ def test_run_stacked_steps_resolves_auto(monkeypatch):
             self.stacked_calls = []
             self.single_calls = 0
 
-        def pad_batch(self, tree):
-            return tree, 1
+        def pad_to(self, tree, rows):
+            return tree
 
-        def place_padded(self, tree):
+        def row_mask(self, n_real, rows):
+            return np.ones(rows, np.float32)
+
+        def place_batch(self, tree):
             return tree
 
         def place_stacked(self, tree):
             return tree
 
-        def train_step(self, f, l):
+        def train_step(self, f, l, mask):
             self.single_calls += 1
 
-        def train_steps_stacked(self, f, l):
-            import jax
-
-            self.stacked_calls.append(
-                jax.tree_util.tree_leaves(f)[0].shape[0]
-            )
+        def train_steps_stacked(self, f, l, weights):
+            self.stacked_calls.append(weights.shape[0])
 
     # ~1.05MB batches (f32 features + f64 labels) -> auto k = 6
     batch = ({"x": np.zeros((256, 1024), np.float32)}, np.zeros(256))
     batches = [batch] * 26
     trainer = FakeTrainer()
-    n = stacking.run_stacked_steps(lambda: trainer, iter(batches), "auto")
+    n = stacking.run_stacked_steps(
+        lambda: trainer, iter(batches), "auto", canonical_rows=256
+    )
     assert n == 26 * 256
-    # four full groups + the 2-batch leftover group
-    assert trainer.stacked_calls == [6, 6, 6, 6, 2]
-    assert trainer.single_calls == 0
+    # four full groups; the 2-batch leftover runs as single steps, never
+    # a third scan length
+    assert trainer.stacked_calls == [6, 6, 6, 6]
+    assert trainer.single_calls == 2
+
+
+def test_run_stacked_steps_requires_canonical_rows():
+    """There is one grouping policy: a caller that omits the canonical
+    row count is refused at the call, not given another program."""
+    with pytest.raises(TypeError, match="canonical_rows"):
+        stacking.run_stacked_steps(lambda: None, iter([]), 1)

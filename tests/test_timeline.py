@@ -446,13 +446,12 @@ def test_kth_produce_batch_is_the_kth_host_fetch(local_run):
 
 
 def test_outermost_place_call_records_one_span():
-    """``place_padded`` and ``place_canonical`` go through the same
-    placement as ``place_batch`` and must not count twice."""
+    """``place_canonical`` goes through the same placement as
+    ``place_batch`` and must not count twice."""
     trainer = _tiny_trainer()
     batch = np.ones((3, 2), np.float32)
     for place in (
         trainer.place_batch,
-        trainer.place_padded,
         lambda t: trainer.place_canonical(t, 4),
         lambda t: trainer.place_stacked(np.stack([t, t])),
     ):
