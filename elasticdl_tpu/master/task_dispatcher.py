@@ -414,32 +414,34 @@ class TaskDispatcher:
             if assignment is None:
                 logger.warning("Unknown or already-reclaimed task id: %d", task_id)
                 from elasticdl_tpu.telemetry.compile_tracker import (
-                    COMPILE_COUNT_KEY,
+                    EXEC_COUNTERS,
                 )
 
-                if (
-                    exec_counters
-                    and COMPILE_COUNT_KEY in exec_counters
-                    and task_id not in self._reported_task_ids
-                ):
-                    # the compile counter is PROCESS-level, not
-                    # task-scoped: a stale (reclaimed-lease) report's
-                    # delta is still a real recompile, and the worker's
-                    # watermark advances on RPC success — dropping it
-                    # here would hide the recompile from the
-                    # elasticdl_compile_total mirror forever.  But a
-                    # DUPLICATE DELIVERY of an already-processed report
-                    # (network chaos: lost reply + re-execution) already
-                    # summed this exact delta on its first execution —
-                    # banking it again would double-count, so the
-                    # reported-ids memory gates the bank
+                process_level = {
+                    key: value
+                    for key, value in (exec_counters or {}).items()
+                    if key in EXEC_COUNTERS
+                }
+                if process_level and task_id not in self._reported_task_ids:
+                    # the compile counter (and the program store's three)
+                    # is PROCESS-level, not task-scoped: a stale
+                    # (reclaimed-lease) report's delta is still a real
+                    # recompile, and the worker's watermark advances on
+                    # RPC success — dropping it here would hide the
+                    # recompile from the elasticdl_compile_total mirror
+                    # forever.  But a DUPLICATE DELIVERY of an
+                    # already-processed report (network chaos: lost
+                    # reply + re-execution) already summed this exact
+                    # delta on its first execution — banking it again
+                    # would double-count, so the reported-ids memory
+                    # gates the bank
                     stale = self._counters.setdefault(
                         TaskType.TRAINING, JobCounters()
                     )
-                    stale.exec_metrics[COMPILE_COUNT_KEY] = (
-                        stale.exec_metrics.get(COMPILE_COUNT_KEY, 0)
-                        + exec_counters[COMPILE_COUNT_KEY]
-                    )
+                    for key, value in process_level.items():
+                        stale.exec_metrics[key] = (
+                            stale.exec_metrics.get(key, 0) + value
+                        )
                 # counted=False: a stale report was (correctly) dropped
                 self._notify(
                     "on_task_reported", task_id, None, success, False

@@ -23,7 +23,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding
 
 from elasticdl_tpu.ops.attention import attention_mesh_scope
-from elasticdl_tpu.parallel import elastic
+from elasticdl_tpu.parallel import elastic, program_store
 from elasticdl_tpu.parallel import sharding as sharding_lib
 from elasticdl_tpu.telemetry import router_load
 from elasticdl_tpu.telemetry.anatomy import PHASE_H2D_TRANSFER, TIMELINE
@@ -60,6 +60,7 @@ class SPMDTrainer:
         embedding_threshold: int | None = EMBEDDING_AUTO_DISTRIBUTE_BYTES,
         device_parse: Callable | None = None,
         donate_batch: bool = False,
+        job_identity: dict | None = None,
     ):
         """``embedding_threshold``: tables bigger than this many bytes are
         auto-distributed over the mesh (the reference's 2MB model-handler
@@ -72,10 +73,24 @@ class SPMDTrainer:
         re-read (the device-pipeline staging layer enforces single-take
         ownership).  Lockstep worlds must agree on this setting: it is
         part of the compiled program, and the enabling env is
-        master-forwarded so they always do."""
+        master-forwarded so they always do.
+
+        ``job_identity`` (``program_store.job_identity(args, module)``):
+        what of the job is a constant of this trainer's programs.  With
+        it, and with the program store switched on, the init program, the
+        train step and the stacked scan are loaded from the store instead
+        of traced (built and stored on a miss); without either, they are
+        plain ``jit`` programs."""
         self.mesh = mesh
 
         sample_features = _host_slice_for_init(sample_features)
+
+        def state_of(variables):
+            params = variables.get("params", {})
+            model_state = {
+                k: v for k, v in variables.items() if k != "params"
+            }
+            return TrainState.create(model.apply, params, tx, model_state)
 
         def create_state():
             init_features = (
@@ -83,19 +98,42 @@ class SPMDTrainer:
                 if device_parse is not None
                 else sample_features
             )
-            variables = model.init(
-                jax.random.PRNGKey(rng_seed), init_features, training=False
+            return state_of(
+                model.init(
+                    jax.random.PRNGKey(rng_seed), init_features, training=False
+                )
             )
-            params = variables.get("params", {})
-            model_state = {
-                k: v for k, v in variables.items() if k != "params"
-            }
-            return TrainState.create(model.apply, params, tx, model_state)
 
+        self._donate_batch = bool(donate_batch)
+        store = program_store.active()
+        self._programs = None
+        if store is not None and job_identity is not None:
+            self._programs = _StoredPrograms(
+                store,
+                mesh,
+                {
+                    **program_store.process_identity(job_identity, mesh),
+                    # baked into the init program
+                    "rng_seed": int(rng_seed),
+                    "sample_features": [
+                        [list(x.shape), str(x.dtype)]
+                        for x in jax.tree_util.tree_leaves(sample_features)
+                    ],
+                },
+            )
         # Shapes first (no FLOPs), then shard-aware materialization: the
         # state is *created* already laid out over the mesh, so no host
         # copy of a model bigger than one host's RAM is ever needed.
-        state_shapes = jax.eval_shape(create_state)
+        # With the program store on, the variables' shapes come from its
+        # note where it has one: tracing the model's init is then skipped
+        # too (what is left traces the optimizer's init alone).
+        state_shapes = None
+        if self._programs is not None:
+            state_shapes = self._programs.noted_state_shapes(state_of)
+        if state_shapes is None:
+            state_shapes = jax.eval_shape(create_state)
+            if self._programs is not None:
+                self._programs.note_state_shapes(state_shapes, state_of)
         if embedding_threshold is not None:
             from elasticdl_tpu.layers.embedding import auto_partition_rules
 
@@ -110,10 +148,26 @@ class SPMDTrainer:
         self.state_shardings = sharding_lib.specs_to_shardings(
             self.state_specs, mesh
         )
+        if self._programs is not None:
+            self._programs.identity["trainer"] = {
+                "compute_dtype": str(compute_dtype),
+                "remat": bool(remat),
+                "donate": bool(donate),
+                "donate_batch": self._donate_batch,
+                "state_shardings": [
+                    program_store.describe_sharding(s)
+                    for s in jax.tree_util.tree_leaves(self.state_shardings)
+                ],
+            }
+        init = jax.jit(create_state, out_shardings=self.state_shardings)
         with mesh, attention_mesh_scope(mesh):
-            self.state = jax.jit(
-                create_state, out_shardings=self.state_shardings
-            )()
+            if self._programs is not None:
+                # called once and dropped: a loaded program holds device
+                # memory for as long as it lives
+                init = self._programs.load_or_build(
+                    "init", init, (), jax.tree_util.tree_structure(state_shapes)
+                )
+            self.state = init()
         # an expert model's router counts stay in the state as device
         # arrays; router_load.read() fetches the newest on demand
         router_load.watch(self)
@@ -129,7 +183,6 @@ class SPMDTrainer:
 
         # the SAME builders LocalExecutor uses (trainer/step.py) — the only
         # SPMD addition is pinning the updated state to the mesh layout
-        self._donate_batch = bool(donate_batch)
         self._train_step = build_train_step(
             loss_fn,
             compute_dtype=compute_dtype,
@@ -255,16 +308,21 @@ class SPMDTrainer:
     @state.setter
     def state(self, value):
         # external assignment (checkpoint restore, re-formation): the
-        # host step mirror is unknown until read
+        # host step mirror is unknown until read, and so is which
+        # stored program the new leaves fit
         self._state = value
         self._step_cache = None
+        self._state_kind = None
 
     def train_step(self, features, labels, weights=None):
         # the timeline's ``enqueue``: mesh scope entry + the jitted call
         # returning (the device runs on); never a block
         t0 = time.perf_counter_ns()
         with self.mesh, attention_mesh_scope(self.mesh):
-            self._state, metrics = self._train_step(
+            step = self._stored(
+                "train_step", self._train_step, (features, labels, weights)
+            )
+            self._state, metrics = step(
                 self._state, features, labels, weights
             )
         TIMELINE.record_enqueue(t0, metrics)
@@ -314,23 +372,31 @@ class SPMDTrainer:
                 out_shardings=(self.state_shardings, None),
             )
             self._stacked_scan_cache[key] = scan_fn
+        batches = (stacked_features, stacked_labels)
+        if stacked_weights is not None:
+            batches += (stacked_weights,)
         t0 = time.perf_counter_ns()
         with self.mesh, attention_mesh_scope(self.mesh):
-            if stacked_weights is None:
-                self._state, metrics = scan_fn(
-                    self._state, stacked_features, stacked_labels
-                )
-            else:
-                self._state, metrics = scan_fn(
-                    self._state,
-                    stacked_features,
-                    stacked_labels,
-                    stacked_weights,
-                )
+            scan_fn = self._stored("train_steps_stacked", scan_fn, batches)
+            self._state, metrics = scan_fn(self._state, *batches)
         TIMELINE.record_enqueue(t0, metrics)
         if self._step_cache is not None:
             self._step_cache += int(num_steps)
         return jax.tree_util.tree_map(lambda m: m[-1], metrics)
+
+    def _stored(self, name: str, jitted, batch: tuple):
+        """``jitted`` itself, or, with the program store on, the stored
+        program of that name for the state and ``batch`` as they are
+        (shapes, dtypes, shardings): loaded, or built from ``jitted`` and
+        stored.  Both take ``(state, *batch)`` and give ``(state,
+        metrics)``."""
+        if self._programs is None:
+            return jitted
+        if self._state_kind is None:
+            self._state_kind = self._programs.kind_of(self._state)
+        return self._programs.for_call(
+            name, jitted, self._state, batch, self._state_kind
+        )
 
     def place_stacked(self, tree):
         """Place a (K, batch, ...) stacked tree: same layout as
@@ -370,6 +436,97 @@ class SPMDTrainer:
         if self._step_cache is None:
             self._step_cache = int(jax.device_get(self._state.step))
         return self._step_cache
+
+
+class _StoredPrograms:
+    """One trainer's programs through the program store: an identity
+    for each (no trace needed), and the loaded or built program kept per
+    call signature, as ``jit`` keeps its own."""
+
+    def __init__(self, store, mesh: Mesh, identity: dict):
+        self._store = store
+        self._devices = list(mesh.devices.flat)
+        # what every program of this trainer shares
+        self.identity = identity
+        self._kinds: dict = {}
+        self._programs: dict = {}
+
+    def noted_state_shapes(self, state_of):
+        """The state's shapes rebuilt from the store's note of the model's
+        variables (``state_of`` adds the optimizer's), else ``None``."""
+        description = self._store.read_note(
+            {**self.identity, "note": "variables"}
+        )
+        if description is None:
+            return None
+        return jax.eval_shape(
+            state_of, program_store.shapes_from(description)
+        )
+
+    def note_state_shapes(self, state_shapes, state_of):
+        """Keep the variables' paths, shapes and dtypes for the next
+        process, if the state can be rebuilt from them as it is."""
+        variables = {"params": state_shapes.params, **state_shapes.model_state}
+        description = program_store.describe_shapes(variables)
+        if description is None:
+            return
+        rebuilt = jax.eval_shape(
+            state_of, program_store.shapes_from(description)
+        )
+        if jax.tree_util.tree_flatten(rebuilt) == jax.tree_util.tree_flatten(
+            state_shapes
+        ):
+            self._store.write_note(
+                {**self.identity, "note": "variables"}, description
+            )
+
+    def kind_of(self, tree) -> int | None:
+        """A small number for the tree's structure with every leaf's
+        shape, dtype and sharding (``None`` when a leaf is no device
+        array: such a call stays on ``jit``)."""
+        leaves, treedef = jax.tree_util.tree_flatten(tree)
+        try:
+            kind = (
+                treedef,
+                tuple((x.shape, x.dtype, x.sharding) for x in leaves),
+            )
+        except AttributeError:
+            return None
+        return self._kinds.setdefault(kind, len(self._kinds))
+
+    def load_or_build(self, name, jitted, args, out_tree):
+        """The program ``jitted`` is for ``args``, through the store."""
+        identity = {
+            **self.identity,
+            "program": name,
+            "arguments": program_store.describe_arrays(args),
+        }
+        return self._store.get_or_build(
+            identity,
+            lambda: jitted.lower(*args),
+            jax.tree_util.tree_structure((args, {})),
+            out_tree,
+            self._devices,
+        )
+
+    def for_call(self, name, jitted, state, batch, state_kind):
+        """A step's program for this state and batch, kept per call
+        signature as ``jit`` keeps its own."""
+        key = (name, state_kind, self.kind_of(batch))
+        program = self._programs.get(key)
+        if program is None:
+            if None in key:
+                return jitted
+            program = self._programs[key] = self.load_or_build(
+                name,
+                jitted,
+                (state,) + batch,
+                # the updated state has the structure of the one handed
+                # in (a scan could not carry it otherwise), the metrics
+                # are build_train_step's
+                jax.tree_util.tree_structure((state, {"loss": 0})),
+            )
+        return program
 
 
 def _note_placed(start_ns: int, placed):

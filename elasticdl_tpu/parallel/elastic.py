@@ -66,7 +66,11 @@ def configure_compilation_cache(cache_dir: str | None = ""):
     world or a re-run of the same job loads its executables from disk
     instead of recompiling.  Worker children inherit the choice (the
     environment, the forwarded flag, or the same package-relative
-    default)."""
+    default).
+
+    The program store (parallel/program_store.py) is switched on here
+    too, in ``program_store/`` under the same directory: a trainer's init
+    program and train step are then loaded ahead of trace and lower."""
     resolved = resolve_compilation_cache_dir(cache_dir)
     if resolved is not None:
         jax.config.update("jax_compilation_cache_dir", resolved)
@@ -74,6 +78,15 @@ def configure_compilation_cache(cache_dir: str | None = ""):
     # small programs a test-size job re-forms over
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    from elasticdl_tpu.parallel import program_store
+
+    directory = jax.config.jax_compilation_cache_dir
+    if directory and jax.config.jax_enable_compilation_cache:
+        program_store.enable(
+            os.path.join(directory, program_store.DIRECTORY_NAME)
+        )
+    else:
+        program_store.disable()
 
 
 # ---- one process per chip ---------------------------------------------------

@@ -151,10 +151,29 @@ class MasterTelemetry:
         # compiled (this process + worker-reported exec-counter deltas);
         # steady state should be flat after warmup — see
         # telemetry/compile_tracker.py and scripts/compile_smoke.py
-        self._compiles = r.counter(
-            "elasticdl_compile_total",
-            "XLA backend compiles (master process + worker-reported)",
-        )
+        # beside it, the program store's traffic
+        # (parallel/program_store.py): a relaunched worker that hits
+        # loads its step instead of tracing it
+        from elasticdl_tpu.telemetry import compile_tracker
+
+        self._exec_counters = {
+            compile_tracker.COMPILE_COUNT_KEY: r.counter(
+                "elasticdl_compile_total",
+                "XLA backend compiles (master process + worker-reported)",
+            ),
+            compile_tracker.PROGRAM_STORE_HITS_KEY: r.counter(
+                "elasticdl_program_store_hits_total",
+                "Programs loaded from the program store",
+            ),
+            compile_tracker.PROGRAM_STORE_MISSES_KEY: r.counter(
+                "elasticdl_program_store_misses_total",
+                "Programs the program store had no entry for",
+            ),
+            compile_tracker.PROGRAM_STORE_REJECTS_KEY: r.counter(
+                "elasticdl_program_store_rejects_total",
+                "Program store entries found and refused",
+            ),
+        }
         # gray-failure RPC plane (rpc/stats.py ships the worker-side
         # totals by heartbeat; the dedup counters are master-observed)
         self._rpc_retries = r.counter(
@@ -251,15 +270,16 @@ class MasterTelemetry:
 
     def _collect(self, _registry):
         """Scrape-time refresh of point-in-time values."""
-        compiles = self._compile_tracker.compile_count()
+        # this process's totals, plus what the workers shipped
+        counted = {
+            key: read()
+            for key, read in self._compile_tracker.EXEC_COUNTERS.items()
+        }
         if self._task_d is not None:
             snap = self._task_d.snapshot()
             self._tasks_pending.set(snap["pending"] + snap["pending_eval"])
             self._tasks_active.set(len(snap["active"]))
             self._epoch.set(snap["epoch"])
-            from elasticdl_tpu.telemetry.compile_tracker import (
-                COMPILE_COUNT_KEY,
-            )
             from elasticdl_tpu.utils.constants import TaskType
 
             # workers ship compile deltas with EVERY report kind, so the
@@ -269,7 +289,8 @@ class MasterTelemetry:
             exec_metrics = {}
             for task_type in TaskType:
                 snapshot = self._task_d.exec_metrics_snapshot(task_type)
-                compiles += snapshot.get(COMPILE_COUNT_KEY, 0)
+                for key in counted:
+                    counted[key] += snapshot.get(key, 0)
                 if task_type == TaskType.TRAINING:
                     exec_metrics = snapshot
             for key, value in exec_metrics.items():
@@ -299,7 +320,8 @@ class MasterTelemetry:
                         ).set(status[f"{role}_watermark"])
         # set_total is monotone (max), so a re-formed generation's fresh
         # per-process counters can never walk the exposed total backward
-        self._compiles.set_total(compiles)
+        for key, total in counted.items():
+            self._exec_counters[key].set_total(total)
         if self._servicer is not None:
             self._workers_live.set(len(self._servicer.live_workers()))
             self._generation.set(self._servicer.cluster_version)

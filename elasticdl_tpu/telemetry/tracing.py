@@ -73,6 +73,7 @@ SPAN_REPLICA_PUSH = "replica_push"  # worker: snapshot + ring-neighbor push
 SPAN_REPLICA_HARVEST = "replica_harvest"  # master: fetch peer shards on reform
 SPAN_REPLICA_RESTORE = "replica_restore"  # worker: restore from peer RAM
 SPAN_COMPILE = "compile"  # any process: one XLA backend compile
+SPAN_PROGRAM_LOAD = "program_load"  # any process: one program store hit
 SPAN_MASTER_RESTART = "master_restart"  # master: restore start -> serving
 SPAN_JOURNAL_REPLAY = "journal_replay"  # master: journal replay proper
 SPAN_WORKER_REHOME = "worker_rehome"  # master: one re-home handshake

@@ -315,6 +315,7 @@ class Worker:
                 self._spec, getattr(self._args, "learning_rate", None)
             )
             compute_dtype = getattr(self._args, "compute_dtype", "float32")
+            from elasticdl_tpu.parallel import program_store
             from elasticdl_tpu.trainer.device_pipeline import (
                 resolve_donate_state,
             )
@@ -333,6 +334,9 @@ class Worker:
                 donate=resolve_donate_state(self._args),
                 device_parse=self._spec.device_parse,
                 donate_batch=self._device_prefetch,
+                job_identity=program_store.job_identity(
+                    self._args, self._spec.module
+                ),
             )
             version = restore_trainer_state(self._trainer, self._args)
         if version is not None:

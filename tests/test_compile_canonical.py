@@ -465,6 +465,107 @@ class TestCompileTracking:
         reporter.attach(third)
         assert compile_tracker.COMPILE_COUNT_KEY not in third
 
+    def test_program_store_counters_and_the_program_load_span(self, tmp_path):
+        """A hit is one program handed to the backend: it moves
+        ``compile_count`` and the ``compile`` span exactly as a miss's
+        compile does, with the load's seconds, and adds a hit and a
+        ``program_load`` span; a miss and a reject move only their own
+        counter."""
+        from elasticdl_tpu.telemetry import tracing
+
+        compile_tracker.install()
+        hits = compile_tracker.program_store_hits()
+        misses = compile_tracker.program_store_misses()
+        rejects = compile_tracker.program_store_rejects()
+        compiles = compile_tracker.compile_count()
+        secs = compile_tracker.compile_secs_total()
+        tracing.install(str(tmp_path), role="worker", sample_rate=1.0)
+        try:
+            compile_tracker.record_program_store_miss()
+            _unique_jit_compile()  # the miss's build
+            compile_tracker.record_program_store_reject()
+            compile_tracker.record_program_load(0.25)
+            tracing.flush()
+        finally:
+            tracing.uninstall()
+        assert compile_tracker.program_store_hits() == hits + 1
+        assert compile_tracker.program_store_misses() == misses + 1
+        assert compile_tracker.program_store_rejects() == rejects + 1
+        # the miss's compile and the hit's load: one each
+        assert compile_tracker.compile_count() == compiles + 2
+        assert compile_tracker.compile_secs_total() >= secs + 0.25
+        spans = tracing.read_spans(str(tmp_path / "spans.jsonl"))
+        by_name = {}
+        for span in spans:
+            by_name.setdefault(span.get("span"), []).append(span)
+        assert len(by_name[tracing.SPAN_COMPILE]) == 2
+        (load,) = by_name[tracing.SPAN_PROGRAM_LOAD]
+        assert load["end"] - load["start"] == pytest.approx(0.25, abs=1e-6)
+
+    def test_reporter_and_master_mirror_carry_the_program_store_counters(self):
+        """What carries ``compile_count`` to ``elasticdl_compile_total``
+        carries the store's three counters beside it: the reporter's
+        deltas, the dispatcher's bank of a stale report, the mirror."""
+        from elasticdl_tpu.master.task_dispatcher import TaskDispatcher
+        from elasticdl_tpu.telemetry.master_hooks import MasterTelemetry
+        from elasticdl_tpu.utils.constants import TaskType
+
+        compile_tracker.install()
+        reporter = compile_tracker.ExecCounterReporter()
+        compile_tracker.record_program_store_miss()
+        compile_tracker.record_program_store_reject()
+        compile_tracker.record_program_load(0.0)
+        compile_tracker.record_program_load(0.0)
+        shipped: dict = {}
+        mark = reporter.attach(shipped)
+        assert shipped == {
+            compile_tracker.COMPILE_COUNT_KEY: 2,
+            compile_tracker.PROGRAM_STORE_HITS_KEY: 2,
+            compile_tracker.PROGRAM_STORE_MISSES_KEY: 1,
+            compile_tracker.PROGRAM_STORE_REJECTS_KEY: 1,
+        }
+        reporter.commit(mark)
+        again: dict = {}
+        reporter.attach(again)
+        assert again == {}
+
+        dispatcher = TaskDispatcher(None)
+        dispatcher.report(999, True, exec_counters=dict(shipped))
+        banked = dispatcher.exec_metrics_snapshot(TaskType.TRAINING)
+        assert banked == shipped
+
+        class _Servicer:
+            cluster_version = 0
+
+            def add_version_observer(self, cb):
+                pass
+
+            def set_event_sink(self, cb):
+                pass
+
+            def set_trace_provider(self, cb):
+                pass
+
+            def live_workers(self):
+                return []
+
+        telemetry = MasterTelemetry()
+        telemetry.attach(dispatcher, _Servicer())
+        exposed = {
+            line.split()[0]: float(line.split()[-1])
+            for line in telemetry.registry.exposition().splitlines()
+            if line.startswith("elasticdl_program_store_")
+        }
+        # the workers' shipped deltas on top of this process's own totals
+        assert exposed == {
+            "elasticdl_program_store_hits_total": 2
+            + compile_tracker.program_store_hits(),
+            "elasticdl_program_store_misses_total": 1
+            + compile_tracker.program_store_misses(),
+            "elasticdl_program_store_rejects_total": 1
+            + compile_tracker.program_store_rejects(),
+        }
+
     def test_compile_metric_visible_without_dispatcher(self):
         from elasticdl_tpu.telemetry.master_hooks import MasterTelemetry
 
