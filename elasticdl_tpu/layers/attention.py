@@ -41,6 +41,9 @@ class MultiHeadSelfAttention(nn.Module):
     # before the split into heads (OLMoE: ``q_norm(q_proj(x))``)
     qk_norm: bool = False
     norm_eps: float = 1e-6
+    # > 0: the heads' own size, where it is not the embedding over the heads
+    # (nemotron_h: 32 heads of 128 beside an embedding of 2,688)
+    head_dim: int = 0
 
     @nn.compact
     def __call__(self, x, decode_pos=None):
@@ -50,11 +53,11 @@ class MultiHeadSelfAttention(nn.Module):
         required in decode mode — there is ONE position source of truth,
         not one per layer."""
         embed = x.shape[-1]
-        if embed % self.num_heads:
+        if not self.head_dim and embed % self.num_heads:
             raise ValueError(
                 f"embed dim {embed} not divisible by {self.num_heads} heads"
             )
-        head_dim = embed // self.num_heads
+        head_dim = self.head_dim or embed // self.num_heads
         kv_heads = self.num_kv_heads or self.num_heads
 
         def _proj(name, heads, norm=None):
@@ -144,7 +147,7 @@ class MultiHeadSelfAttention(nn.Module):
 
 
 NORMS = ("layernorm", "rmsnorm")
-MLPS = ("gelu", "swiglu")
+MLPS = ("gelu", "swiglu", "relu2")
 
 
 def make_norm(kind: str, epsilon: float, dtype):
@@ -155,10 +158,19 @@ def make_norm(kind: str, epsilon: float, dtype):
     return cls(epsilon=epsilon, dtype=dtype)
 
 
+# a layer of a ``layer_pattern`` (nemotron_h's ``hybrid_override_pattern``):
+# one mixer OR one feed-forward part under one pre-norm residual
+LAYER_KINDS = {
+    "*": "attention", "M": "mamba", "E": "experts", "-": "mlp",
+}
+
+
 class TransformerBlock(nn.Module):
     """The one pre-norm block.  Every field defaults to what GPT-2-small
     runs (LayerNorm, biases, a 4x GELU MLP, positions added by the model);
-    a published architecture is a choice of fields, not another block."""
+    a published architecture is a choice of fields, not another block.
+    With ``kind`` (a key of ``LAYER_KINDS``) the block is that one part
+    alone, ``x + part(norm(x))``: a hybrid stack's layer."""
 
     num_heads: int
     mlp_ratio: int = 4
@@ -173,9 +185,10 @@ class TransformerBlock(nn.Module):
     use_bias: bool = True
     rope_theta: float = 0.0  # > 0: rotary positions inside attention
     qk_norm: bool = False
-    mlp: str = "gelu"  # | "swiglu": down(silu(gate(x)) * up(x))
+    # | "swiglu": down(silu(gate(x)) * up(x)) | "relu2": down(relu(up(x))^2)
+    mlp: str = "gelu"
     mlp_width: int = 0  # 0: mlp_ratio x the embedding
-    # > 0 replaces the dense MLP with routed SwiGLU experts (layers.moe);
+    # > 0 replaces the dense MLP with routed experts (layers.moe);
     # shard experts over ep via moe_sharding_rules
     num_experts: int = 0
     experts_per_token: int = 2
@@ -183,14 +196,41 @@ class TransformerBlock(nn.Module):
     norm_topk_prob: bool = False
     router_aux_weight: float = 0.01
     router_z_weight: float = 0.001
+    kind: str = ""  # "": attention then feed-forward; else one of LAYER_KINDS
+    head_dim: int = 0  # 0: the embedding over the heads
+    # further fields of layers.moe.MoEMLP and layers.mamba.Mamba2Mixer, by
+    # their names there
+    moe_fields: Any = ()
+    mamba_fields: Any = ()
 
     @nn.compact
     def __call__(self, x, training: bool = False, decode_pos=None):
         if self.mlp not in MLPS:
             raise ValueError(f"unknown mlp {self.mlp!r}; valid: {MLPS}")
-        width = self.mlp_width or x.shape[-1] * self.mlp_ratio
-        y = make_norm(self.norm, self.norm_eps, self.dtype)(x)
-        y = MultiHeadSelfAttention(
+        if self.kind and self.kind not in LAYER_KINDS:
+            raise ValueError(
+                f"unknown layer kind {self.kind!r}; valid: {list(LAYER_KINDS)}"
+            )
+        parts = [LAYER_KINDS[self.kind]] if self.kind else [
+            "attention", "experts" if self.num_experts > 0 else "mlp"
+        ]
+        for part in parts:
+            x = self._residual(
+                x, getattr(self, "_" + part), training, decode_pos
+            )
+        return x
+
+    def _residual(self, x, part, training, decode_pos):
+        y = part(
+            make_norm(self.norm, self.norm_eps, self.dtype)(x),
+            training, decode_pos,
+        )
+        if self.dropout_rate:
+            y = nn.Dropout(self.dropout_rate, deterministic=not training)(y)
+        return x + y
+
+    def _attention(self, y, training, decode_pos):
+        return MultiHeadSelfAttention(
             num_heads=self.num_heads,
             causal=self.causal,
             num_kv_heads=self.num_kv_heads,
@@ -201,41 +241,54 @@ class TransformerBlock(nn.Module):
             rope_theta=self.rope_theta,
             qk_norm=self.qk_norm,
             norm_eps=self.norm_eps,
+            head_dim=self.head_dim,
             name="attn",
         )(y, decode_pos=decode_pos)
-        if self.dropout_rate:
-            y = nn.Dropout(self.dropout_rate, deterministic=not training)(y)
-        x = x + y
-        y = make_norm(self.norm, self.norm_eps, self.dtype)(x)
-        if self.num_experts > 0:
-            from elasticdl_tpu.layers.moe import MoEMLP
 
-            y = MoEMLP(
-                num_experts=self.num_experts,
-                experts_per_token=self.experts_per_token,
-                expert_width=self.expert_width or width,
-                norm_topk_prob=self.norm_topk_prob,
-                aux_loss_weight=self.router_aux_weight,
-                z_loss_weight=self.router_z_weight,
-                dtype=self.dtype,
-                name="moe",
-            )(y, training=training)
+    def _mamba(self, y, training, decode_pos):
+        from elasticdl_tpu.layers.mamba import Mamba2Mixer
+
+        if self.decode:
+            raise NotImplementedError(
+                "decoding through a Mamba-2 layer's state is not built"
+            )
+        return Mamba2Mixer(
+            norm_eps=self.norm_eps, dtype=self.dtype, name="mamba",
+            **dict(self.mamba_fields),
+        )(y)
+
+    def _experts(self, y, training, decode_pos):
+        from elasticdl_tpu.layers.moe import MoEMLP
+
+        width = self.mlp_width or y.shape[-1] * self.mlp_ratio
+        return MoEMLP(
+            num_experts=self.num_experts,
+            experts_per_token=self.experts_per_token,
+            expert_width=self.expert_width or width,
+            norm_topk_prob=self.norm_topk_prob,
+            aux_loss_weight=self.router_aux_weight,
+            z_loss_weight=self.router_z_weight,
+            dtype=self.dtype,
+            name="moe",
+            **dict(self.moe_fields),
+        )(y, training=training)
+
+    def _mlp(self, y, training, decode_pos):
+        width = self.mlp_width or y.shape[-1] * self.mlp_ratio
+
+        # named for the shared megatron tp rules (default_tp_rules)
+        def dense(features, name):
+            return nn.Dense(
+                features, dtype=self.dtype, use_bias=self.use_bias, name=name
+            )
+
+        if self.mlp == "swiglu":
+            hidden = nn.silu(dense(width, "mlp_gate")(y)) * dense(width, "mlp_up")(y)
+        elif self.mlp == "relu2":
+            hidden = jnp.square(nn.relu(dense(width, "mlp_up")(y)))
         else:
-            # named for the shared megatron tp rules (default_tp_rules)
-            def dense(features, name):
-                return nn.Dense(
-                    features, dtype=self.dtype, use_bias=self.use_bias,
-                    name=name,
-                )
-
-            if self.mlp == "swiglu":
-                y = nn.silu(dense(width, "mlp_gate")(y)) * dense(width, "mlp_up")(y)
-            else:
-                y = nn.gelu(dense(width, "mlp_up")(y))
-            y = dense(x.shape[-1], "mlp_down")(y)
-        if self.dropout_rate:
-            y = nn.Dropout(self.dropout_rate, deterministic=not training)(y)
-        return x + y
+            hidden = nn.gelu(dense(width, "mlp_up")(y))
+        return dense(y.shape[-1], "mlp_down")(hidden)
 
 
 def rope(x, positions, theta: float):

@@ -1,0 +1,136 @@
+"""The Mamba-2 mixer (Dao & Gu, arXiv:2405.21060) as NVIDIA's ``nemotron_h``
+stack runs it (HF ``modeling_nemotron_h.py``, ``NemotronHMamba2Mixer``)::
+
+    [z | xBC | dt] = W_in u                 widths d_in | d_in + 2 G N | H
+    xBC = silu(causal depthwise conv_k(xBC) + b)
+    x, B, C = split(xBC)                    x: H heads of P; B, C: G groups of N
+    dt = softplus(dt + dt_bias)             A = -exp(A_log), a scalar a head
+    h_t = exp(dt_t A) h_{t-1} + dt_t x_t (x) B_t;   y_t = C_t . h_t + D x_t
+    y = RMSNorm_groups(y * silu(z)) * w     the gate BEFORE the norm, the mean
+                                            square within each of the G groups
+    out = W_out y
+
+``d_in = H * P`` is given by the heads, not by an expansion factor.  No bias
+but the convolution's.  The recurrence is ``ops/ssd.py``'s chunked scan; the
+convolution is ``k`` shifted multiply-adds that XLA fuses into one pass.
+``dt``, ``A`` and the norm's statistics are float32 whatever ``dtype`` says.
+
+No reference counterpart; listed in DEVIATIONS.md additions.
+"""
+
+from __future__ import annotations
+
+import math
+from typing import Any
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from elasticdl_tpu.ops import ssd as ssd_ops
+
+
+def _a_log_init(key, shape, dtype=jnp.float32):
+    """``A`` uniform in [1, 16] (Mamba-2's ``A_init_range``)."""
+    return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
+
+
+def _dt_bias_init(low: float, high: float, floor: float):
+    """``softplus(dt_bias)`` log-uniform in [``low``, ``high``], not under
+    ``floor`` (``time_step_min`` / ``_max`` / ``_floor``)."""
+
+    def init(key, shape, dtype=jnp.float32):
+        dt = jnp.exp(
+            jax.random.uniform(key, shape, dtype)
+            * (math.log(high) - math.log(low))
+            + math.log(low)
+        )
+        dt = jnp.maximum(dt, floor)
+        return dt + jnp.log(-jnp.expm1(-dt))  # softplus's inverse
+
+    return init
+
+
+def causal_conv(x, kernel, bias):
+    """Depthwise convolution along time that sees the present and the
+    ``k - 1`` steps before it: ``x`` (batch, T, channels), ``kernel`` (k,
+    channels).  Float32 sums, ``x``'s dtype out."""
+    taps, steps = kernel.shape[0], x.shape[1]
+    padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
+    out = bias.astype(jnp.float32)
+    for tap in range(taps):
+        out = out + padded[:, tap:tap + steps].astype(jnp.float32) * kernel[
+            tap
+        ].astype(jnp.float32)
+    return out.astype(x.dtype)
+
+
+def gated_group_norm(y, z, scale, groups: int, eps: float):
+    """``RMSNorm(y * silu(z)) * scale`` with the mean square taken within
+    each of ``groups`` equal parts of the channels; float32 inside."""
+    gated = y.astype(jnp.float32) * nn.silu(z.astype(jnp.float32))
+    parts = gated.reshape(*gated.shape[:-1], groups, -1)
+    parts = parts * jax.lax.rsqrt(
+        jnp.mean(jnp.square(parts), axis=-1, keepdims=True) + eps
+    )
+    return (parts.reshape(gated.shape) * scale.astype(jnp.float32)).astype(y.dtype)
+
+
+class Mamba2Mixer(nn.Module):
+    num_heads: int
+    head_dim: int
+    groups: int = 1
+    state_size: int = 128
+    conv_kernel: int = 4
+    chunk: int = 128
+    norm_eps: float = 1e-5
+    dt_min: float = 0.001
+    dt_max: float = 0.1
+    dt_floor: float = 1e-4
+    dtype: Any = None  # compute dtype; params stay f32
+
+    @nn.compact
+    def __call__(self, u):
+        """u: (batch, T, embed) -> (batch, T, embed)."""
+        heads, groups, states = self.num_heads, self.groups, self.state_size
+        inner = heads * self.head_dim
+        conv_width = inner + 2 * groups * states
+        projected = nn.Dense(
+            inner + conv_width + heads, use_bias=False, dtype=self.dtype,
+            name="in_proj",
+        )(u)
+        z, xbc, dt = jnp.split(projected, [inner, inner + conv_width], axis=-1)
+        with jax.named_scope("mamba_conv"):
+            xbc = nn.silu(causal_conv(
+                xbc,
+                self.param(
+                    "conv_kernel", nn.initializers.lecun_normal(),
+                    (self.conv_kernel, conv_width),
+                ),
+                self.param("conv_bias", nn.initializers.zeros, (conv_width,)),
+            ))
+        x, b, c = jnp.split(xbc, [inner, inner + groups * states], axis=-1)
+        dt_bias = self.param(
+            "dt_bias",
+            _dt_bias_init(self.dt_min, self.dt_max, self.dt_floor), (heads,),
+        )
+        a_log = self.param("A_log", _a_log_init, (heads,))
+        d = self.param("D", nn.initializers.ones, (heads,))
+        batch, steps = u.shape[:2]
+        with jax.named_scope("ssd_scan"):
+            y = ssd_ops.ssd_scan(
+                x.reshape(batch, steps, heads, self.head_dim),
+                nn.softplus(dt.astype(jnp.float32) + dt_bias),
+                -jnp.exp(a_log.astype(jnp.float32)),
+                b.reshape(batch, steps, groups, states),
+                c.reshape(batch, steps, groups, states),
+                d, chunk=self.chunk,
+            )
+        y = gated_group_norm(
+            y.reshape(batch, steps, inner), z,
+            self.param("norm_scale", nn.initializers.ones, (inner,)),
+            groups, self.norm_eps,
+        )
+        return nn.Dense(
+            u.shape[-1], use_bias=False, dtype=self.dtype, name="out_proj"
+        )(y)

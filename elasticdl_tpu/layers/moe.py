@@ -1,5 +1,5 @@
-"""Routed expert MLP: top-k softmax routing that drops no token, SwiGLU
-experts, a grouped matmul over the (token, slot) pairs sorted by expert.
+"""Routed expert MLP: top-k routing that drops no token, a grouped matmul
+over the (token, slot) pairs sorted by expert.
 
 The equations are OLMoE's (Muennighoff et al., arXiv:2409.02060; HF
 ``OlmoeSparseMoeBlock``): ``p = softmax(x W_r)`` in float32 over all
@@ -9,6 +9,23 @@ combine weights (divided by their sum only with ``norm_topk_prob``),
 losses join the training loss through the ``losses`` collection
 (``trainer/step.py::forward_loss``): the load-balance loss
 ``E * sum_e f_e P_e`` and the router z-loss ``mean(logsumexp(logits)^2)``.
+
+The fields also spell the DeepSeek-V3 / ``nemotron_h`` router (HF
+``NemotronHTopkRouter``): ``scoring="sigmoid"`` scores each expert alone;
+with ``selection_bias`` the ``k`` experts are the largest of ``score +
+bias`` while their weights are the scores without it, and the bias, a buffer
+outside the gradient (collection ``router_stats``), moves by
+``selection_bias_rate`` a step towards the experts that got fewer pairs than
+the mean (Wang et al., arXiv:2408.15664); ``routed_scaling`` multiplies the
+weights; ``expert_kind="relu2"`` makes an expert ``down(relu(up(x))^2)``,
+two stacks through the same grouped matmul; ``shared_width`` adds one such
+expert that every token passes.
+
+``experts_held`` of the ``num_experts`` the router scores, from
+``first_expert`` on, are the ones this deployment holds: one chip's share of
+a layer whose experts lie on several.  Their part of the result is computed
+through the path ``ep`` uses (a pair of another expert gets no row); what the
+absent experts would add is left out, and no code stands in for their chips.
 
 Dispatch has one path and static shapes at any imbalance
 (``ops/grouped_matmul.py``): a stable sort of the pairs by expert, each
@@ -103,16 +120,17 @@ _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
 def routed_experts(
-    x, top_experts, weights, w_gate, w_up, w_down, *, first_expert=0,
+    x, top_experts, weights, *stacks, first_expert=0,
     tile_rows: int = gmm_ops.TILE_ROWS, interpret: bool | None = None,
 ):
     """The experts' part on one device: ``x`` (tokens, d), ``top_experts``
-    and ``weights`` (tokens, k), the three weight stacks of the
-    ``w_gate.shape[0]`` experts that start at ``first_expert``.  A pair
-    whose expert is not among them adds nothing here.  Returns the output
-    and the number of pairs that were given a row."""
+    and ``weights`` (tokens, k), the weight ``stacks`` of the
+    ``stacks[0].shape[0]`` experts that start at ``first_expert`` — three
+    (gate, up, down) for SwiGLU experts, two (up, down) for relu^2 ones.  A
+    pair whose expert is not among them adds nothing here.  Returns the
+    output and the number of pairs that were given a row."""
     tokens, slots = top_experts.shape
-    experts = w_gate.shape[0]
+    experts = stacks[0].shape[0]
     local = top_experts - first_expert
     grouped = (local >= 0) & (local < experts)
     layout = gmm_ops.group_layout(
@@ -126,7 +144,12 @@ def routed_experts(
         tile_rows=tile_rows, interpret=interpret,
     )
     rows = _dispatch(x, row_token, pair_row, grouped)
-    hidden = nn.silu(matmul(rows, w_gate)) * matmul(rows, w_up)
+    if len(stacks) == 3:
+        w_gate, w_up, w_down = stacks
+        hidden = nn.silu(matmul(rows, w_gate)) * matmul(rows, w_up)
+    else:
+        w_up, w_down = stacks
+        hidden = jnp.square(nn.relu(matmul(rows, w_up)))
     out = matmul(hidden, w_down)
     y = _combine(
         out, jnp.where(grouped, weights, 0.0), pair_row, layout.row_pair
@@ -134,10 +157,11 @@ def routed_experts(
     return y, jnp.sum(layout.row_pair < tokens * slots, dtype=jnp.int32)
 
 
-def _experts_on_mesh(x, top_experts, weights, w_gate, w_up, w_down):
+def _experts_on_mesh(x, top_experts, weights, stacks, first_expert=0):
     """``routed_experts`` under the registered mesh: tokens stay on their
-    batch (and sequence) axes, experts shard over ``ep``, and a compiled
-    Pallas kernel, which GSPMD cannot partition, runs per device."""
+    batch (and sequence) axes, the held experts (``stacks``, from
+    ``first_expert`` on) shard over ``ep``, and a compiled Pallas kernel,
+    which GSPMD cannot partition, runs per device."""
     from jax.sharding import PartitionSpec as P
 
     from elasticdl_tpu.ops.ring_attention import sequence_shard_spec
@@ -154,11 +178,14 @@ def _experts_on_mesh(x, top_experts, weights, w_gate, w_up, w_down):
         return y.reshape(x.shape), held
 
     if mesh is None:
-        return local(x, top_experts, weights, w_gate, w_up, w_down)
+        return local(
+            x, top_experts, weights, *stacks, first_expert=first_expert
+        )
     interpret = kernel_interpret(mesh.devices.flat[0].platform)
     if mesh.devices.size == 1 or jax.sharding.get_abstract_mesh().manual_axes:
         return local(
-            x, top_experts, weights, w_gate, w_up, w_down, interpret=interpret
+            x, top_experts, weights, *stacks, first_expert=first_expert,
+            interpret=interpret,
         )
     sharded_seq = (
         sp_axis in mesh.axis_names
@@ -170,9 +197,10 @@ def _experts_on_mesh(x, top_experts, weights, w_gate, w_up, w_down):
     )
     tokens = P(tokens[0], tokens[1], None)
     ep = "ep" if mesh.shape.get("ep", 1) > 1 else None
-    if ep and w_gate.shape[0] % mesh.shape[ep]:
+    if ep and stacks[0].shape[0] % mesh.shape[ep]:
         raise ValueError(
-            f"{w_gate.shape[0]} experts do not divide over ep={mesh.shape[ep]}"
+            f"{stacks[0].shape[0]} experts do not divide over "
+            f"ep={mesh.shape[ep]}"
         )
     experts = P(ep, None, None)
 
@@ -184,10 +212,12 @@ def _experts_on_mesh(x, top_experts, weights, w_gate, w_up, w_down):
         for axis in ((entry,) if isinstance(entry, str) else entry)
     )
 
-    def per_device(x, top_experts, weights, w_gate, w_up, w_down):
-        first = jax.lax.axis_index(ep) * w_gate.shape[0] if ep else 0
+    def per_device(x, top_experts, weights, *stacks):
+        first = first_expert
+        if ep:
+            first += jax.lax.axis_index(ep) * stacks[0].shape[0]
         y, held = local(
-            x, top_experts, weights, w_gate, w_up, w_down,
+            x, top_experts, weights, *stacks,
             first_expert=first, interpret=interpret,
         )
         return (
@@ -198,15 +228,19 @@ def _experts_on_mesh(x, top_experts, weights, w_gate, w_up, w_down):
     return jax.shard_map(
         per_device,
         mesh=mesh,
-        in_specs=(tokens, tokens, tokens, experts, experts, experts),
+        in_specs=(tokens, tokens, tokens) + (experts,) * len(stacks),
         out_specs=(tokens, P()),
         check_vma=False,
-    )(x, top_experts, weights, w_gate, w_up, w_down)
+    )(x, top_experts, weights, *stacks)
+
+
+SCORINGS = ("softmax", "sigmoid")
+EXPERT_KINDS = ("swiglu", "relu2")
 
 
 class MoEMLP(nn.Module):
     """Drop-in MLP replacement: ``experts_per_token`` of ``num_experts``
-    SwiGLU experts of width ``expert_width`` a token, none dropped."""
+    experts of width ``expert_width`` a token, none dropped."""
 
     num_experts: int
     experts_per_token: int = 2
@@ -215,15 +249,34 @@ class MoEMLP(nn.Module):
     aux_loss_weight: float = 0.01
     z_loss_weight: float = 0.001
     dtype: Any = None
+    scoring: str = "softmax"  # | "sigmoid"
+    selection_bias: bool = False
+    selection_bias_rate: float = 0.001
+    routed_scaling: float = 1.0
+    expert_kind: str = "swiglu"  # | "relu2": down(relu(up(x))^2)
+    shared_width: int = 0  # > 0: one expert of this width on every token
+    experts_held: int = 0  # 0: all of them
+    first_expert: int = 0
 
     @nn.compact
     def __call__(self, x, training: bool = False):
         embed = x.shape[-1]
         width = self.expert_width or 4 * embed
+        held = self.experts_held or self.num_experts
         if not 0 < self.experts_per_token <= self.num_experts:
             raise ValueError(
                 f"experts_per_token={self.experts_per_token} of "
                 f"{self.num_experts} experts"
+            )
+        if self.scoring not in SCORINGS or self.expert_kind not in EXPERT_KINDS:
+            raise ValueError(
+                f"scoring {self.scoring!r} of {SCORINGS}, expert_kind "
+                f"{self.expert_kind!r} of {EXPERT_KINDS}"
+            )
+        if not 0 <= self.first_expert <= self.num_experts - held:
+            raise ValueError(
+                f"experts {self.first_expert}..{self.first_expert + held} "
+                f"of {self.num_experts}"
             )
         # the router in float32 at full precision: a near-tie between two
         # experts is decided as a float32 reference decides it
@@ -231,10 +284,26 @@ class MoEMLP(nn.Module):
             self.num_experts, use_bias=False, name="router",
             precision=jax.lax.Precision.HIGHEST,
         )(x.astype(jnp.float32))
-        probs = jax.nn.softmax(logits, axis=-1)
-        weights, top_experts = jax.lax.top_k(probs, self.experts_per_token)
+        if self.scoring == "sigmoid":
+            scores = jax.nn.sigmoid(logits)
+            probs = scores / jnp.sum(scores, axis=-1, keepdims=True)
+        else:
+            scores = probs = jax.nn.softmax(logits, axis=-1)
+        if self.selection_bias:
+            bias = self.variable(
+                ROUTER_STATS, "selection_bias",
+                lambda: jnp.zeros((self.num_experts,), jnp.float32),
+            )
+            _, top_experts = jax.lax.top_k(
+                scores + bias.value, self.experts_per_token
+            )
+            weights = jnp.take_along_axis(scores, top_experts, axis=-1)
+        else:
+            weights, top_experts = jax.lax.top_k(scores, self.experts_per_token)
         if self.norm_topk_prob:
             weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+        if self.routed_scaling != 1.0:
+            weights = weights * self.routed_scaling
 
         # pairs per expert over tokens (sum_e f_e = k, as HF counts it)
         chosen = jax.nn.one_hot(top_experts, self.num_experts, dtype=jnp.float32)
@@ -243,39 +312,66 @@ class MoEMLP(nn.Module):
         router_prob = probs.reshape(-1, self.num_experts).mean(axis=0)
         balance = self.num_experts * jnp.sum(counts / tokens * router_prob)
         z = jnp.mean(jnp.square(jax.nn.logsumexp(logits, axis=-1)))
-        for name, value in (
-            ("moe_load_balance", self.aux_loss_weight * balance),
-            ("moe_router_z", self.z_loss_weight * z),
+        for name, weight, value in (
+            ("moe_load_balance", self.aux_loss_weight, balance),
+            ("moe_router_z", self.z_loss_weight, z),
         ):
-            self.sow(
-                "losses", name, value,
-                init_fn=lambda: jnp.zeros((), jnp.float32),
-                reduce_fn=lambda _prev, new: new,
+            if weight:
+                self.sow(
+                    "losses", name, weight * value,
+                    init_fn=lambda: jnp.zeros((), jnp.float32),
+                    reduce_fn=lambda _prev, new: new,
+                )
+        if (
+            self.selection_bias and training and not self.is_initializing()
+            and self.is_mutable_collection(ROUTER_STATS)
+        ):
+            # outside the gradient: towards the experts under the mean load
+            bias.value = bias.value + self.selection_bias_rate * jnp.sign(
+                jnp.mean(counts) - counts
             )
 
-        shape = (self.num_experts, embed, width)
-        w_gate = self.param("w_gate", _expert_init, shape)
-        w_up = self.param("w_up", _expert_init, shape)
-        w_down = self.param(
-            "w_down", _expert_init, (self.num_experts, width, embed)
-        )
+        names = ("w_gate", "w_up") if self.expert_kind == "swiglu" else ("w_up",)
+        stacks = [
+            self.param(name, _expert_init, (held, embed, width)) for name in names
+        ] + [self.param("w_down", _expert_init, (held, width, embed))]
         if self.dtype is not None:
             x = x.astype(self.dtype)
         y, rows_held = _experts_on_mesh(
-            x, top_experts, weights, w_gate, w_up, w_down
+            x, top_experts, weights, stacks, self.first_expert
         )
+        if self.shared_width:
+            y = y + self._shared_expert(x)
         # what telemetry/router_load.py reads on demand; the dispatch's own
         # count of rows beside the router's says that no pair was dropped
-        for name, value in (
-            ("expert_counts", counts.astype(jnp.int32)),
-            ("rows_held", rows_held),
-        ):
+        stats = {"expert_counts": counts.astype(jnp.int32), "rows_held": rows_held}
+        if held < self.num_experts:
+            # pairs of experts that other chips hold: left out, not dropped
+            last = self.first_expert + held
+            stats["absent_pairs"] = jnp.sum(
+                counts[: self.first_expert], dtype=jnp.int32
+            ) + jnp.sum(counts[last:], dtype=jnp.int32)
+        for name, value in stats.items():
             self.sow(
                 ROUTER_STATS, name, jax.lax.stop_gradient(value),
                 init_fn=lambda value=value: jnp.zeros_like(value),
                 reduce_fn=lambda _prev, new: new,
             )
         return y
+
+    def _shared_expert(self, x):
+        def dense(features, name):
+            return nn.Dense(
+                features, use_bias=False, dtype=self.dtype, name=name
+            )
+
+        if self.expert_kind == "swiglu":
+            hidden = nn.silu(dense(self.shared_width, "shared_gate")(x)) * dense(
+                self.shared_width, "shared_up"
+            )(x)
+        else:
+            hidden = jnp.square(nn.relu(dense(self.shared_width, "shared_up")(x)))
+        return dense(x.shape[-1], "shared_down")(hidden)
 
 
 def moe_sharding_rules():

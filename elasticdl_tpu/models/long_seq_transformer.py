@@ -24,6 +24,7 @@ import optax
 
 from elasticdl_tpu.data.reader import decode_example
 from elasticdl_tpu.layers.attention import (
+    LAYER_KINDS,
     TransformerBlock,
     make_norm,
     sinusoidal_positions,
@@ -55,7 +56,8 @@ class TransformerLM(nn.Module):
     norm: str = "layernorm"  # | "rmsnorm"
     norm_eps: float = 1e-6
     use_bias: bool = True  # the head's too
-    positions: str = "sinusoidal"  # added at the embedding | "rope"
+    # added at the embedding | "rope" | "none": no position signal at all
+    positions: str = "sinusoidal"
     rope_theta: float = 10000.0
     qk_norm: bool = False
     mlp: str = "gelu"  # | "swiglu"
@@ -68,6 +70,28 @@ class TransformerLM(nn.Module):
     # mean of the layers' losses
     router_aux_weight: float = 0.01
     router_z_weight: float = 0.001
+    # a hybrid stack (perf/configs/nemotron_twotower_30b_a3b.json): one
+    # letter a layer, a key of layers.attention.LAYER_KINDS, each layer one
+    # mixer or one feed-forward part; "" is the block above for every layer
+    layer_pattern: str = ""
+    head_dim: int = 0  # 0: the embedding over the heads
+    remat_layers: bool = False  # recompute each layer in the backward pass
+    # the expert layers' further fields (layers/moe.py::MoEMLP)
+    router_scoring: str = "softmax"  # | "sigmoid"
+    selection_bias: bool = False
+    selection_bias_rate: float = 0.001
+    routed_scaling: float = 1.0
+    expert_kind: str = "swiglu"  # | "relu2"
+    shared_expert_width: int = 0
+    experts_held: int = 0  # 0: all; else this deployment's share
+    first_expert: int = 0
+    # the Mamba-2 layers' (layers/mamba.py::Mamba2Mixer)
+    mamba_heads: int = 0
+    mamba_head_dim: int = 64
+    ssm_groups: int = 1
+    ssm_state: int = 128
+    conv_kernel: int = 4
+    ssd_chunk: int = 128
 
     @nn.compact
     def __call__(self, features, training: bool = False):
@@ -75,8 +99,21 @@ class TransformerLM(nn.Module):
             features["tokens"] if isinstance(features, dict) else features
         )
         tokens = jnp.asarray(tokens).astype(jnp.int32)
-        if self.positions not in ("sinusoidal", "rope"):
+        if self.positions not in ("sinusoidal", "rope", "none"):
             raise ValueError(f"unknown positions {self.positions!r}")
+        pattern = self.layer_pattern
+        if pattern and (
+            len(pattern) != self.num_layers or set(pattern) - set(LAYER_KINDS)
+        ):
+            raise ValueError(
+                f"layer_pattern {pattern!r} is not {self.num_layers} of "
+                f"{list(LAYER_KINDS)}"
+            )
+        expert_layers = pattern.count("E") if pattern else self.num_layers
+        block_class = TransformerBlock
+        if self.remat_layers:
+            # (self, x, training, decode_pos): training is a Python bool
+            block_class = nn.remat(TransformerBlock, static_argnums=(2,))
         sinusoidal = self.positions == "sinusoidal"
         x = nn.Embed(
             self.vocab_size, self.embed_dim, dtype=self.dtype,
@@ -106,7 +143,7 @@ class TransformerLM(nn.Module):
                 None, :, :
             ].astype(x.dtype)
         for layer in range(self.num_layers):
-            x = TransformerBlock(
+            x = block_class(
                 num_heads=self.num_heads,
                 causal=True,
                 dropout_rate=self.dropout_rate,
@@ -117,7 +154,9 @@ class TransformerLM(nn.Module):
                 norm=self.norm,
                 norm_eps=self.norm_eps,
                 use_bias=self.use_bias,
-                rope_theta=0.0 if sinusoidal else self.rope_theta,
+                rope_theta=(
+                    self.rope_theta if self.positions == "rope" else 0.0
+                ),
                 qk_norm=self.qk_norm,
                 mlp=self.mlp,
                 mlp_width=self.mlp_width,
@@ -125,10 +164,30 @@ class TransformerLM(nn.Module):
                 experts_per_token=self.experts_per_token,
                 expert_width=self.expert_width,
                 norm_topk_prob=self.norm_topk_prob,
-                router_aux_weight=self.router_aux_weight / self.num_layers,
-                router_z_weight=self.router_z_weight / self.num_layers,
+                router_aux_weight=self.router_aux_weight / max(1, expert_layers),
+                router_z_weight=self.router_z_weight / max(1, expert_layers),
+                kind=pattern[layer] if pattern else "",
+                head_dim=self.head_dim,
+                moe_fields=(
+                    ("scoring", self.router_scoring),
+                    ("selection_bias", self.selection_bias),
+                    ("selection_bias_rate", self.selection_bias_rate),
+                    ("routed_scaling", self.routed_scaling),
+                    ("expert_kind", self.expert_kind),
+                    ("shared_width", self.shared_expert_width),
+                    ("experts_held", self.experts_held),
+                    ("first_expert", self.first_expert),
+                ),
+                mamba_fields=(
+                    ("num_heads", self.mamba_heads),
+                    ("head_dim", self.mamba_head_dim),
+                    ("groups", self.ssm_groups),
+                    ("state_size", self.ssm_state),
+                    ("conv_kernel", self.conv_kernel),
+                    ("chunk", self.ssd_chunk),
+                ),
                 name=f"block_{layer}",
-            )(x, training=training, decode_pos=decode_pos)
+            )(x, training, decode_pos)
         x = make_norm(self.norm, self.norm_eps, self.dtype)(x)
         return nn.Dense(
             self.vocab_size, dtype=self.dtype, use_bias=self.use_bias,

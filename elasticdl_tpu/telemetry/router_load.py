@@ -1,8 +1,10 @@
 """The expert router's load, read on demand.
 
 ``layers/moe.py`` sows, every step, each expert layer's per-expert pair
-counts and the number of (token, slot) pairs its dispatch gave a row to,
-into the ``router_stats`` collection.  They leave the step as device arrays
+counts, the number of (token, slot) pairs its dispatch gave a row to and,
+where the layer holds a share of its experts, the pairs routed to the absent
+ones, into the ``router_stats`` collection (where the router's selection
+bias lives too).  They leave the step as device arrays
 inside the train state; the trainer holds them (``SPMDTrainer.state``) and
 nothing on the train path reads them.  :func:`read` fetches the newest when
 somebody asks — an evaluation milestone (``LocalExecutor.evaluate``, where
@@ -30,8 +32,9 @@ def watch(trainer):
 
 def read(model_state=None) -> dict | None:
     """The newest step's router load, one host readback: the worst layer's
-    busiest expert over the mean load, experts that got no pair, and the
-    pairs routed less the pairs dispatched (0 by construction).  None for a
+    busiest expert over the mean load, experts that got no pair, the pairs
+    routed to experts held here and to absent ones, and the pairs routed to
+    held experts less the pairs dispatched (0 by construction).  None for a
     model without experts."""
     if model_state is None:
         trainer = _watched() if _watched is not None else None
@@ -49,6 +52,7 @@ def read(model_state=None) -> dict | None:
     }
     counts = [v for k, v in flat.items() if "expert_counts" in k]
     held = sum(int(v) for k, v in flat.items() if "rows_held" in k)
+    absent = sum(int(v) for k, v in flat.items() if "absent_pairs" in k)
     pairs = sum(int(c.sum()) for c in counts)
     if not pairs:
         return None  # no step has run yet
@@ -57,7 +61,9 @@ def read(model_state=None) -> dict | None:
         "pairs": pairs,
         "max_over_mean": max(float(c.max() / c.mean()) for c in counts),
         "experts_without_tokens": sum(int((c == 0).sum()) for c in counts),
-        "dropped_pairs": pairs - held,
+        "held_pairs": pairs - absent,
+        "absent_pairs": absent,
+        "dropped_pairs": pairs - absent - held,
     }
 
 
