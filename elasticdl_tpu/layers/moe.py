@@ -27,16 +27,44 @@ a layer whose experts lie on several.  Their part of the result is computed
 through the path ``ep`` uses (a pair of another expert gets no row); what the
 absent experts would add is left out, and no code stands in for their chips.
 
-Dispatch has one path and static shapes at any imbalance
+Dispatch has static shapes at any imbalance
 (``ops/grouped_matmul.py``): a stable sort of the pairs by expert, each
 expert's rows padded to whole tiles, a gather into that order, the grouped
 matmuls, and a gather back with the weights — the permutation's transpose
 is a gather too (each pair has one row), so no scatter of activations runs
-in either direction.  On a mesh the experts' leading dimension shards over
+in either direction (but on a low rung, below).  On a mesh the experts' leading dimension shards over
 ``ep`` (``moe_sharding_rules``): under ``shard_map`` each rank lays out only
 the pairs of its own experts, the others weigh zero, and a ``psum`` over
 ``ep`` completes the combine.  The all-to-all that would move tokens
 instead of replicating them over ``ep`` is ROADMAP B5's follow-up.
+
+**The ladder of row buffers.**  A buffer that no routing can overflow holds
+every pair the router made (``grouped_matmul.num_rows``).  Where the
+experts laid out on a device are fewer than the experts the router scores
+— ``experts_held < num_experts``, or the stacks sharded over ``ep`` — the
+device is sent ``share = local experts / routed experts`` of the pairs at a
+balanced load, and every pass between router and combine would walk a
+buffer sized for all of them.  There the buffer's size is chosen each step,
+on the device, from ``grouped_matmul.ladder``'s static sizes: a low rung
+for twice the balanced share (7,168 rows for 8 of 128 experts and 49,152
+pairs, where the full size is 50,176), then the full size, derived from the
+shapes alone.  The pairs are sorted and counted once, outside the choice;
+``lax.switch`` on the tiles the counts need takes the smallest rung that
+holds them (no host readback), and inside the branch the layout, the three
+kernels' grids, the activation and both permutations run at the rung's
+size.  Below the full rung the permutations move rows and not pairs
+(``_combine_by_rows``, ``_dispatch_by_rows``: the rows added into their
+tokens, the weights' gradient a dot product a row).  "Nothing is ever
+dropped" now rests on the last rung, which is always the full size: a
+routing that outgrows the low rung takes it, at the cost every step paid
+before.  The choice sits inside one ``custom_vjp``
+(``_experts_on_ladder``) whose residuals are its inputs and whose backward
+chooses again and recomputes the taken rung's forward inside the branch, so
+nothing shaped by a rung crosses the choice (plain autodiff through it
+hands out every branch's residuals, zero-filled where not taken).  A layer
+that holds every expert it routes over has one rung: no choice, no wrapper,
+the program it had.  Each layer sows the rows of the rung it took
+(``router_stats``: ``buffer_rows``).
 
 No reference counterpart; listed in DEVIATIONS.md additions.
 """
@@ -119,49 +147,248 @@ def _combine_bwd(residuals, d_y):
 _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
+def _rows_of(weights, row_pair):
+    """Of each row of the buffer: its pair's weight (0 on a padding row)
+    and its token (``tokens``, out of range, on a padding row)."""
+    tokens, slots = weights.shape
+    held = row_pair < tokens * slots  # padding rows hold no pair
+    pair = jnp.minimum(row_pair, tokens * slots - 1)
+    return (
+        jnp.where(held, weights.reshape(-1)[pair], 0.0),
+        jnp.where(held, pair // slots, tokens),
+    )
+
+
+def _sum_by_token(values, row_token, tokens):
+    """(tokens, d) float32: each token's rows added up, a row at a time; a
+    padding row (token ``tokens``) falls out."""
+    return jnp.zeros((tokens, values.shape[1]), jnp.float32).at[row_token].add(
+        values.astype(jnp.float32), mode="drop"
+    )
+
+
+# The same two permutations in the form a rung below the full one takes:
+# what moves is rows x d, not pairs x d.  A buffer that holds an eighth of
+# the pairs makes the pair-indexed gathers above (an absent pair reads row
+# 0) the layer's largest passes; here the rows are added into their tokens
+# instead.  ops/grouped_matmul.py's "no scatter of activations" was found
+# at 65,536 rows; at a low rung's 7,168 rows of 2,688 the scatter-add reads
+# 1.18 ms against the gather's 2.99, and the weights' gradient as a dot
+# product a row 0.51 against 3.26 (PERF.md section 6, PR 33).  A token's
+# rows are summed in row order here and in slot order above: the results
+# differ by a float32 rounding of a sum of at most ``slots`` terms.
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _dispatch_by_rows(x, row_token, tokens):
+    """``x[row_token]``; a padding row reads the last token."""
+    return x[jnp.minimum(row_token, tokens - 1)]
+
+
+def _dispatch_by_rows_fwd(x, row_token, tokens):
+    return _dispatch_by_rows(x, row_token, tokens), row_token
+
+
+def _dispatch_by_rows_bwd(tokens, row_token, d_rows):
+    return _sum_by_token(d_rows, row_token, tokens).astype(d_rows.dtype), None
+
+
+_dispatch_by_rows.defvjp(_dispatch_by_rows_fwd, _dispatch_by_rows_bwd)
+
+
+@jax.custom_vjp
+def _combine_by_rows(rows, weights, row_pair):
+    """:func:`_combine` as ``y[token of r] += weight of r * rows[r]``."""
+    row_weight, row_token = _rows_of(weights, row_pair)
+    return _sum_by_token(
+        rows.astype(jnp.float32) * row_weight[:, None], row_token,
+        weights.shape[0],
+    ).astype(rows.dtype)
+
+
+def _combine_by_rows_fwd(rows, weights, row_pair):
+    return _combine_by_rows(rows, weights, row_pair), (rows, weights, row_pair)
+
+
+def _combine_by_rows_bwd(residuals, d_y):
+    rows, weights, row_pair = residuals
+    row_weight, row_token = _rows_of(weights, row_pair)
+    d_y_rows = d_y[jnp.minimum(row_token, weights.shape[0] - 1)].astype(
+        jnp.float32
+    )
+    d_rows = (d_y_rows * row_weight[:, None]).astype(d_y.dtype)
+    # a pair's weight moves the loss by <its row, its token's d_y>, put at
+    # the pair (each has one row; a padding row's falls out)
+    row_dot = jnp.sum(rows.astype(jnp.float32) * d_y_rows, axis=-1)
+    d_weights = jnp.zeros((weights.size,), weights.dtype).at[row_pair].set(
+        row_dot.astype(weights.dtype), mode="drop", unique_indices=True
+    )
+    return d_rows, d_weights.reshape(weights.shape), None
+
+
+_combine_by_rows.defvjp(_combine_by_rows_fwd, _combine_by_rows_bwd)
+
+
+def _experts_at(
+    rows, by_rows, tile_rows, interpret, x, weights, group_ids, order, stacks
+):
+    """Dispatch, grouped matmuls, activation and combine in a buffer of
+    ``rows`` rows that holds this routing (``order`` =
+    ``group_order(group_ids)``); ``by_rows`` picks the permutations' form.
+    Returns the output and the number of pairs that were given a row."""
+    tokens, slots = weights.shape
+    experts = stacks[0].shape[0]
+    layout = gmm_ops.group_layout(
+        group_ids, experts, tile_rows, rows, order, by_rows
+    )
+    held = layout.row_pair < tokens * slots
+    matmul = functools.partial(
+        gmm_ops.grouped_matmul, tile_group=layout.tile_group,
+        tile_rows=tile_rows, interpret=interpret,
+    )
+    if by_rows:
+        _, row_token = _rows_of(weights, layout.row_pair)
+        buffer = _dispatch_by_rows(x, row_token, tokens)
+    else:
+        pair_row = layout.pair_row.reshape(tokens, slots)
+        row_token = jnp.minimum(layout.row_pair, tokens * slots - 1) // slots
+        buffer = _dispatch(
+            x, row_token, pair_row, (group_ids < experts).reshape(tokens, slots)
+        )
+    if len(stacks) == 3:
+        w_gate, w_up, w_down = stacks
+        hidden = nn.silu(matmul(buffer, w_gate)) * matmul(buffer, w_up)
+    else:
+        w_up, w_down = stacks
+        hidden = jnp.square(nn.relu(matmul(buffer, w_up)))
+    out = matmul(hidden, w_down)
+    if by_rows:
+        y = _combine_by_rows(out, weights, layout.row_pair)
+    else:
+        y = _combine(out, weights, pair_row, layout.row_pair)
+    return y, jnp.sum(held, dtype=jnp.int32)
+
+
+def _rung_of(rungs, tile_rows, sizes):
+    """Index of the smallest rung that holds groups of ``sizes``: a device
+    scalar, read by no host."""
+    tiles = jnp.asarray([rows // tile_rows for rows in rungs[:-1]], jnp.int32)
+    return jnp.sum(gmm_ops.tiles_needed(sizes, tile_rows) > tiles)
+
+
+def _branches(rungs, tile_rows, interpret):
+    # every rung below the last moves rows, the last (today's size) pairs
+    return [
+        functools.partial(
+            _experts_at, rows, rows < rungs[-1], tile_rows, interpret
+        )
+        for rows in rungs
+    ]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _experts_on_ladder(
+    rungs, tile_rows, interpret, x, weights, group_ids, order, stacks
+):
+    """:func:`_experts_at` the smallest of ``rungs`` that holds this step's
+    routing, chosen on the device.  Nothing whose shape follows the rung
+    leaves the choice: plain autodiff through it would have every branch
+    hand out every branch's residuals, zero-filled where not taken (the
+    full-size buffers written for nothing, the low rung's added to the
+    peak).  So the residuals are the inputs, and the backward pass makes the
+    same choice again and runs, inside the branch, that rung's forward up
+    to the combine's input and its backward.  Returns the output, the pairs
+    given a row and the rows of the rung taken."""
+    rung = _rung_of(rungs, tile_rows, order.sizes)
+    y, held = jax.lax.switch(
+        rung, _branches(rungs, tile_rows, interpret),
+        x, weights, group_ids, order, stacks,
+    )
+    return y, held, jnp.asarray(rungs, jnp.int32)[rung]
+
+
+def _experts_on_ladder_fwd(
+    rungs, tile_rows, interpret, x, weights, group_ids, order, stacks
+):
+    out = _experts_on_ladder(
+        rungs, tile_rows, interpret, x, weights, group_ids, order, stacks
+    )
+    return out, (x, weights, group_ids, order, stacks)
+
+
+def _experts_on_ladder_bwd(rungs, tile_rows, interpret, residuals, cotangents):
+    x, weights, group_ids, order, stacks = residuals
+
+    def backward(experts_at):
+        def forward(x, weights, stacks):
+            # under a scope of its own a kernel keeps the op name it gives
+            # itself (``expert_gmm_dw``, which ``perf/`` reads it by); a
+            # differentiation with nothing named inside names the ops
+            # ``jvp(expert_gmm_dw)``
+            with jax.named_scope("rung"):
+                return experts_at(x, weights, group_ids, order, stacks)[0]
+
+        def run(d_y, x, weights, stacks):
+            return jax.vjp(forward, x, weights, stacks)[1](d_y)
+
+        return run
+
+    d_x, d_weights, d_stacks = jax.lax.switch(
+        _rung_of(rungs, tile_rows, order.sizes),
+        [backward(b) for b in _branches(rungs, tile_rows, interpret)],
+        cotangents[0], x, weights, stacks,
+    )
+    return d_x, d_weights, None, None, d_stacks
+
+
+_experts_on_ladder.defvjp(_experts_on_ladder_fwd, _experts_on_ladder_bwd)
+
+
 def routed_experts(
-    x, top_experts, weights, *stacks, first_expert=0,
+    x, top_experts, weights, *stacks, first_expert=0, num_experts=None,
     tile_rows: int = gmm_ops.TILE_ROWS, interpret: bool | None = None,
 ):
     """The experts' part on one device: ``x`` (tokens, d), ``top_experts``
     and ``weights`` (tokens, k), the weight ``stacks`` of the
     ``stacks[0].shape[0]`` experts that start at ``first_expert`` — three
-    (gate, up, down) for SwiGLU experts, two (up, down) for relu^2 ones.  A
-    pair whose expert is not among them adds nothing here.  Returns the
-    output and the number of pairs that were given a row."""
+    (gate, up, down) for SwiGLU experts, two (up, down) for relu^2 ones —
+    of the ``num_experts`` that ``top_experts`` ranges over (default: these
+    are all).  A pair whose expert is not among them adds nothing here.
+    Returns the output, the number of pairs that were given a row, and the
+    rows of the buffer they were laid out in beside the rows of the full
+    one (int32[2])."""
     tokens, slots = top_experts.shape
     experts = stacks[0].shape[0]
     local = top_experts - first_expert
     grouped = (local >= 0) & (local < experts)
-    layout = gmm_ops.group_layout(
-        jnp.where(grouped, local, experts).reshape(-1).astype(jnp.int32),
-        experts, tile_rows,
+    group_ids = jnp.where(grouped, local, experts).reshape(-1).astype(jnp.int32)
+    order = gmm_ops.group_order(group_ids, experts)
+    weights = jnp.where(grouped, weights, 0.0)
+    rungs = gmm_ops.ladder(
+        tokens * slots, experts, num_experts or experts, tile_rows
     )
-    pair_row = layout.pair_row.reshape(tokens, slots)
-    row_token = jnp.minimum(layout.row_pair, tokens * slots - 1) // slots
-    matmul = functools.partial(
-        gmm_ops.grouped_matmul, tile_group=layout.tile_group,
-        tile_rows=tile_rows, interpret=interpret,
-    )
-    rows = _dispatch(x, row_token, pair_row, grouped)
-    if len(stacks) == 3:
-        w_gate, w_up, w_down = stacks
-        hidden = nn.silu(matmul(rows, w_gate)) * matmul(rows, w_up)
+    if len(rungs) == 1:
+        # every routed expert is here: all pairs are held, nothing to choose
+        y, held = _experts_at(
+            rungs[0], False, tile_rows, interpret,
+            x, weights, group_ids, order, stacks,
+        )
+        rows = jnp.int32(rungs[0])
     else:
-        w_up, w_down = stacks
-        hidden = jnp.square(nn.relu(matmul(rows, w_up)))
-    out = matmul(hidden, w_down)
-    y = _combine(
-        out, jnp.where(grouped, weights, 0.0), pair_row, layout.row_pair
-    )
-    return y, jnp.sum(layout.row_pair < tokens * slots, dtype=jnp.int32)
+        y, held, rows = _experts_on_ladder(
+            rungs, tile_rows, interpret, x, weights, group_ids, order, stacks
+        )
+    return y, held, jnp.stack([rows, jnp.int32(rungs[-1])])
 
 
-def _experts_on_mesh(x, top_experts, weights, stacks, first_expert=0):
+def _experts_on_mesh(
+    x, top_experts, weights, stacks, first_expert=0, num_experts=None
+):
     """``routed_experts`` under the registered mesh: tokens stay on their
     batch (and sequence) axes, the held experts (``stacks``, from
-    ``first_expert`` on) shard over ``ep``, and a compiled Pallas kernel,
-    which GSPMD cannot partition, runs per device."""
+    ``first_expert`` on, of the ``num_experts`` routed over) shard over
+    ``ep``, and a compiled Pallas kernel, which GSPMD cannot partition, runs
+    per device.  The counts come back summed over the devices."""
     from jax.sharding import PartitionSpec as P
 
     from elasticdl_tpu.ops.ring_attention import sequence_shard_spec
@@ -171,11 +398,11 @@ def _experts_on_mesh(x, top_experts, weights, stacks, first_expert=0):
     slots = top_experts.shape[-1]
 
     def local(x, top_experts, weights, *stacks, **kw):
-        y, held = routed_experts(
+        y, held, buffer_rows = routed_experts(
             x.reshape(-1, embed), top_experts.reshape(-1, slots),
-            weights.reshape(-1, slots), *stacks, **kw,
+            weights.reshape(-1, slots), *stacks, num_experts=num_experts, **kw,
         )
-        return y.reshape(x.shape), held
+        return y.reshape(x.shape), held, buffer_rows
 
     if mesh is None:
         return local(
@@ -216,20 +443,20 @@ def _experts_on_mesh(x, top_experts, weights, stacks, first_expert=0):
         first = first_expert
         if ep:
             first += jax.lax.axis_index(ep) * stacks[0].shape[0]
-        y, held = local(
+        y, *counts = local(
             x, top_experts, weights, *stacks,
             first_expert=first, interpret=interpret,
         )
         return (
             jax.lax.psum(y, ep) if ep else y,
-            jax.lax.psum(held, counted) if counted else held,
+            *(jax.lax.psum(counts, counted) if counted else counts),
         )
 
     return jax.shard_map(
         per_device,
         mesh=mesh,
         in_specs=(tokens, tokens, tokens) + (experts,) * len(stacks),
-        out_specs=(tokens, P()),
+        out_specs=(tokens, P(), P()),
         check_vma=False,
     )(x, top_experts, weights, *stacks)
 
@@ -337,14 +564,20 @@ class MoEMLP(nn.Module):
         ] + [self.param("w_down", _expert_init, (held, width, embed))]
         if self.dtype is not None:
             x = x.astype(self.dtype)
-        y, rows_held = _experts_on_mesh(
-            x, top_experts, weights, stacks, self.first_expert
+        y, rows_held, buffer_rows = _experts_on_mesh(
+            x, top_experts, weights, stacks, self.first_expert, self.num_experts
         )
         if self.shared_width:
             y = y + self._shared_expert(x)
         # what telemetry/router_load.py reads on demand; the dispatch's own
-        # count of rows beside the router's says that no pair was dropped
-        stats = {"expert_counts": counts.astype(jnp.int32), "rows_held": rows_held}
+        # count of rows beside the router's says that no pair was dropped,
+        # the rows of the rung its buffer took beside the full rung's how
+        # much of the static size this step walked
+        stats = {
+            "expert_counts": counts.astype(jnp.int32),
+            "rows_held": rows_held,
+            "buffer_rows": buffer_rows,
+        }
         if held < self.num_experts:
             # pairs of experts that other chips hold: left out, not dropped
             last = self.first_expert + held

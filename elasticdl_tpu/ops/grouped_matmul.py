@@ -13,8 +13,24 @@ tile that straddles two groups.  Tiles past the last group carry the value
 Row tiles run innermost with the whole contraction in one block, so an
 expert's matrix stays in VMEM across its consecutive tiles and is read from
 HBM once; what streams is the row buffer.  Padding costs at most one tile a
-group: ``rows = ceil(pairs / tile_rows) + num_groups`` tiles hold any
-routing, every pair to one group included, so nothing is ever dropped.
+group: ``rows = ceil(pairs / tile_rows) + num_groups`` tiles
+(:func:`num_rows`) hold any routing, every pair to one group included.
+
+**The ladder.**  That size is what *all* pairs need.  Where the groups laid
+out here are fewer than the groups the pairs were routed over (a device that
+holds a share of a layer's experts, a rank of an ``ep`` mesh), a balanced
+routing sends here ``share = num_groups / routed_groups`` of the pairs, and a
+buffer for all of them is walked almost empty by every pass between the
+router and the combine.  :func:`ladder` therefore gives a short ascending
+list of static buffer sizes derived from the shapes alone: a low rung that
+holds ``LOW_RUNG_SHARES`` times the balanced share (plus the tile a group may
+waste), then the full size.  The caller (``layers/moe.py``) sorts the pairs
+once (:func:`group_order`), reads off the device how many row tiles this
+step's routing needs (:func:`tiles_needed`) and takes, on the device, the
+smallest rung that holds them; :func:`group_layout` lays the pairs out at
+the rows it is given.  "Nothing is ever dropped" rests on the last rung,
+which is always ``num_rows`` of all pairs; where the groups here are all
+the groups, it is the only rung and nothing is chosen.
 
 Each kernel has a name the device trace's op line shows (as the flash
 kernels do, ``ops/attention.py``): ``perf/`` reads them by it.
@@ -42,9 +58,29 @@ GMM_DW = "expert_gmm_dw"
 # and 0.15 GB less than 256 in olmoe_1b7b_seq4096 (PERF.md, PR 27).  A
 # weight block is bounded to _MAX_BLOCK_BYTES
 TILE_ROWS = 128
+# the low rung of the ladder holds this many times the pairs a balanced
+# routing sends to the groups laid out here.  Twice: a load-balanced router
+# keeps a device's share of the pairs (the sum over its experts, steadier
+# than any one expert's) well inside it, and a buffer twice the needed size
+# costs what an empty tile costs, a skipped grid step.  There is no rung
+# between this one and the full size: past twice its share a device is the
+# straggler of its mesh whatever its buffer, every further rung is one more
+# compiled copy of each expert layer's routed part (forward and backward),
+# and the full rung costs what every step cost before the ladder
+LOW_RUNG_SHARES = 2
 _MAX_BLOCK_BYTES = 4 * 1024 * 1024
 _VMEM_LIMIT_BYTES = 64 * 1024 * 1024
 _LANES = 128
+
+
+class GroupOrder(NamedTuple):
+    """The pairs sorted by group: what every rung's layout starts from."""
+
+    # (pairs,) the pairs in group order; a stable sort, so a group's pairs
+    # stay in token order
+    order: jax.Array
+    # (num_groups,) pairs of each group; pairs of no group are not counted
+    sizes: jax.Array
 
 
 class GroupLayout(NamedTuple):
@@ -53,8 +89,8 @@ class GroupLayout(NamedTuple):
     # (rows,) the pair held by each row; ``pairs`` (out of range) on padding
     row_pair: jax.Array
     # (pairs,) the row of each pair; 0 for a pair of no group (masked by
-    # the caller: its weight is zero)
-    pair_row: jax.Array
+    # the caller: its weight is zero).  None in a layout made ``by_rows``
+    pair_row: jax.Array | None
     # (rows // tile_rows,) the group of each row tile; ``num_groups`` = none
     tile_group: jax.Array
 
@@ -64,21 +100,68 @@ def num_rows(pairs: int, num_groups: int, tile_rows: int) -> int:
     return (-(-pairs // tile_rows) + num_groups) * tile_rows
 
 
-def group_layout(group_ids, num_groups: int, tile_rows: int) -> GroupLayout:
+def ladder(
+    pairs: int, num_groups: int, routed_groups: int, tile_rows: int
+) -> tuple[int, ...]:
+    """The static buffer sizes (rows, ascending) for ``pairs`` routed over
+    ``routed_groups`` groups of which ``num_groups`` are laid out here.  The
+    last is ``num_rows(pairs, ...)``, which holds any routing; before it, a
+    rung for ``LOW_RUNG_SHARES`` times the balanced share of the pairs,
+    where that is smaller."""
+    full = num_rows(pairs, num_groups, tile_rows)
+    balanced = -(-LOW_RUNG_SHARES * pairs * num_groups // routed_groups)
+    low = num_rows(balanced, num_groups, tile_rows)
+    return (low, full) if low < full else (full,)
+
+
+def group_order(group_ids, num_groups: int) -> GroupOrder:
     """``group_ids``: (pairs,) int32 in ``[0, num_groups]``; ``num_groups``
     marks a pair that belongs to none of these groups (another rank's
-    expert) and gets no row.  A stable sort keeps a group's pairs in token
-    order."""
-    pairs = group_ids.shape[0]
-    rows = num_rows(pairs, num_groups, tile_rows)
+    expert)."""
     order = jnp.argsort(group_ids, stable=True).astype(jnp.int32)
-    sorted_ids = group_ids[order]
     sizes = jnp.bincount(group_ids, length=num_groups + 1)[:num_groups]
-    sizes = sizes.astype(jnp.int32)
+    return GroupOrder(order, sizes.astype(jnp.int32))
+
+
+def tiles_needed(sizes, tile_rows: int):
+    """Row tiles that hold groups of ``sizes``: every group at least one."""
+    return jnp.sum(jnp.maximum(-(-sizes // tile_rows), 1))
+
+
+def group_layout(
+    group_ids, num_groups: int, tile_rows: int, rows: int,
+    order: GroupOrder | None = None, by_rows: bool = False,
+) -> GroupLayout:
+    """The layout of ``group_ids`` (as :func:`group_order` takes them) in a
+    buffer of ``rows`` rows, a rung of the :func:`ladder`: the caller has
+    seen that ``tiles_needed`` fit (``num_rows`` always do).  A pair of no
+    group gets no row.  ``by_rows``: for a caller that reads no row by its
+    pair.  ``pair_row`` is None, and the rows are laid out from their own
+    side, a gather of ``rows`` entries where the other form scatters
+    ``pairs``: nothing of the layout is then sized by the pairs."""
+    pairs = group_ids.shape[0]
+    order, sizes = group_order(group_ids, num_groups) if order is None else order
     starts = jnp.cumsum(sizes) - sizes
     tiles = jnp.maximum(-(-sizes // tile_rows), 1)
     tile_ends = jnp.cumsum(tiles)
     row_starts = (tile_ends - tiles) * tile_rows
+    tile = jnp.arange(rows // tile_rows, dtype=jnp.int32)
+    tile_group = jnp.searchsorted(tile_ends, tile, side="right").astype(jnp.int32)
+    if by_rows:
+        # row ``i`` of a group's tiles holds the ``i``-th of the group's
+        # pairs in sorted order, if the group has so many
+        group = jnp.minimum(tile_group, num_groups - 1)
+        in_group = (
+            tile[:, None] * tile_rows - row_starts[group][:, None]
+            + jnp.arange(tile_rows, dtype=jnp.int32)
+        )
+        held = (tile_group < num_groups)[:, None] & (
+            in_group < sizes[group][:, None]
+        )
+        sorted_pair = jnp.minimum(starts[group][:, None] + in_group, pairs - 1)
+        row_pair = jnp.where(held, order[sorted_pair], pairs).reshape(rows)
+        return GroupLayout(row_pair, None, tile_group)
+    sorted_ids = group_ids[order]
     grouped = sorted_ids < num_groups
     safe = jnp.minimum(sorted_ids, num_groups - 1)
     sorted_row = (
@@ -92,9 +175,6 @@ def group_layout(group_ids, num_groups: int, tile_rows: int) -> GroupLayout:
     pair_row = jnp.zeros((pairs,), jnp.int32).at[order].set(
         jnp.where(grouped, sorted_row, 0), unique_indices=True
     )
-    tile_group = jnp.searchsorted(
-        tile_ends, jnp.arange(rows // tile_rows, dtype=jnp.int32), side="right"
-    ).astype(jnp.int32)
     return GroupLayout(row_pair, pair_row, tile_group)
 
 

@@ -1,12 +1,14 @@
 """The expert router's load, read on demand.
 
 ``layers/moe.py`` sows, every step, each expert layer's per-expert pair
-counts, the number of (token, slot) pairs its dispatch gave a row to and,
-where the layer holds a share of its experts, the pairs routed to the absent
-ones, into the ``router_stats`` collection (where the router's selection
-bias lives too).  They leave the step as device arrays
-inside the train state; the trainer holds them (``SPMDTrainer.state``) and
-nothing on the train path reads them.  :func:`read` fetches the newest when
+counts, the number of (token, slot) pairs its dispatch gave a row to, the
+rows of the buffer they were laid out in beside the rows of the full one
+(``buffer_rows``: the rung of ``ops/grouped_matmul.py``'s ladder that the
+step took, summed over the devices) and, where the layer holds a share of
+its experts, the pairs routed to the absent ones, into the ``router_stats``
+collection (where the router's selection bias lives too).  They leave the
+step as device arrays inside the train state; the trainer holds them
+(``SPMDTrainer.state``) and nothing on the train path reads them.  :func:`read` fetches the newest when
 somebody asks — an evaluation milestone (``LocalExecutor.evaluate``, where
 the program reads the loss anyway), a benchmark's reader after its window.
 """
@@ -34,8 +36,10 @@ def read(model_state=None) -> dict | None:
     """The newest step's router load, one host readback: the worst layer's
     busiest expert over the mean load, experts that got no pair, the pairs
     routed to experts held here and to absent ones, and the pairs routed to
-    held experts less the pairs dispatched (0 by construction).  None for a
-    model without experts."""
+    held experts less the pairs dispatched (0 by construction), and the
+    rows of the buffers the dispatch walked, as a count and as a share of
+    the full rung's (1.0 where every layer took its largest or only rung).
+    None for a model without experts."""
     if model_state is None:
         trainer = _watched() if _watched is not None else None
         if trainer is None:
@@ -56,6 +60,10 @@ def read(model_state=None) -> dict | None:
     pairs = sum(int(c.sum()) for c in counts)
     if not pairs:
         return None  # no step has run yet
+    buffer_rows, full_rows = (
+        sum(int(v[i]) for k, v in flat.items() if "buffer_rows" in k)
+        for i in (0, 1)
+    )
     return {
         "layers": len(counts),
         "pairs": pairs,
@@ -64,6 +72,9 @@ def read(model_state=None) -> dict | None:
         "held_pairs": pairs - absent,
         "absent_pairs": absent,
         "dropped_pairs": pairs - absent - held,
+        "buffer_rows": buffer_rows,
+        # (a state sown before the counter existed has no rows to share)
+        "buffer_share": buffer_rows / full_rows if full_rows else None,
     }
 
 
@@ -71,9 +82,13 @@ def publish(registry, model_state=None) -> dict | None:
     """:func:`read`, set as gauges on ``registry``."""
     load = read(model_state)
     if load is not None:
-        for name in ("max_over_mean", "experts_without_tokens", "dropped_pairs"):
-            registry.gauge(
-                f"elasticdl_router_{name}",
-                "expert router load of the newest train step",
-            ).set(load[name])
+        for name in (
+            "max_over_mean", "experts_without_tokens", "dropped_pairs",
+            "buffer_share",
+        ):
+            if load[name] is not None:
+                registry.gauge(
+                    f"elasticdl_router_{name}",
+                    "expert router load of the newest train step",
+                ).set(load[name])
     return load

@@ -15,8 +15,10 @@ def ragged_case(sizes, tile_rows, seed=0):
     rng = np.random.RandomState(seed)
     ids = np.repeat(np.arange(len(sizes)), sizes).astype(np.int32)
     rng.shuffle(ids)
-    layout = g.group_layout(jnp.asarray(ids), GROUPS, tile_rows)
     pairs = len(ids)
+    layout = g.group_layout(
+        jnp.asarray(ids), GROUPS, tile_rows, g.num_rows(pairs, GROUPS, tile_rows)
+    )
     x = jnp.asarray(rng.randn(pairs, INNER), jnp.float32)
     w = jnp.asarray(rng.randn(GROUPS, INNER, COLS), jnp.float32)
     return ids, layout, x, w
@@ -90,7 +92,7 @@ def test_pairs_of_no_group_get_no_row_and_tail_tiles_read_zero():
     """Ids equal to ``num_groups`` (another rank's experts) are left out;
     tiles past the last group come back as zeros."""
     ids = jnp.asarray([0, 5, 2, 5, 5, 1, 0, 5], jnp.int32)
-    layout = g.group_layout(ids, GROUPS, 8)
+    layout = g.group_layout(ids, GROUPS, 8, g.num_rows(8, GROUPS, 8))
     row_pair = np.asarray(layout.row_pair)
     assert sorted(row_pair[row_pair < 8]) == [0, 2, 5, 6]
     rows = jnp.ones((row_pair.shape[0], INNER), jnp.float32)
@@ -143,3 +145,70 @@ def test_kernels_carry_the_names_the_benchmark_reads():
     )
     for name in (g.GMM_FWD, g.GMM_DX, g.GMM_DW):
         assert name in text
+
+
+LADDERS = {
+    # pairs, groups here, groups routed over, tile rows -> rungs (rows)
+    "the_cell_8_of_128": ((49152, 8, 128, 128), (7168, 50176)),
+    "ep4_of_64": ((65536, 16, 64, 128), (34816, 67584)),
+    "every_group_here": ((65536, 64, 64, 128), (73728,)),
+    "half_the_groups": ((1000, 4, 8, 8), (1032,)),  # twice a half is all
+    "a_quarter": ((1000, 2, 8, 8), (520, 1016)),
+}
+
+
+@pytest.mark.parametrize("case", LADDERS.values(), ids=LADDERS.keys())
+def test_ladder_is_derived_from_the_share_of_the_groups(case):
+    """The low rung holds twice the balanced share of the pairs plus a tile
+    a group; the last rung is ``num_rows`` of all pairs; a layout that
+    holds every routed group has that one rung."""
+    (pairs, groups, routed, tile_rows), rungs = case
+    assert g.ladder(pairs, groups, routed, tile_rows) == rungs
+    assert rungs[-1] == g.num_rows(pairs, groups, tile_rows)
+    if len(rungs) > 1:
+        held = -(-g.LOW_RUNG_SHARES * pairs * groups // routed)
+        assert rungs[0] == (-(-held // tile_rows) + groups) * tile_rows
+
+
+@pytest.mark.parametrize("tile_rows", [8, 16])
+@pytest.mark.parametrize(
+    "sizes",
+    [[7, 0, 19, 1, 13], [0, 0, 24, 0, 0], [8, 8, 8, 8, 8], [0, 0, 0, 0, 0]],
+    ids=["ragged", "one_group", "whole_tiles", "no_pair_here"],
+)
+def test_layout_at_a_rung_holds_every_grouped_pair_once(sizes, tile_rows):
+    """200 pairs routed over 40 groups of which 5 are laid out here: the
+    sort is made once, the tiles needed are read from its counts, and the
+    layout at the smallest rung that fits holds each grouped pair once, in
+    the rows the full rung gives it."""
+    rng = np.random.RandomState(2)
+    ids = np.full((200,), GROUPS, np.int32)
+    ids[: sum(sizes)] = np.repeat(np.arange(GROUPS), sizes)
+    rng.shuffle(ids)
+    ids = jnp.asarray(ids)
+    order = g.group_order(ids, GROUPS)
+    np.testing.assert_array_equal(order.sizes, sizes)
+    needed = int(g.tiles_needed(order.sizes, tile_rows))
+    assert needed == sum(max(-(-s // tile_rows), 1) for s in sizes)
+    rungs = g.ladder(200, GROUPS, 40, tile_rows)
+    assert len(rungs) == 2 and needed * tile_rows <= rungs[0]
+    low = g.group_layout(ids, GROUPS, tile_rows, rungs[0], order)
+    full = g.group_layout(ids, GROUPS, tile_rows, rungs[1])
+    # laid out from the rows' side, the same rows without the pairs' index
+    by_rows = g.group_layout(ids, GROUPS, tile_rows, rungs[0], order, by_rows=True)
+    assert by_rows.pair_row is None
+    np.testing.assert_array_equal(by_rows.row_pair, low.row_pair)
+    np.testing.assert_array_equal(by_rows.tile_group, low.tile_group)
+    assert low.row_pair.shape == (rungs[0],)
+    assert low.tile_group.shape == (rungs[0] // tile_rows,)
+    row_pair = np.asarray(low.row_pair)
+    grouped = np.flatnonzero(np.asarray(ids) < GROUPS)
+    assert sorted(row_pair[row_pair < 200]) == list(grouped)
+    np.testing.assert_array_equal(row_pair[np.asarray(low.pair_row)[grouped]], grouped)
+    # the low rung is the head of the full one: the same rows and tiles
+    np.testing.assert_array_equal(low.pair_row, full.pair_row)
+    np.testing.assert_array_equal(row_pair, np.asarray(full.row_pair)[: rungs[0]])
+    np.testing.assert_array_equal(
+        low.tile_group, np.asarray(full.tile_group)[: rungs[0] // tile_rows]
+    )
+    assert (np.asarray(full.row_pair)[rungs[0] :] == 200).all()

@@ -9,8 +9,10 @@ import numpy as np
 import optax
 import pytest
 
+from elasticdl_tpu.layers import moe
 from elasticdl_tpu.layers.moe import MoEMLP, moe_sharding_rules, routed_experts
 from elasticdl_tpu.models import long_seq_transformer as lm
+from elasticdl_tpu.ops import grouped_matmul as gmm_ops
 from elasticdl_tpu.parallel.distributed import SPMDTrainer
 from elasticdl_tpu.parallel.mesh import MeshConfig
 from elasticdl_tpu.telemetry import MetricsRegistry, router_load
@@ -81,7 +83,7 @@ def test_no_token_is_dropped_when_every_token_picks_one_expert(tile_rows):
     stacks = [jnp.asarray(rng.randn(4, *s), jnp.float32) * 0.3
               for s in ((16, 8), (16, 8), (8, 16))]
     chosen = jnp.full((48, 1), 3, jnp.int32)
-    y, held = routed_experts(
+    y, held, _ = routed_experts(
         x, chosen, jnp.ones((48, 1)), *stacks, tile_rows=tile_rows
     )
     want = (jax.nn.silu(x @ stacks[0][3]) * (x @ stacks[1][3])) @ stacks[2][3]
@@ -189,3 +191,294 @@ def test_moe_refuses_more_slots_than_experts():
         MoEMLP(num_experts=2, experts_per_token=3).init(
             jax.random.PRNGKey(0), x
         )
+
+
+# --- the ladder of row buffers (layers/moe.py, ops/grouped_matmul.py) ---
+
+TILE = 8
+
+
+def routing(tokens, slots, routed, held_sizes, seed=0):
+    """(tokens, slots) distinct experts a token: expert ``e`` of the held
+    ones (0..len(held_sizes)-1) on ``held_sizes[e]`` tokens, every other
+    slot on an absent expert."""
+    rng = np.random.RandomState(seed)
+    held = len(held_sizes)
+    top = np.stack([
+        held + rng.choice(routed - held, slots, replace=False)
+        for _ in range(tokens)
+    ]).astype(np.int32)
+    free = [list(rng.permutation(tokens)) for _ in range(slots)]
+    for expert, size in enumerate(held_sizes):
+        slot = free[expert % slots]  # a token's experts stay distinct
+        assert size <= len(slot)
+        top[[slot.pop() for _ in range(size)], expert % slots] = expert
+    return jnp.asarray(top)
+
+
+def expert_inputs(tokens, slots, held, kind, seed=0, embed=16, width=8):
+    rng = np.random.RandomState(seed)
+    shapes = [(embed, width)] * (2 if kind == "swiglu" else 1) + [(width, embed)]
+    return (
+        jnp.asarray(rng.randn(tokens, embed), jnp.float32),
+        jnp.asarray(rng.rand(tokens, slots) + 0.1, jnp.float32),
+        tuple(jnp.asarray(rng.randn(held, *s), jnp.float32) * 0.3 for s in shapes),
+    )
+
+
+def value_and_grads(experts, x, weights, stacks):
+    """``y`` and the gradients of ``sum(sin(y))`` in x, weights, stacks."""
+    def loss(x, weights, stacks):
+        y = experts(x, weights, stacks)
+        return jnp.sum(jnp.sin(y)), y
+
+    (_, y), grads = jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True)(
+        x, weights, stacks
+    )
+    return jax.tree_util.tree_leaves((y, grads))
+
+
+SHARES = {
+    # routed experts, held, slots, pairs a held expert gets
+    "8_of_128": (128, 8, 6, [5, 0, 9, 1, 3, 8, 2, 4]),
+    "4_of_16": (16, 4, 2, [9, 0, 17, 3]),
+    "2_of_8": (8, 2, 2, [11, 6]),
+}
+
+
+@pytest.mark.parametrize("kind", ["swiglu", "relu2"])
+@pytest.mark.parametrize("share", SHARES.values(), ids=SHARES.keys())
+def test_low_rung_and_full_rung_agree(share, kind):
+    """One routing through the low rung and through the full one: the same
+    rows through the same kernels, so with the pair-indexed gathers on both
+    ``y``, ``d_x``, ``d_weights`` and the stacks' gradients are equal to the
+    last bit; the low rung's own form adds a token's rows in row order where
+    the gathers add them in slot order, a float32 rounding of a sum of at
+    most ``slots`` terms apart.  ``routed_experts`` takes the low rung by
+    itself and says so."""
+    routed, held, slots, sizes = share
+    tokens = 64
+    top = routing(tokens, slots, routed, sizes)
+    x, weights, stacks = expert_inputs(tokens, slots, held, kind)
+    low, full = gmm_ops.ladder(tokens * slots, held, routed, TILE)
+    group_ids = jnp.where(top < held, top, held).reshape(-1)
+    order = gmm_ops.group_order(group_ids, held)
+    assert int(gmm_ops.tiles_needed(order.sizes, TILE)) * TILE <= low < full
+
+    def at(rows, by_rows):
+        # an absent pair weighs nothing (its weight's gradient reads row 0)
+        return lambda x, weights, stacks: moe._experts_at(
+            rows, by_rows, TILE, None, x, jnp.where(top < held, weights, 0.0),
+            group_ids, order, stacks,
+        )[0]
+
+    def chosen(x, weights, stacks):
+        y, rows_held, buffer_rows = routed_experts(
+            x, top, weights, *stacks, num_experts=routed, tile_rows=TILE
+        )
+        chosen.counts = (rows_held, buffer_rows)
+        return y
+
+    want = value_and_grads(at(full, False), x, weights, stacks)
+    for got in value_and_grads(at(low, False), x, weights, stacks), want:
+        for a, b in zip(got, want):
+            np.testing.assert_array_equal(a, b)
+    by_rows = value_and_grads(at(low, True), x, weights, stacks)
+    laddered = value_and_grads(chosen, x, weights, stacks)
+    # (the chosen branch is compiled as one program, so its products may be
+    # fused where the call above runs them one by one)
+    for a, b, c in zip(by_rows, laddered, want):
+        bound = 4e-7 * float(jnp.abs(c).max())
+        np.testing.assert_allclose(a, c, rtol=0, atol=bound)
+        np.testing.assert_allclose(b, c, rtol=0, atol=bound)
+    assert int(chosen.counts[0]) == sum(sizes)
+    assert list(np.asarray(chosen.counts[1])) == [low, full]
+
+
+CROSSINGS = {
+    # pairs a held expert gets (8 of 128, top-6, 64 tokens: the low rung is
+    # 14 tiles of 8 rows) -> the rung that must be taken
+    "fits_the_low_rung_exactly": ([56, 0, 0, 0, 0, 0, 0, 0], 0),
+    "one_tile_more_than_the_low_rung": ([57, 0, 0, 0, 0, 0, 0, 0], 1),
+    "every_token_on_every_held_expert": ([64, 64, 64, 64, 64, 64], 1),
+    "no_pair_here": ([0] * 8, 0),
+}
+
+
+@pytest.mark.parametrize("kind", ["swiglu", "relu2"])
+@pytest.mark.parametrize("case", CROSSINGS.values(), ids=CROSSINGS.keys())
+def test_rung_follows_the_routing_and_nothing_is_dropped(case, kind):
+    """The smallest rung that holds the routing's tiles is taken; a routing
+    past the low rung takes the full one, whose size holds any: the
+    dispatch's own count of rows equals the held pairs, and the output is
+    the dense per-expert sum."""
+    sizes, rung = case
+    held = len(sizes)
+    tokens, slots, routed = 64, 6, 128
+    top = routing(tokens, slots, routed, sizes, seed=3)
+    x, weights, stacks = expert_inputs(tokens, slots, held, kind, seed=3)
+    rungs = gmm_ops.ladder(tokens * slots, held, routed, TILE)
+    y, rows_held, buffer_rows = jax.jit(
+        lambda *a: routed_experts(*a, num_experts=routed, tile_rows=TILE)
+    )(x, top, weights, *stacks)
+    assert int(rows_held) == sum(sizes) == int((np.asarray(top) < held).sum())
+    assert list(np.asarray(buffer_rows)) == [rungs[rung], rungs[-1]]
+    want = jnp.zeros_like(x)
+    for e in range(held):
+        weight = jnp.sum(jnp.where(top == e, weights, 0.0), axis=-1)
+        if kind == "swiglu":
+            hidden = jax.nn.silu(x @ stacks[0][e]) * (x @ stacks[1][e])
+        else:
+            hidden = jnp.square(jax.nn.relu(x @ stacks[0][e]))
+        want = want + weight[:, None] * (hidden @ stacks[-1][e])
+    np.testing.assert_allclose(y, want, rtol=1e-5, atol=1e-5)
+
+
+def conditionals(jaxpr):
+    """``cond`` equations of a jaxpr outside its Pallas kernels' bodies (a
+    kernel's ``pl.when`` is a ``cond`` of its own)."""
+    found = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            continue
+        found += eqn.primitive.name == "cond"
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += conditionals(sub)
+    return found
+
+
+@pytest.mark.parametrize(
+    "fields, choices",
+    [
+        ({"num_experts": 8}, 0),
+        ({"num_experts": 64, "experts_per_token": 8}, 0),
+        ({"num_experts": 16, "experts_held": 8}, 0),  # twice a half is all
+        ({"num_experts": 16, "experts_held": 4}, 2),
+        ({"num_experts": 16, "experts_held": 4, "expert_kind": "relu2"}, 2),
+    ],
+    ids=["all_8", "all_64", "half", "4_of_16", "4_of_16_relu2"],
+)
+def test_a_layer_that_holds_all_its_experts_has_no_conditional(fields, choices):
+    """Where every routed expert is laid out there is one rung and the
+    program is the one it was: no ``cond`` in forward or backward.  A layer
+    with a ladder chooses once in each."""
+    x = jnp.asarray(np.random.RandomState(0).randn(1, 1024, 16), jnp.float32)
+    layer, variables = init_layer(x, **{"experts_per_token": 2, **fields})
+
+    def loss(params, x):
+        y, _ = layer.apply({**variables, "params": params}, x, mutable=COLLECTIONS)
+        return jnp.sum(y)
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(variables["params"], x)
+    assert conditionals(jaxpr.jaxpr) == choices
+
+
+def test_router_load_reads_the_rung_each_layer_took():
+    """``buffer_rows`` / ``buffer_share`` of ``router_load.read()`` follow
+    the routing across a rung: a router that sends every pair to the four
+    held experts of sixteen walks the full buffer (share 1.0), one that
+    sends them away walks the low rung; a layer that holds all its experts
+    reads 1.0 whatever the routing."""
+    x = jnp.ones((1, 1024, 16), jnp.float32)
+    layer, variables = init_layer(
+        x, num_experts=16, experts_held=4, experts_per_token=2
+    )
+    low, full = gmm_ops.ladder(2048, 4, 16, gmm_ops.TILE_ROWS)
+
+    def read(favoured):
+        kernel = jnp.zeros((16, 16)).at[:, favoured].set(1.0)
+        params = {**variables["params"], "router": {"kernel": kernel}}
+        _, state = layer.apply(
+            {**variables, "params": params}, x, mutable=COLLECTIONS
+        )
+        return router_load.read(state)
+
+    here, away = read(jnp.array([0, 1])), read(jnp.array([14, 15]))
+    assert (here["held_pairs"], here["buffer_rows"], here["buffer_share"]) == (
+        2048, full, 1.0
+    )
+    assert (away["held_pairs"], away["buffer_rows"]) == (0, low)
+    assert away["buffer_share"] == low / full
+    assert here["dropped_pairs"] == away["dropped_pairs"] == 0
+    registry = MetricsRegistry()
+    router_load.publish(registry, layer.apply(
+        {**variables}, x, mutable=COLLECTIONS
+    )[1])
+    assert "elasticdl_router_buffer_share" in registry.exposition()
+
+    whole, variables = init_layer(x, num_experts=16, experts_per_token=2)
+    _, state = whole.apply(variables, x, mutable=COLLECTIONS)
+    load = router_load.read(state)
+    assert load["buffer_share"] == 1.0
+    assert load["buffer_rows"] == gmm_ops.num_rows(2048, 16, gmm_ops.TILE_ROWS)
+
+
+def test_ep_ranks_take_the_low_rung_and_train_like_one_device():
+    """Eight experts over ``ep=4``: each rank lays out its two experts'
+    pairs on a ladder of share 1/4, a balanced routing takes the low rung
+    on every rank (``buffer_share`` under 1), and the step's loss is the
+    one-device step's, which has one rung and the pair-indexed gathers."""
+    rng = np.random.RandomState(0)
+    feats = {"tokens": rng.randint(0, 64, (4, 512)).astype(np.int32)}
+    labels = rng.randint(0, 64, (4, 512)).astype(np.int32)
+    mesh = MeshConfig.from_string("dp=2,ep=4").create()
+    model = lm.custom_model(
+        vocab_size=64, num_layers=1, embed_dim=32, num_heads=2,
+        num_experts=8, experts_per_token=2, positions="rope",
+    )
+    trainer = SPMDTrainer(
+        mesh, model, lm.loss, optax.adam(3e-3), feats,
+        rules=tuple(lm.sharding_rules(mesh)),
+    )
+    params, model_state = init_model(model, feats)
+    one_device = build_train_step(lm.loss, compute_dtype=None)(
+        TrainState.create(model.apply, params, optax.adam(3e-3), model_state),
+        feats, labels,
+    )[1]["loss"]
+    losses = [
+        float(trainer.train_step(
+            trainer.place_batch(feats), trainer.place_batch(labels)
+        )["loss"])
+        for _ in range(3)
+    ]
+    np.testing.assert_allclose(losses[0], float(one_device), rtol=1e-5)
+    assert losses[-1] < losses[0], losses
+    load = router_load.read()
+    low, full = gmm_ops.ladder(2 * 512 * 2, 2, 8, gmm_ops.TILE_ROWS)
+    assert load["dropped_pairs"] == 0 and load["pairs"] == 4 * 512 * 2
+    # eight devices, each with a buffer of its own
+    assert load["buffer_rows"] == 8 * low
+    assert load["buffer_share"] == low / full
+
+
+def test_kernels_of_the_ladders_backward_keep_their_op_names():
+    """The backward of the ladder differentiates the taken rung inside its
+    branch.  A kernel under a differentiation with no scope inside it is
+    named ``jvp(expert_gmm_fwd)`` on the device's op line, where ``perf/``
+    looks for ``expert_gmm_fwd``: so each of them sits under the scope
+    ``rung``, which takes the transform's brackets instead."""
+    top = routing(64, 6, 128, [5, 0, 9, 1, 3, 8, 2, 4])
+    x, weights, stacks = expert_inputs(64, 6, 8, "relu2")
+
+    def loss(x, weights, stacks):
+        return jnp.sum(routed_experts(
+            x, top, weights, *stacks, num_experts=128, tile_rows=TILE,
+            interpret=False,
+        )[0])
+
+    def kernels(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield str(eqn.source_info.name_stack)
+            else:
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    yield from kernels(sub)
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(x, weights, stacks)
+    scopes = list(kernels(jaxpr.jaxpr))
+    # two rungs, each two forward kernels and, in the backward, those two
+    # again, two input gradients and two weight gradients
+    assert len(scopes) == 2 * (2 + 6)
+    names = {gmm_ops.GMM_FWD, gmm_ops.GMM_DX, gmm_ops.GMM_DW}
+    assert all(s.rsplit("/", 1)[-1] in names for s in scopes), scopes
+    assert sum("jvp(rung)" in s for s in scopes) == 2 * 4
