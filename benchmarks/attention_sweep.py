@@ -3,7 +3,8 @@
 A builder's tool for tuning a kernel: its times are a kernel's alone, never
 a ledger number (``perf/run.py`` is the benchmark; nothing under ``perf/``
 imports this).  It runs
-``flash_attention`` forward + gradients at ``--shape B,S,H,D`` in
+``flash_attention`` forward + gradients at ``--shape B,S,H,D`` (``D`` as
+``192:128`` for scores of 192 beside values of 128: latent attention) in
 ``--dtype`` under the profiler and reads each kernel's device time from the
 trace by the name its ``pallas_call`` carries (``flash_fwd``, ``flash_dq``,
 ``flash_dkv``), once per geometry in ``--sweep``.  A geometry is
@@ -96,15 +97,16 @@ def time_geometry(module, geometry, shape, dtype, causal, calls):
         block_q, block_k, chunk = geometry
         kw = dict(block_q=block_q, block_k=block_k)
         if chunk_name == "_CHUNK_BYTES":
-            chunk *= shape[-1] * dtype.itemsize
+            chunk *= min(shape[-2:]) * dtype.itemsize
         setattr(module, chunk_name, chunk)
         # the module's jitted wrappers cache a trace by shapes and blocks,
         # which the chunk constant is not among
         jax.clear_caches()
     keys = jax.random.split(jax.random.PRNGKey(0), 4)
+    d_qk, d_v = shape[3:]
     q, k, v, w = (
-        jax.random.normal(key, shape, jnp.float32).astype(dtype)
-        for key in keys
+        jax.random.normal(key, shape[:3] + (d,), jnp.float32).astype(dtype)
+        for key, d in zip(keys, (d_qk, d_qk, d_v, d_v))
     )
 
     def loss(q, k, v):
@@ -127,7 +129,9 @@ def time_geometry(module, geometry, shape, dtype, causal, calls):
 
 def main() -> int:
     parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    parser.add_argument("--shape", default="1,8192,12,64", help="B,S,H,D")
+    parser.add_argument(
+        "--shape", default="1,8192,12,64", help="B,S,H,D or B,S,H,Dqk:Dv"
+    )
     parser.add_argument("--dtype", default="bfloat16")
     parser.add_argument(
         "--sweep", default="",
@@ -149,12 +153,15 @@ def main() -> int:
     from perf.peaks import peaks_for  # the benchmark's one table of peaks
 
     peak = peaks_for(device.device_kind)["bf16_flops_per_s"]
-    shape = tuple(int(x) for x in args.shape.split(","))
-    batch, seq, heads, d_head = shape
+    *sizes, widths = args.shape.split(",")
+    d_qk, d_v = (int(x) for x in (widths.split(":") * 2)[:2])
+    shape = tuple(int(x) for x in sizes) + (d_qk, d_v)
+    batch, seq, heads = shape[:3]
     causal = not args.non_causal
-    # forward + backward: six matmuls of 2*S*S*D a head, half of them
-    # under the diagonal; a third of that a kernel
-    kernel_flops = 12 * batch * heads * seq * seq * d_head / 3
+    # forward + backward: six matmuls of 2*S*S*D a head (three over the
+    # scores' width, three over the values'), half of them under the
+    # diagonal; a third of that a kernel
+    kernel_flops = 6 * batch * heads * seq * seq * (d_qk + d_v) / 3
     if causal:
         kernel_flops /= 2
     module = load_impl(args.impl)

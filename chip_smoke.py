@@ -115,6 +115,8 @@ SIZES = {
             (1, 8192, 8, 64),
             (8, 1024, 12, 64),
             (2, 4096, 16, 128),
+            # latent attention's two widths: scores of 192, values of 128
+            (1, 4096, 8, 192, 128),
         ),
     ),
     # the rehearsal: same control flow, CPU backend, interpreted kernels
@@ -133,7 +135,7 @@ SIZES = {
         four_chip_steps=8,
         kill_run_steps=24,
         kill_step=2,
-        kernel_shapes=((2, 256, 2, 32), (1, 512, 2, 32)),
+        kernel_shapes=((2, 256, 2, 32), (1, 512, 2, 32), (1, 256, 2, 48, 32)),
     ),
 }
 
@@ -665,11 +667,13 @@ def _child_kernel(run, cfg, workdir):
 
     for shape in cfg["kernel_shapes"]:
         keys = jax.random.split(jax.random.PRNGKey(sum(shape)), 4)
+        # (B, S, H, D), or (B, S, H, D of q and k, D of v)
+        scores, values = shape[:4], shape[:3] + shape[-1:]
         q, k, v = (
-            jax.random.normal(key, shape, jnp.float32).astype(jnp.bfloat16)
-            for key in keys[:3]
+            jax.random.normal(key, dims, jnp.float32).astype(jnp.bfloat16)
+            for key, dims in zip(keys[:3], (scores, scores, values))
         )
-        w = jax.random.normal(keys[3], shape, jnp.float32)
+        w = jax.random.normal(keys[3], values, jnp.float32)
         lowered = jax.jit(flash).lower(q, k, v)
         if cfg["platform"] == "tpu" and "tpu_custom_call" not in (
             lowered.as_text()

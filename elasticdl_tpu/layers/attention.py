@@ -146,16 +146,76 @@ class MultiHeadSelfAttention(nn.Module):
         return out.astype(q.dtype)
 
 
+class LatentSelfAttention(nn.Module):
+    """Multi-head latent attention (DeepSeek-V2/V3, arXiv:2412.19437 section
+    2.1.1) for training: queries and keys/values go through low-rank
+    latents with an RMSNorm on each, a head's query and key are a
+    position-free part beside a rotary part, the rotary key is ONE vector a
+    token shared by every head, and the values are narrower than the
+    scores (``ops/attention.py`` takes the two widths).  Field names are
+    the published configuration's.  The latent cache and the absorbed decode
+    form are not built (docs/designs/latent_attention.md)."""
+
+    num_heads: int
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    rope_theta: float = 10000.0
+    rope_interleave: bool = True  # adjacent pairs | False: rotate halves
+    causal: bool = False
+    dtype: Any = None
+    norm_eps: float = 1e-6
+
+    @nn.compact
+    def __call__(self, x):
+        heads, nope, rot = (
+            self.num_heads, self.qk_nope_head_dim, self.qk_rope_head_dim
+        )
+
+        def dense(features, name, **kwargs):
+            return nn.DenseGeneral(
+                features, use_bias=False, dtype=self.dtype, name=name, **kwargs
+            )
+
+        def norm(name):
+            return nn.RMSNorm(epsilon=self.norm_eps, dtype=self.dtype, name=name)
+
+        q = dense((heads, nope + rot), "q_b")(
+            norm("q_a_norm")(dense(self.q_lora_rank, "q_a")(x))
+        )
+        latent = dense(self.kv_lora_rank + rot, "kv_a")(x)
+        kv = dense((heads, nope + self.v_head_dim), "kv_b")(
+            norm("kv_a_norm")(latent[..., : self.kv_lora_rank])
+        )
+        positions = jnp.arange(x.shape[1])
+        q_rot = rope(
+            q[..., nope:], positions, self.rope_theta, self.rope_interleave
+        )
+        k_rot = rope(
+            latent[..., None, self.kv_lora_rank :], positions,
+            self.rope_theta, self.rope_interleave,
+        )
+        q = jnp.concatenate([q[..., :nope], q_rot], axis=-1)
+        k = jnp.concatenate(
+            [kv[..., :nope], jnp.broadcast_to(k_rot, q_rot.shape)], axis=-1
+        )
+        out = attention_ops.attention(q, k, kv[..., nope:], causal=self.causal)
+        return dense(x.shape[-1], "out", axis=(-2, -1))(out.astype(x.dtype))
+
+
 NORMS = ("layernorm", "rmsnorm")
 MLPS = ("gelu", "swiglu", "relu2")
 
 
-def make_norm(kind: str, epsilon: float, dtype):
-    """The block's norm, auto-named by flax (``LayerNorm_N`` / ``RMSNorm_N``)."""
+def make_norm(kind: str, epsilon: float, dtype, name=None):
+    """The block's norm; without a name flax gives it one (``LayerNorm_N``
+    / ``RMSNorm_N``)."""
     if kind not in NORMS:
         raise ValueError(f"unknown norm {kind!r}; valid: {NORMS}")
     cls = nn.LayerNorm if kind == "layernorm" else nn.RMSNorm
-    return cls(epsilon=epsilon, dtype=dtype)
+    return cls(epsilon=epsilon, dtype=dtype, name=name)
 
 
 # a layer of a ``layer_pattern`` (nemotron_h's ``hybrid_override_pattern``):
@@ -198,10 +258,12 @@ class TransformerBlock(nn.Module):
     router_z_weight: float = 0.001
     kind: str = ""  # "": attention then feed-forward; else one of LAYER_KINDS
     head_dim: int = 0  # 0: the embedding over the heads
-    # further fields of layers.moe.MoEMLP and layers.mamba.Mamba2Mixer, by
-    # their names there
+    # further fields of layers.moe.MoEMLP, layers.mamba.Mamba2Mixer and
+    # LatentSelfAttention, by their names there; latent_fields given makes
+    # the attention part the latent mixer
     moe_fields: Any = ()
     mamba_fields: Any = ()
+    latent_fields: Any = ()
 
     @nn.compact
     def __call__(self, x, training: bool = False, decode_pos=None):
@@ -230,6 +292,18 @@ class TransformerBlock(nn.Module):
         return x + y
 
     def _attention(self, y, training, decode_pos):
+        if self.latent_fields:
+            if self.decode:
+                raise NotImplementedError(
+                    "decoding through a latent-attention layer's cache is "
+                    "not built"
+                )
+            return LatentSelfAttention(
+                num_heads=self.num_heads, causal=self.causal,
+                dtype=self.dtype, norm_eps=self.norm_eps,
+                rope_theta=self.rope_theta, name="attn",
+                **dict(self.latent_fields),
+            )(y)
         return MultiHeadSelfAttention(
             num_heads=self.num_heads,
             causal=self.causal,
@@ -291,19 +365,28 @@ class TransformerBlock(nn.Module):
         return dense(y.shape[-1], "mlp_down")(hidden)
 
 
-def rope(x, positions, theta: float):
-    """Rotary positions (Su et al. 2021) in the rotate-half convention over
-    the whole head, as HF ``apply_rotary_pos_emb``: ``x`` (batch, seq,
-    heads, d), ``positions`` (seq,).  Computed in float32."""
+def rope(x, positions, theta: float, interleave: bool = False):
+    """Rotary positions (Su et al. 2021) over the whole of ``x``'s last
+    axis (hand it the slice of the head that rotates): ``x`` (batch, seq,
+    heads, d), ``positions`` (seq,).  Pair ``i`` turns by ``positions *
+    theta^(-2i/d)``; the pairs are ``(x_i, x_{i+d/2})``, the rotate-half
+    convention of HF ``apply_rotary_pos_emb``, or with ``interleave`` the
+    adjacent ``(x_2i, x_2i+1)`` of the original and of ``rope_interleave``.
+    Computed in float32."""
     half = x.shape[-1] // 2
     rate = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
     angles = positions.astype(jnp.float32)[:, None] * rate[None, :]
     cos = jnp.cos(angles)[None, :, None, :]
     sin = jnp.sin(angles)[None, :, None, :]
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    return jnp.concatenate(
-        [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
-    ).astype(x.dtype)
+    if interleave:
+        pairs = x.astype(jnp.float32).reshape(*x.shape[:-1], half, 2)
+        x1, x2 = pairs[..., 0], pairs[..., 1]
+    else:
+        x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    turned = [x1 * cos - x2 * sin, x2 * cos + x1 * sin]
+    if interleave:
+        return jnp.stack(turned, axis=-1).reshape(x.shape).astype(x.dtype)
+    return jnp.concatenate(turned, axis=-1).astype(x.dtype)
 
 
 def sinusoidal_positions(seq_len: int, dim: int) -> jnp.ndarray:

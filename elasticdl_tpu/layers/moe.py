@@ -47,17 +47,17 @@ balanced load, and every pass between router and combine would walk a
 buffer sized for all of them.  There the buffer's size is chosen each step,
 on the device, from ``grouped_matmul.ladder``'s static sizes: a low rung
 for twice the balanced share (7,168 rows for 8 of 128 experts and 49,152
-pairs, where the full size is 50,176), then the full size, derived from the
-shapes alone.  The pairs are sorted and counted once, outside the choice;
+pairs, where the full size is 50,176), one of twice its rows (14,336
+there), then the full size, derived from the shapes alone.  The pairs are sorted and counted once, outside the choice;
 ``lax.switch`` on the tiles the counts need takes the smallest rung that
 holds them (no host readback), and inside the branch the layout, the three
 kernels' grids, the activation and both permutations run at the rung's
 size.  Below the full rung the permutations move rows and not pairs
 (``_combine_by_rows``, ``_dispatch_by_rows``: the rows added into their
 tokens, the weights' gradient a dot product a row).  "Nothing is ever
-dropped" now rests on the last rung, which is always the full size: a
-routing that outgrows the low rung takes it, at the cost every step paid
-before.  The choice sits inside one ``custom_vjp``
+dropped" now rests on the last rung, which is always the full size, at the
+cost every step paid before; a routing that outgrows the low rung by a few
+thousand rows (a router collapsing onto one held expert) takes the next.  The choice sits inside one ``custom_vjp``
 (``_experts_on_ladder``) whose residuals are its inputs and whose backward
 chooses again and recomputes the taken rung's forward inside the branch, so
 nothing shaped by a rung crosses the choice (plain autodiff through it

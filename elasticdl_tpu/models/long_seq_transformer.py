@@ -14,6 +14,8 @@ is next-token prediction.
 
 from __future__ import annotations
 
+import functools
+import operator
 from typing import Any
 
 import flax.linen as nn
@@ -29,6 +31,7 @@ from elasticdl_tpu.layers.attention import (
     make_norm,
     sinusoidal_positions,
 )
+from elasticdl_tpu.telemetry.router_load import LOSS_PARTS
 from elasticdl_tpu.trainer.losses import (
     softmax_cross_entropy_with_integer_labels,
 )
@@ -92,6 +95,21 @@ class TransformerLM(nn.Module):
     ssm_state: int = 128
     conv_kernel: int = 4
     ssd_chunk: int = 128
+    # kv_lora_rank > 0: the attention parts are latent attention
+    # (layers/attention.py::LatentSelfAttention), fields by their names there
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    rope_interleave: bool = True
+    # multi-token prediction (arXiv:2412.19437 section 2.2): this many
+    # further modules, each one more block behind a projection of
+    # [norm(h) ; norm(embedding of the next token)], sharing tok_embed and
+    # lm_head; in training the model then returns their logits beside the
+    # main ones and ``loss`` adds mtp_weight times their mean loss
+    mtp_depth: int = 0
+    mtp_weight: float = 0.3
 
     @nn.compact
     def __call__(self, features, training: bool = False):
@@ -115,10 +133,11 @@ class TransformerLM(nn.Module):
             # (self, x, training, decode_pos): training is a Python bool
             block_class = nn.remat(TransformerBlock, static_argnums=(2,))
         sinusoidal = self.positions == "sinusoidal"
-        x = nn.Embed(
+        tok_embed = nn.Embed(
             self.vocab_size, self.embed_dim, dtype=self.dtype,
             name="tok_embed",
-        )(tokens)
+        )
+        x = tok_embed(tokens)
         # parameter-free positions: a sequence-sharded activation adds its
         # slice of the encoding without any table gather
         decode_pos = None
@@ -142,8 +161,9 @@ class TransformerLM(nn.Module):
             x = x + sinusoidal_positions(tokens.shape[1], self.embed_dim)[
                 None, :, :
             ].astype(x.dtype)
-        for layer in range(self.num_layers):
-            x = block_class(
+
+        def block(kind, name):
+            return block_class(
                 num_heads=self.num_heads,
                 causal=True,
                 dropout_rate=self.dropout_rate,
@@ -166,7 +186,7 @@ class TransformerLM(nn.Module):
                 norm_topk_prob=self.norm_topk_prob,
                 router_aux_weight=self.router_aux_weight / max(1, expert_layers),
                 router_z_weight=self.router_z_weight / max(1, expert_layers),
-                kind=pattern[layer] if pattern else "",
+                kind=kind,
                 head_dim=self.head_dim,
                 moe_fields=(
                     ("scoring", self.router_scoring),
@@ -186,13 +206,64 @@ class TransformerLM(nn.Module):
                     ("conv_kernel", self.conv_kernel),
                     ("chunk", self.ssd_chunk),
                 ),
-                name=f"block_{layer}",
-            )(x, training, decode_pos)
-        x = make_norm(self.norm, self.norm_eps, self.dtype)(x)
-        return nn.Dense(
+                latent_fields=(
+                    ("q_lora_rank", self.q_lora_rank),
+                    ("kv_lora_rank", self.kv_lora_rank),
+                    ("qk_nope_head_dim", self.qk_nope_head_dim),
+                    ("qk_rope_head_dim", self.qk_rope_head_dim),
+                    ("v_head_dim", self.v_head_dim),
+                    ("rope_interleave", self.rope_interleave),
+                ) if self.kv_lora_rank else (),
+                name=name,
+            )
+
+        def norm(name=None):
+            return make_norm(self.norm, self.norm_eps, self.dtype, name)
+
+        for layer in range(self.num_layers):
+            x = block(pattern[layer] if pattern else "", f"block_{layer}")(
+                x, training, decode_pos
+            )
+        lm_head = nn.Dense(
             self.vocab_size, dtype=self.dtype, use_bias=self.use_bias,
             name="lm_head",
-        )(x)
+        )
+        logits = lm_head(norm()(x))
+        if self.decode or not (
+            self.mtp_depth and (training or self.is_initializing())
+        ):
+            return logits
+        # module k predicts token i + k + 1 at position i from the state
+        # below it and token i + k.  Every module runs at all positions (the
+        # kernels' blocks divide the sequence): the last k columns read
+        # tokens rolled round from the front, the causal mask keeps them
+        # from every earlier column, and ``loss`` leaves them out
+        for part in ("main", "mtp"):
+            # asks trainer/step.py to leave the two losses in the state
+            self.variable(LOSS_PARTS, part, lambda: jnp.zeros((), jnp.float32))
+        mtp_logits = []
+        for k in range(1, self.mtp_depth + 1):
+            ahead = tok_embed(jnp.roll(tokens, -k, axis=1))
+            x = nn.Dense(
+                self.embed_dim, use_bias=False, dtype=self.dtype,
+                name=f"mtp_{k}_proj",
+            )(
+                jnp.concatenate(
+                    [
+                        norm(f"mtp_{k}_hnorm")(x),
+                        norm(f"mtp_{k}_enorm")(ahead),
+                    ],
+                    axis=-1,
+                )
+            )
+            x = block("", f"mtp_{k}_block")(x, training, decode_pos)
+            mtp_logits.append(lm_head(norm(f"mtp_{k}_norm")(x)))
+        return {
+            "logits": logits,
+            "mtp_logits": tuple(mtp_logits),
+            # a value a row: the step's masked loss maps ``loss`` over rows
+            "mtp_weight": jnp.full(tokens.shape[:1], self.mtp_weight),
+        }
 
 
 def custom_model(**kwargs):
@@ -215,8 +286,45 @@ def sharding_rules(mesh):
     return tuple(rules)
 
 
-def loss(labels, logits):
-    return softmax_cross_entropy_with_integer_labels(logits, labels).mean()
+def loss_parts(labels, outputs) -> dict:
+    """The loss by its named parts; ``loss`` is their sum.  ``main``: the
+    next token's mean cross-entropy.  ``mtp`` (a training forward with
+    ``mtp_depth``): ``mtp_weight`` times the mean over the modules of
+    ``L_k``, the cross-entropy of token ``i + k + 1`` summed over the
+    ``T - k`` positions of a row that have one in ``labels`` and divided
+    by ``T`` (arXiv:2412.19437, eqs. 24, 25).  Each a mean of per-row
+    terms, so ``trainer/step.py::weighted_mean_loss`` masks padded rows."""
+    if not isinstance(outputs, dict):
+        return {
+            "main": softmax_cross_entropy_with_integer_labels(
+                outputs, labels
+            ).mean()
+        }
+    seq = labels.shape[1]
+    modules = []
+    for k, logits in enumerate(outputs["mtp_logits"], 1):
+        per_token = softmax_cross_entropy_with_integer_labels(
+            logits, jnp.roll(labels, -k, axis=1)
+        )
+        modules.append(
+            jnp.where(jnp.arange(seq) < seq - k, per_token, 0.0).mean()
+        )
+    return {
+        "main": softmax_cross_entropy_with_integer_labels(
+            outputs["logits"], labels
+        ).mean(),
+        "mtp": outputs["mtp_weight"].mean() * sum(modules) / len(modules),
+    }
+
+
+def loss(labels, outputs):
+    # (not ``sum``: its ``0 +`` would be one more op in every model's step)
+    return functools.reduce(
+        operator.add, loss_parts(labels, outputs).values()
+    )
+
+
+loss.parts = loss_parts
 
 
 def optimizer(lr=3e-3):

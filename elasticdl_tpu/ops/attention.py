@@ -120,6 +120,10 @@ def validate_gqa_heads(q, k, v) -> int:
     must agree, and q heads must be a multiple of kv heads.  Returns the
     group factor (1 = plain MHA)."""
     q_heads, kv_heads = q.shape[2], k.shape[2]
+    if k.shape[-1] != q.shape[-1]:
+        raise ValueError(
+            f"q and k head widths differ: {q.shape[-1]} vs {k.shape[-1]}"
+        )
     if v.shape[2] != kv_heads:
         raise ValueError(
             f"k and v head counts differ: {kv_heads} vs {v.shape[2]}"
@@ -585,7 +589,9 @@ def flash_attention(
     block_k: int = 512,
     interpret: bool | None = None,
 ):
-    """Blockwise flash attention, (B, S, H, D) layout.
+    """Blockwise flash attention, (B, S, H, D) layout.  ``q`` and ``k``
+    share a width (the scores', which sets the default scale); ``v`` and the
+    output may have another (latent attention: 192 beside 128).
 
     ``interpret=None`` follows the default backend through
     :func:`kernel_interpret` (interpreted on CPU, compiled on TPU).
@@ -602,17 +608,23 @@ def flash_attention(
     return out
 
 
-def _flash_geometry(q, k, sm_scale, block_q, block_k, interpret):
+def _flash_geometry(q, k, v, sm_scale, block_q, block_k, interpret):
     """Defaults filled in, blocks made to divide the sequences, and the
     rows of a staged chunk of q (for dK/dV) and of k (for the forward and
-    dQ), from what the call can see: lengths, head width, dtype."""
+    dQ), from what the call can see: lengths, head widths, dtype.  Where
+    the scores are wider than the values (latent attention: 192 beside 128)
+    the narrower width sizes the chunk: measured on a TPU v5e, kernels
+    alone, (1, 8192, 32, 192 | 128): 28.04 / 26.27 / 24.64 ms with chunks of
+    1,024 (what 192 alone would give) / 2,048 / 4,096 rows; 8,192 do not
+    fit VMEM (PERF.md section 6, PR 34)."""
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     if interpret is None:
         interpret = kernel_interpret(jax.default_backend())
     block_q = _pick_block(q.shape[1], block_q)
     block_k = _pick_block(k.shape[1], block_k)
-    rows = _CHUNK_BYTES // (q.shape[-1] * q.dtype.itemsize)
+    width = min(q.shape[-1], v.shape[-1])
+    rows = _CHUNK_BYTES // (width * q.dtype.itemsize)
     chunk_q = _pick_chunk(q.shape[1], block_q, rows)
     chunk_k = _pick_chunk(k.shape[1], block_k, rows)
     return sm_scale, block_q, block_k, chunk_q, chunk_k, interpret
@@ -650,9 +662,10 @@ def _last_live_chunk(i, block_q, chunk_k):
 def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret):
     q, k, v = jnp.asarray(q), jnp.asarray(k), jnp.asarray(v)
     sm_scale, block_q, block_k, _, chunk_k, interpret = _flash_geometry(
-        q, k, sm_scale, block_q, block_k, interpret
+        q, k, v, sm_scale, block_q, block_k, interpret
     )
     batch, seq_q, heads, d = q.shape
+    d_v = v.shape[-1]
     group = validate_gqa_heads(q, k, v)
     kv_heads = k.shape[2]
     seq_k = k.shape[1]
@@ -679,21 +692,21 @@ def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret):
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, c: (b, i, 0)),
             pl.BlockSpec((1, chunk_k, d), _kv_index),
-            pl.BlockSpec((1, chunk_k, d), _kv_index),
+            pl.BlockSpec((1, chunk_k, d_v), _kv_index),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, c: (b, i, 0)),
+            pl.BlockSpec((1, block_q, d_v), lambda b, i, c: (b, i, 0)),
             pl.BlockSpec((1, 1, block_q), lambda b, i, c: (b, 0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((batch * heads, seq_q, d), q.dtype),
+            jax.ShapeDtypeStruct((batch * heads, seq_q, d_v), q.dtype),
             # lane-major and compact: a row of seq_q float32 a head
             jax.ShapeDtypeStruct((batch * heads, 1, seq_q), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, _LANES), jnp.float32),  # m
             pltpu.VMEM((block_q, _LANES), jnp.float32),  # l
-            pltpu.VMEM((block_q, d), jnp.float32),  # acc
+            pltpu.VMEM((block_q, d_v), jnp.float32),  # acc
         ],
         compiler_params=_FLASH_COMPILER_PARAMS,
         interpret=interpret,
@@ -711,9 +724,10 @@ def _flash_backward(
     q, k, v = jnp.asarray(q), jnp.asarray(k), jnp.asarray(v)
     out, lse, g = jnp.asarray(out), jnp.asarray(lse), jnp.asarray(g)
     sm_scale, bq, bk, chunk_q, chunk_k, interpret = _flash_geometry(
-        q, k, sm_scale, block_q, block_k, interpret
+        q, k, v, sm_scale, block_q, block_k, interpret
     )
     batch, seq_q, heads, d = q.shape
+    d_v = v.shape[-1]
     group = validate_gqa_heads(q, k, v)
     kv_heads = k.shape[2]
     seq_k = k.shape[1]
@@ -743,8 +757,8 @@ def _flash_backward(
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda b, i, c: (b, i, 0)),
             pl.BlockSpec((1, chunk_k, d), _kv_chunk_index),
-            pl.BlockSpec((1, chunk_k, d), _kv_chunk_index),
-            pl.BlockSpec((1, bq, d), lambda b, i, c: (b, i, 0)),
+            pl.BlockSpec((1, chunk_k, d_v), _kv_chunk_index),
+            pl.BlockSpec((1, bq, d_v), lambda b, i, c: (b, i, 0)),
             pl.BlockSpec((1, 1, bq), lambda b, i, c: (b, 0, i)),
             pl.BlockSpec((1, 1, bq), lambda b, i, c: (b, 0, i)),
         ],
@@ -784,22 +798,22 @@ def _flash_backward(
         in_specs=[
             pl.BlockSpec((1, chunk_q, d), _q_chunk_index),
             pl.BlockSpec((1, bk, d), _k_block_index),
-            pl.BlockSpec((1, bk, d), _k_block_index),
-            pl.BlockSpec((1, chunk_q, d), _q_chunk_index),
+            pl.BlockSpec((1, bk, d_v), _k_block_index),
+            pl.BlockSpec((1, chunk_q, d_v), _q_chunk_index),
             row_spec,
             row_spec,
         ],
         out_specs=[
             pl.BlockSpec((1, bk, d), lambda b, j, c: (b, j, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, j, c: (b, j, 0)),
+            pl.BlockSpec((1, bk, d_v), lambda b, j, c: (b, j, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((batch * heads, seq_k, d), k.dtype),
-            jax.ShapeDtypeStruct((batch * heads, seq_k, d), v.dtype),
+            jax.ShapeDtypeStruct((batch * heads, seq_k, d_v), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((bk, d), jnp.float32),  # dk
-            pltpu.VMEM((bk, d), jnp.float32),  # dv
+            pltpu.VMEM((bk, d_v), jnp.float32),  # dv
         ],
         compiler_params=_FLASH_COMPILER_PARAMS,
         interpret=interpret,
@@ -812,7 +826,7 @@ def _flash_backward(
     if group > 1:
         # sum each kv head's query group: (B, S, H, D) -> (B, S, KVH, D)
         dk = dk.reshape(batch, seq_k, kv_heads, group, d).sum(axis=3)
-        dv = dv.reshape(batch, seq_k, kv_heads, group, d).sum(axis=3)
+        dv = dv.reshape(batch, seq_k, kv_heads, group, d_v).sum(axis=3)
     return dq, dk.astype(k.dtype), dv.astype(v.dtype)
 
 

@@ -24,7 +24,8 @@ buffer for all of them is walked almost empty by every pass between the
 router and the combine.  :func:`ladder` therefore gives a short ascending
 list of static buffer sizes derived from the shapes alone: a low rung that
 holds ``LOW_RUNG_SHARES`` times the balanced share (plus the tile a group may
-waste), then the full size.  The caller (``layers/moe.py``) sorts the pairs
+waste), one of twice its rows, then the full size.  The caller (``layers/moe.py``)
+sorts the pairs
 once (:func:`group_order`), reads off the device how many row tiles this
 step's routing needs (:func:`tiles_needed`) and takes, on the device, the
 smallest rung that holds them; :func:`group_layout` lays the pairs out at
@@ -62,11 +63,17 @@ TILE_ROWS = 128
 # routing sends to the groups laid out here.  Twice: a load-balanced router
 # keeps a device's share of the pairs (the sum over its experts, steadier
 # than any one expert's) well inside it, and a buffer twice the needed size
-# costs what an empty tile costs, a skipped grid step.  There is no rung
-# between this one and the full size: past twice its share a device is the
-# straggler of its mesh whatever its buffer, every further rung is one more
-# compiled copy of each expert layer's routed part (forward and backward),
-# and the full rung costs what every step cost before the ladder
+# costs what an empty tile costs, a skipped grid step.  One rung of twice
+# its rows stands between it and the full size (PR 34): a router that
+# collapses onto one held expert, as routers without a warm-up do in their
+# first dozens of steps, overflows the low rung by a few thousand rows, and
+# with nothing between that step walked the full size, 6.6 times the rows
+# and 22 ms a layer in ``joyai_flash_seq8192``, so that a window's rate
+# followed the routing of its seed (PERF.md section 6).  Past four times
+# its share a device is the straggler of its mesh whatever its buffer, and
+# every rung is one more compiled copy of each expert layer's routed part
+# (forward and backward); the full rung costs what every step cost before
+# the ladder
 LOW_RUNG_SHARES = 2
 _MAX_BLOCK_BYTES = 4 * 1024 * 1024
 _VMEM_LIMIT_BYTES = 64 * 1024 * 1024
@@ -106,12 +113,12 @@ def ladder(
     """The static buffer sizes (rows, ascending) for ``pairs`` routed over
     ``routed_groups`` groups of which ``num_groups`` are laid out here.  The
     last is ``num_rows(pairs, ...)``, which holds any routing; before it, a
-    rung for ``LOW_RUNG_SHARES`` times the balanced share of the pairs,
-    where that is smaller."""
+    rung for ``LOW_RUNG_SHARES`` times the balanced share of the pairs and
+    one of twice its rows, where they are smaller."""
     full = num_rows(pairs, num_groups, tile_rows)
     balanced = -(-LOW_RUNG_SHARES * pairs * num_groups // routed_groups)
     low = num_rows(balanced, num_groups, tile_rows)
-    return (low, full) if low < full else (full,)
+    return tuple(rows for rows in (low, 2 * low) if rows < full) + (full,)
 
 
 def group_order(group_ids, num_groups: int) -> GroupOrder:

@@ -22,6 +22,10 @@ import numpy as np
 
 # the collection ``layers/moe.py`` sows into
 ROUTER_STATS = "router_stats"
+# the collection a model declares (one scalar a name) to have
+# ``trainer/step.py`` leave its loss there by the parts its ``loss.parts``
+# names: a multi-token-prediction model's ``main`` and ``mtp``
+LOSS_PARTS = "loss_parts"
 
 _watched = None
 
@@ -32,6 +36,24 @@ def watch(trainer):
     _watched = weakref.ref(trainer)
 
 
+def _state(model_state):
+    if model_state is not None:
+        return model_state
+    trainer = _watched() if _watched is not None else None
+    return None if trainer is None else trainer.state.model_state
+
+
+def read_loss_parts(model_state=None) -> dict | None:
+    """The newest train step's loss by its parts (``{"main": ..., "mtp":
+    ...}``: the next token's loss and the weighted second-token loss, whose
+    sum the step reported), one host readback.  None for a model that
+    declares no ``LOSS_PARTS`` collection."""
+    parts = (_state(model_state) or {}).get(LOSS_PARTS)
+    if not parts:
+        return None
+    return {k: float(v) for k, v in jax.device_get(parts).items()}
+
+
 def read(model_state=None) -> dict | None:
     """The newest step's router load, one host readback: the worst layer's
     busiest expert over the mean load, experts that got no pair, the pairs
@@ -40,12 +62,7 @@ def read(model_state=None) -> dict | None:
     rows of the buffers the dispatch walked, as a count and as a share of
     the full rung's (1.0 where every layer took its largest or only rung).
     None for a model without experts."""
-    if model_state is None:
-        trainer = _watched() if _watched is not None else None
-        if trainer is None:
-            return None
-        model_state = trainer.state.model_state
-    stats = (model_state or {}).get(ROUTER_STATS)
+    stats = (_state(model_state) or {}).get(ROUTER_STATS)
     if not stats:
         return None
     flat = {
@@ -79,7 +96,13 @@ def read(model_state=None) -> dict | None:
 
 
 def publish(registry, model_state=None) -> dict | None:
-    """:func:`read`, set as gauges on ``registry``."""
+    """:func:`read` and :func:`read_loss_parts`, set as gauges on
+    ``registry``; returns the router load."""
+    for name, value in (read_loss_parts(model_state) or {}).items():
+        registry.gauge(
+            f"elasticdl_train_loss_{name}",
+            "a named part of the newest train step's loss",
+        ).set(value)
     load = read(model_state)
     if load is not None:
         for name in (

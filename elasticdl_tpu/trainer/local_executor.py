@@ -393,6 +393,10 @@ class LocalExecutor:
         if load is not None:
             results["router_load"] = load
             telemetry_hooks.emit_event("router_load", **load)
+        parts = router_load.read_loss_parts(self._trainer.state.model_state)
+        if parts is not None:
+            results["train_loss_parts"] = parts
+            telemetry_hooks.emit_event("train_loss_parts", **parts)
         logger.info("Evaluation (%s): %s", tag, results)
         return results
 

@@ -20,6 +20,7 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 
+from elasticdl_tpu.telemetry.router_load import LOSS_PARTS
 from elasticdl_tpu.trainer.state import TrainState
 
 
@@ -95,11 +96,14 @@ def weighted_mean_loss(loss_fn, labels, outputs, weights):
         outputs_1 = jax.tree_util.tree_map(lambda x: x[None], outputs_row)
         return loss_fn(labels_1, outputs_1)
 
-    per_row = jax.vmap(one_row)(labels, outputs)
-    weights = weights.astype(per_row.dtype)
-    # max(sum, 1) guards the (never-dispatched) all-zero mask; a real
-    # dispatch always carries >= 1 real row
-    return jnp.sum(weights * per_row) / jnp.maximum(jnp.sum(weights), 1.0)
+    def mean(per_row):
+        w = weights.astype(per_row.dtype)
+        # max(sum, 1) guards the (never-dispatched) all-zero mask; a real
+        # dispatch always carries >= 1 real row
+        return jnp.sum(w * per_row) / jnp.maximum(jnp.sum(w), 1.0)
+
+    # (a ``loss_fn`` that returns its loss by named parts gets each weighted)
+    return jax.tree_util.tree_map(mean, jax.vmap(one_row)(labels, outputs))
 
 
 _DONATION_WARNING_PATTERN = "Some donated buffers were not usable"
@@ -178,10 +182,24 @@ def build_train_step(
             features = device_parse(features)
         features = _cast_floats(features, compute_dtype)
         outputs, new_model_state = _apply(state, params, features, True)
+        # a model that declares the LOSS_PARTS collection (a second-token
+        # loss beside the main one) has its loss computed by the named
+        # parts ``loss_fn.parts`` gives; they leave the step inside the
+        # state, for telemetry/router_load.py to read on demand
+        by_parts = LOSS_PARTS in new_model_state
+        fn = loss_fn.parts if by_parts else loss_fn
         if weights is None:
-            loss = loss_fn(labels, outputs)
+            loss = fn(labels, outputs)
         else:
-            loss = weighted_mean_loss(loss_fn, labels, outputs, weights)
+            loss = weighted_mean_loss(fn, labels, outputs, weights)
+        if by_parts:
+            new_model_state = {
+                **new_model_state,
+                LOSS_PARTS: jax.lax.stop_gradient(
+                    {k: v.astype(jnp.float32) for k, v in loss.items()}
+                ),
+            }
+            loss = sum(loss.values())
         # layer-contributed losses (MoE load balancing, regularizers):
         # any value sown into the "losses" collection joins the training
         # loss — the reference adds Keras model reg losses the same way

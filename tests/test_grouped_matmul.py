@@ -149,7 +149,8 @@ def test_kernels_carry_the_names_the_benchmark_reads():
 
 LADDERS = {
     # pairs, groups here, groups routed over, tile rows -> rungs (rows)
-    "the_cell_8_of_128": ((49152, 8, 128, 128), (7168, 50176)),
+    "the_cell_8_of_128": ((49152, 8, 128, 128), (7168, 14336, 50176)),
+    "the_cell_16_of_256": ((65536, 16, 256, 128), (10240, 20480, 67584)),
     "ep4_of_64": ((65536, 16, 64, 128), (34816, 67584)),
     "every_group_here": ((65536, 64, 64, 128), (73728,)),
     "half_the_groups": ((1000, 4, 8, 8), (1032,)),  # twice a half is all
@@ -160,14 +161,18 @@ LADDERS = {
 @pytest.mark.parametrize("case", LADDERS.values(), ids=LADDERS.keys())
 def test_ladder_is_derived_from_the_share_of_the_groups(case):
     """The low rung holds twice the balanced share of the pairs plus a tile
-    a group; the last rung is ``num_rows`` of all pairs; a layout that
-    holds every routed group has that one rung."""
+    a group, the next twice its rows; the last
+    rung is ``num_rows`` of all pairs; a layout that holds every routed
+    group has that one rung."""
     (pairs, groups, routed, tile_rows), rungs = case
     assert g.ladder(pairs, groups, routed, tile_rows) == rungs
     assert rungs[-1] == g.num_rows(pairs, groups, tile_rows)
     if len(rungs) > 1:
         held = -(-g.LOW_RUNG_SHARES * pairs * groups // routed)
         assert rungs[0] == (-(-held // tile_rows) + groups) * tile_rows
+    for below, above in zip(rungs, rungs[1:-1]):
+        assert above == 2 * below
+    assert all(r % tile_rows == 0 for r in rungs) and list(rungs) == sorted(set(rungs))
 
 
 @pytest.mark.parametrize("tile_rows", [8, 16])
@@ -191,9 +196,9 @@ def test_layout_at_a_rung_holds_every_grouped_pair_once(sizes, tile_rows):
     needed = int(g.tiles_needed(order.sizes, tile_rows))
     assert needed == sum(max(-(-s // tile_rows), 1) for s in sizes)
     rungs = g.ladder(200, GROUPS, 40, tile_rows)
-    assert len(rungs) == 2 and needed * tile_rows <= rungs[0]
+    assert len(rungs) >= 2 and needed * tile_rows <= rungs[0]
     low = g.group_layout(ids, GROUPS, tile_rows, rungs[0], order)
-    full = g.group_layout(ids, GROUPS, tile_rows, rungs[1])
+    full = g.group_layout(ids, GROUPS, tile_rows, rungs[-1])
     # laid out from the rows' side, the same rows without the pairs' index
     by_rows = g.group_layout(ids, GROUPS, tile_rows, rungs[0], order, by_rows=True)
     assert by_rows.pair_row is None

@@ -260,7 +260,7 @@ def test_low_rung_and_full_rung_agree(share, kind):
     tokens = 64
     top = routing(tokens, slots, routed, sizes)
     x, weights, stacks = expert_inputs(tokens, slots, held, kind)
-    low, full = gmm_ops.ladder(tokens * slots, held, routed, TILE)
+    low, *_, full = gmm_ops.ladder(tokens * slots, held, routed, TILE)
     group_ids = jnp.where(top < held, top, held).reshape(-1)
     order = gmm_ops.group_order(group_ids, held)
     assert int(gmm_ops.tiles_needed(order.sizes, TILE)) * TILE <= low < full
@@ -297,10 +297,13 @@ def test_low_rung_and_full_rung_agree(share, kind):
 
 CROSSINGS = {
     # pairs a held expert gets (8 of 128, top-6, 64 tokens: the low rung is
-    # 14 tiles of 8 rows) -> the rung that must be taken
+    # 14 tiles of 8 rows, the next 28, the full one 56) -> the rung that
+    # must be taken
     "fits_the_low_rung_exactly": ([56, 0, 0, 0, 0, 0, 0, 0], 0),
     "one_tile_more_than_the_low_rung": ([57, 0, 0, 0, 0, 0, 0, 0], 1),
-    "every_token_on_every_held_expert": ([64, 64, 64, 64, 64, 64], 1),
+    "fits_the_middle_rung_exactly": ([64, 64, 56, 0, 0, 0, 0, 0], 1),
+    "one_tile_more_than_the_middle_rung": ([64, 64, 57, 0, 0, 0, 0, 0], 2),
+    "every_token_on_every_held_expert": ([64, 64, 64, 64, 64, 64], -1),
     "no_pair_here": ([0] * 8, 0),
 }
 
@@ -308,8 +311,8 @@ CROSSINGS = {
 @pytest.mark.parametrize("kind", ["swiglu", "relu2"])
 @pytest.mark.parametrize("case", CROSSINGS.values(), ids=CROSSINGS.keys())
 def test_rung_follows_the_routing_and_nothing_is_dropped(case, kind):
-    """The smallest rung that holds the routing's tiles is taken; a routing
-    past the low rung takes the full one, whose size holds any: the
+    """The smallest rung that holds the routing's tiles is taken, the last
+    of which holds any routing: the
     dispatch's own count of rows equals the held pairs, and the output is
     the dense per-expert sum."""
     sizes, rung = case
@@ -383,7 +386,7 @@ def test_router_load_reads_the_rung_each_layer_took():
     layer, variables = init_layer(
         x, num_experts=16, experts_held=4, experts_per_token=2
     )
-    low, full = gmm_ops.ladder(2048, 4, 16, gmm_ops.TILE_ROWS)
+    low, *_, full = gmm_ops.ladder(2048, 4, 16, gmm_ops.TILE_ROWS)
 
     def read(favoured):
         kernel = jnp.zeros((16, 16)).at[:, favoured].set(1.0)
@@ -444,7 +447,7 @@ def test_ep_ranks_take_the_low_rung_and_train_like_one_device():
     np.testing.assert_allclose(losses[0], float(one_device), rtol=1e-5)
     assert losses[-1] < losses[0], losses
     load = router_load.read()
-    low, full = gmm_ops.ladder(2 * 512 * 2, 2, 8, gmm_ops.TILE_ROWS)
+    low, *_, full = gmm_ops.ladder(2 * 512 * 2, 2, 8, gmm_ops.TILE_ROWS)
     assert load["dropped_pairs"] == 0 and load["pairs"] == 4 * 512 * 2
     # eight devices, each with a buffer of its own
     assert load["buffer_rows"] == 8 * low
@@ -476,9 +479,10 @@ def test_kernels_of_the_ladders_backward_keep_their_op_names():
 
     jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(x, weights, stacks)
     scopes = list(kernels(jaxpr.jaxpr))
-    # two rungs, each two forward kernels and, in the backward, those two
+    # three rungs, each two forward kernels and, in the backward, those two
     # again, two input gradients and two weight gradients
-    assert len(scopes) == 2 * (2 + 6)
+    assert len(gmm_ops.ladder(64 * 6, 8, 128, TILE)) == 3
+    assert len(scopes) == 3 * (2 + 6)
     names = {gmm_ops.GMM_FWD, gmm_ops.GMM_DX, gmm_ops.GMM_DW}
     assert all(s.rsplit("/", 1)[-1] in names for s in scopes), scopes
-    assert sum("jvp(rung)" in s for s in scopes) == 2 * 4
+    assert sum("jvp(rung)" in s for s in scopes) == 3 * 4
