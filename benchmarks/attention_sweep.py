@@ -18,8 +18,17 @@ of the module (the parent commit's, say) in the same process.
     python benchmarks/attention_sweep.py --shape 1,8192,12,64 \\
         --sweep "512,512,2048;1024,512,2048" [--impl chip_parent/...py]
 
-One JSON line per (implementation, geometry): milliseconds a call for each
-kernel and each kernel's share of the bf16 peak, counted as
+PR 36 (heads read out of the layer's own layout) ran, parent's copy beside
+the change in one call:
+
+    python benchmarks/attention_sweep.py --shape 8,1024,12,64 \
+        [--impl chip_parent/elasticdl_tpu/ops/attention.py]
+    python benchmarks/attention_sweep.py --shape 1,8192,12,64 \
+        [--impl chip_parent/elasticdl_tpu/ops/attention.py]
+
+One JSON line per (implementation, geometry): ``flash_layout`` (``"lanes"``
+or ``"folded"``: how the kernels address a head at this shape), milliseconds
+a call for each kernel and each kernel's share of the bf16 peak, counted as
 ``perf/kernel_rooflines.py`` counts it (a third of the analytic causal
 attention FLOPs a kernel; the recomputed scores are not counted).  A
 geometry Mosaic refuses is reported with its error, not skipped in silence.
@@ -45,15 +54,21 @@ sys.path.insert(
 KERNELS = ("flash_fwd", "flash_dq", "flash_dkv")
 
 
+OTHER = "other_ops"
+
+
 def kernel_ms(trace_dir: str, calls: int) -> dict:
     """Device milliseconds a call of each flash kernel, from the op line
-    of the first device plane of the trace under ``trace_dir``."""
+    of the first device plane of the trace under ``trace_dir``, and of
+    every other op of the program together (``other_ops``: the copies that
+    fold heads on either side of a kernel, the reduction that follows a
+    grouped dK/dV, the loss)."""
     from jax.profiler import ProfileData
 
     (path,) = glob.glob(
         os.path.join(trace_dir, "plugins", "profile", "*", "*.xplane.pb")
     )
-    total = dict.fromkeys(KERNELS, 0)
+    total = dict.fromkeys(KERNELS + (OTHER,), 0)
     for plane in ProfileData.from_file(path).planes:
         if not re.match(r"^/device:TPU:\d+$", plane.name):
             continue
@@ -64,9 +79,8 @@ def kernel_ms(trace_dir: str, calls: int) -> dict:
                 # "%transpose_jvp_flash_dkv__.1 = ...": under a bare jit
                 # the op's name wraps the kernel's in its transformations
                 name = event.name.partition(" = ")[0]
-                for kernel in KERNELS:
-                    if kernel in name:
-                        total[kernel] += int(event.duration_ns)
+                kernel = next((k for k in KERNELS if k in name), OTHER)
+                total[kernel] += int(event.duration_ns)
         break
     return {k: ns / 1e6 / calls for k, ns in total.items()}
 
@@ -84,10 +98,23 @@ def load_impl(path: str | None):
     return module
 
 
+def flash_layout_of(module, q, k, v) -> str:
+    """How ``module``'s kernels address a head at these shapes; a copy from
+    before PR 36 has only the folded form."""
+    layout = getattr(module, "flash_layout", None)
+    return layout(q, k, v) if layout else "folded"
+
+
 def time_geometry(module, geometry, shape, dtype, causal, calls):
     import jax
     import jax.numpy as jnp
 
+    keys = jax.random.split(jax.random.PRNGKey(0), 4)
+    d_qk, d_v = shape[3:]
+    q, k, v, w = (
+        jax.random.normal(key, shape[:3] + (d,), jnp.float32).astype(dtype)
+        for key, d in zip(keys, (d_qk, d_qk, d_v, d_v))
+    )
     kw = {}
     chunk_name = (
         "_CHUNK_BYTES" if hasattr(module, "_CHUNK_BYTES") else "_SEQ_CHUNK"
@@ -97,17 +124,12 @@ def time_geometry(module, geometry, shape, dtype, causal, calls):
         block_q, block_k, chunk = geometry
         kw = dict(block_q=block_q, block_k=block_k)
         if chunk_name == "_CHUNK_BYTES":
+            # rows to bytes, as ``_flash_geometry`` turns them back
             chunk *= min(shape[-2:]) * dtype.itemsize
         setattr(module, chunk_name, chunk)
         # the module's jitted wrappers cache a trace by shapes and blocks,
         # which the chunk constant is not among
         jax.clear_caches()
-    keys = jax.random.split(jax.random.PRNGKey(0), 4)
-    d_qk, d_v = shape[3:]
-    q, k, v, w = (
-        jax.random.normal(key, shape[:3] + (d,), jnp.float32).astype(dtype)
-        for key, d in zip(keys, (d_qk, d_qk, d_v, d_v))
-    )
 
     def loss(q, k, v):
         out = module.flash_attention(q, k, v, causal=causal, **kw)
@@ -121,7 +143,7 @@ def time_geometry(module, geometry, shape, dtype, causal, calls):
             for _ in range(calls):
                 out = step(q, k, v)
             jax.block_until_ready(out)
-        return kernel_ms(trace_dir, calls)
+        return kernel_ms(trace_dir, calls), flash_layout_of(module, q, k, v)
     finally:
         shutil.rmtree(trace_dir, ignore_errors=True)
         setattr(module, chunk_name, module_chunk)
@@ -180,7 +202,7 @@ def main() -> int:
             "device_kind": device.device_kind,
         }
         try:
-            ms = time_geometry(
+            ms, line["flash_layout"] = time_geometry(
                 module, geometry, shape, jnp.dtype(args.dtype), causal,
                 args.calls,
             )
@@ -188,11 +210,11 @@ def main() -> int:
             line["error"] = f"{type(ex).__name__}: {str(ex)[:300]}"
         else:
             line["ms"] = {k: round(v, 4) for k, v in ms.items()}
-            line["ms_total"] = round(sum(ms.values()), 4)
+            line["ms_total"] = round(sum(ms[k] for k in KERNELS), 4)
             line["roofline_pct"] = {
-                k: round(100 * kernel_flops / (v / 1e3) / peak, 2)
-                for k, v in ms.items()
-                if v
+                k: round(100 * kernel_flops / (ms[k] / 1e3) / peak, 2)
+                for k in KERNELS
+                if ms[k]
             }
         print(json.dumps(line), flush=True)
     return 0

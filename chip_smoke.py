@@ -107,16 +107,20 @@ SIZES = {
         # tasks are still unleased when the worker dies
         kill_run_steps=48,
         kill_step=6,
-        # the smoke model's shape, then the benchmark cells' three: the
-        # 8,192 one at 8 heads of its 12 (the float32 reference holds a
-        # few (B, H, S, S) arrays: 2.1 GB each here, 3.2 at 12 heads)
+        # (B, S, H, D), (B, S, H, D of q and k, D of v), or that with the
+        # key/value heads: the smoke model's shape, then the one each LM
+        # cell of the benchmark hands the kernels, whole (the float32
+        # reference is taken a few query heads at a time).  Heads read out
+        # of the layer's own layout ("lanes") at width 64, the folded form
+        # at 128, grouped and not, and at 192 | 128 (PR 36)
         kernel_shapes=(
             (8, 2048, 12, 64),
-            (1, 8192, 8, 64),
             (8, 1024, 12, 64),
+            (1, 8192, 12, 64),
             (2, 4096, 16, 128),
+            (1, 8192, 32, 128, 128, 2),
             # latent attention's two widths: scores of 192, values of 128
-            (1, 4096, 8, 192, 128),
+            (1, 8192, 32, 192, 128),
         ),
     ),
     # the rehearsal: same control flow, CPU backend, interpreted kernels
@@ -135,7 +139,12 @@ SIZES = {
         four_chip_steps=8,
         kill_run_steps=24,
         kill_step=2,
-        kernel_shapes=((2, 256, 2, 32), (1, 512, 2, 32), (1, 256, 2, 48, 32)),
+        kernel_shapes=(
+            (2, 256, 2, 32),
+            (1, 512, 2, 64),
+            (1, 256, 4, 128, 128, 2),
+            (1, 256, 2, 48, 32),
+        ),
     ),
 }
 
@@ -620,19 +629,22 @@ def _check_dp4(cfg, workdir, report, failures) -> dict:
     if not out["batch_param_per_chip"] or out["batch_param_global"]:
         failures.append(f"the batch is not split four ways: {out}")
     if cfg["platform"] == "tpu":
-        # the attention custom call's folded (batch*heads, seq, d) operand
+        # the attention custom calls' operands: (batch, seq, heads * d)
+        # where the kernels read heads out of the layer's layout, folded to
+        # (batch * heads, seq, d) where they cannot
         calls = re.findall(
             r"= \(?bf16\[(\d+),(\d+),(\d+)\][^\n]*custom_call_target="
             r'"tpu_custom_call"',
             hlo,
         )
         lead = sorted({int(c[0]) for c in calls})
-        out["attention_call_batch_x_heads"] = lead
-        if lead != [per_chip * cfg["heads"]]:
+        out["attention_call_leading_dim"] = lead
+        if lead not in ([per_chip], [per_chip * cfg["heads"]]):
             failures.append(
-                f"attention custom calls see batch*heads {lead}, expected "
-                f"the per-chip {per_chip * cfg['heads']} "
-                f"(global would be {whole * cfg['heads']})"
+                f"attention custom calls lead with {lead}, expected the "
+                f"per-chip batch {per_chip} or batch*heads "
+                f"{per_chip * cfg['heads']} (global would be {whole} or "
+                f"{whole * cfg['heads']})"
             )
         peaks = report["peak_bytes_in_use"]
         if not all(peaks) or max(peaks) > 1.5 * min(peaks):
@@ -645,7 +657,11 @@ def _child_kernel(run, cfg, workdir):
     import jax
     import jax.numpy as jnp
 
-    from elasticdl_tpu.ops.attention import flash_attention, mha_reference
+    from elasticdl_tpu.ops.attention import (
+        flash_attention,
+        flash_layout,
+        mha_reference,
+    )
     from elasticdl_tpu.parallel.elastic import configure_compilation_cache
     from elasticdl_tpu.telemetry import compile_tracker
 
@@ -654,9 +670,18 @@ def _child_kernel(run, cfg, workdir):
     compile_tracker.install()
     failures = []
     shapes = {}
+    layouts = {}
 
-    def weighted(fn, w):
-        return lambda q, k, v: jnp.sum(fn(q, k, v).astype(jnp.float32) * w)
+    def weighted(fn):
+        return lambda q, k, v, w: jnp.sum(fn(q, k, v).astype(jnp.float32) * w)
+
+    def with_gradients(fn):
+        return jax.jit(
+            lambda q, k, v, w: (
+                fn(q, k, v),
+                *jax.grad(weighted(fn), argnums=(0, 1, 2))(q, k, v, w),
+            )
+        )
 
     def reference(q, k, v):
         with jax.default_matmul_precision("highest"):
@@ -665,15 +690,57 @@ def _child_kernel(run, cfg, workdir):
     def flash(q, k, v):
         return flash_attention(q, k, v, causal=True)
 
+    def reference_by_heads(q, k, v, w):
+        """The float32 reference's output and gradients, a few query heads
+        of one key/value head at a time: it holds a few (B, heads, S, S)
+        float32 arrays, which ``limit`` keeps to 1 GiB each."""
+        heads, group = q.shape[2], q.shape[2] // k.shape[2]
+        limit = max(1, 2**28 // (q.shape[0] * q.shape[1] * k.shape[1]))
+        among = group if group > 1 else heads
+        step = max(
+            n for n in range(1, min(among, limit) + 1) if among % n == 0
+        )
+        fn = with_gradients(reference)
+        q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+        outs, dqs = [], []
+        dk, dv = jnp.zeros_like(k), jnp.zeros_like(v)
+        for first in range(0, heads, step):
+            mine = slice(first, first + step)
+            kv = (
+                slice(first // group, first // group + 1)
+                if group > 1
+                else mine
+            )
+            out, dq, dk_part, dv_part = fn(
+                q[:, :, mine], k[:, :, kv], v[:, :, kv], w[:, :, mine]
+            )
+            outs.append(out)
+            dqs.append(dq)
+            dk = dk.at[:, :, kv].add(dk_part)
+            dv = dv.at[:, :, kv].add(dv_part)
+        return (
+            jnp.concatenate(outs, axis=2), jnp.concatenate(dqs, axis=2), dk, dv
+        )
+
     for shape in cfg["kernel_shapes"]:
         keys = jax.random.split(jax.random.PRNGKey(sum(shape)), 4)
-        # (B, S, H, D), or (B, S, H, D of q and k, D of v)
-        scores, values = shape[:4], shape[:3] + shape[-1:]
+        batch, seq, heads, d = shape[:4]
+        d_v = shape[4] if len(shape) > 4 else d
+        kv_heads = shape[5] if len(shape) > 5 else heads
         q, k, v = (
             jax.random.normal(key, dims, jnp.float32).astype(jnp.bfloat16)
-            for key, dims in zip(keys[:3], (scores, scores, values))
+            for key, dims in zip(
+                keys[:3],
+                (
+                    (batch, seq, heads, d),
+                    (batch, seq, kv_heads, d),
+                    (batch, seq, kv_heads, d_v),
+                ),
+            )
         )
-        w = jax.random.normal(keys[3], values, jnp.float32)
+        w = jax.random.normal(keys[3], (batch, seq, heads, d_v), jnp.float32)
+        name = "x".join(map(str, shape))
+        layouts[name] = flash_layout(q, k, v)
         lowered = jax.jit(flash).lower(q, k, v)
         if cfg["platform"] == "tpu" and "tpu_custom_call" not in (
             lowered.as_text()
@@ -681,33 +748,31 @@ def _child_kernel(run, cfg, workdir):
             failures.append(f"{shape}: no compiled Mosaic call in the HLO")
         got = (
             lowered.compile()(q, k, v),
-            *jax.jit(jax.grad(weighted(flash, w), argnums=(0, 1, 2)))(q, k, v),
+            *with_gradients(flash)(q, k, v, w)[1:],
         )
-        want = (
-            jax.jit(reference)(q, k, v),
-            *jax.jit(jax.grad(weighted(reference, w), argnums=(0, 1, 2)))(
-                q, k, v
-            ),
-        )
+        want = reference_by_heads(q, k, v, w)
         errs = {}
-        for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
+        for part, a, b in zip(("out", "dq", "dk", "dv"), got, want):
             a = jnp.asarray(a, jnp.float32)
             b = jnp.asarray(b, jnp.float32)
             err = float(jnp.max(jnp.abs(a - b)))
             scale = max(1.0, float(jnp.max(jnp.abs(b))))
-            errs[name] = round(err / scale, 5)
+            errs[part] = round(err / scale, 5)
             if not math.isfinite(err) or err > KERNEL_TOL * scale:
                 failures.append(
-                    f"{shape} {name}: max|flash-ref| = {err:.4g} > "
+                    f"{shape} {part}: max|flash-ref| = {err:.4g} > "
                     f"{KERNEL_TOL} * {scale:.3g}"
                 )
-        shapes["x".join(map(str, shape))] = errs
+        shapes[name] = errs
+    if {"lanes", "folded"} - set(layouts.values()):
+        failures.append(f"a way of addressing heads was not run: {layouts}")
     from elasticdl_tpu.parallel.elastic import describe_devices
 
     report = _common_report(run, cfg, describe_devices(devices), failures)
     report.update(
         peak_bytes_in_use=_peak_bytes(devices),
         tolerance=KERNEL_TOL,
+        flash_layout=layouts,
         scaled_max_abs_err=shapes,
     )
     return report

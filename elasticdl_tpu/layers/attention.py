@@ -10,6 +10,7 @@ ring schedule.
 
 from __future__ import annotations
 
+import math
 from typing import Any
 
 import flax.linen as nn
@@ -17,6 +18,67 @@ import jax
 import jax.numpy as jnp
 
 import elasticdl_tpu.ops.attention as attention_ops
+
+
+class HeadsDense(nn.Module):
+    """A projection into heads, or out of them over ``axis=(-2, -1)``, that
+    owns ``nn.DenseGeneral``'s parameters (names, shapes and seeded initial
+    values: ``kernel`` (embed, heads, width) or (heads, width, embed)) and
+    computes ONE 2-D product over merged dimensions, bias included, before
+    the result is given its heads.  ``MultiHeadSelfAttention`` takes it
+    where the flash kernels read heads out of (batch, tokens, heads * width)
+    rows (``ops.attention.flash_layout`` says ``"lanes"``: 64-wide heads).
+
+    ``nn.DenseGeneral`` adds its bias to the 4-D result, and XLA's TPU
+    layout assignment gives a (batch, tokens, heads, 64) intermediate a
+    tokens-minor layout (a 64-wide minor dimension pads to 128 lanes), so
+    every operand of the kernels crossed a layout-changing copy, eight a
+    layer at 8 x 1,024 x 12 x 64, whatever the kernels read (PERF.md
+    section 6, PR 36).  With nothing between the product and the reshape,
+    the reshape in and the kernels' reshape back cancel.  Where the kernels
+    take folded heads (width 128) ``nn.DenseGeneral`` stays: XLA writes its
+    result straight into the folded layout, and the step compiles to the
+    program it was before this class existed."""
+
+    features: Any
+    axis: Any = -1
+    use_bias: bool = True
+    dtype: Any = None
+
+    @nn.compact
+    def __call__(self, x):
+        features = (
+            (self.features,)
+            if isinstance(self.features, int)
+            else tuple(self.features)
+        )
+        contracted = 1 if isinstance(self.axis, int) else len(self.axis)
+        lead, inputs = x.shape[:-contracted], x.shape[-contracted:]
+        flat = (math.prod(inputs), math.prod(features))
+
+        def kernel_init(rng, shape, dtype):
+            # nn.DenseGeneral's: drawn at the flat shape
+            return nn.initializers.lecun_normal()(rng, flat, dtype).reshape(
+                shape
+            )
+
+        kernel = self.param(
+            "kernel", kernel_init, inputs + features, jnp.float32
+        )
+        bias = (
+            self.param(
+                "bias", nn.initializers.zeros_init(), features, jnp.float32
+            )
+            if self.use_bias
+            else None
+        )
+        x, kernel, bias = nn.dtypes.promote_dtype(
+            x, kernel, bias, dtype=self.dtype
+        )
+        y = x.reshape(lead + flat[:1]) @ kernel.reshape(flat)
+        if bias is not None:
+            y = y + bias.reshape(flat[1:])
+        return y.reshape(lead + features)
 
 
 class MultiHeadSelfAttention(nn.Module):
@@ -60,8 +122,18 @@ class MultiHeadSelfAttention(nn.Module):
         head_dim = self.head_dim or embed // self.num_heads
         kv_heads = self.num_kv_heads or self.num_heads
 
+        # the projections hand the kernels what they read: rows of merged
+        # heads where the kernels take heads out of lanes, else
+        # nn.DenseGeneral's 4-D product, whose result XLA writes folded
+        q_shape, kv_shape = (
+            jax.ShapeDtypeStruct(x.shape[:2] + (heads, head_dim), x.dtype)
+            for heads in (self.num_heads, kv_heads)
+        )
+        layout = attention_ops.flash_layout(q_shape, kv_shape, kv_shape)
+        dense = HeadsDense if layout == "lanes" else nn.DenseGeneral
+
         def _proj(name, heads, norm=None):
-            y = nn.DenseGeneral(
+            y = dense(
                 features=(heads, head_dim), dtype=self.dtype, name=name,
                 use_bias=self.use_bias,
             )(x)
@@ -89,7 +161,7 @@ class MultiHeadSelfAttention(nn.Module):
             out = self._decode_attend(q, k, v, decode_pos)
         else:
             out = attention_ops.attention(q, k, v, causal=self.causal)
-        return nn.DenseGeneral(
+        return dense(
             features=embed, axis=(-2, -1), dtype=self.dtype, name="out",
             use_bias=self.use_bias,
         )(out.astype(x.dtype))

@@ -28,11 +28,11 @@ from jax.experimental.pallas import tpu as pltpu
 # this many (all-equal) columns so stores stay tile-aligned
 _LANES = 128
 
-# batch*heads and q/k-block dims are independent programs; only the
+# batch, head and q/k-block dims are independent programs; only the
 # innermost (accumulation stream) dim is order-dependent — telling
 # Mosaic lets it pipeline the outer dims across cores
 _FLASH_COMPILER_PARAMS = pltpu.CompilerParams(
-    dimension_semantics=("parallel", "parallel", "arbitrary")
+    dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")
 )
 
 _NEG_INF = -1e30
@@ -270,6 +270,39 @@ def _row_to_lanes(row):
     return jnp.broadcast_to(row, (_LANES, row.shape[1])).T
 
 
+def _lane_head(shape, heads):
+    """Which of the ``heads`` a block's lanes hold side by side each lane
+    of an array of ``shape`` belongs to."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, shape, len(shape) - 1)
+    return lane // (shape[-1] // heads)
+
+
+def _only_head(x, h, heads):
+    """``x`` with every lane that is not head ``h``'s zeroed: a product
+    contracted over the block's whole width is then that head's alone (the
+    zeros add exact zeros, and a 64-deep contraction costs the matrix unit
+    the same pass as a 128-deep one)."""
+    if heads == 1:
+        return x
+    return jnp.where(_lane_head(x.shape, heads) == h, x, jnp.zeros_like(x))
+
+
+def _by_head(parts):
+    """One array whose lanes of head ``h`` are ``parts[h]``'s: selected,
+    not scaled, so what a head's product left in the other heads' lanes
+    never reaches an accumulator."""
+    out = parts[-1]
+    for h in range(len(parts) - 2, -1, -1):
+        out = jnp.where(_lane_head(out.shape, len(parts)) == h, parts[h], out)
+    return out
+
+
+def _columns_by_head(cols, width):
+    """Lane-replicated ``(rows, _LANES)`` columns, one a head, as
+    ``(rows, width)`` with each head's column across that head's lanes."""
+    return _by_head([_lanes_to(col, width) for col in cols])
+
+
 def _loop(start, stop, body):
     if isinstance(start, int) and isinstance(stop, int) and start >= stop:
         return
@@ -309,14 +342,21 @@ def _flash_kernel(
     q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
     *, sm_scale, causal, block_q, block_k, chunk_k, num_ck,
 ):
-    """One (batch*head, q-block, k-chunk) grid cell of the online-softmax
+    """One (batch, head, q-block, k-chunk) grid cell of the online-softmax
     forward: loop block_k sub-blocks of the staged (1, chunk_k, d) K/V
     chunk through the online softmax.  m/l/acc persist across the chunk
     stream in VMEM scratch; the output and the per-row logsumexp (of the
     SCALED scores — the backward rebuilds probabilities from it) are
-    written once at the last chunk."""
-    i = pl.program_id(1)
-    c = pl.program_id(2)
+    written once at the last chunk.
+
+    Where the blocks hold two heads side by side in their lanes
+    (:func:`_heads_per_block`; ``m_scr`` has a plane a head) the cell does
+    both: each head's scores from a q with the other's lanes zeroed, its
+    ``p @ v`` taken over the whole block and its own lanes selected into
+    the one lane-dense accumulator."""
+    i = pl.program_id(2)
+    c = pl.program_id(3)
+    heads = m_scr.shape[0]
 
     @pl.when(c == 0)
     def _init():
@@ -333,7 +373,8 @@ def _flash_kernel(
     d = acc_scr.shape[1]
 
     def _chunk():
-        q = q_ref[0]  # (block_q, D)
+        q = q_ref[0]  # (block_q, heads * D)
+        q_of = [_only_head(q, h, heads) for h in range(heads)]
 
         def body(jj, crossed):
             start = pl.multiple_of(jj * block_k, block_k)
@@ -343,22 +384,26 @@ def _flash_kernel(
                 rows = slice(q0, q1)
                 kb = k_ref[0, pl.ds(start + k0, k1 - k0), :]
                 vb = v_ref[0, pl.ds(start + k0, k1 - k0), :]
-                s = _scores(q[rows], kb, sm_scale)
-                if masked:
-                    s = _causal_mask(s, row0 + q0, col0 + start + k0)
-                m_prev = m_scr[rows]  # (rows, _LANES), columns all equal
-                m_next = jnp.maximum(
-                    m_prev, jnp.max(s, axis=1, keepdims=True)
-                )
-                alpha = jnp.exp(m_prev - m_next)
-                p = jnp.exp(s - _lanes_to(m_next, k1 - k0))
-                l_scr[rows] = alpha * l_scr[rows] + p.sum(
-                    axis=1, keepdims=True
-                )
-                m_scr[rows] = m_next
-                acc_scr[rows] = acc_scr[rows] * _lanes_to(alpha, d) + _mxu(
-                    p, vb
-                )
+                alphas, pvs = [], []
+                for h in range(heads):
+                    s = _scores(q_of[h][rows], kb, sm_scale)
+                    if masked:
+                        s = _causal_mask(s, row0 + q0, col0 + start + k0)
+                    m_prev = m_scr[h, rows]  # (rows, _LANES), columns equal
+                    m_next = jnp.maximum(
+                        m_prev, jnp.max(s, axis=1, keepdims=True)
+                    )
+                    alpha = jnp.exp(m_prev - m_next)
+                    p = jnp.exp(s - _lanes_to(m_next, k1 - k0))
+                    l_scr[h, rows] = alpha * l_scr[h, rows] + p.sum(
+                        axis=1, keepdims=True
+                    )
+                    m_scr[h, rows] = m_next
+                    alphas.append(alpha)
+                    pvs.append(_mxu(p, vb))
+                acc_scr[rows] = acc_scr[rows] * _columns_by_head(
+                    alphas, d
+                ) + _by_head(pvs)
 
         _loop(0, full, functools.partial(body, crossed=False))
         _loop(full, live, functools.partial(body, crossed=True))
@@ -370,10 +415,14 @@ def _flash_kernel(
 
     @pl.when(c == num_ck - 1)
     def _write():
-        l = l_scr[...]
-        o_ref[0] = (acc_scr[...] / _lanes_to(l, d)).astype(o_ref.dtype)
-        # one dense (1, block_q) row a block: lanes hold the sequence
-        lse_ref[0] = (m_scr[...] + jnp.log(l)).T[0:1]
+        ls = [l_scr[h] for h in range(heads)]
+        o_ref[0] = (acc_scr[...] / _columns_by_head(ls, d)).astype(
+            o_ref.dtype
+        )
+        # one dense (1, block_q) row a block and head: lanes hold the
+        # sequence
+        for h in range(heads):
+            lse_ref[h] = (m_scr[h] + jnp.log(ls[h])).T[0:1]
 
 
 def _flash_dq_kernel(
@@ -382,26 +431,52 @@ def _flash_dq_kernel(
     v_ref,
     do_ref,
     lse_ref,
-    delta_ref,
-    dq_ref,
-    acc_scr,
-    *,
+    *refs,
     sm_scale,
     causal,
     block_q,
     block_k,
     chunk_k,
     num_ck,
+    makes_delta,
 ):
-    """dQ cell per (batch*head, q-block, k-chunk): rebuild p from the
+    """dQ cell per (batch, head, q-block, k-chunk): rebuild p from the
     saved logsumexp, accumulate dq = sm_scale * ds @ K into VMEM scratch
-    across the chunk stream (same structure as the forward)."""
-    i = pl.program_id(1)
-    c = pl.program_id(2)
+    across the chunk stream (same structure as the forward, two heads a
+    cell where the blocks hold two).
+
+    ``delta_r = rowsum(dO * O)``, the softmax-jacobian correction term,
+    arrives as rows in the lse's layout (``refs``: ``delta_ref, dq_ref,
+    acc_scr``), or, with ``makes_delta``, the cell makes it for its rows
+    from the block of ``out`` (``refs``: ``out_ref, dq_ref, delta_ref,
+    acc_scr, delta_scr``): a head at a time from the two blocks as they
+    lie, kept as lane-replicated columns for its own use and written once
+    in the lse's layout for dK/dV.  The kernels that read heads out of
+    lanes make it: outside them the same sum over a 64-wide minor
+    dimension has XLA turn a float32 (batch, tokens, heads * 64) array
+    tokens-minor first, 100 MB through HBM a layer at 8 x 1,024 x 12 x 64."""
+    if makes_delta:
+        out_ref, dq_ref, delta_ref, acc_scr, delta_scr = refs
+    else:
+        delta_ref, dq_ref, acc_scr = refs
+    i = pl.program_id(2)
+    c = pl.program_id(3)
+    heads = lse_ref.shape[0]
 
     @pl.when(c == 0)
     def _init():
         acc_scr[...] = jnp.zeros_like(acc_scr)
+        if makes_delta:
+            do_o = do_ref[0].astype(jnp.float32) * out_ref[0].astype(
+                jnp.float32
+            )
+            for h in range(heads):
+                delta_scr[h] = jnp.broadcast_to(
+                    jnp.sum(
+                        _only_head(do_o, h, heads), axis=1, keepdims=True
+                    ),
+                    delta_scr.shape[1:],
+                )
 
     nb = chunk_k // block_k
     row0, col0 = i * block_q, c * chunk_k
@@ -412,10 +487,15 @@ def _flash_dq_kernel(
 
     def _chunk():
         q = q_ref[0]
-        do = do_ref[0]  # (block_q, D)
+        do = do_ref[0]  # (block_q, heads * D)
+        q_of = [_only_head(q, h, heads) for h in range(heads)]
+        do_of = [_only_head(do, h, heads) for h in range(heads)]
         # the saved rows, turned once a chunk into lane-replicated columns
-        lse = _row_to_lanes(lse_ref[0])
-        delta = _row_to_lanes(delta_ref[0])
+        lse = [_row_to_lanes(lse_ref[h]) for h in range(heads)]
+        delta = [
+            delta_scr[h] if makes_delta else _row_to_lanes(delta_ref[h])
+            for h in range(heads)
+        ]
 
         def body(jj, crossed):
             start = pl.multiple_of(jj * block_k, block_k)
@@ -425,13 +505,16 @@ def _flash_dq_kernel(
                 rows = slice(q0, q1)
                 kb = k_ref[0, pl.ds(start + k0, k1 - k0), :]
                 vb = v_ref[0, pl.ds(start + k0, k1 - k0), :]
-                s = _scores(q[rows], kb, sm_scale)
-                if masked:
-                    s = _causal_mask(s, row0 + q0, col0 + start + k0)
-                p = jnp.exp(s - _lanes_to(lse[rows], k1 - k0))
-                dp = _scores(do[rows], vb)
-                ds = p * (dp - _lanes_to(delta[rows], k1 - k0))
-                acc_scr[rows] = acc_scr[rows] + _mxu(ds, kb)
+                dqs = []
+                for h in range(heads):
+                    s = _scores(q_of[h][rows], kb, sm_scale)
+                    if masked:
+                        s = _causal_mask(s, row0 + q0, col0 + start + k0)
+                    p = jnp.exp(s - _lanes_to(lse[h][rows], k1 - k0))
+                    dp = _scores(do_of[h][rows], vb)
+                    ds = p * (dp - _lanes_to(delta[h][rows], k1 - k0))
+                    dqs.append(_mxu(ds, kb))
+                acc_scr[rows] = acc_scr[rows] + _by_head(dqs)
 
         _loop(0, full, functools.partial(body, crossed=False))
         _loop(full, live, functools.partial(body, crossed=True))
@@ -444,6 +527,9 @@ def _flash_dq_kernel(
     @pl.when(c == num_ck - 1)
     def _write():
         dq_ref[0] = (acc_scr[...] * sm_scale).astype(dq_ref.dtype)
+        if makes_delta:
+            for h in range(heads):
+                delta_ref[h] = delta_scr[h].T[0:1]
 
 
 def _flash_dkv_kernel(
@@ -465,15 +551,19 @@ def _flash_dkv_kernel(
     chunk_q,
     num_cq,
 ):
-    """dK/dV cell per (batch*head, k-block, q-chunk): loop block_q
+    """dK/dV cell per (batch, head, k-block, q-chunk): loop block_q
     sub-blocks of the staged (1, chunk_q, d) Q/dO chunk over TRANSPOSED
     scores ``s^T = k @ q^T`` (block_k, block_q), so ``p^T`` and ``ds^T``
     come out as the left-hand sides ``dv += p^T @ dO`` and
     ``dk += ds^T @ q`` want, and ``lse``/``delta`` are read as rows along
     the lanes (``lse_ref``: a head's whole (1, seq_q / n, n), a row a
-    q-block or half of one).  ``sm_scale`` meets dk once, at the write."""
-    j = pl.program_id(1)
-    c = pl.program_id(2)
+    q-block or half of one).  ``sm_scale`` meets dk once, at the write.
+    Two heads a cell where the blocks hold two: k and v with the other
+    head's lanes zeroed make the scores, and each head's lanes of the two
+    products are selected into the accumulators."""
+    j = pl.program_id(2)
+    c = pl.program_id(3)
+    heads = lse_ref.shape[0]
 
     @pl.when(c == 0)
     def _init():
@@ -491,20 +581,22 @@ def _flash_dkv_kernel(
 
     saved = lse_ref.shape[2]  # block_q, or its half (_diagonal_half)
 
-    def saved_rows(ref, ii, q0, q1):
+    def saved_rows(ref, h, ii, q0, q1):
         """Rows ``[q0, q1)`` of q-block ``ii`` of the chunk, along lanes."""
         first = (c * nb + ii) * (block_q // saved)
         return jnp.concatenate(
             [
-                ref[0, pl.ds(first + part, 1), :]
+                ref[h, pl.ds(first + part, 1), :]
                 for part in range(q0 // saved, q1 // saved)
             ],
             axis=1,
         )
 
     def _chunk():
-        k = k_ref[0]  # (block_k, D)
+        k = k_ref[0]  # (block_k, heads * D)
         v = v_ref[0]
+        k_of = [_only_head(k, h, heads) for h in range(heads)]
+        v_of = [_only_head(v, h, heads) for h in range(heads)]
 
         def body(ii, crossed):
             start = pl.multiple_of(ii * block_q, block_q)
@@ -512,20 +604,25 @@ def _flash_dkv_kernel(
                 block_q, block_k, crossed, along_q=False
             ):
                 cols = slice(k0, k1)
-                lse = saved_rows(lse_ref, ii, q0, q1)  # (1, q rows)
-                delta = saved_rows(delta_ref, ii, q0, q1)
                 qi = q_ref[0, pl.ds(start + q0, q1 - q0), :]
                 doi = do_ref[0, pl.ds(start + q0, q1 - q0), :]
-                st = _scores(k[cols], qi, sm_scale)  # (k rows, q rows)
-                if masked:
-                    st = _causal_mask(
-                        st, row0 + start + q0, col0 + k0, q_axis=1
-                    )
-                pt = jnp.exp(st - lse)
-                dv_scr[cols] = dv_scr[cols] + _mxu(pt, doi)
-                dpt = _scores(v[cols], doi)
-                dst = pt * (dpt - delta)
-                dk_scr[cols] = dk_scr[cols] + _mxu(dst, qi)
+                dvs, dks = [], []
+                for h in range(heads):
+                    lse = saved_rows(lse_ref, h, ii, q0, q1)  # (1, q rows)
+                    delta = saved_rows(delta_ref, h, ii, q0, q1)
+                    # (k rows, q rows)
+                    st = _scores(k_of[h][cols], qi, sm_scale)
+                    if masked:
+                        st = _causal_mask(
+                            st, row0 + start + q0, col0 + k0, q_axis=1
+                        )
+                    pt = jnp.exp(st - lse)
+                    dvs.append(_mxu(pt, doi))
+                    dpt = _scores(v_of[h][cols], doi)
+                    dst = pt * (dpt - delta)
+                    dks.append(_mxu(dst, qi))
+                dv_scr[cols] = dv_scr[cols] + _by_head(dvs)
+                dk_scr[cols] = dk_scr[cols] + _by_head(dks)
 
         _loop(first, full_from, functools.partial(body, crossed=True))
         _loop(full_from, nb, functools.partial(body, crossed=False))
@@ -608,13 +705,48 @@ def flash_attention(
     return out
 
 
+def _heads_per_block(q, k, v) -> int:
+    """How the kernels can address a head, from the three ``(batch,
+    tokens, heads, width)`` shapes alone: 2 where a block of 128 lanes of
+    the layer's own layout, ``(batch, tokens, heads * width)``, is two
+    heads and a grid cell does both (64-wide heads, an even number of
+    them, ungrouped); 0 where only the folded ``(batch * heads, tokens,
+    width)`` form reaches a head (scores of 192 beside values of 128, an
+    odd head count, grouping at 64), **and at widths of 128**, where the
+    index maps could pick a head's lane block but nothing is won: a
+    128-wide minor dimension is not padded, XLA writes a projection
+    straight into the folded layout, and what sits between a projection
+    and the kernels (rotary positions on a 4-D array) wants that layout
+    too.  Measured on a TPU v5e, lanes against folded at 128:
+    ``olmoe_1b7b_seq4096`` 72,380 against 74,340 tokens/s (73,660 with the
+    rotary positions rewritten over merged rows),
+    ``nemotron_twotower_seq8192`` 29,520 against 29,709 (PERF.md section
+    6, PR 36)."""
+    (heads, d), (kv_heads, d_v) = q.shape[2:], v.shape[2:]
+    if 2 * d == 2 * d_v == _LANES and heads == kv_heads and heads % 2 == 0:
+        return 2
+    return 0
+
+
+def flash_layout(q, k, v) -> str:
+    """``"lanes"`` where the flash kernels read heads out of q, k and v as
+    the layer holds them (no copy on either side of a kernel), ``"folded"``
+    where the operands are turned to ``(batch * heads, tokens, width)``
+    first.  A pure function of the three shapes, decided at trace time."""
+    return "lanes" if _heads_per_block(q, k, v) else "folded"
+
+
 def _flash_geometry(q, k, v, sm_scale, block_q, block_k, interpret):
     """Defaults filled in, blocks made to divide the sequences, and the
     rows of a staged chunk of q (for dK/dV) and of k (for the forward and
-    dQ), from what the call can see: lengths, head widths, dtype.  Where
-    the scores are wider than the values (latent attention: 192 beside 128)
-    the narrower width sizes the chunk: measured on a TPU v5e, kernels
-    alone, (1, 8192, 32, 192 | 128): 28.04 / 26.27 / 24.64 ms with chunks of
+    dQ), from what the call can see: lengths, head widths, dtype.  A chunk
+    of blocks that hold two 64-wide heads keeps a 64-wide chunk's rows: it
+    fills the 128 lanes the narrow one was padded to in VMEM, and measured
+    on a TPU v5e, kernels alone, (1, 8192, 12, 64): 6.15 / 5.99 / 5.72 ms
+    with chunks of 2,048 / 4,096 / 8,192 rows, 6.01 for the folded form
+    (PERF.md section 6, PR 36).  Where the scores are wider than the values
+    (latent attention: 192 beside 128) the narrower width sizes the chunk:
+    measured on a TPU v5e, kernels alone, (1, 8192, 32, 192 | 128): 28.04 / 26.27 / 24.64 ms with chunks of
     1,024 (what 192 alone would give) / 2,048 / 4,096 rows; 8,192 do not
     fit VMEM (PERF.md section 6, PR 34)."""
     if sm_scale is None:
@@ -630,23 +762,69 @@ def _flash_geometry(q, k, v, sm_scale, block_q, block_k, interpret):
     return sm_scale, block_q, block_k, chunk_q, chunk_k, interpret
 
 
-def _fold_heads(x):
-    """(B, S, H, D) -> (B*H, S, D)."""
-    b, s, h, d = x.shape
-    return x.transpose(0, 2, 1, 3).reshape(b * h, s, d)
+class _HeadAddressing:
+    """How the three ``pallas_call``s reach a head of a ``(batch, tokens,
+    heads, width)`` array, chosen by :func:`_heads_per_block`: which array
+    the call is handed, the grid's head dimension, and the block a grid
+    cell ``(b, h, ...)`` reads.  One algorithm; the shapes say which
+    addressing it can use."""
 
+    def __init__(self, q, k, v):
+        self.lanes = _heads_per_block(q, k, v)
+        # heads a grid cell works, and the grid's head dimension
+        self.per_cell = max(1, self.lanes)
+        self.cells = q.shape[2] // self.per_cell
+        # GQA without materializing repeated K/V: the q-head program reads
+        # its group's single kv head.  THE one definition of the grouping
+        # used by every kernel spec (the subtlest index math in these
+        # kernels must not be copy-pasted)
+        self.group = validate_gqa_heads(q, k, v)
 
-def _kv_head(bh, heads, kv_heads, group):
-    """Folded-KV row for folded-Q row ``bh``: GQA without materializing
-    repeated K/V — the q-head program reads its group's single kv head.
-    THE one definition of the grouping used by every kernel spec (the
-    subtlest index math in these kernels must not be copy-pasted)."""
-    return (bh // heads) * kv_heads + (bh % heads) // group
+    def operand(self, x):
+        """``(B, S, H, D)`` as the kernels take it: its two minor
+        dimensions merged (what the projections wrote, no copy), or folded
+        to ``(B*H, S, D)`` (a copy through HBM)."""
+        b, s, h, d = x.shape
+        if self.lanes:
+            return x.reshape(b, s, h * d)
+        return x.transpose(0, 2, 1, 3).reshape(b * h, s, d)
 
+    def shape(self, batch, seq, heads, d):
+        """Of a kernel's result for ``(batch, seq, heads, d)``."""
+        if self.lanes:
+            return (batch, seq, heads * d)
+        return (batch * heads, seq, d)
 
-def _unfold_heads(x, batch, heads):
-    bh, s, d = x.shape
-    return x.reshape(batch, heads, s, d).transpose(0, 2, 1, 3)
+    def result(self, x, batch, heads):
+        """A kernel's result back as ``(B, S, H, D)``."""
+        if self.lanes:
+            return x.reshape(*x.shape[:2], heads, x.shape[2] // heads)
+        bh, s, d = x.shape
+        return x.reshape(batch, heads, s, d).transpose(0, 2, 1, 3)
+
+    def spec(self, rows, d, rows_index, kv_heads=0):
+        """The block of ``rows`` tokens by one cell's heads of width ``d``,
+        at the row block ``rows_index(h_cell, i, c)``; ``kv_heads``: of a
+        key/value operand with that many heads, whose head is the query
+        head's group's (folded form only: the lanes form is ungrouped)."""
+        heads = kv_heads or self.cells * self.per_cell
+        group = self.group if kv_heads else 1
+
+        def index(b, h, i, c):
+            if self.lanes:
+                return (b, rows_index(h, i, c), h)
+            # per_cell is 1: cell h is query head h
+            return (b * heads + h // group, rows_index(h, i, c), 0)
+
+        return pl.BlockSpec((1, rows, d * self.per_cell), index)
+
+    def row_spec(self, block, index):
+        """Of ``lse`` and ``delta``, ``(batch * heads, ...)`` lane-major
+        float32 rows in both forms: a cell's heads are consecutive rows."""
+        return pl.BlockSpec(
+            (self.per_cell,) + block,
+            lambda b, h, i, c: (b * self.cells + h,) + index(i, c),
+        )
 
 
 def _last_live_chunk(i, block_q, chunk_k):
@@ -666,17 +844,19 @@ def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret):
     )
     batch, seq_q, heads, d = q.shape
     d_v = v.shape[-1]
-    group = validate_gqa_heads(q, k, v)
     kv_heads = k.shape[2]
     seq_k = k.shape[1]
     num_ck = seq_k // chunk_k
+    at = _HeadAddressing(q, k, v)
 
-    def _kv_index(b, i, c):
+    def _q_block(h, i, c):
+        return i
+
+    def _kv_chunk(h, i, c):
         if causal:
             c = jnp.minimum(c, _last_live_chunk(i, block_q, chunk_k))
-        return (_kv_head(b, heads, kv_heads, group), c, 0)
+        return c
 
-    qf, kf, vf = _fold_heads(q), _fold_heads(k), _fold_heads(v)
     kernel = functools.partial(
         _flash_kernel,
         sm_scale=sm_scale,
@@ -688,31 +868,33 @@ def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret):
     )
     out, lse = pl.pallas_call(
         kernel,
-        grid=(batch * heads, seq_q // block_q, num_ck),
+        grid=(batch, at.cells, seq_q // block_q, num_ck),
         in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, c: (b, i, 0)),
-            pl.BlockSpec((1, chunk_k, d), _kv_index),
-            pl.BlockSpec((1, chunk_k, d_v), _kv_index),
+            at.spec(block_q, d, _q_block),
+            at.spec(chunk_k, d, _kv_chunk, kv_heads),
+            at.spec(chunk_k, d_v, _kv_chunk, kv_heads),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, d_v), lambda b, i, c: (b, i, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda b, i, c: (b, 0, i)),
+            at.spec(block_q, d_v, _q_block),
+            at.row_spec((1, block_q), lambda i, c: (0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((batch * heads, seq_q, d_v), q.dtype),
+            jax.ShapeDtypeStruct(
+                at.shape(batch, seq_q, heads, d_v), q.dtype
+            ),
             # lane-major and compact: a row of seq_q float32 a head
             jax.ShapeDtypeStruct((batch * heads, 1, seq_q), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, _LANES), jnp.float32),  # m
-            pltpu.VMEM((block_q, _LANES), jnp.float32),  # l
-            pltpu.VMEM((block_q, d_v), jnp.float32),  # acc
+            pltpu.VMEM((at.per_cell, block_q, _LANES), jnp.float32),  # m
+            pltpu.VMEM((at.per_cell, block_q, _LANES), jnp.float32),  # l
+            pltpu.VMEM((block_q, at.per_cell * d_v), jnp.float32),  # acc
         ],
         compiler_params=_FLASH_COMPILER_PARAMS,
         interpret=interpret,
         name=FLASH_FWD,
-    )(qf, kf, vf)
-    return _unfold_heads(out, batch, heads), lse
+    )(at.operand(q), at.operand(k), at.operand(v))
+    return at.result(out, batch, heads), lse
 
 
 @functools.partial(
@@ -728,105 +910,123 @@ def _flash_backward(
     )
     batch, seq_q, heads, d = q.shape
     d_v = v.shape[-1]
-    group = validate_gqa_heads(q, k, v)
     kv_heads = k.shape[2]
     seq_k = k.shape[1]
     num_ck = seq_k // chunk_k
     num_cq = seq_q // chunk_q
+    at = _HeadAddressing(q, k, v)
 
-    qf, kf, vf = _fold_heads(q), _fold_heads(k), _fold_heads(v)
-    dof = _fold_heads(g)
-    # delta_r = rowsum(dO * O): the softmax-jacobian correction term, in
-    # the lse's layout
-    delta = jnp.sum(
-        dof.astype(jnp.float32) * _fold_heads(out).astype(jnp.float32),
-        axis=-1,
-    )[:, None, :]  # (B*H, 1, S_q)
+    qf, kf, vf, dof = (at.operand(x) for x in (q, k, v, g))
 
-    def _kv_chunk_index(b, i, c):
+    def _q_block(h, i, c):
+        return i
+
+    def _kv_chunk(h, i, c):
         if causal:
             c = jnp.minimum(c, _last_live_chunk(i, bq, chunk_k))
-        return (_kv_head(b, heads, kv_heads, group), c, 0)
+        return c
 
-    dq = pl.pallas_call(
+    row_block = at.row_spec((1, bq), lambda i, c: (0, i))
+    dq_spec = at.spec(bq, d, _q_block)
+    dq_shape = jax.ShapeDtypeStruct(at.shape(batch, seq_q, heads, d), q.dtype)
+    dq_scratch = pltpu.VMEM((bq, at.per_cell * d), jnp.float32)
+    if at.lanes:
+        # the kernel makes delta_r = rowsum(dO * O) from out's blocks
+        extra, extra_spec = at.operand(out), at.spec(bq, d_v, _q_block)
+        out_specs = [dq_spec, row_block]
+        out_shape = [dq_shape, jax.ShapeDtypeStruct(lse.shape, jnp.float32)]
+        scratch = [
+            dq_scratch,
+            pltpu.VMEM((at.per_cell, bq, _LANES), jnp.float32),  # delta
+        ]
+    else:
+        # ... or is handed it, in the lse's layout
+        extra = jnp.sum(
+            dof.astype(jnp.float32) * at.operand(out).astype(jnp.float32),
+            axis=-1,
+        )[:, None, :]  # (B*H, 1, S_q)
+        extra_spec, out_specs, out_shape = row_block, dq_spec, dq_shape
+        scratch = [dq_scratch]
+    made = pl.pallas_call(
         functools.partial(
             _flash_dq_kernel, sm_scale=sm_scale, causal=causal,
             block_q=bq, block_k=bk, chunk_k=chunk_k, num_ck=num_ck,
+            makes_delta=bool(at.lanes),
         ),
-        grid=(batch * heads, seq_q // bq, num_ck),
+        grid=(batch, at.cells, seq_q // bq, num_ck),
         in_specs=[
-            pl.BlockSpec((1, bq, d), lambda b, i, c: (b, i, 0)),
-            pl.BlockSpec((1, chunk_k, d), _kv_chunk_index),
-            pl.BlockSpec((1, chunk_k, d_v), _kv_chunk_index),
-            pl.BlockSpec((1, bq, d_v), lambda b, i, c: (b, i, 0)),
-            pl.BlockSpec((1, 1, bq), lambda b, i, c: (b, 0, i)),
-            pl.BlockSpec((1, 1, bq), lambda b, i, c: (b, 0, i)),
+            dq_spec,
+            at.spec(chunk_k, d, _kv_chunk, kv_heads),
+            at.spec(chunk_k, d_v, _kv_chunk, kv_heads),
+            at.spec(bq, d_v, _q_block),
+            row_block,
+            extra_spec,
         ],
-        out_specs=pl.BlockSpec((1, bq, d), lambda b, i, c: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((batch * heads, seq_q, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=scratch,
         compiler_params=_FLASH_COMPILER_PARAMS,
         interpret=interpret,
         name=FLASH_DQ,
-    )(qf, kf, vf, dof, lse, delta)
+    )(qf, kf, vf, dof, lse, extra)
+    dq, delta = made if at.lanes else (made, extra)
 
     # dK/dV are computed per q-head (the kernel never materializes
     # repeated K/V either); a GQA group then sums its q-heads' parts —
-    # one (B, H, S_k, D) pass, the gradient analogue of the repeat.
+    # one pass over (B, S_k, H, D), the gradient analogue of the repeat.
     # Grid: k-block outer, q-CHUNK innermost (the accumulation stream).
-    def _q_chunk_index(b, j, c):
+    def _q_chunk(h, j, c):
         if causal:
             # the first q-chunk whose rows reach this k-block's columns
             c = jnp.maximum(c, (j * bk) // chunk_q)
-        return (b, c, 0)
+        return c
 
-    def _k_block_index(b, j, c):
-        return (_kv_head(b, heads, kv_heads, group), j, 0)
+    def _k_block(h, j, c):
+        return j
 
     # a head's whole row, one q-block (or half of one, where the kernel
     # halves the blocks on the diagonal) a sublane row: the kernel picks
     # its (1, n) by row index
     saved = _diagonal_half(bq, bk) or bq
     rows = (batch * heads, seq_q // saved, saved)
-    row_spec = pl.BlockSpec((1,) + rows[1:], lambda b, j, c: (b, 0, 0))
+    row_spec = at.row_spec(rows[1:], lambda j, c: (0, 0))
     dk_per_q, dv_per_q = pl.pallas_call(
         functools.partial(
             _flash_dkv_kernel, sm_scale=sm_scale, causal=causal,
             block_q=bq, block_k=bk, chunk_q=chunk_q, num_cq=num_cq,
         ),
-        grid=(batch * heads, seq_k // bk, num_cq),
+        grid=(batch, at.cells, seq_k // bk, num_cq),
         in_specs=[
-            pl.BlockSpec((1, chunk_q, d), _q_chunk_index),
-            pl.BlockSpec((1, bk, d), _k_block_index),
-            pl.BlockSpec((1, bk, d_v), _k_block_index),
-            pl.BlockSpec((1, chunk_q, d_v), _q_chunk_index),
+            at.spec(chunk_q, d, _q_chunk),
+            at.spec(bk, d, _k_block, kv_heads),
+            at.spec(bk, d_v, _k_block, kv_heads),
+            at.spec(chunk_q, d_v, _q_chunk),
             row_spec,
             row_spec,
         ],
-        out_specs=[
-            pl.BlockSpec((1, bk, d), lambda b, j, c: (b, j, 0)),
-            pl.BlockSpec((1, bk, d_v), lambda b, j, c: (b, j, 0)),
-        ],
+        out_specs=[at.spec(bk, d, _k_block), at.spec(bk, d_v, _k_block)],
         out_shape=[
-            jax.ShapeDtypeStruct((batch * heads, seq_k, d), k.dtype),
-            jax.ShapeDtypeStruct((batch * heads, seq_k, d_v), v.dtype),
+            jax.ShapeDtypeStruct(at.shape(batch, seq_k, heads, d), k.dtype),
+            jax.ShapeDtypeStruct(
+                at.shape(batch, seq_k, heads, d_v), v.dtype
+            ),
         ],
         scratch_shapes=[
-            pltpu.VMEM((bk, d), jnp.float32),  # dk
-            pltpu.VMEM((bk, d_v), jnp.float32),  # dv
+            pltpu.VMEM((bk, at.per_cell * d), jnp.float32),  # dk
+            pltpu.VMEM((bk, at.per_cell * d_v), jnp.float32),  # dv
         ],
         compiler_params=_FLASH_COMPILER_PARAMS,
         interpret=interpret,
         name=FLASH_DKV,
     )(qf, kf, vf, dof, lse.reshape(rows), delta.reshape(rows))
 
-    dq = _unfold_heads(dq, batch, heads)
-    dk = _unfold_heads(dk_per_q, batch, heads)
-    dv = _unfold_heads(dv_per_q, batch, heads)
-    if group > 1:
+    dq = at.result(dq, batch, heads)
+    dk = at.result(dk_per_q, batch, heads)
+    dv = at.result(dv_per_q, batch, heads)
+    if at.group > 1:
         # sum each kv head's query group: (B, S, H, D) -> (B, S, KVH, D)
-        dk = dk.reshape(batch, seq_k, kv_heads, group, d).sum(axis=3)
-        dv = dv.reshape(batch, seq_k, kv_heads, group, d_v).sum(axis=3)
+        dk = dk.reshape(batch, seq_k, kv_heads, at.group, d).sum(axis=3)
+        dv = dv.reshape(batch, seq_k, kv_heads, at.group, d_v).sum(axis=3)
     return dq, dk.astype(k.dtype), dv.astype(v.dtype)
 
 
@@ -893,6 +1093,27 @@ def attention(q, k, v, causal: bool = False, sm_scale: float | None = None):
     spec = sequence_shard_spec(mesh, None, q.shape[0], q.shape[2])
     if k.shape[2] != q.shape[2]:
         spec = jax.sharding.PartitionSpec(spec[0], None, None, None)
+    if flash_layout(q, k, v) == "lanes":
+        # the per-device region is handed the projections' merged rows and
+        # gives them back: a (..., heads, 64) array at its boundary is a
+        # value XLA lays out tokens-minor, and every operand of the kernels
+        # then crosses a copy again (13 a layer in the compiled dp=4 step)
+        def merged(x):
+            return x.reshape(*x.shape[:2], -1)
+
+        def split(x):
+            return x.reshape(*x.shape[:2], -1, q.shape[-1])
+
+        rows = jax.sharding.PartitionSpec(*spec[:3])
+        return split(
+            jax.shard_map(
+                lambda q, k, v: merged(local(split(q), split(k), split(v))),
+                mesh=mesh,
+                in_specs=(rows, rows, rows),
+                out_specs=rows,
+                check_vma=False,
+            )(merged(q), merged(k), merged(v))
+        )
     return jax.shard_map(
         local,
         mesh=mesh,

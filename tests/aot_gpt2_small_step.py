@@ -2,7 +2,9 @@
 shape: 12 x 768, 12 heads, 50,257-row head, bf16, 8,192 tokens per chip)
 for a v5e 2x2 host from libtpu's topology description (no chip needed) and
 print what the compiled program holds of the gathering loss's footprint:
-``while`` loops, ``dynamic-update-slice`` and ``scatter`` ops, temporaries.
+``while`` loops, ``dynamic-update-slice`` and ``scatter`` ops, temporaries;
+and the copies and transposes of an array as large as a flash kernel's
+operand (``activation_copies``: 96 when the kernels took folded heads).
 Driven by tests/test_chip_bringup.py; exits 77 where no TPU topology
 description is available.
 
@@ -11,6 +13,8 @@ description is available.
 
 import argparse
 import json
+import math
+import re
 import sys
 
 import jax
@@ -86,6 +90,16 @@ with mesh, attention_mesh_scope(mesh):
     compiled = step.lower(state, {"tokens": tokens}, tokens, weights).compile()
 hlo = compiled.as_text()
 memory = compiled.memory_analysis()
+# q, k, v, out, dO, dq, dk or dv of one layer, in whatever shape and layout,
+# copied or turned inside the attention module's scope
+operand = args.rows * args.seq * 768
+activation_copies = [
+    match.group(1)
+    for match in re.finditer(
+        r"= bf16\[([\d,]+)\]\S* (?:copy|transpose)\([^\n]*/attn/", hlo
+    )
+    if math.prod(int(n) for n in match.group(1).split(",")) == operand
+]
 print(
     json.dumps(
         {
@@ -94,6 +108,7 @@ print(
             "dynamic_update_slices": hlo.count(" dynamic-update-slice("),
             "scatters": hlo.count(" scatter("),
             "kernel_calls": hlo.count('custom_call_target="tpu_custom_call"'),
+            "activation_copies": len(activation_copies),
             "temp_bytes": memory.temp_size_in_bytes,
             "live_bytes_per_device": memory.argument_size_in_bytes
             + memory.output_size_in_bytes

@@ -16,6 +16,7 @@ import pytest
 from elasticdl_tpu.ops.attention import (
     attention,
     flash_attention,
+    flash_layout,
     mha_reference,
     set_attention_mesh,
 )
@@ -594,6 +595,11 @@ def test_flash_kernel_dtypes_follow_the_input(kernel, dtype):
         assert out.dtype == dtype
         # compact and lane-major: one row of seq_q float32 a head
         assert (lse.dtype, lse.shape) == (jnp.float32, (2, 1, 256))
+    elif kernel == "flash_dq":
+        dq, delta = outs
+        assert dq.dtype == dtype
+        # rowsum(dO * O) for dK/dV, in the lse's layout
+        assert (delta.dtype, delta.shape) == (jnp.float32, (2, 1, 256))
     else:
         assert all(o.dtype == dtype for o in outs)
     dots = [e for e in _eqns(body) if e.primitive.name == "dot_general"]
@@ -733,3 +739,169 @@ def test_flash_bf16_scale_that_is_no_power_of_two(causal):
     assert out.dtype == jnp.bfloat16
     err = np.max(np.abs(np.asarray(out, np.float32) - ref))
     assert err <= 2.0 ** -8 * np.max(np.abs(ref)), err
+
+
+# (batch, tokens, heads, kv heads, width of q and k, width of v, causal,
+# block, the layout the shapes must choose)
+_LAYOUT_CASES = {
+    # 64 wide: a block of 128 lanes is two heads, a grid cell does both
+    "12x64_causal": (2, 256, 12, 12, 64, 64, True, 64, "lanes"),
+    "12x64_full": (2, 256, 12, 12, 64, 64, False, 64, "lanes"),
+    # the default 512-blocks: one on the diagonal worked in quarters, one
+    # below it, and the saved rows read by half-block
+    "12x64_seq1024": (1, 1024, 12, 12, 64, 64, True, 512, "lanes"),
+    # at 128 XLA writes the projections folded and nothing is won in lanes
+    "16x128": (1, 256, 16, 16, 128, 128, True, 128, "folded"),
+    # ... with the sum over a key/value head's sixteen query heads
+    "32x128_kv2": (1, 256, 32, 2, 128, 128, True, 128, "folded"),
+    # what no block of lanes reaches stays folded
+    "192_beside_128": (1, 256, 4, 4, 192, 128, True, 128, "folded"),
+    "3x64_odd": (1, 256, 3, 3, 64, 64, True, 128, "folded"),
+    "4x64_kv2": (1, 256, 4, 2, 64, 64, True, 128, "folded"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_LAYOUT_CASES))
+def test_flash_layouts_forward_and_gradients_match_reference(case):
+    """Every form of addressing a head against ``mha_reference``, forward
+    and all three gradients, under the chip's rule for bf16; in the
+    ``"lanes"`` forms nothing around the kernels turns a 4-D array."""
+    b, s, h, kvh, d, d_v, causal, block, layout = _LAYOUT_CASES[case]
+    rng = np.random.RandomState(len(case))
+
+    def mk(heads, width):
+        return jnp.asarray(rng.randn(b, s, heads, width), jnp.bfloat16)
+
+    q, k, v = mk(h, d), mk(kvh, d), mk(kvh, d_v)
+    w = jnp.asarray(rng.randn(b, s, h, d_v), jnp.float32)
+    assert flash_layout(q, k, v) == layout
+
+    def flash(q, k, v):
+        return flash_attention(
+            q, k, v, causal=causal, block_q=block, block_k=block
+        )
+
+    def ref(q, k, v):
+        return mha_reference(q, k, v, causal=causal)
+
+    got = (flash(q, k, v), *_weighted_grads(flash, w)(q, k, v))
+    want = (ref(q, k, v), *_weighted_grads(ref, w)(q, k, v))
+    assert [g.dtype for g in got] == [jnp.bfloat16] * 4
+    assert [g.shape for g in got] == [w.shape for w in want]
+    for name, err in zip(("out", "dq", "dk", "dv"), _scaled_errors(got, want)):
+        assert err <= KERNEL_TOL, (name, err)
+    turned = [
+        eqn
+        for eqn in _eqns(
+            jax.make_jaxpr(_weighted_grads(flash, w))(q, k, v).jaxpr
+        )
+        if eqn.primitive.name == "transpose"
+        and eqn.invars[0].aval.ndim == 4
+    ]
+    assert bool(turned) == (layout == "folded"), turned
+
+
+@pytest.mark.parametrize(
+    "cell,shape,kv_heads,d_v,layout",
+    [
+        ("gpt2s_seq1024", (8, 1024, 12, 64), 12, 64, "lanes"),
+        ("gpt2s_seq1024_dp4", (8, 1024, 12, 64), 12, 64, "lanes"),
+        ("gpt2s_seq8192", (1, 8192, 12, 64), 12, 64, "lanes"),
+        ("olmoe_1b7b_seq4096", (2, 4096, 16, 128), 16, 128, "folded"),
+        ("nemotron_twotower_seq8192", (1, 8192, 32, 128), 2, 128, "folded"),
+        ("joyai_flash_seq8192", (1, 8192, 32, 192), 32, 128, "folded"),
+    ],
+)
+def test_flash_layout_of_each_benchmark_cell(
+    cell, shape, kv_heads, d_v, layout
+):
+    """What a device of each LM cell hands the kernels (on ``dp4`` each
+    chip its own 8 sequences), from shapes alone."""
+    q = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    k = jax.ShapeDtypeStruct(shape[:2] + (kv_heads, shape[3]), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct(shape[:2] + (kv_heads, d_v), jnp.bfloat16)
+    assert flash_layout(q, k, v) == layout
+
+
+@pytest.mark.parametrize("use_bias", [True, False])
+@pytest.mark.parametrize(
+    "kwargs,shape",
+    [
+        (dict(features=(4, 8)), (2, 16, 32)),  # into heads
+        (dict(features=32, axis=(-2, -1)), (2, 16, 4, 8)),  # out of them
+    ],
+)
+def test_heads_dense_owns_dense_generals_parameters(kwargs, shape, use_bias):
+    """``HeadsDense`` computes one 2-D product (so that no 4-D intermediate
+    stands between a projection and the kernels' rows) over parameters of
+    ``nn.DenseGeneral``'s names, shapes and seeded initial values: a
+    checkpoint and a seed mean what they meant."""
+    import flax.linen as nn
+
+    from elasticdl_tpu.layers.attention import HeadsDense
+
+    rng = np.random.RandomState(5)
+    x = jnp.asarray(rng.randn(*shape), jnp.float32)
+    mine = HeadsDense(use_bias=use_bias, name="p", **kwargs)
+    flax = nn.DenseGeneral(use_bias=use_bias, name="p", **kwargs)
+    key = jax.random.PRNGKey(7)
+    params, want = mine.init(key, x), flax.init(key, x)
+    structure = jax.tree_util.tree_structure
+    assert structure(params) == structure(want)
+    for a, b in zip(
+        jax.tree_util.tree_leaves(params), jax.tree_util.tree_leaves(want)
+    ):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    # a bias that is not zero, so that its place in the sum shows
+    params = jax.tree_util.tree_map(lambda p: p + 0.25, params)
+    np.testing.assert_allclose(
+        np.asarray(mine.apply(params, x)),
+        np.asarray(flax.apply(params, x)),
+        atol=1e-5,
+        rtol=1e-5,
+    )
+    jaxpr = jax.make_jaxpr(lambda p, x: mine.apply(p, x))(params, x)
+    dots = [e for e in _eqns(jaxpr.jaxpr) if e.primitive.name == "dot_general"]
+    # one product, whose result is (batch, tokens, merged features)
+    assert [e.outvars[0].aval.ndim for e in dots] == [3]
+
+
+@pytest.mark.parametrize("mesh_shape", ["dp=4", "dp=2,tp=2"])
+def test_attention_hands_the_per_device_kernels_merged_rows(mesh_shape):
+    """On a mesh without ``sp`` the kernels are mapped over batch and head
+    axes; where they read heads out of lanes the mapped region takes and
+    gives ``(batch, tokens, heads * 64)`` rows (a 4-D array at its boundary
+    brought back every copy the lanes form removes, in the compiled dp=4
+    step), and results and gradients are the oracle's."""
+    q, k, v = _qkv(b=4, s=128, h=4, d=64)
+    w = np.random.RandomState(2).randn(4, 128, 4, 64).astype(np.float32)
+    mesh = MeshConfig.from_string(mesh_shape).create(
+        devices=jax.devices()[:4]
+    )
+    set_attention_mesh(mesh)
+
+    def mapped(q, k, v):
+        return attention(q, k, v, causal=True)
+
+    def ref(q, k, v):
+        return mha_reference(q, k, v, causal=True)
+
+    got = (
+        jax.jit(mapped)(q, k, v),
+        *jax.jit(_weighted_grads(mapped, w))(q, k, v),
+    )
+    want = (ref(q, k, v), *_weighted_grads(ref, w)(q, k, v))
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), atol=2e-4, rtol=2e-4
+        )
+    regions = [
+        eqn
+        for eqn in _eqns(jax.make_jaxpr(mapped)(q, k, v).jaxpr)
+        if eqn.primitive.name == "shard_map"
+    ]
+    assert regions and all(
+        var.aval.ndim == 3
+        for eqn in regions
+        for var in eqn.invars + eqn.outvars
+    )

@@ -9,6 +9,7 @@ compiled four-chip step's view of the attention kernel (AOT, from
 libtpu's topology description — no chip involved).
 """
 
+import functools
 import json
 import os
 import shutil
@@ -350,12 +351,13 @@ def test_native_codec_unbuildable_fails_loudly(tmp_path, monkeypatch):
 # ---- the compiled four-chip step (AOT, no chip) ---------------------------------------
 
 
-def _compiled_for_v5e(script: str) -> dict:
+@functools.lru_cache(maxsize=None)
+def _compiled_for_v5e(script: str, *args: str) -> dict:
     """Run an AOT script of this directory in a process of its own (it
     describes the topology at its top level) and return the JSON line it
     prints; skip where libtpu gives no topology description."""
     proc = subprocess.run(
-        [sys.executable, os.path.join(REPO, "tests", script)],
+        [sys.executable, os.path.join(REPO, "tests", script), *args],
         env=dict(
             os.environ, PYTHONPATH=REPO, JAX_PLATFORMS="cpu", TPU_LOG_DIR="disabled"
         ),
@@ -398,3 +400,25 @@ def test_gpt2_small_step_compiles_to_no_loop():
     assert seen["dynamic_update_slices"] == 0
     # the temporaries the flat buffer and its copies held: 7.95 GB with them
     assert seen["temp_bytes"] < 5e9
+
+
+def test_gpt2_small_step_copies_no_operand_of_the_flash_kernels():
+    """The same compiled step: the kernels read heads out of the
+    projections' own (batch, tokens, heads * 64) rows and the projections
+    are 2-D products (``layers/attention.py::HeadsDense``), so XLA puts no
+    copy or transpose of a q-sized bf16 array on either side of a kernel.
+    With folded heads it held 96 (eight a layer, 1.7 ms of a 60 ms step on
+    the chip; with the kernels alone in lanes and ``nn.DenseGeneral``'s 4-D
+    bias add, 98: PERF.md section 6, PR 36).  No chip, no speed."""
+    seen = _compiled_for_v5e("aot_gpt2_small_step.py")
+    assert seen["activation_copies"] == 0
+    # the folded copies were temporaries: 3.33 GB with them
+    assert seen["temp_bytes"] < 3e9
+    # mapped over dp=4 the per-device kernels are handed the same rows: with
+    # 4-D arrays at the mapped region's boundary the step held 156
+    assert (
+        _compiled_for_v5e("aot_gpt2_small_step.py", "--chips", "4")[
+            "activation_copies"
+        ]
+        == 0
+    )
