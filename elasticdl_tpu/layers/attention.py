@@ -138,33 +138,37 @@ class MultiHeadSelfAttention(nn.Module):
                 use_bias=self.use_bias,
             )(x)
             if norm is not None:
-                full = y.reshape(*y.shape[:-2], heads * head_dim)
-                y = nn.RMSNorm(
-                    epsilon=self.norm_eps, dtype=self.dtype, name=norm
-                )(full).reshape(y.shape)
+                with jax.named_scope("qk_norm"):
+                    full = y.reshape(*y.shape[:-2], heads * head_dim)
+                    y = nn.RMSNorm(
+                        epsilon=self.norm_eps, dtype=self.dtype, name=norm
+                    )(full).reshape(y.shape)
             return y
 
         q = _proj("query", self.num_heads, "q_norm" if self.qk_norm else None)
         k = _proj("key", kv_heads, "k_norm" if self.qk_norm else None)
         v = _proj("value", kv_heads)
         if self.rope_theta:
-            positions = (
-                jnp.arange(x.shape[1])
-                if decode_pos is None
-                else decode_pos + jnp.arange(x.shape[1])
-            )
-            q = rope(q, positions, self.rope_theta)
-            k = rope(k, positions, self.rope_theta)
+            with jax.named_scope("rope"):
+                positions = (
+                    jnp.arange(x.shape[1])
+                    if decode_pos is None
+                    else decode_pos + jnp.arange(x.shape[1])
+                )
+                q = rope(q, positions, self.rope_theta)
+                k = rope(k, positions, self.rope_theta)
         if self.decode:
             if decode_pos is None:
                 raise ValueError("decode mode needs decode_pos")
             out = self._decode_attend(q, k, v, decode_pos)
         else:
             out = attention_ops.attention(q, k, v, causal=self.causal)
+        with jax.named_scope("fold"):
+            out = out.astype(x.dtype)
         return dense(
             features=embed, axis=(-2, -1), dtype=self.dtype, name="out",
             use_bias=self.use_bias,
-        )(out.astype(x.dtype))
+        )(out)
 
     def _decode_attend(self, q, k, v, pos):
         """One decode step: append this step's K/V to the cache at
@@ -258,23 +262,31 @@ class LatentSelfAttention(nn.Module):
             norm("q_a_norm")(dense(self.q_lora_rank, "q_a")(x))
         )
         latent = dense(self.kv_lora_rank + rot, "kv_a")(x)
+        with jax.named_scope("join"):
+            kv_latent = latent[..., : self.kv_lora_rank]
         kv = dense((heads, nope + self.v_head_dim), "kv_b")(
-            norm("kv_a_norm")(latent[..., : self.kv_lora_rank])
+            norm("kv_a_norm")(kv_latent)
         )
-        positions = jnp.arange(x.shape[1])
-        q_rot = rope(
-            q[..., nope:], positions, self.rope_theta, self.rope_interleave
-        )
-        k_rot = rope(
-            latent[..., None, self.kv_lora_rank :], positions,
-            self.rope_theta, self.rope_interleave,
-        )
-        q = jnp.concatenate([q[..., :nope], q_rot], axis=-1)
-        k = jnp.concatenate(
-            [kv[..., :nope], jnp.broadcast_to(k_rot, q_rot.shape)], axis=-1
-        )
-        out = attention_ops.attention(q, k, kv[..., nope:], causal=self.causal)
-        return dense(x.shape[-1], "out", axis=(-2, -1))(out.astype(x.dtype))
+        # the regions between the modules, by telemetry/op_scopes.py's names
+        with jax.named_scope("rope"):
+            positions = jnp.arange(x.shape[1])
+            q_rot = rope(
+                q[..., nope:], positions, self.rope_theta, self.rope_interleave
+            )
+            k_rot = rope(
+                latent[..., None, self.kv_lora_rank :], positions,
+                self.rope_theta, self.rope_interleave,
+            )
+        with jax.named_scope("join"):
+            q = jnp.concatenate([q[..., :nope], q_rot], axis=-1)
+            k = jnp.concatenate(
+                [kv[..., :nope], jnp.broadcast_to(k_rot, q_rot.shape)], axis=-1
+            )
+            v = kv[..., nope:]
+        out = attention_ops.attention(q, k, v, causal=self.causal)
+        with jax.named_scope("fold"):
+            out = out.astype(x.dtype)
+        return dense(x.shape[-1], "out", axis=(-2, -1))(out)
 
 
 NORMS = ("layernorm", "rmsnorm")
@@ -428,13 +440,16 @@ class TransformerBlock(nn.Module):
                 features, dtype=self.dtype, use_bias=self.use_bias, name=name
             )
 
-        if self.mlp == "swiglu":
-            hidden = nn.silu(dense(width, "mlp_gate")(y)) * dense(width, "mlp_up")(y)
-        elif self.mlp == "relu2":
-            hidden = jnp.square(nn.relu(dense(width, "mlp_up")(y)))
-        else:
-            hidden = nn.gelu(dense(width, "mlp_up")(y))
-        return dense(y.shape[-1], "mlp_down")(hidden)
+        with jax.named_scope("mlp"):
+            if self.mlp == "swiglu":
+                hidden = nn.silu(dense(width, "mlp_gate")(y)) * dense(
+                    width, "mlp_up"
+                )(y)
+            elif self.mlp == "relu2":
+                hidden = jnp.square(nn.relu(dense(width, "mlp_up")(y)))
+            else:
+                hidden = nn.gelu(dense(width, "mlp_up")(y))
+            return dense(y.shape[-1], "mlp_down")(hidden)
 
 
 def rope(x, positions, theta: float, interleave: bool = False):

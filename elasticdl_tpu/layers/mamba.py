@@ -99,7 +99,12 @@ class Mamba2Mixer(nn.Module):
             inner + conv_width + heads, use_bias=False, dtype=self.dtype,
             name="in_proj",
         )(u)
-        z, xbc, dt = jnp.split(projected, [inner, inner + conv_width], axis=-1)
+        # the regions between the modules and the kernel, by
+        # telemetry/op_scopes.py's names
+        with jax.named_scope("fold"):
+            z, xbc, dt = jnp.split(
+                projected, [inner, inner + conv_width], axis=-1
+            )
         with jax.named_scope("mamba_conv"):
             xbc = nn.silu(causal_conv(
                 xbc,
@@ -109,7 +114,8 @@ class Mamba2Mixer(nn.Module):
                 ),
                 self.param("conv_bias", nn.initializers.zeros, (conv_width,)),
             ))
-        x, b, c = jnp.split(xbc, [inner, inner + groups * states], axis=-1)
+        with jax.named_scope("fold"):
+            x, b, c = jnp.split(xbc, [inner, inner + groups * states], axis=-1)
         dt_bias = self.param(
             "dt_bias",
             _dt_bias_init(self.dt_min, self.dt_max, self.dt_floor), (heads,),
@@ -126,11 +132,12 @@ class Mamba2Mixer(nn.Module):
                 c.reshape(batch, steps, groups, states),
                 d, chunk=self.chunk,
             )
-        y = gated_group_norm(
-            y.reshape(batch, steps, inner), z,
-            self.param("norm_scale", nn.initializers.ones, (inner,)),
-            groups, self.norm_eps,
-        )
+        with jax.named_scope("gate_norm"):
+            y = gated_group_norm(
+                y.reshape(batch, steps, inner), z,
+                self.param("norm_scale", nn.initializers.ones, (inner,)),
+                groups, self.norm_eps,
+            )
         return nn.Dense(
             u.shape[-1], use_bias=False, dtype=self.dtype, name="out_proj"
         )(y)

@@ -238,35 +238,44 @@ def _experts_at(
     Returns the output and the number of pairs that were given a row."""
     tokens, slots = weights.shape
     experts = stacks[0].shape[0]
-    layout = gmm_ops.group_layout(
-        group_ids, experts, tile_rows, rows, order, by_rows
-    )
-    held = layout.row_pair < tokens * slots
+    # the three regions by telemetry/op_scopes.py's names; the kernels
+    # keep theirs (a scope around a call changes no custom-call's name)
+    with jax.named_scope("dispatch"):
+        layout = gmm_ops.group_layout(
+            group_ids, experts, tile_rows, rows, order, by_rows
+        )
+        held = layout.row_pair < tokens * slots
+        if by_rows:
+            _, row_token = _rows_of(weights, layout.row_pair)
+            buffer = _dispatch_by_rows(x, row_token, tokens)
+        else:
+            pair_row = layout.pair_row.reshape(tokens, slots)
+            row_token = (
+                jnp.minimum(layout.row_pair, tokens * slots - 1) // slots
+            )
+            buffer = _dispatch(
+                x, row_token, pair_row,
+                (group_ids < experts).reshape(tokens, slots),
+            )
     matmul = functools.partial(
         gmm_ops.grouped_matmul, tile_group=layout.tile_group,
         tile_rows=tile_rows, interpret=interpret,
     )
-    if by_rows:
-        _, row_token = _rows_of(weights, layout.row_pair)
-        buffer = _dispatch_by_rows(x, row_token, tokens)
-    else:
-        pair_row = layout.pair_row.reshape(tokens, slots)
-        row_token = jnp.minimum(layout.row_pair, tokens * slots - 1) // slots
-        buffer = _dispatch(
-            x, row_token, pair_row, (group_ids < experts).reshape(tokens, slots)
-        )
-    if len(stacks) == 3:
-        w_gate, w_up, w_down = stacks
-        hidden = nn.silu(matmul(buffer, w_gate)) * matmul(buffer, w_up)
-    else:
-        w_up, w_down = stacks
-        hidden = jnp.square(nn.relu(matmul(buffer, w_up)))
-    out = matmul(hidden, w_down)
-    if by_rows:
-        y = _combine_by_rows(out, weights, layout.row_pair)
-    else:
-        y = _combine(out, weights, pair_row, layout.row_pair)
-    return y, jnp.sum(held, dtype=jnp.int32)
+    with jax.named_scope("experts"):
+        if len(stacks) == 3:
+            w_gate, w_up, w_down = stacks
+            hidden = nn.silu(matmul(buffer, w_gate)) * matmul(buffer, w_up)
+        else:
+            w_up, w_down = stacks
+            hidden = jnp.square(nn.relu(matmul(buffer, w_up)))
+        out = matmul(hidden, w_down)
+    with jax.named_scope("combine"):
+        if by_rows:
+            y = _combine_by_rows(out, weights, layout.row_pair)
+        else:
+            y = _combine(out, weights, pair_row, layout.row_pair)
+    with jax.named_scope("dispatch"):
+        return y, jnp.sum(held, dtype=jnp.int32)
 
 
 def _rung_of(rungs, tile_rows, sizes):
@@ -299,12 +308,14 @@ def _experts_on_ladder(
     same choice again and runs, inside the branch, that rung's forward up
     to the combine's input and its backward.  Returns the output, the pairs
     given a row and the rows of the rung taken."""
-    rung = _rung_of(rungs, tile_rows, order.sizes)
-    y, held = jax.lax.switch(
-        rung, _branches(rungs, tile_rows, interpret),
-        x, weights, group_ids, order, stacks,
-    )
-    return y, held, jnp.asarray(rungs, jnp.int32)[rung]
+    # (the choice and what XLA puts in its branches, by op_scopes's name)
+    with jax.named_scope("rung"):
+        rung = _rung_of(rungs, tile_rows, order.sizes)
+        y, held = jax.lax.switch(
+            rung, _branches(rungs, tile_rows, interpret),
+            x, weights, group_ids, order, stacks,
+        )
+        return y, held, jnp.asarray(rungs, jnp.int32)[rung]
 
 
 def _experts_on_ladder_fwd(
@@ -333,11 +344,12 @@ def _experts_on_ladder_bwd(rungs, tile_rows, interpret, residuals, cotangents):
 
         return run
 
-    d_x, d_weights, d_stacks = jax.lax.switch(
-        _rung_of(rungs, tile_rows, order.sizes),
-        [backward(b) for b in _branches(rungs, tile_rows, interpret)],
-        cotangents[0], x, weights, stacks,
-    )
+    with jax.named_scope("rung"):
+        d_x, d_weights, d_stacks = jax.lax.switch(
+            _rung_of(rungs, tile_rows, order.sizes),
+            [backward(b) for b in _branches(rungs, tile_rows, interpret)],
+            cotangents[0], x, weights, stacks,
+        )
     return d_x, d_weights, None, None, d_stacks
 
 
@@ -359,11 +371,14 @@ def routed_experts(
     one (int32[2])."""
     tokens, slots = top_experts.shape
     experts = stacks[0].shape[0]
-    local = top_experts - first_expert
-    grouped = (local >= 0) & (local < experts)
-    group_ids = jnp.where(grouped, local, experts).reshape(-1).astype(jnp.int32)
-    order = gmm_ops.group_order(group_ids, experts)
-    weights = jnp.where(grouped, weights, 0.0)
+    with jax.named_scope("dispatch"):
+        local = top_experts - first_expert
+        grouped = (local >= 0) & (local < experts)
+        group_ids = (
+            jnp.where(grouped, local, experts).reshape(-1).astype(jnp.int32)
+        )
+        order = gmm_ops.group_order(group_ids, experts)
+        weights = jnp.where(grouped, weights, 0.0)
     rungs = gmm_ops.ladder(
         tokens * slots, experts, num_experts or experts, tile_rows
     )
@@ -398,11 +413,16 @@ def _experts_on_mesh(
     slots = top_experts.shape[-1]
 
     def local(x, top_experts, weights, *stacks, **kw):
+        with jax.named_scope("dispatch"):
+            flat = (
+                x.reshape(-1, embed), top_experts.reshape(-1, slots),
+                weights.reshape(-1, slots),
+            )
         y, held, buffer_rows = routed_experts(
-            x.reshape(-1, embed), top_experts.reshape(-1, slots),
-            weights.reshape(-1, slots), *stacks, num_experts=num_experts, **kw,
+            *flat, *stacks, num_experts=num_experts, **kw
         )
-        return y.reshape(x.shape), held, buffer_rows
+        with jax.named_scope("combine"):
+            return y.reshape(x.shape), held, buffer_rows
 
     if mesh is None:
         return local(
@@ -505,6 +525,28 @@ class MoEMLP(nn.Module):
                 f"experts {self.first_expert}..{self.first_expert + held} "
                 f"of {self.num_experts}"
             )
+        with jax.named_scope("route"):
+            top_experts, weights, counts = self._route(x, training)
+        names = ("w_gate", "w_up") if self.expert_kind == "swiglu" else ("w_up",)
+        stacks = [
+            self.param(name, _expert_init, (held, embed, width)) for name in names
+        ] + [self.param("w_down", _expert_init, (held, width, embed))]
+        if self.dtype is not None:
+            x = x.astype(self.dtype)
+        y, rows_held, buffer_rows = _experts_on_mesh(
+            x, top_experts, weights, stacks, self.first_expert, self.num_experts
+        )
+        if self.shared_width:
+            with jax.named_scope("shared"):
+                y = y + self._shared_expert(x)
+        with jax.named_scope("route"):
+            self._sow_stats(counts, rows_held, buffer_rows, held)
+        return y
+
+    def _route(self, x, training):
+        """Scores, the ``k`` experts a token and their weights, the
+        auxiliary losses and the selection bias's step; returns the experts,
+        the weights and the pairs routed to each expert."""
         # the router in float32 at full precision: a near-tie between two
         # experts is decided as a float32 reference decides it
         logits = nn.Dense(
@@ -558,17 +600,9 @@ class MoEMLP(nn.Module):
                 jnp.mean(counts) - counts
             )
 
-        names = ("w_gate", "w_up") if self.expert_kind == "swiglu" else ("w_up",)
-        stacks = [
-            self.param(name, _expert_init, (held, embed, width)) for name in names
-        ] + [self.param("w_down", _expert_init, (held, width, embed))]
-        if self.dtype is not None:
-            x = x.astype(self.dtype)
-        y, rows_held, buffer_rows = _experts_on_mesh(
-            x, top_experts, weights, stacks, self.first_expert, self.num_experts
-        )
-        if self.shared_width:
-            y = y + self._shared_expert(x)
+        return top_experts, weights, counts
+
+    def _sow_stats(self, counts, rows_held, buffer_rows, held):
         # what telemetry/router_load.py reads on demand; the dispatch's own
         # count of rows beside the router's says that no pair was dropped,
         # the rows of the rung its buffer took beside the full rung's how
@@ -590,7 +624,6 @@ class MoEMLP(nn.Module):
                 init_fn=lambda value=value: jnp.zeros_like(value),
                 reduce_fn=lambda _prev, new: new,
             )
-        return y
 
     def _shared_expert(self, x):
         def dense(features, name):

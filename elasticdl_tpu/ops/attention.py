@@ -43,6 +43,10 @@ _NEG_INF = -1e30
 FLASH_FWD = "flash_fwd"
 FLASH_DQ = "flash_dq"
 FLASH_DKV = "flash_dkv"
+# the XLA ops beside the kernel calls (reshapes and transposes to and from
+# the kernels' layout) by telemetry/op_scopes.py's name; never around a
+# ``pallas_call``, whose own name is what the device's op line shows
+_FOLD = "fold"
 
 
 # ---- mesh context (set by the trainer, read by layers) ---------------------
@@ -866,6 +870,8 @@ def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret):
         chunk_k=chunk_k,
         num_ck=num_ck,
     )
+    with jax.named_scope(_FOLD):
+        operands = [at.operand(x) for x in (q, k, v)]
     out, lse = pl.pallas_call(
         kernel,
         grid=(batch, at.cells, seq_q // block_q, num_ck),
@@ -893,8 +899,9 @@ def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret):
         compiler_params=_FLASH_COMPILER_PARAMS,
         interpret=interpret,
         name=FLASH_FWD,
-    )(at.operand(q), at.operand(k), at.operand(v))
-    return at.result(out, batch, heads), lse
+    )(*operands)
+    with jax.named_scope(_FOLD):
+        return at.result(out, batch, heads), lse
 
 
 @functools.partial(
@@ -916,7 +923,8 @@ def _flash_backward(
     num_cq = seq_q // chunk_q
     at = _HeadAddressing(q, k, v)
 
-    qf, kf, vf, dof = (at.operand(x) for x in (q, k, v, g))
+    with jax.named_scope(_FOLD):
+        qf, kf, vf, dof = (at.operand(x) for x in (q, k, v, g))
 
     def _q_block(h, i, c):
         return i
@@ -932,7 +940,9 @@ def _flash_backward(
     dq_scratch = pltpu.VMEM((bq, at.per_cell * d), jnp.float32)
     if at.lanes:
         # the kernel makes delta_r = rowsum(dO * O) from out's blocks
-        extra, extra_spec = at.operand(out), at.spec(bq, d_v, _q_block)
+        with jax.named_scope(_FOLD):
+            extra = at.operand(out)
+        extra_spec = at.spec(bq, d_v, _q_block)
         out_specs = [dq_spec, row_block]
         out_shape = [dq_shape, jax.ShapeDtypeStruct(lse.shape, jnp.float32)]
         scratch = [
@@ -941,10 +951,11 @@ def _flash_backward(
         ]
     else:
         # ... or is handed it, in the lse's layout
-        extra = jnp.sum(
-            dof.astype(jnp.float32) * at.operand(out).astype(jnp.float32),
-            axis=-1,
-        )[:, None, :]  # (B*H, 1, S_q)
+        with jax.named_scope(_FOLD):
+            extra = jnp.sum(
+                dof.astype(jnp.float32) * at.operand(out).astype(jnp.float32),
+                axis=-1,
+            )[:, None, :]  # (B*H, 1, S_q)
         extra_spec, out_specs, out_shape = row_block, dq_spec, dq_shape
         scratch = [dq_scratch]
     made = pl.pallas_call(
@@ -1020,14 +1031,15 @@ def _flash_backward(
         name=FLASH_DKV,
     )(qf, kf, vf, dof, lse.reshape(rows), delta.reshape(rows))
 
-    dq = at.result(dq, batch, heads)
-    dk = at.result(dk_per_q, batch, heads)
-    dv = at.result(dv_per_q, batch, heads)
-    if at.group > 1:
-        # sum each kv head's query group: (B, S, H, D) -> (B, S, KVH, D)
-        dk = dk.reshape(batch, seq_k, kv_heads, at.group, d).sum(axis=3)
-        dv = dv.reshape(batch, seq_k, kv_heads, at.group, d_v).sum(axis=3)
-    return dq, dk.astype(k.dtype), dv.astype(v.dtype)
+    with jax.named_scope(_FOLD):
+        dq = at.result(dq, batch, heads)
+        dk = at.result(dk_per_q, batch, heads)
+        dv = at.result(dv_per_q, batch, heads)
+        if at.group > 1:
+            # sum each kv head's query group: (B, S, H, D) -> (B, S, KVH, D)
+            dk = dk.reshape(batch, seq_k, kv_heads, at.group, d).sum(axis=3)
+            dv = dv.reshape(batch, seq_k, kv_heads, at.group, d_v).sum(axis=3)
+        return dq, dk.astype(k.dtype), dv.astype(v.dtype)
 
 
 def _flash_fwd_rule(q, k, v, causal, sm_scale, block_q, block_k, interpret):

@@ -52,6 +52,9 @@ from elasticdl_tpu.ops.attention import get_attention_mesh, kernel_interpret
 
 SSD_FWD = "ssd_fwd"
 SSD_BWD = "ssd_bwd"
+# the transposes to and from the kernels' layout, by telemetry/op_scopes.py's
+# name; never around a ``pallas_call`` (ops/attention.py)
+_FOLD = "fold"
 
 _LANES = 128
 # a @ b.T and a.T @ b: the transposed products the MXU takes natively
@@ -281,34 +284,45 @@ def _ssd_core(xd, cum, b, c, chunk, interpret):
 
 def _ssd_core_fwd(xd, cum, b, c, chunk, interpret):
     groups = b.shape[2]
-    y, start = _forward(
-        _by_group(xd, groups), b.transpose(0, 2, 1, 3), c.transpose(0, 2, 1, 3),
-        _sums_by_group(cum, groups, chunk), interpret,
-    )
-    return _by_step(y), (xd, cum, b, c, start)
+    with jax.named_scope(_FOLD):
+        operands = (
+            _by_group(xd, groups), b.transpose(0, 2, 1, 3),
+            c.transpose(0, 2, 1, 3), _sums_by_group(cum, groups, chunk),
+        )
+    y, start = _forward(*operands, interpret)
+    with jax.named_scope(_FOLD):
+        return _by_step(y), (xd, cum, b, c, start)
 
 
 def _ssd_core_bwd(chunk, interpret, residuals, dy):
     xd, cum, b, c, start = residuals
     groups = b.shape[2]
     batch, steps, heads, _ = xd.shape
-    dy = dy.astype(xd.dtype)
-    dxd, db, dc, dcum, ends = _backward(
-        _by_group(xd, groups), b.transpose(0, 2, 1, 3), c.transpose(0, 2, 1, 3),
-        _sums_by_group(cum, groups, chunk), _by_group(dy, groups), start,
-        interpret,
-    )
-    # (batch, chunks, groups, heads a group, L) back to (batch, T, heads)
-    dcum = dcum.transpose(0, 1, 4, 2, 3).reshape(batch, steps // chunk, chunk, heads)
-    # a chunk's last running sum also scales the state it hands on: the
-    # kernel gives <H, dH> at each chunk's start, which is the chunk
-    # before's <H', dH'>; the last chunk hands nothing on
-    ends = ends[..., 0].reshape(batch, steps // chunk, heads)
-    ends = jnp.concatenate([ends[:, 1:], jnp.zeros_like(ends[:, :1])], axis=1)
-    dcum = dcum.at[:, :, -1, :].add(ends).reshape(batch, steps, heads)
-    return (
-        _by_step(dxd), dcum, db.transpose(0, 2, 1, 3), dc.transpose(0, 2, 1, 3)
-    )
+    with jax.named_scope(_FOLD):
+        dy = dy.astype(xd.dtype)
+        operands = (
+            _by_group(xd, groups), b.transpose(0, 2, 1, 3),
+            c.transpose(0, 2, 1, 3), _sums_by_group(cum, groups, chunk),
+            _by_group(dy, groups),
+        )
+    dxd, db, dc, dcum, ends = _backward(*operands, start, interpret)
+    with jax.named_scope(_FOLD):
+        # (batch, chunks, groups, heads a group, L) back to (batch, T, heads)
+        dcum = dcum.transpose(0, 1, 4, 2, 3).reshape(
+            batch, steps // chunk, chunk, heads
+        )
+        # a chunk's last running sum also scales the state it hands on: the
+        # kernel gives <H, dH> at each chunk's start, which is the chunk
+        # before's <H', dH'>; the last chunk hands nothing on
+        ends = ends[..., 0].reshape(batch, steps // chunk, heads)
+        ends = jnp.concatenate(
+            [ends[:, 1:], jnp.zeros_like(ends[:, :1])], axis=1
+        )
+        dcum = dcum.at[:, :, -1, :].add(ends).reshape(batch, steps, heads)
+        return (
+            _by_step(dxd), dcum, db.transpose(0, 2, 1, 3),
+            dc.transpose(0, 2, 1, 3),
+        )
 
 
 _ssd_core.defvjp(_ssd_core_fwd, _ssd_core_bwd)

@@ -25,7 +25,7 @@ from jax.sharding import Mesh, NamedSharding
 from elasticdl_tpu.ops.attention import attention_mesh_scope
 from elasticdl_tpu.parallel import elastic, program_store
 from elasticdl_tpu.parallel import sharding as sharding_lib
-from elasticdl_tpu.telemetry import router_load
+from elasticdl_tpu.telemetry import op_scopes, router_load
 from elasticdl_tpu.telemetry.anatomy import PHASE_H2D_TRANSFER, TIMELINE
 from elasticdl_tpu.trainer.state import TrainState
 from elasticdl_tpu.trainer.step import (
@@ -171,6 +171,10 @@ class SPMDTrainer:
         # an expert model's router counts stay in the state as device
         # arrays; router_load.read() fetches the newest on demand
         router_load.watch(self)
+        # ... and op_scopes.read() maps the train programs' ops to the
+        # model's scopes, from the programs this trainer dispatched
+        op_scopes.watch(self)
+        self._jit_calls: dict = {}
         self._batch_shardings_cache: dict = {}
         self._stacked_scan_cache: dict = {}
         # mesh topology is immutable for this trainer's lifetime: resolve
@@ -391,12 +395,32 @@ class SPMDTrainer:
         stored.  Both take ``(state, *batch)`` and give ``(state,
         metrics)``."""
         if self._programs is None:
+            if jitted not in self._jit_calls:
+                # what train_programs() needs to ask jit for its executable
+                self._jit_calls[jitted] = [
+                    _shapes_of((self._state,) + batch), None
+                ]
             return jitted
         if self._state_kind is None:
             self._state_kind = self._programs.kind_of(self._state)
         return self._programs.for_call(
             name, jitted, self._state, batch, self._state_kind
         )
+
+    def train_programs(self) -> list:
+        """The compiled train programs (``jax.stages.Compiled``) this
+        trainer has dispatched: the program store's as it keeps them, a
+        ``jit``-only trainer's asked of ``jit`` the way a call does (the
+        lowering and the executable are the ones the calls made: nothing
+        compiles).  For ``telemetry/op_scopes.py``; never on the train
+        path."""
+        if self._programs is not None:
+            return self._programs.train_programs()
+        with self.mesh, attention_mesh_scope(self.mesh):
+            for jitted, call in self._jit_calls.items():
+                if call[1] is None:
+                    call[1] = jitted.lower(*call[0]).compile()
+        return [compiled for _, compiled in self._jit_calls.values()]
 
     def place_stacked(self, tree):
         """Place a (K, batch, ...) stacked tree: same layout as
@@ -509,6 +533,9 @@ class _StoredPrograms:
             self._devices,
         )
 
+    def train_programs(self) -> list:
+        return list(self._programs.values())
+
     def for_call(self, name, jitted, state, batch, state_kind):
         """A step's program for this state and batch, kept per call
         signature as ``jit`` keeps its own."""
@@ -527,6 +554,17 @@ class _StoredPrograms:
                 jax.tree_util.tree_structure((state, {"loss": 0})),
             )
         return program
+
+
+def _shapes_of(tree):
+    """Every array of ``tree`` as the shape, dtype and sharding a lowering
+    takes in its place."""
+    return jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=getattr(x, "sharding", None)
+        ),
+        tree,
+    )
 
 
 def _note_placed(start_ns: int, placed):

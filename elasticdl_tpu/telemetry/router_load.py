@@ -93,25 +93,3 @@ def read(model_state=None) -> dict | None:
         # (a state sown before the counter existed has no rows to share)
         "buffer_share": buffer_rows / full_rows if full_rows else None,
     }
-
-
-def publish(registry, model_state=None) -> dict | None:
-    """:func:`read` and :func:`read_loss_parts`, set as gauges on
-    ``registry``; returns the router load."""
-    for name, value in (read_loss_parts(model_state) or {}).items():
-        registry.gauge(
-            f"elasticdl_train_loss_{name}",
-            "a named part of the newest train step's loss",
-        ).set(value)
-    load = read(model_state)
-    if load is not None:
-        for name in (
-            "max_over_mean", "experts_without_tokens", "dropped_pairs",
-            "buffer_share",
-        ):
-            if load[name] is not None:
-                registry.gauge(
-                    f"elasticdl_router_{name}",
-                    "expert router load of the newest train step",
-                ).set(load[name])
-    return load

@@ -178,9 +178,13 @@ def build_train_step(
     """
 
     def forward_loss(params, state, features, labels, weights):
-        if device_parse is not None:
-            features = device_parse(features)
-        features = _cast_floats(features, compute_dtype)
+        # the regions of the step that no module names carry a scope of
+        # telemetry/op_scopes.py::VOCABULARY: a scope changes an op's
+        # metadata and nothing else of the compiled program
+        with jax.named_scope("parse"):
+            if device_parse is not None:
+                features = device_parse(features)
+            features = _cast_floats(features, compute_dtype)
         outputs, new_model_state = _apply(state, params, features, True)
         # a model that declares the LOSS_PARTS collection (a second-token
         # loss beside the main one) has its loss computed by the named
@@ -188,27 +192,28 @@ def build_train_step(
         # state, for telemetry/router_load.py to read on demand
         by_parts = LOSS_PARTS in new_model_state
         fn = loss_fn.parts if by_parts else loss_fn
-        if weights is None:
-            loss = fn(labels, outputs)
-        else:
-            loss = weighted_mean_loss(fn, labels, outputs, weights)
-        if by_parts:
-            new_model_state = {
-                **new_model_state,
-                LOSS_PARTS: jax.lax.stop_gradient(
-                    {k: v.astype(jnp.float32) for k, v in loss.items()}
-                ),
-            }
-            loss = sum(loss.values())
-        # layer-contributed losses (MoE load balancing, regularizers):
-        # any value sown into the "losses" collection joins the training
-        # loss — the reference adds Keras model reg losses the same way
-        # (worker.py:656-669)
-        for leaf in jax.tree_util.tree_leaves(
-            new_model_state.get("losses", {})
-        ):
-            loss = loss + jnp.sum(leaf)
-        return loss.astype(jnp.float32), (outputs, new_model_state)
+        with jax.named_scope("loss"):
+            if weights is None:
+                loss = fn(labels, outputs)
+            else:
+                loss = weighted_mean_loss(fn, labels, outputs, weights)
+            if by_parts:
+                new_model_state = {
+                    **new_model_state,
+                    LOSS_PARTS: jax.lax.stop_gradient(
+                        {k: v.astype(jnp.float32) for k, v in loss.items()}
+                    ),
+                }
+                loss = sum(loss.values())
+            # layer-contributed losses (MoE load balancing, regularizers):
+            # any value sown into the "losses" collection joins the training
+            # loss — the reference adds Keras model reg losses the same way
+            # (worker.py:656-669)
+            for leaf in jax.tree_util.tree_leaves(
+                new_model_state.get("losses", {})
+            ):
+                loss = loss + jnp.sum(leaf)
+            return loss.astype(jnp.float32), (outputs, new_model_state)
 
     if remat:
         forward_loss = jax.checkpoint(
@@ -220,11 +225,11 @@ def build_train_step(
         (loss, (_, new_model_state)), grads = grad_fn(
             state.params, state, features, labels, weights
         )
-        if extra_grad_fn is not None:
-            grads = extra_grad_fn(grads, state)
-        new_state = state.apply_gradients(grads).replace(
-            model_state=new_model_state
-        )
+        with jax.named_scope("optimizer"):
+            if extra_grad_fn is not None:
+                grads = extra_grad_fn(grads, state)
+            new_state = state.apply_gradients(grads)
+        new_state = new_state.replace(model_state=new_model_state)
         return new_state, {"loss": loss}
 
     donate_argnums = (0,) if donate else ()

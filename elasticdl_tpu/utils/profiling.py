@@ -37,6 +37,14 @@ beside the ``.xplane.pb``; ``perf/program_spans.py::align_profile_window`` puts 
 the trace's clock by that anchor.  One block at the end of a window
 changes nothing the window measured.
 
+The HLO attribution is the program's own: beside those two files the
+close writes ``op_scopes.json``, the train programs' map from the op
+line's names (``fusion.25``) to the model's scopes (part, phase, kind:
+``telemetry/op_scopes.py``, read from the compiled programs the trainer
+dispatched, one parse of each program's HLO text at the close and nothing
+before it), and ``python -m elasticdl_tpu.telemetry.op_scopes <dir>``
+prints the window's device time by part x phase x kind.
+
 Disabled cost: with no window pending or open, :meth:`on_step` is one
 attribute load and a ``not x`` check (``# elastic-lint: hot-path``).
 Thread model: :meth:`arm` is called from the heartbeat thread,
@@ -51,7 +59,7 @@ import os
 import threading
 import time
 
-from elasticdl_tpu.telemetry import anatomy
+from elasticdl_tpu.telemetry import anatomy, op_scopes
 from elasticdl_tpu.utils.log_utils import default_logger as logger
 
 # subdirectory of the telemetry dir an on-demand capture lands in when
@@ -256,7 +264,7 @@ class StepProfiler:
             logger.exception("XLA profiler: stop_trace failed")
         else:
             try:
-                self._write_host_spans()
+                self._write_beside_trace()
             except OSError:
                 logger.exception("XLA profiler: host spans not written")
         self._tracing = False
@@ -279,9 +287,10 @@ class StepProfiler:
         self._out_dir = ""
 
     # lock-holding: _lock
-    def _write_host_spans(self):
-        """The timeline's spans of the window, beside the newest
-        ``.xplane.pb`` (in the window's directory where there is none)."""
+    def _write_beside_trace(self):
+        """The timeline's spans of the window and the train programs' op
+        scopes, beside the newest ``.xplane.pb`` (in the window's directory
+        where there is none)."""
         traces = sorted(
             glob.glob(
                 os.path.join(
@@ -296,6 +305,11 @@ class StepProfiler:
             start_ns=self._opened_ns,
             end_ns=time.perf_counter_ns(),
         )
+        try:
+            op_scopes.dump(os.path.join(where, op_scopes.OP_SCOPES_FILE))
+        except Exception:  # noqa: BLE001 — the map is an aid: a program
+            # whose text cannot be read must not cost the window its trace
+            logger.exception("XLA profiler: op scopes not written")
 
     def stop(self):
         """Idempotent; called at loop exit so a short run still flushes

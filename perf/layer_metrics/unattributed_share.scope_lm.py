@@ -1,0 +1,3 @@
+"""``unattributed_share.scope_lm``: see ``perf.scope_shares.unattributed_share``."""
+
+from perf.scope_shares import unattributed_share as read  # noqa: F401

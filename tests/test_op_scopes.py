@@ -1,0 +1,590 @@
+"""``telemetry/op_scopes.py``: the compiled program's op -> scope map.
+
+The rules on hand-written name stacks and a hand-written module; then, for a
+tiny model of each family through ``build_train_step`` on the CPU, what the
+map of the real compiled step says — every region has a name from the closed
+vocabulary, the scopes change nothing but metadata, and the map of a program
+that went through the program store's serialisation is the built one's."""
+
+import contextlib
+import glob
+import json
+import os
+import re
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+
+from elasticdl_tpu.models import long_seq_transformer as lm
+from elasticdl_tpu.models import resnet50_model
+from elasticdl_tpu.ops import attention as attention_ops
+from elasticdl_tpu.ops import grouped_matmul as gmm_ops
+from elasticdl_tpu.ops import ssd as ssd_ops
+from elasticdl_tpu.parallel.distributed import SPMDTrainer
+from elasticdl_tpu.parallel.mesh import MeshConfig
+from elasticdl_tpu.telemetry import compile_tracker, op_scopes
+from elasticdl_tpu.trainer.state import TrainState
+from elasticdl_tpu.trainer.step import build_train_step
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+KERNELS = {
+    attention_ops.FLASH_FWD, attention_ops.FLASH_DQ, attention_ops.FLASH_DKV,
+    gmm_ops.GMM_FWD, gmm_ops.GMM_DX, gmm_ops.GMM_DW,
+    ssd_ops.SSD_FWD, ssd_ops.SSD_BWD,
+}
+# modules that hold other modules: an op directly under one of these is in
+# a region nobody named
+MIXERS = {"attn", "moe", "mamba"}
+
+STEP = "jit(train_step)/"
+BLOCK = "block_11/block_11._residual/block_11._attention/attn/"
+
+
+@pytest.mark.parametrize(
+    "op_name,part,phase",
+    [
+        # flax's method scopes (``block_11._attention``) go, counters fold
+        (
+            STEP + "jvp(TransformerLM)/" + BLOCK + "query/dot_general",
+            "block/attn/query", "forward",
+        ),
+        # the backward pass: a ``transpose(`` anywhere in the stack
+        (
+            STEP + "transpose(jvp(TransformerLM))/" + BLOCK + "rope/mul",
+            "block/attn/rope", "backward",
+        ),
+        # a recomputed forward inside the backward is a recompute
+        (
+            STEP + "transpose(jvp(TransformerLM))/jvp(TransformerLM)/"
+            "checkpoint/rematted_computation/" + BLOCK
+            + "flash_fwd/pallas_call",
+            "block/attn/flash_fwd", "recompute",
+        ),
+        # ... and the backward of a recomputed layer is the backward
+        (
+            STEP + "transpose(jvp(TransformerLM))/jvp(TransformerLM)/"
+            "checkpoint/" + BLOCK + "out/dot_general",
+            "block/attn/out", "backward",
+        ),
+        # control flow and a function's own jit name no region
+        (
+            STEP + "jvp(TransformerLM)/block_3/block_3._experts/moe/dispatch/"
+            "jit(searchsorted)/vmap()/closed_call/while/body/"
+            "cond/branch_1_fun/jit(_where)/select_n",
+            "block/moe/dispatch", "forward",
+        ),
+        # a function two layers call, compiled once: the stacks strung
+        # together, the first taken
+        (
+            STEP + "jvp(TransformerLM)/block_8/moe/dispatch/jit(searchsorted)/"
+            + STEP + "jvp(TransformerLM)/block_6/moe/dispatch/jit(searchsorted)",
+            "block/moe/dispatch", "forward",
+        ),
+        # a scope entered again inside itself counts once
+        (
+            STEP + "transpose(jvp(TransformerLM))/block_1/moe/rung/cond/"
+            "branch_0_fun/jvp(rung)/experts/expert_gmm_dw/pallas_call",
+            "block/moe/rung/experts/expert_gmm_dw", "backward",
+        ),
+        # an einsum's specification is no identifier
+        (
+            STEP + "jvp(TransformerLM)/block_0/moe/combine/nkd,nk->nd/"
+            "dot_general",
+            "block/moe/combine", "forward",
+        ),
+        # the multi-token-prediction module's names
+        (
+            STEP + "jvp(TransformerLM)/mtp_1_block/mtp_1_block._residual/"
+            "mtp_1_block._attention/attn/join/concatenate",
+            "mtp/block/attn/join", "forward",
+        ),
+        (STEP + "jvp(TransformerLM)/mtp_1_proj/dot_general", "mtp/proj", "forward"),
+        # two counters, an anonymous child, the root alone
+        (
+            STEP + "jvp(ResNet50)/identity_block_2_1/bn_a/reduce_sum",
+            "identity_block/bn_a", "forward",
+        ),
+        (STEP + "jvp(TransformerLM)/LayerNorm_0/rsqrt", "LayerNorm", "forward"),
+        (STEP + "jvp(TransformerLM)/add", "model", "forward"),
+        # the step's own regions
+        (STEP + "jvp(loss)/vmap()/reduce_max", "loss", "forward"),
+        (STEP + "transpose(jvp(loss))/vmap()/mul", "loss", "backward"),
+        (STEP + "optimizer/sqrt", "optimizer", "optimizer"),
+        (
+            "jit(scan_steps)/while/body/jit(train_step)/optimizer/add",
+            "optimizer", "optimizer",
+        ),
+        # an empty stack names nothing
+        (STEP + "mul", None, "forward"),
+        (STEP + "transpose(jvp())/mul", None, "backward"),
+    ],
+)
+def test_canonical_part_and_phase(op_name, part, phase):
+    assert op_scopes.canonical(op_name) == (part, phase)
+
+
+MODULE = """HloModule jit_train_step, is_scheduled=true
+
+%region_0.1 (a: f32[], b: f32[]) -> f32[] {
+  %a = f32[] parameter(0)
+  %b = f32[] parameter(1)
+  ROOT %add.0 = f32[] add(%a, %b)
+}
+
+%fused_computation.1 (param_0: f32[8,64], param_1: f32[64,512]) -> f32[64,512] {
+  %param_0 = f32[8,64]{1,0} parameter(0)
+  %param_1 = f32[64,512]{1,0} parameter(1)
+  %dot.small = f32[8,8]{1,0} dot(%param_0, %param_0), metadata={op_name="jit(train_step)/transpose(jvp(TransformerLM))/block_0/block_0._mlp/mlp/mlp_up/dot_general" stack_frame_id=3}
+  %dot.big = f32[64,512]{1,0} dot(%param_0, %param_1), metadata={op_name="jit(train_step)/transpose(jvp(TransformerLM))/lm_head/dot_general" stack_frame_id=4}
+  %mul.1 = f32[64,512]{1,0} multiply(%dot.big, %param_1), metadata={op_name="jit(train_step)/optimizer/mul"}
+  ROOT %add.1 = f32[64,512]{1,0} add(%mul.1, %param_1), metadata={op_name="jit(train_step)/optimizer/add"}
+}
+
+%fused_computation.2 (param_0.1: f32[8,64]) -> bf16[8,64] {
+  %param_0.1 = f32[8,64]{1,0} parameter(0)
+  %exp.1 = f32[8,64]{1,0} exponential(%param_0.1), metadata={op_name="jit(train_step)/jvp(TransformerLM)/block_1/block_1._attention/attn/rope/exp"}
+  %convert.9 = bf16[8,64]{1,0} convert(%exp.1)
+  ROOT %bitcast.9 = bf16[8,64]{1,0} bitcast(%convert.9)
+}
+
+%body.1 (p: (s32[], f32[8,64])) -> (s32[], f32[8,64]) {
+  %p = (s32[], f32[8,64]{1,0}) parameter(0)
+  %gte.1 = f32[8,64]{1,0} get-tuple-element(%p), index=1
+  %copy.7 = f32[8,64]{0,1} copy(%gte.1)
+  %neg.1 = f32[8,64]{1,0} negate(%gte.1), metadata={op_name="jit(train_step)/jvp(TransformerLM)/block_0/moe/dispatch/while/body/neg"}
+  ROOT %tuple.1 = (s32[], f32[8,64]{1,0}) tuple(%gte.1, %neg.1)
+}
+
+%cond.1 (p.1: (s32[], f32[8,64])) -> pred[] {
+  %p.1 = (s32[], f32[8,64]{1,0}) parameter(0)
+  ROOT %lt.1 = pred[] constant(true)
+}
+
+ENTRY %main.9 (state: f32[8,64], w: f32[64,512]) -> f32[64,512] {
+  %state = f32[8,64]{1,0} parameter(0), metadata={op_name="state.params['w']"}
+  %w = f32[64,512]{1,0} parameter(1)
+  %copy.1 = f32[8,64]{0,1:T(8,128)} copy(%state)
+  %fusion.2 = bf16[8,64]{1,0} fusion(%copy.1), kind=kLoop, calls=%fused_computation.2
+  %flash_fwd.3 = (bf16[8,64]{1,0:T(8,128)(2,1)}, f32[8,1,64]{2,1,0}) custom-call(%fusion.2), custom_call_target="tpu_custom_call", metadata={op_name="jit(train_step)/jvp(TransformerLM)/block_1/block_1._attention/attn/flash_fwd/pallas_call" stack_frame_id=9}, backend_config={"custom_call_config":{"body":"TUzvUg=="}}
+  %concat.1 = f32[8,64]{1,0} custom-call(%state), custom_call_target="ConcatBitcast", metadata={op_name="jit(train_step)/jvp(TransformerLM)/tok_embed/concatenate"}
+  %tuple.9 = (s32[], /*index=1*/f32[8,64]{1,0}) tuple(%state, %concat.1)
+  %while.1 = (s32[], f32[8,64]{1,0}) while(%tuple.9), condition=%cond.1, body=%body.1, metadata={op_name="jit(train_step)/jvp(TransformerLM)/block_0/moe/dispatch/while"}
+  %all-reduce.1 = f32[8,64]{1,0} all-reduce(%concat.1), to_apply=%region_0.1, metadata={op_name="jit(train_step)/transpose(jvp(TransformerLM))/block_0/block_0._mlp/mlp/mlp_up/dot_general"}
+  %orphan.1 = f32[8,64]{1,0} iota(), iota_dimension=0
+  ROOT %fusion.1 = f32[64,512]{1,0} fusion(%all-reduce.1, %w), kind=kOutput, calls=%fused_computation.1, metadata={op_name="jit(train_step)/optimizer/add"}
+}
+"""
+
+
+def test_the_anchor_of_a_fusion_and_what_else_was_fused_into_it():
+    scopes = op_scopes._scope_of_text(MODULE)
+    # the largest dot inside decides, whatever the root says; the other
+    # top-level parts are listed and the time is never split
+    assert scopes["fusion.1"] == (
+        "lm_head", "backward", "matmul", ("block", "optimizer")
+    )
+    # no dot inside and a root without metadata: the named instruction
+    # nearest to the root
+    assert scopes["fusion.2"] == ("block/attn/rope", "forward", "other", ())
+    # a compiled kernel is a kernel, XLA's own custom-call is not
+    assert scopes["flash_fwd.3"] == (
+        "block/attn/flash_fwd", "forward", "kernel", ()
+    )
+    assert scopes["concat.1"] == ("tok_embed", "forward", "other", ())
+    assert scopes["all-reduce.1"] == (
+        "block/mlp/mlp_up", "backward", "collective", ()
+    )
+    # XLA's own copy of an argument is what reads it; one inside a loop's
+    # body with neither is the loop's
+    assert scopes["copy.1"] == scopes["fusion.2"]
+    assert scopes["copy.7"] == ("block/moe/dispatch", "forward", "other", ())
+    assert scopes["while.1"][0] == "block/moe/dispatch"
+    assert scopes["orphan.1"] == (None, "forward", "other", ())
+    # parameters, tuples and reduction regions are on no op line
+    assert not {"state", "w", "tuple.9", "add.0", "dot.big", "p"} & set(scopes)
+
+
+def test_attribute_sums_by_scope_and_says_what_it_could_not_place():
+    scopes = op_scopes._scope_of_text(MODULE)
+    other = dict(scopes, **{"fusion.2": ("optimizer", "optimizer", "other", ())})
+    times = {
+        "fusion.1": 0.5, "fusion.2": 0.25, "flash_fwd.3": 1.0,
+        "orphan.1": 0.125, "fusion.99": 0.0625, "copy.1": 0.25,
+    }
+    found = op_scopes.attribute(times, [scopes])
+    assert found["scopes"] == {
+        ("lm_head", "backward", "matmul"): 0.5,
+        ("block/attn/rope", "forward", "other"): 0.5,
+        ("block/attn/flash_fwd", "forward", "kernel"): 1.0,
+    }
+    # held without a part, and held by no map
+    assert found["unattributed"] == 0.125 + 0.0625
+    assert found["fused_across"] == 0.5
+    assert [name for name, _ in found["unattributed_ops"]] == [
+        "orphan.1", "fusion.99"
+    ]
+    assert sum(found["scopes"].values()) + found["unattributed"] == sum(
+        times.values()
+    )
+    # two programs that ran in the window and disagree on an op
+    both = op_scopes.attribute(times, [scopes, other])
+    assert both["unattributed"] == found["unattributed"] + 0.25
+    text = op_scopes.table(found, 2.1875, depth=2, steps=2)
+    rows = [line.split() for line in text.splitlines()]
+    assert rows[1] == ["block/attn", "forward", "kernel", "500.000", "45.71"]
+    assert rows[-3][0] == "unattributed"
+    assert rows[-2] == ["total", "1093.750", "100.00"]
+
+
+def test_the_vocabulary_is_closed_and_every_scope_of_the_package_is_in_it():
+    assert len(op_scopes.VOCABULARY) < 20
+    assert len(set(op_scopes.VOCABULARY)) == len(op_scopes.VOCABULARY)
+    literal = re.compile(r"named_scope\(\s*([\"']?)(\w+)\1\s*\)")
+    constants = re.compile(r"^(_[A-Z_]+) = \"(\w+)\"$", re.M)
+    used = set()
+    for path in glob.glob(
+        os.path.join(ROOT, "elasticdl_tpu", "**", "*.py"), recursive=True
+    ):
+        with open(path) as f:
+            source = f.read()
+        named = dict(constants.findall(source))
+        for quoted, name in literal.findall(source):
+            used.add(name if quoted else named[name])
+    assert used and used <= set(op_scopes.VOCABULARY), used
+    # "model" is the map's name for the root; the rest are all in use
+    assert set(op_scopes.VOCABULARY) - used == {"model"}
+
+
+# ---- the real compiled step of a tiny model of each family ----------------------
+
+
+def _tiny(name):
+    with open(os.path.join(ROOT, "tests", "perf", "configs", name + ".json")) as f:
+        return json.load(f)["run"]["model_params"]
+
+
+class FirstStage(nn.Module):
+    """ResNet-50's stem and first stage at a tiny width."""
+
+    @nn.compact
+    def __call__(self, features, training: bool = False):
+        x = features["image"]
+        x = nn.Conv(8, (7, 7), strides=(2, 2), use_bias=False, name="conv1")(x)
+        x = resnet50_model._bn(training, "bn_conv1")(x)
+        x = nn.max_pool(nn.relu(x), (3, 3), strides=(2, 2), padding="SAME")
+        x = resnet50_model.ConvBlock(
+            3, (8, 8, 32), strides=(1, 1), name="conv_block_2"
+        )(x, training)
+        x = resnet50_model.IdentityBlock(
+            3, (8, 8, 32), name="identity_block_2_1"
+        )(x, training)
+        return nn.Dense(10, name="fc")(jnp.mean(x, axis=(1, 2)))
+
+
+def _class_loss(labels, outputs):
+    return optax.softmax_cross_entropy_with_integer_labels(
+        outputs, labels
+    ).mean()
+
+
+def _lm_family(config, **more):
+    params = dict(_tiny(config), **more)
+    features = {"tokens": np.zeros((2, 64), np.int32)}
+    return (
+        lm.custom_model(**params), lm.loss, lm.optimizer(), features,
+        np.zeros((2, 64), np.int32), bool(params.get("remat_layers")),
+    )
+
+
+FAMILIES = {
+    "gpt2_block": lambda: _lm_family("tiny_lm"),
+    "gpt2_block_remat": lambda: _lm_family("tiny_lm", remat_layers=True),
+    "olmoe": lambda: _lm_family("tiny_olmoe"),
+    "mamba_experts_attention": lambda: _lm_family("tiny_nemotron"),
+    "latent_attention_mtp": lambda: _lm_family("tiny_joyai"),
+    "resnet_first_stage": lambda: (
+        FirstStage(), _class_loss, optax.sgd(0.1),
+        {"image": np.zeros((2, 32, 32, 3), np.float32)},
+        np.zeros((2,), np.int32), False,
+    ),
+}
+
+
+def _lowered(family):
+    model, loss, tx, features, labels, _ = FAMILIES[family]()
+    variables = model.init(jax.random.PRNGKey(0), features, training=False)
+    state = TrainState.create(
+        model.apply, variables["params"], tx,
+        {k: v for k, v in variables.items() if k != "params"},
+    )
+    step = build_train_step(loss, donate=False)
+    weights = np.ones((labels.shape[0],), np.float32)
+    return state, step.lower(state, features, labels, weights)
+
+
+@pytest.fixture(scope="module", params=sorted(FAMILIES))
+def built(request):
+    state, lowered = _lowered(request.param)
+    compiled = lowered.compile()
+    return request.param, state, compiled
+
+
+def _modules(tree, found=None):
+    """Every flax module of a parameter tree, by its canonical name."""
+    found = set() if found is None else found
+    for name, value in tree.items():
+        if isinstance(value, dict):
+            part, _ = op_scopes.canonical(f"{name}/op")
+            found.update(part.split("/"))
+            _modules(value, found)
+    return found
+
+
+def _owners(tree, found=None):
+    """The modules that own parameters themselves."""
+    found = set() if found is None else found
+    for name, value in tree.items():
+        if isinstance(value, dict):
+            if any(not isinstance(v, dict) for v in value.values()):
+                found.add(op_scopes.canonical(f"{name}/op")[0].split("/")[-1])
+            _owners(value, found)
+    return found
+
+
+def test_every_region_of_the_step_has_a_name(built):
+    family, state, compiled = built
+    scopes = op_scopes.scope_map(compiled)
+    assert op_scopes.scope_map(compiled) is scopes  # built once a program
+    parts = {part for part, _, _, _ in scopes.values() if part is not None}
+    modules = _modules(state.params)
+    known = modules | set(op_scopes.VOCABULARY) | KERNELS
+    for part in parts:
+        elements = part.split("/")
+        assert set(elements) <= known, part
+        # directly under a mixer nothing is bare: a module with parameters
+        # of its own, a kernel, or a name of the vocabulary
+        assert elements[-1] not in MIXERS, part
+        if set(elements) & MIXERS:
+            assert elements[-1] in (
+                _owners(state.params) | set(op_scopes.VOCABULARY) | KERNELS
+            ), part
+    # the coverage of the compiled step
+    held = [part for part, _, _, _ in scopes.values()]
+    assert sum(p is not None for p in held) >= 0.98 * len(held)
+    phases = {phase for _, phase, _, _ in scopes.values()}
+    remat = FAMILIES[family]()[-1]
+    assert ("recompute" in phases) is remat
+    assert {"forward", "backward", "optimizer"} <= phases
+    assert "optimizer" in parts and "loss" in parts
+    assert {
+        phase for part, phase, _, _ in scopes.values() if part == "optimizer"
+    } == {"optimizer"}
+    if family == "latent_attention_mtp":
+        assert {"mtp/block/attn/join", "mtp/block/attn/rope"} <= parts
+        assert {"block/moe/route/router", "block/moe/shared/shared_up"} <= parts
+    if family == "mamba_experts_attention":
+        assert {
+            "block/mamba/gate_norm", "block/mamba/ssd_scan/fold",
+            "block/mamba/mamba_conv", "block/moe/dispatch", "block/moe/combine",
+        } <= parts
+    if family == "olmoe":
+        assert {"block/attn/qk_norm/q_norm", "block/attn/rope"} <= parts
+    if family == "resnet_first_stage":
+        assert {"conv_block/conv_a", "identity_block/bn_c", "fc"} <= parts
+
+
+_METADATA = re.compile(r",? ?metadata=\{[^{}]*\}")
+_TABLES = re.compile(
+    r"\n(FileNames|FunctionNames|FileLocations|StackFrames)\n(?:[^\n]+\n)*"
+)
+
+
+def _stripped(text):
+    return _METADATA.sub("", _TABLES.sub("\n", text))
+
+
+def test_a_scope_changes_metadata_and_nothing_else(built, monkeypatch):
+    family, _, compiled = built
+    text = compiled.as_text()
+    assert "op_name=" in text
+    # every scope gone, flax's too: the program compiles to the same text
+    monkeypatch.setattr(
+        jax, "named_scope", lambda name: contextlib.nullcontext()
+    )
+    _, lowered = _lowered(family)
+    bare = lowered.compile().as_text()
+    assert "/optimizer/" not in bare and "/loss/" not in bare
+    assert _stripped(bare) == _stripped(text)
+
+
+def test_the_map_survives_the_program_stores_serialisation(built):
+    from jax.experimental import serialize_executable
+
+    _, _, compiled = built
+    payload, in_tree, out_tree = serialize_executable.serialize(compiled)
+    loaded = serialize_executable.deserialize_and_load(
+        payload, in_tree, out_tree
+    )
+    assert loaded is not compiled
+    assert op_scopes.scope_map(loaded) == op_scopes.scope_map(compiled)
+
+
+# ---- the trainer hands out the programs it dispatched ----------------------------
+
+
+class Tiny(nn.Module):
+    @nn.compact
+    def __call__(self, features, training=False):
+        return nn.Dense(4, name="head")(features["x"])
+
+
+def _squares(labels, outputs):
+    return jnp.mean((outputs - labels) ** 2)
+
+
+def _trainer(**kwargs):
+    features = {"x": np.ones((8, 3), np.float32)}
+    trainer = SPMDTrainer(
+        MeshConfig.from_string("dp=2").create(), Tiny(), _squares,
+        optax.sgd(0.1), features, **kwargs,
+    )
+    batch = (
+        trainer.place_batch(features),
+        trainer.place_batch(np.ones((8, 4), np.float32)),
+        trainer.place_mask(8, 8),
+    )
+    return trainer, batch
+
+
+def test_nothing_is_read_before_a_step_and_nothing_compiles_for_a_read():
+    compile_tracker.install()
+    trainer, batch = _trainer()
+    assert trainer.train_programs() == [] and op_scopes.read() is None
+    trainer.train_step(*batch)
+    trainer.train_step(*batch)
+    before = compile_tracker.compile_count()
+    programs = trainer.train_programs()
+    maps = op_scopes.read()
+    assert compile_tracker.compile_count() == before  # jit's own executable
+    assert len(programs) == len(maps) == 1
+    parts = {part for part, _, _, _ in maps[0].values()}
+    assert {"head", "loss", "optimizer"} <= parts
+    assert op_scopes.read()[0] is maps[0]
+    # the stacked step is a program of its own, and a train program too
+    stacked = jax.tree_util.tree_map(
+        lambda x: np.stack([np.asarray(x)] * 2), batch
+    )
+    trainer.train_steps_stacked(*map(trainer.place_stacked, stacked))
+    assert len(op_scopes.read()) == 2
+    # the newest trainer is the one watched
+    other, _ = _trainer()
+    assert op_scopes.read() is None and other.train_programs() == []
+
+
+def test_a_trainer_on_the_program_store_hands_out_the_stores_programs(
+    tmp_path, monkeypatch
+):
+    import sys
+
+    from elasticdl_tpu.parallel import program_store
+    from elasticdl_tpu.utils.args import parse_master_args
+
+    compile_tracker.install()
+    store = program_store.ProgramStore(str(tmp_path / "program_store"))
+    monkeypatch.setattr(program_store, "_active", store)
+    args = parse_master_args(
+        ["--model_def", "tests.tiny", "--training_data", "/nowhere"]
+    )
+    trainer, batch = _trainer(
+        job_identity=program_store.job_identity(args, sys.modules[__name__])
+    )
+    trainer.train_step(*batch)
+    (program,) = trainer.train_programs()
+    assert program is trainer._stored("train_step", None, batch)
+    (scopes,) = op_scopes.read()
+    assert "optimizer" in {part for part, _, _, _ in scopes.values()}
+
+
+# ---- a profile window, and the command that reads it --------------------------------
+
+
+def test_a_window_leaves_the_op_scopes_beside_its_trace(tmp_path, monkeypatch):
+    from elasticdl_tpu.utils.profiling import StepProfiler
+
+    trainer, batch = _trainer()
+    monkeypatch.setattr(jax.profiler, "start_trace", lambda d, **_options: None)
+    monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
+    profiler = StepProfiler(str(tmp_path), start_step=1, num_steps=2)
+    for _ in range(5):
+        trainer.train_step(*batch)
+        profiler.on_step()
+    profiler.stop()
+    written = op_scopes.load(str(tmp_path / op_scopes.OP_SCOPES_FILE))
+    assert written == op_scopes.read()
+    assert (tmp_path / "host_spans.json").exists()
+
+
+class _Event:
+    def __init__(self, name, start_ns, duration_ns):
+        self.name, self.start_ns, self.duration_ns = name, start_ns, duration_ns
+
+
+class _Line:
+    def __init__(self, name, events):
+        self.name, self.events = name, events
+
+
+class _Plane:
+    def __init__(self, name, lines):
+        self.name, self.lines = name, lines
+
+
+def test_the_command_prints_a_windows_busy_time_by_scope(
+    tmp_path, monkeypatch, capsys
+):
+    import jax.profiler
+
+    ops = _Line("XLA Ops", [
+        _Event("%while.1 = (s32[]) while(...)", 0, 1000),
+        _Event("%neg.1 = f32[8,64] negate(...)", 100, 300),
+        _Event("%fusion.1 = f32[64,512] fusion(...)", 2000, 500),
+        _Event("%fusion.99 = f32[] fusion(...)", 3000, 200),
+    ])
+    planes = [
+        _Plane("/device:TPU:0", [ops, _Line("Steps", [_Event("0", 0, 5000)])]),
+        _Plane("/host:CPU", [_Line("XLA Ops", [_Event("%x = y", 0, 9)])]),
+    ]
+
+    class _Data:
+        @staticmethod
+        def from_file(path):
+            return type("D", (), {"planes": planes})
+
+    monkeypatch.setattr(jax.profiler, "ProfileData", _Data)
+    window = tmp_path / "plugins" / "profile" / "2026_01_01"
+    window.mkdir(parents=True)
+    (window / "host.xplane.pb").write_bytes(b"")
+    assert op_scopes.main([str(tmp_path)]) == 1  # no op_scopes.json yet
+    with open(window / op_scopes.OP_SCOPES_FILE, "w") as f:
+        json.dump({"programs": [op_scopes._scope_of_text(MODULE)]}, f)
+    op_self, busy_s = op_scopes.window_self_times(str(window / "host.xplane.pb"))
+    # the loop keeps what its body's ops do not take
+    assert op_self == {
+        "while.1": 700e-9, "neg.1": 300e-9, "fusion.1": 500e-9,
+        "fusion.99": 200e-9,
+    }
+    assert busy_s == 1700e-9
+    assert op_scopes.main([str(tmp_path), "--depth", "2"]) == 0
+    out = capsys.readouterr().out
+    rows = [line.split() for line in out.splitlines()]
+    assert ["block/moe", "forward", "other"] == rows[1][:3]
+    assert rows[1][3] == "0.001" and rows[1][4] == "58.82"
+    assert ["lm_head", "backward", "matmul"] == rows[2][:3]
+    assert rows[3][0] == "unattributed"
+    # the table's total is the window's busy time
+    assert rows[4][0] == "total" and rows[4][-1] == "100.00"
+    assert op_scopes.main([str(tmp_path / "nowhere")]) == 1
