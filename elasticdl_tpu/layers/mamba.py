@@ -1,7 +1,8 @@
 """The Mamba-2 mixer (Dao & Gu, arXiv:2405.21060) as NVIDIA's ``nemotron_h``
 stack runs it (HF ``modeling_nemotron_h.py``, ``NemotronHMamba2Mixer``)::
 
-    [z | xBC | dt] = W_in u                 widths d_in | d_in + 2 G N | H
+    [z | xBC | dt] = W_in u                 widths d_in | d_in + 2 G N | H,
+                                            a product a part of W_in's columns
     xBC = silu(causal depthwise conv_k(xBC) + b)
     x, B, C = split(xBC)                    x: H heads of P; B, C: G groups of N
     dt = softplus(dt + dt_bias)             A = -exp(A_log), a scalar a head
@@ -12,14 +13,18 @@ stack runs it (HF ``modeling_nemotron_h.py``, ``NemotronHMamba2Mixer``)::
 
 ``d_in = H * P`` is given by the heads, not by an expansion factor.  No bias
 but the convolution's.  The recurrence is ``ops/ssd.py``'s chunked scan; the
-convolution is ``k`` shifted multiply-adds that XLA fuses into one pass.
-``dt``, ``A`` and the norm's statistics are float32 whatever ``dtype`` says.
+convolution with its SiLU and the gated norm are one pass each of
+``ops/mamba_passes.py``'s kernels where those tile the shape (whole lane
+tiles of channels a group, rows that 16 divides), else the ``jax.numpy`` forms
+below, which the kernels are tested against.  ``dt``, ``A``, the taps' sums
+and the norm's statistics are float32 whatever ``dtype`` says.
 
 No reference counterpart; listed in DEVIATIONS.md additions.
 """
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any
 
@@ -27,6 +32,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from elasticdl_tpu.ops import mamba_passes
 from elasticdl_tpu.ops import ssd as ssd_ops
 
 
@@ -76,6 +82,53 @@ def gated_group_norm(y, z, scale, groups: int, eps: float):
     return (parts.reshape(gated.shape) * scale.astype(jnp.float32)).astype(y.dtype)
 
 
+def conv_silu(x, kernel, bias):
+    """``silu(causal_conv(x, kernel, bias))``: one pass of
+    ``ops/mamba_passes.py``'s kernel where it tiles the shape, else the plain
+    form."""
+    taps, channels = kernel.shape
+    if mamba_passes.conv_tile(x.shape[1], channels, taps):
+        return ssd_ops.over_batch(mamba_passes.conv_silu, (x,), (kernel, bias))
+    return nn.silu(causal_conv(x, kernel, bias))
+
+
+def gate_norm(y, z, scale, groups: int, eps: float):
+    """:func:`gated_group_norm`: one pass of ``ops/mamba_passes.py``'s kernel
+    where it tiles the shape, else the plain form."""
+    if mamba_passes.gate_norm_tile(y.shape[0] * y.shape[1], y.shape[2], groups):
+        return ssd_ops.over_batch(
+            functools.partial(mamba_passes.gate_norm, groups=groups, eps=eps),
+            (y, z), (scale,),
+        )
+    return gated_group_norm(y, z, scale, groups, eps)
+
+
+class SplitDense(nn.Module):
+    """``nn.Dense`` without a bias whose one kernel is applied in ranges of
+    its columns, one matrix product and one output each: the parts come out
+    as arrays of their own, where a split of one wide product is a copy a
+    part (or, read in place by a kernel, a window whose rows are 80.5 lane
+    tiles apart, which the chip's DMA reads at a third of its speed)."""
+
+    widths: tuple
+    dtype: Any = None
+
+    @nn.compact
+    def __call__(self, inputs):
+        kernel = self.param(
+            "kernel", nn.initializers.lecun_normal(),
+            (inputs.shape[-1], sum(self.widths)),
+        )
+        inputs, kernel = nn.dtypes.promote_dtype(
+            inputs, kernel, dtype=self.dtype
+        )
+        parts, first = [], 0
+        for width in self.widths:
+            parts.append(inputs @ kernel[:, first:first + width])
+            first += width
+        return parts
+
+
 class Mamba2Mixer(nn.Module):
     num_heads: int
     head_dim: int
@@ -95,25 +148,20 @@ class Mamba2Mixer(nn.Module):
         heads, groups, states = self.num_heads, self.groups, self.state_size
         inner = heads * self.head_dim
         conv_width = inner + 2 * groups * states
-        projected = nn.Dense(
-            inner + conv_width + heads, use_bias=False, dtype=self.dtype,
-            name="in_proj",
+        z, xbc, dt = SplitDense(
+            (inner, conv_width, heads), dtype=self.dtype, name="in_proj"
         )(u)
         # the regions between the modules and the kernel, by
         # telemetry/op_scopes.py's names
-        with jax.named_scope("fold"):
-            z, xbc, dt = jnp.split(
-                projected, [inner, inner + conv_width], axis=-1
-            )
         with jax.named_scope("mamba_conv"):
-            xbc = nn.silu(causal_conv(
+            xbc = conv_silu(
                 xbc,
                 self.param(
                     "conv_kernel", nn.initializers.lecun_normal(),
                     (self.conv_kernel, conv_width),
                 ),
                 self.param("conv_bias", nn.initializers.zeros, (conv_width,)),
-            ))
+            )
         with jax.named_scope("fold"):
             x, b, c = jnp.split(xbc, [inner, inner + groups * states], axis=-1)
         dt_bias = self.param(
@@ -133,7 +181,7 @@ class Mamba2Mixer(nn.Module):
                 d, chunk=self.chunk,
             )
         with jax.named_scope("gate_norm"):
-            y = gated_group_norm(
+            y = gate_norm(
                 y.reshape(batch, steps, inner), z,
                 self.param("norm_scale", nn.initializers.ones, (inner,)),
                 groups, self.norm_eps,

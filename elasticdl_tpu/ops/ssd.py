@@ -33,7 +33,8 @@ backward kernel forms from its float32 products before anything is rounded
 (a difference of two sums that nearly cancel where the decay is strong),
 plus, at a chunk's last step, ``<H', dH'>`` (a scalar a head and chunk).
 ``dt``, ``A``, ``D`` and the running sums are plain ``jax.numpy`` around the
-kernels, differentiated by JAX (docs/designs/ssd_scan.md).
+kernels, differentiated by JAX (docs/designs/ssd_scan.md); the products with
+``x`` are formed in the kernels' layout, where ``x`` is taken once.
 
 Each kernel has a name the device trace's op line shows, as the flash and
 grouped-matmul kernels do: ``perf/`` reads them by it.
@@ -279,6 +280,9 @@ def _sums_by_group(cum, groups, length):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
 def _ssd_core(xd, cum, b, c, chunk, interpret):
+    """``xd`` and the result as the kernels read and write them, (batch,
+    groups, heads a group, T, P); ``cum`` (batch, T, heads) float32; ``b``,
+    ``c`` (batch, T, groups, N)."""
     return _ssd_core_fwd(xd, cum, b, c, chunk, interpret)[0]
 
 
@@ -286,24 +290,21 @@ def _ssd_core_fwd(xd, cum, b, c, chunk, interpret):
     groups = b.shape[2]
     with jax.named_scope(_FOLD):
         operands = (
-            _by_group(xd, groups), b.transpose(0, 2, 1, 3),
-            c.transpose(0, 2, 1, 3), _sums_by_group(cum, groups, chunk),
+            xd, b.transpose(0, 2, 1, 3), c.transpose(0, 2, 1, 3),
+            _sums_by_group(cum, groups, chunk),
         )
     y, start = _forward(*operands, interpret)
-    with jax.named_scope(_FOLD):
-        return _by_step(y), (xd, cum, b, c, start)
+    return y, (xd, cum, b, c, start)
 
 
 def _ssd_core_bwd(chunk, interpret, residuals, dy):
     xd, cum, b, c, start = residuals
-    groups = b.shape[2]
-    batch, steps, heads, _ = xd.shape
+    batch, groups, per_group, steps, _ = xd.shape
+    heads = groups * per_group
     with jax.named_scope(_FOLD):
-        dy = dy.astype(xd.dtype)
         operands = (
-            _by_group(xd, groups), b.transpose(0, 2, 1, 3),
-            c.transpose(0, 2, 1, 3), _sums_by_group(cum, groups, chunk),
-            _by_group(dy, groups),
+            xd, b.transpose(0, 2, 1, 3), c.transpose(0, 2, 1, 3),
+            _sums_by_group(cum, groups, chunk), dy.astype(xd.dtype),
         )
     dxd, db, dc, dcum, ends = _backward(*operands, start, interpret)
     with jax.named_scope(_FOLD):
@@ -319,10 +320,7 @@ def _ssd_core_bwd(chunk, interpret, residuals, dy):
             [ends[:, 1:], jnp.zeros_like(ends[:, :1])], axis=1
         )
         dcum = dcum.at[:, :, -1, :].add(ends).reshape(batch, steps, heads)
-        return (
-            _by_step(dxd), dcum, db.transpose(0, 2, 1, 3),
-            dc.transpose(0, 2, 1, 3),
-        )
+        return dxd, dcum, db.transpose(0, 2, 1, 3), dc.transpose(0, 2, 1, 3)
 
 
 _ssd_core.defvjp(_ssd_core_fwd, _ssd_core_bwd)
@@ -358,36 +356,59 @@ def ssd_chunked(x, dt, a, b, c, d, *, chunk: int, interpret: bool | None = None)
         decay.reshape(x.shape[0], -1, chunk, heads),
         precision=jax.lax.Precision.HIGHEST,
     ).reshape(decay.shape)
-    xd = (x.astype(jnp.float32) * dt[..., None]).astype(x.dtype)
+    # ``x`` goes to the kernels' layout once, in its own dtype, and ``dt x``,
+    # the skip and its sum with the kernels' output are formed there.  Formed
+    # in the layer's layout, the float32 ``x`` both products share is a 134
+    # MB array that XLA writes and then copies to the layout its transposes
+    # want (0.6 ms a layer and pass); the barrier keeps the conversion this
+    # side of the transpose
+    with jax.named_scope(_FOLD):
+        x = jax.lax.optimization_barrier(_by_group(x, groups))
+        dt = _by_group(dt[..., None], groups)
+        d = d.astype(jnp.float32).reshape(groups, heads // groups, 1, 1)
+    xd = (x.astype(jnp.float32) * dt).astype(x.dtype)
     y = _ssd_core(xd, cum, b, c, chunk, interpret)
-    # in x's dtype: a float32 sum here makes XLA transpose the kernels'
-    # whole output in float32
-    skip = d.astype(jnp.float32)[:, None] * x.astype(jnp.float32)
-    return (y + skip.astype(x.dtype))[:, :steps]
+    y = y + (d * x.astype(jnp.float32)).astype(x.dtype)
+    with jax.named_scope(_FOLD):
+        return _by_step(y)[:, :steps]
 
 
-def ssd_scan(x, dt, a, b, c, d, *, chunk: int):
-    """:func:`ssd_chunked` under the registered mesh (the one the attention
-    kernels read): a compiled Pallas kernel is an opaque custom call GSPMD
-    cannot partition, so on several devices it is mapped over the mesh's
-    data-parallel axes, a sequence whole on its device."""
+def over_batch(local, batched, shared):
+    """``local(*batched, *shared, interpret=...)`` under the registered mesh
+    (the one the attention kernels read): a compiled Pallas kernel is an
+    opaque custom call GSPMD cannot partition, so on several devices it is
+    mapped over the mesh's data-parallel axes, each of ``batched`` (and the
+    result, which is laid out like the first of them) split along its first
+    axis, a sequence whole on its device, each of ``shared`` whole on every
+    device."""
     from jax.sharding import PartitionSpec as P
 
     from elasticdl_tpu.ops.ring_attention import sequence_shard_spec
 
     mesh, _, _ = get_attention_mesh()
     if mesh is None:
-        return ssd_chunked(x, dt, a, b, c, d, chunk=chunk)
+        return local(
+            *batched, *shared,
+            interpret=kernel_interpret(jax.default_backend()),
+        )
     local = functools.partial(
-        ssd_chunked, chunk=chunk,
-        interpret=kernel_interpret(mesh.devices.flat[0].platform),
+        local, interpret=kernel_interpret(mesh.devices.flat[0].platform)
     )
     if mesh.devices.size == 1 or jax.sharding.get_abstract_mesh().manual_axes:
-        return local(x, dt, a, b, c, d)
-    rows = sequence_shard_spec(mesh, None, x.shape[0], 1)[0]
-    per_step, per_head = P(rows, None, None, None), P(None)
+        return local(*batched, *shared)
+    rows = sequence_shard_spec(mesh, None, batched[0].shape[0], 1)[0]
+    by_row = [P(rows, *[None] * (v.ndim - 1)) for v in batched]
     return jax.shard_map(
-        local, mesh=mesh,
-        in_specs=(per_step, P(rows, None, None), per_head, per_step, per_step, per_head),
-        out_specs=per_step, check_vma=False,
-    )(x, dt, a, b, c, d)
+        local, mesh=mesh, in_specs=(*by_row, *[P(None)] * len(shared)),
+        out_specs=by_row[0], check_vma=False,
+    )(*batched, *shared)
+
+
+def ssd_scan(x, dt, a, b, c, d, *, chunk: int):
+    """:func:`ssd_chunked` under the registered mesh (:func:`over_batch`)."""
+    return over_batch(
+        lambda x, dt, b, c, a, d, interpret: ssd_chunked(
+            x, dt, a, b, c, d, chunk=chunk, interpret=interpret
+        ),
+        (x, dt, b, c), (a, d),
+    )

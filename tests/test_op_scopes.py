@@ -23,6 +23,7 @@ from elasticdl_tpu.models import long_seq_transformer as lm
 from elasticdl_tpu.models import resnet50_model
 from elasticdl_tpu.ops import attention as attention_ops
 from elasticdl_tpu.ops import grouped_matmul as gmm_ops
+from elasticdl_tpu.ops import mamba_passes
 from elasticdl_tpu.ops import ssd as ssd_ops
 from elasticdl_tpu.parallel.distributed import SPMDTrainer
 from elasticdl_tpu.parallel.mesh import MeshConfig
@@ -35,6 +36,8 @@ KERNELS = {
     attention_ops.FLASH_FWD, attention_ops.FLASH_DQ, attention_ops.FLASH_DKV,
     gmm_ops.GMM_FWD, gmm_ops.GMM_DX, gmm_ops.GMM_DW,
     ssd_ops.SSD_FWD, ssd_ops.SSD_BWD,
+    mamba_passes.GATE_NORM_FWD, mamba_passes.GATE_NORM_BWD,
+    mamba_passes.MAMBA_CONV_FWD, mamba_passes.MAMBA_CONV_BWD,
 }
 # modules that hold other modules: an op directly under one of these is in
 # a region nobody named
@@ -387,10 +390,12 @@ def test_every_region_of_the_step_has_a_name(built):
         assert {"mtp/block/attn/join", "mtp/block/attn/rope"} <= parts
         assert {"block/moe/route/router", "block/moe/shared/shared_up"} <= parts
     if family == "mamba_experts_attention":
+        # (the convolution, 128 channels wide here, is ops/mamba_passes.py's
+        # kernel, a part of its own under the scope)
         assert {
             "block/mamba/gate_norm", "block/mamba/ssd_scan/fold",
             "block/mamba/mamba_conv", "block/moe/dispatch", "block/moe/combine",
-        } <= parts
+        } <= parts | {op_scopes.at_depth(part, 3) for part in parts}
     if family == "olmoe":
         assert {"block/attn/qk_norm/q_norm", "block/attn/rope"} <= parts
     if family == "resnet_first_stage":
@@ -431,6 +436,95 @@ def test_the_map_survives_the_program_stores_serialisation(built):
     )
     assert loaded is not compiled
     assert op_scopes.scope_map(loaded) == op_scopes.scope_map(compiled)
+
+
+# ---- the step of a mixer the kernels tile, compiled for the chip ---------------
+
+
+@pytest.fixture(scope="module")
+def one_chip_mesh():
+    """A mesh over one described ``v5e`` chip: its compiler is installed
+    here, so Mosaic's kernels compile to the custom-calls the chip runs."""
+    from jax.experimental import topologies
+
+    try:
+        topology = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return MeshConfig.from_string("dp=1").create(devices=topology.devices[:1])
+
+
+def test_the_mixers_passes_are_kernels_under_their_own_parts(one_chip_mesh):
+    """A Mamba-2 layer wide enough for ``ops/mamba_passes.py`` (8 heads of
+    64 in 2 groups of 256 lanes, a 768-wide convolution, 64 steps), each
+    layer recomputed: the four custom-calls keep their names, sit under the
+    parts ``gate_norm`` and ``mamba_conv`` as kind ``kernel`` in all three
+    phases, and none reads as a scan, flash or grouped-matmul kernel to
+    ``perf/``'s readers, which match by name."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from perf import expert_rooflines, layer_readers, ssd_rooflines, trace_reduce
+
+    model, loss, tx, features, labels, _ = _lm_family(
+        "tiny_nemotron", num_layers=1, layer_pattern="M", mamba_heads=8,
+        mamba_head_dim=64, ssm_state=64, ssd_chunk=16,
+    )
+    variables = model.init(jax.random.PRNGKey(0), features, training=False)
+    state = TrainState.create(model.apply, variables["params"], tx, {})
+    whole = NamedSharding(one_chip_mesh, PartitionSpec())
+    described = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(np.shape(x), x.dtype, sharding=whole),
+        (state, features, labels, np.ones((labels.shape[0],), np.float32)),
+    )
+    step = build_train_step(loss, donate=False)
+    with one_chip_mesh, attention_ops.attention_mesh_scope(one_chip_mesh):
+        compiled = step.lower(*described).compile()
+    scopes = op_scopes.scope_map(compiled)
+    kernels = {
+        name: (part, phase) for name, (part, phase, kind, _) in scopes.items()
+        if kind == "kernel"
+    }
+    found = {}
+    for name, (part, phase) in kernels.items():
+        found.setdefault(name.split(".")[0], set()).add((part, phase))
+    assert found[mamba_passes.GATE_NORM_FWD] == {
+        ("block/mamba/gate_norm/gate_norm_fwd", "forward"),
+        ("block/mamba/gate_norm/gate_norm_fwd", "recompute"),
+    }
+    assert found[mamba_passes.GATE_NORM_BWD] == {
+        ("block/mamba/gate_norm/gate_norm_bwd", "backward")
+    }
+    assert found[mamba_passes.MAMBA_CONV_FWD] == {
+        ("block/mamba/mamba_conv/mamba_conv_fwd", "forward"),
+        ("block/mamba/mamba_conv/mamba_conv_fwd", "recompute"),
+    }
+    assert found[mamba_passes.MAMBA_CONV_BWD] == {
+        ("block/mamba/mamba_conv/mamba_conv_bwd", "backward")
+    }
+    assert {ssd_ops.SSD_FWD, ssd_ops.SSD_BWD} <= set(found)
+    assert {
+        op_scopes.at_depth(part, 3) for part, _ in kernels.values()
+    } >= {"block/mamba/gate_norm", "block/mamba/mamba_conv"}
+    # as perf/ reads a trace: an op's self time by its name
+    ours = {
+        name: 1.0 for name in kernels
+        if name.split(".")[0] in (
+            mamba_passes.GATE_NORM_FWD, mamba_passes.GATE_NORM_BWD,
+            mamba_passes.MAMBA_CONV_FWD, mamba_passes.MAMBA_CONV_BWD,
+        )
+    }
+    assert len(ours) == 6
+    reduced = {"op_self_s": ours, "details": {}}
+    patterns = [layer_readers.FLASH_KERNELS] + [
+        rf"^{kernel}\b" for kernel in (
+            expert_rooflines.EXPERT_KERNELS, *ssd_rooflines.KERNEL_SHARE_OF_SSD,
+            "ssd_", "flash_", "expert_gmm_",
+        )
+    ]
+    for pattern in patterns:
+        assert trace_reduce.matching_seconds(reduced, pattern) == 0, pattern
 
 
 # ---- the trainer hands out the programs it dispatched ----------------------------
