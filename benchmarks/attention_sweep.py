@@ -4,7 +4,8 @@ A builder's tool for tuning a kernel: its times are a kernel's alone, never
 a ledger number (``perf/run.py`` is the benchmark; nothing under ``perf/``
 imports this).  It runs
 ``flash_attention`` forward + gradients at ``--shape B,S,H,D`` (``D`` as
-``192:128`` for scores of 192 beside values of 128: latent attention) in
+``192:128`` for scores of 192 beside values of 128: latent attention; ``H``
+as ``32/4`` for 32 query heads over 4 key/value heads) in
 ``--dtype`` under the profiler and reads each kernel's device time from the
 trace by the name its ``pallas_call`` carries (``flash_fwd``, ``flash_dq``,
 ``flash_dkv``), once per geometry in ``--sweep``.  A geometry is
@@ -32,6 +33,15 @@ a call for each kernel and each kernel's share of the bf16 peak, counted as
 ``perf/kernel_rooflines.py`` counts it (a third of the analytic causal
 attention FLOPs a kernel; the recomputed scores are not counted).  A
 geometry Mosaic refuses is reported with its error, not skipped in silence.
+``--topk N`` times the SELECTED-SET kernels alone (``dsa_fwd``, ``dsa_dq``,
+``dsa_dkv``: the same three over a mask, ``selected_flash_attention``) at
+that shape, over the set an indexer of 16 heads of 64 with seeded weights
+selects (``ops/sparse_attention.py::index_select``, outside the timed
+calls), and counts the selected pairs' FLOPs, as ``perf/dsa_rooflines.py``
+does:
+
+    python benchmarks/attention_sweep.py --shape 1,16384,32/4,128 --topk 2048
+
 Exits 3 where JAX finds no TPU: a time from the CPU is not a kernel time.
 """
 
@@ -52,12 +62,13 @@ sys.path.insert(
 )
 
 KERNELS = ("flash_fwd", "flash_dq", "flash_dkv")
+SELECTED_KERNELS = ("dsa_fwd", "dsa_dq", "dsa_dkv")
 
 
 OTHER = "other_ops"
 
 
-def kernel_ms(trace_dir: str, calls: int) -> dict:
+def kernel_ms(trace_dir: str, calls: int, kernels=KERNELS) -> dict:
     """Device milliseconds a call of each flash kernel, from the op line
     of the first device plane of the trace under ``trace_dir``, and of
     every other op of the program together (``other_ops``: the copies that
@@ -68,7 +79,7 @@ def kernel_ms(trace_dir: str, calls: int) -> dict:
     (path,) = glob.glob(
         os.path.join(trace_dir, "plugins", "profile", "*", "*.xplane.pb")
     )
-    total = dict.fromkeys(KERNELS + (OTHER,), 0)
+    total = dict.fromkeys(kernels + (OTHER,), 0)
     for plane in ProfileData.from_file(path).planes:
         if not re.match(r"^/device:TPU:\d+$", plane.name):
             continue
@@ -79,7 +90,7 @@ def kernel_ms(trace_dir: str, calls: int) -> dict:
                 # "%transpose_jvp_flash_dkv__.1 = ...": under a bare jit
                 # the op's name wraps the kernel's in its transformations
                 name = event.name.partition(" = ")[0]
-                kernel = next((k for k in KERNELS if k in name), OTHER)
+                kernel = next((k for k in kernels if k in name), OTHER)
                 total[kernel] += int(event.duration_ns)
         break
     return {k: ns / 1e6 / calls for k, ns in total.items()}
@@ -105,16 +116,35 @@ def flash_layout_of(module, q, k, v) -> str:
     return layout(q, k, v) if layout else "folded"
 
 
-def time_geometry(module, geometry, shape, dtype, causal, calls):
+def time_geometry(
+    module, geometry, shape, dtype, causal, calls, kv_heads=0, topk=0
+):
     import jax
     import jax.numpy as jnp
 
-    keys = jax.random.split(jax.random.PRNGKey(0), 4)
+    keys = jax.random.split(jax.random.PRNGKey(0), 7)
     d_qk, d_v = shape[3:]
+    batch, seq, heads = shape[:3]
     q, k, v, w = (
-        jax.random.normal(key, shape[:3] + (d,), jnp.float32).astype(dtype)
-        for key, d in zip(keys, (d_qk, d_qk, d_v, d_v))
+        jax.random.normal(
+            key, (batch, seq, h, d), jnp.float32
+        ).astype(dtype)
+        for key, h, d in zip(
+            keys, (heads, kv_heads or heads, kv_heads or heads, heads),
+            (d_qk, d_qk, d_v, d_v),
+        )
     )
+    masks = ()
+    if topk:
+        from elasticdl_tpu.ops import sparse_attention as sparse_ops
+
+        qi = jax.random.normal(keys[4], (batch, seq, 16, 64)).astype(dtype)
+        ki = jax.random.normal(keys[5], (batch, seq, 64)).astype(dtype)
+        wi = jax.random.normal(keys[6], (batch, seq, 16)) / 32
+        mask = jax.jit(
+            lambda qi, ki, wi: sparse_ops.index_select(qi, ki, wi, topk)[0]
+        )(qi, ki, wi)
+        masks = (mask, sparse_ops.transpose_mask(mask))
     kw = {}
     chunk_name = (
         "_CHUNK_BYTES" if hasattr(module, "_CHUNK_BYTES") else "_SEQ_CHUNK"
@@ -132,7 +162,10 @@ def time_geometry(module, geometry, shape, dtype, causal, calls):
         jax.clear_caches()
 
     def loss(q, k, v):
-        out = module.flash_attention(q, k, v, causal=causal, **kw)
+        if topk:  # the module's own blocks: the mask's
+            out, _ = module.selected_flash_attention(q, k, v, *masks)
+        else:
+            out = module.flash_attention(q, k, v, causal=causal, **kw)
         return jnp.sum(out.astype(jnp.float32) * w.astype(jnp.float32))
 
     step = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
@@ -143,7 +176,10 @@ def time_geometry(module, geometry, shape, dtype, causal, calls):
             for _ in range(calls):
                 out = step(q, k, v)
             jax.block_until_ready(out)
-        return kernel_ms(trace_dir, calls), flash_layout_of(module, q, k, v)
+        return (
+            kernel_ms(trace_dir, calls, SELECTED_KERNELS if topk else KERNELS),
+            flash_layout_of(module, q, k, v),
+        )
     finally:
         shutil.rmtree(trace_dir, ignore_errors=True)
         setattr(module, chunk_name, module_chunk)
@@ -152,7 +188,12 @@ def time_geometry(module, geometry, shape, dtype, causal, calls):
 def main() -> int:
     parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     parser.add_argument(
-        "--shape", default="1,8192,12,64", help="B,S,H,D or B,S,H,Dqk:Dv"
+        "--shape", default="1,8192,12,64",
+        help="B,S,H,D; D as Dqk:Dv, H as H/KV for grouped heads",
+    )
+    parser.add_argument(
+        "--topk", type=int, default=0,
+        help="> 0: the selected-set kernels over a seeded indexer's set",
     )
     parser.add_argument("--dtype", default="bfloat16")
     parser.add_argument(
@@ -175,10 +216,11 @@ def main() -> int:
     from perf.peaks import peaks_for  # the benchmark's one table of peaks
 
     peak = peaks_for(device.device_kind)["bf16_flops_per_s"]
-    *sizes, widths = args.shape.split(",")
+    batch, seq, grouped, widths = args.shape.split(",")
     d_qk, d_v = (int(x) for x in (widths.split(":") * 2)[:2])
-    shape = tuple(int(x) for x in sizes) + (d_qk, d_v)
-    batch, seq, heads = shape[:3]
+    heads, kv_heads = (int(x) for x in (grouped.split("/") * 2)[:2])
+    batch, seq = int(batch), int(seq)
+    shape = (batch, seq, heads, d_qk, d_v)
     causal = not args.non_causal
     # forward + backward: six matmuls of 2*S*S*D a head (three over the
     # scores' width, three over the values'), half of them under the
@@ -186,6 +228,11 @@ def main() -> int:
     kernel_flops = 6 * batch * heads * seq * seq * (d_qk + d_v) / 3
     if causal:
         kernel_flops /= 2
+    if args.topk:  # the selected pairs, sum_t min(t + 1, topk), of seq^2
+        kept = min(args.topk, seq)
+        pairs = kept * (kept + 1) // 2 + (seq - kept) * kept
+        kernel_flops = 2 * batch * heads * pairs * (d_qk + d_v)
+    kernels = SELECTED_KERNELS if args.topk else KERNELS
     module = load_impl(args.impl)
     geometries = [
         tuple(int(x) for x in g.split(","))
@@ -196,6 +243,8 @@ def main() -> int:
         line = {
             "impl": args.label or args.impl or "elasticdl_tpu.ops.attention",
             "shape": list(shape),
+            "kv_heads": kv_heads,
+            "topk": args.topk,
             "dtype": args.dtype,
             "causal": causal,
             "geometry": geometry,
@@ -204,16 +253,16 @@ def main() -> int:
         try:
             ms, line["flash_layout"] = time_geometry(
                 module, geometry, shape, jnp.dtype(args.dtype), causal,
-                args.calls,
+                args.calls, kv_heads, args.topk,
             )
         except Exception as ex:  # noqa: BLE001 — Mosaic refuses a geometry
             line["error"] = f"{type(ex).__name__}: {str(ex)[:300]}"
         else:
             line["ms"] = {k: round(v, 4) for k, v in ms.items()}
-            line["ms_total"] = round(sum(ms[k] for k in KERNELS), 4)
+            line["ms_total"] = round(sum(ms[k] for k in kernels), 4)
             line["roofline_pct"] = {
                 k: round(100 * kernel_flops / (ms[k] / 1e3) / peak, 2)
-                for k in KERNELS
+                for k in kernels
                 if ms[k]
             }
         print(json.dumps(line), flush=True)

@@ -122,6 +122,10 @@ SIZES = {
             # latent attention's two widths: scores of 192, values of 128
             (1, 8192, 32, 192, 128),
         ),
+        # sparse attention at its cell's shape (keye_vl2_seq16384): batch,
+        # tokens, heads, width, key/value heads, the indexer's heads and
+        # width, keys a query
+        sparse_shape=(1, 16384, 32, 128, 4, 16, 64, 2048),
     ),
     # the rehearsal: same control flow, CPU backend, interpreted kernels
     "tiny": dict(
@@ -145,6 +149,7 @@ SIZES = {
             (1, 256, 4, 128, 128, 2),
             (1, 256, 2, 48, 32),
         ),
+        sparse_shape=(1, 256, 4, 32, 2, 2, 16, 48),
     ),
 }
 
@@ -766,6 +771,9 @@ def _child_kernel(run, cfg, workdir):
         shapes[name] = errs
     if {"lanes", "folded"} - set(layouts.values()):
         failures.append(f"a way of addressing heads was not run: {layouts}")
+    shapes["sparse_" + "x".join(map(str, cfg["sparse_shape"]))] = (
+        _check_sparse_kernels(cfg["sparse_shape"], failures)
+    )
     from elasticdl_tpu.parallel.elastic import describe_devices
 
     report = _common_report(run, cfg, describe_devices(devices), failures)
@@ -776,6 +784,146 @@ def _child_kernel(run, cfg, workdir):
         scaled_max_abs_err=shapes,
     )
     return report
+
+
+def _check_sparse_kernels(shape, failures, rows=256) -> dict:
+    """The five sparse-attention kernels (``ops/sparse_attention.py``; the
+    flash kernels over a selected set) against the materialised form, whole
+    at ``shape`` with the float32 reference taken ``rows`` queries at a time
+    (a block holds (heads, rows, tokens) float32 scores): the selection
+    against ``lax.top_k`` pair by pair, then, over the KERNEL's own set,
+    the attention's output and three gradients and the indexer's loss and
+    its three gradients."""
+    import jax
+    import jax.numpy as jnp
+
+    from elasticdl_tpu.ops import sparse_attention as sparse_ops
+    from elasticdl_tpu.ops.attention import selected_flash_attention
+
+    batch, seq, heads, d, kv_heads, index_heads, index_width, topk = shape
+    rows = min(rows, seq)
+    keys = jax.random.split(jax.random.PRNGKey(sum(shape)), 7)
+    q, k, v, qi, ki = (
+        jax.random.normal(key, dims, jnp.float32).astype(jnp.bfloat16)
+        for key, dims in zip(
+            keys,
+            (
+                (batch, seq, heads, d), (batch, seq, kv_heads, d),
+                (batch, seq, kv_heads, d),
+                (batch, seq, index_heads, index_width),
+                (batch, seq, index_width),
+            ),
+        )
+    )
+    w = jax.random.normal(keys[5], (batch, seq, index_heads)) * (
+        index_heads * index_width
+    ) ** -0.5
+    weight = jax.random.normal(keys[6], (batch, seq, heads, d), jnp.float32)
+
+    @jax.jit
+    def kernels(q, k, v, qi, ki, w, weight):
+        mask, lse_i, kept, _ = sparse_ops.index_select(qi, ki, w, topk)
+        mask_t = sparse_ops.transpose_mask(mask)
+
+        def attend(q, k, v):
+            out, lse = selected_flash_attention(q, k, v, mask, mask_t)
+            return jnp.sum(out.astype(jnp.float32) * weight), (out, lse)
+
+        (_, (out, lse)), grads = jax.value_and_grad(
+            attend, argnums=(0, 1, 2), has_aux=True
+        )(q, k, v)
+        kl, kl_grads = jax.value_and_grad(
+            lambda qi, ki, w: sparse_ops.indexer_kl(
+                q, k, lse, mask, qi, ki, w, lse_i
+            ),
+            argnums=(0, 1, 2),
+        )(qi, ki, w)
+        return mask, kept, (out, *grads), (kl, *kl_grads)
+
+    mask, kept, got, got_kl = kernels(q, k, v, qi, ki, w, weight)
+    f32 = [x.astype(jnp.float32) for x in (q, k, v, qi, ki)]
+
+    @jax.jit
+    def block(start, chosen, q, k, v, qi, ki, w, weight):
+        """Rows ``[start, start + rows)``: the reference's own selection,
+        and over ``chosen`` (the kernel's rows of the mask) the attention and
+        the KL with every gradient (k, v and ki whole: summed outside)."""
+        def cut(x):
+            return jax.lax.dynamic_slice_in_dim(x, start, rows, axis=1)
+
+        with jax.default_matmul_precision("highest"):
+            scores = sparse_ops.index_scores_reference(cut(qi), ki, cut(w))
+            seen = (start + jnp.arange(rows))[:, None] >= jnp.arange(seq)
+            _, index = jax.lax.top_k(
+                jnp.where(seen, scores, -jnp.inf), min(topk, seq)
+            )
+            own = jax.vmap(jax.vmap(
+                lambda ix: jnp.zeros((seq,), bool).at[ix].set(True)
+            ))(index) & seen
+
+            def attend(qb, k, v):
+                out, probs = sparse_ops.selected_reference(qb, k, v, chosen)
+                return jnp.sum(out * cut(weight)), (out, probs)
+
+            (_, (out, probs)), grads = jax.value_and_grad(
+                attend, argnums=(0, 1, 2), has_aux=True
+            )(cut(q), k, v)
+            kl, kl_grads = jax.value_and_grad(
+                lambda qib, ki, wb: sparse_ops.indexer_kl_reference(
+                    probs, sparse_ops.index_scores_reference(qib, ki, wb),
+                    chosen,
+                ),
+                argnums=(0, 1, 2),
+            )(cut(qi), ki, cut(w))
+        agree = jnp.sum(own & chosen), jnp.sum(own)
+        return agree, (out, *grads), (kl, *kl_grads)
+
+    dense = sparse_ops.dense_mask(mask)
+    parts = {name: [] for name in ("out", "dq", "dqi", "dw")}
+    sums = {
+        "dk": jnp.zeros(k.shape, jnp.float32), "dv": jnp.zeros(v.shape, jnp.float32),
+        "dki": jnp.zeros(ki.shape, jnp.float32), "kl": 0.0,
+    }
+    agreed = pairs = 0
+    for start in range(0, seq, rows):
+        (same, all_), (out, dq, dk, dv), (kl, dqi, dki, dw) = block(
+            start, dense[:, start:start + rows], *f32, w, weight
+        )
+        agreed, pairs = agreed + int(same), pairs + int(all_)
+        for name, value in (("out", out), ("dq", dq), ("dqi", dqi), ("dw", dw)):
+            parts[name].append(value)
+        for name, value in (("dk", dk), ("dv", dv), ("dki", dki), ("kl", kl)):
+            sums[name] = sums[name] + value
+    want = {
+        **{name: jnp.concatenate(value, axis=1) for name, value in parts.items()},
+        **sums,
+    }
+    have = dict(
+        zip(("out", "dq", "dk", "dv", "kl", "dqi", "dki", "dw"), got + got_kl)
+    )
+    errs = {"selected_pairs_agreeing": round(agreed / pairs, 6)}
+    if agreed < 0.999 * pairs:
+        failures.append(
+            f"sparse {shape}: the selection agrees with lax.top_k on "
+            f"{agreed} of {pairs} pairs"
+        )
+    expected = sum(min(t + 1, topk) for t in range(seq)) / seq
+    if float(jnp.mean(kept)) != expected:
+        failures.append(
+            f"sparse {shape}: {float(jnp.mean(kept))} keys a query, not {expected}"
+        )
+    for part, b in want.items():
+        a = jnp.asarray(have[part], jnp.float32)
+        b = jnp.asarray(b, jnp.float32)
+        err = float(jnp.max(jnp.abs(a - b)))
+        scale = max(1.0, float(jnp.max(jnp.abs(b))))
+        errs[part] = round(err / scale, 5)
+        if not math.isfinite(err) or err > KERNEL_TOL * scale:
+            failures.append(
+                f"sparse {shape} {part}: max|kernel-ref| = {err:.4g} > "
+                f"{KERNEL_TOL} * {scale:.3g}"
+            )
+    return errs
 
 
 def _child_workers(run, cfg, workdir):

@@ -10,6 +10,7 @@ ring schedule.
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any
 
@@ -18,6 +19,23 @@ import jax
 import jax.numpy as jnp
 
 import elasticdl_tpu.ops.attention as attention_ops
+
+
+def _kernels_interpret() -> bool:
+    """Whether the sparse-attention kernels run interpreted: by the
+    platform of the mesh the trainer registered, as ``ops.attention.attention``
+    decides it for the flash kernels."""
+    mesh, _, _ = attention_ops.get_attention_mesh()
+    if mesh is None:
+        return attention_ops.kernel_interpret(jax.default_backend())
+    if mesh.devices.size > 1 and not (
+        jax.sharding.get_abstract_mesh().manual_axes
+    ):
+        raise NotImplementedError(
+            "sparse attention across devices is not built: one chip a "
+            "sequence (docs/designs/sparse_attention.md)"
+        )
+    return attention_ops.kernel_interpret(mesh.devices.flat[0].platform)
 
 
 class HeadsDense(nn.Module):
@@ -106,14 +124,32 @@ class MultiHeadSelfAttention(nn.Module):
     # > 0: the heads' own size, where it is not the embedding over the heads
     # (nemotron_h: 32 heads of 128 beside an embedding of 2,688)
     head_dim: int = 0
+    # an RMSNorm over each head's width, one scale shared by the heads
+    # (Qwen3: ``q_norm(q_proj(x).view(..., head_dim))``), in ``qk_norm``'s place
+    qk_norm_per_head: bool = False
+    # rotary positions of several components (Qwen2-VL's ``mrope_section``):
+    # how many of a head's frequencies take their angle from each
+    mrope_section: tuple = ()
+    # index_topk > 0: learned sparse attention (DeepSeek-V3.2-Exp's sparse
+    # attention; docs/designs/sparse_attention.md): an indexer of index_heads
+    # heads of index_head_dim over ONE key head scores every visible key
+    # from the layer's input DETACHED, a query attends to its index_topk
+    # best keys alone, and the indexer's KL to the main attention's
+    # distribution over them joins the ``losses`` collection
+    index_topk: int = 0
+    index_heads: int = 0
+    index_head_dim: int = 0
+    index_kl_weight: float = 1.0
 
     @nn.compact
-    def __call__(self, x, decode_pos=None):
+    def __call__(self, x, decode_pos=None, positions=None):
         """x: (batch, seq, embed) -> (batch, seq, embed).
 
         ``decode_pos``: the model's single decode cursor (traced scalar),
         required in decode mode — there is ONE position source of truth,
-        not one per layer."""
+        not one per layer.  ``positions``: (batch, components, seq) where the
+        records carry positions of several components (``mrope_section``);
+        None: every component is the token's index."""
         embed = x.shape[-1]
         if not self.head_dim and embed % self.num_heads:
             raise ValueError(
@@ -137,7 +173,12 @@ class MultiHeadSelfAttention(nn.Module):
                 features=(heads, head_dim), dtype=self.dtype, name=name,
                 use_bias=self.use_bias,
             )(x)
-            if norm is not None:
+            if norm is not None and self.qk_norm_per_head:
+                with jax.named_scope("qk_norm"):
+                    y = nn.RMSNorm(
+                        epsilon=self.norm_eps, dtype=self.dtype, name=norm
+                    )(y)
+            elif norm is not None:
                 with jax.named_scope("qk_norm"):
                     full = y.reshape(*y.shape[:-2], heads * head_dim)
                     y = nn.RMSNorm(
@@ -145,22 +186,32 @@ class MultiHeadSelfAttention(nn.Module):
                     )(full).reshape(y.shape)
             return y
 
-        q = _proj("query", self.num_heads, "q_norm" if self.qk_norm else None)
-        k = _proj("key", kv_heads, "k_norm" if self.qk_norm else None)
+        normed = self.qk_norm or self.qk_norm_per_head
+        q = _proj("query", self.num_heads, "q_norm" if normed else None)
+        k = _proj("key", kv_heads, "k_norm" if normed else None)
         v = _proj("value", kv_heads)
         if self.rope_theta:
             with jax.named_scope("rope"):
-                positions = (
-                    jnp.arange(x.shape[1])
-                    if decode_pos is None
-                    else decode_pos + jnp.arange(x.shape[1])
-                )
-                q = rope(q, positions, self.rope_theta)
-                k = rope(k, positions, self.rope_theta)
+                if positions is None or not self.mrope_section:
+                    positions = (
+                        jnp.arange(x.shape[1])
+                        if decode_pos is None
+                        else decode_pos + jnp.arange(x.shape[1])
+                    )
+                sections = tuple(self.mrope_section)
+                q = rope(q, positions, self.rope_theta, sections=sections)
+                k = rope(k, positions, self.rope_theta, sections=sections)
+        if self.index_topk and (self.decode or not self.causal):
+            raise NotImplementedError(
+                "sparse attention is built for causal training: the "
+                "indexer's key cache and sparse decode are not"
+            )
         if self.decode:
             if decode_pos is None:
                 raise ValueError("decode mode needs decode_pos")
             out = self._decode_attend(q, k, v, decode_pos)
+        elif self.index_topk:
+            out = self._sparse_attend(x, q, k, v, positions)
         else:
             out = attention_ops.attention(q, k, v, causal=self.causal)
         with jax.named_scope("fold"):
@@ -169,6 +220,76 @@ class MultiHeadSelfAttention(nn.Module):
             features=embed, axis=(-2, -1), dtype=self.dtype, name="out",
             use_bias=self.use_bias,
         )(out)
+
+    def _sparse_attend(self, x, q, k, v, positions):
+        """The indexer, the selection, attention over the selected set and
+        the indexer's loss.  Two detachments keep the gradient paths apart:
+        the indexer reads ``stop_gradient(x)`` and its loss's target is the
+        main attention's probabilities detached, so the main model's
+        parameters see the language-model loss alone and the indexer's
+        their KL alone; the selection passes no gradient."""
+        from elasticdl_tpu.ops import sparse_attention as sparse_ops
+        from elasticdl_tpu.telemetry.router_load import SELECTION_STATS
+
+        heads, width = self.index_heads, self.index_head_dim
+        interpret = _kernels_interpret()
+        with jax.named_scope("indexer"):
+            detached = jax.lax.stop_gradient(x)
+
+            def dense(features, name):
+                return nn.DenseGeneral(
+                    features, use_bias=False, dtype=self.dtype, name=name
+                )
+
+            qi = dense((heads, width), "index_query")(detached)
+            ki = nn.LayerNorm(
+                epsilon=self.norm_eps, dtype=self.dtype, name="index_key_norm"
+            )(dense(width, "index_key")(detached))
+            weights = dense(heads, "index_weights")(detached).astype(
+                jnp.float32
+            ) * (heads * width) ** -0.5
+            if self.rope_theta:
+                # the sections in proportion, over the indexer's narrower head
+                scale = 2 * sum(self.mrope_section) // width or 1
+                sections = tuple(n // scale for n in self.mrope_section)
+                qi = rope(qi, positions, self.rope_theta, sections=sections)
+                ki = rope(
+                    ki[..., None, :], positions, self.rope_theta,
+                    sections=sections,
+                )[..., 0, :]
+        with jax.named_scope("index_select"):
+            mask, lse_i, kept, ties = sparse_ops.index_select(
+                qi, ki, weights, self.index_topk, interpret=interpret
+            )
+            mask_t = sparse_ops.transpose_mask(mask)
+            stats = {"kept_keys": jnp.mean(kept), "ties_broken": jnp.sum(ties)}
+        # (only an apply that asks for ``intermediates`` keeps the mask and
+        # the input it was made from: the chip comparison holds the mask to
+        # the reference's set, pair by pair, on that same input)
+        self.sow("intermediates", "selection", mask)
+        self.sow("intermediates", "indexer_input", detached)
+        out, lse = attention_ops.selected_flash_attention(
+            q, k, v, mask, mask_t, interpret=interpret
+        )
+        with jax.named_scope("indexer_kl"):
+            kl = sparse_ops.indexer_kl(
+                jax.lax.stop_gradient(q), jax.lax.stop_gradient(k),
+                jax.lax.stop_gradient(lse), mask, qi, ki, weights, lse_i,
+                interpret=interpret,
+            ) * (self.index_kl_weight / (x.shape[0] * x.shape[1]))
+        self.sow(
+            "losses", "indexer_kl", kl,
+            init_fn=lambda: jnp.zeros((), jnp.float32),
+            reduce_fn=lambda _prev, new: new,
+        )
+        # what telemetry/router_load.py::read_selection reads on demand
+        for name, value in stats.items():
+            self.sow(
+                SELECTION_STATS, name, jax.lax.stop_gradient(value),
+                init_fn=lambda: jnp.zeros((), jnp.float32),
+                reduce_fn=lambda _prev, new: new,
+            )
+        return out
 
     def _decode_attend(self, q, k, v, pos):
         """One decode step: append this step's K/V to the cache at
@@ -348,9 +469,14 @@ class TransformerBlock(nn.Module):
     moe_fields: Any = ()
     mamba_fields: Any = ()
     latent_fields: Any = ()
+    # MultiHeadSelfAttention's (per-head QK-norm, positions of several
+    # components, the sparse-attention indexer)
+    attention_fields: Any = ()
 
     @nn.compact
-    def __call__(self, x, training: bool = False, decode_pos=None):
+    def __call__(
+        self, x, training: bool = False, decode_pos=None, positions=None
+    ):
         if self.mlp not in MLPS:
             raise ValueError(f"unknown mlp {self.mlp!r}; valid: {MLPS}")
         if self.kind and self.kind not in LAYER_KINDS:
@@ -361,9 +487,10 @@ class TransformerBlock(nn.Module):
             "attention", "experts" if self.num_experts > 0 else "mlp"
         ]
         for part in parts:
-            x = self._residual(
-                x, getattr(self, "_" + part), training, decode_pos
-            )
+            run = getattr(self, "_" + part)
+            if part == "attention" and positions is not None:
+                run = functools.partial(run, positions=positions)
+            x = self._residual(x, run, training, decode_pos)
         return x
 
     def _residual(self, x, part, training, decode_pos):
@@ -375,7 +502,7 @@ class TransformerBlock(nn.Module):
             y = nn.Dropout(self.dropout_rate, deterministic=not training)(y)
         return x + y
 
-    def _attention(self, y, training, decode_pos):
+    def _attention(self, y, training, decode_pos, positions=None):
         if self.latent_fields:
             if self.decode:
                 raise NotImplementedError(
@@ -401,7 +528,11 @@ class TransformerBlock(nn.Module):
             norm_eps=self.norm_eps,
             head_dim=self.head_dim,
             name="attn",
-        )(y, decode_pos=decode_pos)
+            **dict(self.attention_fields),
+        )(
+            y, decode_pos=decode_pos,
+            **({} if positions is None else {"positions": positions}),
+        )
 
     def _mamba(self, y, training, decode_pos):
         from elasticdl_tpu.layers.mamba import Mamba2Mixer
@@ -452,16 +583,43 @@ class TransformerBlock(nn.Module):
             return dense(y.shape[-1], "mlp_down")(hidden)
 
 
-def rope(x, positions, theta: float, interleave: bool = False):
+def rope(
+    x, positions, theta: float, interleave: bool = False, sections=()
+):
     """Rotary positions (Su et al. 2021) over the whole of ``x``'s last
     axis (hand it the slice of the head that rotates): ``x`` (batch, seq,
     heads, d), ``positions`` (seq,).  Pair ``i`` turns by ``positions *
     theta^(-2i/d)``; the pairs are ``(x_i, x_{i+d/2})``, the rotate-half
     convention of HF ``apply_rotary_pos_emb``, or with ``interleave`` the
     adjacent ``(x_2i, x_2i+1)`` of the original and of ``rope_interleave``.
-    Computed in float32."""
+    Computed in float32.
+
+    ``positions`` (batch, components, seq) with ``sections`` (Qwen2-VL's
+    multimodal RoPE): frequency ``i`` takes its angle from the component
+    whose section it lies in, ``sections[c]`` frequencies each in order
+    (temporal, height, width).  Where every component is the token's index
+    (text) that is the rule above, and (seq,) positions take it."""
     half = x.shape[-1] // 2
     rate = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    if positions.ndim == 3:
+        if sum(sections) != half:
+            raise ValueError(
+                f"mrope sections {sections} do not cover {half} frequencies"
+            )
+        component = jnp.repeat(
+            jnp.arange(len(sections)), jnp.asarray(sections),
+            total_repeat_length=half,
+        )
+        # (batch, seq, half): each frequency's own component
+        of_frequency = jnp.take(
+            positions.astype(jnp.float32), component, axis=1
+        ).transpose(0, 2, 1)
+        angles = (of_frequency * rate)[:, :, None, :]
+        cos, sin = jnp.cos(angles), jnp.sin(angles)
+        x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+        return jnp.concatenate(
+            [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
+        ).astype(x.dtype)
     angles = positions.astype(jnp.float32)[:, None] * rate[None, :]
     cos = jnp.cos(angles)[None, :, None, :]
     sin = jnp.sin(angles)[None, :, None, :]

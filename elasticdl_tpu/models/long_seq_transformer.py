@@ -110,6 +110,18 @@ class TransformerLM(nn.Module):
     # main ones and ``loss`` adds mtp_weight times their mean loss
     mtp_depth: int = 0
     mtp_weight: float = 0.3
+    # the attention parts' further fields (layers/attention.py::
+    # MultiHeadSelfAttention): an RMSNorm a head on q and k; rotary
+    # positions of several components, read from the records' ``positions``
+    # (batch, components, seq) where they carry them; index_topk > 0: learned
+    # sparse attention (docs/designs/sparse_attention.md), the indexers' KL
+    # loss at index_kl_weight a layer in the ``losses`` collection
+    qk_norm_per_head: bool = False
+    mrope_section: Any = ()
+    index_topk: int = 0
+    index_heads: int = 0
+    index_head_dim: int = 0
+    index_kl_weight: float = 1.0
 
     @nn.compact
     def __call__(self, features, training: bool = False):
@@ -117,6 +129,11 @@ class TransformerLM(nn.Module):
             features["tokens"] if isinstance(features, dict) else features
         )
         tokens = jnp.asarray(tokens).astype(jnp.int32)
+        components = (
+            features.get("positions")
+            if self.mrope_section and isinstance(features, dict)
+            else None
+        )
         if self.positions not in ("sinusoidal", "rope", "none"):
             raise ValueError(f"unknown positions {self.positions!r}")
         pattern = self.layer_pattern
@@ -214,6 +231,7 @@ class TransformerLM(nn.Module):
                     ("v_head_dim", self.v_head_dim),
                     ("rope_interleave", self.rope_interleave),
                 ) if self.kv_lora_rank else (),
+                **self._attention_fields(),
                 name=name,
             )
 
@@ -222,8 +240,23 @@ class TransformerLM(nn.Module):
 
         for layer in range(self.num_layers):
             x = block(pattern[layer] if pattern else "", f"block_{layer}")(
-                x, training, decode_pos
+                x, training, decode_pos,
+                *(() if components is None else (components,)),
             )
+        if self.index_topk and (training or self.is_initializing()):
+            # asks trainer/step.py for the loss by its parts: the sown
+            # losses join them under their own names
+            for part in ("main", "indexer_kl") + tuple(
+                name
+                for name, weight in (
+                    ("moe_load_balance", self.router_aux_weight),
+                    ("moe_router_z", self.router_z_weight),
+                )
+                if weight and self.num_experts and expert_layers
+            ):
+                self.variable(
+                    LOSS_PARTS, part, lambda: jnp.zeros((), jnp.float32)
+                )
         lm_head = nn.Dense(
             self.vocab_size, dtype=self.dtype, use_bias=self.use_bias,
             name="lm_head",
@@ -264,6 +297,24 @@ class TransformerLM(nn.Module):
             # a value a row: the step's masked loss maps ``loss`` over rows
             "mtp_weight": jnp.full(tokens.shape[:1], self.mtp_weight),
         }
+
+
+    def _attention_fields(self) -> dict:
+        """The block's ``attention_fields``, given only where a field is
+        set: a model without them builds the block it always built."""
+        fields = tuple(
+            (name, value)
+            for name, value in (
+                ("qk_norm_per_head", self.qk_norm_per_head),
+                ("mrope_section", tuple(self.mrope_section)),
+                ("index_topk", self.index_topk),
+                ("index_heads", self.index_heads),
+                ("index_head_dim", self.index_head_dim),
+                ("index_kl_weight", self.index_kl_weight),
+            )
+            if value and (name != "index_kl_weight" or self.index_topk)
+        )
+        return {"attention_fields": fields} if fields else {}
 
 
 def custom_model(**kwargs):

@@ -35,6 +35,12 @@ _FLASH_COMPILER_PARAMS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")
 )
 
+# the selected-set variants stage a chunk of the mask beside k and v
+_SELECTED_COMPILER_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+    vmem_limit_bytes=64 << 20,
+)
+
 _NEG_INF = -1e30
 
 # the three kernels' names: each ``pallas_call``'s ``name``, which the
@@ -43,6 +49,12 @@ _NEG_INF = -1e30
 FLASH_FWD = "flash_fwd"
 FLASH_DQ = "flash_dq"
 FLASH_DKV = "flash_dkv"
+# the same three kernels over a selected set (a mask beside the causal
+# structure, ops/sparse_attention.py): names of their own, so that a trace
+# tells a step's dense attention from its sparse one
+SELECTED_FWD = "dsa_fwd"
+SELECTED_DQ = "dsa_dq"
+SELECTED_DKV = "dsa_dkv"
 # the XLA ops beside the kernel calls (reshapes and transposes to and from
 # the kernels' layout) by telemetry/op_scopes.py's name; never around a
 # ``pallas_call``, whose own name is what the device's op line shows
@@ -241,6 +253,13 @@ def _causal_mask(s, row0, col0, q_axis=0):
     return jnp.where(ahead >= col0 - row0, s, _NEG_INF)
 
 
+def _selected(s, chosen):
+    """Scores outside the selected set masked: ``chosen`` is the tile of
+    the selection's int8 mask (1 where the query reads the key, causality
+    included; transposed like ``s`` for dK/dV)."""
+    return jnp.where(chosen.astype(jnp.int32) != 0, s, _NEG_INF)
+
+
 def _scores(a, b, sm_scale=None):
     """``a @ b.T`` in float32, times the softmax scale where one is given
     (applied to the float32 scores: 1/sqrt(128) is not a bf16 number, so
@@ -343,8 +362,8 @@ def _block_pieces(block_q, block_k, crossed, along_q):
 
 
 def _flash_kernel(
-    q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
-    *, sm_scale, causal, block_q, block_k, chunk_k, num_ck,
+    q_ref, k_ref, v_ref, *refs,
+    sm_scale, causal, block_q, block_k, chunk_k, num_ck, selected=False,
 ):
     """One (batch, head, q-block, k-chunk) grid cell of the online-softmax
     forward: loop block_k sub-blocks of the staged (1, chunk_k, d) K/V
@@ -357,7 +376,18 @@ def _flash_kernel(
     (:func:`_heads_per_block`; ``m_scr`` has a plane a head) the cell does
     both: each head's scores from a q with the other's lanes zeroed, its
     ``p @ v`` taken over the whole block and its own lanes selected into
-    the one lane-dense accumulator."""
+    the one lane-dense accumulator.
+
+    ``selected``: ``refs`` starts with the chunk of the selection's mask,
+    ``(1, blocks of the chunk, block_q, block_k)`` int8, applied to every
+    live block in the place of the diagonal's iota mask (it holds the
+    causal structure too).  A row may then meet blocks that hold none of
+    its keys before one that does: what ``exp(-1e30 - -1e30) = 1`` adds to
+    ``l`` and ``acc`` there is wiped by ``alpha = 0`` at the first real
+    key, and every row has one (itself or an earlier key)."""
+    if selected:
+        mask_ref, *refs = refs
+    o_ref, lse_ref, m_scr, l_scr, acc_scr = refs
     i = pl.program_id(2)
     c = pl.program_id(3)
     heads = m_scr.shape[0]
@@ -391,7 +421,9 @@ def _flash_kernel(
                 alphas, pvs = [], []
                 for h in range(heads):
                     s = _scores(q_of[h][rows], kb, sm_scale)
-                    if masked:
+                    if selected:
+                        s = _selected(s, mask_ref[0, jj, q0:q1, k0:k1])
+                    elif masked:
                         s = _causal_mask(s, row0 + q0, col0 + start + k0)
                     m_prev = m_scr[h, rows]  # (rows, _LANES), columns equal
                     m_next = jnp.maximum(
@@ -443,6 +475,7 @@ def _flash_dq_kernel(
     chunk_k,
     num_ck,
     makes_delta,
+    selected=False,
 ):
     """dQ cell per (batch, head, q-block, k-chunk): rebuild p from the
     saved logsumexp, accumulate dq = sm_scale * ds @ K into VMEM scratch
@@ -459,6 +492,8 @@ def _flash_dq_kernel(
     lanes make it: outside them the same sum over a 64-wide minor
     dimension has XLA turn a float32 (batch, tokens, heads * 64) array
     tokens-minor first, 100 MB through HBM a layer at 8 x 1,024 x 12 x 64."""
+    if selected:  # the mask's chunk, as the forward takes it
+        mask_ref, *refs = refs
     if makes_delta:
         out_ref, dq_ref, delta_ref, acc_scr, delta_scr = refs
     else:
@@ -512,7 +547,9 @@ def _flash_dq_kernel(
                 dqs = []
                 for h in range(heads):
                     s = _scores(q_of[h][rows], kb, sm_scale)
-                    if masked:
+                    if selected:
+                        s = _selected(s, mask_ref[0, jj, q0:q1, k0:k1])
+                    elif masked:
                         s = _causal_mask(s, row0 + q0, col0 + start + k0)
                     p = jnp.exp(s - _lanes_to(lse[h][rows], k1 - k0))
                     dp = _scores(do_of[h][rows], vb)
@@ -543,17 +580,14 @@ def _flash_dkv_kernel(
     do_ref,
     lse_ref,
     delta_ref,
-    dk_ref,
-    dv_ref,
-    dk_scr,
-    dv_scr,
-    *,
+    *refs,
     sm_scale,
     causal,
     block_q,
     block_k,
     chunk_q,
     num_cq,
+    selected=False,
 ):
     """dK/dV cell per (batch, head, k-block, q-chunk): loop block_q
     sub-blocks of the staged (1, chunk_q, d) Q/dO chunk over TRANSPOSED
@@ -564,7 +598,12 @@ def _flash_dkv_kernel(
     q-block or half of one).  ``sm_scale`` meets dk once, at the write.
     Two heads a cell where the blocks hold two: k and v with the other
     head's lanes zeroed make the scores, and each head's lanes of the two
-    products are selected into the accumulators."""
+    products are selected into the accumulators.  ``selected``: ``refs``
+    starts with the chunk of the TRANSPOSED mask, ``(1, blocks of the
+    chunk, block_k, block_q)``."""
+    if selected:
+        mask_ref, *refs = refs
+    dk_ref, dv_ref, dk_scr, dv_scr = refs
     j = pl.program_id(2)
     c = pl.program_id(3)
     heads = lse_ref.shape[0]
@@ -616,7 +655,9 @@ def _flash_dkv_kernel(
                     delta = saved_rows(delta_ref, h, ii, q0, q1)
                     # (k rows, q rows)
                     st = _scores(k_of[h][cols], qi, sm_scale)
-                    if masked:
+                    if selected:
+                        st = _selected(st, mask_ref[0, ii, k0:k1, q0:q1])
+                    elif masked:
                         st = _causal_mask(
                             st, row0 + start + q0, col0 + k0, q_axis=1
                         )
@@ -831,6 +872,19 @@ class _HeadAddressing:
         )
 
 
+def _mask_operand(mask, block, index):
+    """``(operands, in_specs)`` a selected-set call adds: the mask and its
+    block of ``block`` behind the batch at ``index(b, h, i, c)``; nothing
+    for a dense call, whose program stays as it was."""
+    if mask is None:
+        return [], []
+    return [mask], [pl.BlockSpec((1,) + block, index)]
+
+
+def _compiler_params(mask):
+    return _FLASH_COMPILER_PARAMS if mask is None else _SELECTED_COMPILER_PARAMS
+
+
 def _last_live_chunk(i, block_q, chunk_k):
     """The last k-chunk a causal q-block sees: the index maps stop there,
     so the pipeline fetches no chunk the kernel would skip."""
@@ -841,7 +895,12 @@ def _last_live_chunk(i, block_q, chunk_k):
 # kernel (the twelve layers of the benchmark's LM traced them 36 times: on
 # the chip's host 15 s of a 50 s set-up, PERF.md section 6, PR 28)
 @functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7), inline=True)
-def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret):
+def _flash_forward(
+    q, k, v, causal, sm_scale, block_q, block_k, interpret, mask=None
+):
+    """``mask``: the selection's, ``(batch, seq_k / block_k, seq_q,
+    block_k)`` int8 (``ops/sparse_attention.py``): the kernel then runs
+    under its selected-set name and reads a chunk of it beside k and v."""
     q, k, v = jnp.asarray(q), jnp.asarray(k), jnp.asarray(v)
     sm_scale, block_q, block_k, _, chunk_k, interpret = _flash_geometry(
         q, k, v, sm_scale, block_q, block_k, interpret
@@ -869,9 +928,14 @@ def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret):
         block_k=block_k,
         chunk_k=chunk_k,
         num_ck=num_ck,
+        **({} if mask is None else {"selected": True}),
     )
     with jax.named_scope(_FOLD):
         operands = [at.operand(x) for x in (q, k, v)]
+    masks, mask_specs = _mask_operand(
+        mask, (chunk_k // block_k, block_q, block_k),
+        lambda b, h, i, c: (b, _kv_chunk(h, i, c), i, 0),
+    )
     out, lse = pl.pallas_call(
         kernel,
         grid=(batch, at.cells, seq_q // block_q, num_ck),
@@ -879,7 +943,7 @@ def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret):
             at.spec(block_q, d, _q_block),
             at.spec(chunk_k, d, _kv_chunk, kv_heads),
             at.spec(chunk_k, d_v, _kv_chunk, kv_heads),
-        ],
+        ] + mask_specs,
         out_specs=[
             at.spec(block_q, d_v, _q_block),
             at.row_spec((1, block_q), lambda i, c: (0, i)),
@@ -896,10 +960,10 @@ def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret):
             pltpu.VMEM((at.per_cell, block_q, _LANES), jnp.float32),  # l
             pltpu.VMEM((block_q, at.per_cell * d_v), jnp.float32),  # acc
         ],
-        compiler_params=_FLASH_COMPILER_PARAMS,
+        compiler_params=_compiler_params(mask),
         interpret=interpret,
-        name=FLASH_FWD,
-    )(*operands)
+        name=FLASH_FWD if mask is None else SELECTED_FWD,
+    )(*operands, *masks)
     with jax.named_scope(_FOLD):
         return at.result(out, batch, heads), lse
 
@@ -908,8 +972,11 @@ def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret):
     jax.jit, static_argnums=(6, 7, 8, 9, 10), inline=True
 )
 def _flash_backward(
-    q, k, v, out, lse, g, causal, sm_scale, block_q, block_k, interpret
+    q, k, v, out, lse, g, causal, sm_scale, block_q, block_k, interpret,
+    mask=None, mask_t=None,
 ):
+    """``mask`` as the forward takes it (for dQ), ``mask_t`` its transpose
+    by blocks, ``(batch, seq_q / block_q, seq_k, block_q)`` (for dK/dV)."""
     q, k, v = jnp.asarray(q), jnp.asarray(k), jnp.asarray(v)
     out, lse, g = jnp.asarray(out), jnp.asarray(lse), jnp.asarray(g)
     sm_scale, bq, bk, chunk_q, chunk_k, interpret = _flash_geometry(
@@ -958,11 +1025,16 @@ def _flash_backward(
             )[:, None, :]  # (B*H, 1, S_q)
         extra_spec, out_specs, out_shape = row_block, dq_spec, dq_shape
         scratch = [dq_scratch]
+    selected = {} if mask is None else {"selected": True}
+    masks, mask_specs = _mask_operand(
+        mask, (chunk_k // bk, bq, bk),
+        lambda b, h, i, c: (b, _kv_chunk(h, i, c), i, 0),
+    )
     made = pl.pallas_call(
         functools.partial(
             _flash_dq_kernel, sm_scale=sm_scale, causal=causal,
             block_q=bq, block_k=bk, chunk_k=chunk_k, num_ck=num_ck,
-            makes_delta=bool(at.lanes),
+            makes_delta=bool(at.lanes), **selected,
         ),
         grid=(batch, at.cells, seq_q // bq, num_ck),
         in_specs=[
@@ -971,15 +1043,14 @@ def _flash_backward(
             at.spec(chunk_k, d_v, _kv_chunk, kv_heads),
             at.spec(bq, d_v, _q_block),
             row_block,
-            extra_spec,
-        ],
+        ] + mask_specs + [extra_spec],
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=scratch,
-        compiler_params=_FLASH_COMPILER_PARAMS,
+        compiler_params=_compiler_params(mask),
         interpret=interpret,
-        name=FLASH_DQ,
-    )(qf, kf, vf, dof, lse, extra)
+        name=FLASH_DQ if mask is None else SELECTED_DQ,
+    )(qf, kf, vf, dof, lse, *masks, extra)
     dq, delta = made if at.lanes else (made, extra)
 
     # dK/dV are computed per q-head (the kernel never materializes
@@ -1001,10 +1072,15 @@ def _flash_backward(
     saved = _diagonal_half(bq, bk) or bq
     rows = (batch * heads, seq_q // saved, saved)
     row_spec = at.row_spec(rows[1:], lambda j, c: (0, 0))
+    masks, mask_specs = _mask_operand(
+        mask_t, (chunk_q // bq, bk, bq),
+        lambda b, h, j, c: (b, _q_chunk(h, j, c), j, 0),
+    )
     dk_per_q, dv_per_q = pl.pallas_call(
         functools.partial(
             _flash_dkv_kernel, sm_scale=sm_scale, causal=causal,
             block_q=bq, block_k=bk, chunk_q=chunk_q, num_cq=num_cq,
+            **selected,
         ),
         grid=(batch, at.cells, seq_k // bk, num_cq),
         in_specs=[
@@ -1014,7 +1090,7 @@ def _flash_backward(
             at.spec(chunk_q, d_v, _q_chunk),
             row_spec,
             row_spec,
-        ],
+        ] + mask_specs,
         out_specs=[at.spec(bk, d, _k_block), at.spec(bk, d_v, _k_block)],
         out_shape=[
             jax.ShapeDtypeStruct(at.shape(batch, seq_k, heads, d), k.dtype),
@@ -1026,10 +1102,10 @@ def _flash_backward(
             pltpu.VMEM((bk, at.per_cell * d), jnp.float32),  # dk
             pltpu.VMEM((bk, at.per_cell * d_v), jnp.float32),  # dv
         ],
-        compiler_params=_FLASH_COMPILER_PARAMS,
+        compiler_params=_compiler_params(mask),
         interpret=interpret,
-        name=FLASH_DKV,
-    )(qf, kf, vf, dof, lse.reshape(rows), delta.reshape(rows))
+        name=FLASH_DKV if mask is None else SELECTED_DKV,
+    )(qf, kf, vf, dof, lse.reshape(rows), delta.reshape(rows), *masks)
 
     with jax.named_scope(_FOLD):
         dq = at.result(dq, batch, heads)
@@ -1057,6 +1133,46 @@ def _flash_bwd_rule(causal, sm_scale, block_q, block_k, interpret, res, g):
 
 
 flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def selected_flash_attention(
+    q, k, v, mask, mask_t, sm_scale: float | None = None,
+    interpret: bool | None = None,
+):
+    """Causal attention over a selected set of keys a query, through the
+    flash kernels: ``mask`` ``(batch, seq / block, seq, block)`` int8 holds
+    1 where query ``t`` reads key ``s`` (``[b, s // block, t, s % block]``,
+    causality included) and ``mask_t`` the same by query blocks
+    (``[b, t // block, s, t % block]``), as
+    ``ops/sparse_attention.py::index_select`` and ``transpose_mask`` make
+    them.  Returns ``(out, lse)``: the per-head logsumexp of the scaled
+    scores over the selected set, ``(batch * heads, 1, seq)``, is what the
+    indexer's loss rebuilds the probabilities from; it takes no gradient.
+    No block is skipped for being unselected: the set is per token."""
+    block_k, block_q = mask.shape[3], mask_t.shape[3]
+    return _flash_forward(
+        q, k, v, True, sm_scale, block_q, block_k, interpret, mask
+    )
+
+
+def _selected_fwd_rule(q, k, v, mask, mask_t, sm_scale, interpret):
+    out, lse = selected_flash_attention(
+        q, k, v, mask, mask_t, sm_scale, interpret
+    )
+    return (out, lse), (q, k, v, out, lse, mask, mask_t)
+
+
+def _selected_bwd_rule(sm_scale, interpret, res, g):
+    q, k, v, out, lse, mask, mask_t = res
+    dq, dk, dv = _flash_backward(
+        q, k, v, out, lse, g[0], True, sm_scale, mask_t.shape[3],
+        mask.shape[3], interpret, mask, mask_t,
+    )
+    return dq, dk, dv, None, None
+
+
+selected_flash_attention.defvjp(_selected_fwd_rule, _selected_bwd_rule)
 
 
 # ---- dispatch --------------------------------------------------------------

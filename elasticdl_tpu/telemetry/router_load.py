@@ -26,6 +26,11 @@ ROUTER_STATS = "router_stats"
 # ``trainer/step.py`` leave its loss there by the parts its ``loss.parts``
 # names: a multi-token-prediction model's ``main`` and ``mtp``
 LOSS_PARTS = "loss_parts"
+# the collection a sparse-attention layer (``layers/attention.py``) sows
+# what its selection did into, a scalar a name and layer: the selected keys
+# a query (``kept_keys``, a mean over batch and queries) and the queries at
+# which a tie was broken at the last place (``ties_broken``)
+SELECTION_STATS = "selection_stats"
 
 _watched = None
 
@@ -52,6 +57,23 @@ def read_loss_parts(model_state=None) -> dict | None:
     if not parts:
         return None
     return {k: float(v) for k, v in jax.device_get(parts).items()}
+
+
+def read_selection(model_state=None) -> dict | None:
+    """The newest step's selection by layer, one host readback:
+    ``{"kept_keys": [a layer ...], "ties_broken": [...]}`` in the order of
+    the layers' names.  None for a model without sparse attention."""
+    stats = (_state(model_state) or {}).get(SELECTION_STATS)
+    if not stats:
+        return None
+    out: dict = {}
+    leaves = jax.tree_util.tree_leaves_with_path(jax.device_get(stats))
+    # (block_10 after block_2: by the number in the layer's name)
+    for path, leaf in sorted(
+        leaves, key=lambda item: int("0" + "".join(filter(str.isdigit, item[0][0].key)))
+    ):
+        out.setdefault(path[-1].key, []).append(float(leaf))
+    return out
 
 
 def read(model_state=None) -> dict | None:

@@ -197,7 +197,18 @@ def build_train_step(
                 loss = fn(labels, outputs)
             else:
                 loss = weighted_mean_loss(fn, labels, outputs, weights)
+            # layer-contributed losses (MoE load balancing, regularizers):
+            # any value sown into the "losses" collection joins the training
+            # loss — the reference adds Keras model reg losses the same way
+            # (worker.py:656-669); where the loss goes by parts, as the part
+            # of its own name (the layers' summed)
+            sown = jax.tree_util.tree_leaves_with_path(
+                new_model_state.get("losses", {})
+            )
             if by_parts:
+                for path, leaf in sown:
+                    name = path[-1].key
+                    loss[name] = loss.get(name, 0.0) + jnp.sum(leaf)
                 new_model_state = {
                     **new_model_state,
                     LOSS_PARTS: jax.lax.stop_gradient(
@@ -205,14 +216,9 @@ def build_train_step(
                     ),
                 }
                 loss = sum(loss.values())
-            # layer-contributed losses (MoE load balancing, regularizers):
-            # any value sown into the "losses" collection joins the training
-            # loss — the reference adds Keras model reg losses the same way
-            # (worker.py:656-669)
-            for leaf in jax.tree_util.tree_leaves(
-                new_model_state.get("losses", {})
-            ):
-                loss = loss + jnp.sum(leaf)
+            else:
+                for _, leaf in sown:
+                    loss = loss + jnp.sum(leaf)
             return loss.astype(jnp.float32), (outputs, new_model_state)
 
     if remat:

@@ -24,6 +24,7 @@ from elasticdl_tpu.models import resnet50_model
 from elasticdl_tpu.ops import attention as attention_ops
 from elasticdl_tpu.ops import grouped_matmul as gmm_ops
 from elasticdl_tpu.ops import mamba_passes
+from elasticdl_tpu.ops import sparse_attention as sparse_ops
 from elasticdl_tpu.ops import ssd as ssd_ops
 from elasticdl_tpu.parallel.distributed import SPMDTrainer
 from elasticdl_tpu.parallel.mesh import MeshConfig
@@ -38,6 +39,8 @@ KERNELS = {
     ssd_ops.SSD_FWD, ssd_ops.SSD_BWD,
     mamba_passes.GATE_NORM_FWD, mamba_passes.GATE_NORM_BWD,
     mamba_passes.MAMBA_CONV_FWD, mamba_passes.MAMBA_CONV_BWD,
+    attention_ops.SELECTED_FWD, attention_ops.SELECTED_DQ,
+    attention_ops.SELECTED_DKV, sparse_ops.INDEX_SELECT, sparse_ops.INDEXER_KL,
 }
 # modules that hold other modules: an op directly under one of these is in
 # a region nobody named
@@ -244,7 +247,7 @@ def test_attribute_sums_by_scope_and_says_what_it_could_not_place():
 
 
 def test_the_vocabulary_is_closed_and_every_scope_of_the_package_is_in_it():
-    assert len(op_scopes.VOCABULARY) < 20
+    assert len(op_scopes.VOCABULARY) == 21
     assert len(set(op_scopes.VOCABULARY)) == len(op_scopes.VOCABULARY)
     literal = re.compile(r"named_scope\(\s*([\"']?)(\w+)\1\s*\)")
     constants = re.compile(r"^(_[A-Z_]+) = \"(\w+)\"$", re.M)
@@ -309,6 +312,7 @@ FAMILIES = {
     "olmoe": lambda: _lm_family("tiny_olmoe"),
     "mamba_experts_attention": lambda: _lm_family("tiny_nemotron"),
     "latent_attention_mtp": lambda: _lm_family("tiny_joyai"),
+    "sparse_attention": lambda: _lm_family("tiny_keye"),
     "resnet_first_stage": lambda: (
         FirstStage(), _class_loss, optax.sgd(0.1),
         {"image": np.zeros((2, 32, 32, 3), np.float32)},
@@ -525,6 +529,93 @@ def test_the_mixers_passes_are_kernels_under_their_own_parts(one_chip_mesh):
     ]
     for pattern in patterns:
         assert trace_reduce.matching_seconds(reduced, pattern) == 0, pattern
+
+
+def test_sparse_attentions_kernels_are_under_their_own_parts(one_chip_mesh):
+    """A sparse-attention expert layer at the published head widths (heads
+    of 128 grouped 4 : 1, an indexer of 4 x 64, 1,024 tokens, top-256),
+    recomputed, compiled for the described chip: Mosaic takes the five
+    kernels, each custom-call keeps its name, the indexer's scores and
+    selection sit under ``index_select``, its loss under ``indexer_kl``, the
+    three selected-set kernels directly under ``attn``, in the phases the
+    step runs them in, and none reads as a dense flash kernel to ``perf/``'s
+    readers, which match by name; ``perf/dsa_rooflines.py``'s shares add up
+    over that map."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from perf import dsa_rooflines, layer_readers, scope_shares, trace_reduce
+
+    model, loss, tx, _, _, _ = _lm_family(
+        "tiny_keye", num_layers=1, embed_dim=256, num_heads=4, num_kv_heads=1,
+        head_dim=128, mrope_section=(16, 24, 24), index_topk=256,
+        index_heads=4, index_head_dim=64,
+    )
+    features = {"tokens": np.zeros((1, 1024), np.int32)}
+    labels = np.zeros((1, 1024), np.int32)
+    shapes = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), features, training=False)
+    )
+    whole = NamedSharding(one_chip_mesh, PartitionSpec())
+
+    def described(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(np.shape(x), x.dtype, sharding=whole),
+            tree,
+        )
+
+    state = jax.eval_shape(
+        lambda: TrainState.create(
+            model.apply,
+            jax.tree_util.tree_map(
+                lambda x: jnp.zeros(x.shape, x.dtype), shapes["params"]
+            ),
+            tx,
+            jax.tree_util.tree_map(
+                lambda x: jnp.zeros(x.shape, x.dtype),
+                {k: v for k, v in shapes.items() if k != "params"},
+            ),
+        )
+    )
+    step = build_train_step(loss, donate=False)
+    with one_chip_mesh, attention_ops.attention_mesh_scope(one_chip_mesh):
+        compiled = step.lower(
+            described(state), described(features), described(labels),
+            described(np.ones((1,), np.float32)),
+        ).compile()
+    scopes = op_scopes.scope_map(compiled)
+    found = {}
+    for name, (part, phase, kind, _) in scopes.items():
+        if kind == "kernel":
+            found.setdefault(name.split(".")[0], set()).add((part, phase))
+    twice = {"forward", "recompute"}
+    assert found[sparse_ops.INDEX_SELECT] == {
+        ("block/attn/index_select/dsa_index", phase) for phase in twice
+    }
+    # its value in the first pass, its gradients in the backward pass; the
+    # recomputed pass needs nothing of the value call and does not run it
+    assert found[sparse_ops.INDEXER_KL] == {
+        ("block/attn/indexer_kl/dsa_kl", phase) for phase in ("forward", "backward")
+    }
+    assert found[attention_ops.SELECTED_FWD] == {
+        ("block/attn/dsa_fwd", phase) for phase in twice
+    }
+    assert found[attention_ops.SELECTED_DQ] == {("block/attn/dsa_dq", "backward")}
+    assert found[attention_ops.SELECTED_DKV] == {("block/attn/dsa_dkv", "backward")}
+    assert not {"flash_fwd", "flash_dq", "flash_dkv"} & set(found)
+    # as perf/ reads a trace: an op's self time by its name and by its scope
+    ours = {name: 1.0 for name, (_, _, kind, _) in scopes.items() if kind == "kernel"
+            and name.startswith("dsa_")}
+    assert len(ours) == 8
+    reduced = {"op_self_s": ours, "details": {}}
+    assert trace_reduce.matching_seconds(reduced, layer_readers.FLASH_KERNELS) == 0
+    run = {
+        "trace": {"busy_s": 16.0, "op_self_s": ours},
+        "_scope_shares": op_scopes.attribute(ours, [scopes]),
+    }
+    assert scope_shares.attributed(run) is run["_scope_shares"]
+    assert dsa_rooflines.selection_time_share(run) == pytest.approx(100 * 2 / 16)
+    assert dsa_rooflines.indexer_time_share(run) == pytest.approx(100 * 2 / 16)
+    assert dsa_rooflines.sparse_attention_time_share(run) == pytest.approx(50.0)
 
 
 # ---- the trainer hands out the programs it dispatched ----------------------------
