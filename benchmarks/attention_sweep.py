@@ -38,7 +38,11 @@ geometry Mosaic refuses is reported with its error, not skipped in silence.
 that shape, over the set an indexer of 16 heads of 64 with seeded weights
 selects (``ops/sparse_attention.py::index_select``, outside the timed
 calls), and counts the selected pairs' FLOPs, as ``perf/dsa_rooflines.py``
-does:
+does; its first line is the selection alone, search then check
+(``dsa_index`` and ``dsa_index_hinted`` on the first's threshold and the same
+operands: the two times side by side, the share of blocks whose tie search
+ran and of blocks whose hint held, which must be all; exit 1 otherwise;
+``--select-impl`` times another copy of ``ops/sparse_attention.py``):
 
     python benchmarks/attention_sweep.py --shape 1,16384,32/4,128 --topk 2048
 
@@ -63,6 +67,8 @@ sys.path.insert(
 
 KERNELS = ("flash_fwd", "flash_dq", "flash_dkv")
 SELECTED_KERNELS = ("dsa_fwd", "dsa_dq", "dsa_dkv")
+# (matched by substring in this order: the hinted name holds the other)
+SELECTION_KERNELS = ("dsa_index_hinted", "dsa_index")
 
 
 OTHER = "other_ops"
@@ -138,9 +144,7 @@ def time_geometry(
     if topk:
         from elasticdl_tpu.ops import sparse_attention as sparse_ops
 
-        qi = jax.random.normal(keys[4], (batch, seq, 16, 64)).astype(dtype)
-        ki = jax.random.normal(keys[5], (batch, seq, 64)).astype(dtype)
-        wi = jax.random.normal(keys[6], (batch, seq, 16)) / 32
+        qi, ki, wi = indexer_operands(keys[4:], batch, seq, dtype)
         mask = jax.jit(
             lambda qi, ki, wi: sparse_ops.index_select(qi, ki, wi, topk)[0]
         )(qi, ki, wi)
@@ -185,6 +189,68 @@ def time_geometry(
         setattr(module, chunk_name, module_chunk)
 
 
+def indexer_operands(keys, batch, seq, dtype):
+    """A seeded indexer of 16 heads of 64: queries, its one key head, head
+    weights."""
+    import jax
+
+    return (
+        jax.random.normal(keys[0], (batch, seq, 16, 64)).astype(dtype),
+        jax.random.normal(keys[1], (batch, seq, 64)).astype(dtype),
+        jax.random.normal(keys[2], (batch, seq, 16)) / 32,
+    )
+
+
+def time_selection(sparse_ops, batch, seq, dtype, topk, calls) -> dict:
+    """The selection alone, search then check: ``index_select_threshold``
+    and ``index_select_hinted`` on its threshold and the same operands.
+    Every pair of the two masks, ``lse`` and the counters must be equal and
+    the hint must hold in every block; milliseconds a call of each kernel.
+    A copy of the module from before PR 40 (``--select-impl``) has the
+    search alone."""
+    import jax
+    import jax.numpy as jnp
+
+    qi, ki, wi = indexer_operands(
+        jax.random.split(jax.random.PRNGKey(0), 7)[4:], batch, seq, dtype
+    )
+    hinted = hasattr(sparse_ops, "index_select_hinted")
+
+    @jax.jit
+    def both(qi, ki, wi):
+        if not hinted:
+            _, _, kept, ties = sparse_ops.index_select(qi, ki, wi, topk)
+            return {"kept_keys": jnp.mean(kept), "ties_broken": jnp.sum(ties)}
+        mask, lse, kept, ties, searched, threshold = (
+            sparse_ops.index_select_threshold(qi, ki, wi, topk)
+        )
+        again = sparse_ops.index_select_hinted(qi, ki, wi, threshold, topk)
+        return {
+            "kept_keys": jnp.mean(again[2]),
+            "ties_broken": jnp.sum(ties),
+            "tie_search_blocks": jnp.mean(searched),
+            "hint_held": jnp.mean(again[4]),
+            "unequal": sum(
+                jnp.sum(a != b) for a, b in zip(again, (mask, lse, kept, ties))
+            ),
+        }
+
+    jax.block_until_ready(both(qi, ki, wi))  # compiles
+    trace_dir = tempfile.mkdtemp(prefix="attention_sweep_")
+    try:
+        with jax.profiler.trace(trace_dir):
+            for _ in range(calls):
+                out = both(qi, ki, wi)
+            jax.block_until_ready(out)
+        ms = kernel_ms(trace_dir, calls, SELECTION_KERNELS)
+    finally:
+        shutil.rmtree(trace_dir, ignore_errors=True)
+    return {
+        **{name: float(value) for name, value in out.items()},
+        "ms": {k: round(v, 4) for k, v in ms.items() if v},
+    }
+
+
 def main() -> int:
     parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     parser.add_argument(
@@ -201,6 +267,10 @@ def main() -> int:
         help="'block_q,block_k,chunk;...'; empty: the module's own choice",
     )
     parser.add_argument("--impl", default=None, help="another attention.py")
+    parser.add_argument(
+        "--select-impl", default=None,
+        help="with --topk: another sparse_attention.py for the selection",
+    )
     parser.add_argument("--label", default=None)
     parser.add_argument("--non-causal", action="store_true")
     parser.add_argument("--calls", type=int, default=5)
@@ -239,6 +309,30 @@ def main() -> int:
         for g in args.sweep.split(";")
         if g.strip()
     ] or [None]
+    status = 0
+    if args.topk:
+        if args.select_impl:
+            sparse_ops = load_impl(args.select_impl)
+        else:
+            from elasticdl_tpu.ops import sparse_attention as sparse_ops
+        selection = time_selection(
+            sparse_ops, batch, seq, jnp.dtype(args.dtype), args.topk,
+            args.calls,
+        )
+        print(
+            json.dumps(
+                {
+                    "impl": args.select_impl
+                    or "elasticdl_tpu.ops.sparse_attention",
+                    "shape": [batch, seq, 16, 64], "topk": args.topk,
+                    "device_kind": device.device_kind,
+                    "selection": selection,
+                }
+            ),
+            flush=True,
+        )
+        if selection.get("unequal") or selection.get("hint_held", 1.0) < 1.0:
+            status = 1  # identical operands: the hint holds and nothing differs
     for geometry in geometries:
         line = {
             "impl": args.label or args.impl or "elasticdl_tpu.ops.attention",
@@ -266,7 +360,7 @@ def main() -> int:
                 if ms[k]
             }
         print(json.dumps(line), flush=True)
-    return 0
+    return status
 
 
 if __name__ == "__main__":

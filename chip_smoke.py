@@ -793,7 +793,11 @@ def _check_sparse_kernels(shape, failures, rows=256) -> dict:
     (a block holds (heads, rows, tokens) float32 scores): the selection
     against ``lax.top_k`` pair by pair, then, over the KERNEL's own set,
     the attention's output and three gradients and the indexer's loss and
-    its three gradients."""
+    its three gradients.  The selection is made twice, searched
+    (``dsa_index``) and then checked from the search's threshold on the same
+    operands (``dsa_index_hinted``): every pair, ``lse`` and the counters
+    equal, every block's hint held, the hinted mask's own count of keys a
+    query, and the two calls' times side by side."""
     import jax
     import jax.numpy as jnp
 
@@ -822,7 +826,19 @@ def _check_sparse_kernels(shape, failures, rows=256) -> dict:
 
     @jax.jit
     def kernels(q, k, v, qi, ki, w, weight):
-        mask, lse_i, kept, _ = sparse_ops.index_select(qi, ki, w, topk)
+        mask, lse_i, kept, ties, searched, threshold = (
+            sparse_ops.index_select_threshold(qi, ki, w, topk)
+        )
+        again = sparse_ops.index_select_hinted(qi, ki, w, threshold, topk)
+        hinted = {
+            "unequal": sum(
+                jnp.sum(a != b)
+                for a, b in zip(again, (mask, lse_i, kept, ties))
+            ),
+            "hint_held": jnp.mean(again[4]),
+            "tie_search_blocks": jnp.mean(searched),
+            "keys_a_query": jnp.sum(again[0].astype(jnp.int32)) / (batch * seq),
+        }
         mask_t = sparse_ops.transpose_mask(mask)
 
         def attend(q, k, v):
@@ -838,9 +854,11 @@ def _check_sparse_kernels(shape, failures, rows=256) -> dict:
             ),
             argnums=(0, 1, 2),
         )(qi, ki, w)
-        return mask, kept, (out, *grads), (kl, *kl_grads)
+        return mask, kept, (out, *grads), (kl, *kl_grads), hinted, threshold
 
-    mask, kept, got, got_kl = kernels(q, k, v, qi, ki, w, weight)
+    mask, kept, got, got_kl, hinted, threshold = kernels(
+        q, k, v, qi, ki, w, weight
+    )
     f32 = [x.astype(jnp.float32) for x in (q, k, v, qi, ki)]
 
     @jax.jit
@@ -902,16 +920,41 @@ def _check_sparse_kernels(shape, failures, rows=256) -> dict:
         zip(("out", "dq", "dk", "dv", "kl", "dqi", "dki", "dw"), got + got_kl)
     )
     errs = {"selected_pairs_agreeing": round(agreed / pairs, 6)}
+    errs.update({name: float(value) for name, value in hinted.items()})
+    if errs["unequal"] or errs["hint_held"] != 1.0:
+        failures.append(
+            f"sparse {shape}: the hinted selection on the search's own "
+            f"operands: {errs['unequal']} values differ, the hint held in "
+            f"{errs['hint_held']} of the blocks"
+        )
+
+    def seconds(call, *operands, calls=3):
+        jax.block_until_ready(call(*operands))  # compiles
+        start = time.perf_counter()
+        for _ in range(calls):
+            made = call(*operands)
+        jax.block_until_ready(made)
+        return (time.perf_counter() - start) / calls
+
+    errs["select_ms"] = round(1e3 * seconds(
+        jax.jit(lambda *x: sparse_ops.index_select_threshold(*x, topk)),
+        qi, ki, w,
+    ), 3)
+    errs["hinted_ms"] = round(1e3 * seconds(
+        jax.jit(lambda *x: sparse_ops.index_select_hinted(*x, topk)),
+        qi, ki, w, threshold,
+    ), 3)
     if agreed < 0.999 * pairs:
         failures.append(
             f"sparse {shape}: the selection agrees with lax.top_k on "
             f"{agreed} of {pairs} pairs"
         )
     expected = sum(min(t + 1, topk) for t in range(seq)) / seq
-    if float(jnp.mean(kept)) != expected:
-        failures.append(
-            f"sparse {shape}: {float(jnp.mean(kept))} keys a query, not {expected}"
-        )
+    for counted in (float(jnp.mean(kept)), errs["keys_a_query"]):
+        if counted != expected:
+            failures.append(
+                f"sparse {shape}: {counted} keys a query, not {expected}"
+            )
     for part, b in want.items():
         a = jnp.asarray(have[part], jnp.float32)
         b = jnp.asarray(b, jnp.float32)

@@ -228,6 +228,7 @@ class MultiHeadSelfAttention(nn.Module):
         main attention's probabilities detached, so the main model's
         parameters see the language-model loss alone and the indexer's
         their KL alone; the selection passes no gradient."""
+        from elasticdl_tpu.layers import recompute
         from elasticdl_tpu.ops import sparse_attention as sparse_ops
         from elasticdl_tpu.telemetry.router_load import SELECTION_STATS
 
@@ -258,11 +259,31 @@ class MultiHeadSelfAttention(nn.Module):
                     sections=sections,
                 )[..., 0, :]
         with jax.named_scope("index_select"):
-            mask, lse_i, kept, ties = sparse_ops.index_select(
-                qi, ki, weights, self.index_topk, interpret=interpret
-            )
+            # a recomputed pass is handed the threshold its first pass found
+            # and checks it by count instead of searching again
+            threshold = recompute.found()
+            if threshold is None:
+                mask, lse_i, kept, ties, searched, threshold = (
+                    sparse_ops.index_select_threshold(
+                        qi, ki, weights, self.index_topk, interpret=interpret
+                    )
+                )
+                recompute.offer(threshold)
+            else:
+                # (its fifth result is where the hint held; a recomputed
+                # pass's counters leave the layer nowhere)
+                mask, lse_i, kept, ties, searched = (
+                    sparse_ops.index_select_hinted(
+                        qi, ki, weights, threshold, self.index_topk,
+                        interpret=interpret,
+                    )
+                )
             mask_t = sparse_ops.transpose_mask(mask)
-            stats = {"kept_keys": jnp.mean(kept), "ties_broken": jnp.sum(ties)}
+            stats = {
+                "kept_keys": jnp.mean(kept),
+                "ties_broken": jnp.sum(ties),
+                "tie_search_blocks": jnp.mean(searched),
+            }
         # (only an apply that asks for ``intermediates`` keeps the mask and
         # the input it was made from: the chip comparison holds the mask to
         # the reference's set, pair by pair, on that same input)

@@ -31,6 +31,7 @@ from elasticdl_tpu.layers.attention import (
     make_norm,
     sinusoidal_positions,
 )
+from elasticdl_tpu.layers.recompute import remat_with_findings
 from elasticdl_tpu.telemetry.router_load import LOSS_PARTS
 from elasticdl_tpu.trainer.losses import (
     softmax_cross_entropy_with_integer_labels,
@@ -148,7 +149,10 @@ class TransformerLM(nn.Module):
         block_class = TransformerBlock
         if self.remat_layers:
             # (self, x, training, decode_pos): training is a Python bool
-            block_class = nn.remat(TransformerBlock, static_argnums=(2,))
+            # a sparse layer's recomputed pass checks the selection its
+            # first pass found (layers/recompute.py) instead of searching
+            remat = remat_with_findings if self.index_topk else nn.remat
+            block_class = remat(TransformerBlock, static_argnums=(2,))
         sinusoidal = self.positions == "sinusoidal"
         tok_embed = nn.Embed(
             self.vocab_size, self.embed_dim, dtype=self.dtype,

@@ -16,9 +16,14 @@ kernels wants is then a leading index and two tile-aligned dimensions), and
 never a score: :func:`index_select` keeps one block of queries' scores in
 VMEM, finds each row's ``topk``-th value EXACTLY by a radix select over the
 float32 bits (32 counting passes; a tie at the last place goes to the lower
-key index by a second search over the index bits), and writes the mask.
-``lax.top_k`` would sort 16,384 rows of 16,384 scores a layer out of a 1 GB
-array.
+key index by a second search over the index bits, which runs in a block of
+queries that has such a tie), and writes the mask.  ``lax.top_k`` would sort
+16,384 rows of 16,384 scores a layer out of a 1 GB array.  The whole answer
+of the search is two int32 a query (the threshold and the tie cut):
+:func:`index_select_threshold` hands them out and
+:func:`index_select_hinted` checks them by ONE counting pass instead of
+searching again, which is how a recomputed layer gets its mask back
+(``layers/recompute.py``).
 """
 
 from __future__ import annotations
@@ -45,6 +50,7 @@ from elasticdl_tpu.ops.attention import (
 
 # each ``pallas_call``'s name, which the device's op line shows
 INDEX_SELECT = "dsa_index"
+INDEX_SELECT_HINTED = "dsa_index_hinted"  # no ``^dsa_index\b``: read apart
 INDEXER_KL = "dsa_kl"
 
 _INT_MIN = np.int32(-(2**31))
@@ -93,14 +99,36 @@ def _row_sum(x):
     )
 
 
+def _column_row(col):
+    """A lane-replicated column ``(n, <= _LANES)`` as the ``(1, n)`` row an
+    output block of one value a query is."""
+    return jnp.broadcast_to(col[:, 0:1], (col.shape[0], _LANES)).T[0:1]
+
+
 def _index_kernel(
-    qi_ref, ki_ref, w_ref, mask_ref, lse_ref, count_ref, tie_ref, key_scr,
-    *, topk, block_q, block_k, seq,
+    qi_ref, ki_ref, w_ref, *refs, topk, block_q, block_k, seq, hinted,
 ):
     """One (batch, q-block) cell: the block's index scores against every
-    visible key into VMEM as ordered int32 keys, the per-row threshold and
-    tie cut, then the mask, the logsumexp of the selected scores and the two
-    counters a row."""
+    visible key into VMEM as ordered int32 keys, the per-row threshold
+    ``kth`` and tie cut ``cut``, then the mask (``key > kth or (key == kth
+    and s <= cut)``), the logsumexp of the selected scores and the two
+    counters a row.
+
+    A pass of the select runs only where its answer is not known.  The tie
+    search runs in a block that has a tie to cut (some row holds more
+    entries at ``kth`` than it still needs); elsewhere every tied entry is
+    chosen and ``cut`` is the last index.  ``hinted``: the cell is handed a
+    ``(kth, cut)`` a row and counts what it selects; where every row counts
+    exactly ``min(t + 1, topk)`` the hint IS the selection (``k`` entries
+    none of which a left-out entry precedes in the order (score down, index
+    up) are the top ``k``, whatever search found them) and no search runs;
+    where any row counts otherwise the block is searched as if unhinted."""
+    if hinted:
+        kth_in_ref, cut_in_ref, *refs = refs
+    mask_ref, lse_ref, count_ref, tie_ref, flag_ref, *refs = refs
+    if not hinted:
+        kth_ref, cut_ref, *refs = refs
+    key_scr, pick_scr = refs
     i = pl.program_id(1)
     heads = qi_ref.shape[1]
     num_kb = seq // block_k
@@ -109,95 +137,216 @@ def _index_kernel(
     shape = (block_q, block_k)
     rows = row0 + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
     lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
-    row_col = row0 + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, _LANES), 0
-    )
+    # the passes over the keys in VMEM work a group of lanes at a time, so
+    # what a pass accumulates a row stays a column of registers
+    width = min(block_k, _LANES)
+    column = (block_q, width)
+    row_col = row0 + jax.lax.broadcasted_iota(jnp.int32, column, 0)
+    lane_col = jax.lax.broadcasted_iota(jnp.int32, column, 1)
     w_cols = [_row_to_lanes(w_ref[j]) for j in range(heads)]
 
-    def score(jb):
+    def groups(jb):
+        """A key block's lane groups: ``(lanes, first key index)``."""
+        for g in range(block_k // width):
+            yield slice(g * width, (g + 1) * width), jb * block_k + g * width
+
+    def score(jb, top):
         start = pl.multiple_of(jb * block_k, block_k)
         total = _index_scores(
             qi_ref, w_cols, ki_ref[0, pl.ds(start, block_k), :]
         )
-        key_scr[jb] = jnp.where(
-            start + lane <= rows, _ordered(total), _INT_MIN
-        )
-
-    _loop(0, live, score)
-
-    def count(pred):
-        """Per row, over the live blocks: how many entries ``pred(keys,
-        columns)`` holds for, as a lane-replicated int32 column."""
-        def body(jb, acc):
-            return acc + pred(key_scr[jb], jb * block_k + lane).astype(
-                jnp.int32
-            )
-        acc = jax.lax.fori_loop(0, live, body, jnp.zeros(shape, jnp.int32))
-        return _row_sum(acc)
-
-    def wide(col):
-        return _lanes_to(col, block_k)
-
-    # the k-th largest key a row, k = min(t + 1, topk): the largest value
-    # with at least k entries at or above it, built from its top bit down
-    # (keys offset to unsigned order: INT_MIN is 0)
-    want = jnp.minimum(row_col + 1, topk)
-    kth = jnp.full((block_q, _LANES), _INT_MIN, jnp.int32)
-    for bit in range(31, -1, -1):
-        cand = kth ^ _INT_MIN if bit == 31 else kth + np.int32(1 << bit)
-        enough = count(lambda key, col, c=cand: key >= wide(c)) >= want
-        kth = jnp.where(enough, cand, kth)
-    above = count(lambda key, col: key > wide(kth))
-    tied = count(lambda key, col: key == wide(kth))
-    need = want - above  # of the tied entries, from the lowest index up
-    # the largest index d with fewer than ``need`` tied entries before it
-    cut = jnp.zeros((block_q, _LANES), jnp.int32)
-    for bit in range(max(seq - 1, 1).bit_length() - 1, -1, -1):
-        cand = cut + np.int32(1 << bit)
-        before = count(
-            lambda key, col, c=cand: (key == wide(kth)) & (col < wide(c))
-        )
-        cut = jnp.where(before <= need - 1, cand, cut)
+        key = jnp.where(start + lane <= rows, _ordered(total), _INT_MIN)
+        key_scr[jb] = key
+        for lanes, _ in groups(jb):
+            top = jnp.maximum(top, key[:, lanes])
+        return top
 
     top = jax.lax.fori_loop(
-        0, live,
-        lambda jb, m: jnp.maximum(m, key_scr[jb]),
-        jnp.full(shape, _INT_MIN, jnp.int32),
+        0, live, score, jnp.full(column, _INT_MIN, jnp.int32)
     )
-    top = _unordered(
-        jnp.broadcast_to(
-            jnp.max(top, axis=1, keepdims=True), (block_q, _LANES)
+
+    def along(reduce, x):  # a column's lanes reduced, as a column again
+        return jnp.broadcast_to(reduce(x, axis=1, keepdims=True), column)
+
+    top = _unordered(along(jnp.max, top))
+
+    def count(*preds):
+        """Per row, over the live blocks: how many entries each of
+        ``preds(keys, columns)`` holds for, as int32 columns."""
+        def body(jb, accs):
+            for lanes, first in groups(jb):
+                key, col = key_scr[jb, :, lanes], first + lane_col
+                accs = tuple(
+                    acc + pred(key, col).astype(jnp.int32)
+                    for acc, pred in zip(accs, preds)
+                )
+            return accs
+        accs = jax.lax.fori_loop(
+            0, live, body, (jnp.zeros(column, jnp.int32),) * len(preds)
         )
-    )
+        return [along(jnp.sum, acc) for acc in accs]
+
+    want = jnp.minimum(row_col + 1, topk)
+
+    def selects(key, col, kth, cut):
+        """Where ``(kth, cut)`` keeps a visible key."""
+        return (col <= row_col) & (
+            (key > kth) | ((key == kth) & (col <= cut))
+        )
+
+    def search():
+        """``pick_scr``: the rows' ``kth``, ``cut`` and whether a tie was
+        cut.  Returns whether the block's tie search ran."""
+        # the k-th largest key a row, k = min(t + 1, topk): the largest
+        # value with at least k entries at or above it, built from its top
+        # bit down (keys offset to unsigned order: INT_MIN is 0)
+        kth = jnp.full(column, _INT_MIN, jnp.int32)
+        for bit in range(31, -1, -1):
+            cand = kth ^ _INT_MIN if bit == 31 else kth + np.int32(1 << bit)
+            (enough,) = count(lambda key, col, c=cand: key >= c)
+            kth = jnp.where(enough >= want, cand, kth)
+        above, tied = count(
+            lambda key, col: key > kth, lambda key, col: key == kth
+        )
+        need = want - above  # of the tied entries, from the lowest index up
+        pick_scr[0] = kth
+        pick_scr[1] = jnp.full(column, seq - 1, jnp.int32)
+        pick_scr[2] = (tied > need).astype(jnp.int32)
+        cuts = jnp.max(tied - need) > 0
+
+        @pl.when(cuts)
+        def _cut():
+            # the largest index d with fewer than ``need`` tied entries
+            # before it
+            cut = jnp.zeros(column, jnp.int32)
+            for bit in range(max(seq - 1, 1).bit_length() - 1, -1, -1):
+                cand = cut + np.int32(1 << bit)
+                (before,) = count(
+                    lambda key, col, c=cand: (key == kth) & (col < c)
+                )
+                cut = jnp.where(before <= need - 1, cand, cut)
+            pick_scr[1] = cut
+
+        return cuts
+
+    if hinted:
+        kth, cut = (
+            _row_to_lanes(ref[0])[:, :width] for ref in (kth_in_ref, cut_in_ref)
+        )
+        chosen, beyond = count(
+            lambda key, col: selects(key, col, kth, cut),
+            lambda key, col: (col <= row_col) & (key == kth) & (col > cut),
+        )
+        pick_scr[0] = kth
+        pick_scr[1] = cut
+        pick_scr[2] = (beyond > 0).astype(jnp.int32)
+        flag = jnp.min((chosen == want).astype(jnp.int32)) > 0  # it held
+
+        @pl.when(jnp.logical_not(flag))
+        def _fall_back():
+            search()
+    else:
+        flag = search()
+    kth, cut = pick_scr[0], pick_scr[1]
 
     def write(jb, carry):
-        total, chosen_rows = carry
-        col = jb * block_k + lane
-        key = key_scr[jb]
-        chosen = (col <= rows) & (
-            (key > wide(kth)) | ((key == wide(kth)) & (col <= wide(cut)))
-        )
-        mask_ref[0, jb] = jnp.where(chosen, 1, 0).astype(mask_ref.dtype)
-        total = total + jnp.where(
-            chosen, jnp.exp(_unordered(key) - wide(top)), 0.0
-        )
-        return total, chosen_rows + chosen.astype(jnp.int32)
+        total, kept = carry
+        for lanes, first in groups(jb):
+            key, col = key_scr[jb, :, lanes], first + lane_col
+            chosen = selects(key, col, kth, cut)
+            mask_ref[0, jb, :, lanes] = jnp.where(chosen, 1, 0).astype(
+                mask_ref.dtype
+            )
+            total = total + jnp.where(
+                chosen, jnp.exp(_unordered(key) - top), 0.0
+            )
+            kept = kept + chosen.astype(jnp.int32)
+        return total, kept
 
-    total, chosen_rows = jax.lax.fori_loop(
+    total, kept = jax.lax.fori_loop(
         0, live, write,
-        (jnp.zeros(shape, jnp.float32), jnp.zeros(shape, jnp.int32)),
+        (jnp.zeros(column, jnp.float32), jnp.zeros(column, jnp.int32)),
     )
 
     def blank(jb):
         mask_ref[0, jb] = jnp.zeros(shape, mask_ref.dtype)
 
     _loop(live, num_kb, blank)
-    lse_ref[0] = (top + jnp.log(_row_sum(total))).T[0:1]
-    count_ref[0] = _row_sum(chosen_rows).astype(jnp.float32).T[0:1]
-    tie_ref[0] = jnp.where(tied > need, 1.0, 0.0).T[0:1]
+
+    lse_ref[0] = _column_row(top + jnp.log(along(jnp.sum, total)))
+    count_ref[0] = _column_row(along(jnp.sum, kept).astype(jnp.float32))
+    tie_ref[0] = _column_row(pick_scr[2].astype(jnp.float32))
+    flag_ref[0] = jnp.full((1, block_q), flag.astype(jnp.float32))
+    if not hinted:
+        kth_ref[0] = _column_row(kth)
+        cut_ref[0] = _column_row(cut)
 
 
-@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6), inline=True)
+def _index_call(qi, ki, w, topk, block_k, block_q, interpret, threshold=None):
+    """``dsa_index``, or ``dsa_index_hinted`` where a ``threshold`` ``(kth,
+    cut)`` is handed in: ``(mask, lse, kept, ties, flag)`` and, unhinted,
+    ``(kth, cut)``."""
+    qi, ki, w = (
+        jax.lax.stop_gradient(jnp.asarray(x)) for x in (qi, ki, w)
+    )
+    batch, seq, heads, width = qi.shape
+    if interpret is None:
+        interpret = kernel_interpret(jax.default_backend())
+    block_k = _pick_block(seq, block_k)
+    block_q = _pick_block(seq, block_q)
+    num_kb = seq // block_k
+    hinted = threshold is not None
+    rows = jax.ShapeDtypeStruct((batch, 1, seq), jnp.float32)
+    picks = jax.ShapeDtypeStruct((batch, 1, seq), jnp.int32)
+    row_spec = pl.BlockSpec((1, 1, block_q), lambda b, i: (b, 0, i))
+    made = pl.pallas_call(
+        functools.partial(
+            _index_kernel, topk=topk, block_q=block_q, block_k=block_k,
+            seq=seq, hinted=hinted,
+        ),
+        grid=(batch, seq // block_q),
+        in_specs=[
+            pl.BlockSpec(
+                (1, heads, block_q, width), lambda b, i: (b, 0, i, 0)
+            ),
+            pl.BlockSpec((1, seq, width), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((heads, 1, block_q), lambda b, i: (b, 0, i)),
+        ] + [row_spec] * (2 * hinted),
+        out_specs=[
+            pl.BlockSpec(
+                (1, num_kb, block_q, block_k), lambda b, i: (b, 0, i, 0)
+            ),
+        ] + [row_spec] * (4 if hinted else 6),
+        out_shape=[
+            jax.ShapeDtypeStruct((batch, num_kb, seq, block_k), jnp.int8),
+            rows, rows, rows, rows,
+        ] + [picks] * (0 if hinted else 2),
+        scratch_shapes=[
+            pltpu.VMEM((num_kb, block_q, block_k), jnp.int32),
+            pltpu.VMEM((3, block_q, min(block_k, _LANES)), jnp.int32),
+        ],
+        compiler_params=_SEQUENTIAL,
+        interpret=interpret,
+        name=INDEX_SELECT_HINTED if hinted else INDEX_SELECT,
+    )(
+        qi.transpose(0, 2, 1, 3),
+        ki,
+        w.astype(jnp.float32).transpose(0, 2, 1).reshape(
+            batch * heads, 1, seq
+        ),
+        *(
+            jax.lax.stop_gradient(x).astype(jnp.int32)[:, None, :]
+            for x in (threshold or ())
+        ),
+    )
+    mask, *per_row = made
+    return (mask, *(x[:, 0] for x in per_row))
+
+
+_STATIC = dict(static_argnums=(3, 4, 5, 6), inline=True)
+
+
+@functools.partial(jax.jit, **_STATIC)
 def index_select(
     qi, ki, w, topk: int, block_k: int = 512, block_q: int = 128,
     interpret: bool | None = None,
@@ -211,52 +360,39 @@ def index_select(
     logsumexp of a query's selected scores, and per query the keys it kept
     and whether a tie was broken at the last place (float32 0 / 1).
     No gradient passes through any of them."""
-    qi, ki, w = (
-        jax.lax.stop_gradient(jnp.asarray(x)) for x in (qi, ki, w)
+    return _index_call(qi, ki, w, topk, block_k, block_q, interpret)[:4]
+
+
+@functools.partial(jax.jit, **_STATIC)
+def index_select_threshold(
+    qi, ki, w, topk: int, block_k: int = 512, block_q: int = 128,
+    interpret: bool | None = None,
+):
+    """:func:`index_select` with what its search found: ``(mask, lse, kept,
+    ties, searched, (kth, cut))``.  ``searched`` ``(batch, seq)`` is 1.0 for
+    the queries of a block whose tie search ran; ``kth`` and ``cut`` are
+    int32 a query, the whole answer of the select (a key is kept where its
+    ordered score is above ``kth``, or at it with an index up to ``cut``):
+    what :func:`index_select_hinted` takes."""
+    *made, kth, cut = _index_call(qi, ki, w, topk, block_k, block_q, interpret)
+    return (*made, (kth, cut))
+
+
+@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7), inline=True)
+def index_select_hinted(
+    qi, ki, w, threshold, topk: int, block_k: int = 512, block_q: int = 128,
+    interpret: bool | None = None,
+):
+    """:func:`index_select`'s ``(mask, lse, kept, ties)`` and ``held``
+    ``(batch, seq)``, from a ``threshold`` an earlier call on (nearly) the
+    same operands found (``dsa_index_hinted``): a block of queries whose
+    every row the threshold selects exactly ``min(t + 1, topk)`` keys for is
+    written from it, ``held`` 1.0, after one counting pass; any other block
+    is searched as :func:`index_select` searches it, ``held`` 0.0.  Either
+    way the result is the exact selection of THESE operands' scores."""
+    return _index_call(
+        qi, ki, w, topk, block_k, block_q, interpret, tuple(threshold)
     )
-    batch, seq, heads, width = qi.shape
-    if interpret is None:
-        interpret = kernel_interpret(jax.default_backend())
-    block_k = _pick_block(seq, block_k)
-    block_q = _pick_block(seq, block_q)
-    num_kb = seq // block_k
-    rows = jax.ShapeDtypeStruct((batch, 1, seq), jnp.float32)
-    row_spec = pl.BlockSpec((1, 1, block_q), lambda b, i: (b, 0, i))
-    mask, lse, kept, ties = pl.pallas_call(
-        functools.partial(
-            _index_kernel, topk=topk, block_q=block_q, block_k=block_k,
-            seq=seq,
-        ),
-        grid=(batch, seq // block_q),
-        in_specs=[
-            pl.BlockSpec(
-                (1, heads, block_q, width), lambda b, i: (b, 0, i, 0)
-            ),
-            pl.BlockSpec((1, seq, width), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((heads, 1, block_q), lambda b, i: (b, 0, i)),
-        ],
-        out_specs=[
-            pl.BlockSpec(
-                (1, num_kb, block_q, block_k), lambda b, i: (b, 0, i, 0)
-            ),
-            row_spec, row_spec, row_spec,
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((batch, num_kb, seq, block_k), jnp.int8),
-            rows, rows, rows,
-        ],
-        scratch_shapes=[pltpu.VMEM((num_kb, block_q, block_k), jnp.int32)],
-        compiler_params=_SEQUENTIAL,
-        interpret=interpret,
-        name=INDEX_SELECT,
-    )(
-        qi.transpose(0, 2, 1, 3),
-        ki,
-        w.astype(jnp.float32).transpose(0, 2, 1).reshape(
-            batch * heads, 1, seq
-        ),
-    )
-    return mask, lse[:, 0], kept[:, 0], ties[:, 0]
 
 
 def transpose_mask(mask, block_q: int = 512):

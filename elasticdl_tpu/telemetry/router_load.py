@@ -28,8 +28,10 @@ ROUTER_STATS = "router_stats"
 LOSS_PARTS = "loss_parts"
 # the collection a sparse-attention layer (``layers/attention.py``) sows
 # what its selection did into, a scalar a name and layer: the selected keys
-# a query (``kept_keys``, a mean over batch and queries) and the queries at
-# which a tie was broken at the last place (``ties_broken``)
+# a query (``kept_keys``, a mean over batch and queries), the queries at
+# which a tie was broken at the last place (``ties_broken``) and the share of
+# query blocks whose tie search ran (``tie_search_blocks``: the blocks that
+# held such a query)
 SELECTION_STATS = "selection_stats"
 
 _watched = None
@@ -61,8 +63,9 @@ def read_loss_parts(model_state=None) -> dict | None:
 
 def read_selection(model_state=None) -> dict | None:
     """The newest step's selection by layer, one host readback:
-    ``{"kept_keys": [a layer ...], "ties_broken": [...]}`` in the order of
-    the layers' names.  None for a model without sparse attention."""
+    ``{"kept_keys": [a layer ...], "ties_broken": [...],
+    "tie_search_blocks": [...]}`` in the order of the layers' names.  None
+    for a model without sparse attention."""
     stats = (_state(model_state) or {}).get(SELECTION_STATS)
     if not stats:
         return None

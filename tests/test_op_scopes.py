@@ -40,7 +40,8 @@ KERNELS = {
     mamba_passes.GATE_NORM_FWD, mamba_passes.GATE_NORM_BWD,
     mamba_passes.MAMBA_CONV_FWD, mamba_passes.MAMBA_CONV_BWD,
     attention_ops.SELECTED_FWD, attention_ops.SELECTED_DQ,
-    attention_ops.SELECTED_DKV, sparse_ops.INDEX_SELECT, sparse_ops.INDEXER_KL,
+    attention_ops.SELECTED_DKV, sparse_ops.INDEX_SELECT,
+    sparse_ops.INDEX_SELECT_HINTED, sparse_ops.INDEXER_KL,
 }
 # modules that hold other modules: an op directly under one of these is in
 # a region nobody named
@@ -588,8 +589,12 @@ def test_sparse_attentions_kernels_are_under_their_own_parts(one_chip_mesh):
         if kind == "kernel":
             found.setdefault(name.split(".")[0], set()).add((part, phase))
     twice = {"forward", "recompute"}
+    # the first pass searches; the recomputed one checks what it found
     assert found[sparse_ops.INDEX_SELECT] == {
-        ("block/attn/index_select/dsa_index", phase) for phase in twice
+        ("block/attn/index_select/dsa_index", "forward")
+    }
+    assert found[sparse_ops.INDEX_SELECT_HINTED] == {
+        ("block/attn/index_select/dsa_index_hinted", "recompute")
     }
     # its value in the first pass, its gradients in the backward pass; the
     # recomputed pass needs nothing of the value call and does not run it
@@ -605,7 +610,19 @@ def test_sparse_attentions_kernels_are_under_their_own_parts(one_chip_mesh):
     # as perf/ reads a trace: an op's self time by its name and by its scope
     ours = {name: 1.0 for name, (_, _, kind, _) in scopes.items() if kind == "kernel"
             and name.startswith("dsa_")}
+    # eight calls a layer as before (the cell's four layers: 4 ``dsa_index``,
+    # 4 ``dsa_index_hinted``, 176 kernel calls with the experts', PERF.md):
+    # one search, one check, and the first pass of the backward rule's own
+    # ``jax.checkpoint`` left no kernel behind
     assert len(ours) == 8
+    assert sorted(name.split(".")[0] for name in ours) == [
+        "dsa_dkv", "dsa_dq", "dsa_fwd", "dsa_fwd", "dsa_index",
+        "dsa_index_hinted", "dsa_kl", "dsa_kl",
+    ]
+    # ``perf/kernel_rooflines.py::kernel_seconds`` reads the searching calls alone
+    assert trace_reduce.matching_seconds(
+        {"op_self_s": ours, "details": {}}, r"^dsa_index\b"
+    ) == 1.0
     reduced = {"op_self_s": ours, "details": {}}
     assert trace_reduce.matching_seconds(reduced, layer_readers.FLASH_KERNELS) == 0
     run = {
@@ -616,6 +633,35 @@ def test_sparse_attentions_kernels_are_under_their_own_parts(one_chip_mesh):
     assert dsa_rooflines.selection_time_share(run) == pytest.approx(100 * 2 / 16)
     assert dsa_rooflines.indexer_time_share(run) == pytest.approx(100 * 2 / 16)
     assert dsa_rooflines.sparse_attention_time_share(run) == pytest.approx(50.0)
+
+
+@pytest.mark.parametrize("config", ["tiny_nemotron", "tiny_joyai"])
+def test_a_recomputed_layer_without_a_selection_is_nn_remats(config, monkeypatch):
+    """``layers/recompute.py`` is a sparse layer's alone: a recomputed model
+    that sets no ``index_topk`` never reaches it and lowers to the text it
+    lowers to with ``nn.remat`` in its place."""
+    from elasticdl_tpu.layers import recompute
+
+    def lowered():
+        model, loss, tx, features, labels, remat = _lm_family(config)
+        assert remat
+        variables = model.init(jax.random.PRNGKey(0), features, training=False)
+        state = TrainState.create(
+            model.apply, variables["params"], tx,
+            {k: v for k, v in variables.items() if k != "params"},
+        )
+        return build_train_step(loss, donate=False).lower(
+            state, features, labels, np.ones((labels.shape[0],), np.float32)
+        ).as_text()
+
+    def never(*args, **kwargs):
+        raise AssertionError("a layer without a selection took the hinted remat")
+
+    ours = lowered()
+    monkeypatch.setattr(lm, "remat_with_findings", never)
+    monkeypatch.setattr(recompute, "_lifted", never)
+    assert lowered() == ours
+    assert "checkpoint" in ours or "remat" in ours or "optimization_barrier" in ours
 
 
 # ---- the trainer hands out the programs it dispatched ----------------------------

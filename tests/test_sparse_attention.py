@@ -143,3 +143,207 @@ def test_a_sparse_layer_is_causal_training_only():
     )
     with pytest.raises(NotImplementedError, match="sparse"):
         layer.init(jax.random.PRNGKey(0), jnp.zeros((1, 1, 32)), decode_pos=0)
+
+
+# ---- a pass of the select runs only where its answer is not known --------------
+
+
+def tied_operands(seq, heads=2, width=8, seed=0):
+    """Every key one of three vectors: whole runs of exact ties, across the
+    last place for nearly every query (``tests/perf/test_perf_keye.py``)."""
+    rng = np.random.default_rng(seed)
+    qi = jnp.asarray(rng.normal(size=(1, seq, heads, width)), jnp.float32)
+    three = rng.normal(size=(3, width))
+    ki = jnp.asarray(three[rng.integers(3, size=seq)][None], jnp.float32)
+    w = jnp.asarray(np.abs(rng.normal(size=(1, seq, heads))), jnp.float32)
+    return qi, ki, w
+
+
+def zeros_operands(seq, heads=2, width=8, seed=0):
+    """Runs of exact zeros (every head's ReLU shut) across the last place:
+    queries that point away from most keys."""
+    rng = np.random.default_rng(seed)
+    ki = np.abs(rng.normal(size=(1, seq, width)))
+    qi = -np.abs(rng.normal(size=(1, seq, heads, width)))
+    open_ = rng.random(size=(1, seq)) < 0.1  # a tenth of the keys score
+    ki = np.where(open_[..., None], -ki, ki)
+    w = np.abs(rng.normal(size=(1, seq, heads)))
+    return tuple(jnp.asarray(x, jnp.float32) for x in (qi, ki, w))
+
+
+def random_operands(seq, seed=0):
+    return operands(seq, seed)[3:]
+
+
+CASES = {
+    "random": (random_operands, 256, 48, 128),
+    "random_three_blocks": (random_operands, 384, 96, 128),
+    "tied_keys": (tied_operands, 256, 8, 128),
+    "exact_zeros": (zeros_operands, 256, 64, 128),
+    "fewer_keys_than_topk": (random_operands, 128, 512, 128),
+    "one_narrow_block": (random_operands, 64, 8, 64),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_the_hinted_selection_is_the_selection(case):
+    """``index_select_hinted`` on the threshold ``index_select_threshold``
+    found and the same operands: the mask on every pair, ``lse`` and both
+    counters equal, every block held; and both are ``lax.top_k``'s set."""
+    make, seq, topk, block = CASES[case]
+    qi, ki, w = make(seq)
+    mask, lse, kept, ties, _, threshold = sparse_ops.index_select_threshold(
+        qi, ki, w, topk, block, block
+    )
+    for got, want in zip(
+        sparse_ops.index_select(qi, ki, w, topk, block, block),
+        (mask, lse, kept, ties),
+    ):
+        np.testing.assert_array_equal(got, want)
+    again = sparse_ops.index_select_hinted(
+        qi, ki, w, threshold, topk, block, block
+    )
+    for got, want in zip(again, (mask, lse, kept, ties)):
+        np.testing.assert_array_equal(got, want)
+    np.testing.assert_array_equal(again[4], 1.0)
+    chosen = sparse_ops.select_reference(
+        sparse_ops.index_scores_reference(qi, ki, w), topk
+    )
+    np.testing.assert_array_equal(sparse_ops.dense_mask(again[0]), chosen)
+    np.testing.assert_array_equal(
+        kept, np.broadcast_to(np.minimum(np.arange(seq) + 1, topk), kept.shape)
+    )
+
+
+def _one_ulp_up(threshold, qi, ki, w):
+    kth, cut = threshold
+    return (kth + 1, cut), (qi, ki, w)
+
+
+def _cut_one_short(threshold, qi, ki, w):
+    kth, cut = threshold
+    return (kth, cut - 1), (qi, ki, w)
+
+
+def _one_bf16_bit(threshold, qi, ki, w):
+    # the last place of one key's bfloat16 numbers, as a recomputation
+    # that rounded differently would hand it over
+    moved = ki.astype(jnp.bfloat16)
+    bits = jax.lax.bitcast_convert_type(moved[:, 5], jnp.uint16) ^ 1
+    moved = moved.at[:, 5].set(jax.lax.bitcast_convert_type(bits, jnp.bfloat16))
+    return threshold, (qi, moved.astype(ki.dtype), w)
+
+
+@pytest.mark.parametrize(
+    "spoil", [_one_ulp_up, _cut_one_short, _one_bf16_bit],
+    ids=["threshold_one_ulp_up", "cut_one_short", "operand_one_bf16_bit"],
+)
+@pytest.mark.parametrize("case", ["random", "tied_keys"])
+def test_a_wrong_hint_falls_back_to_the_search(case, spoil):
+    """A hint that does not select exactly ``min(t + 1, topk)`` keys in some
+    row of a block sends that block through the search: the result is the
+    exact selection of the operands in front of the kernel either way."""
+    make, seq, topk, block = CASES[case]
+    qi, ki, w = make(seq)
+    if spoil is _one_bf16_bit:  # operands a bfloat16 layer would hand over
+        qi, ki = (x.astype(jnp.bfloat16).astype(x.dtype) for x in (qi, ki))
+    threshold = sparse_ops.index_select_threshold(qi, ki, w, topk, block, block)[5]
+    threshold, moved = spoil(threshold, qi, ki, w)
+    mask, lse, kept, ties, held = sparse_ops.index_select_hinted(
+        *moved, threshold, topk, block, block
+    )
+    want = sparse_ops.index_select(*moved, topk, block, block)
+    for got, wanted in zip((mask, lse, kept, ties), want):
+        np.testing.assert_array_equal(got, wanted)
+    np.testing.assert_array_equal(
+        sparse_ops.dense_mask(mask),
+        sparse_ops.select_reference(
+            sparse_ops.index_scores_reference(*moved), topk
+        ),
+    )
+    assert float(jnp.mean(held)) < 1.0  # some block fell back
+
+
+def test_the_tie_search_runs_in_the_blocks_that_have_a_tie_to_cut():
+    """``searched`` is 1.0 for the queries of a block whose tie search ran:
+    none without a tie at the last place, and exactly the blocks that hold a
+    query with one (``ties``) otherwise."""
+    seq, block = 512, 128
+    # distinct scores: one head of weight 1 against keys that grow
+    qi = jnp.ones((1, seq, 1, 8), jnp.float32)
+    ki = jnp.broadcast_to(jnp.arange(1.0, seq + 1)[None, :, None], (1, seq, 8))
+    w = jnp.ones((1, seq, 1), jnp.float32)
+    made = sparse_ops.index_select_threshold(qi, ki, w, 16, block, block)
+    assert float(jnp.sum(made[3])) == 0 and float(jnp.sum(made[4])) == 0
+    # one run of equal keys that only the third block's queries must cut
+    # (later queries see 16 larger keys; earlier ones keep all they see)
+    ki = ki.at[:, 256:300].set(ki[:, 256])
+    _, _, _, ties, searched, _ = sparse_ops.index_select_threshold(
+        qi, ki, w, 16, block, block
+    )
+    by_block = np.asarray(ties).reshape(seq // block, block).sum(axis=1) > 0
+    assert by_block.tolist() == [False, False, True, False]
+    np.testing.assert_array_equal(
+        np.asarray(searched).reshape(seq // block, block),
+        np.broadcast_to(by_block[:, None], (seq // block, block)).astype(np.float32),
+    )
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_a_recomputed_sparse_layer_is_the_layer(dtype, monkeypatch):
+    """A sparse model's loss, its parts, its counters and every gradient
+    under ``remat_layers`` (the recomputed pass checks the first pass's
+    threshold, ``layers/recompute.py``) against what ``nn.remat``, which
+    searches again, gives, and the same model's without recomputation: all
+    three equal in float32; in bfloat16 as close as two programs XLA fuses
+    its own ways come (``nn.remat``'s gradients differ from the plain
+    model's by 5% of the largest, and ours read a layer's input as stored,
+    where XLA may hand ``nn.remat``'s first pass the unrounded sum)."""
+    import flax.linen as nn
+
+    from elasticdl_tpu.models import long_seq_transformer as zoo
+
+    fields = dict(
+        vocab_size=64, embed_dim=32, num_heads=4, num_kv_heads=2, head_dim=8,
+        num_layers=2, dtype=dtype, norm="rmsnorm", use_bias=False,
+        positions="rope", mrope_section=(1, 2, 1), qk_norm_per_head=True,
+        index_topk=8, index_heads=2, index_head_dim=8, mlp="swiglu",
+    )
+    tokens = np.random.default_rng(0).integers(64, size=(2, 64)).astype(np.int32)
+    features = {"tokens": tokens}
+
+    def run(remat):
+        model = zoo.custom_model(remat_layers=remat, **fields)
+        variables = model.init(jax.random.PRNGKey(0), features, training=False)
+        state = {k: v for k, v in variables.items() if k != "params"}
+
+        def loss(params):
+            logits, new = model.apply(
+                {"params": params, **state}, features, training=True,
+                mutable=list(state) + ["losses"],
+            )
+            sown = sum(jax.tree_util.tree_leaves(new.pop("losses")))
+            return zoo.loss(tokens, logits) + sown, new
+
+        (value, new), grads = jax.jit(
+            jax.value_and_grad(loss, has_aux=True)
+        )(variables["params"])
+        return jax.tree_util.tree_leaves((value, new, grads)), new, grads
+
+    ours, new, grads = run(True)
+    monkeypatch.setattr(zoo, "remat_with_findings", nn.remat)
+    searched_again, _, _ = run(True)
+    for ours_leaf, want in zip(ours, searched_again):
+        if dtype == "float32":
+            np.testing.assert_array_equal(ours_leaf, want)
+        else:
+            np.testing.assert_allclose(
+                ours_leaf, want, rtol=0.05,
+                atol=0.1 * float(jnp.max(jnp.abs(want))) + 1e-6,
+            )
+    if dtype == "float32":
+        for ours_leaf, want in zip(ours, run(False)[0]):
+            np.testing.assert_array_equal(ours_leaf, want)
+    stats = new["selection_stats"]["block_0"]["attn"]
+    assert set(stats) == {"kept_keys", "ties_broken", "tie_search_blocks"}
+    assert np.any(grads["block_0"]["attn"]["index_query"]["kernel"])
