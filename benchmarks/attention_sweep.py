@@ -46,6 +46,16 @@ ran and of blocks whose hint held, which must be all; exit 1 otherwise;
 
     python benchmarks/attention_sweep.py --shape 1,16384,32/4,128 --topk 2048
 
+``--window N`` times the WINDOW kernels alone (``swa_fwd``, ``swa_dq``,
+``swa_dkv``: the same three where a query reads its last ``N`` keys,
+``flash_attention(..., window=N)``), counts the FLOPs of the pairs inside
+the window, as ``perf/window_rooflines.py`` does, and prints the block plan
+beside the times (``blocks``: ``flash_block_plan``'s visited, masked and
+skipped a head at the geometry's blocks), so that the window against the
+dense kernels at one shape is two calls whose ratio the plan predicts:
+
+    python benchmarks/attention_sweep.py --shape 1,16384,32/4,128 --window 2048
+
 Exits 3 where JAX finds no TPU: a time from the CPU is not a kernel time.
 """
 
@@ -67,6 +77,7 @@ sys.path.insert(
 
 KERNELS = ("flash_fwd", "flash_dq", "flash_dkv")
 SELECTED_KERNELS = ("dsa_fwd", "dsa_dq", "dsa_dkv")
+WINDOW_KERNELS = ("swa_fwd", "swa_dq", "swa_dkv")
 # (matched by substring in this order: the hinted name holds the other)
 SELECTION_KERNELS = ("dsa_index_hinted", "dsa_index")
 
@@ -123,7 +134,8 @@ def flash_layout_of(module, q, k, v) -> str:
 
 
 def time_geometry(
-    module, geometry, shape, dtype, causal, calls, kv_heads=0, topk=0
+    module, geometry, shape, dtype, causal, calls, kv_heads=0, topk=0,
+    window=0,
 ):
     import jax
     import jax.numpy as jnp
@@ -149,14 +161,14 @@ def time_geometry(
             lambda qi, ki, wi: sparse_ops.index_select(qi, ki, wi, topk)[0]
         )(qi, ki, wi)
         masks = (mask, sparse_ops.transpose_mask(mask))
-    kw = {}
+    kw = {"window": window} if window else {}
     chunk_name = (
         "_CHUNK_BYTES" if hasattr(module, "_CHUNK_BYTES") else "_SEQ_CHUNK"
     )
     module_chunk = getattr(module, chunk_name)
     if geometry is not None:
         block_q, block_k, chunk = geometry
-        kw = dict(block_q=block_q, block_k=block_k)
+        kw.update(block_q=block_q, block_k=block_k)
         if chunk_name == "_CHUNK_BYTES":
             # rows to bytes, as ``_flash_geometry`` turns them back
             chunk *= min(shape[-2:]) * dtype.itemsize
@@ -181,12 +193,18 @@ def time_geometry(
                 out = step(q, k, v)
             jax.block_until_ready(out)
         return (
-            kernel_ms(trace_dir, calls, SELECTED_KERNELS if topk else KERNELS),
+            kernel_ms(trace_dir, calls, kernels_of(topk, window)),
             flash_layout_of(module, q, k, v),
         )
     finally:
         shutil.rmtree(trace_dir, ignore_errors=True)
         setattr(module, chunk_name, module_chunk)
+
+
+def kernels_of(topk: int, window: int) -> tuple:
+    if topk:
+        return SELECTED_KERNELS
+    return WINDOW_KERNELS if window else KERNELS
 
 
 def indexer_operands(keys, batch, seq, dtype):
@@ -261,6 +279,10 @@ def main() -> int:
         "--topk", type=int, default=0,
         help="> 0: the selected-set kernels over a seeded indexer's set",
     )
+    parser.add_argument(
+        "--window", type=int, default=0,
+        help="> 0: the window kernels, a query reading its last N keys",
+    )
     parser.add_argument("--dtype", default="bfloat16")
     parser.add_argument(
         "--sweep", default="",
@@ -302,7 +324,13 @@ def main() -> int:
         kept = min(args.topk, seq)
         pairs = kept * (kept + 1) // 2 + (seq - kept) * kept
         kernel_flops = 2 * batch * heads * pairs * (d_qk + d_v)
-    kernels = SELECTED_KERNELS if args.topk else KERNELS
+    if args.window and (args.topk or not causal):
+        parser.error("--window goes with causal attention and without --topk")
+    window = args.window if args.window < seq else 0  # no window: the dense kernels
+    if window:  # the pairs inside the window, sum_t min(t + 1, window)
+        pairs = window * (window + 1) // 2 + (seq - window) * window
+        kernel_flops = 2 * batch * heads * pairs * (d_qk + d_v)
+    kernels = kernels_of(args.topk, window)
     module = load_impl(args.impl)
     geometries = [
         tuple(int(x) for x in g.split(","))
@@ -339,6 +367,7 @@ def main() -> int:
             "shape": list(shape),
             "kv_heads": kv_heads,
             "topk": args.topk,
+            "window": window,
             "dtype": args.dtype,
             "causal": causal,
             "geometry": geometry,
@@ -347,8 +376,16 @@ def main() -> int:
         try:
             ms, line["flash_layout"] = time_geometry(
                 module, geometry, shape, jnp.dtype(args.dtype), causal,
-                args.calls, kv_heads, args.topk,
+                args.calls, kv_heads, args.topk, window,
             )
+            if hasattr(module, "flash_block_plan") and not args.topk:
+                blocks = tuple(
+                    module._pick_block(seq, (geometry or (512, 512))[i])
+                    for i in (0, 1)
+                )
+                line["blocks"] = module.flash_block_plan(
+                    seq, seq, *blocks, causal, *((window,) if window else ())
+                )
         except Exception as ex:  # noqa: BLE001 — Mosaic refuses a geometry
             line["error"] = f"{type(ex).__name__}: {str(ex)[:300]}"
         else:

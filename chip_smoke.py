@@ -107,8 +107,8 @@ SIZES = {
         # tasks are still unleased when the worker dies
         kill_run_steps=48,
         kill_step=6,
-        # (B, S, H, D), (B, S, H, D of q and k, D of v), or that with the
-        # key/value heads: the smoke model's shape, then the one each LM
+        # (B, S, H, D), (B, S, H, D of q and k, D of v), that with the
+        # key/value heads, or that with a window: the smoke model's shape, then the one each LM
         # cell of the benchmark hands the kernels, whole (the float32
         # reference is taken a few query heads at a time).  Heads read out
         # of the layer's own layout ("lanes") at width 64, the folded form
@@ -121,6 +121,11 @@ SIZES = {
             (1, 8192, 32, 128, 128, 2),
             # latent attention's two widths: scores of 192, values of 128
             (1, 8192, 32, 192, 128),
+            # trinity_mini_seq16384: the dense kernels at 16,384 tokens (the
+            # full layer; past 1 MiB an array they stream chunks) and the
+            # window kernels at a window of 2,048
+            (1, 16384, 32, 128, 128, 4),
+            (1, 16384, 32, 128, 128, 4, 2048),
         ),
         # sparse attention at its cell's shape (keye_vl2_seq16384): batch,
         # tokens, heads, width, key/value heads, the indexer's heads and
@@ -148,6 +153,7 @@ SIZES = {
             (1, 512, 2, 64),
             (1, 256, 4, 128, 128, 2),
             (1, 256, 2, 48, 32),
+            (1, 256, 4, 128, 128, 2, 80),
         ),
         sparse_shape=(1, 256, 4, 32, 2, 2, 16, 48),
     ),
@@ -688,14 +694,7 @@ def _child_kernel(run, cfg, workdir):
             )
         )
 
-    def reference(q, k, v):
-        with jax.default_matmul_precision("highest"):
-            return mha_reference(q, k, v, causal=True)
-
-    def flash(q, k, v):
-        return flash_attention(q, k, v, causal=True)
-
-    def reference_by_heads(q, k, v, w):
+    def reference_by_heads(reference, q, k, v, w):
         """The float32 reference's output and gradients, a few query heads
         of one key/value head at a time: it holds a few (B, heads, S, S)
         float32 arrays, which ``limit`` keeps to 1 GiB each."""
@@ -732,6 +731,18 @@ def _child_kernel(run, cfg, workdir):
         batch, seq, heads, d = shape[:4]
         d_v = shape[4] if len(shape) > 4 else d
         kv_heads = shape[5] if len(shape) > 5 else heads
+        # the shape's window (None: every earlier key).  Both functions are
+        # made anew a shape: ``jax.jit`` keeps a function's trace by the
+        # operands' shapes, and two shapes differ in the window alone
+        window = shape[6] if len(shape) > 6 else None
+
+        def reference(q, k, v, window=window):
+            with jax.default_matmul_precision("highest"):
+                return mha_reference(q, k, v, causal=True, window=window)
+
+        def flash(q, k, v, window=window):
+            return flash_attention(q, k, v, causal=True, window=window)
+
         q, k, v = (
             jax.random.normal(key, dims, jnp.float32).astype(jnp.bfloat16)
             for key, dims in zip(
@@ -755,7 +766,7 @@ def _child_kernel(run, cfg, workdir):
             lowered.compile()(q, k, v),
             *with_gradients(flash)(q, k, v, w)[1:],
         )
-        want = reference_by_heads(q, k, v, w)
+        want = reference_by_heads(reference, q, k, v, w)
         errs = {}
         for part, a, b in zip(("out", "dq", "dk", "dv"), got, want):
             a = jnp.asarray(a, jnp.float32)

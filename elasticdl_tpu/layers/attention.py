@@ -140,6 +140,12 @@ class MultiHeadSelfAttention(nn.Module):
     index_heads: int = 0
     index_head_dim: int = 0
     index_kl_weight: float = 1.0
+    # > 0: a query reads its last ``window`` keys alone, itself among them
+    # (``0 <= t - s < window``; docs/designs/window_attention.md)
+    window: int = 0
+    # the heads' merged output times ``sigmoid(gate(x))``, a projection of
+    # its width, before the output projection (AFMoE's gated attention)
+    output_gate: bool = False
 
     @nn.compact
     def __call__(self, x, decode_pos=None, positions=None):
@@ -206,16 +212,32 @@ class MultiHeadSelfAttention(nn.Module):
                 "sparse attention is built for causal training: the "
                 "indexer's key cache and sparse decode are not"
             )
+        if self.window and (self.index_topk or not self.causal):
+            raise ValueError(
+                "a window is built for causal attention without an indexer"
+            )
         if self.decode:
             if decode_pos is None:
                 raise ValueError("decode mode needs decode_pos")
             out = self._decode_attend(q, k, v, decode_pos)
         elif self.index_topk:
             out = self._sparse_attend(x, q, k, v, positions)
+        elif self.window:
+            out = attention_ops.attention(
+                q, k, v, causal=self.causal, window=self.window
+            )
+            self._sow_block_plan(q, k, v)
         else:
             out = attention_ops.attention(q, k, v, causal=self.causal)
         with jax.named_scope("fold"):
             out = out.astype(x.dtype)
+        if self.output_gate:
+            gate = dense(
+                features=(self.num_heads, head_dim), dtype=self.dtype,
+                name="gate", use_bias=self.use_bias,
+            )(x)
+            with jax.named_scope("gate"):
+                out = out * jax.nn.sigmoid(gate)
         return dense(
             features=embed, axis=(-2, -1), dtype=self.dtype, name="out",
             use_bias=self.use_bias,
@@ -312,10 +334,28 @@ class MultiHeadSelfAttention(nn.Module):
             )
         return out
 
+    def _sow_block_plan(self, q, k, v):
+        """What the window kernels' block plan visited, masked and never
+        touched this step, over the batch and the heads
+        (``ops.attention.flash_block_plan``'s triple at the blocks the
+        kernels chose): what ``telemetry/router_load.py::read_block_plan``
+        reads on demand, so that a change that stops skipping is seen
+        without a trace."""
+        from elasticdl_tpu.telemetry.router_load import BLOCK_PLAN
+
+        plan = attention_ops.window_block_plan(q, k, v, self.window)
+        for name, count in zip(("visited", "masked", "skipped"), plan):
+            self.sow(
+                BLOCK_PLAN, name, jnp.asarray(count, jnp.int32),
+                init_fn=lambda: jnp.zeros((), jnp.int32),
+                reduce_fn=lambda _prev, new: new,
+            )
+
     def _decode_attend(self, q, k, v, pos):
         """One decode step: append this step's K/V to the cache at
         ``pos``, attend the single query over the filled prefix
-        (positions beyond the cursor are masked)."""
+        (positions beyond the cursor are masked, and under a window the
+        prefix behind its trailing edge)."""
         if not self.max_decode_len:
             raise ValueError("decode=True needs max_decode_len")
         if q.shape[1] != 1:
@@ -351,9 +391,10 @@ class MultiHeadSelfAttention(nn.Module):
             )
             * scale
         )
-        valid = (
-            jnp.arange(self.max_decode_len) <= pos
-        )  # filled prefix incl. this step
+        slots = jnp.arange(self.max_decode_len)
+        valid = slots <= pos  # filled prefix incl. this step
+        if self.window:
+            valid = valid & (pos - slots < self.window)
         scores = jnp.where(
             valid[None, None, None, :], scores, attention_ops._NEG_INF
         )
@@ -446,8 +487,11 @@ def make_norm(kind: str, epsilon: float, dtype, name=None):
 
 # a layer of a ``layer_pattern`` (nemotron_h's ``hybrid_override_pattern``):
 # one mixer OR one feed-forward part under one pre-norm residual
+# (``w``: the attention part under the block's ``window``, a query reading
+# its last ``window`` keys alone; ``*`` beside it reads every earlier key)
 LAYER_KINDS = {
     "*": "attention", "M": "mamba", "E": "experts", "-": "mlp",
+    "w": "attention",
 }
 
 
@@ -491,8 +535,15 @@ class TransformerBlock(nn.Module):
     mamba_fields: Any = ()
     latent_fields: Any = ()
     # MultiHeadSelfAttention's (per-head QK-norm, positions of several
-    # components, the sparse-attention indexer)
+    # components, the sparse-attention indexer, the output gate)
     attention_fields: Any = ()
+    # kind "w": its attention part's window
+    window: int = 0
+    # False: rotary positions in the window parts alone, none in the ``*``
+    # parts of a stack that has both (AFMoE)
+    full_attention_rope: bool = True
+    # a second norm, on each part's output: x + norm(part(norm(x)))
+    norm_outputs: bool = False
 
     @nn.compact
     def __call__(
@@ -519,6 +570,9 @@ class TransformerBlock(nn.Module):
             make_norm(self.norm, self.norm_eps, self.dtype)(x),
             training, decode_pos,
         )
+        if self.norm_outputs:
+            with jax.named_scope("norm_out"):
+                y = make_norm(self.norm, self.norm_eps, self.dtype)(y)
         if self.dropout_rate:
             y = nn.Dropout(self.dropout_rate, deterministic=not training)(y)
         return x + y
@@ -536,6 +590,11 @@ class TransformerBlock(nn.Module):
                 rope_theta=self.rope_theta, name="attn",
                 **dict(self.latent_fields),
             )(y)
+        windowed = {}
+        if self.kind == "w":
+            if self.window < 1:
+                raise ValueError("a layer of kind 'w' needs a window")
+            windowed = {"window": self.window}
         return MultiHeadSelfAttention(
             num_heads=self.num_heads,
             causal=self.causal,
@@ -544,12 +603,16 @@ class TransformerBlock(nn.Module):
             max_decode_len=self.max_decode_len,
             dtype=self.dtype,
             use_bias=self.use_bias,
-            rope_theta=self.rope_theta,
+            rope_theta=(
+                self.rope_theta
+                if self.kind == "w" or self.full_attention_rope else 0.0
+            ),
             qk_norm=self.qk_norm,
             norm_eps=self.norm_eps,
             head_dim=self.head_dim,
             name="attn",
             **dict(self.attention_fields),
+            **windowed,
         )(
             y, decode_pos=decode_pos,
             **({} if positions is None else {"positions": positions}),

@@ -123,6 +123,20 @@ class TransformerLM(nn.Module):
     index_heads: int = 0
     index_head_dim: int = 0
     index_kl_weight: float = 1.0
+    # window and full attention mixed (docs/designs/window_attention.md;
+    # perf/configs/trinity_mini_26b_a3b.json): a ``w`` of ``layer_pattern``
+    # is an attention part whose queries read their last ``sliding_window``
+    # keys alone, a ``*`` reads every earlier key; with
+    # ``full_attention_rope`` False the rotary positions turn the ``w``
+    # parts alone and the ``*`` parts get no position signal
+    sliding_window: int = 0
+    full_attention_rope: bool = True
+    # the attention parts' output times sigmoid(gate(x)) before the output
+    # projection; a second norm on every part's output (x + norm(part(
+    # norm(x)))); the embedding times sqrt(embed_dim) (muP)
+    output_gate: bool = False
+    norm_outputs: bool = False
+    scale_embedding: bool = False
 
     @nn.compact
     def __call__(self, features, training: bool = False):
@@ -159,6 +173,8 @@ class TransformerLM(nn.Module):
             name="tok_embed",
         )
         x = tok_embed(tokens)
+        if self.scale_embedding:
+            x = x * jnp.asarray(self.embed_dim**0.5, x.dtype)
         # parameter-free positions: a sequence-sharded activation adds its
         # slice of the encoding without any table gather
         decode_pos = None
@@ -235,7 +251,7 @@ class TransformerLM(nn.Module):
                     ("v_head_dim", self.v_head_dim),
                     ("rope_interleave", self.rope_interleave),
                 ) if self.kv_lora_rank else (),
-                **self._attention_fields(),
+                **self._further_block_fields(),
                 name=name,
             )
 
@@ -303,9 +319,10 @@ class TransformerLM(nn.Module):
         }
 
 
-    def _attention_fields(self) -> dict:
-        """The block's ``attention_fields``, given only where a field is
-        set: a model without them builds the block it always built."""
+    def _further_block_fields(self) -> dict:
+        """The block's ``attention_fields`` and its fields for a stack of
+        window and full layers, given only where a field is set: a model
+        without them builds the block it always built."""
         fields = tuple(
             (name, value)
             for name, value in (
@@ -315,10 +332,18 @@ class TransformerLM(nn.Module):
                 ("index_heads", self.index_heads),
                 ("index_head_dim", self.index_head_dim),
                 ("index_kl_weight", self.index_kl_weight),
+                ("output_gate", self.output_gate),
             )
             if value and (name != "index_kl_weight" or self.index_topk)
         )
-        return {"attention_fields": fields} if fields else {}
+        out = {"attention_fields": fields} if fields else {}
+        if self.sliding_window:
+            out["window"] = self.sliding_window
+        if not self.full_attention_rope:
+            out["full_attention_rope"] = False
+        if self.norm_outputs:
+            out["norm_outputs"] = True
+        return out
 
 
 def custom_model(**kwargs):

@@ -55,6 +55,12 @@ FLASH_DKV = "flash_dkv"
 SELECTED_FWD = "dsa_fwd"
 SELECTED_DQ = "dsa_dq"
 SELECTED_DKV = "dsa_dkv"
+# and under a window (a query reads its last ``window`` keys alone,
+# docs/designs/window_attention.md): a stack that mixes window and full
+# layers shows each kind's calls on the op line
+WINDOW_FWD = "swa_fwd"
+WINDOW_DQ = "swa_dq"
+WINDOW_DKV = "swa_dkv"
 # the XLA ops beside the kernel calls (reshapes and transposes to and from
 # the kernels' layout) by telemetry/op_scopes.py's name; never around a
 # ``pallas_call``, whose own name is what the device's op line shows
@@ -165,10 +171,14 @@ def repeat_kv_heads(q, k, v):
     )
 
 
-def mha_reference(q, k, v, causal: bool = False, sm_scale: float | None = None):
+def mha_reference(
+    q, k, v, causal: bool = False, sm_scale: float | None = None,
+    window: int | None = None,
+):
     """Plain multi-head attention, (B, S, H, D) layout (K/V may carry
     fewer heads — GQA) — the numerical oracle for the kernels and the
-    CPU fallback."""
+    CPU fallback.  ``window`` (causal only): query ``t`` reads key ``s``
+    iff ``0 <= t - s < window``."""
     k, v = repeat_kv_heads(q, k, v)
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
@@ -180,7 +190,12 @@ def mha_reference(q, k, v, causal: bool = False, sm_scale: float | None = None):
         s_q, s_k = scores.shape[-2], scores.shape[-1]
         row = jax.lax.broadcasted_iota(jnp.int32, (s_q, s_k), 0)
         col = jax.lax.broadcasted_iota(jnp.int32, (s_q, s_k), 1)
-        scores = jnp.where(row >= col, scores, _NEG_INF)
+        seen = row >= col
+        if window is not None:
+            seen = seen & (row - col < window)
+        scores = jnp.where(seen, scores, _NEG_INF)
+    elif window is not None:
+        raise ValueError("a window needs causal attention")
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bhqk,bkhd->bqhd", probs, v.astype(jnp.float32))
     return out.astype(q.dtype)
@@ -225,11 +240,59 @@ def _q_blocks_visible(col0, cols, row0, block_q, num_blocks):
     return first, full_from
 
 
-def flash_block_plan(seq_q, seq_k, block_q, block_k, causal):
+def _k_blocks_in_window(row0, rows, col0, block_k, window, full, live):
+    """The window's trailing edge over the same k-blocks: ``(behind, edge,
+    full)`` — of the blocks ``[0, live)`` that :func:`_k_blocks_visible`
+    leaves, ``[0, behind)`` lie wholly behind the edge (every pair has
+    ``t - s >= window``: never visited), ``[behind, edge)`` are crossed by
+    it, and ``full`` comes back no smaller than ``edge`` (where one block
+    is crossed by both the edge and the diagonal the first range has it)."""
+    behind = _clip((row0 - window - col0 + 1) // block_k, 0, live)
+    edge = _clip(
+        (row0 + rows - 1 - window - col0 + block_k) // block_k, behind, live
+    )
+    return behind, edge, _clip(full, edge, live)
+
+
+def _q_blocks_in_window(
+    col0, cols, row0, block_q, num_blocks, window, first, full_from
+):
+    """The trailing edge along the other axis, for dK/dV: ``(full_from,
+    edge_from, end)`` — of the q-blocks ``[first, num_blocks)`` that
+    :func:`_q_blocks_visible` leaves, ``[edge_from, end)`` are crossed by
+    the edge and ``[end, num_blocks)`` lie wholly behind it; ``full_from``
+    comes back no larger than ``end`` and ``edge_from`` no smaller than it."""
+    end = _clip(
+        (col0 + cols - 1 + window - row0 + block_q - 1) // block_q,
+        first, num_blocks,
+    )
+    full_from = _clip(full_from, first, end)
+    edge_from = _clip((col0 + window - row0) // block_q, full_from, end)
+    return full_from, edge_from, end
+
+
+# how a block is crossed: by the diagonal (``True``: what a dense causal
+# kernel knows), by the window's trailing edge, or whole under both
+# conditions
+_EDGE = "edge"
+_BOTH = "both"
+
+
+def _crossings(block_q, block_k, window):
+    """How the kernels mask a block the diagonal crosses and one the window's
+    trailing edge crosses: ``(on_diagonal, on_edge)``.  Where one block can
+    be crossed by both (``t - s`` spans ``block_q + block_k - 1`` values in a
+    block), such blocks are computed whole under both conditions."""
+    if window is not None and window < block_q + block_k - 1:
+        return _BOTH, _BOTH
+    return True, _EDGE
+
+
+def flash_block_plan(seq_q, seq_k, block_q, block_k, causal, window=None):
     """``(live, masked, skipped)`` score blocks a head: the blocks the
-    kernels compute, those of them the diagonal crosses (the only ones
-    that build a mask), and the ones never touched.  Counted with the
-    kernels' own loop bounds."""
+    kernels compute, those of them the diagonal or the window's trailing
+    edge crosses (the only ones that build a mask), and the ones never
+    touched.  Counted with the kernels' own loop bounds."""
     num_q, num_k = seq_q // block_q, seq_k // block_k
     if not causal:
         return num_q * num_k, 0, 0
@@ -238,8 +301,13 @@ def flash_block_plan(seq_q, seq_k, block_q, block_k, causal):
         full, upto = _k_blocks_visible(
             i * block_q, block_q, 0, block_k, num_k
         )
-        live += upto
-        masked += upto - full
+        behind = edge = 0
+        if window is not None:
+            behind, edge, full = _k_blocks_in_window(
+                i * block_q, block_q, 0, block_k, window, full, upto
+            )
+        live += upto - behind
+        masked += (edge - behind) + (upto - full)
     return live, masked, num_q * num_k - live
 
 
@@ -251,6 +319,21 @@ def _causal_mask(s, row0, col0, q_axis=0):
         jnp.int32, s.shape, q_axis
     ) - jax.lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis)
     return jnp.where(ahead >= col0 - row0, s, _NEG_INF)
+
+
+def _visible(s, crossed, row0, col0, window, q_axis=0):
+    """Scores a query does not read masked, for a tile whose first query
+    row is ``row0`` and first key column ``col0``: above the diagonal,
+    behind the window's trailing edge (``t - s >= window``), or both."""
+    if crossed is True:
+        return _causal_mask(s, row0, col0, q_axis)
+    ahead = jax.lax.broadcasted_iota(
+        jnp.int32, s.shape, q_axis
+    ) - jax.lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis)
+    seen = ahead < window - (row0 - col0)
+    if crossed == _BOTH:
+        seen = seen & (ahead >= col0 - row0)
+    return jnp.where(seen, s, _NEG_INF)
 
 
 def _selected(s, chosen):
@@ -342,15 +425,29 @@ def _diagonal_half(block_q, block_k):
     return half if block_q == block_k and half and half % 8 == 0 else 0
 
 
-def _block_pieces(block_q, block_k, crossed, along_q):
+def _block_pieces(block_q, block_k, crossed, along_q, window=None):
     """The parts of a score block worth computing, as static
     ``(q_from, q_to, k_from, k_to, masked)`` ranges inside it: one piece
     for a block the diagonal does not cross; for one it does, the three
     visible quarters (:func:`_diagonal_half`) — as two pieces split along
     q for the kernels that accumulate by q row, as three for dK/dV, whose
-    pieces each read one saved half-row — or the whole block, masked."""
+    pieces each read one saved half-row — or the whole block, masked.  A
+    block the window's trailing edge crosses is the diagonal block's
+    complement where the window is a whole number of such blocks (its
+    lower-left quarter sees nothing), else the whole block, masked."""
     half = _diagonal_half(block_q, block_k)
-    if not crossed or not half:
+    if crossed == _EDGE and half and window % block_k == 0:
+        if along_q:
+            return [
+                (0, half, 0, block_k, _EDGE),
+                (half, block_q, half, block_k, _EDGE),
+            ]
+        return [
+            (0, half, 0, half, _EDGE),
+            (0, half, half, block_k, False),
+            (half, block_q, half, block_k, _EDGE),
+        ]
+    if crossed is not True or not half:
         return [(0, block_q, 0, block_k, crossed)]
     if along_q:
         return [(0, half, 0, half, True), (half, block_q, 0, block_k, True)]
@@ -364,6 +461,7 @@ def _block_pieces(block_q, block_k, crossed, along_q):
 def _flash_kernel(
     q_ref, k_ref, v_ref, *refs,
     sm_scale, causal, block_q, block_k, chunk_k, num_ck, selected=False,
+    window=None,
 ):
     """One (batch, head, q-block, k-chunk) grid cell of the online-softmax
     forward: loop block_k sub-blocks of the staged (1, chunk_k, d) K/V
@@ -384,13 +482,23 @@ def _flash_kernel(
     causal structure too).  A row may then meet blocks that hold none of
     its keys before one that does: what ``exp(-1e30 - -1e30) = 1`` adds to
     ``l`` and ``acc`` there is wiped by ``alpha = 0`` at the first real
-    key, and every row has one (itself or an earlier key)."""
+    key, and every row has one (itself or an earlier key).
+
+    ``window``: a query reads its last ``window`` keys alone.  The grid's
+    chunk stream then starts at the q-block's first live chunk
+    (:func:`_first_live_chunk`) and is ``num_ck`` chunks long, the blocks of
+    a chunk wholly behind the trailing edge are outside the loops' bounds,
+    and the ones the edge crosses, which come first, build a second mask
+    (a row may find no key in them: the same ``alpha = 0`` wipes it)."""
     if selected:
         mask_ref, *refs = refs
     o_ref, lse_ref, m_scr, l_scr, acc_scr = refs
     i = pl.program_id(2)
     c = pl.program_id(3)
     heads = m_scr.shape[0]
+    chunk = c
+    if window is not None:
+        chunk = c + _first_live_chunk(i, block_q, chunk_k, window)
 
     @pl.when(c == 0)
     def _init():
@@ -399,11 +507,17 @@ def _flash_kernel(
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     nb = chunk_k // block_k
-    row0, col0 = i * block_q, c * chunk_k
+    row0, col0 = i * block_q, chunk * chunk_k
     if causal:
         full, live = _k_blocks_visible(row0, block_q, col0, block_k, nb)
     else:
         full = live = nb
+    behind = edge = 0
+    if window is not None:
+        behind, edge, full = _k_blocks_in_window(
+            row0, block_q, col0, block_k, window, full, live
+        )
+    on_diagonal, on_edge = _crossings(block_q, block_k, window)
     d = acc_scr.shape[1]
 
     def _chunk():
@@ -413,7 +527,7 @@ def _flash_kernel(
         def body(jj, crossed):
             start = pl.multiple_of(jj * block_k, block_k)
             for q0, q1, k0, k1, masked in _block_pieces(
-                block_q, block_k, crossed, along_q=True
+                block_q, block_k, crossed, along_q=True, window=window
             ):
                 rows = slice(q0, q1)
                 kb = k_ref[0, pl.ds(start + k0, k1 - k0), :]
@@ -424,7 +538,9 @@ def _flash_kernel(
                     if selected:
                         s = _selected(s, mask_ref[0, jj, q0:q1, k0:k1])
                     elif masked:
-                        s = _causal_mask(s, row0 + q0, col0 + start + k0)
+                        s = _visible(
+                            s, masked, row0 + q0, col0 + start + k0, window
+                        )
                     m_prev = m_scr[h, rows]  # (rows, _LANES), columns equal
                     m_next = jnp.maximum(
                         m_prev, jnp.max(s, axis=1, keepdims=True)
@@ -441,11 +557,13 @@ def _flash_kernel(
                     alphas, d
                 ) + _by_head(pvs)
 
-        _loop(0, full, functools.partial(body, crossed=False))
-        _loop(full, live, functools.partial(body, crossed=True))
+        _loop(behind, edge, functools.partial(body, crossed=on_edge))
+        _loop(edge, full, functools.partial(body, crossed=False))
+        _loop(full, live, functools.partial(body, crossed=on_diagonal))
 
     if causal:
-        pl.when(live > 0)(_chunk)  # chunks above the diagonal add nothing
+        # chunks above the diagonal, or behind the window, add nothing
+        pl.when(live > behind)(_chunk)
     else:
         _chunk()
 
@@ -476,6 +594,7 @@ def _flash_dq_kernel(
     num_ck,
     makes_delta,
     selected=False,
+    window=None,
 ):
     """dQ cell per (batch, head, q-block, k-chunk): rebuild p from the
     saved logsumexp, accumulate dq = sm_scale * ds @ K into VMEM scratch
@@ -501,6 +620,9 @@ def _flash_dq_kernel(
     i = pl.program_id(2)
     c = pl.program_id(3)
     heads = lse_ref.shape[0]
+    chunk = c
+    if window is not None:  # the forward's chunk stream and block ranges
+        chunk = c + _first_live_chunk(i, block_q, chunk_k, window)
 
     @pl.when(c == 0)
     def _init():
@@ -518,11 +640,17 @@ def _flash_dq_kernel(
                 )
 
     nb = chunk_k // block_k
-    row0, col0 = i * block_q, c * chunk_k
+    row0, col0 = i * block_q, chunk * chunk_k
     if causal:
         full, live = _k_blocks_visible(row0, block_q, col0, block_k, nb)
     else:
         full = live = nb
+    behind = edge = 0
+    if window is not None:
+        behind, edge, full = _k_blocks_in_window(
+            row0, block_q, col0, block_k, window, full, live
+        )
+    on_diagonal, on_edge = _crossings(block_q, block_k, window)
 
     def _chunk():
         q = q_ref[0]
@@ -539,7 +667,7 @@ def _flash_dq_kernel(
         def body(jj, crossed):
             start = pl.multiple_of(jj * block_k, block_k)
             for q0, q1, k0, k1, masked in _block_pieces(
-                block_q, block_k, crossed, along_q=True
+                block_q, block_k, crossed, along_q=True, window=window
             ):
                 rows = slice(q0, q1)
                 kb = k_ref[0, pl.ds(start + k0, k1 - k0), :]
@@ -550,18 +678,21 @@ def _flash_dq_kernel(
                     if selected:
                         s = _selected(s, mask_ref[0, jj, q0:q1, k0:k1])
                     elif masked:
-                        s = _causal_mask(s, row0 + q0, col0 + start + k0)
+                        s = _visible(
+                            s, masked, row0 + q0, col0 + start + k0, window
+                        )
                     p = jnp.exp(s - _lanes_to(lse[h][rows], k1 - k0))
                     dp = _scores(do_of[h][rows], vb)
                     ds = p * (dp - _lanes_to(delta[h][rows], k1 - k0))
                     dqs.append(_mxu(ds, kb))
                 acc_scr[rows] = acc_scr[rows] + _by_head(dqs)
 
-        _loop(0, full, functools.partial(body, crossed=False))
-        _loop(full, live, functools.partial(body, crossed=True))
+        _loop(behind, edge, functools.partial(body, crossed=on_edge))
+        _loop(edge, full, functools.partial(body, crossed=False))
+        _loop(full, live, functools.partial(body, crossed=on_diagonal))
 
     if causal:
-        pl.when(live > 0)(_chunk)
+        pl.when(live > behind)(_chunk)
     else:
         _chunk()
 
@@ -588,6 +719,8 @@ def _flash_dkv_kernel(
     chunk_q,
     num_cq,
     selected=False,
+    window=None,
+    all_cq=None,
 ):
     """dK/dV cell per (batch, head, k-block, q-chunk): loop block_q
     sub-blocks of the staged (1, chunk_q, d) Q/dO chunk over TRANSPOSED
@@ -600,13 +733,21 @@ def _flash_dkv_kernel(
     head's lanes zeroed make the scores, and each head's lanes of the two
     products are selected into the accumulators.  ``selected``: ``refs``
     starts with the chunk of the TRANSPOSED mask, ``(1, blocks of the
-    chunk, block_k, block_q)``."""
+    chunk, block_k, block_q)``.  ``window``: the chunk stream starts at the
+    first q-chunk whose rows reach this k-block's columns and is ``num_cq``
+    chunks long (``all_cq``: the sequence's, past which a late k-block's
+    stream finds nothing); q-blocks wholly behind the trailing edge are
+    outside the loops' bounds and the ones it crosses, which come last,
+    build a second mask."""
     if selected:
         mask_ref, *refs = refs
     dk_ref, dv_ref, dk_scr, dv_scr = refs
     j = pl.program_id(2)
     c = pl.program_id(3)
     heads = lse_ref.shape[0]
+    chunk = c
+    if window is not None:
+        chunk = c + (j * block_k) // chunk_q
 
     @pl.when(c == 0)
     def _init():
@@ -614,19 +755,26 @@ def _flash_dkv_kernel(
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
     nb = chunk_q // block_q
-    col0, row0 = j * block_k, c * chunk_q
+    col0, row0 = j * block_k, chunk * chunk_q
     if causal:
         first, full_from = _q_blocks_visible(
             col0, block_k, row0, block_q, nb
         )
     else:
         first = full_from = 0
+    edge_from = end = nb
+    if window is not None:
+        full_from, edge_from, end = _q_blocks_in_window(
+            col0, block_k, row0, block_q, _clip((all_cq - chunk) * nb, 0, nb),
+            window, first, full_from,
+        )
+    on_diagonal, on_edge = _crossings(block_q, block_k, window)
 
     saved = lse_ref.shape[2]  # block_q, or its half (_diagonal_half)
 
     def saved_rows(ref, h, ii, q0, q1):
         """Rows ``[q0, q1)`` of q-block ``ii`` of the chunk, along lanes."""
-        first = (c * nb + ii) * (block_q // saved)
+        first = (chunk * nb + ii) * (block_q // saved)
         return jnp.concatenate(
             [
                 ref[h, pl.ds(first + part, 1), :]
@@ -644,7 +792,7 @@ def _flash_dkv_kernel(
         def body(ii, crossed):
             start = pl.multiple_of(ii * block_q, block_q)
             for q0, q1, k0, k1, masked in _block_pieces(
-                block_q, block_k, crossed, along_q=False
+                block_q, block_k, crossed, along_q=False, window=window
             ):
                 cols = slice(k0, k1)
                 qi = q_ref[0, pl.ds(start + q0, q1 - q0), :]
@@ -658,8 +806,9 @@ def _flash_dkv_kernel(
                     if selected:
                         st = _selected(st, mask_ref[0, ii, k0:k1, q0:q1])
                     elif masked:
-                        st = _causal_mask(
-                            st, row0 + start + q0, col0 + k0, q_axis=1
+                        st = _visible(
+                            st, masked, row0 + start + q0, col0 + k0,
+                            window, q_axis=1,
                         )
                     pt = jnp.exp(st - lse)
                     dvs.append(_mxu(pt, doi))
@@ -669,11 +818,13 @@ def _flash_dkv_kernel(
                 dv_scr[cols] = dv_scr[cols] + _by_head(dvs)
                 dk_scr[cols] = dk_scr[cols] + _by_head(dks)
 
-        _loop(first, full_from, functools.partial(body, crossed=True))
-        _loop(full_from, nb, functools.partial(body, crossed=False))
+        _loop(first, full_from, functools.partial(body, crossed=on_diagonal))
+        _loop(full_from, edge_from, functools.partial(body, crossed=False))
+        _loop(edge_from, end, functools.partial(body, crossed=on_edge))
 
     if causal:
-        pl.when(first < nb)(_chunk)  # chunks above the diagonal see nothing
+        # chunks above the diagonal, or behind the window, see nothing
+        pl.when(first < end)(_chunk)
     else:
         _chunk()
 
@@ -711,7 +862,7 @@ def _pick_chunk(seq: int, block: int, preferred: int) -> int:
 
 
 @functools.partial(
-    jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7)
+    jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8)
 )
 def flash_attention(
     q,
@@ -730,10 +881,18 @@ def flash_attention(
     block_q: int = 512,
     block_k: int = 512,
     interpret: bool | None = None,
+    window: int | None = None,
 ):
     """Blockwise flash attention, (B, S, H, D) layout.  ``q`` and ``k``
     share a width (the scores', which sets the default scale); ``v`` and the
     output may have another (latent attention: 192 beside 128).
+
+    ``window`` (causal self-attention only): query ``t`` reads key ``s`` iff
+    ``0 <= t - s < window``.  Blocks wholly behind that trailing edge are in
+    no loop and chunks wholly behind it in no grid cell, in all three
+    kernels, which then run as ``swa_fwd`` / ``swa_dq`` / ``swa_dkv``; a
+    window that holds the whole sequence is no window
+    (docs/designs/window_attention.md).
 
     ``interpret=None`` follows the default backend through
     :func:`kernel_interpret` (interpreted on CPU, compiled on TPU).
@@ -745,7 +904,7 @@ def flash_attention(
     ever materializes an (S, S) score matrix in HBM.
     """
     out, _lse = _flash_forward(
-        q, k, v, causal, sm_scale, block_q, block_k, interpret
+        q, k, v, causal, sm_scale, block_q, block_k, interpret, window=window
     )
     return out
 
@@ -891,18 +1050,93 @@ def _last_live_chunk(i, block_q, chunk_k):
     return (i * block_q + block_q - 1) // chunk_k
 
 
+def _first_live_chunk(i, block_q, chunk_k, window):
+    """The first k-chunk a q-block reads under a window: the one that holds
+    the first key its first row sees.  Python ints or traced int32."""
+    first_key = i * block_q - (window - 1)
+    if isinstance(first_key, int):
+        return max(first_key, 0) // chunk_k
+    return jnp.maximum(first_key, 0) // chunk_k
+
+
+def _last_live_q_chunk(j, block_k, chunk_q, num_cq, window):
+    """The last q-chunk whose rows read a k-block under a window: the one
+    that holds the last row that sees the block's last column."""
+    last_row = j * block_k + block_k - 1 + window - 1
+    return _clip(last_row // chunk_q, 0, num_cq - 1)
+
+
+def _window_streams(window, seq_q, seq_k, bq, bk, chunk_q, chunk_k):
+    """``(k-chunks, q-chunks)`` the grids' innermost dimensions run under a
+    window: the most chunks any q-block (forward, dQ) or k-block (dK/dV)
+    reads, counted from its own first live chunk.  The chunks of the
+    sequence that lie outside are in no grid cell."""
+    over_k = max(
+        _last_live_chunk(i, bq, chunk_k)
+        - _first_live_chunk(i, bq, chunk_k, window) + 1
+        for i in range(seq_q // bq)
+    )
+    num_cq = seq_q // chunk_q
+    over_q = max(
+        _last_live_q_chunk(j, bk, chunk_q, num_cq, window)
+        - (j * bk) // chunk_q + 1
+        for j in range(seq_k // bk)
+    )
+    return over_k, over_q
+
+
+def _effective_window(window, causal, mask, seq_q, seq_k):
+    """``window`` as the kernels take it: None where there is none or it
+    holds the whole sequence (the dense kernels then run, under their own
+    names)."""
+    if window is None:
+        return None
+    if not causal or mask is not None or seq_q != seq_k:
+        raise ValueError(
+            "a window is built for causal self-attention without a "
+            "selected set"
+        )
+    if window < 1:
+        raise ValueError(f"window must be at least 1, got {window}")
+    return None if window >= seq_k else int(window)
+
+
+_SELECTED_NAMES = {
+    FLASH_FWD: SELECTED_FWD, FLASH_DQ: SELECTED_DQ, FLASH_DKV: SELECTED_DKV,
+}
+_WINDOW_NAMES = {
+    FLASH_FWD: WINDOW_FWD, FLASH_DQ: WINDOW_DQ, FLASH_DKV: WINDOW_DKV,
+}
+
+
+def _kernel_name(dense, mask, window):
+    """The name a kernel's call runs under: the dense one, or its
+    selected-set or window sibling."""
+    if mask is not None:
+        return _SELECTED_NAMES[dense]
+    if window is not None:
+        return _WINDOW_NAMES[dense]
+    return dense
+
+
 # jitted so that a model's layers share ONE trace and one lowering of each
 # kernel (the twelve layers of the benchmark's LM traced them 36 times: on
 # the chip's host 15 s of a 50 s set-up, PERF.md section 6, PR 28)
-@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7), inline=True)
+@functools.partial(
+    jax.jit, static_argnums=(3, 4, 5, 6, 7), static_argnames=("window",),
+    inline=True,
+)
 def _flash_forward(
-    q, k, v, causal, sm_scale, block_q, block_k, interpret, mask=None
+    q, k, v, causal, sm_scale, block_q, block_k, interpret, mask=None,
+    window=None,
 ):
     """``mask``: the selection's, ``(batch, seq_k / block_k, seq_q,
     block_k)`` int8 (``ops/sparse_attention.py``): the kernel then runs
-    under its selected-set name and reads a chunk of it beside k and v."""
+    under its selected-set name and reads a chunk of it beside k and v.
+    ``window``: the kernel runs under its window name over the chunks and
+    blocks the window leaves."""
     q, k, v = jnp.asarray(q), jnp.asarray(k), jnp.asarray(v)
-    sm_scale, block_q, block_k, _, chunk_k, interpret = _flash_geometry(
+    sm_scale, block_q, block_k, chunk_q, chunk_k, interpret = _flash_geometry(
         q, k, v, sm_scale, block_q, block_k, interpret
     )
     batch, seq_q, heads, d = q.shape
@@ -910,12 +1144,21 @@ def _flash_forward(
     kv_heads = k.shape[2]
     seq_k = k.shape[1]
     num_ck = seq_k // chunk_k
+    window = _effective_window(window, causal, mask, seq_q, seq_k)
+    windowed = {}
+    if window is not None:
+        num_ck, _ = _window_streams(
+            window, seq_q, seq_k, block_q, block_k, chunk_q, chunk_k
+        )
+        windowed = {"window": window}
     at = _HeadAddressing(q, k, v)
 
     def _q_block(h, i, c):
         return i
 
     def _kv_chunk(h, i, c):
+        if window is not None:
+            c = c + _first_live_chunk(i, block_q, chunk_k, window)
         if causal:
             c = jnp.minimum(c, _last_live_chunk(i, block_q, chunk_k))
         return c
@@ -929,6 +1172,7 @@ def _flash_forward(
         chunk_k=chunk_k,
         num_ck=num_ck,
         **({} if mask is None else {"selected": True}),
+        **windowed,
     )
     with jax.named_scope(_FOLD):
         operands = [at.operand(x) for x in (q, k, v)]
@@ -962,21 +1206,23 @@ def _flash_forward(
         ],
         compiler_params=_compiler_params(mask),
         interpret=interpret,
-        name=FLASH_FWD if mask is None else SELECTED_FWD,
+        name=_kernel_name(FLASH_FWD, mask, window),
     )(*operands, *masks)
     with jax.named_scope(_FOLD):
         return at.result(out, batch, heads), lse
 
 
 @functools.partial(
-    jax.jit, static_argnums=(6, 7, 8, 9, 10), inline=True
+    jax.jit, static_argnums=(6, 7, 8, 9, 10), static_argnames=("window",),
+    inline=True,
 )
 def _flash_backward(
     q, k, v, out, lse, g, causal, sm_scale, block_q, block_k, interpret,
-    mask=None, mask_t=None,
+    mask=None, mask_t=None, window=None,
 ):
     """``mask`` as the forward takes it (for dQ), ``mask_t`` its transpose
-    by blocks, ``(batch, seq_q / block_q, seq_k, block_q)`` (for dK/dV)."""
+    by blocks, ``(batch, seq_q / block_q, seq_k, block_q)`` (for dK/dV);
+    ``window`` as the forward takes it."""
     q, k, v = jnp.asarray(q), jnp.asarray(k), jnp.asarray(v)
     out, lse, g = jnp.asarray(out), jnp.asarray(lse), jnp.asarray(g)
     sm_scale, bq, bk, chunk_q, chunk_k, interpret = _flash_geometry(
@@ -988,6 +1234,14 @@ def _flash_backward(
     seq_k = k.shape[1]
     num_ck = seq_k // chunk_k
     num_cq = seq_q // chunk_q
+    all_cq = num_cq  # of the sequence; num_cq: of a k-block's stream
+    window = _effective_window(window, causal, mask, seq_q, seq_k)
+    windowed = {}
+    if window is not None:
+        num_ck, num_cq = _window_streams(
+            window, seq_q, seq_k, bq, bk, chunk_q, chunk_k
+        )
+        windowed = {"window": window}
     at = _HeadAddressing(q, k, v)
 
     with jax.named_scope(_FOLD):
@@ -997,6 +1251,8 @@ def _flash_backward(
         return i
 
     def _kv_chunk(h, i, c):
+        if window is not None:
+            c = c + _first_live_chunk(i, bq, chunk_k, window)
         if causal:
             c = jnp.minimum(c, _last_live_chunk(i, bq, chunk_k))
         return c
@@ -1034,7 +1290,7 @@ def _flash_backward(
         functools.partial(
             _flash_dq_kernel, sm_scale=sm_scale, causal=causal,
             block_q=bq, block_k=bk, chunk_k=chunk_k, num_ck=num_ck,
-            makes_delta=bool(at.lanes), **selected,
+            makes_delta=bool(at.lanes), **selected, **windowed,
         ),
         grid=(batch, at.cells, seq_q // bq, num_ck),
         in_specs=[
@@ -1049,7 +1305,7 @@ def _flash_backward(
         scratch_shapes=scratch,
         compiler_params=_compiler_params(mask),
         interpret=interpret,
-        name=FLASH_DQ if mask is None else SELECTED_DQ,
+        name=_kernel_name(FLASH_DQ, mask, window),
     )(qf, kf, vf, dof, lse, *masks, extra)
     dq, delta = made if at.lanes else (made, extra)
 
@@ -1058,6 +1314,13 @@ def _flash_backward(
     # one pass over (B, S_k, H, D), the gradient analogue of the repeat.
     # Grid: k-block outer, q-CHUNK innermost (the accumulation stream).
     def _q_chunk(h, j, c):
+        if window is not None:
+            # from the first q-chunk whose rows reach this k-block's
+            # columns to the last whose rows still read them
+            return jnp.minimum(
+                c + (j * bk) // chunk_q,
+                _last_live_q_chunk(j, bk, chunk_q, all_cq, window),
+            )
         if causal:
             # the first q-chunk whose rows reach this k-block's columns
             c = jnp.maximum(c, (j * bk) // chunk_q)
@@ -1080,7 +1343,8 @@ def _flash_backward(
         functools.partial(
             _flash_dkv_kernel, sm_scale=sm_scale, causal=causal,
             block_q=bq, block_k=bk, chunk_q=chunk_q, num_cq=num_cq,
-            **selected,
+            **selected, **windowed,
+            **({"all_cq": all_cq} if windowed else {}),
         ),
         grid=(batch, at.cells, seq_k // bk, num_cq),
         in_specs=[
@@ -1104,7 +1368,7 @@ def _flash_backward(
         ],
         compiler_params=_compiler_params(mask),
         interpret=interpret,
-        name=FLASH_DKV if mask is None else SELECTED_DKV,
+        name=_kernel_name(FLASH_DKV, mask, window),
     )(qf, kf, vf, dof, lse.reshape(rows), delta.reshape(rows), *masks)
 
     with jax.named_scope(_FOLD):
@@ -1118,17 +1382,22 @@ def _flash_backward(
         return dq, dk.astype(k.dtype), dv.astype(v.dtype)
 
 
-def _flash_fwd_rule(q, k, v, causal, sm_scale, block_q, block_k, interpret):
+def _flash_fwd_rule(
+    q, k, v, causal, sm_scale, block_q, block_k, interpret, window
+):
     out, lse = _flash_forward(
-        q, k, v, causal, sm_scale, block_q, block_k, interpret
+        q, k, v, causal, sm_scale, block_q, block_k, interpret, window=window
     )
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd_rule(causal, sm_scale, block_q, block_k, interpret, res, g):
+def _flash_bwd_rule(
+    causal, sm_scale, block_q, block_k, interpret, window, res, g
+):
     q, k, v, out, lse = res
     return _flash_backward(
-        q, k, v, out, lse, g, causal, sm_scale, block_q, block_k, interpret
+        q, k, v, out, lse, g, causal, sm_scale, block_q, block_k, interpret,
+        window=window,
     )
 
 
@@ -1178,7 +1447,24 @@ selected_flash_attention.defvjp(_selected_fwd_rule, _selected_bwd_rule)
 # ---- dispatch --------------------------------------------------------------
 
 
-def attention(q, k, v, causal: bool = False, sm_scale: float | None = None):
+def window_block_plan(q, k, v, window: int) -> tuple[int, int, int]:
+    """:func:`flash_block_plan`'s ``(live, masked, skipped)`` for causal
+    attention of these ``(batch, tokens, heads, width)`` operands under
+    ``window``, at the blocks the kernels choose for them, over the batch
+    and the heads: what a window layer counts of itself."""
+    _, bq, bk, _, _, _ = _flash_geometry(q, k, v, None, 512, 512, False)
+    seq = q.shape[1]
+    plan = flash_block_plan(
+        seq, seq, bq, bk, True,
+        _effective_window(window, True, None, seq, k.shape[1]),
+    )
+    return tuple(q.shape[0] * q.shape[2] * n for n in plan)
+
+
+def attention(
+    q, k, v, causal: bool = False, sm_scale: float | None = None,
+    window: int | None = None,
+):
     """Self-attention entry point for layers: sequence-parallel attention
     (ring by default, ulysses when configured) when the registered mesh
     has an ``sp`` axis > 1, else the local flash kernel — mapped over the
@@ -1193,9 +1479,20 @@ def attention(q, k, v, causal: bool = False, sm_scale: float | None = None):
     from elasticdl_tpu.ops.ulysses import ulysses_attention
 
     mesh, sp_axis, sp_impl = get_attention_mesh()
+    # (given only where there is one: a call without a window is the call
+    # it always was)
+    windowed = {} if window is None else {"window": window}
     if mesh is None:
-        return flash_attention(q, k, v, causal=causal, sm_scale=sm_scale)
+        return flash_attention(
+            q, k, v, causal=causal, sm_scale=sm_scale, **windowed
+        )
     if sp_axis in mesh.axis_names and mesh.shape[sp_axis] > 1:
+        if window is not None:
+            raise NotImplementedError(
+                "a window across the sp axis is not built: the ring and "
+                "ulysses schedules read every key "
+                "(docs/designs/window_attention.md)"
+            )
         impl = (
             ulysses_attention if sp_impl == "ulysses" else ring_attention
         )
@@ -1211,6 +1508,7 @@ def attention(q, k, v, causal: bool = False, sm_scale: float | None = None):
         causal=causal,
         sm_scale=sm_scale,
         interpret=kernel_interpret(mesh.devices.flat[0].platform),
+        **windowed,
     )
     if mesh.devices.size == 1 or jax.sharding.get_abstract_mesh().manual_axes:
         # one device, or already inside a caller's per-device region

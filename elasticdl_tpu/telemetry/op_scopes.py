@@ -66,6 +66,9 @@ VOCABULARY = (
     "loss", "optimizer", "parse",
     # layers/attention.py, ops/attention.py
     "rope", "qk_norm", "join", "fold", "mlp",
+    # the attention output's gate (its product; flax names its projection
+    # the same) and the norm on a part's output
+    "gate", "norm_out",
     # the sparse-attention indexer: its projections, the index scores and
     # the selection (with the mask's transpose), its KL loss
     "indexer", "index_select", "indexer_kl",

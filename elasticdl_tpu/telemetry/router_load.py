@@ -33,6 +33,11 @@ LOSS_PARTS = "loss_parts"
 # query blocks whose tie search ran (``tie_search_blocks``: the blocks that
 # held such a query)
 SELECTION_STATS = "selection_stats"
+# the collection a window-attention layer (``layers/attention.py``) sows the
+# score blocks its kernels' plan ``visited``, ``masked`` (the diagonal and
+# the window's trailing edge) and ``skipped`` this step into, over the batch
+# and the heads (``ops/attention.py::flash_block_plan``)
+BLOCK_PLAN = "block_plan"
 
 _watched = None
 
@@ -76,6 +81,23 @@ def read_selection(model_state=None) -> dict | None:
         leaves, key=lambda item: int("0" + "".join(filter(str.isdigit, item[0][0].key)))
     ):
         out.setdefault(path[-1].key, []).append(float(leaf))
+    return out
+
+
+def read_block_plan(model_state=None) -> dict | None:
+    """The newest step's window layers' block plans summed, one host
+    readback: ``{"layers", "visited", "masked", "skipped",
+    "skipped_share"}``.  None for a model without a window layer."""
+    stats = (_state(model_state) or {}).get(BLOCK_PLAN)
+    if not stats:
+        return None
+    out = {"layers": 0, "visited": 0, "masked": 0, "skipped": 0}
+    for path, leaf in jax.tree_util.tree_leaves_with_path(
+        jax.device_get(stats)
+    ):
+        out[path[-1].key] += int(leaf)
+        out["layers"] += path[-1].key == "visited"
+    out["skipped_share"] = out["skipped"] / (out["visited"] + out["skipped"])
     return out
 
 

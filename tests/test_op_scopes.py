@@ -40,7 +40,8 @@ KERNELS = {
     mamba_passes.GATE_NORM_FWD, mamba_passes.GATE_NORM_BWD,
     mamba_passes.MAMBA_CONV_FWD, mamba_passes.MAMBA_CONV_BWD,
     attention_ops.SELECTED_FWD, attention_ops.SELECTED_DQ,
-    attention_ops.SELECTED_DKV, sparse_ops.INDEX_SELECT,
+    attention_ops.SELECTED_DKV, attention_ops.WINDOW_FWD,
+    attention_ops.WINDOW_DQ, attention_ops.WINDOW_DKV, sparse_ops.INDEX_SELECT,
     sparse_ops.INDEX_SELECT_HINTED, sparse_ops.INDEXER_KL,
 }
 # modules that hold other modules: an op directly under one of these is in
@@ -248,7 +249,7 @@ def test_attribute_sums_by_scope_and_says_what_it_could_not_place():
 
 
 def test_the_vocabulary_is_closed_and_every_scope_of_the_package_is_in_it():
-    assert len(op_scopes.VOCABULARY) == 21
+    assert len(op_scopes.VOCABULARY) == 23  # PR 42: gate, norm_out
     assert len(set(op_scopes.VOCABULARY)) == len(op_scopes.VOCABULARY)
     literal = re.compile(r"named_scope\(\s*([\"']?)(\w+)\1\s*\)")
     constants = re.compile(r"^(_[A-Z_]+) = \"(\w+)\"$", re.M)
@@ -314,6 +315,7 @@ FAMILIES = {
     "mamba_experts_attention": lambda: _lm_family("tiny_nemotron"),
     "latent_attention_mtp": lambda: _lm_family("tiny_joyai"),
     "sparse_attention": lambda: _lm_family("tiny_keye"),
+    "window_and_full_attention": lambda: _lm_family("tiny_trinity"),
     "resnet_first_stage": lambda: (
         FirstStage(), _class_loss, optax.sgd(0.1),
         {"image": np.zeros((2, 32, 32, 3), np.float32)},
@@ -403,6 +405,9 @@ def test_every_region_of_the_step_has_a_name(built):
         } <= parts | {op_scopes.at_depth(part, 3) for part in parts}
     if family == "olmoe":
         assert {"block/attn/qk_norm/q_norm", "block/attn/rope"} <= parts
+    if family == "window_and_full_attention":
+        # the gate's projection and its product, the norm on a part's output
+        assert {"block/attn/gate", "block/norm_out/RMSNorm"} <= parts
     if family == "resnet_first_stage":
         assert {"conv_block/conv_a", "identity_block/bn_c", "fc"} <= parts
 
