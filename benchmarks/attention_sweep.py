@@ -42,7 +42,9 @@ does; its first line is the selection alone, search then check
 (``dsa_index`` and ``dsa_index_hinted`` on the first's threshold and the same
 operands: the two times side by side, the share of blocks whose tie search
 ran and of blocks whose hint held, which must be all; exit 1 otherwise;
-``--select-impl`` times another copy of ``ops/sparse_attention.py``):
+``--select-impl`` times another copy of ``ops/sparse_attention.py``), and
+its second the indexer's loss alone (``dsa_kl``: the value variant and the
+gradient variant, a call of each):
 
     python benchmarks/attention_sweep.py --shape 1,16384,32/4,128 --topk 2048
 
@@ -111,6 +113,22 @@ def kernel_ms(trace_dir: str, calls: int, kernels=KERNELS) -> dict:
                 total[kernel] += int(event.duration_ns)
         break
     return {k: ns / 1e6 / calls for k, ns in total.items()}
+
+
+def traced_kernel_ms(call, calls: int, kernels) -> dict:
+    """:func:`kernel_ms` of ``calls`` calls of ``call()`` (compiled before)
+    under a profiler trace of their own, and the last call's result."""
+    import jax
+
+    trace_dir = tempfile.mkdtemp(prefix="attention_sweep_")
+    try:
+        with jax.profiler.trace(trace_dir):
+            for _ in range(calls):
+                out = call()
+            jax.block_until_ready(out)
+        return kernel_ms(trace_dir, calls, kernels), out
+    finally:
+        shutil.rmtree(trace_dir, ignore_errors=True)
 
 
 def load_impl(path: str | None):
@@ -185,19 +203,13 @@ def time_geometry(
         return jnp.sum(out.astype(jnp.float32) * w.astype(jnp.float32))
 
     step = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
-    jax.block_until_ready(step(q, k, v))  # compiles
-    trace_dir = tempfile.mkdtemp(prefix="attention_sweep_")
     try:
-        with jax.profiler.trace(trace_dir):
-            for _ in range(calls):
-                out = step(q, k, v)
-            jax.block_until_ready(out)
-        return (
-            kernel_ms(trace_dir, calls, kernels_of(topk, window)),
-            flash_layout_of(module, q, k, v),
+        jax.block_until_ready(step(q, k, v))  # compiles
+        ms, _ = traced_kernel_ms(
+            lambda: step(q, k, v), calls, kernels_of(topk, window)
         )
+        return ms, flash_layout_of(module, q, k, v)
     finally:
-        shutil.rmtree(trace_dir, ignore_errors=True)
         setattr(module, chunk_name, module_chunk)
 
 
@@ -254,18 +266,58 @@ def time_selection(sparse_ops, batch, seq, dtype, topk, calls) -> dict:
         }
 
     jax.block_until_ready(both(qi, ki, wi))  # compiles
-    trace_dir = tempfile.mkdtemp(prefix="attention_sweep_")
-    try:
-        with jax.profiler.trace(trace_dir):
-            for _ in range(calls):
-                out = both(qi, ki, wi)
-            jax.block_until_ready(out)
-        ms = kernel_ms(trace_dir, calls, SELECTION_KERNELS)
-    finally:
-        shutil.rmtree(trace_dir, ignore_errors=True)
+    ms, out = traced_kernel_ms(
+        lambda: both(qi, ki, wi), calls, SELECTION_KERNELS
+    )
     return {
         **{name: float(value) for name, value in out.items()},
         "ms": {k: round(v, 4) for k, v in ms.items() if v},
+    }
+
+
+def time_kl(sparse_ops, shape, kv_heads, dtype, topk, calls) -> dict:
+    """The indexer's loss alone (``dsa_kl``) over the same seeded operands
+    and set: milliseconds a call of the value variant and of the gradient
+    variant, each in a program of its own (both carry the one name), and
+    whether the gradient variant's value is the value variant's."""
+    import jax
+    import jax.numpy as jnp
+
+    from elasticdl_tpu.ops import attention
+
+    batch, seq, heads, d_qk, _ = shape
+    keys = jax.random.split(jax.random.PRNGKey(0), 7)
+    q, k = (
+        jax.random.normal(key, (batch, seq, h, d_qk), jnp.float32).astype(dtype)
+        for key, h in zip(keys, (heads, kv_heads or heads))
+    )
+    qi, ki, wi = indexer_operands(keys[4:], batch, seq, dtype)
+    mask, lse_i, _, _ = jax.jit(
+        lambda qi, ki, wi: sparse_ops.index_select(qi, ki, wi, topk)
+    )(qi, ki, wi)
+    _, lse = jax.jit(attention.selected_flash_attention)(
+        q, k, k, mask, sparse_ops.transpose_mask(mask)
+    )
+    operands = (q, k, lse, mask, qi, ki, wi, lse_i)
+    programs = {
+        "value": jax.jit(sparse_ops.indexer_kl),
+        # (the call behind ``indexer_kl``'s backward rule and, since PR 43,
+        # behind ``indexer_kl_with_grads``: a copy from before has it too)
+        "with_grads": jax.jit(
+            lambda *xs: sparse_ops._kl_call(*xs, None, None, True)
+        ),
+    }
+    ms, made = {}, {}
+    for name, program in programs.items():
+        jax.block_until_ready(program(*operands))  # compiles
+        times, made[name] = traced_kernel_ms(
+            lambda: program(*operands), calls, (sparse_ops.INDEXER_KL,)
+        )
+        ms[name] = round(times[sparse_ops.INDEXER_KL], 4)
+    return {
+        "ms": ms,
+        "kl": float(made["value"]),
+        "same_value": bool(made["value"] == made["with_grads"][0]),
     }
 
 
@@ -361,6 +413,21 @@ def main() -> int:
         )
         if selection.get("unequal") or selection.get("hint_held", 1.0) < 1.0:
             status = 1  # identical operands: the hint holds and nothing differs
+        print(
+            json.dumps(
+                {
+                    "impl": args.select_impl
+                    or "elasticdl_tpu.ops.sparse_attention",
+                    "shape": list(shape), "kv_heads": kv_heads,
+                    "topk": args.topk, "device_kind": device.device_kind,
+                    "indexer_kl": time_kl(
+                        sparse_ops, shape, kv_heads, jnp.dtype(args.dtype),
+                        args.topk, args.calls,
+                    ),
+                }
+            ),
+            flush=True,
+        )
     for geometry in geometries:
         line = {
             "impl": args.label or args.impl or "elasticdl_tpu.ops.attention",

@@ -21,6 +21,13 @@ import jax.numpy as jnp
 import elasticdl_tpu.ops.attention as attention_ops
 
 
+# the indexer's four submodules (``MultiHeadSelfAttention._indexer``): the
+# parameters the indexer's loss trains, and no other loss
+_INDEXER_PARAMS = (
+    "index_query", "index_key", "index_key_norm", "index_weights"
+)
+
+
 def _kernels_interpret() -> bool:
     """Whether the sparse-attention kernels run interpreted: by the
     platform of the mesh the trainer registered, as ``ops.attention.attention``
@@ -254,37 +261,24 @@ class MultiHeadSelfAttention(nn.Module):
         from elasticdl_tpu.ops import sparse_attention as sparse_ops
         from elasticdl_tpu.telemetry.router_load import SELECTION_STATS
 
-        heads, width = self.index_heads, self.index_head_dim
         interpret = _kernels_interpret()
+        detached = jax.lax.stop_gradient(x)
+        # a first pass that is being differentiated (its offers reach the
+        # recomputed pass) runs the indexer's backward pass itself, below
+        offering = recompute.offers_kept()
         with jax.named_scope("indexer"):
-            detached = jax.lax.stop_gradient(x)
-
-            def dense(features, name):
-                return nn.DenseGeneral(
-                    features, use_bias=False, dtype=self.dtype, name=name
+            if offering:
+                (qi, ki, weights), indexer_vjp = nn.vjp(
+                    lambda layer, x: layer._indexer(x, positions), self, detached
                 )
-
-            qi = dense((heads, width), "index_query")(detached)
-            ki = nn.LayerNorm(
-                epsilon=self.norm_eps, dtype=self.dtype, name="index_key_norm"
-            )(dense(width, "index_key")(detached))
-            weights = dense(heads, "index_weights")(detached).astype(
-                jnp.float32
-            ) * (heads * width) ** -0.5
-            if self.rope_theta:
-                # the sections in proportion, over the indexer's narrower head
-                scale = 2 * sum(self.mrope_section) // width or 1
-                sections = tuple(n // scale for n in self.mrope_section)
-                qi = rope(qi, positions, self.rope_theta, sections=sections)
-                ki = rope(
-                    ki[..., None, :], positions, self.rope_theta,
-                    sections=sections,
-                )[..., 0, :]
+            else:
+                qi, ki, weights = self._indexer(detached, positions)
         with jax.named_scope("index_select"):
             # a recomputed pass is handed the threshold its first pass found
             # and checks it by count instead of searching again
             threshold = recompute.found()
-            if threshold is None:
+            recomputed = threshold is not None
+            if not recomputed:
                 mask, lse_i, kept, ties, searched, threshold = (
                     sparse_ops.index_select_threshold(
                         qi, ki, weights, self.index_topk, interpret=interpret
@@ -315,11 +309,39 @@ class MultiHeadSelfAttention(nn.Module):
             q, k, v, mask, mask_t, interpret=interpret
         )
         with jax.named_scope("indexer_kl"):
-            kl = sparse_ops.indexer_kl(
+            operands = (
                 jax.lax.stop_gradient(q), jax.lax.stop_gradient(k),
                 jax.lax.stop_gradient(lse), mask, qi, ki, weights, lse_i,
-                interpret=interpret,
-            ) * (self.index_kl_weight / (x.shape[0] * x.shape[1]))
+            )
+            if recomputed:
+                # the first pass ran the loss's one call a layer and step
+                # and offered its value and the gradient to the indexer's
+                # parameters: no kernel here, and nothing to differentiate
+                # but the handing over
+                found = recompute.found()
+                kl = sparse_ops.indexer_kl_found(
+                    {name: self.variables["params"][name] for name in found[1]},
+                    found,
+                )
+            elif offering:
+                # the gradient variant writes the value too, and the
+                # gradient depends on nothing downstream of the layer (the
+                # target and the selection are constants, the indexer reads
+                # a detached input): it is pushed through the indexer's
+                # projections here, where the backward pass would, and
+                # offered as the parameters' own (9 MB a layer at the
+                # published widths where the three operands' is 37)
+                kl, grads = sparse_ops.indexer_kl_with_grads(
+                    *operands, interpret=interpret
+                )
+                with jax.named_scope("indexer"):
+                    ours = indexer_vjp(grads)[0]["params"]
+                recompute.offer(
+                    (kl, {name: ours[name] for name in _INDEXER_PARAMS})
+                )
+            else:  # a pass nobody differentiates pays for no gradient
+                kl = sparse_ops.indexer_kl(*operands, interpret=interpret)
+            kl = kl * (self.index_kl_weight / (x.shape[0] * x.shape[1]))
         self.sow(
             "losses", "indexer_kl", kl,
             init_fn=lambda: jnp.zeros((), jnp.float32),
@@ -333,6 +355,35 @@ class MultiHeadSelfAttention(nn.Module):
                 reduce_fn=lambda _prev, new: new,
             )
         return out
+
+    def _indexer(self, detached, positions):
+        """The indexer's queries ``(batch, seq, heads, width)``, its one key
+        head ``(batch, seq, width)`` and its float32 head weights."""
+        heads, width = self.index_heads, self.index_head_dim
+        query, key, key_norm, head_weights = _INDEXER_PARAMS
+
+        def dense(features, name):
+            return nn.DenseGeneral(
+                features, use_bias=False, dtype=self.dtype, name=name
+            )
+
+        qi = dense((heads, width), query)(detached)
+        ki = nn.LayerNorm(
+            epsilon=self.norm_eps, dtype=self.dtype, name=key_norm
+        )(dense(width, key)(detached))
+        weights = dense(heads, head_weights)(detached).astype(
+            jnp.float32
+        ) * (heads * width) ** -0.5
+        if self.rope_theta:
+            # the sections in proportion, over the indexer's narrower head
+            scale = 2 * sum(self.mrope_section) // width or 1
+            sections = tuple(n // scale for n in self.mrope_section)
+            qi = rope(qi, positions, self.rope_theta, sections=sections)
+            ki = rope(
+                ki[..., None, :], positions, self.rope_theta,
+                sections=sections,
+            )[..., 0, :]
+        return qi, ki, weights
 
     def _sow_block_plan(self, q, k, v):
         """What the window kernels' block plan visited, masked and never

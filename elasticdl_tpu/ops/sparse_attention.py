@@ -80,14 +80,18 @@ def _unordered(key):
     )
 
 
-def _index_scores(qi_ref, w_cols, kb):
+def _index_scores(qi_ref, w_cols, kb, products=None):
     """``sum_j w_j relu(qi_j @ kb^T)`` for a block of keys (``qi_ref``: a
     block ``(1, heads, rows, width)``): float32 sums of exact products,
-    ReLU, weights and their sum in float32."""
+    ReLU, weights and their sum in float32.  ``products``: a scratch
+    ``(heads, rows, keys)`` that is left each head's product as it was
+    before its ReLU."""
     total = None
     for j, w in enumerate(w_cols):
-        s = jnp.maximum(_scores(qi_ref[0, j], kb), 0.0)
-        s = s * _lanes_to(w, kb.shape[0])
+        s = _scores(qi_ref[0, j], kb)
+        if products is not None:
+            products[j] = s
+        s = jnp.maximum(s, 0.0) * _lanes_to(w, kb.shape[0])
         total = s if total is None else total + s
     return total
 
@@ -427,9 +431,12 @@ def _kl_kernel(
     the block's index scores again, the row's ``sum p (log p - log
     softmax I)`` accumulated along k; with ``with_grads`` also the
     gradient to the indexer's queries, weights (accumulated along k) and
-    keys (a part a q-block, summed outside), ``dI = softmax_S I - p``."""
+    keys (a part a q-block, summed outside), ``dI = softmax_S I - p``; a
+    head's ``ds`` needs every head's sum first, so the index products are
+    kept in VMEM (``s_scr``) between the two and made once a cell."""
+    s_scr = None
     if with_grads:
-        kl_ref, dqi_ref, dw_ref, dki_ref, kl_scr, dqi_scr, dw_scr = refs
+        kl_ref, dqi_ref, dw_ref, dki_ref, kl_scr, dqi_scr, dw_scr, s_scr = refs
     else:
         kl_ref, kl_scr = refs
     i, j = pl.program_id(1), pl.program_id(2)
@@ -458,7 +465,7 @@ def _kl_kernel(
         p = jnp.where(chosen, p * (1.0 / heads), 0.0)
         kib = ki_ref[0]
         w_cols = [_row_to_lanes(w_ref[h]) for h in range(index_heads)]
-        log_q = _index_scores(qi_ref, w_cols, kib) - _lanes_to(
+        log_q = _index_scores(qi_ref, w_cols, kib, s_scr) - _lanes_to(
             _row_to_lanes(lsei_ref[0]), block_k
         )
         kl = jnp.where(
@@ -471,7 +478,7 @@ def _kl_kernel(
         dki = jnp.zeros(dki_ref.shape[2:], jnp.float32)
         for h in range(index_heads):
             qih = qi_ref[0, h]
-            s = _scores(qih, kib)
+            s = s_scr[h]
             dw_scr[h] = dw_scr[h] + _row_sum(d_index * jnp.maximum(s, 0.0))
             ds = jnp.where(
                 s > 0.0, d_index * _lanes_to(w_cols[h], block_k), 0.0
@@ -541,6 +548,7 @@ def _kl_call(q, k, lse, mask, qi, ki, w, lse_i, sm_scale, interpret, with_grads)
         scratch += [
             pltpu.VMEM((index_heads, block_q, width), jnp.float32),
             pltpu.VMEM((index_heads, block_q, _LANES), jnp.float32),
+            pltpu.VMEM((index_heads, block_q, block_k), jnp.float32),
         ]
     made = pl.pallas_call(
         functools.partial(
@@ -643,6 +651,50 @@ def _kl_bwd_rule(sm_scale, interpret, res, g):
 
 
 indexer_kl.defvjp(_kl_fwd_rule, _kl_bwd_rule)
+
+
+def indexer_kl_with_grads(
+    q, k, lse, mask, qi, ki, w, lse_i, sm_scale: float | None = None,
+    interpret: bool | None = None,
+):
+    """:func:`indexer_kl`'s value AND its gradients to ``qi``, ``ki`` and
+    ``w`` (in their dtypes, before any cotangent) from ONE call of the
+    gradient variant, which writes the value's rows too: ``(kl, (dqi, dki,
+    dw))``.  The gradients depend on nothing downstream of the layer (the
+    target and the selection are constants), so a first pass that knows it
+    is being differentiated (``layers/recompute.py::offers_kept``) makes the
+    loss's one call a layer and step here, and :func:`indexer_kl_found`
+    hands the result to the backward pass.  Not itself differentiable."""
+    kl, grads = _kl_call(
+        q, k, lse, mask, qi, ki, w, lse_i, sm_scale, interpret, True
+    )
+    return kl, tuple(x.astype(z.dtype) for x, z in zip(grads, (qi, ki, w)))
+
+
+@jax.custom_vjp
+def indexer_kl_found(wrt, found):
+    """The indexer's loss where an earlier pass already made its one call:
+    ``found`` is ``(kl, grads)``, ``grads`` the loss's gradient to the tree
+    ``wrt`` (:func:`indexer_kl_with_grads`'s three, or what they are to the
+    parameters behind them).  Returns ``kl``; the backward rule is ``grads``
+    times the cotangent.  No kernel."""
+    return found[0]
+
+
+def _kl_found_fwd_rule(wrt, found):
+    return found
+
+
+def _kl_found_bwd_rule(grads, g):
+    # ``grads`` was rounded to its operand's dtype before the cotangent where
+    # :func:`indexer_kl`'s rule rounds after it: the same bits where ``g`` is
+    # a power of two, one rounding apart elsewhere
+    return (
+        jax.tree_util.tree_map(lambda x: (g * x).astype(x.dtype), grads), None
+    )
+
+
+indexer_kl_found.defvjp(_kl_found_fwd_rule, _kl_found_bwd_rule)
 
 
 # ---- the materialised form (tests, chip_smoke.py) ----------------------------

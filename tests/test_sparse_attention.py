@@ -347,3 +347,175 @@ def test_a_recomputed_sparse_layer_is_the_layer(dtype, monkeypatch):
     stats = new["selection_stats"]["block_0"]["attn"]
     assert set(stats) == {"kept_keys", "ties_broken", "tie_search_blocks"}
     assert np.any(grads["block_0"]["attn"]["index_query"]["kernel"])
+
+
+# ---- the indexer's loss makes one pass a layer and step --------------------------
+
+
+def _bf16_ulp(x):
+    """The spacing of bfloat16 at ``x``'s magnitude (8 bits of precision)."""
+    x = np.abs(np.asarray(x, np.float32))
+    return 2.0 ** (np.floor(np.log2(np.maximum(x, 1e-30))) - 7)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize(
+    "cotangent", [2.0**-14, 1.0 / 3.0], ids=["power_of_two", "a_third"]
+)
+def test_the_one_pass_kl_is_the_two_call_kl(dtype, cotangent):
+    """``indexer_kl_found`` on what ``indexer_kl_with_grads`` found against
+    ``indexer_kl`` under ``value_and_grad``: the value bit for bit (the
+    gradient variant writes the rows the value variant writes); the three
+    gradients bit for bit where the cotangent is a power of two (rounding
+    before a scale by one is rounding after it) and within one rounding of
+    the operand's dtype elsewhere; in float32 both are the materialised
+    form's within this file's limits."""
+    seq, topk, block = 256, 48, 128
+    q, k, v, qi, ki, w = operands(seq)
+    mask, mask_t, lse_i, _, _ = selection(qi, ki, w, topk, block)
+    _, lse = attention_ops.selected_flash_attention(q, k, v, mask, mask_t)
+    qi, ki = qi.astype(dtype), ki.astype(dtype)
+
+    def two_calls(qi, ki, w):
+        return cotangent * sparse_ops.indexer_kl(q, k, lse, mask, qi, ki, w, lse_i)
+
+    found = sparse_ops.indexer_kl_with_grads(q, k, lse, mask, qi, ki, w, lse_i)
+    assert [x.dtype for x in found[1]] == [qi.dtype, ki.dtype, w.dtype]
+
+    def one_pass(qi, ki, w):
+        return cotangent * sparse_ops.indexer_kl_found((qi, ki, w), found)
+
+    want, want_grads = jax.value_and_grad(two_calls, argnums=(0, 1, 2))(qi, ki, w)
+    value, grads = jax.value_and_grad(one_pass, argnums=(0, 1, 2))(qi, ki, w)
+    np.testing.assert_array_equal(value, want)
+    for got, wanted, name in zip(grads, want_grads, ("qi", "ki", "w")):
+        assert got.dtype == wanted.dtype
+        got, wanted = (np.asarray(x, np.float32) for x in (got, wanted))
+        if cotangent == 2.0**-14 or dtype == "float32" or name == "w":
+            np.testing.assert_array_equal(got, wanted, err_msg=f"d{name}")
+        else:
+            assert np.all(np.abs(got - wanted) <= _bf16_ulp(wanted)), name
+    if dtype == "float32":
+        _, probs = sparse_ops.selected_reference(
+            q, k, v, sparse_ops.dense_mask(mask)
+        )
+
+        def materialised(qi, ki, w):
+            return cotangent * sparse_ops.indexer_kl_reference(
+                probs, sparse_ops.index_scores_reference(qi, ki, w),
+                sparse_ops.dense_mask(mask),
+            )
+
+        wanted, want_grads = jax.value_and_grad(
+            materialised, argnums=(0, 1, 2)
+        )(qi, ki, w)
+        np.testing.assert_allclose(value, wanted, rtol=1e-5)
+        for got, want_grad, name in zip(grads, want_grads, ("qi", "ki", "w")):
+            np.testing.assert_allclose(
+                got, want_grad, rtol=2e-4, atol=2e-5 * cotangent,
+                err_msg=f"d{name}",
+            )
+
+
+def _kl_calls(jaxpr):
+    """The number of results of every ``dsa_kl`` call a jaxpr holds, its
+    sub-programs' too: 1 the value variant, 4 with the three gradients."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            if eqn.params["name"] == sparse_ops.INDEXER_KL:
+                found.append(len(eqn.outvars))
+            continue
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    found += _kl_calls(sub)
+    return sorted(found)
+
+
+def _tiny_sparse_model(remat, layers=2):
+    from elasticdl_tpu.models import long_seq_transformer as zoo
+
+    model = zoo.custom_model(
+        remat_layers=remat, vocab_size=64, embed_dim=32, num_heads=4,
+        num_kv_heads=2, head_dim=8, num_layers=layers, dtype="float32",
+        norm="rmsnorm", use_bias=False, positions="rope", index_topk=8,
+        index_heads=2, index_head_dim=8, mlp="swiglu",
+    )
+    tokens = np.random.default_rng(0).integers(64, size=(2, 64)).astype(np.int32)
+    features = {"tokens": tokens}
+    variables = model.init(jax.random.PRNGKey(0), features, training=False)
+    state = {k: v for k, v in variables.items() if k != "params"}
+
+    def loss(params):
+        logits, new = model.apply(
+            {"params": params, **state}, features, training=True,
+            mutable=list(state) + ["losses"],
+        )
+        return zoo.loss(tokens, logits) + sum(
+            jax.tree_util.tree_leaves(new["losses"])
+        )
+
+    return loss, variables["params"]
+
+
+@pytest.mark.parametrize("remat", [True, False], ids=["remat", "no_remat"])
+def test_a_pass_that_is_not_differentiated_pays_for_no_gradient(remat):
+    """An undifferentiated apply traces the loss's value variant alone, one
+    call a layer, recomputed layers or not; a differentiated step without
+    ``remat_layers`` is the two-call path as it was (value in the forward
+    rule, gradients in the backward rule).  What a differentiated
+    ``remat_layers`` step compiles to is ``tests/test_op_scopes.py``'s."""
+    loss, params = _tiny_sparse_model(remat)
+    assert _kl_calls(jax.make_jaxpr(loss)(params).jaxpr) == [1, 1]
+    if not remat:
+        step = jax.make_jaxpr(jax.value_and_grad(loss))(params)
+        assert _kl_calls(step.jaxpr) == [1, 1, 4, 4]
+
+
+def test_a_layer_can_see_that_its_first_pass_is_being_differentiated():
+    """``recompute.offers_kept``: True in the forward rule's pass of a
+    differentiated layer, False in an undifferentiated pass, in a recomputed
+    one and outside ``remat_with_findings``; an offer made where it is True
+    is what the recomputed pass finds."""
+    import flax.linen as nn
+
+    from elasticdl_tpu.layers import recompute
+
+    seen = []
+
+    class Probe(nn.Module):
+        @nn.compact
+        def __call__(self, x):
+            finding = recompute.found()
+            kept = recompute.offers_kept()
+            seen.append((finding is not None, kept))
+            if finding is None:
+                recompute.offer(jnp.full((), 3.0 if kept else 5.0))
+                finding = 1.0
+            return nn.Dense(4)(x) * finding
+
+    assert recompute.offers_kept() is False
+    x = jnp.ones((2, 4))
+    plain = Probe()
+    params = plain.init(jax.random.PRNGKey(0), x)
+    assert seen == [(False, False)]  # no ``remat_with_findings`` around it
+    layer = recompute.remat_with_findings(Probe)()
+    del seen[:]
+    out = layer.apply(params, x)
+    assert seen == [(False, False)]
+    np.testing.assert_array_equal(out, plain.apply(params, x))
+    del seen[:]
+    grads = jax.grad(lambda p: jnp.sum(layer.apply(p, x)))(params)
+    assert seen.count((False, True)) == 1  # the forward rule's pass
+    assert (True, False) in seen  # the recomputed pass
+    assert (True, True) not in seen
+    # the backward pass differentiated the recomputed layer, which was
+    # handed the kept pass's offer (3.0) and not an undifferentiated one's
+    want = jax.grad(lambda p: jnp.sum(plain.apply(p, x) * 3.0))(params)
+    for got, wanted in zip(
+        jax.tree_util.tree_leaves(grads), jax.tree_util.tree_leaves(want)
+    ):
+        np.testing.assert_allclose(got, wanted, rtol=1e-6)
+    assert recompute.offers_kept() is False
