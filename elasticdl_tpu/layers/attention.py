@@ -19,6 +19,8 @@ import jax
 import jax.numpy as jnp
 
 import elasticdl_tpu.ops.attention as attention_ops
+from elasticdl_tpu.ops import rotary as rotary_ops
+from elasticdl_tpu.ops import ssd as ssd_ops
 
 
 # the indexer's four submodules (``MultiHeadSelfAttention._indexer``): the
@@ -718,6 +720,21 @@ class TransformerBlock(nn.Module):
             return dense(y.shape[-1], "mlp_down")(hidden)
 
 
+def _rope_takes_kernel(x, interleave: bool) -> bool:
+    """Whether :func:`rope` hands ``x`` to ``ops/rotary.py``'s kernel: the
+    shape tiles (``rotary.rotate_tile``) and no ``sp`` axis shards the
+    sequence, where GSPMD partitions the plain form and the kernel, mapped
+    over the batch alone, would gather it."""
+    if not rotary_ops.rotate_tile(x.shape, interleave):
+        return False
+    mesh, sp_axis, _ = attention_ops.get_attention_mesh()
+    return not (
+        mesh is not None
+        and sp_axis in mesh.axis_names
+        and mesh.shape[sp_axis] > 1
+    )
+
+
 def rope(
     x, positions, theta: float, interleave: bool = False, sections=()
 ):
@@ -733,29 +750,43 @@ def rope(
     multimodal RoPE): frequency ``i`` takes its angle from the component
     whose section it lies in, ``sections[c]`` frequencies each in order
     (temporal, height, width).  Where every component is the token's index
-    (text) that is the rule above, and (seq,) positions take it."""
+    (text) that is the rule above, and (seq,) positions take it.
+
+    Which code runs is chosen from the shapes (:func:`_rope_takes_kernel`):
+    rotate-half over heads a whole number of 128-lane tiles wide, with at
+    least one tile of 512 rows and the sequence whole on its device, is
+    ``ops/rotary.py``'s single-pass kernel (the same products and sums a
+    number, in float32; forward, recomputed and backward); everything else
+    (``interleave``, a 64-wide head, a decode step, a small model, a
+    sequence sharded over ``sp``) is :func:`rope_plain`."""
+    if not _rope_takes_kernel(x, interleave):
+        return rope_plain(x, positions, theta, interleave, sections)
+    # one pass of ops/rotary.py's kernel over the folded form the attention
+    # kernels take: the two transposes are layouts for XLA to assign
+    by_batch = positions.ndim == 3
+    out = ssd_ops.over_batch(
+        lambda x, positions, interpret: rotary_ops.rotate_half(
+            x, positions, theta, tuple(sections), interpret
+        ),
+        (x.transpose(0, 2, 1, 3),) + ((positions,) if by_batch else ()),
+        () if by_batch else (positions,),
+    )
+    return out.transpose(0, 2, 1, 3)
+
+
+def rope_plain(
+    x, positions, theta: float, interleave: bool = False, sections=()
+):
+    """:func:`rope` in plain ``jnp``, for every shape."""
     half = x.shape[-1] // 2
-    rate = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    angles = rotary_ops.angles(positions, theta, half, sections)
     if positions.ndim == 3:
-        if sum(sections) != half:
-            raise ValueError(
-                f"mrope sections {sections} do not cover {half} frequencies"
-            )
-        component = jnp.repeat(
-            jnp.arange(len(sections)), jnp.asarray(sections),
-            total_repeat_length=half,
-        )
-        # (batch, seq, half): each frequency's own component
-        of_frequency = jnp.take(
-            positions.astype(jnp.float32), component, axis=1
-        ).transpose(0, 2, 1)
-        angles = (of_frequency * rate)[:, :, None, :]
+        angles = angles[:, :, None, :]
         cos, sin = jnp.cos(angles), jnp.sin(angles)
         x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
         return jnp.concatenate(
             [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
         ).astype(x.dtype)
-    angles = positions.astype(jnp.float32)[:, None] * rate[None, :]
     cos = jnp.cos(angles)[None, :, None, :]
     sin = jnp.sin(angles)[None, :, None, :]
     if interleave:

@@ -1,0 +1,176 @@
+"""Rotary positions in the rotate-half convention as one Pallas pass that
+reads an array once and writes it once (``layers/attention.py::rope`` holds
+the plain form and chooses between the two by shape)::
+
+    out = x * C + roll(x, width / 2 lanes) * S      a head, in float32
+    C = [cos | cos]    S = [-sin | sin]             (tokens, width) tables
+
+which is ``x1 * cos - x2 * sin | x2 * cos + x1 * sin`` product for product and
+sum for sum, so the same bits: read in ``x``'s dtype, computed in float32,
+written in ``x``'s dtype.  A head is a whole number of 128-lane tiles wide and
+its partner half a head away, a rotation of whole lanes; a 64-wide head's
+partner is 32 lanes away inside a tile that holds two heads, and the plain
+form keeps it.
+
+**The layout is the point.**  Where heads are 128 wide the attention kernels
+take ``(batch * heads, tokens, width)`` (``ops/attention.py::
+_heads_per_block``), XLA writes a projection and the QK-norm after it straight
+into that layout, and a ``pallas_call`` pins its operands to the row-major
+layout of the shape it is handed.  So the kernel is handed ``(batch, heads,
+tokens, width)``, the transpose of the layer's ``(batch, tokens, heads,
+width)``, reads it folded and writes it folded, forward and backward: both
+transposes around it are layouts XLA was assigning already, and the compiled
+steps hold no copy of a q-sized array that they did not hold before.  (Handed
+the projection's merged rows ``(batch, tokens, heads * width)`` instead, the
+step of ``trinity_mini_seq16384`` grew 32 float32 copies of q and k: XLA kept
+the projection folded and turned the norm's output into rows through a copy;
+PERF.md section 6, PR 45.)
+
+A grid step is a tile of rows by a block of heads; the tables' block follows
+the row tile alone and the heads are the grid's innermost dimension, so a
+table block is fetched once a row tile.  The backward is the same body with
+``S`` negated (``roll`` by half a head is its own transpose, and it moves
+``S`` onto ``-S``).  The ``custom_vjp``'s residuals are the positions alone:
+the tables are made again where the backward wants them (XLA shares one pair
+a step where the positions are the tokens' indices), never kept a layer.
+
+The names below are the device trace's op names; none starts with ``flash_``,
+``swa_``, ``dsa_``, ``expert_gmm`` or ``ssd_``, which ``perf/`` reads as those
+kernels.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+ROPE_FWD = "rope_fwd"
+ROPE_BWD = "rope_bwd"
+
+_LANES = 128
+# rows of a grid step, and the lanes of its heads together at most (TPU v5e,
+# 1 x 16,384 x 32 : 4 heads of 128: docs/designs/rotary_kernel.md)
+_ROWS = 512
+_BLOCK_LANES = 1024
+
+_f32 = jnp.float32
+
+
+def rotate_tile(shape, interleave: bool = False):
+    """``(row tile, heads a block)`` the kernels take ``x`` of ``shape``
+    (batch, tokens, heads, width) with, from what the call can see; None
+    where the plain form stays: adjacent pairs, a head that is no whole
+    number of lane tiles (its partner sits inside a tile), fewer rows than
+    one tile (a decode step, a small model)."""
+    if interleave or len(shape) != 4:
+        return None
+    _, rows, heads, width = shape
+    if width % _LANES or rows < _ROWS:
+        return None
+    held = max(1, min(heads, _BLOCK_LANES // width))
+    while heads % held:
+        held -= 1
+    return _ROWS, held
+
+
+def angles(positions, theta: float, half: int, sections=()):
+    """Float32 ``positions * theta^(-i / half)`` for the ``half`` pairs of a
+    head: ``(tokens, half)`` for ``positions`` (tokens,); ``(batch, tokens,
+    half)`` for (batch, components, tokens), frequency ``i`` taking the
+    component whose section it lies in, ``sections[c]`` frequencies each in
+    order.  The one definition both forms of ``layers/attention.py::rope``
+    turn by."""
+    rate = theta ** (-jnp.arange(half, dtype=_f32) / half)
+    if positions.ndim != 3:
+        return positions.astype(_f32)[:, None] * rate[None, :]
+    if sum(sections) != half:
+        raise ValueError(
+            f"mrope sections {sections} do not cover {half} frequencies"
+        )
+    component = jnp.repeat(
+        jnp.arange(len(sections)), jnp.asarray(sections),
+        total_repeat_length=half,
+    )
+    # (batch, tokens, half): each frequency's own component
+    of_frequency = jnp.take(
+        positions.astype(_f32), component, axis=1
+    ).transpose(0, 2, 1)
+    return of_frequency * rate
+
+
+def tables(positions, theta: float, width: int, sections=()):
+    """``(C, S)`` float32, ``[cos | cos]`` and ``[-sin | sin]`` of
+    :func:`angles` over a head of ``width``."""
+    turn = angles(positions, theta, width // 2, sections)
+    cos, sin = jnp.cos(turn), jnp.sin(turn)
+    return (
+        jnp.concatenate([cos, cos], axis=-1),
+        jnp.concatenate([-sin, sin], axis=-1),
+    )
+
+
+def _rotate_kernel(x_ref, cos_ref, sin_ref, out_ref, *, width, backward):
+    """Some heads of a tile of rows, ``(heads, rows, width)``.  The
+    backward takes ``S`` negated: ``roll`` by half a head is its own
+    transpose, and it moves ``S`` onto ``-S``."""
+    cos, sin = cos_ref[...], sin_ref[...]
+    for head in range(x_ref.shape[0]):
+        x = x_ref[head].astype(_f32)
+        turned = pltpu.roll(x, width // 2, 1) * sin
+        out = x * cos - turned if backward else x * cos + turned
+        out_ref[head] = out.astype(out_ref.dtype)
+
+
+def _rotate(x, cos, sin, interpret, backward=False):
+    """The one ``pallas_call``, over ``x`` (batch, heads, tokens, width)."""
+    batch, heads, rows, width = x.shape
+    tile, held = rotate_tile((batch, rows, heads, width))
+    folded = pl.BlockSpec(
+        (None, held, tile, width), lambda b, i, h: (b, h, i, 0)
+    )
+    table = (
+        pl.BlockSpec((None, tile, width), lambda b, i, h: (b, i, 0))
+        if cos.ndim == 3
+        else pl.BlockSpec((tile, width), lambda b, i, h: (i, 0))
+    )
+    return pl.pallas_call(
+        functools.partial(_rotate_kernel, width=width, backward=backward),
+        grid=(batch, pl.cdiv(rows, tile), heads // held),
+        in_specs=[folded, table, table],
+        out_specs=folded,
+        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")
+        ),
+        interpret=interpret,
+        name=ROPE_BWD if backward else ROPE_FWD,
+    )(x, cos, sin)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
+def rotate_half(x, positions, theta, sections, interpret):
+    """Rotary positions on ``x`` (batch, heads, tokens, width), folded as
+    the attention kernels take it; ``positions`` and ``sections`` as
+    :func:`tables` takes them.  The shape must tile (:func:`rotate_tile`)."""
+    cos, sin = tables(positions, theta, x.shape[3], sections)
+    return _rotate(x, cos, sin, interpret)
+
+
+def _rotate_half_fwd(x, positions, theta, sections, interpret):
+    return rotate_half(x, positions, theta, sections, interpret), positions
+
+
+def _rotate_half_bwd(theta, sections, interpret, positions, d_out):
+    cos, sin = tables(positions, theta, d_out.shape[3], sections)
+    d_x = _rotate(d_out, cos, sin, interpret, backward=True)
+    if jnp.issubdtype(positions.dtype, jnp.floating):
+        return d_x, jnp.zeros_like(positions)
+    return d_x, np.zeros(positions.shape, jax.dtypes.float0)
+
+
+rotate_half.defvjp(_rotate_half_fwd, _rotate_half_bwd)

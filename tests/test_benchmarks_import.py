@@ -25,6 +25,7 @@ def test_the_kept_drivers():
         "dispatch_overhead_bench.py",
         "preemption_accuracy_bench.py",
         "reform_bench.py",
+        "rope_sweep.py",
     ]
 
 

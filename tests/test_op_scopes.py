@@ -466,6 +466,43 @@ def one_chip_mesh():
     return MeshConfig.from_string("dp=1").create(devices=topology.devices[:1])
 
 
+def _lowered_for_the_chip(mesh, model, loss, tx, tokens=1024):
+    """The train step of ``model`` over one sequence of ``tokens``, lowered
+    for ``mesh``'s described chip from shapes alone (nothing is placed)."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    features = {"tokens": np.zeros((1, tokens), np.int32)}
+    labels = np.zeros((1, tokens), np.int32)
+    shapes = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), features, training=False)
+    )
+    whole = NamedSharding(mesh, PartitionSpec())
+
+    def described(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(np.shape(x), x.dtype, sharding=whole),
+            tree,
+        )
+
+    def zeros(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jnp.zeros(x.shape, x.dtype), tree
+        )
+
+    state = jax.eval_shape(
+        lambda: TrainState.create(
+            model.apply, zeros(shapes["params"]), tx,
+            zeros({k: v for k, v in shapes.items() if k != "params"}),
+        )
+    )
+    step = build_train_step(loss, donate=False)
+    with mesh, attention_ops.attention_mesh_scope(mesh):
+        return step.lower(
+            described(state), described(features), described(labels),
+            described(np.ones((1,), np.float32)),
+        )
+
+
 def test_the_mixers_passes_are_kernels_under_their_own_parts(one_chip_mesh):
     """A Mamba-2 layer wide enough for ``ops/mamba_passes.py`` (8 heads of
     64 in 2 groups of 256 lanes, a 768-wide convolution, 64 steps), each
@@ -547,8 +584,6 @@ def test_sparse_attentions_kernels_are_under_their_own_parts(one_chip_mesh):
     step runs them in, and none reads as a dense flash kernel to ``perf/``'s
     readers, which match by name; ``perf/dsa_rooflines.py``'s shares add up
     over that map."""
-    from jax.sharding import NamedSharding, PartitionSpec
-
     from perf import dsa_rooflines, layer_readers, scope_shares, trace_reduce
 
     model, loss, tx, _, _, _ = _lm_family(
@@ -556,38 +591,7 @@ def test_sparse_attentions_kernels_are_under_their_own_parts(one_chip_mesh):
         head_dim=128, mrope_section=(16, 24, 24), index_topk=256,
         index_heads=4, index_head_dim=64,
     )
-    features = {"tokens": np.zeros((1, 1024), np.int32)}
-    labels = np.zeros((1, 1024), np.int32)
-    shapes = jax.eval_shape(
-        lambda: model.init(jax.random.PRNGKey(0), features, training=False)
-    )
-    whole = NamedSharding(one_chip_mesh, PartitionSpec())
-
-    def described(tree):
-        return jax.tree_util.tree_map(
-            lambda x: jax.ShapeDtypeStruct(np.shape(x), x.dtype, sharding=whole),
-            tree,
-        )
-
-    state = jax.eval_shape(
-        lambda: TrainState.create(
-            model.apply,
-            jax.tree_util.tree_map(
-                lambda x: jnp.zeros(x.shape, x.dtype), shapes["params"]
-            ),
-            tx,
-            jax.tree_util.tree_map(
-                lambda x: jnp.zeros(x.shape, x.dtype),
-                {k: v for k, v in shapes.items() if k != "params"},
-            ),
-        )
-    )
-    step = build_train_step(loss, donate=False)
-    with one_chip_mesh, attention_ops.attention_mesh_scope(one_chip_mesh):
-        compiled = step.lower(
-            described(state), described(features), described(labels),
-            described(np.ones((1,), np.float32)),
-        ).compile()
+    compiled = _lowered_for_the_chip(one_chip_mesh, model, loss, tx).compile()
     scopes = op_scopes.scope_map(compiled)
     found = {}
     for name, (part, phase, kind, _) in scopes.items():
@@ -642,6 +646,60 @@ def test_sparse_attentions_kernels_are_under_their_own_parts(one_chip_mesh):
     assert dsa_rooflines.indexer_time_share(run) == pytest.approx(100 * 1 / 16)
     assert dsa_rooflines.sparse_attention_time_share(run) == pytest.approx(
         100 * 7 / 16
+    )
+
+
+@pytest.mark.parametrize(
+    "config,calls",
+    [
+        # four window layers rotate q and k (the full layer has no rope),
+        # each layer recomputed: 4 x 2 calls a pass
+        ("trinity_mini_26b_a3b", 8),
+        # four sparse layers' main heads, 32 : 4 of 128; the indexer's
+        # 64-wide heads keep the plain form
+        ("keye_vl2_30b_a3b", 8),
+        # adjacent pairs on a 64-wide slice: no call
+        ("joyai_llm_flash_48b_a3b", 0),
+    ],
+)
+def test_the_rotary_kernels_calls_in_the_cells_models(
+    one_chip_mesh, config, calls
+):
+    """The benchmark's models at their published widths and 1,024 tokens,
+    lowered for the described chip: ``ops/rotary.py``'s kernel is called
+    under ``block/attn/rope`` once an array, layer and pass where heads are
+    128 wide and rotate by halves, and nowhere else."""
+    from jax._src.lib import xla_client
+
+    from elasticdl_tpu.ops import rotary
+
+    with open(os.path.join(ROOT, "perf", "configs", config + ".json")) as f:
+        params = json.load(f)["run"]["model_params"]
+    model = lm.custom_model(**params)
+    lowered = _lowered_for_the_chip(
+        one_chip_mesh, model, lm.loss, lm.optimizer()
+    )
+    # the lowered module as HLO text with its metadata, without the kernels'
+    # bodies: what ``scope_map`` reads of a compiled program, before XLA
+    options = xla_client._xla.HloPrintOptions()
+    options.print_metadata = True
+    options.print_backend_config = False
+    scopes = op_scopes._scope_of_text(
+        lowered.compiler_ir("hlo").as_hlo_module().to_string(options)
+    )
+    found = {}
+    for part, phase, kind, _ in scopes.values():
+        if kind == "kernel" and "/rope" in part:
+            found[part, phase] = found.get((part, phase), 0) + 1
+    fwd, bwd = (
+        f"block/attn/rope/{name}" for name in (rotary.ROPE_FWD, rotary.ROPE_BWD)
+    )
+    assert found == (
+        {
+            (fwd, "forward"): calls, (fwd, "recompute"): calls,
+            (bwd, "backward"): calls,
+        }
+        if calls else {}
     )
 
 
