@@ -19,8 +19,8 @@ import jax
 import jax.numpy as jnp
 
 import elasticdl_tpu.ops.attention as attention_ops
+from elasticdl_tpu.ops import on_mesh
 from elasticdl_tpu.ops import rotary as rotary_ops
-from elasticdl_tpu.ops import ssd as ssd_ops
 
 
 # the indexer's four submodules (``MultiHeadSelfAttention._indexer``): the
@@ -31,20 +31,16 @@ _INDEXER_PARAMS = (
 
 
 def _kernels_interpret() -> bool:
-    """Whether the sparse-attention kernels run interpreted: by the
-    platform of the mesh the trainer registered, as ``ops.attention.attention``
-    decides it for the flash kernels."""
-    mesh, _, _ = attention_ops.get_attention_mesh()
-    if mesh is None:
-        return attention_ops.kernel_interpret(jax.default_backend())
-    if mesh.devices.size > 1 and not (
-        jax.sharding.get_abstract_mesh().manual_axes
-    ):
+    """Whether the sparse-attention kernels run interpreted, as
+    ``ops/on_mesh.py`` decides it for every kernel; where it would map the
+    call over a mesh, these kernels have no mapping to give it."""
+    interpret, mesh = on_mesh.resolve()
+    if mesh is not None:
         raise NotImplementedError(
             "sparse attention across devices is not built: one chip a "
             "sequence (docs/designs/sparse_attention.md)"
         )
-    return attention_ops.kernel_interpret(mesh.devices.flat[0].platform)
+    return interpret
 
 
 class HeadsDense(nn.Module):
@@ -231,13 +227,12 @@ class MultiHeadSelfAttention(nn.Module):
             out = self._decode_attend(q, k, v, decode_pos)
         elif self.index_topk:
             out = self._sparse_attend(x, q, k, v, positions)
-        elif self.window:
-            out = attention_ops.attention(
-                q, k, v, causal=self.causal, window=self.window
-            )
-            self._sow_block_plan(q, k, v)
         else:
-            out = attention_ops.attention(q, k, v, causal=self.causal)
+            out = attention_ops.attention(
+                q, k, v, causal=self.causal, window=self.window or None
+            )
+            if self.window:
+                self._sow_block_plan(q, k, v)
         with jax.named_scope("fold"):
             out = out.astype(x.dtype)
         if self.output_gate:
@@ -553,50 +548,43 @@ class TransformerBlock(nn.Module):
     runs (LayerNorm, biases, a 4x GELU MLP, positions added by the model);
     a published architecture is a choice of fields, not another block.
     With ``kind`` (a key of ``LAYER_KINDS``) the block is that one part
-    alone, ``x + part(norm(x))``: a hybrid stack's layer."""
+    alone, ``x + part(norm(x))``: a hybrid stack's layer.
 
-    num_heads: int
-    mlp_ratio: int = 4
+    The block declares what it decides itself.  A part's own fields come as
+    one group a part, pairs of (the part's field name, value), which the
+    block hands to the part whole: a new field of a part is declared by the
+    part and named by the model (``models/long_seq_transformer.py::
+    PART_FIELDS``), and the block does not change."""
+
+    kind: str = ""  # "": attention then feed-forward; else one of LAYER_KINDS
     causal: bool = False
     dropout_rate: float = 0.0
-    num_kv_heads: int = 0  # > 0: grouped-query attention
-    decode: bool = False  # autoregressive decoding with a KV cache
+    # autoregressive decoding with a KV cache: the attention part's, and
+    # refused by the parts that have no cache built
+    decode: bool = False
     max_decode_len: int = 0
     dtype: Any = None  # compute dtype; params stay f32
     norm: str = "layernorm"  # | "rmsnorm"
     norm_eps: float = 1e-6
+    # a second norm, on each part's output: x + norm(part(norm(x)))
+    norm_outputs: bool = False
     use_bias: bool = True
-    rope_theta: float = 0.0  # > 0: rotary positions inside attention
-    qk_norm: bool = False
     # | "swiglu": down(silu(gate(x)) * up(x)) | "relu2": down(relu(up(x))^2)
     mlp: str = "gelu"
     mlp_width: int = 0  # 0: mlp_ratio x the embedding
-    # > 0 replaces the dense MLP with routed experts (layers.moe);
-    # shard experts over ep via moe_sharding_rules
-    num_experts: int = 0
-    experts_per_token: int = 2
-    expert_width: int = 0  # 0: the dense MLP's width
-    norm_topk_prob: bool = False
-    router_aux_weight: float = 0.01
-    router_z_weight: float = 0.001
-    kind: str = ""  # "": attention then feed-forward; else one of LAYER_KINDS
-    head_dim: int = 0  # 0: the embedding over the heads
-    # further fields of layers.moe.MoEMLP, layers.mamba.Mamba2Mixer and
-    # LatentSelfAttention, by their names there; latent_fields given makes
-    # the attention part the latent mixer
-    moe_fields: Any = ()
-    mamba_fields: Any = ()
-    latent_fields: Any = ()
-    # MultiHeadSelfAttention's (per-head QK-norm, positions of several
-    # components, the sparse-attention indexer, the output gate)
-    attention_fields: Any = ()
-    # kind "w": its attention part's window
-    window: int = 0
+    mlp_ratio: int = 4
     # False: rotary positions in the window parts alone, none in the ``*``
     # parts of a stack that has both (AFMoE)
     full_attention_rope: bool = True
-    # a second norm, on each part's output: x + norm(part(norm(x)))
-    norm_outputs: bool = False
+    # MultiHeadSelfAttention's fields (``window``: the ``w`` layers' alone)
+    attention_fields: Any = ()
+    # LatentSelfAttention's; given, the attention part is the latent mixer
+    latent_fields: Any = ()
+    # layers.moe.MoEMLP's; given, routed experts replace the dense MLP
+    # (shard them over ep via moe_sharding_rules)
+    moe_fields: Any = ()
+    # layers.mamba.Mamba2Mixer's
+    mamba_fields: Any = ()
 
     @nn.compact
     def __call__(
@@ -609,11 +597,11 @@ class TransformerBlock(nn.Module):
                 f"unknown layer kind {self.kind!r}; valid: {list(LAYER_KINDS)}"
             )
         parts = [LAYER_KINDS[self.kind]] if self.kind else [
-            "attention", "experts" if self.num_experts > 0 else "mlp"
+            "attention", "experts" if self.moe_fields else "mlp"
         ]
         for part in parts:
             run = getattr(self, "_" + part)
-            if part == "attention" and positions is not None:
+            if part == "attention":
                 run = functools.partial(run, positions=positions)
             x = self._residual(x, run, training, decode_pos)
         return x
@@ -631,45 +619,29 @@ class TransformerBlock(nn.Module):
         return x + y
 
     def _attention(self, y, training, decode_pos, positions=None):
+        shared = dict(
+            causal=self.causal, dtype=self.dtype, norm_eps=self.norm_eps,
+            name="attn",
+        )
         if self.latent_fields:
             if self.decode:
                 raise NotImplementedError(
                     "decoding through a latent-attention layer's cache is "
                     "not built"
                 )
-            return LatentSelfAttention(
-                num_heads=self.num_heads, causal=self.causal,
-                dtype=self.dtype, norm_eps=self.norm_eps,
-                rope_theta=self.rope_theta, name="attn",
-                **dict(self.latent_fields),
-            )(y)
-        windowed = {}
+            return LatentSelfAttention(**shared, **dict(self.latent_fields))(y)
+        fields = dict(self.attention_fields)
         if self.kind == "w":
-            if self.window < 1:
+            if fields.get("window", 0) < 1:
                 raise ValueError("a layer of kind 'w' needs a window")
-            windowed = {"window": self.window}
+        else:
+            fields["window"] = 0
+            if not self.full_attention_rope:
+                fields["rope_theta"] = 0.0
         return MultiHeadSelfAttention(
-            num_heads=self.num_heads,
-            causal=self.causal,
-            num_kv_heads=self.num_kv_heads,
-            decode=self.decode,
-            max_decode_len=self.max_decode_len,
-            dtype=self.dtype,
-            use_bias=self.use_bias,
-            rope_theta=(
-                self.rope_theta
-                if self.kind == "w" or self.full_attention_rope else 0.0
-            ),
-            qk_norm=self.qk_norm,
-            norm_eps=self.norm_eps,
-            head_dim=self.head_dim,
-            name="attn",
-            **dict(self.attention_fields),
-            **windowed,
-        )(
-            y, decode_pos=decode_pos,
-            **({} if positions is None else {"positions": positions}),
-        )
+            decode=self.decode, max_decode_len=self.max_decode_len,
+            use_bias=self.use_bias, **shared, **fields,
+        )(y, decode_pos=decode_pos, positions=positions)
 
     def _mamba(self, y, training, decode_pos):
         from elasticdl_tpu.layers.mamba import Mamba2Mixer
@@ -686,18 +658,9 @@ class TransformerBlock(nn.Module):
     def _experts(self, y, training, decode_pos):
         from elasticdl_tpu.layers.moe import MoEMLP
 
-        width = self.mlp_width or y.shape[-1] * self.mlp_ratio
-        return MoEMLP(
-            num_experts=self.num_experts,
-            experts_per_token=self.experts_per_token,
-            expert_width=self.expert_width or width,
-            norm_topk_prob=self.norm_topk_prob,
-            aux_loss_weight=self.router_aux_weight,
-            z_loss_weight=self.router_z_weight,
-            dtype=self.dtype,
-            name="moe",
-            **dict(self.moe_fields),
-        )(y, training=training)
+        return MoEMLP(dtype=self.dtype, name="moe", **dict(self.moe_fields))(
+            y, training=training
+        )
 
     def _mlp(self, y, training, decode_pos):
         width = self.mlp_width or y.shape[-1] * self.mlp_ratio
@@ -727,7 +690,7 @@ def _rope_takes_kernel(x, interleave: bool) -> bool:
     over the batch alone, would gather it."""
     if not rotary_ops.rotate_tile(x.shape, interleave):
         return False
-    mesh, sp_axis, _ = attention_ops.get_attention_mesh()
+    mesh, sp_axis, _ = on_mesh.get_attention_mesh()
     return not (
         mesh is not None
         and sp_axis in mesh.axis_names
@@ -764,7 +727,7 @@ def rope(
     # one pass of ops/rotary.py's kernel over the folded form the attention
     # kernels take: the two transposes are layouts for XLA to assign
     by_batch = positions.ndim == 3
-    out = ssd_ops.over_batch(
+    out = on_mesh.over_batch(
         lambda x, positions, interpret: rotary_ops.rotate_half(
             x, positions, theta, tuple(sections), interpret
         ),

@@ -32,7 +32,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from elasticdl_tpu.ops import mamba_passes
+from elasticdl_tpu.ops import mamba_passes, on_mesh
 from elasticdl_tpu.ops import ssd as ssd_ops
 
 
@@ -88,7 +88,7 @@ def conv_silu(x, kernel, bias):
     form."""
     taps, channels = kernel.shape
     if mamba_passes.conv_tile(x.shape[1], channels, taps):
-        return ssd_ops.over_batch(mamba_passes.conv_silu, (x,), (kernel, bias))
+        return on_mesh.over_batch(mamba_passes.conv_silu, (x,), (kernel, bias))
     return nn.silu(causal_conv(x, kernel, bias))
 
 
@@ -96,7 +96,7 @@ def gate_norm(y, z, scale, groups: int, eps: float):
     """:func:`gated_group_norm`: one pass of ``ops/mamba_passes.py``'s kernel
     where it tiles the shape, else the plain form."""
     if mamba_passes.gate_norm_tile(y.shape[0] * y.shape[1], y.shape[2], groups):
-        return ssd_ops.over_batch(
+        return on_mesh.over_batch(
             functools.partial(mamba_passes.gate_norm, groups=groups, eps=eps),
             (y, z), (scale,),
         )

@@ -79,7 +79,7 @@ import jax
 import jax.numpy as jnp
 
 from elasticdl_tpu.ops import grouped_matmul as gmm_ops
-from elasticdl_tpu.ops.attention import get_attention_mesh, kernel_interpret
+from elasticdl_tpu.ops import on_mesh
 from elasticdl_tpu.telemetry.router_load import ROUTER_STATS
 
 # fan_in must count only the per-expert receptive field: axis 0 is the
@@ -403,12 +403,16 @@ def _experts_on_mesh(
     batch (and sequence) axes, the held experts (``stacks``, from
     ``first_expert`` on, of the ``num_experts`` routed over) shard over
     ``ep``, and a compiled Pallas kernel, which GSPMD cannot partition, runs
-    per device.  The counts come back summed over the devices."""
+    per device.  The counts come back summed over the devices.
+
+    The decision is ``ops/on_mesh.py``'s; the mapped region is this
+    function's own, because what runs in it is not what runs without it:
+    a device's first expert follows its place on ``ep``, and the output and
+    the counts are summed over the axes of the specs below."""
     from jax.sharding import PartitionSpec as P
 
     from elasticdl_tpu.ops.ring_attention import sequence_shard_spec
 
-    mesh, sp_axis, _ = get_attention_mesh()
     batch, seq, embed = x.shape
     slots = top_experts.shape[-1]
 
@@ -424,16 +428,13 @@ def _experts_on_mesh(
         with jax.named_scope("combine"):
             return y.reshape(x.shape), held, buffer_rows
 
+    interpret, mesh = on_mesh.resolve()
     if mesh is None:
-        return local(
-            x, top_experts, weights, *stacks, first_expert=first_expert
-        )
-    interpret = kernel_interpret(mesh.devices.flat[0].platform)
-    if mesh.devices.size == 1 or jax.sharding.get_abstract_mesh().manual_axes:
         return local(
             x, top_experts, weights, *stacks, first_expert=first_expert,
             interpret=interpret,
         )
+    sp_axis = on_mesh.get_attention_mesh()[1]
     sharded_seq = (
         sp_axis in mesh.axis_names
         and mesh.shape[sp_axis] > 1

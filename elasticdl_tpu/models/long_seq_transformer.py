@@ -42,6 +42,68 @@ from elasticdl_tpu.trainer.state import Modes
 VOCAB = 256
 
 
+# The parts' fields by the model's names, a group of
+# ``layers/attention.py::TransformerBlock`` each: the part -> {the model's
+# field: the part's own name for it}.  ``attention`` is
+# MultiHeadSelfAttention, ``latent`` LatentSelfAttention (the two kinds of
+# attention part share the heads and the rotary base), ``moe``
+# layers/moe.py::MoEMLP, ``mamba`` layers/mamba.py::Mamba2Mixer.  A new field
+# of a part is declared there, as a field of the model below, and on one line
+# here; every field of the model that is not here is the model's own or the
+# block's.
+PART_FIELDS = {
+    "attention": {
+        "num_heads": "num_heads",
+        "rope_theta": "rope_theta",
+        "num_kv_heads": "num_kv_heads",
+        "head_dim": "head_dim",
+        "qk_norm": "qk_norm",
+        "qk_norm_per_head": "qk_norm_per_head",
+        "mrope_section": "mrope_section",
+        "index_topk": "index_topk",
+        "index_heads": "index_heads",
+        "index_head_dim": "index_head_dim",
+        "index_kl_weight": "index_kl_weight",
+        "sliding_window": "window",
+        "output_gate": "output_gate",
+    },
+    "latent": {
+        "num_heads": "num_heads",
+        "rope_theta": "rope_theta",
+        "q_lora_rank": "q_lora_rank",
+        "kv_lora_rank": "kv_lora_rank",
+        "qk_nope_head_dim": "qk_nope_head_dim",
+        "qk_rope_head_dim": "qk_rope_head_dim",
+        "v_head_dim": "v_head_dim",
+        "rope_interleave": "rope_interleave",
+    },
+    "moe": {
+        "num_experts": "num_experts",
+        "experts_per_token": "experts_per_token",
+        "expert_width": "expert_width",
+        "norm_topk_prob": "norm_topk_prob",
+        "router_aux_weight": "aux_loss_weight",
+        "router_z_weight": "z_loss_weight",
+        "router_scoring": "scoring",
+        "selection_bias": "selection_bias",
+        "selection_bias_rate": "selection_bias_rate",
+        "routed_scaling": "routed_scaling",
+        "expert_kind": "expert_kind",
+        "shared_expert_width": "shared_width",
+        "experts_held": "experts_held",
+        "first_expert": "first_expert",
+    },
+    "mamba": {
+        "mamba_heads": "num_heads",
+        "mamba_head_dim": "head_dim",
+        "ssm_groups": "groups",
+        "ssm_state": "state_size",
+        "conv_kernel": "conv_kernel",
+        "ssd_chunk": "chunk",
+    },
+}
+
+
 class TransformerLM(nn.Module):
     vocab_size: int = VOCAB
     embed_dim: int = 128
@@ -199,59 +261,44 @@ class TransformerLM(nn.Module):
                 None, :, :
             ].astype(x.dtype)
 
+        # what the parts are given: the model's fields under the parts'
+        # names, but for the five values the model decides itself
+        decided = dict(
+            rope_theta=self.rope_theta if self.positions == "rope" else 0.0,
+            mrope_section=tuple(self.mrope_section),
+            # 0: the dense MLP's width (and MoEMLP's own 0 is its 4x)
+            expert_width=self.expert_width or self.mlp_width,
+            # over the mean of the layers' losses
+            router_aux_weight=self.router_aux_weight / max(1, expert_layers),
+            router_z_weight=self.router_z_weight / max(1, expert_layers),
+        )
+        # a group that is given chooses its part (TransformerBlock)
+        absent = {"latent": not self.kv_lora_rank, "moe": not self.num_experts}
+        groups = {
+            part + "_fields": tuple(
+                (field, decided.get(name, getattr(self, name)))
+                for name, field in fields.items()
+                if not absent.get(part)
+            )
+            for part, fields in PART_FIELDS.items()
+        }
+
         def block(kind, name):
             return block_class(
-                num_heads=self.num_heads,
+                kind=kind,
                 causal=True,
                 dropout_rate=self.dropout_rate,
-                num_kv_heads=self.num_kv_heads,
                 decode=self.decode,
                 max_decode_len=self.max_decode_len,
                 dtype=self.dtype,
                 norm=self.norm,
                 norm_eps=self.norm_eps,
+                norm_outputs=self.norm_outputs,
                 use_bias=self.use_bias,
-                rope_theta=(
-                    self.rope_theta if self.positions == "rope" else 0.0
-                ),
-                qk_norm=self.qk_norm,
                 mlp=self.mlp,
                 mlp_width=self.mlp_width,
-                num_experts=self.num_experts,
-                experts_per_token=self.experts_per_token,
-                expert_width=self.expert_width,
-                norm_topk_prob=self.norm_topk_prob,
-                router_aux_weight=self.router_aux_weight / max(1, expert_layers),
-                router_z_weight=self.router_z_weight / max(1, expert_layers),
-                kind=kind,
-                head_dim=self.head_dim,
-                moe_fields=(
-                    ("scoring", self.router_scoring),
-                    ("selection_bias", self.selection_bias),
-                    ("selection_bias_rate", self.selection_bias_rate),
-                    ("routed_scaling", self.routed_scaling),
-                    ("expert_kind", self.expert_kind),
-                    ("shared_width", self.shared_expert_width),
-                    ("experts_held", self.experts_held),
-                    ("first_expert", self.first_expert),
-                ),
-                mamba_fields=(
-                    ("num_heads", self.mamba_heads),
-                    ("head_dim", self.mamba_head_dim),
-                    ("groups", self.ssm_groups),
-                    ("state_size", self.ssm_state),
-                    ("conv_kernel", self.conv_kernel),
-                    ("chunk", self.ssd_chunk),
-                ),
-                latent_fields=(
-                    ("q_lora_rank", self.q_lora_rank),
-                    ("kv_lora_rank", self.kv_lora_rank),
-                    ("qk_nope_head_dim", self.qk_nope_head_dim),
-                    ("qk_rope_head_dim", self.qk_rope_head_dim),
-                    ("v_head_dim", self.v_head_dim),
-                    ("rope_interleave", self.rope_interleave),
-                ) if self.kv_lora_rank else (),
-                **self._further_block_fields(),
+                full_attention_rope=self.full_attention_rope,
+                **groups,
                 name=name,
             )
 
@@ -260,8 +307,7 @@ class TransformerLM(nn.Module):
 
         for layer in range(self.num_layers):
             x = block(pattern[layer] if pattern else "", f"block_{layer}")(
-                x, training, decode_pos,
-                *(() if components is None else (components,)),
+                x, training, decode_pos, components
             )
         if self.index_topk and (training or self.is_initializing()):
             # asks trainer/step.py for the loss by its parts: the sown
@@ -317,33 +363,6 @@ class TransformerLM(nn.Module):
             # a value a row: the step's masked loss maps ``loss`` over rows
             "mtp_weight": jnp.full(tokens.shape[:1], self.mtp_weight),
         }
-
-
-    def _further_block_fields(self) -> dict:
-        """The block's ``attention_fields`` and its fields for a stack of
-        window and full layers, given only where a field is set: a model
-        without them builds the block it always built."""
-        fields = tuple(
-            (name, value)
-            for name, value in (
-                ("qk_norm_per_head", self.qk_norm_per_head),
-                ("mrope_section", tuple(self.mrope_section)),
-                ("index_topk", self.index_topk),
-                ("index_heads", self.index_heads),
-                ("index_head_dim", self.index_head_dim),
-                ("index_kl_weight", self.index_kl_weight),
-                ("output_gate", self.output_gate),
-            )
-            if value and (name != "index_kl_weight" or self.index_topk)
-        )
-        out = {"attention_fields": fields} if fields else {}
-        if self.sliding_window:
-            out["window"] = self.sliding_window
-        if not self.full_attention_rope:
-            out["full_attention_rope"] = False
-        if self.norm_outputs:
-            out["norm_outputs"] = True
-        return out
 
 
 def custom_model(**kwargs):

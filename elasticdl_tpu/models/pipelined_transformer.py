@@ -34,7 +34,8 @@ from elasticdl_tpu.models.long_seq_transformer import (  # noqa: F401
     loss,
     optimizer,
 )
-from elasticdl_tpu.ops.attention import get_attention_mesh, mha_reference
+from elasticdl_tpu.ops.attention import mha_reference
+from elasticdl_tpu.ops.on_mesh import get_attention_mesh
 
 
 def _layernorm(x, scale, bias, eps=1e-6):

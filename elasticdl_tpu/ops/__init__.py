@@ -12,8 +12,8 @@ long-context sequence parallelism.
 from elasticdl_tpu.ops.attention import (  # noqa: F401
     flash_attention,
     mha_reference,
-    set_attention_mesh,
 )
+from elasticdl_tpu.ops.on_mesh import set_attention_mesh  # noqa: F401
 from elasticdl_tpu.ops.pipeline import (  # noqa: F401
     pipeline_apply,
     pipeline_sharding_rules,

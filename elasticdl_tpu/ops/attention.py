@@ -15,7 +15,6 @@ batch placement shards sequence over ``sp``.
 
 from __future__ import annotations
 
-import contextlib
 import functools
 import math
 
@@ -23,6 +22,15 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from elasticdl_tpu.ops import on_mesh
+# the registry's names, for ``perf/`` and whoever has always found them here
+from elasticdl_tpu.ops.on_mesh import (  # noqa: F401
+    attention_mesh_scope,
+    get_attention_mesh,
+    kernel_interpret,
+    set_attention_mesh,
+)
 
 # lane width of TPU vector registers: the m/l scratch accumulators keep
 # this many (all-equal) columns so stores stay tile-aligned
@@ -65,73 +73,6 @@ WINDOW_DKV = "swa_dkv"
 # the kernels' layout) by telemetry/op_scopes.py's name; never around a
 # ``pallas_call``, whose own name is what the device's op line shows
 _FOLD = "fold"
-
-
-# ---- mesh context (set by the trainer, read by layers) ---------------------
-
-# process-global, NOT thread-local: one mesh per worker process (the SPMD
-# model), and jit tracing may happen on a different thread than trainer
-# construction
-_mesh_context: list = [None, "sp", "ring"]
-
-
-_SP_IMPLS = ("ring", "ulysses")
-
-
-def set_attention_mesh(mesh, sp_axis: str = "sp", sp_impl: str = "ring"):
-    """Register the mesh attention layers should use for sequence
-    parallelism.  A ``None`` mesh (or an ``sp`` axis of size 1) makes
-    :func:`attention` run the local kernel and lets GSPMD handle any
-    sharding.  ``sp_impl`` picks the sequence-parallel algorithm:
-    ``"ring"`` (K/V rotation; any head count) or ``"ulysses"``
-    (head/sequence all-to-all; needs heads % sp == 0).  SPMDTrainer
-    scopes this around every step call via :func:`attention_mesh_scope`
-    — two trainers with different meshes in one process (bench, dryrun)
-    must not see each other's mesh at (re)trace time."""
-    if sp_impl not in _SP_IMPLS:
-        # a typo must not silently fall back to ring
-        raise ValueError(
-            f"unknown sp_impl {sp_impl!r}; valid: {_SP_IMPLS}"
-        )
-    _mesh_context[0] = mesh
-    _mesh_context[1] = sp_axis
-    _mesh_context[2] = sp_impl
-
-
-def get_attention_mesh():
-    return _mesh_context[0], _mesh_context[1], _mesh_context[2]
-
-
-@contextlib.contextmanager
-def attention_mesh_scope(mesh, sp_axis: str = "sp", sp_impl: str | None = None):
-    """Set-and-restore the attention mesh: tracing inside the scope (jit
-    retraces on new shapes happen at call time) reads this mesh.
-    ``sp_impl=None`` preserves the currently selected implementation —
-    SPMDTrainer's step scopes must not clobber a global
-    ``set_attention_mesh(..., sp_impl="ulysses")`` choice."""
-    prev = tuple(_mesh_context)
-    set_attention_mesh(
-        mesh, sp_axis, _mesh_context[2] if sp_impl is None else sp_impl
-    )
-    try:
-        yield
-    finally:
-        _mesh_context[:] = prev
-
-
-def kernel_interpret(platform: str) -> bool:
-    """Whether the pallas kernels run INTERPRETED on ``platform``: the
-    CPU has no Mosaic, so it interprets (tests run the same kernel code
-    the chip compiles); a TPU compiles; any other platform is an error —
-    never a silent trip through the interpreter."""
-    if platform == "cpu":
-        return True
-    if platform == "tpu":
-        return False
-    raise ValueError(
-        f"pallas flash attention runs compiled on 'tpu' and interpreted "
-        f"on 'cpu'; got platform {platform!r}"
-    )
 
 
 # ---- reference (jnp) -------------------------------------------------------
@@ -956,7 +897,7 @@ def _flash_geometry(q, k, v, sm_scale, block_q, block_k, interpret):
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     if interpret is None:
-        interpret = kernel_interpret(jax.default_backend())
+        interpret = on_mesh.default_interpret()
     block_q = _pick_block(q.shape[1], block_q)
     block_k = _pick_block(k.shape[1], block_k)
     width = min(q.shape[-1], v.shape[-1])
@@ -1467,11 +1408,8 @@ def attention(
 ):
     """Self-attention entry point for layers: sequence-parallel attention
     (ring by default, ulysses when configured) when the registered mesh
-    has an ``sp`` axis > 1, else the local flash kernel — mapped over the
-    mesh's batch and head axes, because a compiled pallas kernel is an
-    opaque custom call GSPMD cannot partition: JAX refuses to lower it
-    bare inside a multi-device jitted program (interpreted, on CPU, it
-    is ordinary HLO and partitions, which hid this from every test)."""
+    has an ``sp`` axis > 1, else the local flash kernel, mapped over the
+    mesh's batch and head axes (``ops/on_mesh.py``)."""
     from elasticdl_tpu.ops.ring_attention import (
         ring_attention,
         sequence_shard_spec,
@@ -1479,14 +1417,9 @@ def attention(
     from elasticdl_tpu.ops.ulysses import ulysses_attention
 
     mesh, sp_axis, sp_impl = get_attention_mesh()
-    # (given only where there is one: a call without a window is the call
-    # it always was)
-    windowed = {} if window is None else {"window": window}
-    if mesh is None:
-        return flash_attention(
-            q, k, v, causal=causal, sm_scale=sm_scale, **windowed
-        )
-    if sp_axis in mesh.axis_names and mesh.shape[sp_axis] > 1:
+    if mesh is not None and (
+        sp_axis in mesh.axis_names and mesh.shape[sp_axis] > 1
+    ):
         if window is not None:
             raise NotImplementedError(
                 "a window across the sp axis is not built: the ring and "
@@ -1500,50 +1433,33 @@ def attention(
             q, k, v, mesh=mesh, axis_name=sp_axis, causal=causal,
             sm_scale=sm_scale,
         )
-    # interpret follows the MESH's platform, not the process default: a
-    # CPU mesh on a TPU-default machine (virtual-device dryrun) compiles
-    # for CPU, where pallas only runs interpreted
-    local = functools.partial(
-        flash_attention,
-        causal=causal,
-        sm_scale=sm_scale,
-        interpret=kernel_interpret(mesh.devices.flat[0].platform),
-        **windowed,
+    lanes = flash_layout(q, k, v) == "lanes"
+
+    def specs(mesh):
+        # the layout the sp paths share, with no sequence axis: batch on the
+        # data-parallel axes, heads on tp (not under GQA — query groups must
+        # stay aligned)
+        spec = sequence_shard_spec(mesh, None, q.shape[0], q.shape[2])
+        if k.shape[2] != q.shape[2]:
+            spec = jax.sharding.PartitionSpec(spec[0], None, None, None)
+        if lanes:
+            spec = jax.sharding.PartitionSpec(*spec[:3])
+        return (spec, spec, spec), spec
+
+    # with heads read out of lanes the per-device region is handed the
+    # projections' merged rows and gives them back: a (..., heads, 64) array
+    # at its boundary is a value XLA lays out tokens-minor, and every
+    # operand of the kernels then crosses a copy again (13 a layer in the
+    # compiled dp=4 step)
+    def merged(x):
+        return x.reshape(*x.shape[:2], -1)
+
+    def split(x):
+        return x.reshape(*x.shape[:2], -1, q.shape[-1])
+
+    return on_mesh.mapped(
+        functools.partial(
+            flash_attention, causal=causal, sm_scale=sm_scale, window=window
+        ),
+        (q, k, v), specs, crossing=(merged, split) if lanes else None,
     )
-    if mesh.devices.size == 1 or jax.sharding.get_abstract_mesh().manual_axes:
-        # one device, or already inside a caller's per-device region
-        return local(q, k, v)
-    # the layout the sp paths share, with no sequence axis: batch on the
-    # data-parallel axes, heads on tp (not under GQA — query groups must
-    # stay aligned)
-    spec = sequence_shard_spec(mesh, None, q.shape[0], q.shape[2])
-    if k.shape[2] != q.shape[2]:
-        spec = jax.sharding.PartitionSpec(spec[0], None, None, None)
-    if flash_layout(q, k, v) == "lanes":
-        # the per-device region is handed the projections' merged rows and
-        # gives them back: a (..., heads, 64) array at its boundary is a
-        # value XLA lays out tokens-minor, and every operand of the kernels
-        # then crosses a copy again (13 a layer in the compiled dp=4 step)
-        def merged(x):
-            return x.reshape(*x.shape[:2], -1)
-
-        def split(x):
-            return x.reshape(*x.shape[:2], -1, q.shape[-1])
-
-        rows = jax.sharding.PartitionSpec(*spec[:3])
-        return split(
-            jax.shard_map(
-                lambda q, k, v: merged(local(split(q), split(k), split(v))),
-                mesh=mesh,
-                in_specs=(rows, rows, rows),
-                out_specs=rows,
-                check_vma=False,
-            )(merged(q), merged(k), merged(v))
-        )
-    return jax.shard_map(
-        local,
-        mesh=mesh,
-        in_specs=(spec, spec, spec),
-        out_specs=spec,
-        check_vma=False,
-    )(q, k, v)

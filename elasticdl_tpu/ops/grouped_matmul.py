@@ -47,7 +47,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from elasticdl_tpu.ops.attention import kernel_interpret
+from elasticdl_tpu.ops import on_mesh
 
 GMM_FWD = "expert_gmm_fwd"
 GMM_DX = "expert_gmm_dx"
@@ -361,7 +361,7 @@ def grouped_matmul(
     accumulates each group's tiles in float32.  ``interpret=None`` follows
     the default backend (interpreted on the CPU, compiled on a TPU)."""
     if interpret is None:
-        interpret = kernel_interpret(jax.default_backend())
+        interpret = on_mesh.default_interpret()
     if lhs.shape[0] != tile_group.shape[0] * tile_rows:
         raise ValueError(
             f"{lhs.shape[0]} rows are not {tile_group.shape[0]} tiles of "

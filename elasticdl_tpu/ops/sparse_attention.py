@@ -44,9 +44,9 @@ from elasticdl_tpu.ops.attention import (
     _pick_block,
     _row_to_lanes,
     _scores,
-    kernel_interpret,
     validate_gqa_heads,
 )
+from elasticdl_tpu.ops import on_mesh
 
 # each ``pallas_call``'s name, which the device's op line shows
 INDEX_SELECT = "dsa_index"
@@ -295,7 +295,7 @@ def _index_call(qi, ki, w, topk, block_k, block_q, interpret, threshold=None):
     )
     batch, seq, heads, width = qi.shape
     if interpret is None:
-        interpret = kernel_interpret(jax.default_backend())
+        interpret = on_mesh.default_interpret()
     block_k = _pick_block(seq, block_k)
     block_q = _pick_block(seq, block_q)
     num_kb = seq // block_k
@@ -508,7 +508,7 @@ def _kl_call(q, k, lse, mask, qi, ki, w, lse_i, sm_scale, interpret, with_grads)
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(d)
     if interpret is None:
-        interpret = kernel_interpret(jax.default_backend())
+        interpret = on_mesh.default_interpret()
     block_k = mask.shape[3]
     block_q = _pick_block(seq, 256)
     num_kb, num_qb = seq // block_k, seq // block_q

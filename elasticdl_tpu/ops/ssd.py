@@ -49,7 +49,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from elasticdl_tpu.ops.attention import get_attention_mesh, kernel_interpret
+from elasticdl_tpu.ops import on_mesh
 
 SSD_FWD = "ssd_fwd"
 SSD_BWD = "ssd_bwd"
@@ -335,7 +335,7 @@ def ssd_chunked(x, dt, a, b, c, d, *, chunk: int, interpret: bool | None = None)
     nothing and add nothing.  Differentiable in every argument.
     ``interpret=None`` follows the default backend."""
     if interpret is None:
-        interpret = kernel_interpret(jax.default_backend())
+        interpret = on_mesh.default_interpret()
     heads, groups = x.shape[2], b.shape[2]
     if heads % groups:
         raise ValueError(f"{heads} heads do not divide into {groups} groups")
@@ -373,40 +373,10 @@ def ssd_chunked(x, dt, a, b, c, d, *, chunk: int, interpret: bool | None = None)
         return _by_step(y)[:, :steps]
 
 
-def over_batch(local, batched, shared):
-    """``local(*batched, *shared, interpret=...)`` under the registered mesh
-    (the one the attention kernels read): a compiled Pallas kernel is an
-    opaque custom call GSPMD cannot partition, so on several devices it is
-    mapped over the mesh's data-parallel axes, each of ``batched`` (and the
-    result, which is laid out like the first of them) split along its first
-    axis, a sequence whole on its device, each of ``shared`` whole on every
-    device."""
-    from jax.sharding import PartitionSpec as P
-
-    from elasticdl_tpu.ops.ring_attention import sequence_shard_spec
-
-    mesh, _, _ = get_attention_mesh()
-    if mesh is None:
-        return local(
-            *batched, *shared,
-            interpret=kernel_interpret(jax.default_backend()),
-        )
-    local = functools.partial(
-        local, interpret=kernel_interpret(mesh.devices.flat[0].platform)
-    )
-    if mesh.devices.size == 1 or jax.sharding.get_abstract_mesh().manual_axes:
-        return local(*batched, *shared)
-    rows = sequence_shard_spec(mesh, None, batched[0].shape[0], 1)[0]
-    by_row = [P(rows, *[None] * (v.ndim - 1)) for v in batched]
-    return jax.shard_map(
-        local, mesh=mesh, in_specs=(*by_row, *[P(None)] * len(shared)),
-        out_specs=by_row[0], check_vma=False,
-    )(*batched, *shared)
-
-
 def ssd_scan(x, dt, a, b, c, d, *, chunk: int):
-    """:func:`ssd_chunked` under the registered mesh (:func:`over_batch`)."""
-    return over_batch(
+    """:func:`ssd_chunked` under the registered mesh, mapped over the batch
+    (``ops/on_mesh.py::over_batch``)."""
+    return on_mesh.over_batch(
         lambda x, dt, b, c, a, d, interpret: ssd_chunked(
             x, dt, a, b, c, d, chunk=chunk, interpret=interpret
         ),

@@ -86,7 +86,7 @@ def ulysses_attention(
             f"{axis_name}={sp}"
         )
 
-    from elasticdl_tpu.ops.attention import kernel_interpret
+    from elasticdl_tpu.ops.on_mesh import kernel_interpret
     from elasticdl_tpu.ops.ring_attention import sequence_shard_spec
 
     # shared layout with ring (batch on dp; head sharding over tp is

@@ -22,7 +22,7 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding
 
-from elasticdl_tpu.ops.attention import attention_mesh_scope
+from elasticdl_tpu.ops.on_mesh import attention_mesh_scope
 from elasticdl_tpu.parallel import elastic, program_store
 from elasticdl_tpu.parallel import sharding as sharding_lib
 from elasticdl_tpu.telemetry import op_scopes, router_load

@@ -5,8 +5,9 @@ The device trace names an op ``fusion.25``; the compiled program knows where
 in the model that instruction came from.  Every op JAX lowers carries its
 name stack as ``metadata={op_name="jit(train_step)/transpose(jvp(
 TransformerLM))/block_3/.../attn/query/dot_general"}``: flax names a
-module's ops, ``jax.named_scope`` names the regions between modules (the
-closed :data:`VOCABULARY` below), ``transpose(`` marks the backward pass and
+module's ops, ``jax.named_scope`` names the regions between modules (each
+named in place; ``tests/test_op_scopes.py`` collects them from the
+sources), ``transpose(`` marks the backward pass and
 ``rematted_computation`` a forward that runs again inside it.  The metadata
 survives compilation, fusion and ``serialize`` -> ``deserialize_and_load``,
 so a running trainer can be asked what its own program's ops are
@@ -58,27 +59,10 @@ import sys
 import weakref
 from typing import NamedTuple
 
-# every ``jax.named_scope`` of the package takes its name from here
-# (tests/test_op_scopes.py holds the sources to that); flax modules name
-# the rest of a part
-VOCABULARY = (
-    # trainer/step.py
-    "loss", "optimizer", "parse",
-    # layers/attention.py, ops/attention.py
-    "rope", "qk_norm", "join", "fold", "mlp",
-    # the attention output's gate (its product; flax names its projection
-    # the same) and the norm on a part's output
-    "gate", "norm_out",
-    # the sparse-attention indexer: its projections, the index scores and
-    # the selection (with the mask's transpose), its KL loss
-    "indexer", "index_select", "indexer_kl",
-    # layers/moe.py
-    "route", "dispatch", "experts", "combine", "rung", "shared",
-    # layers/mamba.py
-    "gate_norm", "mamba_conv", "ssd_scan",
-    # ops directly under the root module
-    "model",
-)
+# an op directly under the root module: the map's own name for that part
+# (every other name of a part is a flax module's or a ``jax.named_scope``'s,
+# named where it is used)
+ROOT = "model"
 OP_SCOPES_FILE = "op_scopes.json"
 UNATTRIBUTED = "unattributed"
 
@@ -184,7 +168,7 @@ def canonical(op_name: str) -> tuple[str | None, str]:
     else:
         phase = "forward"
     if not kept:
-        return ("model" if rooted else None), phase
+        return (ROOT if rooted else None), phase
     return "/".join(kept), phase
 
 

@@ -178,9 +178,10 @@ def build_train_step(
     """
 
     def forward_loss(params, state, features, labels, weights):
-        # the regions of the step that no module names carry a scope of
-        # telemetry/op_scopes.py::VOCABULARY: a scope changes an op's
-        # metadata and nothing else of the compiled program
+        # the regions of the step that no module names carry a
+        # ``jax.named_scope``, named here, which telemetry/op_scopes.py
+        # reads back as the op's part: a scope changes an op's metadata and
+        # nothing else of the compiled program
         with jax.named_scope("parse"):
             if device_parse is not None:
                 features = device_parse(features)

@@ -864,44 +864,3 @@ def test_heads_dense_owns_dense_generals_parameters(kwargs, shape, use_bias):
     dots = [e for e in _eqns(jaxpr.jaxpr) if e.primitive.name == "dot_general"]
     # one product, whose result is (batch, tokens, merged features)
     assert [e.outvars[0].aval.ndim for e in dots] == [3]
-
-
-@pytest.mark.parametrize("mesh_shape", ["dp=4", "dp=2,tp=2"])
-def test_attention_hands_the_per_device_kernels_merged_rows(mesh_shape):
-    """On a mesh without ``sp`` the kernels are mapped over batch and head
-    axes; where they read heads out of lanes the mapped region takes and
-    gives ``(batch, tokens, heads * 64)`` rows (a 4-D array at its boundary
-    brought back every copy the lanes form removes, in the compiled dp=4
-    step), and results and gradients are the oracle's."""
-    q, k, v = _qkv(b=4, s=128, h=4, d=64)
-    w = np.random.RandomState(2).randn(4, 128, 4, 64).astype(np.float32)
-    mesh = MeshConfig.from_string(mesh_shape).create(
-        devices=jax.devices()[:4]
-    )
-    set_attention_mesh(mesh)
-
-    def mapped(q, k, v):
-        return attention(q, k, v, causal=True)
-
-    def ref(q, k, v):
-        return mha_reference(q, k, v, causal=True)
-
-    got = (
-        jax.jit(mapped)(q, k, v),
-        *jax.jit(_weighted_grads(mapped, w))(q, k, v),
-    )
-    want = (ref(q, k, v), *_weighted_grads(ref, w)(q, k, v))
-    for a, b in zip(got, want):
-        np.testing.assert_allclose(
-            np.asarray(a), np.asarray(b), atol=2e-4, rtol=2e-4
-        )
-    regions = [
-        eqn
-        for eqn in _eqns(jax.make_jaxpr(mapped)(q, k, v).jaxpr)
-        if eqn.primitive.name == "shard_map"
-    ]
-    assert regions and all(
-        var.aval.ndim == 3
-        for eqn in regions
-        for var in eqn.invars + eqn.outvars
-    )

@@ -78,15 +78,14 @@ def test_qk_norm_is_an_rmsnorm_over_the_whole_projection_before_the_heads():
 def test_swiglu_mlp_is_down_of_silu_gate_times_up():
     x = jnp.asarray(np.random.RandomState(3).randn(2, 4, 16), jnp.float32)
     block = layers.TransformerBlock(
-        num_heads=2, norm="rmsnorm", use_bias=False, mlp="swiglu", mlp_width=24
+        norm="rmsnorm", use_bias=False, mlp="swiglu", mlp_width=24,
+        attention_fields=(("num_heads", 2),),
     )
     p = block.init(jax.random.PRNGKey(0), x)["params"]
     assert p["mlp_gate"]["kernel"].shape == p["mlp_up"]["kernel"].shape == (16, 24)
     assert p["mlp_down"]["kernel"].shape == (24, 16)
     assert {"RMSNorm_0", "RMSNorm_1"} <= set(p)
-    after_attention = layers.TransformerBlock(
-        num_heads=2, norm="rmsnorm", use_bias=False, mlp="swiglu", mlp_width=24
-    ).apply(
+    after_attention = block.apply(
         {"params": {**p, "mlp_down": {"kernel": jnp.zeros((24, 16))}}}, x
     )
     y = after_attention / jnp.sqrt(
@@ -97,7 +96,7 @@ def test_swiglu_mlp_is_down_of_silu_gate_times_up():
     ) @ p["mlp_down"]["kernel"]
     np.testing.assert_allclose(block.apply({"params": p}, x), want, rtol=2e-5, atol=2e-5)
     with pytest.raises(ValueError, match="unknown mlp"):
-        layers.TransformerBlock(num_heads=2, mlp="relu").init(jax.random.PRNGKey(0), x)
+        block.clone(mlp="relu").init(jax.random.PRNGKey(0), x)
 
 
 def test_decoding_with_rope_applies_the_position_of_the_cursor():
@@ -181,3 +180,127 @@ def test_the_defaults_still_build_gpt2_smalls_block():
         state, metrics = step(state, feats, labels)
         losses.append(float(metrics["loss"]))
     np.testing.assert_allclose(losses, GPT2_DEFAULT_LOSSES, rtol=1e-6)
+
+
+# ---- a part's field: declared by the part, named once by the model -------------
+
+
+def _parts():
+    from elasticdl_tpu.layers.mamba import Mamba2Mixer
+    from elasticdl_tpu.layers.moe import MoEMLP
+
+    return {
+        "attention": layers.MultiHeadSelfAttention,
+        "latent": layers.LatentSelfAttention,
+        "moe": MoEMLP,
+        "mamba": Mamba2Mixer,
+    }
+
+
+def _declared(module_class):
+    """A flax module's own dataclass fields."""
+    return set(module_class.__dataclass_fields__) - {"parent", "name"}
+
+
+# what the block itself decides or refuses, and hands to every part that has
+# the field: not a part's own
+_HANDED_TO_ALL = {
+    "dtype", "norm_eps", "use_bias", "causal", "decode", "max_decode_len"
+}
+# the model's own and the block's
+_MODEL_OWN = {
+    "vocab_size", "embed_dim", "num_layers", "positions", "layer_pattern",
+    "remat_layers", "mtp_depth", "mtp_weight", "scale_embedding",
+    "dropout_rate", "decode", "max_decode_len", "dtype", "norm", "norm_eps",
+    "norm_outputs", "use_bias", "mlp", "mlp_width", "full_attention_rope",
+}
+
+
+@pytest.mark.parametrize("part", ["attention", "latent", "moe", "mamba"])
+def test_the_models_table_names_declared_fields_of_the_part(part):
+    """Every target of ``PART_FIELDS`` is a field the part's module
+    declares, once a part, and with what the block hands to all of them the
+    part has every field it needs."""
+    targets = list(lm.PART_FIELDS[part].values())
+    declared = _declared(_parts()[part])
+    assert targets and len(set(targets)) == len(targets)
+    assert set(targets) <= declared, set(targets) - declared
+    assert not set(targets) & _HANDED_TO_ALL
+    required = {
+        name
+        for name, field in _parts()[part].__dataclass_fields__.items()
+        if name not in ("parent", "name")
+        and field.default is field.default_factory  # both MISSING
+    }
+    assert required <= set(targets), required - set(targets)
+
+
+def test_a_models_field_is_its_own_or_in_the_table_and_the_block_declares_no_parts():
+    fields = _declared(lm.TransformerLM)
+    named = [name for group in lm.PART_FIELDS.values() for name in group]
+    assert len(fields) == 59
+    assert set(named) | _MODEL_OWN == fields
+    assert not set(named) & _MODEL_OWN
+    # once, but for what the two kinds of attention part share
+    assert {
+        name for name in named if named.count(name) > 1
+    } == {"num_heads", "rope_theta"}
+    assert set(lm.PART_FIELDS) == set(_parts())
+    block = _declared(layers.TransformerBlock)
+    assert len(block) <= 18
+    groups = {part + "_fields" for part in _parts()}
+    assert groups <= block
+    for part, module_class in _parts().items():
+        assert (block - groups) & _declared(module_class) <= _HANDED_TO_ALL, part
+    # what the block is handed by name is the model's own
+    assert block - groups - {"kind", "causal", "mlp_ratio"} <= _MODEL_OWN
+
+
+def test_a_field_of_a_part_reaches_it_under_the_parts_name():
+    """The groups the model builds, read back from the block it makes."""
+    model = lm.custom_model(
+        embed_dim=32, num_heads=2, num_layers=3, layer_pattern="wME",
+        positions="rope", rope_theta=500.0, sliding_window=8, mlp_width=48,
+        num_experts=4, router_scoring="sigmoid", shared_expert_width=16,
+        router_aux_weight=0.02, mamba_heads=2, mamba_head_dim=16,
+        ssm_state=16, ssd_chunk=8, mrope_section=[4, 2, 2],
+    )
+    tokens = np.zeros((1, 16), np.int32)
+    blocks = _blocks_of(model, tokens)
+    attention = dict(blocks["block_0"].attention_fields)
+    assert attention["window"] == 8 and attention["rope_theta"] == 500.0
+    assert attention["mrope_section"] == (4, 2, 2)
+    assert blocks["block_0"].latent_fields == ()
+    experts = dict(blocks["block_2"].moe_fields)
+    assert experts["scoring"] == "sigmoid" and experts["shared_width"] == 16
+    # 0: the dense MLP's width; the weight over the one expert layer
+    assert experts["expert_width"] == 48
+    assert experts["aux_loss_weight"] == 0.02
+    mixer = dict(blocks["block_1"].mamba_fields)
+    assert mixer == {
+        "num_heads": 2, "head_dim": 16, "groups": 1, "state_size": 16,
+        "conv_kernel": 4, "chunk": 8,
+    }
+    # positions that are not rotary reach no part, and no group, no part
+    plain = lm.custom_model(embed_dim=32, num_heads=2, num_layers=1)
+    blocks = _blocks_of(plain, tokens)
+    assert dict(blocks["block_0"].attention_fields)["rope_theta"] == 0.0
+    assert blocks["block_0"].moe_fields == ()
+
+
+def _blocks_of(model, tokens):
+    """The model's blocks by name, as it builds them."""
+    import flax.linen as nn
+
+    blocks = {}
+
+    def keep(next_fun, args, kwargs, context):
+        if isinstance(context.module, layers.TransformerBlock):
+            blocks[context.module.name] = context.module
+        return next_fun(*args, **kwargs)
+
+    with nn.intercept_methods(keep):
+        jax.eval_shape(
+            lambda: model.init(jax.random.PRNGKey(0), {"tokens": tokens})
+        )
+    return blocks

@@ -213,8 +213,8 @@ def test_block_with_latent_fields_is_the_same_block_and_refuses_to_decode():
     )
     x = np.random.RandomState(0).randn(1, 8, 16).astype(np.float32)
     block = TransformerBlock(
-        num_heads=2, causal=True, norm="rmsnorm", use_bias=False, mlp="swiglu",
-        rope_theta=1e4, kind="*", latent_fields=latent,
+        causal=True, norm="rmsnorm", use_bias=False, mlp="swiglu", kind="*",
+        latent_fields=(("num_heads", 2), ("rope_theta", 1e4)) + latent,
     )
     params = block.init(jax.random.PRNGKey(0), x)["params"]
     assert set(params) == {"RMSNorm_0", "attn"}

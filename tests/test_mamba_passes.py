@@ -10,7 +10,6 @@ import numpy as np
 import pytest
 
 from elasticdl_tpu.layers import mamba
-from elasticdl_tpu.ops import attention as attention_ops
 from elasticdl_tpu.ops import mamba_passes
 
 # (batch, steps, d_in, groups, states): the cell's widths at a short sequence
@@ -273,28 +272,3 @@ def test_kernels_carry_their_own_names_and_none_of_the_scan_or_flash_kernels():
     ))
     for name in names:
         assert f"name={name}" in text, name
-
-
-def test_the_passes_run_mapped_over_a_data_parallel_mesh():
-    """Under a multi-device mesh the kernels go through ``shard_map`` over
-    the batch axes, like the scan; the parameters' gradients are the sum over
-    the devices."""
-    from elasticdl_tpu.parallel.mesh import MeshConfig
-
-    a, (inner, _, groups) = _arrays("two_groups", jnp.float32, seed=5)
-
-    def both(y, z, scale, xbc, kernel, bias):
-        out = mamba.gate_norm(y, z, scale, groups, 1e-5)
-        return jnp.sum(a["weigh_norm"] * out) + jnp.sum(
-            a["weigh_conv"] * mamba.conv_silu(xbc, kernel, bias)
-        )
-
-    args = (a["y"], a["z"], a["scale"], a["xbc"], a["kernel"], a["bias"])
-    grads = jax.value_and_grad(both, argnums=tuple(range(6)))
-    want = grads(*args)
-    mesh = MeshConfig.from_string("dp=2").create(devices=jax.devices()[:2])
-    with mesh, attention_ops.attention_mesh_scope(mesh):
-        assert "shard_map" in str(jax.make_jaxpr(both)(*args))
-        mapped = jax.jit(grads)(*args)
-    errors = _scaled_errors(mapped, want)
-    assert max(errors) < 2e-5, errors

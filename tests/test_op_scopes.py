@@ -2,11 +2,12 @@
 
 The rules on hand-written name stacks and a hand-written module; then, for a
 tiny model of each family through ``build_train_step`` on the CPU, what the
-map of the real compiled step says — every region has a name from the closed
-vocabulary, the scopes change nothing but metadata, and the map of a program
+map of the real compiled step says — every region has a name (a module's, a
+``named_scope``'s of the sources, a kernel's), the scopes change nothing but metadata, and the map of a program
 that went through the program store's serialisation is the built one's."""
 
 import contextlib
+import functools
 import glob
 import json
 import os
@@ -248,23 +249,46 @@ def test_attribute_sums_by_scope_and_says_what_it_could_not_place():
     assert rows[-2] == ["total", "1093.750", "100.00"]
 
 
-def test_the_vocabulary_is_closed_and_every_scope_of_the_package_is_in_it():
-    assert len(op_scopes.VOCABULARY) == 23  # PR 42: gate, norm_out
-    assert len(set(op_scopes.VOCABULARY)) == len(op_scopes.VOCABULARY)
-    literal = re.compile(r"named_scope\(\s*([\"']?)(\w+)\1\s*\)")
-    constants = re.compile(r"^(_[A-Z_]+) = \"(\w+)\"$", re.M)
-    used = set()
+@functools.cache
+def _source_scopes():
+    """Every ``jax.named_scope`` name of the package, read from the sources:
+    a literal where it is used, or a module's constant (``_FOLD``)."""
+    call = re.compile(r"named_scope\(\s*([^)]*?)\s*\)")
+    literal = re.compile(r"^([\"'])([^\"']*)\1$")
+    constants = re.compile(r"^(_[A-Z_]+) = \"([^\"]*)\"$", re.M)
+    used, by_constant = set(), {}
     for path in glob.glob(
         os.path.join(ROOT, "elasticdl_tpu", "**", "*.py"), recursive=True
     ):
         with open(path) as f:
             source = f.read()
-        named = dict(constants.findall(source))
-        for quoted, name in literal.findall(source):
-            used.add(name if quoted else named[name])
-    assert used and used <= set(op_scopes.VOCABULARY), used
-    # "model" is the map's name for the root; the rest are all in use
-    assert set(op_scopes.VOCABULARY) - used == {"model"}
+        defined = constants.findall(source)
+        named = dict(defined)
+        for argument in call.findall(source):
+            quoted = literal.match(argument)
+            # a literal or a constant of the same file, nothing computed
+            assert quoted or argument in named, (path, argument)
+            name = quoted.group(2) if quoted else named[argument]
+            if not quoted:
+                # a constant names one region's scope: defined once a file,
+                # and the same name wherever another file has it too
+                assert [n for n, _ in defined].count(argument) == 1, path
+                assert by_constant.setdefault(argument, name) == name, path
+            used.add(name)
+    return used
+
+
+def test_every_scope_of_the_package_is_an_identifier_named_in_place():
+    used = _source_scopes()
+    assert used and all(op_scopes._IDENTIFIER.match(name) for name in used), used
+    # the map's own name for the root is nobody's scope, and no scope takes
+    # a kernel's name (a kernel keeps the name the op line shows)
+    assert op_scopes.ROOT == "model" and op_scopes.ROOT not in used
+    assert not used & KERNELS
+    # what a scope is for: ``canonical`` keeps it as the part's element
+    for name in used:
+        part, _ = op_scopes.canonical(f"jit(step)/jvp(M)/block_3/{name}/add")
+        assert part == f"block/{name}"
 
 
 # ---- the real compiled step of a tiny model of each family ----------------------
@@ -371,16 +395,17 @@ def test_every_region_of_the_step_has_a_name(built):
     assert op_scopes.scope_map(compiled) is scopes  # built once a program
     parts = {part for part, _, _, _ in scopes.values() if part is not None}
     modules = _modules(state.params)
-    known = modules | set(op_scopes.VOCABULARY) | KERNELS
+    scopes_of_the_sources = _source_scopes() | {op_scopes.ROOT}
+    known = modules | scopes_of_the_sources | KERNELS
     for part in parts:
         elements = part.split("/")
         assert set(elements) <= known, part
         # directly under a mixer nothing is bare: a module with parameters
-        # of its own, a kernel, or a name of the vocabulary
+        # of its own, a kernel, or a scope of the sources
         assert elements[-1] not in MIXERS, part
         if set(elements) & MIXERS:
             assert elements[-1] in (
-                _owners(state.params) | set(op_scopes.VOCABULARY) | KERNELS
+                _owners(state.params) | scopes_of_the_sources | KERNELS
             ), part
     # the coverage of the compiled step
     held = [part for part, _, _, _ in scopes.values()]

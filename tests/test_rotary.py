@@ -9,8 +9,7 @@ import numpy as np
 import pytest
 
 from elasticdl_tpu.layers.attention import rope
-from elasticdl_tpu.ops import attention as attention_ops
-from elasticdl_tpu.ops import rotary
+from elasticdl_tpu.ops import on_mesh, rotary
 from elasticdl_tpu.parallel.mesh import MeshConfig
 
 SECTIONS = (16, 24, 24)
@@ -198,25 +197,6 @@ def test_a_sequence_sharded_over_sp_keeps_the_plain_form():
     mesh = MeshConfig.from_string("dp=1,sp=2").create(
         devices=jax.devices()[:2]
     )
-    with attention_ops.attention_mesh_scope(mesh):
+    with on_mesh.attention_mesh_scope(mesh):
         assert _calls(rope, x, jnp.arange(1024)) == []
     assert _calls(rope, x, jnp.arange(1024)) == [rotary.ROPE_FWD]
-
-
-@pytest.mark.parametrize("components", [False, True], ids=["index", "mrope"])
-def test_over_a_data_parallel_mesh_each_device_rotates_its_own(components):
-    """The kernel is mapped over the mesh's batch axes (a compiled Pallas
-    call cannot be partitioned): the same numbers as on one device."""
-    x, g, positions, sections = _operands(
-        4, 528, 4, 128, jnp.bfloat16, components
-    )
-    want = _both_ways(rope, positions, sections)(x, g)
-    mesh = MeshConfig.from_string("dp=4").create(devices=jax.devices()[:4])
-    with mesh, attention_ops.attention_mesh_scope(mesh):
-        step = _both_ways(rope, positions, sections)
-        assert "shard_map" in str(jax.make_jaxpr(step)(x, g))
-        got = step(x, g)
-    for ours, theirs in zip(got, want):
-        np.testing.assert_array_equal(
-            np.asarray(ours, np.float32), np.asarray(theirs, np.float32)
-        )

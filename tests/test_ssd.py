@@ -139,20 +139,6 @@ def test_kernels_carry_the_names_the_benchmark_reads():
         ssd.ssd_chunked(*args[:3], args[3][:, :, :1].repeat(3, 2), *args[4:], chunk=8)
 
 
-def test_the_scan_runs_mapped_over_a_data_parallel_mesh():
-    """Under a multi-device mesh the kernels go through ``shard_map`` over
-    the batch axes, like the other Mosaic kernels."""
-    from elasticdl_tpu.parallel.mesh import MeshConfig
-
-    args, _ = inputs()
-    mesh = MeshConfig.from_string("dp=2").create(devices=jax.devices()[:2])
-    with mesh, attention_ops.attention_mesh_scope(mesh):
-        mapped = jax.jit(lambda *a: ssd.ssd_scan(*a, chunk=8))(*args)
-    np.testing.assert_allclose(
-        mapped, ssd.ssd_chunked(*args, chunk=8), rtol=1e-6, atol=1e-6
-    )
-
-
 def test_convolution_is_causal_and_depthwise():
     rng = np.random.RandomState(1)
     x = jnp.asarray(rng.randn(2, 12, 6), jnp.float32)
