@@ -131,6 +131,9 @@ SIZES = {
         # tokens, heads, width, key/value heads, the indexer's heads and
         # width, keys a query
         sparse_shape=(1, 16384, 32, 128, 4, 16, 64, 2048),
+        # the gated short convolution's pass at its cell's shape
+        # (lfm2_24b_a2b_seq4096x4): batch, tokens, channels, taps
+        short_conv_shape=(4, 4096, 2048, 3),
     ),
     # the rehearsal: same control flow, CPU backend, interpreted kernels
     "tiny": dict(
@@ -156,6 +159,7 @@ SIZES = {
             (1, 256, 4, 128, 128, 2, 80),
         ),
         sparse_shape=(1, 256, 4, 32, 2, 2, 16, 48),
+        short_conv_shape=(3, 64, 256, 3),
     ),
 }
 
@@ -785,6 +789,9 @@ def _child_kernel(run, cfg, workdir):
     shapes["sparse_" + "x".join(map(str, cfg["sparse_shape"]))] = (
         _check_sparse_kernels(cfg["sparse_shape"], failures)
     )
+    shapes["short_conv_" + "x".join(map(str, cfg["short_conv_shape"]))] = (
+        _check_short_conv_kernels(cfg["short_conv_shape"], failures)
+    )
     from elasticdl_tpu.parallel.elastic import describe_devices
 
     report = _common_report(run, cfg, describe_devices(devices), failures)
@@ -795,6 +802,51 @@ def _child_kernel(run, cfg, workdir):
         scaled_max_abs_err=shapes,
     )
     return report
+
+
+def _check_short_conv_kernels(shape, failures) -> dict:
+    """The compiled ``short_conv_fwd`` / ``short_conv_bwd`` (the layer's own
+    entry, so through ``ops/on_mesh.py`` as the model calls them) against
+    the plain form of ``layers/short_conv.py``: the output and all four
+    gradients, bfloat16 streams, several sequences a batch."""
+    import jax
+    import jax.numpy as jnp
+
+    from elasticdl_tpu.layers import short_conv
+
+    batch, steps, channels, taps = shape
+    keys = jax.random.split(jax.random.PRNGKey(48), 5)
+    b, c, x, w = (
+        jax.random.normal(key, (batch, steps, channels), jnp.bfloat16)
+        for key in keys[:4]
+    )
+    kernel = 0.5 * jax.random.normal(keys[4], (taps, channels), jnp.float32)
+
+    def with_gradients(fn):
+        def run(b, c, x, kernel):
+            out, vjp = jax.vjp(fn, b, c, x, kernel)
+            return (out, *vjp(w))
+        return jax.jit(run)
+
+    lowered = with_gradients(short_conv.short_conv).lower(b, c, x, kernel)
+    if jax.default_backend() == "tpu" and "tpu_custom_call" not in (
+        lowered.as_text()
+    ):
+        failures.append(f"short_conv {shape}: no compiled Mosaic call in the HLO")
+    got = lowered.compile()(b, c, x, kernel)
+    want = with_gradients(short_conv.gated_short_conv)(b, c, x, kernel)
+    errs = {}
+    for part, a, r in zip(("out", "db", "dc", "dx", "dkernel"), got, want):
+        a, r = jnp.asarray(a, jnp.float32), jnp.asarray(r, jnp.float32)
+        err = float(jnp.max(jnp.abs(a - r)))
+        scale = max(1.0, float(jnp.max(jnp.abs(r))))
+        errs[part] = round(err / scale, 5)
+        if not math.isfinite(err) or err > KERNEL_TOL * scale:
+            failures.append(
+                f"short_conv {shape} {part}: max|kernel-plain| = {err:.4g} > "
+                f"{KERNEL_TOL} * {scale:.3g}"
+            )
+    return errs
 
 
 def _check_sparse_kernels(shape, failures, rows=256) -> dict:

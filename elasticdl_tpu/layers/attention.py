@@ -536,10 +536,11 @@ def make_norm(kind: str, epsilon: float, dtype, name=None):
 # a layer of a ``layer_pattern`` (nemotron_h's ``hybrid_override_pattern``):
 # one mixer OR one feed-forward part under one pre-norm residual
 # (``w``: the attention part under the block's ``window``, a query reading
-# its last ``window`` keys alone; ``*`` beside it reads every earlier key)
+# its last ``window`` keys alone; ``*`` beside it reads every earlier key;
+# ``c``: the gated short convolution)
 LAYER_KINDS = {
     "*": "attention", "M": "mamba", "E": "experts", "-": "mlp",
-    "w": "attention",
+    "w": "attention", "c": "conv",
 }
 
 
@@ -654,6 +655,16 @@ class TransformerBlock(nn.Module):
             norm_eps=self.norm_eps, dtype=self.dtype, name="mamba",
             **dict(self.mamba_fields),
         )(y)
+
+    def _conv(self, y, training, decode_pos):
+        from elasticdl_tpu.layers.short_conv import ShortConv
+
+        if self.decode:
+            raise NotImplementedError(
+                "decoding through a short-convolution layer's cache is not "
+                "built"
+            )
+        return ShortConv(dtype=self.dtype, name="conv")(y)
 
     def _experts(self, y, training, decode_pos):
         from elasticdl_tpu.layers.moe import MoEMLP

@@ -47,7 +47,9 @@ VOCAB = 256
 # field: the part's own name for it}.  ``attention`` is
 # MultiHeadSelfAttention, ``latent`` LatentSelfAttention (the two kinds of
 # attention part share the heads and the rotary base), ``moe``
-# layers/moe.py::MoEMLP, ``mamba`` layers/mamba.py::Mamba2Mixer.  A new field
+# layers/moe.py::MoEMLP, ``mamba`` layers/mamba.py::Mamba2Mixer
+# (layers/short_conv.py::ShortConv, the ``c`` layers' part, has no field the
+# model sets: its three taps are its own default).  A new field
 # of a part is declared there, as a field of the model below, and on one line
 # here; every field of the model that is not here is the model's own or the
 # block's.
@@ -199,6 +201,9 @@ class TransformerLM(nn.Module):
     output_gate: bool = False
     norm_outputs: bool = False
     scale_embedding: bool = False
+    # the head is the token embedding: logits = norm(x) @ tok_embed^T, one
+    # parameter whose gradient is the sum of its two uses (no ``lm_head``)
+    tie_embedding: bool = False
 
     @nn.compact
     def __call__(self, features, training: bool = False):
@@ -323,10 +328,20 @@ class TransformerLM(nn.Module):
                 self.variable(
                     LOSS_PARTS, part, lambda: jnp.zeros((), jnp.float32)
                 )
-        lm_head = nn.Dense(
-            self.vocab_size, dtype=self.dtype, use_bias=self.use_bias,
-            name="lm_head",
-        )
+        if self.tie_embedding:
+            if self.use_bias:
+                raise ValueError("a tied head has no bias: use_bias=False")
+
+            def lm_head(h):
+                # the head's time under the head's name, the embedding's
+                # parameter (telemetry/op_scopes.py)
+                with jax.named_scope("lm_head"):
+                    return tok_embed.attend(h)
+        else:
+            lm_head = nn.Dense(
+                self.vocab_size, dtype=self.dtype, use_bias=self.use_bias,
+                name="lm_head",
+            )
         logits = lm_head(norm()(x))
         if self.decode or not (
             self.mtp_depth and (training or self.is_initializing())

@@ -12,7 +12,7 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from elasticdl_tpu.layers import mamba, moe
+from elasticdl_tpu.layers import mamba, moe, short_conv
 from elasticdl_tpu.layers.attention import rope
 from elasticdl_tpu.ops import mamba_passes, on_mesh, rotary, ssd
 from elasticdl_tpu.ops.attention import attention, flash_layout
@@ -78,6 +78,12 @@ def _conv_silu():
     return mamba.conv_silu, args, None
 
 
+def _short_conv():
+    args = (*(_randn(s, 4, 48, 256) for s in range(3)), _randn(3, 3, 256) * 0.5)
+    assert mamba_passes.conv_tile(48, 256, 3)
+    return short_conv.short_conv, args, None
+
+
 def _gate_norm():
     args = (_randn(0, 4, 48, 256), _randn(1, 4, 48, 256), _randn(2, 256) + 2.0)
     assert mamba_passes.gate_norm_tile(4 * 48, 256, 2)
@@ -126,6 +132,7 @@ CALLERS = {
     "flash_folded_grouped_heads-dp=2,tp=2": lambda: _flash(4, 2, 128),
     "scan-dp=4": _scan,
     "conv_silu-dp=4": _conv_silu,
+    "short_conv-dp=4": _short_conv,
     "gate_norm-dp=2": _gate_norm,
     "rope-dp=4": lambda: _rope(False),
     "rope_mrope-dp=4": lambda: _rope(True),

@@ -25,6 +25,7 @@ from elasticdl_tpu.models import resnet50_model
 from elasticdl_tpu.ops import attention as attention_ops
 from elasticdl_tpu.ops import grouped_matmul as gmm_ops
 from elasticdl_tpu.ops import mamba_passes
+from elasticdl_tpu.ops import short_conv as short_conv_ops
 from elasticdl_tpu.ops import sparse_attention as sparse_ops
 from elasticdl_tpu.ops import ssd as ssd_ops
 from elasticdl_tpu.parallel.distributed import SPMDTrainer
@@ -44,10 +45,11 @@ KERNELS = {
     attention_ops.SELECTED_DKV, attention_ops.WINDOW_FWD,
     attention_ops.WINDOW_DQ, attention_ops.WINDOW_DKV, sparse_ops.INDEX_SELECT,
     sparse_ops.INDEX_SELECT_HINTED, sparse_ops.INDEXER_KL,
+    short_conv_ops.SHORT_CONV_FWD, short_conv_ops.SHORT_CONV_BWD,
 }
 # modules that hold other modules: an op directly under one of these is in
 # a region nobody named
-MIXERS = {"attn", "moe", "mamba"}
+MIXERS = {"attn", "moe", "mamba", "conv"}
 
 STEP = "jit(train_step)/"
 BLOCK = "block_11/block_11._residual/block_11._attention/attn/"
@@ -340,6 +342,7 @@ FAMILIES = {
     "latent_attention_mtp": lambda: _lm_family("tiny_joyai"),
     "sparse_attention": lambda: _lm_family("tiny_keye"),
     "window_and_full_attention": lambda: _lm_family("tiny_trinity"),
+    "short_conv_attention_experts_tied": lambda: _lm_family("tiny_lfm2"),
     "resnet_first_stage": lambda: (
         FirstStage(), _class_loss, optax.sgd(0.1),
         {"image": np.zeros((2, 32, 32, 3), np.float32)},
@@ -433,6 +436,19 @@ def test_every_region_of_the_step_has_a_name(built):
     if family == "window_and_full_attention":
         # the gate's projection and its product, the norm on a part's output
         assert {"block/attn/gate", "block/norm_out/RMSNorm"} <= parts
+    if family == "short_conv_attention_experts_tied":
+        # the operator's two projections and its pass (128 channels here:
+        # ops/short_conv.py's kernels, parts of their own under the scope);
+        # the tied head's product keeps the head's name, and the tree has
+        # no ``lm_head`` module to give it
+        assert {
+            "block/conv/in_proj", "block/conv/out_proj", "block/conv/pass",
+            "lm_head",
+        } <= parts | {op_scopes.at_depth(part, 3) for part in parts}
+        assert "lm_head" not in state.params
+        assert {
+            phase for part, phase, _, _ in scopes.values() if part == "lm_head"
+        } >= {"forward", "backward"}
     if family == "resnet_first_stage":
         assert {"conv_block/conv_a", "identity_block/bn_c", "fc"} <= parts
 
@@ -596,6 +612,48 @@ def test_the_mixers_passes_are_kernels_under_their_own_parts(one_chip_mesh):
         )
     ]
     for pattern in patterns:
+        assert trace_reduce.matching_seconds(reduced, pattern) == 0, pattern
+
+
+def test_the_short_convolutions_pass_is_a_kernel_each_way(one_chip_mesh):
+    """A gated short convolution layer at a width the kernels tile (256
+    channels, 1,024 tokens), recomputed, compiled for the described chip:
+    Mosaic takes both kernels, each custom-call keeps its name and sits
+    under ``block/conv/pass`` in the phases the step runs it in, and neither
+    reads as another family's kernel to ``perf/``'s readers, which match by
+    name."""
+    from perf import conv_rooflines, expert_rooflines, layer_readers, trace_reduce
+
+    model, loss, tx, _, _, _ = _lm_family(
+        "tiny_lfm2", num_layers=2, layer_pattern="c-", embed_dim=256,
+    )
+    compiled = _lowered_for_the_chip(one_chip_mesh, model, loss, tx).compile()
+    scopes = op_scopes.scope_map(compiled)
+    found = {}
+    for name, (part, phase, kind, _) in scopes.items():
+        if kind == "kernel":
+            found.setdefault(name.split(".")[0], set()).add((part, phase))
+    assert found == {
+        short_conv_ops.SHORT_CONV_FWD: {
+            ("block/conv/pass/short_conv_fwd", "forward"),
+            ("block/conv/pass/short_conv_fwd", "recompute"),
+        },
+        short_conv_ops.SHORT_CONV_BWD: {
+            ("block/conv/pass/short_conv_bwd", "backward")
+        },
+    }
+    ours = {
+        name: 1.0 for name, (_, _, kind, _) in scopes.items() if kind == "kernel"
+    }
+    assert len(ours) == 3
+    reduced = {"op_self_s": ours, "details": {}}
+    assert trace_reduce.matching_seconds(reduced, conv_rooflines.CONV_KERNELS) == 3
+    for pattern in [layer_readers.FLASH_KERNELS] + [
+        rf"^{kernel}\b" for kernel in (
+            expert_rooflines.EXPERT_KERNELS, "ssd_", "flash_", "expert_gmm_",
+            "mamba_conv", "gate_norm", "swa_", "dsa_", "rope_",
+        )
+    ]:
         assert trace_reduce.matching_seconds(reduced, pattern) == 0, pattern
 
 
