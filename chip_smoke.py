@@ -134,6 +134,10 @@ SIZES = {
         # the gated short convolution's pass at its cell's shape
         # (lfm2_24b_a2b_seq4096x4): batch, tokens, channels, taps
         short_conv_shape=(4, 4096, 2048, 3),
+        # the expert layer's rows added into their tokens at its largest
+        # low rung (trinity_mini_seq16384): rows, tokens, width, experts a
+        # token, experts held, experts routed over
+        expert_rows_shape=(34816, 16384, 2048, 8, 16, 128),
     ),
     # the rehearsal: same control flow, CPU backend, interpreted kernels
     "tiny": dict(
@@ -160,6 +164,7 @@ SIZES = {
         ),
         sparse_shape=(1, 256, 4, 32, 2, 2, 16, 48),
         short_conv_shape=(3, 64, 256, 3),
+        expert_rows_shape=(640, 200, 64, 2, 4, 16),
     ),
 }
 
@@ -792,6 +797,9 @@ def _child_kernel(run, cfg, workdir):
     shapes["short_conv_" + "x".join(map(str, cfg["short_conv_shape"]))] = (
         _check_short_conv_kernels(cfg["short_conv_shape"], failures)
     )
+    shapes["expert_rows_" + "x".join(map(str, cfg["expert_rows_shape"]))] = (
+        _check_expert_rows_sum(cfg["expert_rows_shape"], failures)
+    )
     from elasticdl_tpu.parallel.elastic import describe_devices
 
     report = _common_report(run, cfg, describe_devices(devices), failures)
@@ -845,6 +853,68 @@ def _check_short_conv_kernels(shape, failures) -> dict:
             failures.append(
                 f"short_conv {shape} {part}: max|kernel-plain| = {err:.4g} > "
                 f"{KERNEL_TOL} * {scale:.3g}"
+            )
+    return errs
+
+
+def _check_expert_rows_sum(shape, failures) -> dict:
+    """The compiled ``expert_rows_sum`` (``ops/grouped_matmul.py``'s
+    ``sum_by_token``) against the plain float32 scatter-add it replaced, on
+    a uniform routing laid out by the layer's own ``group_layout``:
+    bfloat16 rows with float32 weights (the combine) and with weight 1 (the
+    dispatch's transpose).  Both sides sum float32 products and round once,
+    so they differ by a bfloat16 rounding where the order of a token's terms
+    moved its sum."""
+    import jax
+    import jax.numpy as jnp
+
+    from elasticdl_tpu.layers import moe
+    from elasticdl_tpu.ops import grouped_matmul as gmm_ops
+
+    rows, tokens, width, slots, held, routed = shape
+    keys = jax.random.split(jax.random.PRNGKey(49), 3)
+    _, top = jax.lax.top_k(jax.random.uniform(keys[0], (tokens, routed)), slots)
+    group_ids = jnp.where(top < held, top, held).reshape(-1).astype(jnp.int32)
+    order = gmm_ops.group_order(group_ids, held)
+    layout = gmm_ops.group_layout(
+        group_ids, held, gmm_ops.TILE_ROWS, rows, order, True
+    )
+    spans = gmm_ops.token_spans(
+        group_ids, order.sizes, tokens, gmm_ops.TILE_ROWS
+    )
+    weights = jax.random.uniform(keys[1], (tokens, slots), jnp.float32, 0.05, 1.0)
+    row_weight, row_token = moe._rows_of(weights, layout.row_pair)
+    values = jax.random.normal(keys[2], (rows, width), jnp.bfloat16)
+    if int(jnp.sum(row_token < tokens)) != int(jnp.sum(group_ids < held)):
+        failures.append(f"expert_rows {shape}: the rung does not hold the draw")
+
+    def plain(values, row_token, row_weight):
+        products = values.astype(jnp.float32)
+        if row_weight is not None:
+            products = products * row_weight[:, None]
+        return jnp.zeros((tokens, width), jnp.float32).at[row_token].add(
+            products, mode="drop"
+        ).astype(values.dtype)
+
+    errs = {}
+    for part, weight in (("combine", row_weight), ("dispatch_transpose", None)):
+        lowered = jax.jit(
+            lambda v, t, w: gmm_ops.sum_by_token(v, t, tokens, spans, w)
+        ).lower(values, row_token, weight)
+        if jax.default_backend() == "tpu" and "tpu_custom_call" not in (
+            lowered.as_text()
+        ):
+            failures.append(f"expert_rows {shape}: no compiled Mosaic call in the HLO")
+        a = jnp.asarray(lowered.compile()(values, row_token, weight), jnp.float32)
+        r = jnp.asarray(jax.jit(plain)(values, row_token, weight), jnp.float32)
+        err = float(jnp.max(jnp.abs(a - r)))
+        scale = max(1.0, float(jnp.max(jnp.abs(r))))
+        errs[part] = round(err / scale, 5)
+        # (one bfloat16 rounding apart at most: 2^-8 of the value)
+        if not math.isfinite(err) or err > 2.0**-7 * scale:
+            failures.append(
+                f"expert_rows {shape} {part}: max|kernel-plain| = {err:.4g} > "
+                f"2^-7 * {scale:.3g}"
             )
     return errs
 

@@ -32,7 +32,9 @@ Dispatch has static shapes at any imbalance
 expert's rows padded to whole tiles, a gather into that order, the grouped
 matmuls, and a gather back with the weights — the permutation's transpose
 is a gather too (each pair has one row), so no scatter of activations runs
-in either direction (but on a low rung, below).  On a mesh the experts' leading dimension shards over
+in either direction.  (On a low rung, below, the rows are added into their
+tokens: by a kernel, ``grouped_matmul.sum_by_token``, not by a scatter.)  On
+a mesh the experts' leading dimension shards over
 ``ep`` (``moe_sharding_rules``): under ``shard_map`` each rank lays out only
 the pairs of its own experts, the others weigh zero, and a ``psum`` over
 ``ep`` completes the combine.  The all-to-all that would move tokens
@@ -54,7 +56,9 @@ holds them (no host readback), and inside the branch the layout, the three
 kernels' grids, the activation and both permutations run at the rung's
 size.  Below the full rung the permutations move rows and not pairs
 (``_combine_by_rows``, ``_dispatch_by_rows``: the rows added into their
-tokens, the weights' gradient a dot product a row).  "Nothing is ever
+tokens by the kernel ``expert_rows_sum``, each tile of tokens fetching its
+rows' spans from the buffer, ``grouped_matmul.sum_by_token``; the weights'
+gradient a dot product a row).  "Nothing is ever
 dropped" now rests on the last rung, which is always the full size, at the
 cost every step paid before; a routing that outgrows the low rung by a few
 thousand rows (a router collapsing onto one held expert) takes the next.  The choice sits inside one ``custom_vjp``
@@ -159,58 +163,63 @@ def _rows_of(weights, row_pair):
     )
 
 
-def _sum_by_token(values, row_token, tokens):
-    """(tokens, d) float32: each token's rows added up, a row at a time; a
-    padding row (token ``tokens``) falls out."""
-    return jnp.zeros((tokens, values.shape[1]), jnp.float32).at[row_token].add(
-        values.astype(jnp.float32), mode="drop"
-    )
-
-
 # The same two permutations in the form a rung below the full one takes:
 # what moves is rows x d, not pairs x d.  A buffer that holds an eighth of
 # the pairs makes the pair-indexed gathers above (an absent pair reads row
 # 0) the layer's largest passes; here the rows are added into their tokens
-# instead.  ops/grouped_matmul.py's "no scatter of activations" was found
-# at 65,536 rows; at a low rung's 7,168 rows of 2,688 the scatter-add reads
-# 1.18 ms against the gather's 2.99, and the weights' gradient as a dot
-# product a row 0.51 against 3.26 (PERF.md section 6, PR 33).  A token's
-# rows are summed in row order here and in slot order above: the results
-# differ by a float32 rounding of a sum of at most ``slots`` terms.
+# instead, by ``grouped_matmul.sum_by_token``: the combine's forward and the
+# dispatch's transpose.  Up to PR 48 that was XLA's float32 scatter-add,
+# chosen at a low rung's 7,168 rows of 2,688 (1.18 ms against the gather's
+# 2.99, PERF.md section 6, PR 33) and a row at a time whatever the row's
+# width: 4.1 ms a call at the 34,816 rows of a 16,384-token step, where the
+# kernel takes 0.86 (weights) and 0.53 (weight 1; PERF.md section 6, PR 49).
+# The weights' gradient is a dot product a row (0.51 ms against 3.26 by
+# pairs).  A token's rows are summed in the order the kernel meets them
+# here and in slot order above: the results differ by a float32 rounding of
+# a sum of at most ``slots`` terms.
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
-def _dispatch_by_rows(x, row_token, tokens):
-    """``x[row_token]``; a padding row reads the last token."""
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _dispatch_by_rows(x, row_token, spans, tokens, interpret):
+    """``x[row_token]``; a padding row reads the last token.  ``spans``:
+    ``grouped_matmul.token_spans`` of the layout, for the way back."""
     return x[jnp.minimum(row_token, tokens - 1)]
 
 
-def _dispatch_by_rows_fwd(x, row_token, tokens):
-    return _dispatch_by_rows(x, row_token, tokens), row_token
+def _dispatch_by_rows_fwd(x, row_token, spans, tokens, interpret):
+    return _dispatch_by_rows(x, row_token, spans, tokens, interpret), (
+        row_token, spans,
+    )
 
 
-def _dispatch_by_rows_bwd(tokens, row_token, d_rows):
-    return _sum_by_token(d_rows, row_token, tokens).astype(d_rows.dtype), None
+def _dispatch_by_rows_bwd(tokens, interpret, residuals, d_rows):
+    row_token, spans = residuals
+    d_x = gmm_ops.sum_by_token(
+        d_rows, row_token, tokens, spans, interpret=interpret
+    )
+    return d_x, None, None
 
 
 _dispatch_by_rows.defvjp(_dispatch_by_rows_fwd, _dispatch_by_rows_bwd)
 
 
-@jax.custom_vjp
-def _combine_by_rows(rows, weights, row_pair):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _combine_by_rows(rows, weights, row_pair, spans, interpret):
     """:func:`_combine` as ``y[token of r] += weight of r * rows[r]``."""
     row_weight, row_token = _rows_of(weights, row_pair)
-    return _sum_by_token(
-        rows.astype(jnp.float32) * row_weight[:, None], row_token,
-        weights.shape[0],
-    ).astype(rows.dtype)
+    return gmm_ops.sum_by_token(
+        rows, row_token, weights.shape[0], spans, row_weight,
+        interpret=interpret,
+    )
 
 
-def _combine_by_rows_fwd(rows, weights, row_pair):
-    return _combine_by_rows(rows, weights, row_pair), (rows, weights, row_pair)
+def _combine_by_rows_fwd(rows, weights, row_pair, spans, interpret):
+    return _combine_by_rows(rows, weights, row_pair, spans, interpret), (
+        rows, weights, row_pair,
+    )
 
 
-def _combine_by_rows_bwd(residuals, d_y):
+def _combine_by_rows_bwd(interpret, residuals, d_y):
     rows, weights, row_pair = residuals
     row_weight, row_token = _rows_of(weights, row_pair)
     d_y_rows = d_y[jnp.minimum(row_token, weights.shape[0] - 1)].astype(
@@ -223,7 +232,7 @@ def _combine_by_rows_bwd(residuals, d_y):
     d_weights = jnp.zeros((weights.size,), weights.dtype).at[row_pair].set(
         row_dot.astype(weights.dtype), mode="drop", unique_indices=True
     )
-    return d_rows, d_weights.reshape(weights.shape), None
+    return d_rows, d_weights.reshape(weights.shape), None, None
 
 
 _combine_by_rows.defvjp(_combine_by_rows_fwd, _combine_by_rows_bwd)
@@ -247,7 +256,10 @@ def _experts_at(
         held = layout.row_pair < tokens * slots
         if by_rows:
             _, row_token = _rows_of(weights, layout.row_pair)
-            buffer = _dispatch_by_rows(x, row_token, tokens)
+            spans = gmm_ops.token_spans(
+                group_ids, order.sizes, tokens, tile_rows
+            )
+            buffer = _dispatch_by_rows(x, row_token, spans, tokens, interpret)
         else:
             pair_row = layout.pair_row.reshape(tokens, slots)
             row_token = (
@@ -271,7 +283,9 @@ def _experts_at(
         out = matmul(hidden, w_down)
     with jax.named_scope("combine"):
         if by_rows:
-            y = _combine_by_rows(out, weights, layout.row_pair)
+            y = _combine_by_rows(
+                out, weights, layout.row_pair, spans, interpret
+            )
         else:
             y = _combine(out, weights, pair_row, layout.row_pair)
     with jax.named_scope("dispatch"):

@@ -33,6 +33,13 @@ the rows it is given.  "Nothing is ever dropped" rests on the last rung,
 which is always ``num_rows`` of all pairs; where the groups here are all
 the groups, it is the only rung and nothing is chosen.
 
+**The way back.**  On the full rung each pair has one row and the
+permutations are gathers both ways.  Below it the rows of a buffer are added
+into their tokens (``layers/moe.py``: the combine, the dispatch's transpose)
+by a fourth kernel, :func:`sum_by_token`: a tile of tokens fetches its rows,
+a contiguous span a group (:func:`token_spans`), and adds them as a one-hot
+product; XLA's scatter-add, which it replaced, took a row at a time.
+
 Each kernel has a name the device trace's op line shows (as the flash
 kernels do, ``ops/attention.py``): ``perf/`` reads them by it.
 """
@@ -52,6 +59,10 @@ from elasticdl_tpu.ops import on_mesh
 GMM_FWD = "expert_gmm_fwd"
 GMM_DX = "expert_gmm_dx"
 GMM_DW = "expert_gmm_dw"
+# the rows of a buffer added into their tokens (:func:`sum_by_token`): a
+# kernel of the expert layer but no grouped matmul, so a name the three
+# above (and ``perf/expert_rooflines.py``'s pattern for them) do not match
+ROWS_SUM = "expert_rows_sum"
 
 # rows of a tile: what a group is padded to.  On the chip 128, 256 and 512
 # run the kernels at the same rate (a group's matrix stays resident, so a
@@ -78,6 +89,21 @@ LOW_RUNG_SHARES = 2
 _MAX_BLOCK_BYTES = 4 * 1024 * 1024
 _VMEM_LIMIT_BYTES = 64 * 1024 * 1024
 _LANES = 128
+# :func:`sum_by_token`: tokens of an output tile (a grid step), rows fetched
+# a group and round, and the rows a fetch may start at a multiple of (a
+# bfloat16 tile's).  A round multiplies the staged rows of every block of
+# groups that has a row in it into the tile, whatever else the block holds,
+# so the matrix unit's work grows with both; a balanced load sends a tile
+# ~8 rows a group in the cells (rows a rung / 2 / tiles / groups), and with
+# the 0-15 rows before a span's start 32 hold most spans in one round.
+# Swept on the chip at the four low rungs of the cells (PERF.md section 6,
+# PR 49): 16 rows take more rounds (+19%); 48 and 64 stage rows for nothing
+# (+36%, +75%, read before the fetches overlapped the products); 256 tokens
+# halve the steps and double a round's product (+52% at 34,816 rows, -10%
+# at 10,240)
+_SUM_TOKENS = 128
+_SPAN_ROWS = 32
+_SPAN_ALIGN = 16
 
 
 class GroupOrder(NamedTuple):
@@ -368,3 +394,233 @@ def grouped_matmul(
             f"{tile_rows}"
         )
     return _grouped_matmul(lhs, rhs, tile_group, tile_rows, interpret)
+
+
+# ---- the rows of a buffer added into their tokens ---------------------------
+
+
+def token_spans(group_ids, sizes, tokens: int, tile_rows: int):
+    """``(lo, hi)``, each (token tiles, groups): the rows ``[lo, hi)`` of a
+    buffer laid out by :func:`group_layout` that hold the pairs of each
+    group whose tokens lie in each tile of ``_SUM_TOKENS`` tokens
+    (``group_ids`` and ``sizes`` as :func:`group_order` takes and gives
+    them).  A group's rows are in token order (the stable sort), so a
+    tile's are one contiguous span of them, whatever the rung."""
+    pairs = group_ids.shape[0]
+    slots = pairs // tokens
+    groups = sizes.shape[0]
+    tiles = -(-tokens // _SUM_TOKENS)
+    ids = jnp.pad(
+        group_ids, (0, tiles * _SUM_TOKENS * slots - pairs),
+        constant_values=groups,
+    )
+    counts = jnp.sum(
+        ids.reshape(tiles, -1, 1) == jnp.arange(groups, dtype=jnp.int32),
+        axis=1, dtype=jnp.int32,
+    )
+    group_tiles = jnp.maximum(-(-sizes // tile_rows), 1)
+    row_starts = (jnp.cumsum(group_tiles) - group_tiles) * tile_rows
+    lo = row_starts + jnp.cumsum(counts, axis=0) - counts
+    return lo.astype(jnp.int32), (lo + counts).astype(jnp.int32)
+
+
+def _rows_sum_kernel(
+    lo, hi, rounds, rows, onehot_rows, out, stage, hot_stage, acc, sems,
+    fetched, *, groups, span, count, weighted
+):
+    tile = pl.program_id(0)
+    tiles = pl.num_programs(0)
+    # the staged rows are multiplied a block of whole lanes' worth at a
+    # time, and a block none of whose groups has a row in a round is neither
+    # fetched nor multiplied in it: past the first round that is all but the
+    # blocks of the groups a skewed routing sends most of a tile's tokens to
+    blocks = [
+        range(g, min(g + _LANES // span, groups))
+        for g in range(0, groups, _LANES // span)
+    ]
+
+    def window(tile, group, k):
+        # the k-th window of the group's span in the tile, the rows of it
+        # the span holds, and where its fetch starts (inside the buffer)
+        low = lo[tile * groups + group]
+        first = low // _SPAN_ALIGN * _SPAN_ALIGN + k * span
+        return (
+            jnp.maximum(low, first),
+            jnp.minimum(hi[tile * groups + group], first + span),
+            pl.multiple_of(jnp.minimum(first, count - span), _SPAN_ALIGN),
+        )
+
+    def active(tile, block, k):
+        held = False
+        for group in block:
+            low, high, _ = window(tile, group, k)
+            held = jnp.logical_or(held, low < high)
+        return held
+
+    def copies(tile, group, k, slot):
+        start = window(tile, group, k)[2]
+        at = pl.ds(group * span, span)
+        return (
+            pltpu.make_async_copy(
+                rows.at[pl.ds(start, span)], stage.at[slot, at],
+                sems.at[slot, 0],
+            ),
+            pltpu.make_async_copy(
+                onehot_rows.at[pl.ds(start, span)], hot_stage.at[slot, at],
+                sems.at[slot, 1],
+            ),
+        )
+
+    def fetch(tile, k, slot):
+        for block in blocks:
+            @pl.when(active(tile, block, k))
+            def _():
+                for group in block:
+                    for copy in copies(tile, group, k, slot):
+                        copy.start()
+
+    # the rounds of all tiles are one sequence, each fetched into the slot
+    # the one before it does not hold while that one is multiplied
+    @pl.when(tile == 0)
+    def _():
+        fetched[0] = 0
+        fetch(0, 0, 0)
+
+    acc[...] = jnp.zeros_like(acc)
+
+    def one_round(k, carry):
+        slot = fetched[0] % 2
+        more = k + 1 < rounds[tile]
+        next_tile = jnp.where(more, tile, tile + 1)
+
+        @pl.when(next_tile < tiles)
+        def _():
+            fetch(next_tile, jnp.where(more, k + 1, 0), 1 - slot)
+
+        for block in blocks:
+            @pl.when(active(tile, block, k))
+            def _():
+                lines = []
+                for group in block:
+                    for copy in copies(tile, group, k, slot):
+                        copy.wait()
+                    low, high, start = window(tile, group, k)
+                    at = pl.ds(group * span, span)
+                    row = start + jax.lax.broadcasted_iota(
+                        jnp.int32, (span, hot_stage.shape[2]), 0
+                    )
+                    lines.append(jnp.where(
+                        jnp.logical_and(row >= low, row < high),
+                        hot_stage[slot, at, :], 0.0,
+                    ))
+                rest = jnp.concatenate(lines, axis=0)
+                staged = stage[slot, pl.ds(block[0] * span, len(block) * span), :]
+                contract = (((0,), (0,)), ((), ()))
+                if staged.dtype != jnp.bfloat16:
+                    acc[...] += jax.lax.dot_general(
+                        rest, staged.astype(jnp.float32), contract,
+                        precision=jax.lax.Precision.HIGHEST,
+                        preferred_element_type=jnp.float32,
+                    )
+                    return
+                for _ in range(3 if weighted else 1):
+                    piece = rest.astype(jnp.bfloat16)
+                    acc[...] += jax.lax.dot_general(
+                        piece, staged, contract,
+                        preferred_element_type=jnp.float32,
+                    )
+                    rest = rest - piece.astype(jnp.float32)
+
+        fetched[0] += 1
+        return carry
+
+    jax.lax.fori_loop(0, rounds[tile], one_round, 0)
+    out[...] = acc[...].astype(out.dtype)
+
+
+def sum_by_token(
+    rows, row_token, tokens: int, spans, row_weight=None, *,
+    interpret: bool | None = None,
+):
+    """``out[t] = sum of row_weight[r] * rows[r] over the r with
+    row_token[r] == t``: (tokens, d) in ``rows``' dtype, float32 products
+    summed in float32 and rounded once.  A row whose token is ``tokens`` (a
+    padding row) adds nothing, a token with no row reads zero;
+    ``row_weight=None`` weighs every row 1.  ``spans`` =
+    :func:`token_spans` of the layout the rows lie in.
+
+    What XLA does as a scatter-add, a row at a time whatever the row's
+    width (0.12 us a row on a v5e: 4.1 ms for a rung of 34,816 rows), as one
+    kernel, ``expert_rows_sum``, with no (rows, d) or (tokens, d) float32
+    array in HBM.  A grid step owns a tile of tokens.  The tile's rows of
+    one group are contiguous in the buffer, so a round fetches a window of
+    ``span`` rows of each group's span (``make_async_copy`` from the
+    16-row boundary before its start; the next round's, or the next tile's
+    first, is in flight while this one is multiplied), masks the rows of the
+    window outside the span, and adds the staged rows into the tile as a
+    product on the matrix unit with their lines of the tile's one-hot
+    matrix: ``weight`` at the row's token, which XLA writes once as a
+    (rows, 128) float32 array and the same windows fetch.  The
+    groups are taken 128 staged rows at a time, and a block with no row in
+    a round is neither fetched nor multiplied: a router that sends most of
+    a tile to one group pays the later rounds for that group's block alone,
+    a tile with no row pays nothing.  bfloat16
+    rows are multiplied as they are, by the three bfloat16 pieces (8 + 8 + 8
+    bits) a float32 weight is the sum of: each product is exact in float32,
+    so no weight is rounded and what is summed are float32 products (one
+    pass for weight 1).  Rows of another dtype take one float32 product at
+    the highest precision.  Rounds go on while a group's span has rows
+    left, so any routing is summed whole; ``lo``, ``hi`` and the rounds
+    ride in scalar memory, 4 bytes a (tile, group) each.  (Zero times a row
+    that is not finite is not zero: such a row spoils every token of the
+    tiles whose windows hold it, where the scatter-add spoilt its own.)"""
+    if interpret is None:
+        interpret = on_mesh.default_interpret()
+    token_tile, span = _SUM_TOKENS, _SPAN_ROWS
+    lo, hi = spans
+    tiles, groups = lo.shape
+    count, width = rows.shape
+    weight = (row_token < tokens).astype(jnp.float32)
+    if row_weight is not None:
+        weight = weight * row_weight
+    # a row's line of the one-hot matrix of its tile: its weight at its token
+    onehot_rows = jnp.where(
+        (row_token % token_tile)[:, None]
+        == jnp.arange(token_tile, dtype=jnp.int32),
+        weight[:, None], 0.0,
+    )
+    if count < span:
+        rows = jnp.pad(rows, ((0, span - count), (0, 0)))
+        onehot_rows = jnp.pad(onehot_rows, ((0, span - count), (0, 0)))
+        count = span
+    first = lo // _SPAN_ALIGN * _SPAN_ALIGN
+    rounds = jnp.maximum(jnp.max(-(-(hi - first) // span), axis=1), 1)
+    staged = groups * span
+    return pl.pallas_call(
+        functools.partial(
+            _rows_sum_kernel, groups=groups, span=span, count=count,
+            weighted=row_weight is not None,
+        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(tiles,),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 2,
+            out_specs=pl.BlockSpec(
+                (token_tile, width), lambda i, lo, hi, rounds: (i, 0)
+            ),
+            scratch_shapes=[
+                pltpu.VMEM((2, staged, width), rows.dtype),
+                pltpu.VMEM((2, staged, token_tile), jnp.float32),
+                pltpu.VMEM((token_tile, width), jnp.float32),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.SMEM((1,), jnp.int32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((tokens, width), rows.dtype),
+        compiler_params=_compiler_params(1),
+        interpret=interpret,
+        name=ROWS_SUM,
+    )(
+        lo.reshape(-1), hi.reshape(-1), rounds.astype(jnp.int32),
+        rows, onehot_rows,
+    )

@@ -23,6 +23,7 @@ def test_the_kept_drivers():
     assert DRIVERS == [
         "attention_sweep.py",
         "dispatch_overhead_bench.py",
+        "expert_rows_sweep.py",
         "preemption_accuracy_bench.py",
         "reform_bench.py",
         "rope_sweep.py",

@@ -217,3 +217,155 @@ def test_layout_at_a_rung_holds_every_grouped_pair_once(sizes, tile_rows):
         low.tile_group, np.asarray(full.tile_group)[: rungs[0] // tile_rows]
     )
     assert (np.asarray(full.row_pair)[rungs[0] :] == 200).all()
+
+
+# --- the rows of a buffer added into their tokens (sum_by_token) ---
+
+
+def scatter_add(rows, row_token, tokens, row_weight=None):
+    """The plain form the kernel replaced (layers/moe.py up to PR 48):
+    float32 products added into a float32 array a row at a time."""
+    values = rows.astype(jnp.float32)
+    if row_weight is not None:
+        values = values * row_weight[:, None]
+    return jnp.zeros((tokens, rows.shape[1]), jnp.float32).at[row_token].add(
+        values, mode="drop"
+    ).astype(rows.dtype)
+
+
+def _rows_in_group_order(tokens, slots, sizes, tile_rows, tail, seed):
+    """A routing of ``sizes[g]`` tokens to group ``g`` laid out by
+    ``group_layout`` in a rung with ``tail`` tiles of no group after the
+    last: ``row_token`` (the rows of each group in token order and padded
+    to whole tiles, token ``tokens`` on a padding row, so the buffer as a
+    whole is out of token order) and the layout's ``token_spans``.  A token
+    is in ``slots`` groups at most, every seventh token in none."""
+    rng = np.random.RandomState(seed)
+    groups = len(sizes)
+    free = np.full(tokens, slots)
+    free[::7] = 0
+    group_ids = np.full((tokens, slots), groups, np.int32)
+    for group, size in enumerate(sizes):
+        # (the tokens with most slots left first, so the sizes always fit)
+        chosen = np.lexsort((rng.rand(tokens), -free))[:size]
+        assert free[chosen].all()
+        free[chosen] -= 1
+        group_ids[chosen, free[chosen]] = group
+    group_ids = jnp.asarray(group_ids.reshape(-1))
+    order = g.group_order(group_ids, groups)
+    rows = (int(g.tiles_needed(order.sizes, tile_rows)) + tail) * tile_rows
+    layout = g.group_layout(group_ids, groups, tile_rows, rows, order, True)
+    row_token = np.where(
+        layout.row_pair < tokens * slots, layout.row_pair // slots, tokens
+    ).astype(np.int32)
+    return row_token, g.token_spans(group_ids, order.sizes, tokens, tile_rows)
+
+
+SUMS = {
+    # tokens, slots, rows of each group, tile rows, tail tiles of no group
+    "tokens_with_no_row_the_others_with_every_slot": (40, 3, [11, 34, 7, 34, 16], 8, 0),
+    "padding_rows_inside_and_after_the_last_group": (64, 2, [5, 0, 9, 1], 8, 3),
+    "tokens_and_rows_that_end_mid_tile": (200, 4, [150, 37, 99, 1, 64], 8, 1),
+    "most_tiles_of_tokens_with_no_row": (700, 2, [3, 20], 8, 0),
+    "no_row_held": (130, 2, [0, 0, 0], 16, 2),
+    "whole_tiles_of_held_rows": (256, 4, [128, 128, 128, 128], 128, 0),
+    # nine groups are three blocks of the kernel's staged rows: past the
+    # first round the middle one holds nothing and is skipped
+    "two_groups_on_most_tokens_seven_on_few": (
+        256, 4, [219, 2, 0, 7, 1, 0, 3, 5, 219], 8, 1
+    ),
+}
+
+
+@pytest.mark.parametrize("weighted", [True, False], ids=["weights", "weight_1"])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"])
+@pytest.mark.parametrize("case", SUMS.values(), ids=SUMS.keys())
+def test_sum_by_token_is_the_scatter_add(case, dtype, weighted):
+    """The kernel against the plain scatter-add on rows in group order:
+    bfloat16 rows to the last bit (their products with a float32 weight are
+    summed in float32 on both sides and rounded once; a token has few
+    enough rows here that the order of its terms rounds alike), float32
+    rows to a float32 rounding of a token's sum."""
+    tokens, slots, sizes, tile_rows, tail = case
+    row_token, spans = _rows_in_group_order(
+        tokens, slots, sizes, tile_rows, tail, 1
+    )
+    count = len(row_token)
+    held = row_token < tokens
+    assert not np.all(np.diff(row_token[held]) >= 0) or sum(map(bool, sizes)) < 2
+    rng = np.random.RandomState(2)
+    rows = jnp.asarray(rng.randn(count, 48), dtype)
+    # weights that bfloat16 would round: 16 more bits than it keeps
+    weight = jnp.asarray(rng.rand(count) + 2.0**-20, jnp.float32) if weighted else None
+    got = jax.jit(lambda r, t, w: g.sum_by_token(r, t, tokens, spans, w))(
+        rows, jnp.asarray(row_token), weight
+    )
+    want = scatter_add(rows, jnp.asarray(row_token), tokens, weight)
+    assert got.shape == (tokens, 48) and got.dtype == dtype
+    counts = np.bincount(row_token[held], minlength=tokens)
+    assert counts[0] == 0 and counts.max() <= slots
+    if case is SUMS["tokens_with_no_row_the_others_with_every_slot"]:
+        assert set(counts) == {0, slots}
+    np.testing.assert_array_equal(np.asarray(got)[counts == 0], 0)
+    if dtype == jnp.bfloat16:
+        # one rounding of a float32 sum: at most the neighbouring bfloat16
+        # where the terms' order moved the sum across a rounding boundary
+        np.testing.assert_allclose(
+            np.asarray(got, np.float32), np.asarray(want, np.float32),
+            rtol=2.0**-7, atol=0,
+        )
+        assert np.mean(np.asarray(got) == np.asarray(want)) > 0.99
+    else:
+        bound = 4e-7 * float(jnp.abs(want).max())
+        np.testing.assert_allclose(got, want, rtol=0, atol=bound)
+
+
+def test_sum_by_token_rounds_neither_a_weight_nor_a_partial_sum():
+    """Rows and weights chosen so that every product and sum is exact in
+    float32 and none in bfloat16: a weight rounded to bfloat16 before the
+    product, or a partial sum rounded on the way, gives another result."""
+    tokens, width = 24, 32
+    # two groups of a tile of four rows each, and a tile of no group
+    row_token = jnp.asarray([5, 9, 24, 24, 0, 5, 9, 24, 24, 24, 24, 24], jnp.int32)
+    group_ids = jnp.full((tokens, 2), 2, jnp.int32)
+    group_ids = group_ids.at[jnp.asarray([5, 9]), 0].set(0)
+    group_ids = group_ids.at[jnp.asarray([0, 5, 9]), 1].set(1).reshape(-1)
+    spans = g.token_spans(group_ids, jnp.asarray([2, 3]), tokens, 4)
+    rows = jnp.full((12, width), 3.0, jnp.bfloat16)
+    # 1 + 2^-10 and 2^-9 + 2^-18: both lose their low bit in bfloat16
+    weight = jnp.asarray(
+        [1 + 2.0**-10, 2.0**-9 + 2.0**-18] * 6, jnp.float32
+    )
+    got = g.sum_by_token(rows, row_token, tokens, spans, weight).astype(jnp.float32)
+    want = scatter_add(rows, row_token, tokens, weight).astype(jnp.float32)
+    np.testing.assert_array_equal(got, want)
+    # float32 rows: the sum of token 5, exact, has bits bfloat16 drops
+    rows32 = rows.astype(jnp.float32)
+    got32 = g.sum_by_token(rows32, row_token, tokens, spans, weight)
+    np.testing.assert_array_equal(
+        got32, scatter_add(rows32, row_token, tokens, weight)
+    )
+    token_5 = 3 * (1 + 2.0**-10 + 2.0**-9 + 2.0**-18)
+    assert float(got32[5, 0]) == token_5
+    assert float(jnp.asarray(token_5, jnp.bfloat16)) != token_5
+
+
+def test_the_sum_has_a_name_of_its_own_on_the_op_line():
+    """The trace's breakdown and ``op_scopes``' table show the kernel by
+    this name; ``perf/expert_rooflines.py`` reads the grouped matmuls by
+    theirs and must not take it for one of them."""
+    import re
+
+    from perf import expert_rooflines
+
+    assert g.ROWS_SUM == "expert_rows_sum"
+    assert not re.search(expert_rooflines.EXPERT_KERNELS, g.ROWS_SUM)
+    text = str(
+        jax.make_jaxpr(
+            lambda r, t, s: g.sum_by_token(r, t, 16, (s, s), interpret=False)
+        )(
+            jnp.zeros((32, 128), jnp.bfloat16), jnp.zeros((32,), jnp.int32),
+            jnp.zeros((1, 2), jnp.int32),
+        )
+    )
+    assert g.ROWS_SUM in text and "scatter" not in text

@@ -472,9 +472,102 @@ def test_kernels_of_the_ladders_backward_keep_their_op_names():
     jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(x, weights, stacks)
     scopes = list(kernels(jaxpr.jaxpr))
     # three rungs, each two forward kernels and, in the backward, those two
-    # again, two input gradients and two weight gradients
+    # again, two input gradients and two weight gradients; the two rungs
+    # below the full one add their rows into the tokens by a kernel: the
+    # combine, the combine again in the backward's forward, and the
+    # dispatch's transpose
     assert len(gmm_ops.ladder(64 * 6, 8, 128, TILE)) == 3
-    assert len(scopes) == 3 * (2 + 6)
-    names = {gmm_ops.GMM_FWD, gmm_ops.GMM_DX, gmm_ops.GMM_DW}
+    assert len(scopes) == 3 * (2 + 6) + 2 * 3
+    names = {gmm_ops.GMM_FWD, gmm_ops.GMM_DX, gmm_ops.GMM_DW, gmm_ops.ROWS_SUM}
     assert all(s.rsplit("/", 1)[-1] in names for s in scopes), scopes
-    assert sum("jvp(rung)" in s for s in scopes) == 3 * 4
+    assert sum(s.endswith(gmm_ops.ROWS_SUM) for s in scopes) == 2 * 3
+    assert sum("jvp(rung)" in s for s in scopes) == 3 * 4 + 2
+    # and no scatter of activations is left on any rung: what is scattered
+    # is a scalar an entry (the sort's counts, the full rung's layout, the
+    # weights' gradient put at its pair)
+    def scatters(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name.startswith("scatter"):
+                yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from scatters(sub)
+
+    assert all(eqn.outvars[0].aval.ndim == 1 for eqn in scatters(jaxpr.jaxpr))
+
+
+BY_ROWS = {
+    # tokens, routed experts, slots, pairs a held expert gets, tile rows
+    "8_of_128": (64, 128, 6, [5, 0, 9, 1, 3, 8, 2, 4], 8),
+    "a_token_on_every_held_expert": (48, 16, 4, [48, 48, 48, 48], 16),
+    "tokens_that_end_mid_tile": (200, 8, 2, [150, 77], 8),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"])
+@pytest.mark.parametrize("case", BY_ROWS.values(), ids=BY_ROWS.keys())
+def test_by_rows_forms_and_their_gradients_are_the_scatter_adds(case, dtype):
+    """``_combine_by_rows`` and ``_dispatch_by_rows`` on a low rung's layout
+    against the plain forms they are written from: the combine as a float32
+    scatter-add of weighted rows and its two gradients by autodiff, the
+    dispatch's transpose as the scatter-add of the rows' cotangents."""
+    tokens, routed, slots, sizes, tile = case
+    held = len(sizes)
+    top = routing(tokens, slots, routed, sizes, seed=3)
+    group_ids = jnp.where(top < held, top, held).reshape(-1)
+    # a rung that holds them, with two tiles of no group after the last
+    rows = gmm_ops.num_rows(sum(sizes), held, tile) + 2 * tile
+    order = gmm_ops.group_order(group_ids, held)
+    layout = gmm_ops.group_layout(group_ids, held, tile, rows, order, True)
+    spans = gmm_ops.token_spans(group_ids, order.sizes, tokens, tile)
+    assert int(jnp.sum(layout.row_pair < tokens * slots)) == sum(sizes)
+    rng = np.random.RandomState(4)
+    x = jnp.asarray(rng.randn(tokens, 32), dtype)
+    buffer = jnp.asarray(rng.randn(rows, 32), dtype)
+    weights = jnp.where(
+        top < held, jnp.asarray(rng.rand(tokens, slots) + 2.0**-20, jnp.float32), 0.0
+    )
+    d_y = jnp.asarray(rng.randn(tokens, 32), dtype)
+    d_buffer = jnp.asarray(rng.randn(rows, 32), dtype)
+    row_weight, row_token = moe._rows_of(weights, layout.row_pair)
+
+    def scatter_add(values):
+        return jnp.zeros((tokens, 32), jnp.float32).at[row_token].add(
+            values.astype(jnp.float32), mode="drop"
+        )
+
+    def plain_combine(buffer, weights):
+        row_weight, _ = moe._rows_of(weights, layout.row_pair)
+        return scatter_add(
+            buffer.astype(jnp.float32) * row_weight[:, None]
+        ).astype(dtype)
+
+    def close(got, want):
+        got, want = (np.asarray(v, np.float32) for v in (got, want))
+        if dtype == jnp.bfloat16 and got.ndim == 2:
+            # one rounding on either side of sums whose terms' order differs
+            np.testing.assert_allclose(got, want, rtol=2.0**-7, atol=0)
+            assert np.mean(got == want) > 0.99
+        else:
+            np.testing.assert_allclose(
+                got, want, rtol=0, atol=4e-7 * np.abs(want).max()
+            )
+
+    y, vjp = jax.vjp(
+        lambda b, w: moe._combine_by_rows(b, w, layout.row_pair, spans, None),
+        buffer, weights,
+    )
+    want_y, want_vjp = jax.vjp(plain_combine, buffer, weights)
+    close(y, want_y)
+    for got, want in zip(vjp(d_y), want_vjp(d_y)):
+        assert got.dtype == want.dtype
+        close(got, want)
+
+    dispatched, vjp = jax.vjp(
+        lambda x: moe._dispatch_by_rows(x, row_token, spans, tokens, None), x
+    )
+    np.testing.assert_array_equal(
+        dispatched, x[jnp.minimum(row_token, tokens - 1)]
+    )
+    (d_x,) = vjp(d_buffer)
+    assert d_x.dtype == dtype
+    close(d_x, scatter_add(d_buffer).astype(dtype))

@@ -657,6 +657,52 @@ def test_the_short_convolutions_pass_is_a_kernel_each_way(one_chip_mesh):
         assert trace_reduce.matching_seconds(reduced, pattern) == 0, pattern
 
 
+def test_a_low_rungs_rows_are_summed_by_a_kernel_of_its_own_name(one_chip_mesh):
+    """An expert layer that holds 8 of 64 experts (so its buffer is on the
+    ladder: a low rung and the full one) compiled for the described chip:
+    Mosaic takes ``expert_rows_sum`` with its row fetches and scalar tables,
+    the custom-calls keep the name and sit under ``moe/rung`` as kind
+    ``kernel`` (outside ``experts_other_share``, which counts kind
+    ``other``): the combine in the forward, the recomputed and the
+    backward's own forward, the dispatch's transpose in the backward.  To
+    ``perf/``'s readers it is no grouped matmul, and the step holds no
+    scatter of activations but the embedding's gradient."""
+    from perf import expert_rooflines, layer_readers, trace_reduce
+
+    model, loss, tx, _, _, _ = _lm_family(
+        "tiny_lfm2", num_layers=2, layer_pattern="-E", num_experts=64,
+    )
+    assert len(gmm_ops.ladder(1024 * 2, 8, 64, gmm_ops.TILE_ROWS)) == 2
+    compiled = _lowered_for_the_chip(one_chip_mesh, model, loss, tx).compile()
+    scopes = op_scopes.scope_map(compiled)
+    found = {}
+    for name, (part, phase, kind, _) in scopes.items():
+        if name.split(".")[0] == gmm_ops.ROWS_SUM:
+            assert kind == "kernel", (name, kind)
+            found.setdefault(part, set()).add(phase)
+    assert set(found) == {
+        f"block/moe/rung/{region}/{gmm_ops.ROWS_SUM}"
+        for region in ("combine", "dispatch")
+    }, found
+    assert found[f"block/moe/rung/combine/{gmm_ops.ROWS_SUM}"] >= {"forward"}
+    assert found[f"block/moe/rung/dispatch/{gmm_ops.ROWS_SUM}"] == {"backward"}
+    ours = {
+        name: 1.0 for name in scopes if name.split(".")[0] == gmm_ops.ROWS_SUM
+    }
+    reduced = {"op_self_s": ours, "details": {}}
+    for pattern in [layer_readers.FLASH_KERNELS] + [
+        rf"^{kernel}\b" for kernel in (
+            expert_rooflines.EXPERT_KERNELS, "expert_gmm_", "flash_", "ssd_",
+        )
+    ]:
+        assert trace_reduce.matching_seconds(reduced, pattern) == 0, pattern
+    wide = [
+        line for line in compiled.as_text().split("\n")
+        if re.search(r"= \(?[a-z0-9]+\[\d+,\d+[^ ]* scatter\(", line)
+    ]
+    assert len(wide) == 1 and "[256,128]" in wide[0], wide
+
+
 def test_sparse_attentions_kernels_are_under_their_own_parts(one_chip_mesh):
     """A sparse-attention expert layer at the published head widths (heads
     of 128 grouped 4 : 1, an indexer of 4 x 64, 1,024 tokens, top-256),
