@@ -32,7 +32,11 @@ from elasticdl_tpu.layers.attention import (
     sinusoidal_positions,
 )
 from elasticdl_tpu.layers.recompute import remat_with_findings
-from elasticdl_tpu.telemetry.router_load import LOSS_PARTS
+from elasticdl_tpu.telemetry.router_load import (
+    LOSS_OBSERVED,
+    LOSS_PARTS,
+    observed_names,
+)
 from elasticdl_tpu.trainer.losses import (
     softmax_cross_entropy_with_integer_labels,
 )
@@ -40,6 +44,8 @@ from elasticdl_tpu.trainer.metrics import Accuracy
 from elasticdl_tpu.trainer.state import Modes
 
 VOCAB = 256
+# a looped model's loss by its parts (``looped_rows``)
+LOOPED_PARTS = ("expected_ce", "exit_entropy")
 
 
 # The parts' fields by the model's names, a group of
@@ -204,6 +210,17 @@ class TransformerLM(nn.Module):
     # the head is the token embedding: logits = norm(x) @ tok_embed^T, one
     # parameter whose gradient is the sum of its two uses (no ``lm_head``)
     tie_embedding: bool = False
+    # > 1: a looped model (docs/designs/looped_layers.md;
+    # perf/configs/ouro_2p6b.json): the stack runs this many times on ONE set
+    # of block parameters, each pass reading the final norm of the pass
+    # before; the norm, an exit gate (one Dense(1) for all passes) and the
+    # head follow every pass.  A training forward returns every pass's exit
+    # by what makes it (``looped_outputs``) and ``loss`` is the exit
+    # distribution's expected cross-entropy less ``exit_entropy_weight``
+    # times the distribution's entropy; any other forward returns the last
+    # pass's logits
+    loop_steps: int = 1
+    exit_entropy_weight: float = 0.1
 
     @nn.compact
     def __call__(self, features, training: bool = False):
@@ -310,10 +327,31 @@ class TransformerLM(nn.Module):
         def norm(name=None):
             return make_norm(self.norm, self.norm_eps, self.dtype, name)
 
-        for layer in range(self.num_layers):
-            x = block(pattern[layer] if pattern else "", f"block_{layer}")(
-                x, training, decode_pos, components
+        def stack(x):
+            for layer in range(self.num_layers):
+                x = block(pattern[layer] if pattern else "", f"block_{layer}")(
+                    x, training, decode_pos, components
+                )
+            return x
+
+        looped = self.loop_steps > 1
+        if self.loop_steps < 1:
+            raise ValueError(f"loop_steps {self.loop_steps} is not 1 or more")
+        if looped and self.decode:
+            raise NotImplementedError(
+                "decoding through a loop of layers (a KV cache a layer and "
+                "pass) is not built"
             )
+        kept_apart = [
+            name for name in ("mtp_depth", "index_topk", "num_experts", "sliding_window")
+            if getattr(self, name)
+        ]
+        if looped and kept_apart:
+            # (each keeps a collection a layer, which a loop would have to
+            # keep a layer and pass)
+            raise ValueError(f"{kept_apart} in a loop of layers is not built")
+        if not looped:
+            x = stack(x)
         if self.index_topk and (training or self.is_initializing()):
             # asks trainer/step.py for the loss by its parts: the sown
             # losses join them under their own names
@@ -342,6 +380,67 @@ class TransformerLM(nn.Module):
                 self.vocab_size, dtype=self.dtype, use_bias=self.use_bias,
                 name="lm_head",
             )
+        if looped:
+            # one pass: the stack, then the exit's norm (whose output the
+            # next pass reads) and gate.  The passes are ONE scan body, its
+            # modules made inside the scan's scope, once (``mdl`` is this
+            # module there), the parameters broadcast to every pass: a
+            # shared weight's gradient is summed in the backward scan's
+            # carry.  (Unrolled, 32 applications in the program, the same
+            # step compiled in 173 s against 97 and ran 2.2% slower:
+            # docs/designs/looped_layers.md)
+            def one_pass(mdl, h, _):
+                h = stack(h)
+                with jax.named_scope("exit"):
+                    with jax.named_scope("norm"):
+                        h = norm()(h)
+                    # over sqrt(width), as attention's scores are over
+                    # sqrt(head_dim): a product with a normed state moves by
+                    # width x rate a step of Adam, which at the logit's own
+                    # scale took the gate to "leave after the first pass"
+                    # inside twenty steps at 2,048 wide (PERF.md, PR 53)
+                    gate = nn.Dense(
+                        1, dtype=jnp.float32, name="exit_gate",
+                        kernel_init=nn.initializers.zeros,
+                    )(h.astype(jnp.float32))[..., 0] * self.embed_dim**-0.5
+                return h, (h, gate)
+
+            # (the loop's own ops, the carry's copies and the stacked
+            # exits, get the loop's name: telemetry/op_scopes.py::LOOP)
+            with jax.named_scope("loop"):
+                x, (states, gates) = nn.scan(
+                    one_pass,
+                    variable_broadcast="params",
+                    split_rngs={"params": False, "dropout": True},
+                    length=self.loop_steps,
+                )(self, x, None)
+            if training or self.is_initializing():
+                # asks trainer/step.py to leave the loss's two parts, and
+                # what the loss saw of every pass, in the state
+                for collection, names in (
+                    (LOSS_PARTS, LOOPED_PARTS),
+                    (LOSS_OBSERVED, observed_names(self.loop_steps)),
+                ):
+                    for name in names:
+                        self.variable(
+                            collection, name, lambda: jnp.zeros((), jnp.float32)
+                        )
+            if self.is_initializing() or not training:
+                # (the last pass's logits; ``init`` makes the head's
+                # parameters here)
+                return lm_head(x)
+            # every pass's exit by what makes it: four passes' logits side by
+            # side are 3.2 GB at 8,192 x 49,152, so the head is the loss's to
+            # apply, a pass at a time (``looped_rows``)
+            return {
+                "exit_states": states,  # (passes, batch, seq, embed)
+                "exit_gates": gates,  # (passes, batch, seq): the gate's logit
+                "head": (
+                    {"kernel": tok_embed.embedding.T} if self.tie_embedding
+                    else self.variables["params"]["lm_head"]
+                ),
+                "exit_entropy_weight": jnp.float32(self.exit_entropy_weight),
+            }
         logits = lm_head(norm()(x))
         if self.decode or not (
             self.mtp_depth and (training or self.is_initializing())
@@ -400,14 +499,72 @@ def sharding_rules(mesh):
     return tuple(rules)
 
 
+def exit_distribution(gates):
+    """``log p_t`` over the passes (the leading axis) from the exit gate's
+    logits: ``p_t = g_t prod_{j<t} (1 - g_j)``, the last pass taking what is
+    left, ``prod_{j<P} (1 - g_j)`` (its own gate is not read); ``g =
+    sigmoid(logit)``.  Sums to 1 a token; (1/2, 1/4, 1/8, 1/8) at 0."""
+    log_p, stayed = [], jnp.zeros_like(gates[0])
+    for gate in gates[:-1]:
+        log_p.append(stayed + jax.nn.log_sigmoid(gate))
+        stayed = stayed + jax.nn.log_sigmoid(-gate)
+    return jnp.stack(log_p + [stayed])
+
+
+@jax.checkpoint
+def _exit_cross_entropy(state, head, labels):
+    """One pass's logits and their per-token cross-entropy; recomputed in the
+    backward pass, so that no pass's logits outlive it."""
+    with jax.named_scope("lm_head"):
+        logits = state @ head["kernel"].astype(state.dtype)
+        if "bias" in head:
+            logits = logits + head["bias"].astype(state.dtype)
+    return softmax_cross_entropy_with_integer_labels(logits, labels)
+
+
+def looped_rows(labels, outputs) -> dict | None:
+    """A looped model's loss a row, by its parts (arXiv:2510.25741, stage I:
+    gate and model trained together): ``expected_ce``, the mean over tokens
+    of ``sum_t p_t CE_t``, and ``exit_entropy``, ``-beta`` times the mean of
+    ``H(p) = -sum_t p_t log p_t``; under ``LOSS_OBSERVED`` what is no term
+    of the loss, each pass's own mean cross-entropy ``ce_t`` and mean exit
+    probability ``exit_t``.  The head is applied here, inside a loop over
+    the passes whose body holds one pass's logits; None for outputs of any
+    other kind."""
+    if not (isinstance(outputs, dict) and "exit_states" in outputs):
+        return None
+    head = outputs["head"]
+    cross_entropy = jax.lax.map(
+        lambda state: _exit_cross_entropy(state, head, labels),
+        outputs["exit_states"],
+    )
+    log_p = exit_distribution(outputs["exit_gates"])
+    p = jnp.exp(log_p)
+    entropy = -jnp.sum(p * log_p, axis=0)
+    seen = {"ce": cross_entropy, "exit": p}
+    observed = {
+        f"{kind}_{t + 1}": seen[kind][t].mean(axis=-1)
+        for t in range(p.shape[0]) for kind in seen
+    }
+    return {
+        "expected_ce": jnp.sum(p * cross_entropy, axis=0).mean(axis=-1),
+        "exit_entropy": -outputs["exit_entropy_weight"] * entropy.mean(axis=-1),
+        LOSS_OBSERVED: observed,
+    }
+
+
 def loss_parts(labels, outputs) -> dict:
     """The loss by its named parts; ``loss`` is their sum.  ``main``: the
     next token's mean cross-entropy.  ``mtp`` (a training forward with
     ``mtp_depth``): ``mtp_weight`` times the mean over the modules of
     ``L_k``, the cross-entropy of token ``i + k + 1`` summed over the
     ``T - k`` positions of a row that have one in ``labels`` and divided
-    by ``T`` (arXiv:2412.19437, eqs. 24, 25).  Each a mean of per-row
-    terms, so ``trainer/step.py::weighted_mean_loss`` masks padded rows."""
+    by ``T`` (arXiv:2412.19437, eqs. 24, 25).  A looped model's training
+    forward: ``looped_rows``' parts.  Each a mean of per-row terms, so
+    ``trainer/step.py::weighted_mean_loss`` masks padded rows."""
+    rows = looped_rows(labels, outputs)
+    if rows is not None:
+        return jax.tree_util.tree_map(lambda row: row.mean(), rows)
     if not isinstance(outputs, dict):
         return {
             "main": softmax_cross_entropy_with_integer_labels(
@@ -431,14 +588,28 @@ def loss_parts(labels, outputs) -> dict:
     }
 
 
-def loss(labels, outputs):
+def _terms(parts: dict):
     # (not ``sum``: its ``0 +`` would be one more op in every model's step)
     return functools.reduce(
-        operator.add, loss_parts(labels, outputs).values()
+        operator.add,
+        (value for name, value in parts.items() if name != LOSS_OBSERVED),
     )
 
 
+def loss(labels, outputs):
+    return _terms(loss_parts(labels, outputs))
+
+
+def _loss_rows(labels, outputs):
+    rows = looped_rows(labels, outputs)
+    return None if rows is None else _terms(rows)
+
+
 loss.parts = loss_parts
+# (the outputs of a looped model's training forward hold the head's weight,
+# which is no row's: the per-row terms weighted_mean_loss asks for)
+loss.rows = _loss_rows
+loss_parts.rows = looped_rows
 
 
 def optimizer(lr=3e-3):

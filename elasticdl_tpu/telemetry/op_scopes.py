@@ -64,6 +64,11 @@ from typing import NamedTuple
 # named where it is used)
 ROOT = "model"
 OP_SCOPES_FILE = "op_scopes.json"
+# the scope a model puts around a loop that runs its stack several times
+# (models/long_seq_transformer.py): the loop's own ops (the carry's copies,
+# the stacked exits) are this part's; an op of a part inside the loop is that
+# part's, as if the loop were not there
+LOOP = "loop"
 UNATTRIBUTED = "unattributed"
 
 # perf/trace_reduce.py::COLLECTIVE, copied: the program imports nothing of
@@ -159,6 +164,8 @@ def canonical(op_name: str) -> tuple[str | None, str]:
             # (a scope entered again inside itself counts once)
             if piece and piece != (kept[-1] if kept else None):
                 kept.append(piece)
+    if len(kept) > 1 and kept[0] == LOOP:
+        del kept[0]
     if "optimizer" in kept:
         phase = "optimizer"
     elif rematted:

@@ -26,6 +26,12 @@ ROUTER_STATS = "router_stats"
 # ``trainer/step.py`` leave its loss there by the parts its ``loss.parts``
 # names: a multi-token-prediction model's ``main`` and ``mtp``
 LOSS_PARTS = "loss_parts"
+# what a loss function saw on its way that is no term of the loss, a scalar a
+# name: the key ``loss.parts`` returns it under and the collection a model
+# declares to have ``trainer/step.py`` leave it there.  A looped model's
+# (``models/long_seq_transformer.py::looped_rows``): each pass's own mean
+# cross-entropy ``ce_t`` and mean exit probability ``exit_t``, ``t`` from 1
+LOSS_OBSERVED = "loss_observed"
 # the collection a sparse-attention layer (``layers/attention.py``) sows
 # what its selection did into, a scalar a name and layer: the selected keys
 # a query (``kept_keys``, a mean over batch and queries), the queries at
@@ -64,6 +70,30 @@ def read_loss_parts(model_state=None) -> dict | None:
     if not parts:
         return None
     return {k: float(v) for k, v in jax.device_get(parts).items()}
+
+
+def observed_names(passes: int) -> list[str]:
+    return [
+        f"{kind}_{t}" for t in range(1, passes + 1) for kind in ("ce", "exit")
+    ]
+
+
+def read_exits(model_state=None) -> dict | None:
+    """The newest train step's passes of a looped model, one host readback:
+    ``{"cross_entropy": [a pass ...], "exit_distribution": [...],
+    "exit_step_mean": sum_t t * p_t}``, means over the step's tokens.  None
+    for a model that is not looped."""
+    observed = (_state(model_state) or {}).get(LOSS_OBSERVED)
+    if not observed:
+        return None
+    observed = {k: float(v) for k, v in jax.device_get(observed).items()}
+    passes = range(1, len(observed) // 2 + 1)
+    exits = [observed[f"exit_{t}"] for t in passes]
+    return {
+        "cross_entropy": [observed[f"ce_{t}"] for t in passes],
+        "exit_distribution": exits,
+        "exit_step_mean": sum(t * p for t, p in zip(passes, exits)),
+    }
 
 
 def read_selection(model_state=None) -> dict | None:

@@ -20,7 +20,7 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 
-from elasticdl_tpu.telemetry.router_load import LOSS_PARTS
+from elasticdl_tpu.telemetry.router_load import LOSS_OBSERVED, LOSS_PARTS
 from elasticdl_tpu.trainer.state import TrainState
 
 
@@ -102,8 +102,15 @@ def weighted_mean_loss(loss_fn, labels, outputs, weights):
         # dispatch always carries >= 1 real row
         return jnp.sum(w * per_row) / jnp.maximum(jnp.sum(w), 1.0)
 
+    # a ``loss_fn`` whose model's outputs hold something that is no row's (a
+    # looped model's head, applied inside the loss a pass at a time) forms
+    # its per-row terms itself: ``loss_fn.rows``, None for any other outputs
+    rows = getattr(loss_fn, "rows", None)
+    per_row = rows(labels, outputs) if rows is not None else None
+    if per_row is None:
+        per_row = jax.vmap(one_row)(labels, outputs)
     # (a ``loss_fn`` that returns its loss by named parts gets each weighted)
-    return jax.tree_util.tree_map(mean, jax.vmap(one_row)(labels, outputs))
+    return jax.tree_util.tree_map(mean, per_row)
 
 
 _DONATION_WARNING_PATTERN = "Some donated buffers were not usable"
@@ -207,6 +214,13 @@ def build_train_step(
                 new_model_state.get("losses", {})
             )
             if by_parts:
+                # (what the loss function saw and is no term of the loss)
+                observed = loss.pop(LOSS_OBSERVED, None)
+                if observed is not None:
+                    new_model_state = {
+                        **new_model_state,
+                        LOSS_OBSERVED: jax.lax.stop_gradient(observed),
+                    }
                 for path, leaf in sown:
                     name = path[-1].key
                     loss[name] = loss.get(name, 0.0) + jnp.sum(leaf)

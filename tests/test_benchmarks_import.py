@@ -24,6 +24,7 @@ def test_the_kept_drivers():
         "attention_sweep.py",
         "dispatch_overhead_bench.py",
         "expert_rows_sweep.py",
+        "ouro_loop_control.py",
         "preemption_accuracy_bench.py",
         "reform_bench.py",
         "rope_sweep.py",

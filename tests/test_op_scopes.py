@@ -343,6 +343,7 @@ FAMILIES = {
     "sparse_attention": lambda: _lm_family("tiny_keye"),
     "window_and_full_attention": lambda: _lm_family("tiny_trinity"),
     "short_conv_attention_experts_tied": lambda: _lm_family("tiny_lfm2"),
+    "looped_stack": lambda: _lm_family("tiny_ouro"),
     "resnet_first_stage": lambda: (
         FirstStage(), _class_loss, optax.sgd(0.1),
         {"image": np.zeros((2, 32, 32, 3), np.float32)},
@@ -449,6 +450,23 @@ def test_every_region_of_the_step_has_a_name(built):
         assert {
             phase for part, phase, _, _ in scopes.values() if part == "lm_head"
         } >= {"forward", "backward"}
+    if family == "looped_stack":
+        # a block's ops keep the block's names inside the loop; the loop's
+        # own (the carry, the stacked exits) are the loop's; a pass's exit
+        # and the head, which the loss applies, have theirs
+        assert op_scopes.LOOP in _source_scopes()
+        assert {
+            op_scopes.LOOP, "block/attn/query", "block/norm_out/RMSNorm",
+            "exit/norm/RMSNorm", "exit/exit_gate", "loss/lm_head",
+        } <= parts
+        assert not [p for p in parts if p.startswith(op_scopes.LOOP + "/")]
+        for name in ("exit/exit_gate", "loss/lm_head", "block/mlp/mlp_up"):
+            assert {
+                phase for part, phase, _, _ in scopes.values() if part == name
+            } >= {"forward", "backward"}, name
+        # (the head is a module of the tree; a training step's product is
+        # the loss's, which applies it a pass at a time)
+        assert "lm_head" in state.params
     if family == "resnet_first_stage":
         assert {"conv_block/conv_a", "identity_block/bn_c", "fc"} <= parts
 
