@@ -12,11 +12,13 @@ stack runs it (HF ``modeling_nemotron_h.py``, ``NemotronHMamba2Mixer``)::
     out = W_out y
 
 ``d_in = H * P`` is given by the heads, not by an expansion factor.  No bias
-but the convolution's.  The recurrence is ``ops/ssd.py``'s chunked scan; the
-convolution with its SiLU and the gated norm are one pass each of
-``ops/mamba_passes.py``'s kernels where those tile the shape (whole lane
-tiles of channels a group, rows that 16 divides), else the ``jax.numpy`` forms
-below, which the kernels are tested against.  ``dt``, ``A``, the taps' sums
+but the convolution's.  The recurrence is ``ops/ssd.py``'s chunked scan,
+which reads ``x``, ``B`` and ``C`` out of the convolved ``xBC`` where it
+stands and writes ``y`` as ``(batch, T, d_in)``: the split above is the
+kernels' index maps, not a copy.  The convolution with its SiLU and the gated
+norm are one pass each of ``ops/mamba_passes.py``'s kernels where those tile
+the shape (whole lane tiles of channels a group, rows that 16 divides), else
+the ``jax.numpy`` forms below, which the kernels are tested against.  ``dt``, ``A``, the taps' sums
 and the norm's statistics are float32 whatever ``dtype`` says.
 
 No reference counterpart; listed in DEVIATIONS.md additions.
@@ -162,27 +164,21 @@ class Mamba2Mixer(nn.Module):
                 ),
                 self.param("conv_bias", nn.initializers.zeros, (conv_width,)),
             )
-        with jax.named_scope("fold"):
-            x, b, c = jnp.split(xbc, [inner, inner + groups * states], axis=-1)
         dt_bias = self.param(
             "dt_bias",
             _dt_bias_init(self.dt_min, self.dt_max, self.dt_floor), (heads,),
         )
         a_log = self.param("A_log", _a_log_init, (heads,))
         d = self.param("D", nn.initializers.ones, (heads,))
-        batch, steps = u.shape[:2]
         with jax.named_scope("ssd_scan"):
             y = ssd_ops.ssd_scan(
-                x.reshape(batch, steps, heads, self.head_dim),
-                nn.softplus(dt.astype(jnp.float32) + dt_bias),
-                -jnp.exp(a_log.astype(jnp.float32)),
-                b.reshape(batch, steps, groups, states),
-                c.reshape(batch, steps, groups, states),
-                d, chunk=self.chunk,
+                xbc, nn.softplus(dt.astype(jnp.float32) + dt_bias),
+                -jnp.exp(a_log.astype(jnp.float32)), d,
+                groups=groups, states=states, chunk=self.chunk,
             )
         with jax.named_scope("gate_norm"):
             y = gate_norm(
-                y.reshape(batch, steps, inner), z,
+                y, z,
                 self.param("norm_scale", nn.initializers.ones, (inner,)),
                 groups, self.norm_eps,
             )

@@ -24,6 +24,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from elasticdl_tpu.ops import on_mesh
+from elasticdl_tpu.ops.lane_heads import by_head, only_head
 # the registry's names, for ``perf/`` and whoever has always found them here
 from elasticdl_tpu.ops.on_mesh import (  # noqa: F401
     attention_mesh_scope,
@@ -317,37 +318,10 @@ def _row_to_lanes(row):
     return jnp.broadcast_to(row, (_LANES, row.shape[1])).T
 
 
-def _lane_head(shape, heads):
-    """Which of the ``heads`` a block's lanes hold side by side each lane
-    of an array of ``shape`` belongs to."""
-    lane = jax.lax.broadcasted_iota(jnp.int32, shape, len(shape) - 1)
-    return lane // (shape[-1] // heads)
-
-
-def _only_head(x, h, heads):
-    """``x`` with every lane that is not head ``h``'s zeroed: a product
-    contracted over the block's whole width is then that head's alone (the
-    zeros add exact zeros, and a 64-deep contraction costs the matrix unit
-    the same pass as a 128-deep one)."""
-    if heads == 1:
-        return x
-    return jnp.where(_lane_head(x.shape, heads) == h, x, jnp.zeros_like(x))
-
-
-def _by_head(parts):
-    """One array whose lanes of head ``h`` are ``parts[h]``'s: selected,
-    not scaled, so what a head's product left in the other heads' lanes
-    never reaches an accumulator."""
-    out = parts[-1]
-    for h in range(len(parts) - 2, -1, -1):
-        out = jnp.where(_lane_head(out.shape, len(parts)) == h, parts[h], out)
-    return out
-
-
 def _columns_by_head(cols, width):
     """Lane-replicated ``(rows, _LANES)`` columns, one a head, as
     ``(rows, width)`` with each head's column across that head's lanes."""
-    return _by_head([_lanes_to(col, width) for col in cols])
+    return by_head([_lanes_to(col, width) for col in cols])
 
 
 def _loop(start, stop, body):
@@ -463,7 +437,7 @@ def _flash_kernel(
 
     def _chunk():
         q = q_ref[0]  # (block_q, heads * D)
-        q_of = [_only_head(q, h, heads) for h in range(heads)]
+        q_of = [only_head(q, h, heads) for h in range(heads)]
 
         def body(jj, crossed):
             start = pl.multiple_of(jj * block_k, block_k)
@@ -496,7 +470,7 @@ def _flash_kernel(
                     pvs.append(_mxu(p, vb))
                 acc_scr[rows] = acc_scr[rows] * _columns_by_head(
                     alphas, d
-                ) + _by_head(pvs)
+                ) + by_head(pvs)
 
         _loop(behind, edge, functools.partial(body, crossed=on_edge))
         _loop(edge, full, functools.partial(body, crossed=False))
@@ -575,7 +549,7 @@ def _flash_dq_kernel(
             for h in range(heads):
                 delta_scr[h] = jnp.broadcast_to(
                     jnp.sum(
-                        _only_head(do_o, h, heads), axis=1, keepdims=True
+                        only_head(do_o, h, heads), axis=1, keepdims=True
                     ),
                     delta_scr.shape[1:],
                 )
@@ -596,8 +570,8 @@ def _flash_dq_kernel(
     def _chunk():
         q = q_ref[0]
         do = do_ref[0]  # (block_q, heads * D)
-        q_of = [_only_head(q, h, heads) for h in range(heads)]
-        do_of = [_only_head(do, h, heads) for h in range(heads)]
+        q_of = [only_head(q, h, heads) for h in range(heads)]
+        do_of = [only_head(do, h, heads) for h in range(heads)]
         # the saved rows, turned once a chunk into lane-replicated columns
         lse = [_row_to_lanes(lse_ref[h]) for h in range(heads)]
         delta = [
@@ -626,7 +600,7 @@ def _flash_dq_kernel(
                     dp = _scores(do_of[h][rows], vb)
                     ds = p * (dp - _lanes_to(delta[h][rows], k1 - k0))
                     dqs.append(_mxu(ds, kb))
-                acc_scr[rows] = acc_scr[rows] + _by_head(dqs)
+                acc_scr[rows] = acc_scr[rows] + by_head(dqs)
 
         _loop(behind, edge, functools.partial(body, crossed=on_edge))
         _loop(edge, full, functools.partial(body, crossed=False))
@@ -727,8 +701,8 @@ def _flash_dkv_kernel(
     def _chunk():
         k = k_ref[0]  # (block_k, heads * D)
         v = v_ref[0]
-        k_of = [_only_head(k, h, heads) for h in range(heads)]
-        v_of = [_only_head(v, h, heads) for h in range(heads)]
+        k_of = [only_head(k, h, heads) for h in range(heads)]
+        v_of = [only_head(v, h, heads) for h in range(heads)]
 
         def body(ii, crossed):
             start = pl.multiple_of(ii * block_q, block_q)
@@ -756,8 +730,8 @@ def _flash_dkv_kernel(
                     dpt = _scores(v_of[h][cols], doi)
                     dst = pt * (dpt - delta)
                     dks.append(_mxu(dst, qi))
-                dv_scr[cols] = dv_scr[cols] + _by_head(dvs)
-                dk_scr[cols] = dk_scr[cols] + _by_head(dks)
+                dv_scr[cols] = dv_scr[cols] + by_head(dvs)
+                dk_scr[cols] = dk_scr[cols] + by_head(dks)
 
         _loop(first, full_from, functools.partial(body, crossed=on_diagonal))
         _loop(full_from, edge_from, functools.partial(body, crossed=False))

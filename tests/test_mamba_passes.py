@@ -221,17 +221,18 @@ def test_a_shape_the_kernels_refuse_takes_the_plain_form(steps, inner, groups):
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
 def test_the_mixer_is_unchanged_end_to_end(dtype, monkeypatch):
     """``Mamba2Mixer`` at a size the kernels tile (4 heads of 64 in 2 groups
-    of 128 lanes, a 512-wide convolution, 2 x 32 steps) against the same
-    mixer on the plain forms: the output and every parameter's gradient."""
+    of 128 lanes and 128 states, a 768-wide convolution, 2 x 32 steps)
+    against the same mixer on the passes' plain forms: the output and every
+    parameter's gradient."""
     layer = mamba.Mamba2Mixer(
-        num_heads=4, head_dim=64, groups=2, state_size=64, chunk=16,
+        num_heads=4, head_dim=64, groups=2, state_size=128, chunk=16,
         dtype=None if dtype == jnp.float32 else dtype,
     )
     rng = np.random.RandomState(4)
     u = jnp.asarray(rng.randn(2, 32, 32), dtype)
     weigh = jnp.asarray(rng.randn(2, 32, 32), jnp.float32)
     params = layer.init(jax.random.PRNGKey(0), u)["params"]
-    params["conv_bias"] = jnp.asarray(rng.randn(512) * 0.1, jnp.float32)
+    params["conv_bias"] = jnp.asarray(rng.randn(768) * 0.1, jnp.float32)
     params["norm_scale"] = jnp.asarray(rng.rand(256) + 0.5, jnp.float32)
 
     def run(params, u):

@@ -65,11 +65,28 @@ def _flash(heads, kv_heads, width):
     return functools.partial(attention, causal=True), (q, k, v), merged_rows
 
 
-def _scan():
-    x, b, c = (_randn(s, 4, 24, n, 16) for s, n in ((0, 4), (1, 2), (2, 2)))
+def _scan(width, states):
+    """4 heads of ``width`` in 2 groups of ``states`` states, the layer's
+    ``xBC``: heads of 64 at 128 states are what the two scan kernels tile
+    (tests/test_ssd.py's ``KERNELS``), heads of 16 take the plain form."""
+    xbc = _randn(0, 4, 24, 4 * width + 2 * 2 * states)
     dt = jnp.log1p(jnp.exp(_randn(3, 4, 24, 4)))
     a = -jnp.exp(_randn(4, 4))
-    return functools.partial(ssd.ssd_scan, chunk=8), (x, dt, a, b, c, _randn(5, 4)), None
+    kernels = ssd.scan_tile(4, width, 2, states) is not None
+    assert kernels == (width == 64)
+
+    def kernels_inside(regions):
+        # the custom_vjp's forward kernel is what the mapped region holds
+        # (its backward appears once differentiated: the comparison below)
+        assert all(
+            ("pallas_call" in str(eqn.params["jaxpr"])) == kernels
+            for eqn in regions
+        )
+
+    return (
+        functools.partial(ssd.ssd_scan, groups=2, states=states, chunk=8),
+        (xbc, dt, a, _randn(5, 4)), kernels_inside,
+    )
 
 
 def _conv_silu():
@@ -130,7 +147,8 @@ CALLERS = {
     "flash_lanes-dp=4": lambda: _flash(4, 4, 64),
     "flash_lanes-dp=2,tp=2": lambda: _flash(4, 4, 64),
     "flash_folded_grouped_heads-dp=2,tp=2": lambda: _flash(4, 2, 128),
-    "scan-dp=4": _scan,
+    "scan_kernels-dp=4": lambda: _scan(64, 128),
+    "scan_plain-dp=4": lambda: _scan(16, 16),
     "conv_silu-dp=4": _conv_silu,
     "short_conv-dp=4": _short_conv,
     "gate_norm-dp=2": _gate_norm,
@@ -201,7 +219,7 @@ def test_a_caller_is_mapped_once_and_computes_what_it_computes_unmapped(caller):
 
 
 def test_one_device_or_no_mesh_calls_the_function_as_it_stands():
-    call, args, _ = _scan()
+    call, args, _ = _scan(64, 128)
     assert on_mesh.resolve() == (on_mesh.default_interpret(), None)
     mesh = MeshConfig.from_string("dp=1").create(devices=jax.devices()[:1])
     with mesh, on_mesh.attention_mesh_scope(mesh):

@@ -427,9 +427,11 @@ def test_every_region_of_the_step_has_a_name(built):
         assert {"block/moe/route/router", "block/moe/shared/shared_up"} <= parts
     if family == "mamba_experts_attention":
         # (the convolution, 128 channels wide here, is ops/mamba_passes.py's
-        # kernel, a part of its own under the scope)
+        # kernel, a part of its own under the scope; the scan at 16-wide
+        # heads is ops/ssd.py's plain form, whose ops are the scope's own:
+        # nothing is transposed to a kernel's layout any more)
         assert {
-            "block/mamba/gate_norm", "block/mamba/ssd_scan/fold",
+            "block/mamba/gate_norm", "block/mamba/ssd_scan",
             "block/mamba/mamba_conv", "block/moe/dispatch", "block/moe/combine",
         } <= parts | {op_scopes.at_depth(part, 3) for part in parts}
     if family == "olmoe":
@@ -563,19 +565,21 @@ def _lowered_for_the_chip(mesh, model, loss, tx, tokens=1024):
 
 
 def test_the_mixers_passes_are_kernels_under_their_own_parts(one_chip_mesh):
-    """A Mamba-2 layer wide enough for ``ops/mamba_passes.py`` (8 heads of
-    64 in 2 groups of 256 lanes, a 768-wide convolution, 64 steps), each
-    layer recomputed: the four custom-calls keep their names, sit under the
-    parts ``gate_norm`` and ``mamba_conv`` as kind ``kernel`` in all three
-    phases, and none reads as a scan, flash or grouped-matmul kernel to
-    ``perf/``'s readers, which match by name."""
+    """A Mamba-2 layer wide enough for ``ops/mamba_passes.py`` and for
+    ``ops/ssd.py``'s kernels (8 heads of 64 in 2 groups of 256 lanes and 128
+    states, a 1,024-wide convolution, 64 steps), each layer recomputed: the
+    six custom-calls keep their names, sit under the parts ``gate_norm``,
+    ``mamba_conv`` and ``ssd_scan`` as kind ``kernel`` in all three phases,
+    the scan kernels read the layer's own layout (nothing under the mixer is
+    a ``fold``), and none of the passes' four reads as a scan, flash or
+    grouped-matmul kernel to ``perf/``'s readers, which match by name."""
     from jax.sharding import NamedSharding, PartitionSpec
 
     from perf import expert_rooflines, layer_readers, ssd_rooflines, trace_reduce
 
     model, loss, tx, features, labels, _ = _lm_family(
         "tiny_nemotron", num_layers=1, layer_pattern="M", mamba_heads=8,
-        mamba_head_dim=64, ssm_state=64, ssd_chunk=16,
+        mamba_head_dim=64, ssm_state=128, ssd_chunk=16,
     )
     variables = model.init(jax.random.PRNGKey(0), features, training=False)
     state = TrainState.create(model.apply, variables["params"], tx, {})
@@ -609,10 +613,27 @@ def test_the_mixers_passes_are_kernels_under_their_own_parts(one_chip_mesh):
     assert found[mamba_passes.MAMBA_CONV_BWD] == {
         ("block/mamba/mamba_conv/mamba_conv_bwd", "backward")
     }
-    assert {ssd_ops.SSD_FWD, ssd_ops.SSD_BWD} <= set(found)
+    assert found[ssd_ops.SSD_FWD] == {
+        ("block/mamba/ssd_scan/ssd_fwd", "forward"),
+        ("block/mamba/ssd_scan/ssd_fwd", "recompute"),
+    }
+    assert found[ssd_ops.SSD_BWD] == {
+        ("block/mamba/ssd_scan/ssd_bwd", "backward")
+    }
     assert {
         op_scopes.at_depth(part, 3) for part, _ in kernels.values()
-    } >= {"block/mamba/gate_norm", "block/mamba/mamba_conv"}
+    } == {
+        "block/mamba/gate_norm", "block/mamba/mamba_conv",
+        "block/mamba/ssd_scan",
+    }
+    # the scan kernels read the layer's (batch, T, channels) layout: no
+    # transposes to theirs and no split of xBC are left under the mixer
+    under_the_mixer = {
+        part for part, _, _, _ in scopes.values()
+        if part is not None and part.startswith("block/mamba")
+    }
+    assert under_the_mixer
+    assert not {p for p in under_the_mixer if "fold" in p.split("/")}
     # as perf/ reads a trace: an op's self time by its name
     ours = {
         name: 1.0 for name in kernels
