@@ -170,14 +170,25 @@ def read_host_total() -> int | None:
     return _host_total_cache[0]
 
 
+# the allocator's figures of one device, as ``Device.memory_stats()`` names
+# them; the benchmark's ``peak_hbm_gb`` is ``peak_bytes_in_use +
+# peak_bytes_reserved`` of the fullest device (live arrays, and what is
+# reserved for the programs' temporaries and code)
+ALLOCATOR_FIGURES = (
+    "bytes_in_use", "peak_bytes_in_use", "bytes_reserved",
+    "peak_bytes_reserved", "largest_free_block_bytes", "bytes_limit",
+)
+
+
 def read_device_memory() -> dict:
-    """Accelerator allocator stats summed over local devices:
-    ``{"bytes_in_use", "peak_bytes_in_use", "bytes_limit"}`` (the limit
-    is 0 where the allocator reports none), or ``{}`` on backends
-    without allocator stats (CPU returns ``None`` from
-    ``memory_stats()``) — the graceful-None contract.  The limit minus
-    in-use is the headroom the device stager's admission control
-    budgets against.
+    """Accelerator allocator stats: ``{"bytes_in_use", "peak_bytes_in_use",
+    "bytes_reserved", "peak_bytes_reserved", "bytes_limit"}`` summed over
+    local devices (the limit is 0 where the allocator reports none) and
+    ``"devices"``, each device's own :data:`ALLOCATOR_FIGURES` under its
+    ``id``; or ``{}`` on backends without allocator stats (CPU returns
+    ``None`` from ``memory_stats()``) — the graceful-None contract.
+    :func:`device_headroom_bytes` is what the device stager's admission
+    control budgets against.
 
     Reads the backend this process ALREADY runs and never starts one:
     ``{}`` before any backend is initialized.  A chip belongs to one
@@ -189,30 +200,54 @@ def read_device_memory() -> dict:
 
     if not xla_bridge.backends_are_initialized():
         return {}
-    devices = jax.local_devices()
-    in_use = peak = limit = 0
-    found = False
-    for device in devices:
+    devices = []
+    for device in jax.local_devices():
         try:
             stats = device.memory_stats()
         except Exception:  # noqa: BLE001 — per-device stats are optional
             stats = None
         if not stats:
             continue
-        found = True
-        in_use += int(stats.get("bytes_in_use", 0) or 0)
-        peak += int(
-            stats.get("peak_bytes_in_use", stats.get("bytes_in_use", 0))
-            or 0
-        )
-        limit += int(stats.get("bytes_limit", 0) or 0)
-    if not found:
+        figures = {
+            name: int(stats.get(name, 0) or 0) for name in ALLOCATOR_FIGURES
+        }
+        for peak in ("peak_bytes_in_use", "peak_bytes_reserved"):
+            # an allocator without a high-water mark: what it holds now
+            figures[peak] = max(figures[peak], figures[peak[len("peak_"):]])
+        devices.append({"id": int(device.id), **figures})
+    if not devices:
         return {}
-    return {
-        "bytes_in_use": in_use,
-        "peak_bytes_in_use": peak,
-        "bytes_limit": limit,
+    summed = {
+        name: sum(d[name] for d in devices)
+        for name in ALLOCATOR_FIGURES
+        if name != "largest_free_block_bytes"
     }
+    return {**summed, "devices": devices}
+
+
+def fullest_device(stats: dict) -> dict | None:
+    """Of ``read_device_memory()``'s devices the one the benchmark's
+    ``peak_hbm_gb`` reads: the largest ``peak_bytes_in_use +
+    peak_bytes_reserved``."""
+    return max(
+        stats.get("devices", ()),
+        key=lambda d: d["peak_bytes_in_use"] + d["peak_bytes_reserved"],
+        default=None,
+    )
+
+
+def device_headroom_bytes() -> int | None:
+    """What the fullest local device has left: its limit less its live
+    arrays and less what is reserved for the programs' temporaries and
+    code (2.7-8 GB of a 16 GB chip under a train step, which
+    ``bytes_in_use`` does not count).  None without allocator stats or a
+    limit."""
+    left = [
+        d["bytes_limit"] - d["bytes_in_use"] - d["bytes_reserved"]
+        for d in read_device_memory().get("devices", ())
+        if d["bytes_limit"] > 0
+    ]
+    return max(0, min(left)) if left else None
 
 
 def host_memory_health() -> dict:
@@ -229,6 +264,121 @@ def host_memory_health() -> dict:
         if available is not None and total
         else None,
     }
+
+
+# ---- the byte side of the train step, read on demand --------------------------
+#
+# What the device holds while the trainer's step runs: the state as the
+# fullest device holds it, XLA's own sizes of each compiled train program
+# with the reading of what is alive at its peak
+# (``telemetry/op_scopes.py::live_bytes``), and the allocator's figures.
+# Read when somebody asks (a benchmark's reader after its window,
+# ``utils/profiling.py`` at a window's close), from the trainer
+# ``op_scopes.watch`` remembers, and never on the train path.
+
+
+def _on_device_bytes(array) -> int:
+    try:
+        return int(array.on_device_size_in_bytes())
+    except Exception:  # noqa: BLE001 — a backend without the figure
+        return int(getattr(array, "nbytes", 0) or 0)
+
+
+def device_bytes(tree) -> dict[int, int]:
+    """``{device id: bytes}`` of a pytree's arrays as each device holds
+    them: its addressable shards at their size on the device, not the
+    global ``nbytes`` (a replicated leaf stands whole on every device)."""
+    import jax
+
+    held: dict[int, int] = {}
+    for leaf in jax.tree_util.tree_leaves(tree):
+        for shard in getattr(leaf, "addressable_shards", ()):
+            at = int(shard.device.id)
+            held[at] = held.get(at, 0) + _on_device_bytes(shard.data)
+    return held
+
+
+def _state_on(device_id: int | None, state) -> dict:
+    """The state's bytes on one device (the one that holds most of it where
+    ``device_id`` is None): ``params``, ``opt_state``, each model buffer's
+    collection, ``step``, and their ``total``."""
+    groups = {
+        "params": state.params,
+        "opt_state": state.opt_state,
+        "step": state.step,
+        **dict(state.model_state),
+    }
+    held = {name: device_bytes(tree) for name, tree in groups.items()}
+    if device_id is None:
+        totals = device_bytes(groups)
+        device_id = max(totals, key=totals.get, default=0)
+    split = {name: by_device.get(device_id, 0) for name, by_device in held.items()}
+    return {"device": device_id, **split, "total": sum(split.values())}
+
+
+def read_step_memory() -> dict | None:
+    """The byte side of the watched trainer's step; None without a trainer
+    or before its first step.
+
+    - ``programs``: per compiled train program ``op_scopes.live_bytes``:
+      ``xla`` (its ``argument``, ``output``, ``alias``, ``temp``,
+      ``generated_code`` and ``peak`` bytes a device) and what is alive at
+      its peak by the model's scopes (``live``: None where the reading is
+      off XLA's figure), the program with the most temporaries first;
+    - ``state``: the train state on the fullest device, split (``params``,
+      ``opt_state``, the model's buffers, ``total``);
+    - ``undonated``: the state's leaves the first program writes no output
+      in place of, ``[path, bytes]``: each stands twice while the step
+      runs (``alias`` short of the state's bytes says the same in sum);
+    - ``other_arrays``: what else is alive on that device now, batches
+      placed and not yet retired and the last metrics;
+    - ``allocator``: that device's :data:`ALLOCATOR_FIGURES` ({} on the
+      CPU)."""
+    import jax
+
+    from elasticdl_tpu.telemetry import op_scopes
+
+    trainer, programs = op_scopes.watched_programs()
+    if trainer is None:
+        return None
+    read = [
+        op_scopes.live_bytes(program) or {"xla": op_scopes.xla_sizes(program)}
+        for program in programs
+    ]
+    read.sort(key=lambda p: -(p["xla"] or {}).get("temp", 0))
+    allocator = fullest_device(read_device_memory())
+    state = _state_on(allocator["id"] if allocator else None, trainer.state)
+    # (a shard's own array is a live array too, of the same buffer: each
+    # buffer once)
+    alive: dict[int, int] = {}
+    for array in jax.live_arrays():
+        for shard in array.addressable_shards:
+            if int(shard.device.id) == state["device"]:
+                try:
+                    buffer = shard.data.unsafe_buffer_pointer()
+                except Exception:  # noqa: BLE001 — a backend without it
+                    buffer = id(shard.data)
+                alive[buffer] = _on_device_bytes(shard.data)
+    return {
+        "programs": read,
+        "state": state,
+        "undonated": read[0].get("undonated", []),
+        "other_arrays": max(0, sum(alive.values()) - state["total"]),
+        "allocator": allocator or {},
+    }
+
+
+def dump_step_memory(path: str) -> bool:
+    """Write :func:`read_step_memory` to ``path``; False with nothing to
+    write."""
+    import json
+
+    reading = read_step_memory()
+    if reading is None:
+        return False
+    with open(path, "w") as f:
+        json.dump(reading, f, separators=(",", ":"))
+    return True
 
 
 # ---- the ledger --------------------------------------------------------------

@@ -209,10 +209,12 @@ def resolve_pipeline_depth(flag=None) -> int:
 
 def staging_budget_bytes() -> int | None:
     """Byte budget for staged-but-untaken device buffers, or None for
-    unbounded: the env override when set, else half the live device
-    headroom (``bytes_limit - bytes_in_use`` from the allocator
-    stats), else None — backends without allocator stats (CPU) stay
-    unbounded and rely on the queue bound alone."""
+    unbounded: the env override when set, else half the fullest device's
+    headroom (its ``bytes_limit`` less ``bytes_in_use`` and less
+    ``bytes_reserved``, where the step's temporaries stand:
+    ``telemetry/memory.py::device_headroom_bytes``), else None —
+    backends without allocator stats (CPU) stay unbounded and rely on
+    the queue bound alone."""
     raw = os.environ.get(STAGING_BUDGET_ENV, "").strip()
     if raw:
         try:
@@ -228,13 +230,10 @@ def staging_budget_bytes() -> int | None:
             )
         else:
             return budget if budget > 0 else None
-    from elasticdl_tpu.telemetry.memory import read_device_memory
+    from elasticdl_tpu.telemetry.memory import device_headroom_bytes
 
-    stats = read_device_memory()
-    limit = int(stats.get("bytes_limit", 0)) if stats else 0
-    if limit <= 0:
-        return None
-    return max(0, limit - int(stats.get("bytes_in_use", 0))) // 2
+    headroom = device_headroom_bytes()
+    return None if headroom is None else headroom // 2
 
 
 def resolve_donate_state(args) -> bool:

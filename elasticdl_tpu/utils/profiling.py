@@ -43,7 +43,12 @@ line's names (``fusion.25``) to the model's scopes (part, phase, kind:
 ``telemetry/op_scopes.py``, read from the compiled programs the trainer
 dispatched, one parse of each program's HLO text at the close and nothing
 before it), and ``python -m elasticdl_tpu.telemetry.op_scopes <dir>``
-prints the window's device time by part x phase x kind.
+prints the window's device time by part x phase x kind.  The byte side is
+one more file of the same close, ``step_memory.json``
+(``telemetry/memory.py::read_step_memory``: the state on the fullest device,
+XLA's sizes of each train program, what is alive at the step's peak by the
+same scopes, the allocator's figures); ``... op_scopes <dir> --memory``
+prints it.
 
 Disabled cost: with no window pending or open, :meth:`on_step` is one
 attribute load and a ``not x`` check (``# elastic-lint: hot-path``).
@@ -59,7 +64,7 @@ import os
 import threading
 import time
 
-from elasticdl_tpu.telemetry import anatomy, op_scopes
+from elasticdl_tpu.telemetry import anatomy, memory, op_scopes
 from elasticdl_tpu.utils.log_utils import default_logger as logger
 
 # subdirectory of the telemetry dir an on-demand capture lands in when
@@ -288,9 +293,9 @@ class StepProfiler:
 
     # lock-holding: _lock
     def _write_beside_trace(self):
-        """The timeline's spans of the window and the train programs' op
-        scopes, beside the newest ``.xplane.pb`` (in the window's directory
-        where there is none)."""
+        """The timeline's spans of the window, the train programs' op scopes
+        and the step's bytes, beside the newest ``.xplane.pb`` (in the
+        window's directory where there is none)."""
         traces = sorted(
             glob.glob(
                 os.path.join(
@@ -305,11 +310,16 @@ class StepProfiler:
             start_ns=self._opened_ns,
             end_ns=time.perf_counter_ns(),
         )
-        try:
-            op_scopes.dump(os.path.join(where, op_scopes.OP_SCOPES_FILE))
-        except Exception:  # noqa: BLE001 — the map is an aid: a program
-            # whose text cannot be read must not cost the window its trace
-            logger.exception("XLA profiler: op scopes not written")
+        for name, dump in (
+            (op_scopes.OP_SCOPES_FILE, op_scopes.dump),
+            (op_scopes.STEP_MEMORY_FILE, memory.dump_step_memory),
+        ):
+            try:
+                dump(os.path.join(where, name))
+            except Exception:  # noqa: BLE001 — the maps are an aid: a
+                # program whose text cannot be read must not cost the
+                # window its trace
+                logger.exception("XLA profiler: %s not written", name)
 
     def stop(self):
         """Idempotent; called at loop exit so a short run still flushes

@@ -28,6 +28,7 @@ def test_the_kept_drivers():
         "preemption_accuracy_bench.py",
         "reform_bench.py",
         "rope_sweep.py",
+        "step_memory_aot.py",
     ]
 
 

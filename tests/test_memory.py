@@ -965,3 +965,153 @@ def test_engine_swap_records_double_residency(tmp_path):
     assert snap["peak"]["serving_model"] >= int(1.8 * built)
     assert snap["current"]["serving_model"] < snap["peak"]["serving_model"]
     jax.clear_caches()
+
+
+# ---- the byte side of the train step, read on demand ---------------------------------
+
+
+def _tiny_trainer(mesh="dp=4", **kwargs):
+    import flax.linen as nn
+    import jax.numpy as jnp
+    import optax
+
+    from elasticdl_tpu.parallel.distributed import SPMDTrainer
+    from elasticdl_tpu.parallel.mesh import MeshConfig
+
+    class Tiny(nn.Module):
+        @nn.compact
+        def __call__(self, features, training=False):
+            return nn.Dense(16, name="head")(features["x"])
+
+    features = {"x": np.ones((8, 32), np.float32)}
+    trainer = SPMDTrainer(
+        MeshConfig.from_string(mesh).create(), Tiny(),
+        lambda labels, outputs: jnp.mean((outputs - labels) ** 2),
+        optax.adam(0.1), features, **kwargs,
+    )
+    batch = (
+        trainer.place_batch(features),
+        trainer.place_batch(np.ones((8, 16), np.float32)),
+        trainer.place_mask(8, 8),
+    )
+    return trainer, batch
+
+
+def test_the_steps_bytes_are_not_read_before_a_step():
+    trainer, batch = _tiny_trainer()
+    assert memory_mod.read_step_memory() is None
+    assert memory_mod.dump_step_memory(os.devnull) is False
+    trainer.train_step(*batch)
+    assert memory_mod.read_step_memory() is not None
+
+
+def test_the_states_split_on_a_dp4_mesh_is_one_devices_share():
+    """``gpt2s_seq1024_dp4`` replicates its parameters: a device holds the
+    whole of them once, the four hold four times ``nbytes`` together, and a
+    batch sharded over the mesh stands a quarter on each."""
+    trainer, batch = _tiny_trainer()
+    trainer.train_step(*batch)
+    state = trainer.state
+    kernel = 32 * 16 * 4 + 16 * 4
+    by_device = memory_mod.device_bytes(state.params)
+    assert len(by_device) == 4 and set(by_device.values()) == {kernel}
+    assert pytree_bytes(state.params) == kernel  # the global array's, once
+    assert set(memory_mod.device_bytes(batch[0]).values()) == {8 * 32 * 4 // 4}
+    read = memory_mod.read_step_memory()
+    split = read["state"]
+    assert split["params"] == kernel
+    assert split["opt_state"] == 2 * kernel + 4  # Adam's moments and count
+    assert split["total"] == (
+        split["params"] + split["opt_state"] + split["step"]
+    )
+    assert split["device"] in by_device
+    # what else is alive on that device: its quarter of the batch at least,
+    # and no buffer twice (a shard's own array is a live array too, and
+    # reading the split has just made one for every leaf)
+    assert 8 * 32 * 4 // 4 <= read["other_arrays"] < split["total"]
+    assert memory_mod.read_step_memory()["other_arrays"] == read["other_arrays"]
+    assert read["allocator"] == {}  # the CPU's allocator says nothing
+    (program,) = read["programs"]
+    assert program["xla"]["argument"] >= split["total"]
+
+
+@pytest.mark.parametrize("donate", [True, False])
+def test_a_state_leaf_the_step_does_not_donate_is_found(donate):
+    trainer, batch = _tiny_trainer(mesh="dp=2", donate=donate)
+    trainer.train_step(*batch)
+    read = memory_mod.read_step_memory()
+    paths = [path for path, _ in read["undonated"]]
+    if donate:
+        assert paths == []
+        return
+    assert "state.params['head']['kernel']" in paths
+    assert "state.opt_state[0].mu['head']['kernel']" in paths
+    assert all(size > 0 for _, size in read["undonated"])
+    (program,) = read["programs"]
+    assert program["xla"]["alias"] == 0  # the sum says the same
+
+
+class _FakeDevice:
+    def __init__(self, id_, stats):
+        self.id, self._stats = id_, stats
+
+    def memory_stats(self):
+        return self._stats
+
+
+GIB = 1 << 30
+
+
+@pytest.mark.parametrize("devices,fullest,headroom", [
+    # one chip under a train step: 6 GiB of arrays, 5 GiB reserved for the
+    # step's temporaries and code, of 16
+    ([{"bytes_in_use": 6 * GIB, "peak_bytes_in_use": 7 * GIB,
+       "bytes_reserved": 5 * GIB, "peak_bytes_reserved": 5 * GIB,
+       "largest_free_block_bytes": 4 * GIB, "bytes_limit": 16 * GIB}],
+     0, 5 * GIB),
+    # four chips: the fullest one decides, not the sum
+    ([{"bytes_in_use": 2 * GIB, "bytes_reserved": GIB, "bytes_limit": 16 * GIB},
+      {"bytes_in_use": 9 * GIB, "peak_bytes_in_use": 9 * GIB,
+       "bytes_reserved": 4 * GIB, "peak_bytes_reserved": 6 * GIB,
+       "bytes_limit": 16 * GIB},
+      {"bytes_in_use": 2 * GIB, "bytes_limit": 16 * GIB}, None],
+     1, 3 * GIB),
+    # a backend whose allocator says nothing (the CPU's)
+    ([None, None], None, None),
+    # no limit reported: nothing to budget against
+    ([{"bytes_in_use": GIB}], 0, None),
+])
+def test_device_memory_is_read_device_by_device_with_what_is_reserved(
+    monkeypatch, devices, fullest, headroom
+):
+    import jax
+
+    from elasticdl_tpu.trainer import device_pipeline
+
+    jax.devices()  # a backend is running
+    monkeypatch.setattr(
+        jax, "local_devices",
+        lambda: [_FakeDevice(at, stats) for at, stats in enumerate(devices)],
+    )
+    monkeypatch.delenv(device_pipeline.STAGING_BUDGET_ENV, raising=False)
+    stats = memory_mod.read_device_memory()
+    if fullest is None:
+        assert stats == {} and memory_mod.fullest_device(stats) is None
+    else:
+        told = [d for d in devices if d]
+        assert [d["id"] for d in stats["devices"]] == [
+            at for at, d in enumerate(devices) if d
+        ]
+        assert all(
+            set(d) == {"id", *memory_mod.ALLOCATOR_FIGURES} for d in stats["devices"]
+        )
+        # the summed figures the ledger's samples read are still there
+        assert stats["bytes_in_use"] == sum(d["bytes_in_use"] for d in told)
+        assert stats["bytes_reserved"] == sum(d.get("bytes_reserved", 0) for d in told)
+        assert stats["peak_bytes_in_use"] >= stats["bytes_in_use"]
+        assert memory_mod.fullest_device(stats)["id"] == fullest
+    assert memory_mod.device_headroom_bytes() == headroom
+    # the stager takes half of what is left beside the reserved bytes
+    assert device_pipeline.staging_budget_bytes() == (
+        None if headroom is None else headroom // 2
+    )

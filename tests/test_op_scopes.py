@@ -1055,3 +1055,257 @@ def test_the_command_prints_a_windows_busy_time_by_scope(
     # the table's total is the window's busy time
     assert rows[4][0] == "total" and rows[4][-1] == "100.00"
     assert op_scopes.main([str(tmp_path / "nowhere")]) == 1
+
+
+# ---- the live bytes of a scheduled program -------------------------------------------
+
+FWD = 'metadata={op_name="jit(f)/jvp(M)/enc/op"}'
+BWD = 'metadata={op_name="jit(f)/transpose(jvp(M))/enc/op"}'
+HEAD = "HloModule m, is_scheduled=true"
+
+# name -> (text, bytes at the peak, the instruction there, the loops it is
+# within, some of the rows [owner, phase, role, bytes])
+LIVE_CASES = {
+    # a (1 KiB) feeds b (2 KiB) feeds c: a is dead when c is made
+    "chain": (f"""{HEAD}
+
+ENTRY %main (x: f32[256]) -> f32[256] {{
+  %x = f32[256]{{0}} parameter(0), metadata={{op_name="tokens"}}
+  %a = f32[256]{{0}} exponential(%x), {FWD}
+  %b = f32[512]{{0}} concatenate(%a, %a), dimensions={{0}}, {FWD}
+  ROOT %c = f32[256]{{0}} slice(%b), slice={{[0:256]}}, {FWD}
+}}
+""", 4096, "b", [], [["batch", "", "argument", 1024], ["enc", "forward", "temporary", 3072]]),
+    # both branches of a are alive where the second is made, a with them
+    "diamond": (f"""{HEAD}
+
+ENTRY %main (x: f32[256]) -> f32[256] {{
+  %x = f32[256]{{0}} parameter(0)
+  %a = f32[256]{{0}} exponential(%x), {FWD}
+  %b = f32[512]{{0}} concatenate(%a, %a), dimensions={{0}}, {FWD}
+  %c = f32[768]{{0}} concatenate(%a, %a, %a), dimensions={{0}}, {FWD}
+  ROOT %d = f32[256]{{0}} custom-call(%b, %c), custom_call_target="join", {FWD}
+}}
+""", 7168, "c", [], [["argument", "", "argument", 1024], ["enc", "forward", "temporary", 6144]]),
+    # r is made early and read last by the backward pass: alive at the peak
+    # (u), and a residual there, as u is; t dies into u's slice, a temporary
+    "outlives_the_peak": (f"""{HEAD}
+
+ENTRY %main (x: f32[256]) -> f32[256] {{
+  %x = f32[256]{{0}} parameter(0)
+  %r = f32[256]{{0}} exponential(%x), {FWD}
+  %t = f32[2048]{{0}} broadcast(%x), dimensions={{}}, {FWD}
+  %u = f32[256]{{0}} slice(%t), slice={{[0:256]}}, {FWD}
+  %v = f32[256]{{0}} custom-call(%u), custom_call_target="k", {BWD}
+  ROOT %g = f32[256]{{0}} custom-call(%v, %r), custom_call_target="k", {BWD}
+}}
+""", 11264, "u", [], [
+        ["enc", "forward", "residual", 2048], ["enc", "forward", "temporary", 8192],
+    ]),
+    # the update is written into buf: no second kilobyte-array for its result
+    "dynamic_update_slice_in_place": (f"""{HEAD}
+
+ENTRY %main (x: f32[1024], i: s32[]) -> f32[1024] {{
+  %x = f32[1024]{{0}} parameter(0)
+  %i = s32[] parameter(1)
+  %buf = f32[1024]{{0}} broadcast(%i), dimensions={{}}, {FWD}
+  %upd = f32[256]{{0}} slice(%x), slice={{[0:256]}}, {FWD}
+  %dus = f32[1024]{{0}} dynamic-update-slice(%buf, %upd, %i), {FWD}
+  ROOT %out = f32[1024]{{0}} custom-call(%dus), custom_call_target="k", {FWD}
+}}
+""", 12292, "out", [], [["enc", "forward", "temporary", 4096], ["enc", "forward", "output", 4096]]),
+    # the new parameter is written in place of the donated one and counts
+    # once; the moment is not donated and is named
+    "donated_argument": ("""HloModule m, is_scheduled=true, input_output_alias={ {0}: (0, {}, may-alias) }, entry_computation_layout={(f32[1024]{0}, f32[1024]{0})->(f32[1024]{0}, f32[])}
+
+ENTRY %main (p: f32[1024], g: f32[1024]) -> (f32[1024], f32[]) {
+  %p = f32[1024]{0} parameter(0), metadata={op_name="state.params[\\'w\\']"}
+  %g = f32[1024]{0} parameter(1), metadata={op_name="state.opt_state[0].mu[\\'w\\']"}
+  %new = f32[1024]{0} custom-call(%p, %g), custom_call_target="adam", metadata={op_name="jit(f)/optimizer/adam"}
+  %loss = f32[] custom-call(%g), custom_call_target="l", metadata={op_name="jit(f)/jvp(M)/loss/l"}
+  ROOT %t = (f32[1024]{0}, f32[]) tuple(%new, %loss)
+}
+""", 8196, "loss", [], [
+        ["params", "", "argument", 4096], ["opt_state", "", "argument", 4096],
+        ["loss", "forward", "output", 4],
+    ]),
+    # the loop's body holds 16 KiB over the 2 KiB that live across the loop;
+    # its new carry is written in place of the old
+    "while_body_peak": (f"""{HEAD}
+
+%body (c: (s32[], f32[256])) -> (s32[], f32[256]) {{
+  %c = (s32[], f32[256]{{0}}) parameter(0)
+  %i = s32[] get-tuple-element(%c), index=0
+  %v = f32[256]{{0}} get-tuple-element(%c), index=1
+  %one = s32[] constant(1)
+  %big = f32[4096]{{0}} broadcast(%v), dimensions={{}}, metadata={{op_name="jit(f)/jvp(M)/while/body/block_3/mlp/mul"}}
+  %nv = f32[256]{{0}} slice(%big), slice={{[0:256]}}, metadata={{op_name="jit(f)/jvp(M)/while/body/block_3/mlp/slice"}}
+  %ni = s32[] add(%i, %one)
+  ROOT %r = (s32[], f32[256]{{0}}) tuple(%ni, %nv)
+}}
+
+%cond (c.1: (s32[], f32[256])) -> pred[] {{
+  %c.1 = (s32[], f32[256]{{0}}) parameter(0)
+  %i.1 = s32[] get-tuple-element(%c.1), index=0
+  %n = s32[] constant(4)
+  ROOT %lt = pred[] compare(%i.1, %n), direction=LT
+}}
+
+ENTRY %main (x: f32[256]) -> f32[256] {{
+  %x = f32[256]{{0}} parameter(0)
+  %zero = s32[] constant(0)
+  %init = f32[256]{{0}} copy(%x), {FWD}
+  %t = (s32[], f32[256]{{0}}) tuple(%zero, %init)
+  %w = (s32[], f32[256]{{0}}) while(%t), condition=%cond, body=%body, metadata={{op_name="jit(f)/jvp(M)/while"}}
+  ROOT %out = f32[256]{{0}} get-tuple-element(%w), index=1
+}}
+""", 18432, "big", ["w"], [["block/mlp", "forward", "temporary", 16384], ["enc", "forward", "output", 1024]]),
+    # one element of a tuple-shaped result dies before the other
+    "tuple_shaped_result": (f"""{HEAD}
+
+ENTRY %main (x: f32[256]) -> f32[256] {{
+  %x = f32[256]{{0}} parameter(0)
+  %f = (f32[256]{{0}}, f32[512]{{0}}) custom-call(%x), custom_call_target="two", {FWD}
+  %f0 = f32[256]{{0}} get-tuple-element(%f), index=0
+  %f1 = f32[512]{{0}} get-tuple-element(%f), index=1
+  %h = f32[512]{{0}} custom-call(%f0), custom_call_target="k", {FWD}
+  %k = f32[2048]{{0}} custom-call(%f1, %h), custom_call_target="k", {FWD}
+  ROOT %m = f32[256]{{0}} custom-call(%k), custom_call_target="k", {FWD}
+}}
+""", 13312, "k", [], [["enc", "forward", "temporary", 12288]]),
+    # an element-wise result takes the place of an operand nothing reads
+    # afterwards (n over a, q over n), as XLA assigns them: four arrays are
+    # alive at n without that; s is read again and stays
+    "elementwise_result_over_its_operand": (f"""{HEAD}
+
+ENTRY %main (x: f32[256]) -> f32[256] {{
+  %x = f32[256]{{0}} parameter(0)
+  %a = f32[256]{{0}} custom-call(%x), custom_call_target="k", {FWD}
+  %s = f32[256]{{0}} custom-call(%x), custom_call_target="k", {FWD}
+  %n = f32[256]{{0}} negate(%a), {FWD}
+  %q = f32[256]{{0}} multiply(%s, %n), {FWD}
+  ROOT %z = f32[256]{{0}} custom-call(%q, %s), custom_call_target="k", {FWD}
+}}
+""", 4096, "z", [], []),
+    # the branch that holds most decides; its result is made inside it and
+    # is the caller's once the branch has ended
+    "conditional_branches": (f"""{HEAD}
+
+%small (a.0: f32[256]) -> f32[256] {{
+  %a.0 = f32[256]{{0}} parameter(0)
+  ROOT %s.0 = f32[256]{{0}} custom-call(%a.0), custom_call_target="k", {FWD}
+}}
+
+%large (a.1: f32[256]) -> f32[256] {{
+  %a.1 = f32[256]{{0}} parameter(0)
+  %wide = f32[1024]{{0}} broadcast(%a.1), dimensions={{}}, metadata={{op_name="jit(f)/jvp(M)/block_0/moe/rung/cond/branch_1_fun/mul"}}
+  ROOT %s.1 = f32[256]{{0}} slice(%wide), slice={{[0:256]}}, {FWD}
+}}
+
+ENTRY %main (x: f32[256], which: s32[]) -> f32[256] {{
+  %x = f32[256]{{0}} parameter(0)
+  %which = s32[] parameter(1)
+  ROOT %picked = f32[256]{{0}} conditional(%which, %x, %x), branch_computations={{%small, %large}}, {FWD}
+}}
+""", 6148, "s.1", ["picked"], [["block/moe/rung", "forward", "temporary", 4096]]),
+    # what the compiler prefetches into on-chip memory (S(1)) is no HBM, a
+    # copy's source is no new buffer, and an array is padded to its tile
+    "memory_spaces_and_tiles": (f"""{HEAD}
+
+ENTRY %main (x: f32[12,64]) -> f32[12,64] {{
+  %x = f32[12,64]{{1,0:T(8,128)}} parameter(0)
+  %cs = (f32[12,64]{{1,0:T(8,128)S(1)}}, f32[12,64]{{1,0:T(8,128)}}, u32[]{{:S(2)}}) copy-start(%x)
+  %cd = f32[12,64]{{1,0:T(8,128)S(1)}} copy-done(%cs)
+  ROOT %y = f32[12,64]{{1,0:T(8,128)}} custom-call(%cd), custom_call_target="k", {FWD}
+}}
+""", 16384, "y", [], [["argument", "", "argument", 8192], ["enc", "forward", "output", 8192]]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(LIVE_CASES))
+def test_live_bytes_of_a_hand_written_schedule(case):
+    text, peak, instruction, within, rows = LIVE_CASES[case]
+    read = op_scopes._live_of_text(text)
+    assert read["peak_bytes"] == peak
+    assert (read["instruction"], read["within"]) == (instruction, within)
+    # the owners' bytes add up to the total
+    assert sum(size for *_, size in read["live"]) == peak
+    for row in rows:
+        assert row in read["live"], read["live"]
+    assert read["largest"][0][-1] == max(size for *_, size in read["largest"])
+
+
+def test_a_donated_argument_counts_once_and_an_undonated_leaf_is_named():
+    read = op_scopes._live_of_text(LIVE_CASES["donated_argument"][0])
+    assert read["undonated"] == [["state.opt_state[0].mu['w']", 4096]]
+    assert (read["part"], read["phase"]) == ("loss", "forward")
+    # without the alias the new parameter is a second array
+    undonated = LIVE_CASES["donated_argument"][0].replace(
+        "input_output_alias={ {0}: (0, {}, may-alias) }, ", ""
+    )
+    assert op_scopes._live_of_text(undonated)["peak_bytes"] == 8196 + 4096
+
+
+def test_a_text_that_is_not_scheduled_is_not_read():
+    text = LIVE_CASES["chain"][0].replace(", is_scheduled=true", "")
+    assert op_scopes._live_of_text(text) is None
+
+
+@pytest.mark.parametrize("shape,size", [
+    ("f32[768]{0:T(1024)}", 4096),
+    ("f32[12,64]{1,0:T(8,128)}", 16 * 128 * 4),
+    ("f32[768,12,64]{0,2,1:T(8,128)}", 768 * 12 * 64 * 4),
+    ("bf16[2,4096]{1,0:T(2,128)(2,1)}", 2 * 4096 * 2),
+    ("bf16[8,1024,768]{2,1,0:T(8,128)(2,1)S(1)}", 0),
+    ("s32[]{:T(128)}", 512),
+    ("f32[8,64]{1,0}", 2048),
+    ("pred[]", 1),
+])
+def test_an_arrays_bytes_are_its_tiles_in_main_memory(shape, size):
+    assert op_scopes._shape_tree(shape) == (size, shape)
+
+
+def test_a_window_leaves_the_steps_bytes_beside_its_trace_and_the_command_prints_them(
+    tmp_path, monkeypatch, capsys
+):
+    from elasticdl_tpu.telemetry import memory
+    from elasticdl_tpu.utils.profiling import StepProfiler
+
+    trainer, batch = _trainer()
+    monkeypatch.setattr(jax.profiler, "start_trace", lambda d, **_options: None)
+    monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
+    assert op_scopes.main([str(tmp_path), "--memory"]) == 1  # nothing yet
+    profiler = StepProfiler(str(tmp_path), start_step=1, num_steps=2)
+    for _ in range(5):
+        trainer.train_step(*batch)
+        profiler.on_step()
+    profiler.stop()
+    with open(tmp_path / op_scopes.STEP_MEMORY_FILE) as f:
+        written = json.load(f)
+    read = memory.read_step_memory()
+    assert written["state"] == read["state"]
+    assert [p["peak_bytes"] for p in written["programs"]] == [
+        p["peak_bytes"] for p in read["programs"]
+    ]
+    assert (tmp_path / op_scopes.OP_SCOPES_FILE).exists()  # beside the map
+    assert op_scopes.main([str(tmp_path), "--memory", "--depth", "1"]) == 0
+    out = capsys.readouterr().out
+    assert "peak at " in out and "XLA: argument " in out
+    assert "state on device " in out and "params " in out
+
+
+def test_the_memory_table_adds_up_and_names_the_peak():
+    read = op_scopes._live_of_text(LIVE_CASES["while_body_peak"][0])
+    read.update(
+        xla={"argument": 1024, "output": 1024, "alias": 0, "temp": 17408,
+             "generated_code": 0, "peak": 18432},
+        ratio=1.0, held_to="peak",
+    )
+    rows = [line.split() for line in op_scopes.memory_table(read, depth=1).splitlines()]
+    assert rows[0] == ["owner", "phase", "role", "MB", "%"]
+    assert rows[1][:3] == ["block", "forward", "temporary"]
+    total = next(row for row in rows if row[0] == "total")
+    assert total[-1] == "100.00"
+    peak = " ".join(next(row for row in rows if row[0] == "peak"))
+    assert "peak at big in w: block/mlp, forward" in peak
+    assert "1.0000 of XLA's peak" in peak

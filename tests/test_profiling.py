@@ -61,3 +61,38 @@ def test_step_profiler_window_bounds(monkeypatch, tmp_path):
         prof.on_step(step)
     prof.stop()  # idempotent after the window closed
     assert calls == [("start", out), ("stop",)]
+
+
+def test_a_run_without_a_window_never_reads_the_steps_bytes(tmp_path, monkeypatch):
+    """The byte side is read on demand (a window's close, a benchmark's
+    reader): ``LocalExecutor.run`` with no profile window calls none of it,
+    nor the text of a program."""
+    from elasticdl_tpu.telemetry import memory, op_scopes
+
+    called = []
+    for module, name in (
+        (memory, "read_step_memory"), (memory, "dump_step_memory"),
+        (memory, "device_bytes"), (op_scopes, "live_bytes"),
+        (op_scopes, "xla_sizes"), (op_scopes, "scope_map"),
+        (op_scopes, "_live_of_text"), (op_scopes, "watched_programs"),
+    ):
+        monkeypatch.setattr(
+            module, name,
+            lambda *args, _name=name, **kwargs: called.append(_name),
+        )
+    train = synthetic.gen_mnist(
+        str(tmp_path / "t"), num_records=192, num_shards=1, seed=0
+    )
+    args = parse_master_args(
+        [
+            "--model_def",
+            "mnist_functional_api.mnist_functional_api.custom_model",
+            "--training_data", train,
+            "--minibatch_size", "32",
+            "--records_per_task", "96",
+        ]
+    )
+    executor = LocalExecutor(args)
+    executor.run()
+    assert executor._version == 6  # it trained
+    assert called == []
