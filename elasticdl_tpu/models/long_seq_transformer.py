@@ -44,7 +44,7 @@ from elasticdl_tpu.trainer.metrics import Accuracy
 from elasticdl_tpu.trainer.state import Modes
 
 VOCAB = 256
-# a looped model's loss by its parts (``looped_rows``)
+# a looped model's loss by its parts (``looped_parts``)
 LOOPED_PARTS = ("expected_ce", "exit_entropy")
 
 
@@ -431,7 +431,7 @@ class TransformerLM(nn.Module):
                 return lm_head(x)
             # every pass's exit by what makes it: four passes' logits side by
             # side are 3.2 GB at 8,192 x 49,152, so the head is the loss's to
-            # apply, a pass at a time (``looped_rows``)
+            # apply, a pass at a time (``looped_parts``)
             return {
                 "exit_states": states,  # (passes, batch, seq, embed)
                 "exit_gates": gates,  # (passes, batch, seq): the gate's logit
@@ -511,45 +511,146 @@ def exit_distribution(gates):
     return jnp.stack(log_p + [stayed])
 
 
-@jax.checkpoint
-def _exit_cross_entropy(state, head, labels):
-    """One pass's logits and their per-token cross-entropy; recomputed in the
-    backward pass, so that no pass's logits outlive it."""
-    with jax.named_scope("lm_head"):
-        logits = state @ head["kernel"].astype(state.dtype)
-        if "bias" in head:
-            logits = logits + head["bias"].astype(state.dtype)
-    return softmax_cross_entropy_with_integer_labels(logits, labels)
+@jax.custom_vjp
+def _exits_cross_entropy(states, head, labels, token_weight):
+    return _exits_fwd(states, head, labels, token_weight)[0]
 
 
-def looped_rows(labels, outputs) -> dict | None:
-    """A looped model's loss a row, by its parts (arXiv:2510.25741, stage I:
-    gate and model trained together): ``expected_ce``, the mean over tokens
-    of ``sum_t p_t CE_t``, and ``exit_entropy``, ``-beta`` times the mean of
-    ``H(p) = -sum_t p_t log p_t``; under ``LOSS_OBSERVED`` what is no term
-    of the loss, each pass's own mean cross-entropy ``ce_t`` and mean exit
-    probability ``exit_t``.  The head is applied here, inside a loop over
-    the passes whose body holds one pass's logits; None for outputs of any
-    other kind."""
+def _exits_fwd(states, head, labels, token_weight):
+    """``exits_cross_entropy`` and, as residuals, its gradients: a pass's
+    logits, their cross-entropy and its gradient at ``token_weight`` are
+    formed in ONE loop body and the gradient is applied there, to the pass's
+    state and, summed over the passes in float32, to the head.  No pass's
+    logits leave the body, and nothing is left for the backward rule to
+    multiply."""
+    dtype = states.dtype
+    kernel = head["kernel"].astype(dtype)
+    bias = head["bias"].astype(dtype) if "bias" in head else None
+
+    def one_pass(d_head, exit_):
+        state, weight = exit_
+        # a pass's state as an array of its own: without the barrier XLA
+        # slices the stacked states inside the products' fusions, with it
+        # the slice is prefetched into fast memory as the loop of before
+        # had it (the head's gradient 42.1 ms a step for 49.0 at 8,192 x
+        # 2,048 x 49,152, the logits 35.3 for 37.0; PERF.md section 6, PR 57)
+        state = jax.lax.optimization_barrier(state)
+        with jax.named_scope("lm_head"):
+            logits = state @ kernel
+            if bias is not None:
+                logits = logits + bias
+        # trainer/losses.py's cross-entropy and what autodiff makes of it at
+        # the tokens' weights: float32 statistics, the gradient rounded to
+        # the logits' dtype
+        cross_entropy, pullback = jax.vjp(
+            lambda logits: softmax_cross_entropy_with_integer_labels(
+                logits, labels
+            ),
+            logits,
+        )
+        (d_logits,) = pullback(weight)
+        with jax.named_scope("lm_head"):
+            d_state = d_logits @ kernel.T
+            d_pass = {
+                "kernel": jnp.einsum(
+                    "bsd,bsv->dv", state, d_logits,
+                    preferred_element_type=jnp.float32,
+                )
+            }
+            if bias is not None:
+                d_pass["bias"] = jnp.sum(
+                    d_logits, axis=(0, 1), dtype=jnp.float32
+                )
+        d_head = jax.tree_util.tree_map(jnp.add, d_head, d_pass)
+        return d_head, (cross_entropy, d_state)
+
+    zeros = {
+        name: jnp.zeros(head[name].shape, jnp.float32) for name in head
+    }
+    d_head, (cross_entropy, d_states) = jax.lax.scan(
+        one_pass, zeros, (states, token_weight)
+    )
+    total = jnp.sum(token_weight * cross_entropy)
+    d_head = {name: d_head[name].astype(head[name].dtype) for name in head}
+    return (total, cross_entropy), (d_states, d_head, cross_entropy)
+
+
+def _exits_bwd(residuals, cotangents):
+    d_states, d_head, cross_entropy = residuals
+    # (``cross_entropy`` is handed out under ``stop_gradient``)
+    scale, _ = cotangents
+
+    def scaled(d):
+        # (in float32, as autodiff scales the logits' gradient before it
+        # rounds it; the step's cotangent is 1 and XLA folds this away)
+        return (scale * d.astype(jnp.float32)).astype(d.dtype)
+
+    return (
+        scaled(d_states),
+        jax.tree_util.tree_map(scaled, d_head),
+        None,
+        scale * cross_entropy,
+    )
+
+
+_exits_cross_entropy.defvjp(_exits_fwd, _exits_bwd)
+
+
+def exits_cross_entropy(states, head, labels, token_weight):
+    """``(sum(token_weight * CE), CE)`` of a looped model's exits: ``CE`` the
+    ``(passes, batch, seq)`` float32 cross-entropies of ``labels`` under
+    ``states[t] @ head``, handed out with no gradient path.  The sum's
+    gradient reaches the states and the head through products made beside
+    the logits (``_exits_fwd``), which takes a token's weight in the
+    loss to be known before its logits are: a weight gradient summed over
+    tokens can afterwards be scaled by a scalar, not by token or row.  So
+    the sum is a scalar, and ``token_weight`` gets ``CE`` times its
+    cotangent, through which the gates get theirs."""
+    total, cross_entropy = _exits_cross_entropy(
+        states, head, labels, token_weight
+    )
+    return total, jax.lax.stop_gradient(cross_entropy)
+
+
+def looped_parts(labels, outputs, weights=None) -> dict | None:
+    """A looped model's loss by its parts (arXiv:2510.25741, stage I: gate
+    and model trained together), each the mean over rows that
+    ``trainer/step.py::weighted_mean_loss`` takes with ``weights`` (a row of
+    weight 0 adds nothing to it or to any gradient) and the plain mean
+    without: ``expected_ce``, the mean over tokens of ``sum_t p_t CE_t``, and
+    ``exit_entropy``, ``-beta`` times the mean of ``H(p) = -sum_t p_t log
+    p_t``; under ``LOSS_OBSERVED`` what is no term of the loss, each pass's
+    own mean cross-entropy ``ce_t`` and mean exit probability ``exit_t``.
+    The head is applied here (``exits_cross_entropy``), so the rows' weights
+    are taken here too; None for outputs of any other kind."""
     if not (isinstance(outputs, dict) and "exit_states" in outputs):
         return None
-    head = outputs["head"]
-    cross_entropy = jax.lax.map(
-        lambda state: _exit_cross_entropy(state, head, labels),
-        outputs["exit_states"],
-    )
+    rows, seq = labels.shape
+    if weights is None:
+        share = jnp.full((rows,), 1.0 / rows, jnp.float32)
+    else:
+        # a row's share of ``weighted_mean_loss``'s mean, its guard too
+        share = weights.astype(jnp.float32)
+        share = share / jnp.maximum(jnp.sum(share), 1.0)
     log_p = exit_distribution(outputs["exit_gates"])
     p = jnp.exp(log_p)
+    expected, cross_entropy = exits_cross_entropy(
+        outputs["exit_states"], outputs["head"], labels,
+        p * (share / seq)[:, None],
+    )
     entropy = -jnp.sum(p * log_p, axis=0)
+
+    def mean(per_token):
+        return jnp.sum(share * per_token.mean(axis=-1))
+
     seen = {"ce": cross_entropy, "exit": p}
-    observed = {
-        f"{kind}_{t + 1}": seen[kind][t].mean(axis=-1)
-        for t in range(p.shape[0]) for kind in seen
-    }
     return {
-        "expected_ce": jnp.sum(p * cross_entropy, axis=0).mean(axis=-1),
-        "exit_entropy": -outputs["exit_entropy_weight"] * entropy.mean(axis=-1),
-        LOSS_OBSERVED: observed,
+        "expected_ce": expected,
+        "exit_entropy": -outputs["exit_entropy_weight"] * mean(entropy),
+        LOSS_OBSERVED: {
+            f"{kind}_{t + 1}": mean(seen[kind][t])
+            for t in range(p.shape[0]) for kind in seen
+        },
     }
 
 
@@ -560,11 +661,11 @@ def loss_parts(labels, outputs) -> dict:
     ``L_k``, the cross-entropy of token ``i + k + 1`` summed over the
     ``T - k`` positions of a row that have one in ``labels`` and divided
     by ``T`` (arXiv:2412.19437, eqs. 24, 25).  A looped model's training
-    forward: ``looped_rows``' parts.  Each a mean of per-row terms, so
+    forward: ``looped_parts``.  Each a mean of per-row terms, so
     ``trainer/step.py::weighted_mean_loss`` masks padded rows."""
-    rows = looped_rows(labels, outputs)
-    if rows is not None:
-        return jax.tree_util.tree_map(lambda row: row.mean(), rows)
+    parts = looped_parts(labels, outputs)
+    if parts is not None:
+        return parts
     if not isinstance(outputs, dict):
         return {
             "main": softmax_cross_entropy_with_integer_labels(
@@ -600,16 +701,17 @@ def loss(labels, outputs):
     return _terms(loss_parts(labels, outputs))
 
 
-def _loss_rows(labels, outputs):
-    rows = looped_rows(labels, outputs)
-    return None if rows is None else _terms(rows)
+def _looped_loss(labels, outputs, weights):
+    parts = looped_parts(labels, outputs, weights)
+    return None if parts is None else _terms(parts)
 
 
 loss.parts = loss_parts
 # (the outputs of a looped model's training forward hold the head's weight,
-# which is no row's: the per-row terms weighted_mean_loss asks for)
-loss.rows = _loss_rows
-loss_parts.rows = looped_rows
+# which is no row's, and the head's gradient is formed where a token's weight
+# in the mean has to be known: what weighted_mean_loss asks first)
+loss.weighted_mean = _looped_loss
+loss_parts.weighted_mean = looped_parts
 
 
 def optimizer(lr=3e-3):

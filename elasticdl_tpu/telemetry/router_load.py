@@ -29,7 +29,7 @@ LOSS_PARTS = "loss_parts"
 # what a loss function saw on its way that is no term of the loss, a scalar a
 # name: the key ``loss.parts`` returns it under and the collection a model
 # declares to have ``trainer/step.py`` leave it there.  A looped model's
-# (``models/long_seq_transformer.py::looped_rows``): each pass's own mean
+# (``models/long_seq_transformer.py::looped_parts``): each pass's own mean
 # cross-entropy ``ce_t`` and mean exit probability ``exit_t``, ``t`` from 1
 LOSS_OBSERVED = "loss_observed"
 # the collection a sparse-attention layer (``layers/attention.py``) sows

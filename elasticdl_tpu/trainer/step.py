@@ -103,14 +103,14 @@ def weighted_mean_loss(loss_fn, labels, outputs, weights):
         return jnp.sum(w * per_row) / jnp.maximum(jnp.sum(w), 1.0)
 
     # a ``loss_fn`` whose model's outputs hold something that is no row's (a
-    # looped model's head, applied inside the loss a pass at a time) forms
-    # its per-row terms itself: ``loss_fn.rows``, None for any other outputs
-    rows = getattr(loss_fn, "rows", None)
-    per_row = rows(labels, outputs) if rows is not None else None
-    if per_row is None:
-        per_row = jax.vmap(one_row)(labels, outputs)
+    # looped model's head, whose gradient the loss forms beside the logits,
+    # at the rows' weights) takes them itself: ``weighted_mean``, else None
+    own = getattr(loss_fn, "weighted_mean", None)
+    reduced = own(labels, outputs, weights) if own is not None else None
+    if reduced is not None:
+        return reduced
     # (a ``loss_fn`` that returns its loss by named parts gets each weighted)
-    return jax.tree_util.tree_map(mean, per_row)
+    return jax.tree_util.tree_map(mean, jax.vmap(one_row)(labels, outputs))
 
 
 _DONATION_WARNING_PATTERN = "Some donated buffers were not usable"

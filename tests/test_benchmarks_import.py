@@ -25,6 +25,7 @@ def test_the_kept_drivers():
         "dispatch_overhead_bench.py",
         "expert_rows_sweep.py",
         "ouro_loop_control.py",
+        "ouro_loss_forms.py",
         "preemption_accuracy_bench.py",
         "reform_bench.py",
         "rope_sweep.py",

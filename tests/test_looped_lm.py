@@ -3,7 +3,8 @@
 ``loop_steps=1`` builds, the shared weights' gradient as the sum over their
 uses, the exit distribution, the loss by hand, the system against the plain
 reference (``perf/references/ouro.py``), the step's masked loss and what it
-leaves in the state, and what a loop refuses."""
+leaves in the state, the head's gradient formed beside the logits against the
+plain composition, and what a loop refuses."""
 
 import functools
 import importlib.util
@@ -19,7 +20,7 @@ from elasticdl_tpu.layers.attention import TransformerBlock, make_norm
 from elasticdl_tpu.models import long_seq_transformer as zoo
 from elasticdl_tpu.telemetry import op_scopes, router_load
 from elasticdl_tpu.trainer.state import TrainState
-from elasticdl_tpu.trainer.step import build_train_step
+from elasticdl_tpu.trainer.step import build_train_step, weighted_mean_loss
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 VOCAB, SEQ, WIDTH, LAYERS, PASSES = 64, 32, 64, 2, 4
@@ -80,15 +81,21 @@ def float32_system(remat=False):
     )
 
 
-def loop_control():
-    """``benchmarks/ouro_loop_control.py``: the faults of the loop alone that
-    are read on the chip at the cell's own state and limits."""
+@functools.lru_cache(maxsize=None)
+def benchmark_tool(name):
+    """A builder's tool under ``benchmarks/``, as a module."""
     spec = importlib.util.spec_from_file_location(
-        "ouro_loop_control", os.path.join(ROOT, "benchmarks", "ouro_loop_control.py")
+        name, os.path.join(ROOT, "benchmarks", name + ".py")
     )
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
     return module
+
+
+def loop_control():
+    """``benchmarks/ouro_loop_control.py``: the faults of the loop alone that
+    are read on the chip at the cell's own state and limits."""
+    return benchmark_tool("ouro_loop_control")
 
 
 def plain_reference():
@@ -118,7 +125,7 @@ def test_one_pass_is_the_model_of_before():
     assert logits.shape == (3, SEQ, VOCAB)  # an array, no exits
     np.testing.assert_array_equal(logits, one.apply(variables, features, training=True))
     assert set(zoo.loss_parts(labels, logits)) == {"main"}
-    assert zoo.loss.rows(labels, logits) is None
+    assert zoo.loss.weighted_mean(labels, logits, None) is None
 
 
 @pytest.mark.parametrize("passes", [2, 3, 4])
@@ -431,8 +438,6 @@ def test_a_masked_rows_gradient_is_zero():
 
     def masked(p, tokens):
         outputs = training_outputs(model, p, state, {"tokens": tokens})
-        from elasticdl_tpu.trainer.step import weighted_mean_loss
-
         parts = weighted_mean_loss(
             zoo.loss_parts, labels, outputs, jnp.asarray([1.0, 0.0])
         )
@@ -443,6 +448,233 @@ def test_a_masked_rows_gradient_is_zero():
     a, b = (jax.grad(masked)(params, jnp.asarray(t)) for t in (features["tokens"], other))
     for x, y in zip(jax.tree_util.tree_leaves(a), jax.tree_util.tree_leaves(b)):
         np.testing.assert_allclose(x, y, rtol=1e-5, atol=1e-7)
+
+
+# ---- the head's gradient is formed where the logits are -------------------------------
+
+
+def plain_parts(labels, outputs, weights=None):
+    """The looped loss as a plain composition (the form up to PR 55, what
+    the shipped one is compared with here and, on the chip at the cell's
+    size, by ``benchmarks/ouro_loss_forms.py``, which keeps it): a pass's
+    logits and their cross-entropy under ``jax.checkpoint`` inside
+    ``jax.lax.map``, the rows' terms, then the step's weighted mean."""
+    return benchmark_tool("ouro_loss_forms").plain_parts(labels, outputs, weights)
+
+
+def both_forms(labels, outputs_of, weights):
+    """``(shipped, plain)``, each the loss's parts by what is differentiated:
+    through the module's own loss as the step asks it, and through
+    ``plain_parts``."""
+    tool = benchmark_tool("ouro_loss_forms")
+    return tuple(
+        lambda d, form=form: form(labels, outputs_of(d), weights)
+        for form in (tool.shipped_parts, tool.plain_parts)
+    )
+
+
+LOOSE_VOCAB = 80  # no other dimension of these exits
+
+
+def loose_exits(head, dtype=jnp.float32, rows=3):
+    """``(differentiated, labels, outputs_of)``: exits with no model under
+    them, the states, the gates and the head's parameters as what a gradient
+    is taken by; a tied head's parameter is the embedding."""
+    keys = jax.random.split(jax.random.PRNGKey(5), 5)
+    labels = jax.random.randint(keys[0], (rows, SEQ), 0, LOOSE_VOCAB)
+    kernel = 0.3 * jax.random.normal(keys[1], (WIDTH, LOOSE_VOCAB))
+    differentiated = {
+        "states": jax.random.normal(keys[2], (PASSES, rows, SEQ, WIDTH)).astype(dtype),
+        "gates": 1.5 * jax.random.normal(keys[3], (PASSES, rows, SEQ)),
+        "head": {
+            "untied": {"kernel": kernel},
+            "untied_with_bias": {
+                "kernel": kernel,
+                "bias": jax.random.normal(keys[4], (LOOSE_VOCAB,)),
+            },
+            "tied": {"embedding": kernel.T},
+        }[head],
+    }
+
+    def outputs_of(d):
+        return {
+            "exit_states": d["states"], "exit_gates": d["gates"],
+            "head": (
+                {"kernel": d["head"]["embedding"].T} if head == "tied"
+                else d["head"]
+            ),
+            "exit_entropy_weight": jnp.float32(0.1),
+        }
+
+    return differentiated, labels, outputs_of
+
+
+def _total(parts):
+    return benchmark_tool("ouro_loss_forms").total(parts)
+
+
+MASKS = {"no_mask": None, "a_zero_row": [1.0, 0.0, 2.0]}
+
+
+@pytest.mark.parametrize("mask", MASKS)
+@pytest.mark.parametrize("head", ["untied", "untied_with_bias", "tied"])
+def test_the_fused_loss_and_its_gradients_are_the_plain_compositions(head, mask):
+    differentiated, labels, outputs_of = loose_exits(head)
+    weights = None if MASKS[mask] is None else jnp.asarray(MASKS[mask])
+    shipped, plain = both_forms(labels, outputs_of, weights)
+
+    got, want = shipped(differentiated), plain(differentiated)
+    assert jax.tree_util.tree_structure(got) == jax.tree_util.tree_structure(want)
+    for (path, x), y in zip(
+        jax.tree_util.tree_leaves_with_path(got), jax.tree_util.tree_leaves(want)
+    ):
+        assert x.shape == () and x.dtype == jnp.float32, path
+        np.testing.assert_allclose(x, y, rtol=2e-6, err_msg=jax.tree_util.keystr(path))
+    if weights is not None:
+        # the step's other spelling, the loss as one number
+        np.testing.assert_allclose(
+            weighted_mean_loss(zoo.loss, labels, outputs_of(differentiated), weights),
+            _total(want), rtol=2e-6,
+        )
+    # under a cotangent of 1 and under one that is not
+    for scale in (1.0, 3.0):
+        grads, grads_plain = (
+            jax.jit(jax.grad(lambda d: scale * _total(f(d))))(differentiated)
+            for f in (shipped, plain)
+        )
+        for (path, x), y in zip(
+            jax.tree_util.tree_leaves_with_path(grads),
+            jax.tree_util.tree_leaves(grads_plain),
+        ):
+            assert x.dtype == y.dtype and float(jnp.linalg.norm(y)) > 0
+            assert relative(x, y) < 5e-6, (scale, jax.tree_util.keystr(path))
+    if weights is not None:
+        # the zero row: nothing of it in any gradient a row has
+        assert not np.any(grads["states"][:, 1]) and not np.any(grads["gates"][:, 1])
+        assert np.all(np.any(np.asarray(grads["states"][:, 2]) != 0, axis=(1, 2)))
+
+
+@pytest.mark.parametrize("mask", MASKS)
+def test_at_bfloat16_the_value_is_the_plain_forms_and_the_gradient_rounds_where_autodiffs_did(
+    mask,
+):
+    """bfloat16 exits, as the cell runs them.  The loss VALUE is the plain
+    form's to float32 summation order (the number a traced run's ``loss_err``
+    is read from: the logits are rounded to bfloat16 where they were, the
+    statistics are float32); the logits' gradient is rounded to the logits'
+    dtype as autodiff rounds it, so the states' gradient is the plain form's
+    bit for bit; the head's is summed over tokens and passes in float32
+    where the plain form rounds a pass's to bfloat16 first, so it is no
+    farther from the float32 gradient."""
+    differentiated, labels, outputs_of = loose_exits("untied_with_bias", jnp.bfloat16)
+    weights = None if MASKS[mask] is None else jnp.asarray(MASKS[mask])
+    shipped, plain = both_forms(labels, outputs_of, weights)
+
+    (value, grads), (value_plain, grads_plain) = (
+        jax.jit(jax.value_and_grad(lambda d: _total(f(d))))(differentiated)
+        for f in (shipped, plain)
+    )
+    assert value.dtype == value_plain.dtype == jnp.float32
+    assert abs(float(value) - float(value_plain)) <= 1e-6 * abs(float(value_plain))
+    assert grads["states"].dtype == jnp.bfloat16
+    assert grads["head"]["kernel"].dtype == jnp.float32
+    # (a gradient not rounded to the logits' dtype there reads ~1e-3)
+    np.testing.assert_array_equal(
+        np.asarray(grads["states"].astype(jnp.float32)),
+        np.asarray(grads_plain["states"].astype(jnp.float32)),
+    )
+    as_float = functools.partial(jax.tree_util.tree_map, lambda x: x.astype(jnp.float32))
+    grads, grads_plain = as_float(grads), as_float(grads_plain)
+    assert relative(grads["gates"], grads_plain["gates"]) < 1e-5
+    exact = as_float(jax.grad(lambda d: _total(plain(d)))(
+        {**as_float(differentiated), "gates": differentiated["gates"]}
+    ))
+    for name in ("kernel", "bias"):
+        fused, rounded = (
+            relative(g["head"][name], exact["head"][name]) for g in (grads, grads_plain)
+        )
+        assert fused <= rounded * 1.05 and fused < 5e-3, (name, fused, rounded)
+
+
+def _vocabulary_products(jaxpr, vocab):
+    """``(in no loop, [in each loop's body])``: the ``dot_general``s of a
+    jaxpr that have the vocabulary as a dimension, by the loop they are in."""
+    own, loops = 0, []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            own += any(vocab in v.aval.shape for v in (*eqn.invars, *eqn.outvars))
+        for inner in jax.core.jaxprs_in_params(eqn.params):
+            inside, deeper = _vocabulary_products(inner, vocab)
+            if eqn.primitive.name in ("scan", "while"):
+                loops += [inside] + deeper if inside or deeper else []
+            else:
+                own, loops = own + inside, loops + deeper
+    return own, loops
+
+
+def test_the_heads_product_is_made_three_times_a_pass_and_never_in_the_backward_rule():
+    features, labels = batch()
+    model = zoo.custom_model(
+        loop_steps=PASSES, **{**FIELDS, "vocab_size": LOOSE_VOCAB}
+    )
+    variables = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), features, training=False)
+    )
+    state = {k: v for k, v in variables.items() if k != "params"}
+
+    def step_gradient(parts):
+        def loss_of(p):
+            return _total(parts(labels, training_outputs(model, p, state, features)))
+
+        return jax.make_jaxpr(jax.grad(loss_of))(variables["params"]).jaxpr
+
+    # logits, the states' gradient, the head's: one loop body, and no other
+    assert _vocabulary_products(step_gradient(zoo.loss_parts), LOOSE_VOCAB) == (0, [3])
+    # the plain form: the product in the forward loop, then made again with
+    # its two gradients in the backward loop
+    assert _vocabulary_products(step_gradient(plain_parts), LOOSE_VOCAB) == (0, [1, 3])
+    # the backward rule alone, its residuals given
+    differentiated, labels, outputs_of = loose_exits("untied_with_bias")
+    for parts, products in ((zoo.loss_parts, (0, [])), (plain_parts, (0, [3]))):
+        _, pullback = jax.vjp(
+            lambda d: _total(parts(labels, outputs_of(d))), differentiated
+        )
+        backward = jax.make_jaxpr(pullback)(jnp.float32(1.0)).jaxpr
+        assert _vocabulary_products(backward, LOOSE_VOCAB) == products, parts
+
+
+def _weighted_mean_loss_of_before(loss_fn, labels, outputs, weights):
+    """``trainer/step.py::weighted_mean_loss`` with no seam in it."""
+
+    def one_row(labels_row, outputs_row):
+        labels_1 = jax.tree_util.tree_map(lambda x: x[None], labels_row)
+        outputs_1 = jax.tree_util.tree_map(lambda x: x[None], outputs_row)
+        return loss_fn(labels_1, outputs_1)
+
+    def mean(per_row):
+        w = weights.astype(per_row.dtype)
+        return jnp.sum(w * per_row) / jnp.maximum(jnp.sum(w), 1.0)
+
+    return jax.tree_util.tree_map(mean, jax.vmap(one_row)(labels, outputs))
+
+
+@pytest.mark.parametrize("loss_fn", ["loss", "loss_parts"])
+@pytest.mark.parametrize("outputs", ["logits", "logits_and_a_second_tokens"])
+def test_a_model_that_is_not_looped_gets_the_weighted_mean_of_before(outputs, loss_fn):
+    _, labels = batch(rows=4)
+    logits = jax.random.normal(jax.random.PRNGKey(1), (4, SEQ, VOCAB))
+    given = logits if outputs == "logits" else {
+        "logits": logits, "mtp_logits": (logits[::-1],),
+        "mtp_weight": jnp.full((4,), 0.3),
+    }
+    weights = jnp.asarray([1.0, 1.0, 0.0, 1.0])
+    fn = getattr(zoo, loss_fn)
+    assert fn.weighted_mean(labels, given, weights) is None
+    now, before = (
+        jax.make_jaxpr(functools.partial(f, fn))(labels, given, weights)
+        for f in (weighted_mean_loss, _weighted_mean_loss_of_before)
+    )
+    assert str(now) == str(before)
 
 
 # ---- what a loop refuses ---------------------------------------------------------------

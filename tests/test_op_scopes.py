@@ -462,10 +462,17 @@ def test_every_region_of_the_step_has_a_name(built):
             "exit/norm/RMSNorm", "exit/exit_gate", "loss/lm_head",
         } <= parts
         assert not [p for p in parts if p.startswith(op_scopes.LOOP + "/")]
-        for name in ("exit/exit_gate", "loss/lm_head", "block/mlp/mlp_up"):
+        for name in ("exit/exit_gate", "block/mlp/mlp_up"):
             assert {
                 phase for part, phase, _, _ in scopes.values() if part == name
             } >= {"forward", "backward"}, name
+        # the head's product and its two gradients are made in one loop body
+        # beside the logits (``exits_cross_entropy``): the backward pass has
+        # nothing of the head left to make, or to make again
+        assert {
+            phase for part, phase, _, _ in scopes.values()
+            if part == "loss/lm_head"
+        } == {"forward"}
         # (the head is a module of the tree; a training step's product is
         # the loss's, which applies it a pass at a time)
         assert "lm_head" in state.params
