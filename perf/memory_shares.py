@@ -11,10 +11,16 @@ alive at the largest program's peak by the model's scopes
 allocator's figures.  It is read here after the window, once a ``run``, and
 kept in it; every figure is in GB of 1e9 bytes, as ``peak_hbm_gb`` is.
 
-``state_hbm_gb + step_temp_hbm_gb + the step's unaliased outputs and code +
-the other arrays alive + hbm_unexplained_gb`` is the allocator's
-``peak_bytes_in_use + peak_bytes_reserved``, the run's ``peak_hbm_gb``, by
-construction: :func:`account` has every term.
+``state_hbm_gb + what the step holds over its arguments at XLA's own peak +
+the step's code + the other arrays alive + hbm_unexplained_gb`` is the
+allocator's ``peak_bytes_in_use + peak_bytes_reserved``, the run's
+``peak_hbm_gb``, by construction: :func:`account` has every term.  The
+step's term is XLA's ``peak_memory_in_bytes - argument_size_in_bytes`` and
+not ``step_temp_hbm_gb``: ``temp_size_in_bytes`` counts what XLA put in the
+chip's other memory spaces too, 0.01-0.97 GB more than the chip reserves
+(PERF.md, PR 55), and a remainder taken from it is negative by construction.
+Taken from XLA's peak the remainder is the allocator's packing of the step's
+one allocation, and is not below nought.
 
 A reader returns None where the program has no such function (the parent of
 the PR that added it: these files are laid over its checkout too), where the
@@ -73,8 +79,7 @@ def _largest(run) -> dict | None:
 
 def account(run) -> dict | None:
     """The allocator's peak of the fullest device, term by term, in bytes:
-    ``peak`` is ``state + other_arrays + temp + outputs + code +
-    unexplained``."""
+    ``peak`` is ``state + other_arrays + step + code + unexplained``."""
     found, program = reading(run), _largest(run)
     if program is None or not found["allocator"]:
         return None
@@ -83,20 +88,20 @@ def account(run) -> dict | None:
         "state": found["state"]["total"],
         # batches placed and not yet retired, the last step's metrics
         "other_arrays": found["other_arrays"],
-        "temp": xla["temp"],
-        # what the step writes that is in place of no argument
-        "outputs": xla["output"] - xla["alias"],
+        # what is alive at XLA's own peak beside the arguments: temporaries
+        # in HBM and what the step writes in place of no argument
+        "step": xla["peak"] - xla["argument"],
         "code": xla["generated_code"],
     }
     peak = allocator["peak_bytes_in_use"] + allocator["peak_bytes_reserved"]
     return {
         **terms, "peak": peak, "unexplained": peak - sum(terms.values()),
-        # beside the sum, what says where a negative remainder comes from:
-        # the allocator reserves for the step what XLA's own peak holds over
-        # the arguments and a little packing, while XLA's ``temp`` counts
-        # what it put in the chip's other memory spaces too
+        # beside the sum: XLA's ``temp``, which counts the chip's other
+        # memory spaces too and is what ``step_temp_hbm_gb`` reads, the
+        # step's unaliased outputs, and what the allocator reserved
+        "temp": xla["temp"],
+        "outputs": xla["output"] - xla["alias"],
         "reserved": allocator["peak_bytes_reserved"],
-        "xla_peak_over_arguments": xla["peak"] - xla["argument"],
     }
 
 
@@ -142,6 +147,8 @@ def head_loss_at_peak_hbm_gb(run) -> float | None:
 
 
 def hbm_unexplained_gb(run) -> float | None:
-    """The allocator's peak less everything that has a name."""
+    """The allocator's peak less everything that has a name, the step by
+    XLA's ``peak_memory_in_bytes - argument_size_in_bytes`` (the module's
+    text says why not by ``temp``): the allocator's packing."""
     found = account(run)
     return None if found is None else found["unexplained"] / GB

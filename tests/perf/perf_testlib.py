@@ -23,6 +23,25 @@ TINY_RESIDENT_CELL = "tiny_lm_resident"
 # another model family: its record kind and FLOP arithmetic are files under
 # tests/perf too, found by the names its traffic and configuration files give
 TINY_FAMILY_CELL = "tiny_mnist_digits"
+# ``peak_hbm_gb``'s five per-layer metrics, which every cell reports
+HBM_READERS = (
+    "state_hbm_gb", "step_temp_hbm_gb", "residuals_at_peak_hbm_gb",
+    "head_loss_at_peak_hbm_gb", "hbm_unexplained_gb",
+)
+# ``trinity_mini_seq16384``'s eight, the last of the list up to PR 58
+SWA_READERS = (
+    "attention_kernels_time_share.swa", "window_attention_time_share.swa",
+    "swa_fwd_roofline.swa", "swa_dq_roofline.swa", "swa_dkv_roofline.swa",
+    "held_pair_share.swa", "router_load_max_over_mean.swa",
+    "expert_gmm_time_share.swa",
+)
+# ``lfm2_24b_a2b_seq4096x4``'s seven
+CONV_READERS = (
+    "short_conv_time_share.conv", "short_conv_fwd_roofline.conv",
+    "short_conv_bwd_roofline.conv", "conv_operator_share.scope_conv",
+    "held_pair_share.conv", "router_load_max_over_mean.conv",
+    "expert_gmm_time_share.conv",
+)
 # a layer no entry of BENCHMARK.json names
 NEW_LAYER = "rehearsal layer (tests/perf only)"
 
@@ -36,6 +55,18 @@ MANIFEST_COPY = "PERF_TESTS_MANIFEST_COPY"
 def repo_manifest() -> dict:
     with open(os.environ.get(MANIFEST_COPY) or os.path.join(ROOT, "BENCHMARK.json")) as f:
         return json.load(f)
+
+
+def stand_together_after(listed, names, earlier) -> bool:
+    """Whether ``names`` stand in ``listed`` (the names of ``per_layer``) one
+    after another in their own order, each of ``earlier`` before the first of
+    them.  Names only, no count and no scan by suffix: what a later PR puts
+    after them, whatever it is called, is held by nothing."""
+    names = list(names)
+    first = listed.index(names[0])
+    return listed[first:first + len(names)] == names and all(
+        listed.index(name) < first for name in earlier
+    )
 
 
 def manifest_with_tiny_cell() -> dict:

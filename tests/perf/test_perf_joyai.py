@@ -103,6 +103,7 @@ def reference_errors(module, loss_sys, grads_sys, params, buffers, features, lab
 TOLERANCE = {"float32": (1e-5, 2e-5), "bfloat16": (3e-3, 0.09)}
 
 
+@pytest.mark.compiles_a_model
 def test_reference_agrees_with_the_zoo_model_in_float32(float32_system):
     loss, grads, *rest = float32_system
     got = reference_errors(shipped_reference(), loss, grads, *rest[:-1])
@@ -115,6 +116,7 @@ def test_reference_agrees_with_the_zoo_model_in_float32(float32_system):
     assert max(got["by_block"].values()) <= 1e-4, got
 
 
+@pytest.mark.compiles_a_model
 def test_reference_agrees_with_the_zoo_model_in_bfloat16():
     system, params, buffers, features, labels, _ = tiny_joyai("bfloat16")
     loss, grads = jax.jit(jax.value_and_grad(system))(params)
@@ -178,6 +180,7 @@ FAULTS = {
 
 
 @pytest.mark.parametrize("fault", FAULTS)
+@pytest.mark.compiles_a_model
 def test_comparison_fails_on_wrong_mathematics(monkeypatch, float32_system, fault):
     """Each wrong term, in float32 where nothing else differs, is far outside
     the float32 agreement.  (``every_expert_held`` is the share moved to
@@ -193,6 +196,7 @@ def test_comparison_fails_on_wrong_mathematics(monkeypatch, float32_system, faul
     assert not (got["loss_err"] <= 100 * loss_limit and got["grad_err"] <= 100 * grad_limit), got
 
 
+@pytest.mark.compiles_a_model
 def test_control_in_fp8_fails(float32_system):
     """The reference in the program's place with its weights rounded through
     float8 (e4m3), the nearest precision below the bfloat16 the configuration
@@ -214,6 +218,7 @@ def test_control_in_fp8_fails(float32_system):
 # ---- the chip's share tied to the model ------------------------------------------
 
 
+@pytest.mark.compiles_a_model
 def test_sixteen_shares_of_sixteen_experts_add_up_to_the_whole_layer():
     """16 chips, 16 of 256 experts each (``experts_held`` / ``first_expert``),
     the shared expert counted once: the parts add up to what the uncut
@@ -485,6 +490,7 @@ def manifest_with_tiny_joyai() -> dict:
     return manifest
 
 
+@pytest.mark.compiles_a_model
 def test_cell_rehearsal_on_cpu(tmp_path, trace=1):
     """Two tiny layers and the module through ``perf/run.py --rehearse-cpu``
     (the traced run, which measures untraced first): the path driver, the

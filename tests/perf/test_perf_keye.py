@@ -115,6 +115,7 @@ def reference_errors(module, loss_sys, grads_sys, params, features, labels):
 TOLERANCE = {"float32": (1e-5, 2e-5), "bfloat16": (5e-3, 0.12)}
 
 
+@pytest.mark.compiles_a_model
 def test_reference_agrees_with_the_zoo_model_in_float32(float32_system):
     loss, grads, *rest = float32_system
     got = reference_errors(shipped_reference(), loss, grads, *rest)
@@ -126,6 +127,7 @@ def test_reference_agrees_with_the_zoo_model_in_float32(float32_system):
     assert max(got["by_block"].values()) <= 1e-4, got
 
 
+@pytest.mark.compiles_a_model
 def test_reference_agrees_with_the_zoo_model_in_bfloat16():
     system, params, features, labels = tiny_keye("bfloat16", index_topk=2 * TOPK)
     loss, grads = jax.jit(jax.value_and_grad(system))(params)
@@ -135,6 +137,7 @@ def test_reference_agrees_with_the_zoo_model_in_bfloat16():
     assert got["loss_err"] <= loss_limit and got["grad_err"] <= grad_limit, got
 
 
+@pytest.mark.compiles_a_model
 def test_positions_of_three_distinct_components_agree_with_the_reference():
     """The only place mRoPE differs from RoPE: the zoo model reads the
     records' ``positions`` and so does the reference; and they matter (the
@@ -195,6 +198,7 @@ FAULTS = {
 
 
 @pytest.mark.parametrize("fault", FAULTS)
+@pytest.mark.compiles_a_model
 def test_comparison_fails_on_wrong_mathematics(monkeypatch, fault):
     """Each wrong term, in float32 where nothing else differs, is far outside
     the float32 agreement.  ``mrope_sections_swapped`` is read on positions of
@@ -212,6 +216,7 @@ def test_comparison_fails_on_wrong_mathematics(monkeypatch, fault):
     ), got
 
 
+@pytest.mark.compiles_a_model
 def test_comparison_fails_when_the_indexers_input_is_not_detached(monkeypatch):
     """The reference with ``stop_gradient`` taken off the indexer's input: the
     main model's parameters would then receive the KL's gradient too."""
@@ -238,6 +243,7 @@ def test_comparison_fails_when_the_indexers_input_is_not_detached(monkeypatch):
     assert got["grad_err"] > 100 * TOLERANCE["float32"][1], got
 
 
+@pytest.mark.compiles_a_model
 def test_control_in_fp8_fails(float32_system):
     """The reference in the program's place with its weights rounded through
     float8 (e4m3), the nearest precision below the bfloat16 the configuration
@@ -257,6 +263,7 @@ def test_control_in_fp8_fails(float32_system):
 # ---- the selection -------------------------------------------------------------
 
 
+@pytest.mark.compiles_a_model
 def test_a_built_tie_at_the_last_place_goes_to_the_lower_index_in_both():
     """Index scores with exact ties across the ``topk``-th place (keys that
     are copies of one another score alike for every query): the kernel's
@@ -292,6 +299,7 @@ def test_a_built_tie_at_the_last_place_goes_to_the_lower_index_in_both():
 
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.compiles_a_model
 def test_the_programs_selection_is_the_references_on_the_programs_own_inputs(dtype):
     """What the chip comparison reads layer by layer: the program's mask (its
     ``selection``, kept by an apply that asks for ``intermediates``, through
@@ -330,6 +338,7 @@ def test_the_programs_selection_is_the_references_on_the_programs_own_inputs(dty
         assert min(own) >= 0.995 and own[1] > free[1] and free[1] < 0.99, (free, own)
 
 
+@pytest.mark.compiles_a_model
 def test_the_programs_counter_reads_the_keys_a_query_keeps():
     """``selection_stats``: ``sum_t min(t + 1, topk) / T`` keys a query in
     every layer (1,920.06 at 16,384 and 2,048), read on demand."""
@@ -354,6 +363,7 @@ def test_the_programs_counter_reads_the_keys_a_query_keeps():
 # ---- the two gradient paths ------------------------------------------------------
 
 
+@pytest.mark.compiles_a_model
 def test_the_two_gradient_paths_stay_apart():
     """The main model's parameters receive the gradient of the language-model
     loss and the balance loss alone (unchanged by the KL's weight), the
@@ -388,6 +398,7 @@ def test_the_two_gradient_paths_stay_apart():
 # ---- the chip's share tied to the model ------------------------------------------
 
 
+@pytest.mark.compiles_a_model
 def test_eight_shares_of_sixteen_experts_add_up_to_the_whole_layer():
     """8 chips, 16 of 128 experts each (``experts_held`` / ``first_expert``):
     the parts add up to what the uncut reference gives for the whole expert
@@ -755,6 +766,7 @@ def manifest_with_tiny_keye() -> dict:
     return manifest
 
 
+@pytest.mark.compiles_a_model
 def test_cell_rehearsal_on_cpu(tmp_path, trace=1):
     """Two tiny layers through ``perf/run.py --rehearse-cpu`` (the traced run,
     which measures untraced first): the path driver, the stacked dispatch, the

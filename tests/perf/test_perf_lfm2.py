@@ -19,7 +19,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from perf_testlib import ROOT, manifest_with_tiny_cell, repo_manifest
+from perf_testlib import (
+    CONV_READERS as OWN_READERS,
+    ROOT,
+    SWA_READERS,
+    manifest_with_tiny_cell,
+    repo_manifest,
+    stand_together_after,
+)
 
 from perf import manifest as manifest_lib, reference
 
@@ -109,6 +116,7 @@ def reference_errors(module, loss_sys, grads_sys, params, buffers, features, lab
 TOLERANCE = {"float32": (1e-5, 3e-5), "bfloat16": (5e-3, 0.15)}
 
 
+@pytest.mark.compiles_a_model
 def test_reference_agrees_with_the_zoo_model_in_float32(float32_system):
     loss, grads, *rest = float32_system
     got = reference_errors(shipped_reference(), loss, grads, *rest[:-1])
@@ -121,6 +129,7 @@ def test_reference_agrees_with_the_zoo_model_in_float32(float32_system):
     assert max(got["by_block"].values()) <= 1e-4, got
 
 
+@pytest.mark.compiles_a_model
 def test_reference_agrees_with_the_zoo_model_in_bfloat16():
     system, params, buffers, features, labels, _ = tiny_lfm2("bfloat16")
     loss, grads = jax.jit(jax.value_and_grad(system))(params)
@@ -201,6 +210,7 @@ FAULTS = {
 
 
 @pytest.mark.parametrize("fault", FAULTS)
+@pytest.mark.compiles_a_model
 def test_comparison_fails_on_wrong_mathematics(monkeypatch, float32_system, fault):
     """Each wrong term, in float32 where nothing else differs, is far outside
     the float32 agreement (a hundred times its limits at least)."""
@@ -213,6 +223,7 @@ def test_comparison_fails_on_wrong_mathematics(monkeypatch, float32_system, faul
     assert not (got["loss_err"] <= 100 * loss_limit and got["grad_err"] <= 100 * grad_limit), got
 
 
+@pytest.mark.compiles_a_model
 def test_the_divisors_epsilon_is_the_references_alone(monkeypatch):
     """HF adds 1e-6 to the sum the chosen scores are divided by.  The
     reference keeps it, as published; the program divides by the sum alone
@@ -240,6 +251,7 @@ def test_the_divisors_epsilon_is_the_references_alone(monkeypatch):
     assert 0 < moved < 1e-3 * loss_limit, moved
 
 
+@pytest.mark.compiles_a_model
 def test_control_in_fp8_fails(float32_system):
     """The reference in the program's place with its weights rounded through
     float8 (e4m3), the nearest precision below the bfloat16 the configuration
@@ -258,6 +270,7 @@ def test_control_in_fp8_fails(float32_system):
 # ---- the chip's share, and the tie, tied to the model ------------------------------
 
 
+@pytest.mark.compiles_a_model
 def test_four_shares_of_eight_experts_add_up_to_the_whole_layer():
     """4 chips, 2 of 8 experts each (``experts_held`` / ``first_expert``),
     sigmoid scores, a selection bias and no shared expert: nothing is
@@ -314,6 +327,7 @@ def test_four_shares_of_eight_experts_add_up_to_the_whole_layer():
     np.testing.assert_allclose(total, want, rtol=2e-5, atol=2e-6)
 
 
+@pytest.mark.compiles_a_model
 def test_the_tied_head_is_one_parameter_whose_gradient_is_the_sum_of_both_uses():
     from elasticdl_tpu.models import long_seq_transformer as zoo
 
@@ -473,26 +487,6 @@ def synthetic_run():
     }
 
 
-OWN_READERS = (
-    "short_conv_time_share.conv", "short_conv_fwd_roofline.conv",
-    "short_conv_bwd_roofline.conv", "conv_operator_share.scope_conv",
-    "held_pair_share.conv", "router_load_max_over_mean.conv",
-    "expert_gmm_time_share.conv",
-)
-
-
-def manifest_with_own_entries() -> dict:
-    """The repository's manifest with the entries of
-    ``perf/layer_metrics/conv_entries.json`` at the end of ``per_layer``,
-    where a ``benchmark`` PR puts them."""
-    manifest = copy.deepcopy(repo_manifest())
-    with open(os.path.join(ROOT, "perf", "layer_metrics", "conv_entries.json")) as f:
-        entries = json.load(f)["per_layer"]
-    have = {m["name"] for m in manifest["per_layer"]}
-    manifest["per_layer"] += [m for m in entries if m["name"] not in have]
-    return manifest
-
-
 def test_time_share_readers_on_a_synthetic_run():
     cell = manifest_lib.Cell(repo_manifest(), CELL)
     run = synthetic_run()
@@ -604,32 +598,33 @@ def test_cell_reports_the_lm_metrics_it_can():
     assert cell.reference().__name__.endswith("lfm2_moe")
 
 
-def test_the_cells_own_entries_wait_beside_their_readers():
+def test_the_cells_own_entries_stand_in_the_manifest():
     """The seven readers of what this configuration adds, each this cell's
-    alone and each moving its rate.  ``BENCHMARK.json`` does not list them
-    yet: tests/perf/test_perf_trinity.py holds trinity_mini_seq16384's eight
-    ``.swa`` entries to the end of ``per_layer``, and the driver takes a new
-    entry nowhere but there, so the entries wait as data beside the readers
-    for the ``benchmark`` PR that relaxes that pin (PERF.md section 7, "From
-    PR 48" (a)).  Held here: appended as they are they keep the manifest's
-    rules, and the cell then reports them through the files that are there."""
-    manifest = manifest_with_own_entries()
+    first and each moving its rate.  They waited as data beside their readers
+    (``conv_entries.json``) while tests/perf/test_perf_trinity.py held the
+    eight ``.swa`` entries to the end of ``per_layer``; PR 59 dropped that pin
+    and listed them, in the file's order, after the ``.swa`` entries.  Held
+    here: they keep the manifest's rules and the cell reports them through
+    the files that are there; a later PR may put further cells on their
+    lists and entries after them."""
+    manifest = repo_manifest()
     listed = [m["name"] for m in manifest["per_layer"]]
     assert len(set(listed)) == len(listed)
     own = [m for m in manifest["per_layer"] if m["name"] in OWN_READERS]
     assert tuple(m["name"] for m in own) == OWN_READERS
+    assert stand_together_after(listed, OWN_READERS, SWA_READERS)
     keys = ["name", "unit", "better", "source", "layer", "moves", "workloads"]
     assert all(list(m) == keys for m in own)
-    assert all(m["workloads"] == [CELL] for m in own)
+    assert all(m["workloads"][:1] == [CELL] for m in own)
     assert all(m["moves"] == "tokens_per_s_chip" for m in own)
     assert all(m["better"] in ("lower", "higher") for m in own)
     assert {m["source"] for m in own} == {"device_trace", "program_counter"}
     assert {m["name"]: m["unit"] for m in own if m["unit"] != "%"} == {
         "router_load_max_over_mean.conv": "x"
     }
-    # a layer is spelled as the accepted entries spell it, or is the new one
-    accepted = {m["layer"] for m in repo_manifest()["per_layer"]}
-    assert {m["layer"] for m in own} - accepted <= {"kernels (ops/short_conv.py)"}
+    # the experts' layer is spelled as the entries before these spell it
+    before = {m["layer"] for m in manifest["per_layer"][: listed.index(OWN_READERS[0])]}
+    assert {m["layer"] for m in own} - before == {"kernels (ops/short_conv.py)"}
     assert {m["layer"] for m in own} == {
         "kernels (ops/short_conv.py)", "experts (layers/moe.py, ops/grouped_matmul.py)"
     }
@@ -752,6 +747,7 @@ def manifest_with_tiny_lfm2() -> dict:
     return manifest
 
 
+@pytest.mark.compiles_a_model
 def test_cell_rehearsal_on_cpu(tmp_path, trace=1):
     """Six tiny parts through ``perf/run.py --rehearse-cpu`` (the traced run,
     which measures untraced first): the path driver, the stacked dispatch,

@@ -1,7 +1,7 @@
 """``peak_hbm_gb``'s five per-layer readers (``perf/memory_shares.py``, the
-files under ``perf/layer_metrics/`` named in ``memory_entries.json``): the
-entries that wait beside them obey the manifest's rules and every cell is
-read through a manifest that carries them; each reader gives its number on
+files under ``perf/layer_metrics/`` of their names): their entries, listed
+in ``BENCHMARK.json`` since PR 59, obey the manifest's rules and every cell
+that reports ``peak_hbm_gb`` reports them; each reader gives its number on
 a synthetic reading, None for a program without the function (the parent:
 these files are laid over its checkout too) and None for the split at the
 peak where the reading is off XLA's figure; the account adds up to the
@@ -10,38 +10,26 @@ find what the CPU can give.  The reading itself is
 ``tests/test_op_scopes.py``, ``tests/test_live_bytes.py`` and
 ``tests/test_memory.py``."""
 
-import copy
 import json
 import os
 
 import pytest
-from perf_testlib import ROOT, repo_manifest
+from perf_testlib import (
+    CONV_READERS as CONV,
+    HBM_READERS as READERS,
+    ROOT,
+    SWA_READERS,
+    repo_manifest,
+    stand_together_after,
+)
 
 from perf import manifest as manifest_lib
 from perf import memory_shares
 
-READERS = (
-    "state_hbm_gb", "step_temp_hbm_gb", "residuals_at_peak_hbm_gb",
-    "head_loss_at_peak_hbm_gb", "hbm_unexplained_gb",
-)
 LAYER = "SPMD step (parallel/distributed.py, trainer/step.py)"
-WAITING = ("conv_entries.json", "loop_entries.json", "memory_entries.json")
 GIB = 1 << 30
-
-
-def waiting(name):
-    with open(os.path.join(ROOT, "perf", "layer_metrics", name)) as f:
-        return json.load(f)["per_layer"]
-
-
-def manifest_with_waiting_entries() -> dict:
-    """``BENCHMARK.json`` with the three waiting lists appended in the order
-    a ``benchmark`` PR appends them."""
-    manifest = copy.deepcopy(repo_manifest())
-    have = {m["name"] for m in manifest["per_layer"]}
-    for name in WAITING:
-        manifest["per_layer"] += [m for m in waiting(name) if m["name"] not in have]
-    return manifest
+# what waited beside its readers up to PR 59, in the order it was listed in
+LOOP = ("exit_heads_share.loop", "loop_overhead_share.loop")
 
 
 def synthetic_run(ratio_in_range=True) -> dict:
@@ -88,19 +76,19 @@ EXPECTED = {
     "residuals_at_peak_hbm_gb": (GIB + GIB // 2 + GIB // 4) / 1e9,
     # the head's and the loss's own, every phase; never the parameters
     "head_loss_at_peak_hbm_gb": (GIB // 4 + 2 * (GIB // 8) + GIB // 16) / 1e9,
+    # the step by XLA's peak over its arguments, not by ``temp``
     "hbm_unexplained_gb": (
         6 * GIB + 400000
-        - (2 * GIB + 4) - 3 * 65536 - 3 * GIB - 512 - GIB // 8
+        - (2 * GIB + 4) - 3 * 65536 - (4 * GIB - 2 * GIB - 4096) - GIB // 8
     ) / 1e9,
 }
 
 
 @pytest.mark.parametrize("name", READERS)
-def test_an_entry_waits_beside_its_reader_and_obeys_the_manifests_rules(name):
-    """As ``test_perf_ouro.py`` holds ``loop_entries.json``: appended as it
-    is the entry keeps the manifest's rules, and every cell then reports it
-    through the file that is there."""
-    manifest = manifest_with_waiting_entries()
+def test_an_entry_stands_in_the_manifest_and_obeys_its_rules(name):
+    """The entry keeps the manifest's rules, and every cell that reports
+    ``peak_hbm_gb`` reports it through the file that is there."""
+    manifest = repo_manifest()
     listed = [m["name"] for m in manifest["per_layer"]]
     assert len(set(listed)) == len(listed)
     (entry,) = [m for m in manifest["per_layer"] if m["name"] == name]
@@ -115,9 +103,17 @@ def test_an_entry_waits_beside_its_reader_and_obeys_the_manifests_rules(name):
     moved = next(m for m in manifest["end_to_end"] if m["name"] == "peak_hbm_gb")
     assert (moved["unit"], moved["better"]) == (entry["unit"], entry["better"])
     assert entry["layer"] == LAYER
-    assert LAYER in {m["layer"] for m in repo_manifest()["per_layer"]}
-    # every cell reports peak_hbm_gb, and every cell can be read
-    assert entry["workloads"] == [w["name"] for w in manifest["workloads"]]
+    before = manifest["per_layer"][: listed.index(READERS[0])]
+    assert LAYER in {m["layer"] for m in before}
+    # the five lists are alike, name only cells of the manifest, and name
+    # EVERY cell that reports peak_hbm_gb: a cell added at the end of
+    # ``workloads`` goes at the end of these lists too
+    cells = [w["name"] for w in manifest["workloads"]]
+    five = [m["workloads"] for m in manifest["per_layer"] if m["name"] in READERS]
+    assert all(one == entry["workloads"] for one in five)
+    assert len(set(entry["workloads"])) == len(entry["workloads"])
+    assert set(entry["workloads"]) <= set(cells)
+    assert set(moved.get("workloads", cells)) <= set(entry["workloads"])
     for workload in entry["workloads"]:
         cell = manifest_lib.Cell(manifest, workload)
         assert name in {m["name"] for m in cell.metrics("per_layer")}
@@ -127,22 +123,18 @@ def test_an_entry_waits_beside_its_reader_and_obeys_the_manifests_rules(name):
     )
 
 
-def test_the_three_waiting_lists_are_appended_in_their_order():
-    manifest = manifest_with_waiting_entries()
-    listed = [m["name"] for m in manifest["per_layer"]]
-    if not set(READERS) & {m["name"] for m in repo_manifest()["per_layer"]}:
-        assert tuple(listed[-5:]) == READERS
-        assert listed[-7:-5] == [m["name"] for m in waiting("loop_entries.json")]
-    names = [{m["name"] for m in waiting(name)} for name in WAITING]
-    assert not names[0] & names[1] and not (names[0] | names[1]) & names[2]
-    assert names[2] == set(READERS)
-    with open(os.path.join(ROOT, "perf", "layer_metrics", WAITING[2])) as f:
-        assert set(json.load(f)) == {"what", "per_layer"}
+def test_the_fourteen_that_waited_stand_in_their_order():
+    """``conv_entries.json``'s seven, ``loop_entries.json``'s two and
+    ``memory_entries.json``'s five, one after another after the eight
+    ``.swa`` entries; what follows them is held by nothing."""
+    listed = [m["name"] for m in repo_manifest()["per_layer"]]
+    assert stand_together_after(listed, CONV + LOOP + READERS, SWA_READERS)
+    assert len(set(CONV + LOOP + READERS)) == 14
 
 
 @pytest.mark.parametrize("name", READERS)
 def test_a_reader_gives_its_number_on_a_synthetic_reading(name):
-    read = manifest_lib.Cell(manifest_with_waiting_entries(), "gpt2s_seq1024").reader(name)
+    read = manifest_lib.Cell(repo_manifest(), "gpt2s_seq1024").reader(name)
     assert read(synthetic_run()) == pytest.approx(EXPECTED[name], rel=1e-12)
 
 
@@ -152,7 +144,7 @@ def test_a_reader_returns_none_for_a_program_without_the_function(name, monkeypa
     from elasticdl_tpu.telemetry import memory
 
     monkeypatch.delattr(memory, "read_step_memory")
-    read = manifest_lib.Cell(manifest_with_waiting_entries(), "gpt2s_seq1024").reader(name)
+    read = manifest_lib.Cell(repo_manifest(), "gpt2s_seq1024").reader(name)
     run = {"cell": None}
     assert read(run) is None
     assert run["_step_memory"] is None  # asked once a run
@@ -160,7 +152,7 @@ def test_a_reader_returns_none_for_a_program_without_the_function(name, monkeypa
 
 @pytest.mark.parametrize("name", READERS)
 def test_a_reading_off_xlas_figure_gives_no_split_and_keeps_the_totals(name):
-    read = manifest_lib.Cell(manifest_with_waiting_entries(), "gpt2s_seq1024").reader(name)
+    read = manifest_lib.Cell(repo_manifest(), "gpt2s_seq1024").reader(name)
     value = read(synthetic_run(ratio_in_range=False))
     if name in ("residuals_at_peak_hbm_gb", "head_loss_at_peak_hbm_gb"):
         assert value is None
@@ -171,14 +163,16 @@ def test_a_reading_off_xlas_figure_gives_no_split_and_keeps_the_totals(name):
 def test_the_account_adds_up_to_the_allocators_peak_to_the_byte():
     run = synthetic_run()
     account = memory_shares.account(run)
-    terms = ("state", "other_arrays", "temp", "outputs", "code", "unexplained")
+    terms = ("state", "other_arrays", "step", "code", "unexplained")
     assert sum(account[term] for term in terms) == account["peak"]
+    assert account["unexplained"] >= 0  # the allocator's packing
     # the benchmark's peak_hbm_gb of that device (perf/run.py::describe_device)
     allocator = run["_step_memory"]["allocator"]
     assert account["peak"] == (
         allocator["peak_bytes_in_use"] + allocator["peak_bytes_reserved"]
     )
     assert account["temp"] == 3 * GIB  # the largest program's, not the newest's
+    assert account["step"] == 2 * GIB - 4096
     # without allocator figures (the CPU) there is nothing to account for
     run["_step_memory"]["allocator"] = {}
     assert memory_shares.account(run) is None

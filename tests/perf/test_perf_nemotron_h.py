@@ -104,6 +104,7 @@ def reference_errors(module, loss_sys, grads_sys, params, buffers, features, lab
 TOLERANCE = {"float32": (1e-5, 1e-5), "bfloat16": (2e-3, 0.09)}
 
 
+@pytest.mark.compiles_a_model
 def test_reference_agrees_with_the_zoo_model_in_float32(float32_system):
     loss, grads, *rest = float32_system
     got = reference_errors(shipped_reference(), loss, grads, *rest[:-1])
@@ -114,6 +115,7 @@ def test_reference_agrees_with_the_zoo_model_in_float32(float32_system):
     assert max(got["by_block"].values()) <= 1e-5, got
 
 
+@pytest.mark.compiles_a_model
 def test_reference_agrees_with_the_zoo_model_in_bfloat16():
     system, params, buffers, features, labels, _ = tiny_nemotron("bfloat16")
     loss, grads = jax.jit(jax.value_and_grad(system))(params)
@@ -194,6 +196,7 @@ FAULTS = {
 
 
 @pytest.mark.parametrize("fault", FAULTS)
+@pytest.mark.compiles_a_model
 def test_comparison_fails_on_wrong_mathematics(monkeypatch, float32_system, fault):
     """Each wrong term, in float32 where nothing else differs, is outside the
     bf16 limits and four orders over the float32 agreement.  (``every_expert_
@@ -208,6 +211,7 @@ def test_comparison_fails_on_wrong_mathematics(monkeypatch, float32_system, faul
     assert not got["grad_err"] <= 0.05, (fault, got)  # a NaN is not correct either
 
 
+@pytest.mark.compiles_a_model
 def test_control_in_fp8_fails(float32_system):
     """The reference in the program's place with its weights rounded through
     float8 (e4m3), the nearest precision below the bfloat16 the configuration
@@ -229,6 +233,7 @@ def test_control_in_fp8_fails(float32_system):
 # ---- the chip's share tied to the model ------------------------------------------
 
 
+@pytest.mark.compiles_a_model
 def test_sixteen_shares_of_eight_experts_add_up_to_the_whole_layer():
     """16 chips, 8 of 128 experts each (``experts_held`` / ``first_expert``),
     the shared expert counted once: the parts add up to what the uncut
@@ -282,6 +287,7 @@ def test_sixteen_shares_of_eight_experts_add_up_to_the_whole_layer():
     np.testing.assert_allclose(total + shared_part, want, rtol=2e-5, atol=2e-6)
 
 
+@pytest.mark.compiles_a_model
 def test_eight_vocabulary_slices_give_the_whole_heads_columns():
     """A chip's head is rows ``[i V/8, (i+1) V/8)`` of the vocabulary: its
     logits are those columns of the whole head's, so the eight slices side by
@@ -313,6 +319,7 @@ def test_eight_vocabulary_slices_give_the_whole_heads_columns():
 # ---- arithmetic -----------------------------------------------------------------
 
 
+@pytest.mark.compiles_a_model
 def test_flops_and_parameters_come_from_the_published_shapes():
     cell = manifest_lib.Cell(repo_manifest(), CELL)
     per_token = {k: v / 8192 for k, v in cell.flops_per_record().items()}
@@ -571,6 +578,7 @@ def manifest_with_tiny_nemotron() -> dict:
     return manifest
 
 
+@pytest.mark.compiles_a_model
 def test_cell_rehearsal_on_cpu(tmp_path, trace=1):
     """Five tiny layers of the hybrid stack through ``perf/run.py
     --rehearse-cpu`` (the traced run, which measures untraced first): the path

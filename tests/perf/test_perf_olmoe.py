@@ -110,6 +110,7 @@ TOLERANCE = {"float32": (1e-5, 1e-5), "bfloat16": (1e-3, 0.08)}
     ],
     ids=["float32", "bfloat16", "float32_top8_of_64", "float32_norm_topk_prob"],
 )
+@pytest.mark.compiles_a_model
 def test_olmoe_reference_agrees_with_the_zoo_model(dtype, shape):
     system, params, features, labels = tiny_olmoe(dtype, **shape)
     got = compared(
@@ -146,6 +147,7 @@ FAULTS = {
 
 
 @pytest.mark.parametrize("fault", FAULTS)
+@pytest.mark.compiles_a_model
 def test_olmoe_comparison_fails_on_wrong_mathematics(monkeypatch, fault):
     """Each wrong term, in float32 where nothing else differs, is outside the
     bf16 limits (measured: the eighth expert dropped moves the loss 2.9e-3
@@ -166,6 +168,7 @@ def test_olmoe_comparison_fails_on_wrong_mathematics(monkeypatch, fault):
     assert got["grad_err"] > 0.05, (fault, got)
 
 
+@pytest.mark.compiles_a_model
 def test_olmoe_control_in_fp8_fails():
     """The reference in the program's place with its weights rounded through
     float8 (e4m3), the nearest precision below the bfloat16 the
@@ -357,6 +360,7 @@ def test_new_cell_reports_every_lm_metric_but_the_collective_one():
 
 
 @pytest.mark.parametrize("trace", [0, 1])
+@pytest.mark.compiles_a_model
 def test_olmoe_cell_rehearsal_on_cpu(tmp_path, trace):
     """One tiny layer of OLMoE's block through ``perf/run.py --rehearse-cpu``:
     the path driver, the stacked dispatch, the expert kernels interpreted,

@@ -9,7 +9,6 @@ comparison with the reference included.  The model against the reference at
 tiny sizes is ``tests/test_looped_lm.py``; the compiled step's scopes are
 ``tests/test_op_scopes.py`` (family ``looped_stack``)."""
 
-import copy
 import json
 import os
 import subprocess
@@ -19,7 +18,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from perf_testlib import ROOT, manifest_with_tiny_cell, repo_manifest
+from perf_testlib import (
+    CONV_READERS,
+    ROOT,
+    manifest_with_tiny_cell,
+    repo_manifest,
+    stand_together_after,
+)
 
 from perf import manifest as manifest_lib
 
@@ -31,17 +36,6 @@ LAYER = "looped stack (models/long_seq_transformer.py)"
 
 def cell(manifest=None):
     return manifest_lib.Cell(manifest or repo_manifest(), CELL)
-
-
-def manifest_with_own_entries(manifest=None) -> dict:
-    """A manifest with the entries of ``perf/layer_metrics/loop_entries.json``
-    at the end of ``per_layer``, where a ``benchmark`` PR puts them."""
-    manifest = copy.deepcopy(manifest or repo_manifest())
-    with open(os.path.join(ROOT, "perf", "layer_metrics", "loop_entries.json")) as f:
-        entries = json.load(f)["per_layer"]
-    have = {m["name"] for m in manifest["per_layer"]}
-    manifest["per_layer"] += [m for m in entries if m["name"] not in have]
-    return manifest
 
 
 # ---- the configuration ---------------------------------------------------------------
@@ -230,7 +224,7 @@ def synthetic_run():
 
 
 def test_share_readers_on_a_synthetic_run():
-    read = {name: cell(manifest_with_own_entries()).reader(name) for name in OWN_READERS}
+    read = {name: cell().reader(name) for name in OWN_READERS}
     run = synthetic_run()
     # the exits' norm and gate, the head's three products, the loss; not the
     # optimizer's update of the head
@@ -280,31 +274,28 @@ def test_cell_reports_the_lm_metrics_it_can():
     assert config["reduced"] == this.config["reduced"]
     assert config["source"] == this.config["source"] and len(config["why"]) <= 200
     # at most a quarter of the cells, rounded down, on four chips
-    assert sum(w["chips"] == 4 for w in manifest["workloads"]) == 1
+    four = sum(w["chips"] == 4 for w in manifest["workloads"])
+    assert four <= max(1, len(manifest["workloads"]) // 4)
 
 
-def test_the_cells_own_entries_wait_beside_their_readers():
+def test_the_cells_own_entries_stand_in_the_manifest():
     """The two readers of what this configuration adds, each this cell's
-    alone and each a share of the step's device time, so moving its rate
+    first and each a share of the step's device time, so moving its rate
     (the passes a token takes is the program's counter and no metric: every
-    pass runs in training whatever the gate says).  ``BENCHMARK.json`` does not list them
-    yet: tests/perf/test_perf_trinity.py holds trinity_mini_seq16384's eight
-    ``.swa`` entries to the end of ``per_layer``, and the driver takes a new
-    entry nowhere but there, so the entries wait as data beside the readers,
-    after ``conv_entries.json``'s seven, for the ``benchmark`` PR that relaxes
-    that pin (PERF.md section 7, "From PR 53").  Held here: appended as they
-    are they keep the manifest's rules, and the cell then reports them
-    through the files that are there."""
-    manifest = manifest_with_own_entries()
+    pass runs in training whatever the gate says).  They waited as data
+    beside their readers (``loop_entries.json``) until PR 59 listed them,
+    after ``lfm2_24b_a2b_seq4096x4``'s seven ``.conv`` entries.  Held here:
+    they keep the manifest's rules and the cell reports them through the
+    files that are there; what follows them in the list is held by nothing."""
+    manifest = repo_manifest()
     listed = [m["name"] for m in manifest["per_layer"]]
     assert len(set(listed)) == len(listed)
     own = [m for m in manifest["per_layer"] if m["name"] in OWN_READERS]
     assert tuple(m["name"] for m in own) == OWN_READERS
-    if not set(OWN_READERS) & {m["name"] for m in repo_manifest()["per_layer"]}:
-        assert listed[-2:] == list(OWN_READERS)
+    assert stand_together_after(listed, OWN_READERS, CONV_READERS)
     keys = ["name", "unit", "better", "source", "layer", "moves", "workloads"]
     assert all(list(m) == keys for m in own)
-    assert all(m["workloads"] == [CELL] for m in own)
+    assert all(m["workloads"][:1] == [CELL] for m in own)
     assert all(m["moves"] == "tokens_per_s_chip" for m in own)
     assert all(m["better"] in ("lower", "higher") for m in own)
     assert [m["source"] for m in own] == ["device_trace", "device_trace"]
@@ -315,17 +306,13 @@ def test_the_cells_own_entries_wait_beside_their_readers():
     assert set(OWN_READERS) <= {m["name"] for m in this.metrics("per_layer")}
     for name in OWN_READERS:
         assert callable(this.reader(name)), name
-    # the two waiting lists do not collide
-    with open(os.path.join(ROOT, "perf", "layer_metrics", "conv_entries.json")) as f:
-        conv = {m["name"] for m in json.load(f)["per_layer"]}
-    assert not conv & set(OWN_READERS)
 
 
 # ---- the cell's control flow on the CPU ---------------------------------------
 
 
 def manifest_with_tiny_ouro() -> dict:
-    manifest = manifest_with_own_entries(manifest_with_tiny_cell())
+    manifest = manifest_with_tiny_cell()
     manifest["configs"].append({
         "name": "tiny_ouro",
         "source": "none: CPU rehearsal of the harness only",
@@ -343,6 +330,7 @@ def manifest_with_tiny_ouro() -> dict:
     return manifest
 
 
+@pytest.mark.compiles_a_model
 def test_cell_rehearsal_on_cpu(tmp_path):
     """Two tiny layers four times through ``perf/run.py --rehearse-cpu`` (the
     traced run, which measures untraced first): the path driver, the stacked

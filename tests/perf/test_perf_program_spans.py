@@ -287,6 +287,7 @@ def test_profile_window_spans_go_on_the_traces_clock(tmp_path):
     assert reduced["busy_s"] == pytest.approx(8 * 900_000 / 1e9)
 
 
+@pytest.mark.compiles_a_model
 def test_rehearsal_still_passes_with_the_new_entries(tmp_path):
     """``--rehearse-cpu`` of the MNIST rehearsal cell against the manifest
     as this PR leaves it (the new ``per_layer`` entries present)."""

@@ -80,6 +80,7 @@ LM_TOLERANCE = {"float32": (1e-5, 1e-5), "bfloat16": (1e-3, 0.05)}
 
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.compiles_a_model
 def test_lm_reference_agrees_with_the_zoo_model(dtype):
     system, params, features, labels = tiny_lm(dtype)
     got = compared(system, load(TINY_CELL, "transformer_lm"), params, features, labels)
@@ -91,6 +92,7 @@ def test_lm_reference_agrees_with_the_zoo_model(dtype):
     assert max(got["by_block"].values()) <= 2 * grad_limit
 
 
+@pytest.mark.compiles_a_model
 def test_lm_reference_in_blocks_is_the_plain_one(monkeypatch):
     """Rows of 16 at a context of 64: four blocks of attention rows and of
     the head, each recomputed in the backward pass, against the same file
@@ -109,6 +111,7 @@ def test_lm_reference_in_blocks_is_the_plain_one(monkeypatch):
 
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.compiles_a_model
 def test_lm_comparison_fails_when_the_causal_mask_is_dropped(monkeypatch, dtype):
     system, params, features, labels = tiny_lm(dtype)
     module = load(TINY_CELL, "transformer_lm")
@@ -121,6 +124,7 @@ def test_lm_comparison_fails_when_the_causal_mask_is_dropped(monkeypatch, dtype)
     assert not (got["loss_err"] <= loss_limit and got["grad_err"] <= grad_limit)
 
 
+@pytest.mark.compiles_a_model
 def test_lm_control_in_fp8_fails(monkeypatch):
     """The contract's control at a size a test can hold: the reference put in
     the program's place with its weights rounded through float8 (e4m3), the
@@ -193,6 +197,7 @@ RESNET_TOLERANCE = {
 
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.compiles_a_model
 def test_resnet_reference_agrees_with_the_zoo_model(dtype):
     loss_limit, grad_limit, fc_limit, last_scale = RESNET_TOLERANCE[dtype]
     system, params, features, labels = small_resnet(dtype, last_scale)
@@ -206,6 +211,7 @@ def test_resnet_reference_agrees_with_the_zoo_model(dtype):
 
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.compiles_a_model
 def test_resnet_comparison_fails_with_batch_norm_in_inference_mode(monkeypatch, dtype):
     loss_limit, _, fc_limit, last_scale = RESNET_TOLERANCE[dtype]
     system, params, features, labels = small_resnet(dtype, last_scale)
@@ -226,6 +232,7 @@ def test_resnet_comparison_fails_with_batch_norm_in_inference_mode(monkeypatch, 
     "activation,low,high",
     [("relu", 0.01, 0.5), ("gelu", 0.0, 0.005)],
 )
+@pytest.mark.compiles_a_model
 def test_resnet_gradient_noise_is_the_relu_kink(monkeypatch, activation, low, high):
     """Why no limit holds the bfloat16 ResNet-50's gradient.  The reference
     against itself with one float32 rounding difference (BatchNorm's variance

@@ -50,6 +50,7 @@ def rehearse(tmp_path, trace: int, seed: int, cell: str = TINY_CELL, extra=()):
         (0, 2**31 + 3, TINY_FAMILY_CELL),
     ],
 )
+@pytest.mark.compiles_a_model
 def test_rehearsal_on_cpu(tmp_path, trace, seed, cell):
     info, result = rehearse(tmp_path, trace, seed, cell)
     assert set(result) >= {"correct", "attempted", "failed", "metrics", "device"}
@@ -96,7 +97,10 @@ def test_without_the_program_the_command_fails_and_prints_no_result(tmp_path):
     import shutil
 
     shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), tmp_path)
-    for path in manifest_with_tiny_cell()["paths"]:
+    # the paths of the file that is copied, whatever manifest these tests read
+    with open(tmp_path / "BENCHMARK.json") as f:
+        paths = json.load(f)["paths"]
+    for path in paths:
         shutil.copytree(
             os.path.join(ROOT, path), tmp_path / path,
             ignore=shutil.ignore_patterns("__pycache__", ".data"),
@@ -128,6 +132,7 @@ sys.exit(perf.run.main())
 '''
 
 
+@pytest.mark.compiles_a_model
 def test_a_run_whose_model_is_broken_underneath_is_not_correct(tmp_path):
     """The whole of a traced run but the look for a chip, with the model's
     answer altered under the harness: the job still trains (its loss falls,
@@ -168,6 +173,7 @@ def test_a_run_whose_model_is_broken_underneath_is_not_correct(tmp_path):
     assert done.stderr.strip().splitlines()[-1] == said[-1]
 
 
+@pytest.mark.compiles_a_model
 def test_the_control_through_the_harness_is_not_correct(tmp_path):
     """``--control``: a whole traced rehearsal whose comparison puts the plain
     reference, its weights rounded through ``float8_e4m3fn``, in the

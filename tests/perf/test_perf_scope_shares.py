@@ -182,6 +182,7 @@ def test_the_manifest_holds_the_fourteen_and_stays_small():
     assert sorted(n for n in names if n in NEW) == sorted(NEW)
 
 
+@pytest.mark.compiles_a_model
 def test_the_readers_on_a_real_trainers_map():
     """A tiny model's real compiled step on the CPU: each new metric's
     reader, found by name as the harness finds it, reads a number from the
@@ -228,6 +229,7 @@ def manifest_with_the_new_metrics_in_the_tiny_cell():
     return manifest
 
 
+@pytest.mark.compiles_a_model
 def test_a_rehearsal_of_a_cell_that_lists_them_passes_as_before(tmp_path):
     """The harness reports per-layer metrics only from a chip
     (``tests/perf/test_perf_rehearsal.py``): the traced rehearsal of a cell
