@@ -119,9 +119,10 @@ class MultiHeadSelfAttention(nn.Module):
     # in it; parameters stay in param_dtype (f32) — mixed precision
     dtype: Any = None
     use_bias: bool = True
-    # > 0: rotary positions on q and k with this base (``rope``); 0: the
+    # the rule the rotary positions turn q and k by (``rope``): a base > 0,
+    # the power law's theta, or an ``ops/rotary.py::Yarn``; 0: none, the
     # model adds its positions at the embedding
-    rope_theta: float = 0.0
+    rope_theta: Any = 0.0
     # an RMSNorm with a learned scale over the whole q and k projections,
     # before the split into heads (OLMoE: ``q_norm(q_proj(x))``)
     qk_norm: bool = False
@@ -574,9 +575,6 @@ class TransformerBlock(nn.Module):
     mlp: str = "gelu"
     mlp_width: int = 0  # 0: mlp_ratio x the embedding
     mlp_ratio: int = 4
-    # False: rotary positions in the window parts alone, none in the ``*``
-    # parts of a stack that has both (AFMoE)
-    full_attention_rope: bool = True
     # MultiHeadSelfAttention's fields (``window``: the ``w`` layers' alone)
     attention_fields: Any = ()
     # LatentSelfAttention's; given, the attention part is the latent mixer
@@ -637,8 +635,6 @@ class TransformerBlock(nn.Module):
                 raise ValueError("a layer of kind 'w' needs a window")
         else:
             fields["window"] = 0
-            if not self.full_attention_rope:
-                fields["rope_theta"] = 0.0
         return MultiHeadSelfAttention(
             decode=self.decode, max_decode_len=self.max_decode_len,
             use_bias=self.use_bias, **shared, **fields,
@@ -709,16 +705,16 @@ def _rope_takes_kernel(x, interleave: bool) -> bool:
     )
 
 
-def rope(
-    x, positions, theta: float, interleave: bool = False, sections=()
-):
+def rope(x, positions, rule, interleave: bool = False, sections=()):
     """Rotary positions (Su et al. 2021) over the whole of ``x``'s last
     axis (hand it the slice of the head that rotates): ``x`` (batch, seq,
-    heads, d), ``positions`` (seq,).  Pair ``i`` turns by ``positions *
-    theta^(-2i/d)``; the pairs are ``(x_i, x_{i+d/2})``, the rotate-half
-    convention of HF ``apply_rotary_pos_emb``, or with ``interleave`` the
-    adjacent ``(x_2i, x_2i+1)`` of the original and of ``rope_interleave``.
-    Computed in float32.
+    heads, d), ``positions`` (seq,).  ``rule`` a float, the base ``theta``:
+    pair ``i`` turns by ``positions * theta^(-2i/d)``; a
+    ``ops/rotary.py::Yarn``: by its blended frequencies, cos and sin scaled
+    (docs/designs/yarn_rope.md).  The pairs are ``(x_i, x_{i+d/2})``, the
+    rotate-half convention of HF ``apply_rotary_pos_emb``, or with
+    ``interleave`` the adjacent ``(x_2i, x_2i+1)`` of the original and of
+    ``rope_interleave``.  Computed in float32.
 
     ``positions`` (batch, components, seq) with ``sections`` (Qwen2-VL's
     multimodal RoPE): frequency ``i`` takes its angle from the component
@@ -734,13 +730,13 @@ def rope(
     (``interleave``, a 64-wide head, a decode step, a small model, a
     sequence sharded over ``sp``) is :func:`rope_plain`."""
     if not _rope_takes_kernel(x, interleave):
-        return rope_plain(x, positions, theta, interleave, sections)
+        return rope_plain(x, positions, rule, interleave, sections)
     # one pass of ops/rotary.py's kernel over the folded form the attention
     # kernels take: the two transposes are layouts for XLA to assign
     by_batch = positions.ndim == 3
     out = on_mesh.over_batch(
         lambda x, positions, interpret: rotary_ops.rotate_half(
-            x, positions, theta, tuple(sections), interpret
+            x, positions, rule, tuple(sections), interpret
         ),
         (x.transpose(0, 2, 1, 3),) + ((positions,) if by_batch else ()),
         () if by_batch else (positions,),
@@ -748,21 +744,20 @@ def rope(
     return out.transpose(0, 2, 1, 3)
 
 
-def rope_plain(
-    x, positions, theta: float, interleave: bool = False, sections=()
-):
+def rope_plain(x, positions, rule, interleave: bool = False, sections=()):
     """:func:`rope` in plain ``jnp``, for every shape."""
     half = x.shape[-1] // 2
-    angles = rotary_ops.angles(positions, theta, half, sections)
+    angles = rotary_ops.angles(positions, rule, half, sections)
     if positions.ndim == 3:
         angles = angles[:, :, None, :]
-        cos, sin = jnp.cos(angles), jnp.sin(angles)
+        cos, sin = rotary_ops.scaled(rule, jnp.cos(angles), jnp.sin(angles))
         x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
         return jnp.concatenate(
             [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
         ).astype(x.dtype)
-    cos = jnp.cos(angles)[None, :, None, :]
-    sin = jnp.sin(angles)[None, :, None, :]
+    cos, sin = rotary_ops.scaled(
+        rule, jnp.cos(angles)[None, :, None, :], jnp.sin(angles)[None, :, None, :]
+    )
     if interleave:
         pairs = x.astype(jnp.float32).reshape(*x.shape[:-1], half, 2)
         x1, x2 = pairs[..., 0], pairs[..., 1]

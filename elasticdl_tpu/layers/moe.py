@@ -26,6 +26,13 @@ expert that every token passes.
 a layer whose experts lie on several.  Their part of the result is computed
 through the path ``ep`` uses (a pair of another expert gets no row); what the
 absent experts would add is left out, and no code stands in for their chips.
+The gradient of the router's logits is then a partial sum too: only the held
+experts' pairs add to it, so a step taken along it sends the pairs to the
+held experts, at a speed Adam's rate alone sets (PERF.md section 7, From PR
+61 (c)).  A router with a selection bias is held to the mean load all the
+same; one without takes ``router_trains=False``: the logits enter the step
+as constants, the router's weights get a zero gradient, and the routing
+stays where the seeded weights put it.
 
 Dispatch has static shapes at any imbalance
 (``ops/grouped_matmul.py``): a stable sort of the pairs by expert, each
@@ -519,6 +526,7 @@ class MoEMLP(nn.Module):
     shared_width: int = 0  # > 0: one expert of this width on every token
     experts_held: int = 0  # 0: all of them
     first_expert: int = 0
+    router_trains: bool = True  # False: the logits are constants of the step
 
     @nn.compact
     def __call__(self, x, training: bool = False):
@@ -568,6 +576,8 @@ class MoEMLP(nn.Module):
             self.num_experts, use_bias=False, name="router",
             precision=jax.lax.Precision.HIGHEST,
         )(x.astype(jnp.float32))
+        if not self.router_trains:
+            logits = jax.lax.stop_gradient(logits)
         if self.scoring == "sigmoid":
             scores = jax.nn.sigmoid(logits)
             probs = scores / jnp.sum(scores, axis=-1, keepdims=True)

@@ -32,6 +32,7 @@ from elasticdl_tpu.layers.attention import (
     sinusoidal_positions,
 )
 from elasticdl_tpu.layers.recompute import remat_with_findings
+from elasticdl_tpu.ops import rotary
 from elasticdl_tpu.telemetry.router_load import (
     LOSS_OBSERVED,
     LOSS_PARTS,
@@ -100,6 +101,7 @@ PART_FIELDS = {
         "shared_expert_width": "shared_width",
         "experts_held": "experts_held",
         "first_expert": "first_expert",
+        "router_trains": "router_trains",
     },
     "mamba": {
         "mamba_heads": "num_heads",
@@ -110,6 +112,10 @@ PART_FIELDS = {
         "ssd_chunk": "chunk",
     },
 }
+
+# the kinds of attention layer a published ``rope_parameters`` names a rule
+# for: every earlier key (``*`` and the plain block), or a window (``w``)
+ROPE_KINDS = ("full_attention", "sliding_attention")
 
 
 class TransformerLM(nn.Module):
@@ -159,6 +165,9 @@ class TransformerLM(nn.Module):
     shared_expert_width: int = 0
     experts_held: int = 0  # 0: all; else this deployment's share
     first_expert: int = 0
+    # False: a cut's routers take no step (their gradient lacks the absent
+    # experts' part and would send every pair to the held ones)
+    router_trains: bool = True
     # the Mamba-2 layers' (layers/mamba.py::Mamba2Mixer)
     mamba_heads: int = 0
     mamba_head_dim: int = 64
@@ -201,6 +210,15 @@ class TransformerLM(nn.Module):
     # parts alone and the ``*`` parts get no position signal
     sliding_window: int = 0
     full_attention_rope: bool = True
+    # the rotary rule by the attention layer's kind (``ROPE_KINDS``), as a
+    # published ``rope_parameters`` gives it: {"sliding_attention":
+    # {"rope_type": "default", "rope_theta": ...}, "full_attention":
+    # {"rope_type": "yarn", "rope_theta", "factor",
+    # "original_max_position_embeddings", "beta_fast", "beta_slow",
+    # "attention_factor"}} (``ops/rotary.py::rule_of``;
+    # docs/designs/yarn_rope.md); a kind it does not name, and every layer
+    # where it is None, turns by ``rope_theta`` (``_rope_rules``)
+    rope_parameters: Any = None
     # the attention parts' output times sigmoid(gate(x)) before the output
     # projection; a second norm on every part's output (x + norm(part(
     # norm(x)))); the embedding times sqrt(embed_dim) (muP)
@@ -221,6 +239,33 @@ class TransformerLM(nn.Module):
     # pass's logits
     loop_steps: int = 1
     exit_entropy_weight: float = 0.1
+
+    def _rope_rules(self) -> dict:
+        """What each kind of attention layer turns its positions by: a
+        base, an ``ops/rotary.py::Yarn``, or 0.0 for no rotation."""
+        full, _ = ROPE_KINDS
+        stated = {
+            kind: rotary.rule_of(group)
+            for kind, group in (self.rope_parameters or {}).items()
+        }
+        if set(stated) - set(ROPE_KINDS):
+            raise ValueError(
+                f"rope_parameters names {sorted(stated)}; valid: {ROPE_KINDS}"
+            )
+        if stated and self.positions != "rope":
+            raise ValueError(
+                f"rope_parameters with positions {self.positions!r}: its "
+                "rules turn rotary positions alone"
+            )
+        if full in stated and not self.full_attention_rope:
+            raise ValueError(
+                "rope_parameters gives the full layers a rule and "
+                "full_attention_rope False gives them none"
+            )
+        if self.positions != "rope":
+            return dict.fromkeys(ROPE_KINDS, 0.0)
+        rules = {kind: stated.get(kind, self.rope_theta) for kind in ROPE_KINDS}
+        return rules if self.full_attention_rope else {**rules, full: 0.0}
 
     @nn.compact
     def __call__(self, features, training: bool = False):
@@ -304,8 +349,15 @@ class TransformerLM(nn.Module):
             )
             for part, fields in PART_FIELDS.items()
         }
+        rope_rules = self._rope_rules()
 
         def block(kind, name):
+            # an attention part turns its positions by its kind's rule
+            rule = rope_rules[ROPE_KINDS[kind == "w"]]
+            attention = tuple(
+                (field, rule if field == "rope_theta" else value)
+                for field, value in groups["attention_fields"]
+            )
             return block_class(
                 kind=kind,
                 causal=True,
@@ -319,8 +371,7 @@ class TransformerLM(nn.Module):
                 use_bias=self.use_bias,
                 mlp=self.mlp,
                 mlp_width=self.mlp_width,
-                full_attention_rope=self.full_attention_rope,
-                **groups,
+                **{**groups, "attention_fields": attention},
                 name=name,
             )
 

@@ -34,6 +34,13 @@ table block is fetched once a row tile.  The backward is the same body with
 the tables are made again where the backward wants them (XLA shares one pair
 a step where the positions are the tokens' indices), never kept a layer.
 
+**The rule.**  What turns a position into an angle is a rule (:func:`rates`):
+a float is the power law's base, ``theta^(-i / half)``; a :class:`Yarn` blends
+that with the same frequencies over ``factor`` by a ramp over the pairs and
+scales cos and sin (docs/designs/yarn_rope.md).  A rule changes the tables and
+nothing else: the kernel is the one kernel, and the ``custom_vjp`` carries the
+rule where it carried the base, as a static argument.
+
 The names below are the device trace's op names; none starts with ``flash_``,
 ``swa_``, ``dsa_``, ``expert_gmm`` or ``ssd_``, which ``perf/`` reads as those
 kernels.
@@ -42,6 +49,8 @@ kernels.
 from __future__ import annotations
 
 import functools
+import math
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -78,14 +87,96 @@ def rotate_tile(shape, interleave: bool = False):
     return _ROWS, held
 
 
-def angles(positions, theta: float, half: int, sections=()):
-    """Float32 ``positions * theta^(-i / half)`` for the ``half`` pairs of a
-    head: ``(tokens, half)`` for ``positions`` (tokens,); ``(batch, tokens,
+class Yarn(NamedTuple):
+    """YaRN (Peng et al. 2023, arXiv:2309.00071) as HF
+    ``_compute_yarn_parameters`` reads a ``rope_parameters`` group of
+    ``rope_type: yarn``: the pairs that turn more than ``beta_fast`` times
+    over ``original_length`` positions keep ``theta``'s frequency, those that
+    turn less than ``beta_slow`` times take it over ``factor``, a linear ramp
+    over the pairs between; cos and sin times ``attention_factor`` (None:
+    ``0.1 ln(factor) + 1``)."""
+
+    theta: float
+    factor: float
+    original_length: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float | None = None
+
+
+# a ``rope_type: yarn`` group's keys by :class:`Yarn`'s fields
+_YARN_KEYS = {
+    "theta": "rope_theta",
+    "factor": "factor",
+    "original_length": "original_max_position_embeddings",
+    "beta_fast": "beta_fast",
+    "beta_slow": "beta_slow",
+    "attention_factor": "attention_factor",
+}
+
+
+def rule_of(parameters):
+    """The rule a published ``rope_parameters`` group states (``rope_type``,
+    ``rope_theta`` and, for ``yarn``, its five numbers, those it leaves out
+    or null at :class:`Yarn`'s defaults)."""
+    kind = parameters.get("rope_type", "default")
+    if kind == "default":
+        return float(parameters["rope_theta"])
+    if kind != "yarn":
+        raise ValueError(f"unknown rope_type {kind!r}; valid: default, yarn")
+    return Yarn(**{
+        field: float(parameters[key])
+        for field, key in _YARN_KEYS.items()
+        if parameters.get(key) is not None
+    })
+
+
+def scaled(rule, *arrays):
+    """cos and sin times what the rule multiplies them by (a base: nothing,
+    the arrays themselves)."""
+    if not isinstance(rule, Yarn):
+        return arrays
+    by = rule.attention_factor
+    if by is None:
+        by = 0.1 * math.log(rule.factor) + 1.0
+    return tuple(x * by for x in arrays)
+
+
+def rates(rule, half: int):
+    """The float32 angle a position turns pair ``i`` of the ``half`` pairs
+    of a head by."""
+    if not isinstance(rule, Yarn):
+        return rule ** (-jnp.arange(half, dtype=_f32) / half)
+    width = 2 * half
+
+    def pair_turning(turns):
+        # the (fractional) pair that turns ``turns`` times over the
+        # original length
+        return (
+            width
+            * math.log(rule.original_length / (turns * 2 * math.pi))
+            / (2 * math.log(rule.theta))
+        )
+
+    low = max(math.floor(pair_turning(rule.beta_fast)), 0)
+    high = min(math.ceil(pair_turning(rule.beta_slow)), width - 1)
+    if low == high:
+        high += 0.001  # HF: no division by zero
+    pair = jnp.arange(half, dtype=_f32)
+    ramp = jnp.clip((pair - low) / (high - low), 0.0, 1.0)
+    rate = rule.theta ** (-pair / half)
+    return (1.0 - ramp) * rate + ramp * (rate / rule.factor)
+
+
+def angles(positions, rule, half: int, sections=()):
+    """Float32 ``positions * rate_i`` for the ``half`` pairs of a head
+    (:func:`rates`; ``theta^(-i / half)`` where the rule is a base):
+    ``(tokens, half)`` for ``positions`` (tokens,); ``(batch, tokens,
     half)`` for (batch, components, tokens), frequency ``i`` taking the
     component whose section it lies in, ``sections[c]`` frequencies each in
     order.  The one definition both forms of ``layers/attention.py::rope``
     turn by."""
-    rate = theta ** (-jnp.arange(half, dtype=_f32) / half)
+    rate = rates(rule, half)
     if positions.ndim != 3:
         return positions.astype(_f32)[:, None] * rate[None, :]
     if sum(sections) != half:
@@ -103,11 +194,11 @@ def angles(positions, theta: float, half: int, sections=()):
     return of_frequency * rate
 
 
-def tables(positions, theta: float, width: int, sections=()):
+def tables(positions, rule, width: int, sections=()):
     """``(C, S)`` float32, ``[cos | cos]`` and ``[-sin | sin]`` of
-    :func:`angles` over a head of ``width``."""
-    turn = angles(positions, theta, width // 2, sections)
-    cos, sin = jnp.cos(turn), jnp.sin(turn)
+    :func:`angles` over a head of ``width``, :func:`scaled`."""
+    turn = angles(positions, rule, width // 2, sections)
+    cos, sin = scaled(rule, jnp.cos(turn), jnp.sin(turn))
     return (
         jnp.concatenate([cos, cos], axis=-1),
         jnp.concatenate([-sin, sin], axis=-1),
@@ -153,20 +244,21 @@ def _rotate(x, cos, sin, interpret, backward=False):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
-def rotate_half(x, positions, theta, sections, interpret):
+def rotate_half(x, positions, rule, sections, interpret):
     """Rotary positions on ``x`` (batch, heads, tokens, width), folded as
-    the attention kernels take it; ``positions`` and ``sections`` as
-    :func:`tables` takes them.  The shape must tile (:func:`rotate_tile`)."""
-    cos, sin = tables(positions, theta, x.shape[3], sections)
+    the attention kernels take it; ``positions``, ``rule`` (hashable: a
+    base or a :class:`Yarn`) and ``sections`` as :func:`tables` takes them.
+    The shape must tile (:func:`rotate_tile`)."""
+    cos, sin = tables(positions, rule, x.shape[3], sections)
     return _rotate(x, cos, sin, interpret)
 
 
-def _rotate_half_fwd(x, positions, theta, sections, interpret):
-    return rotate_half(x, positions, theta, sections, interpret), positions
+def _rotate_half_fwd(x, positions, rule, sections, interpret):
+    return rotate_half(x, positions, rule, sections, interpret), positions
 
 
-def _rotate_half_bwd(theta, sections, interpret, positions, d_out):
-    cos, sin = tables(positions, theta, d_out.shape[3], sections)
+def _rotate_half_bwd(rule, sections, interpret, positions, d_out):
+    cos, sin = tables(positions, rule, d_out.shape[3], sections)
     d_x = _rotate(d_out, cos, sin, interpret, backward=True)
     if jnp.issubdtype(positions.dtype, jnp.floating):
         return d_x, jnp.zeros_like(positions)

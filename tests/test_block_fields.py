@@ -213,7 +213,7 @@ _MODEL_OWN = {
     "remat_layers", "mtp_depth", "mtp_weight", "scale_embedding",
     "dropout_rate", "decode", "max_decode_len", "dtype", "norm", "norm_eps",
     "norm_outputs", "use_bias", "mlp", "mlp_width", "full_attention_rope",
-    "tie_embedding", "loop_steps", "exit_entropy_weight",
+    "rope_parameters", "tie_embedding", "loop_steps", "exit_entropy_weight",
 }
 
 
@@ -239,7 +239,7 @@ def test_the_models_table_names_declared_fields_of_the_part(part):
 def test_a_models_field_is_its_own_or_in_the_table_and_the_block_declares_no_parts():
     fields = _declared(lm.TransformerLM)
     named = [name for group in lm.PART_FIELDS.values() for name in group]
-    assert len(fields) == 62  # PR 53: loop_steps, exit_entropy_weight
+    assert len(fields) == 64  # PR 61: rope_parameters, router_trains
     assert set(named) | _MODEL_OWN == fields
     assert not set(named) & _MODEL_OWN
     # once, but for what the two kinds of attention part share

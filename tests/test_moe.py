@@ -190,6 +190,84 @@ def test_moe_refuses_more_slots_than_experts():
         )
 
 
+# --- a cut whose routers take no step (``router_trains``) ---
+
+CUT = dict(
+    num_experts=16, experts_per_token=4, expert_width=16, norm_topk_prob=True,
+    experts_held=4, aux_loss_weight=0.0, z_loss_weight=0.0,
+)
+
+
+def cut_layer_loss(x, target, **fields):
+    layer = MoEMLP(**{**CUT, **fields})
+    params = layer.init(jax.random.PRNGKey(0), x, training=False)["params"]
+
+    def loss(p, x):
+        y, stats = layer.apply(
+            {"params": p}, x, training=True, mutable=[router_load.ROUTER_STATS]
+        )
+        counts = stats[router_load.ROUTER_STATS]["expert_counts"]
+        return jnp.mean(jnp.square(y - target)), (y, counts)
+
+    return params, loss
+
+
+def test_a_router_that_does_not_train_routes_alike_and_takes_no_gradient():
+    """``router_trains=False``: the same output to the bit, the experts'
+    gradients those of the trained form, a zero gradient on the router's
+    weights, and at the layer's input what is left when the logits are
+    constants (the trained form adds the router's transpose to it)."""
+    x, target = (
+        jnp.asarray(np.random.RandomState(s).randn(2, 64, 32), jnp.float32)
+        for s in (0, 1)
+    )
+    grads, outputs = {}, {}
+    for trains in (True, False):
+        params, loss = cut_layer_loss(x, target, router_trains=trains)
+        (_, (outputs[trains], _)), grads[trains] = jax.value_and_grad(
+            loss, argnums=(0, 1), has_aux=True
+        )(params, x)
+    np.testing.assert_array_equal(outputs[True], outputs[False])
+    (trained, trained_x), (constant, constant_x) = grads[True], grads[False]
+    assert float(jnp.abs(trained["router"]["kernel"]).max()) > 0
+    np.testing.assert_array_equal(constant["router"]["kernel"], 0.0)
+    for name in ("w_gate", "w_up", "w_down"):
+        np.testing.assert_array_equal(trained[name], constant[name])
+    assert float(jnp.abs(trained_x - constant_x).max()) > 1e-6
+
+
+def test_a_cuts_trained_router_sends_the_pairs_to_the_held_experts():
+    """Why the field exists (PERF.md section 7, From PR 61 (c)): 4 of 16
+    experts held, no balancing term, plain Adam on one batch.  Trained, the
+    router moves the pairs to the experts that carry a gradient (27% of them
+    at the seeded weights, 36% forty steps on); left alone it sends every
+    step's pairs where it sent the first step's."""
+    x, target = (
+        jnp.asarray(np.random.RandomState(s).randn(2, 64, 32), jnp.float32)
+        for s in (0, 1)
+    )
+
+    def held_shares(trains):
+        params, loss = cut_layer_loss(x, target, router_trains=trains)
+        optimizer = optax.adam(1e-2)
+
+        @jax.jit
+        def step(params, state):
+            (_, (_, counts)), g = jax.value_and_grad(loss, has_aux=True)(params, x)
+            updates, state = optimizer.update(g, state)
+            return optax.apply_updates(params, updates), state, counts
+
+        state, shares = optimizer.init(params), []
+        for _ in range(40):
+            params, state, counts = step(params, state)
+            shares.append(float(counts[: CUT["experts_held"]].sum() / counts.sum()))
+        return shares
+
+    trained, constant = held_shares(True), held_shares(False)
+    assert trained[0] == constant[0] and set(constant) == {constant[0]}
+    assert trained[-1] > trained[0] + 0.05, trained
+
+
 # --- the ladder of row buffers (layers/moe.py, ops/grouped_matmul.py) ---
 
 TILE = 8

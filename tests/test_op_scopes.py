@@ -342,6 +342,7 @@ FAMILIES = {
     "latent_attention_mtp": lambda: _lm_family("tiny_joyai"),
     "sparse_attention": lambda: _lm_family("tiny_keye"),
     "window_and_full_attention": lambda: _lm_family("tiny_trinity"),
+    "window_and_yarn_attention": lambda: _lm_family("tiny_mellum"),
     "short_conv_attention_experts_tied": lambda: _lm_family("tiny_lfm2"),
     "looped_stack": lambda: _lm_family("tiny_ouro"),
     "resnet_first_stage": lambda: (
@@ -876,6 +877,95 @@ def test_the_rotary_kernels_calls_in_the_cells_models(
         }
         if calls else {}
     )
+
+
+def test_a_window_layer_and_a_yarn_layer_at_published_widths(one_chip_mesh):
+    """Mellum2's layer compiled for the described chip: a window layer of
+    1,024 keys under the base and a full layer under YaRN at 2,304, 32 : 4
+    heads of 128, 16 of 64 experts of 896 held, top-8, recomputed, 2,048
+    tokens (two windows).  Mosaic takes every kernel at widths that are no
+    multiple of 256; every region has a name; the YaRN tables and the scaled
+    rotation sit under ``attn/rope`` (what ``attention_other_share.scope_lm``
+    reads) around the one kernel; the window layer runs ``swa_*`` and the
+    full layer ``flash_*`` directly under ``attn``."""
+    with open(os.path.join(ROOT, "perf", "configs", "mellum2_12b_a2p5b.json")) as f:
+        params = json.load(f)["run"]["model_params"]
+    model = lm.custom_model(**{
+        **params, "num_layers": 4, "layer_pattern": "wE*E", "vocab_size": 1024,
+    })
+    compiled = _lowered_for_the_chip(
+        one_chip_mesh, model, lm.loss, lm.optimizer(), tokens=2048
+    ).compile()
+    scopes = op_scopes.scope_map(compiled)
+    held = [part for part, _, _, _ in scopes.values()]
+    assert sum(p is not None for p in held) >= 0.98 * len(held)
+    kernels = {}
+    for name, (part, phase, kind, _) in scopes.items():
+        if kind == "kernel":
+            kernels.setdefault(part, set()).add(phase)
+    twice = {"forward", "recompute"}
+    for name in ("swa_fwd", "flash_fwd", "rope/rope_fwd"):
+        assert kernels[f"block/attn/{name}"] == twice, name
+    for name in ("swa_dq", "swa_dkv", "flash_dq", "flash_dkv", "rope/rope_bwd"):
+        assert kernels[f"block/attn/{name}"] == {"backward"}, name
+    assert {
+        op_scopes.at_depth(part, 3) for part in kernels if "/moe/" in part
+    } >= {"block/moe/rung"}
+    assert any(part.endswith(gmm_ops.ROWS_SUM) for part in kernels)
+    assert any("expert_gmm_fwd" in part for part in kernels)
+    # the tables' ops (the ramp's blend, cos and sin times the factor) are
+    # the rope scope's own, of kind ``other``
+    others = {
+        part for part, _, kind, _ in scopes.values()
+        if kind != "kernel" and part is not None
+    }
+    assert "block/attn/rope" in others
+
+
+def test_mellum2s_cut_names_every_kernel_at_the_cells_shape(one_chip_mesh):
+    """The whole cut ``mellum2_seq16384`` times (four layers, three window
+    to one full, 24,576 rows of the vocabulary), lowered for the described
+    chip at the cell's 16,384 tokens: every kernel call sits in a named
+    region, once a layer and pass."""
+    from jax._src.lib import xla_client
+
+    from elasticdl_tpu.ops import rotary
+
+    with open(os.path.join(ROOT, "perf", "configs", "mellum2_12b_a2p5b.json")) as f:
+        params = json.load(f)["run"]["model_params"]
+    lowered = _lowered_for_the_chip(
+        one_chip_mesh, lm.custom_model(**params), lm.loss, lm.optimizer(),
+        tokens=16384,
+    )
+    options = xla_client._xla.HloPrintOptions()
+    options.print_metadata = True
+    options.print_backend_config = False
+    scopes = op_scopes._scope_of_text(
+        lowered.compiler_ir("hlo").as_hlo_module().to_string(options)
+    )
+    found = {}
+    for part, phase, kind, _ in scopes.values():
+        if kind == "kernel":
+            assert part is not None
+            found[part, phase] = found.get((part, phase), 0) + 1
+    attention = {
+        key: n for key, n in found.items() if key[0].startswith("block/attn/")
+    }
+    # q and k a layer; three window layers, one full layer
+    per_pass = {"rope/" + rotary.ROPE_FWD: 8, "swa_fwd": 3, "flash_fwd": 1}
+    back = {
+        "rope/" + rotary.ROPE_BWD: 8, "swa_dq": 3, "swa_dkv": 3,
+        "flash_dq": 1, "flash_dkv": 1,
+    }
+    assert attention == {
+        **{
+            (f"block/attn/{name}", phase): n
+            for name, n in per_pass.items() for phase in ("forward", "recompute")
+        },
+        **{(f"block/attn/{name}", "backward"): n for name, n in back.items()},
+    }
+    experts = {key for key in found if key not in attention}
+    assert experts and all(part.startswith("block/moe/") for part, _ in experts)
 
 
 @pytest.mark.parametrize("config", ["tiny_nemotron", "tiny_joyai"])

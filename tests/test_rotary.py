@@ -114,10 +114,10 @@ def test_the_kernel_is_the_plain_form(heads, seq, width, dtype, components):
     assert (difference == 0).mean() > 0.7
 
 
-def _calls(form, x, positions, **kwargs):
+def _calls(form, x, positions, rule=1e4, **kwargs):
     """The kernel calls in ``form``'s jaxpr, by name."""
     text = str(
-        jax.make_jaxpr(lambda x: form(x, positions, 1e4, **kwargs))(x)
+        jax.make_jaxpr(lambda x: form(x, positions, rule, **kwargs))(x)
     )
     return [
         name for name in (rotary.ROPE_FWD, rotary.ROPE_BWD) if name in text
@@ -200,3 +200,248 @@ def test_a_sequence_sharded_over_sp_keeps_the_plain_form():
     with on_mesh.attention_mesh_scope(mesh):
         assert _calls(rope, x, jnp.arange(1024)) == []
     assert _calls(rope, x, jnp.arange(1024)) == [rotary.ROPE_FWD]
+
+
+# ---- a rule that is no power law: YaRN (docs/designs/yarn_rope.md) ---------------
+
+# Mellum2-12B-A2.5B's ``rope_parameters``, as config.json gives them
+MELLUM_ROPE = {
+    "full_attention": {
+        "rope_type": "yarn", "rope_theta": 500000, "factor": 16,
+        "original_max_position_embeddings": 8192, "beta_fast": 32,
+        "beta_slow": 1, "attention_factor": 1.2772588722239782,
+    },
+    "sliding_attention": {"rope_type": "default", "rope_theta": 500000},
+}
+YARN = rotary.rule_of(MELLUM_ROPE["full_attention"])
+
+
+def test_a_published_group_gives_its_rule():
+    assert rotary.rule_of(MELLUM_ROPE["sliding_attention"]) == 500000.0
+    assert YARN == rotary.Yarn(500000.0, 16.0, 8192, 32.0, 1.0, 1.2772588722239782)
+    hash(YARN)  # a static argument of the kernel's custom_vjp
+    # no attention_factor given: 0.1 ln(factor) + 1, which is what this row states
+    unstated = rotary.Yarn(500000.0, 16.0, 8192)
+    cos, = rotary.scaled(unstated, jnp.ones(()))
+    assert float(cos) == pytest.approx(1.2772588722239782, rel=1e-7)
+    assert rotary.scaled(1e4, cos) == (cos,)
+    with pytest.raises(ValueError, match="rope_type"):
+        rotary.rule_of({"rope_type": "longrope", "rope_theta": 1e4})
+
+
+def test_yarn_frequencies_of_this_row_by_hand():
+    """128-wide heads, theta 500,000, factor 16 from 8,192 positions, beta 32
+    and 1.  The pair that turns ``r`` times over 8,192 positions is ``128
+    ln(8192 / (2 pi r)) / (2 ln 500000)``: 18.08 for 32 turns, 34.98 for
+    one, so ``low`` 18 and ``high`` 35.  Pairs 0..18 keep ``500000^(-i/64)``,
+    pairs 35..63 take a sixteenth of it, pair ``i`` between blends the two
+    with ``g = (i - 18) / 17``: ``rate_i (1 - 15 g / 16)``."""
+    ln = np.log(500000.0)
+    assert 128 * np.log(8192 / (2 * np.pi * 32)) / (2 * ln) == pytest.approx(18.081, abs=1e-3)
+    assert 128 * np.log(8192 / (2 * np.pi * 1)) / (2 * ln) == pytest.approx(34.984, abs=1e-3)
+    pair = np.arange(64)
+    power_law = np.exp(-pair / 64 * ln)
+    g = np.clip((pair - 18) / 17, 0.0, 1.0)
+    want = power_law * (1.0 - g * 15 / 16)
+    got = np.asarray(rotary.rates(YARN, 64), np.float64)
+    np.testing.assert_allclose(got, want, rtol=2e-6)
+    # a few of them written out: the last kept, the first blended, the last
+    # blended, the first and the last interpolated
+    by_hand = {
+        0: 1.0, 18: 2.495541e-2, 19: 1.920802e-2, 34: 1.104087e-4,
+        35: 4.778106e-5, 63: 1.534463e-7,
+    }
+    for i, rate in by_hand.items():
+        assert got[i] == pytest.approx(rate, rel=2e-6), i
+    assert (got[:19] == np.asarray(rotary.rates(500000.0, 64))[:19]).all()
+    np.testing.assert_allclose(got[35:] * 16, power_law[35:], rtol=2e-6)
+    # the power law itself is the expression it was
+    assert (
+        np.asarray(rotary.rates(1e4, 64))
+        == np.asarray(1e4 ** (-jnp.arange(64, dtype=jnp.float32) / 64))
+    ).all()
+
+
+def yarn_by_the_formulas(x, positions):
+    """HF's ``_compute_yarn_parameters`` and ``apply_rotary_pos_emb`` for
+    this row, written out: nothing of ``ops/rotary.py``."""
+    d = x.shape[-1]
+    base = 500000.0 ** (np.arange(0, d, 2, dtype=np.float64) / d)
+    low = np.floor(d * np.log(8192 / (32 * 2 * np.pi)) / (2 * np.log(500000.0)))
+    high = np.ceil(d * np.log(8192 / (1 * 2 * np.pi)) / (2 * np.log(500000.0)))
+    ramp = np.clip((np.arange(d // 2) - low) / (high - low), 0, 1)
+    inv_freq = (1 / (16 * base)) * ramp + (1 / base) * (1 - ramp)
+    freqs = np.asarray(positions, np.float64)[:, None] * inv_freq[None, :]
+    emb = np.concatenate([freqs, freqs], axis=-1)[None, :, None, :]
+    x = np.asarray(x, np.float64)
+    x1, x2 = np.split(x, 2, axis=-1)
+    turned = np.concatenate([-x2, x1], axis=-1)
+    factor = 0.1 * np.log(16.0) + 1.0
+    return x * np.cos(emb) * factor + turned * np.sin(emb) * factor
+
+
+@pytest.mark.parametrize(
+    "seq,heads", [(64, 4), (656, 32)], ids=["plain", "kernel"]
+)
+def test_yarn_through_both_forms_is_the_formulas(seq, heads):
+    """Positions past the 8,192 the rule extends from, where the blended
+    frequencies differ most; float32 angles at positions of 10^4 are good to
+    about 1e-3 of a turn, which is the tolerance."""
+    x, g, _, _ = _operands(1, seq, heads, 128, jnp.float32, False)
+    positions = 9000 + jnp.arange(seq)
+    assert (rotary.rotate_tile(x.shape) is not None) == (seq >= 512)
+    assert _calls(rope, x, positions, rule=YARN) == (
+        [rotary.ROPE_FWD] if seq >= 512 else []
+    )
+    out, pull = jax.vjp(lambda x: rope(x, positions, YARN), x)
+    np.testing.assert_allclose(
+        np.asarray(out), yarn_by_the_formulas(x, positions), atol=5e-3
+    )
+    # the map is linear: its transpose on g against the formulas' own, which
+    # is the rotation by the negated angle
+    d_x = pull(g)[0]
+    want = yarn_by_the_formulas(g, -np.asarray(positions))
+    np.testing.assert_allclose(np.asarray(d_x), want, atol=5e-3)
+    # without the ramp, and without the factor, it is another function
+    plain = rope(x, positions, 500000.0)
+    assert float(jnp.max(jnp.abs(out - plain * 1.2772588722239782))) > 0.1
+    unscaled = rope(x, positions, YARN._replace(attention_factor=1.0))
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(unscaled) * 1.2772588722239782, rtol=1e-5,
+        atol=1e-6,
+    )
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_the_kernel_under_yarn_is_the_plain_form_under_yarn(dtype):
+    """Values bit for bit and the gradient to a rounding of a product, as
+    under the power law: the rule changes the tables, the kernel is the one
+    kernel."""
+    from elasticdl_tpu.layers.attention import rope_plain
+
+    x, g, _, _ = _operands(2, 656, 32, 128, dtype, False)
+    positions = 8000 + jnp.arange(656)
+
+    def both_ways(form):
+        def run(x, g):
+            out, pull = jax.vjp(lambda x: form(x, positions, YARN), x)
+            return out, pull(g)[0]
+        return jax.jit(run)(x, g)
+
+    out, d_x = both_ways(rope)
+    want, want_d_x = both_ways(rope_plain)
+    np.testing.assert_array_equal(
+        np.asarray(out, np.float32), np.asarray(want, np.float32)
+    )
+    g32 = 1.2772588722239782 * np.abs(np.asarray(g, np.float32))
+    slack = 2.0**-22 * (g32 + np.roll(g32, 64, axis=-1))
+    if dtype == jnp.bfloat16:
+        slack = slack + 2.0**-7 * np.abs(np.asarray(want_d_x, np.float32))
+    difference = np.abs(
+        np.asarray(d_x, np.float32) - np.asarray(want_d_x, np.float32)
+    )
+    assert (difference <= slack).all(), difference.max()
+
+
+def test_a_full_layers_scores_carry_the_factor_squared():
+    """cos and sin both carry ``attention_factor``, so q and k each do and a
+    score carries its square; and a score still depends on ``t - s`` alone."""
+    x, y, _, _ = _operands(1, 32, 2, 128, jnp.float32, False)
+    unscaled = YARN._replace(attention_factor=1.0)
+
+    def scores(rule, offset):
+        positions = offset + jnp.arange(32)
+        return jnp.einsum(
+            "bqhd,bkhd->bhqk", rope(x, positions, rule), rope(y, positions, rule)
+        )
+
+    np.testing.assert_allclose(
+        np.asarray(scores(YARN, 0)),
+        1.2772588722239782**2 * np.asarray(scores(unscaled, 0)), rtol=2e-5,
+        atol=1e-5,
+    )
+    np.testing.assert_allclose(
+        np.asarray(scores(YARN, 0)), np.asarray(scores(YARN, 5000)), atol=0.05
+    )
+
+
+def _two_kind_lm(**fields):
+    from elasticdl_tpu.models import long_seq_transformer as zoo
+
+    return zoo.custom_model(**{
+        **dict(
+            vocab_size=64, embed_dim=32, num_heads=4, num_kv_heads=2,
+            head_dim=16, num_layers=4, layer_pattern="w-*-", norm="rmsnorm",
+            use_bias=False, positions="rope", rope_theta=100,
+            sliding_window=6, qk_norm_per_head=True, mlp="swiglu", mlp_width=48,
+            # theta 100 from 16 positions over 8 pairs: the ramp ends at
+            # pair 2 (16 ln(16 / 2 pi) / (2 ln 100) = 1.62) and starts at 0
+            rope_parameters={
+                "full_attention": {
+                    **MELLUM_ROPE["full_attention"], "rope_theta": 100,
+                    "original_max_position_embeddings": 16,
+                },
+                "sliding_attention": {"rope_type": "default", "rope_theta": 100},
+            },
+        ),
+        **fields,
+    })
+
+
+def test_the_models_field_gives_each_kind_of_layer_its_rule():
+    """``rope_parameters`` reaches the attention parts through
+    ``PART_FIELDS``: the window part turns by the base, the full part by
+    YaRN; a model that names no rule turns every layer by ``rope_theta``,
+    and a kind that is not named does too."""
+    tokens = np.random.default_rng(0).integers(64, size=(2, 24)).astype(np.int32)
+    model = _two_kind_lm()
+    params = model.init(jax.random.PRNGKey(0), {"tokens": tokens})["params"]
+
+    def logits(model):
+        return np.asarray(model.apply({"params": params}, {"tokens": tokens}))
+
+    both = logits(model)
+    none = logits(_two_kind_lm(rope_parameters=None))
+    assert np.abs(both - none).max() > 1e-3
+    window_alone = {"sliding_attention": model.rope_parameters["sliding_attention"]}
+    np.testing.assert_array_equal(
+        logits(_two_kind_lm(rope_parameters=window_alone)), none
+    )
+    # the full part's rule alone moves what the full part alone computes
+    full_alone = {"full_attention": model.rope_parameters["full_attention"]}
+    np.testing.assert_array_equal(logits(_two_kind_lm(rope_parameters=full_alone)), both)
+    # a rule with nothing to turn, or for a layer that is told not to, is refused
+    for fields, refusal in (
+        ({"rope_parameters": {"chunked_attention": window_alone["sliding_attention"]}},
+         "rope_parameters names"),
+        ({"positions": "none"}, "rotary positions alone"),
+        ({"full_attention_rope": False}, "gives them none"),
+    ):
+        with pytest.raises(ValueError, match=refusal):
+            _two_kind_lm(**fields).init(jax.random.PRNGKey(0), {"tokens": tokens})
+
+
+def test_decoding_under_yarn_is_the_full_forward_pass():
+    """One token at a time through the caches: the one new position is
+    turned by its layer's rule (the tables are made from the decode
+    cursor)."""
+    model = _two_kind_lm()
+    tokens = np.random.default_rng(1).integers(64, size=(2, 16)).astype(np.int32)
+    params = model.init(jax.random.PRNGKey(0), {"tokens": tokens})["params"]
+    want = model.apply({"params": params}, {"tokens": tokens})
+    decoder = model.clone(decode=True, max_decode_len=16)
+    cache = decoder.init(jax.random.PRNGKey(0), {"tokens": tokens[:, :1]})["cache"]
+    step = jax.jit(
+        lambda cache, token: decoder.apply(
+            {"params": params, "cache": cache}, {"tokens": token},
+            mutable=["cache"],
+        )
+    )
+    got = []
+    for t in range(16):
+        logits, mutated = step(cache, tokens[:, t:t + 1])
+        cache = mutated["cache"]
+        got.append(logits[:, 0])
+    np.testing.assert_allclose(
+        np.asarray(jnp.stack(got, axis=1)), np.asarray(want), atol=2e-4, rtol=2e-4
+    )

@@ -298,6 +298,59 @@ def test_block_plan_of_the_window_cell():
     ) == (2, 2)
 
 
+def test_block_plan_of_a_window_of_1024_at_16384():
+    """``mellum2_seq16384``: at the 512-square blocks the kernels pick, a
+    window of 1,024 is two whole blocks, so a row of blocks is the block on
+    the diagonal, one whole block and one the trailing edge crosses: 93 live
+    of 1,024 (17.6% of a full layer's 528), 32 + 30 masked, and no block is
+    crossed twice (``window >= block_q + block_k - 1``), so the edge block is
+    worked in halves like the diagonal one."""
+    assert flash_block_plan(16384, 16384, 512, 512, True, 1024) == (93, 62, 931)
+    assert attention_ops._crossings(512, 512, 1024) == (True, attention_ops._EDGE)
+    pieces = attention_ops._block_pieces(512, 512, attention_ops._EDGE, True, 1024)
+    assert [piece[:4] for piece in pieces] == [
+        (0, 256, 0, 512), (256, 512, 256, 512)
+    ]
+    # one chunk of 4,096 rows holds a q-block's three live k-blocks but for
+    # the q-blocks at a chunk's first two blocks: two chunks at most
+    assert attention_ops._window_streams(
+        1024, 16384, 16384, 512, 512, 4096, 4096
+    ) == (2, 2)
+    # blocks of 1,024 would be crossed by the diagonal AND the trailing edge
+    assert attention_ops._crossings(1024, 1024, 1024) == (
+        attention_ops._BOTH, attention_ops._BOTH
+    )
+    assert flash_block_plan(16384, 16384, 1024, 1024, True, 1024) == (31, 31, 225)
+
+
+@pytest.mark.parametrize(
+    "block,crossed_twice", [(1024, True), (512, False)],
+    ids=["blocks_crossed_twice", "the_cells_blocks"],
+)
+def test_a_window_of_1024_at_published_widths(block, crossed_twice):
+    """A window of 1,024 keys over 128-wide heads in groups of four, three
+    windows long: at 1,024-square blocks every live block off the first is
+    crossed by the diagonal and by the trailing edge and is computed whole
+    under both conditions; at the cell's 512 the edge block is halved.
+    Values and the three gradients against the masked plain form."""
+    q, k, v, w = _operands(4, 1, 128, seq=3072)
+    both = attention_ops._crossings(block, block, 1024)[0] == attention_ops._BOTH
+    assert both == crossed_twice
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, True, None, block, block, True, 1024)
+
+    def plain(q, k, v):
+        return mha_reference(q, k, v, causal=True, window=1024)
+
+    got = _forward_and_gradients(flash, q, k, v, w)
+    want = _forward_and_gradients(plain, q, k, v, w)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), atol=5e-5, rtol=5e-5
+        )
+
+
 # ---- the dispatch -----------------------------------------------------------
 
 
