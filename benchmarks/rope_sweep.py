@@ -18,6 +18,16 @@ lanes of its heads together at most, in place of the module's constants.
     python benchmarks/rope_sweep.py --shape 1,16384,32/4,128 \\
         --sweep "256,1024;512,1024;512,2048;1024,512;1024,1024"
 
+``--width N`` rotates ``N`` lanes a head in place of the shape's ``W`` (64:
+an array's one head; several such heads the kernel does not take, PR 62 having
+measured them: docs/designs/rotary_kernel.md, and the plain form's line
+stands alone), ``--interleave 1`` by adjacent pairs, and
+``--tail NOPE`` gives the query heads ``NOPE`` lanes that pass through
+ahead of the rotating ones (the key heads have none: latent attention's q
+at ``--shape 1,8192,32/1,64 --interleave 1 --tail 128`` beside its one
+shared rotary key); the plain form there slices the tail out, turns it and
+joins it back, as the layer did.
+
 One JSON line per array and form: milliseconds a call and GB/s counted as
 the array read once and written once (the tables' bytes are the kernel's to
 pay and not counted), and whether the kernel's values and gradient are the
@@ -42,10 +52,12 @@ PLAIN = "plain"
 
 
 def time_array(
-    batch, seq, heads, width, dtype, mrope, geometry, calls, time_plain=True
+    batch, seq, heads, width, dtype, mrope, geometry, calls, time_plain=True,
+    interleave=False, skip=0,
 ):
     """One array's lines: the kernel's and, with ``time_plain``, the plain
-    form's (it is run for the comparison either way)."""
+    form's (it is run for the comparison either way).  ``width`` lanes of a
+    head rotate behind ``skip`` that pass through."""
     import jax
     import jax.numpy as jnp
 
@@ -59,7 +71,7 @@ def time_array(
     keys = jax.random.split(jax.random.PRNGKey(heads), 3)
     x, g = (
         jax.random.normal(
-            key, (batch, heads, seq, width), jnp.float32
+            key, (batch, heads, seq, skip + width), jnp.float32
         ).astype(dtype)
         for key in keys[:2]
     )
@@ -71,12 +83,18 @@ def time_array(
     theta = 1e4
 
     def kernel(x):
-        return rotary.rotate_half(x, positions, theta, sections, False)
+        return rotary.rotate(
+            x, positions, theta, sections, interleave, skip, False
+        )
 
     def plain(x):
-        return rope_plain(
-            x.transpose(0, 2, 1, 3), positions, theta, sections=sections
-        ).transpose(0, 2, 1, 3)
+        rows = x.transpose(0, 2, 1, 3)
+        turned = rope_plain(
+            rows[..., skip:], positions, theta, interleave, sections
+        )
+        if skip:
+            turned = jnp.concatenate([rows[..., :skip], turned], axis=-1)
+        return turned.transpose(0, 2, 1, 3)
 
     def both_ways(form):
         return jax.jit(lambda x, g: (form(x), jax.vjp(form, x)[1](g)[0]))
@@ -88,7 +106,11 @@ def time_array(
     nbytes = 2 * x.size * x.dtype.itemsize
     try:
         results = {}
-        for name, form in (("kernel", kernel), (PLAIN, plain)):
+        forms = (("kernel", kernel), (PLAIN, plain))
+        tile = rotary.rotate_tile((batch, seq, heads, skip + width), skip)
+        if tile is None:
+            forms = forms[1:]  # a shape the kernel does not take
+        for name, form in forms:
             step = both_ways(form)
             results[name] = jax.block_until_ready(step(x, g))  # compiles
             if name == PLAIN and not time_plain:
@@ -100,9 +122,9 @@ def time_array(
             parts = {k: ms[k] for k in names} or {"fwd+bwd": ms[OTHER]}
             passes = len(KERNELS) // len(parts)
             lines.append({
-                "array": [batch, heads, seq, width], "form": name,
-                "tile": list(rotary.rotate_tile((batch, seq, heads, width)))
-                if names else None,
+                "array": [batch, heads, seq, skip + width], "form": name,
+                "interleave": interleave, "skip": skip,
+                "tile": list(tile) if names else None,
                 "ms": {k: round(v, 4) for k, v in parts.items()},
                 "gb_per_s": {
                     k: round(passes * nbytes / v / 1e6, 1)
@@ -110,12 +132,13 @@ def time_array(
                 },
                 "other_ops_ms": round(ms[OTHER], 4) if names else 0.0,
             })
-        lines[0]["equal_values"] = bool(
-            jnp.array_equal(results["kernel"][0], results[PLAIN][0])
-        )
-        lines[0]["equal_gradient"] = bool(
-            jnp.array_equal(results["kernel"][1], results[PLAIN][1])
-        )
+        if "kernel" in results:
+            lines[0]["equal_values"] = bool(
+                jnp.array_equal(results["kernel"][0], results[PLAIN][0])
+            )
+            lines[0]["equal_gradient"] = bool(
+                jnp.array_equal(results["kernel"][1], results[PLAIN][1])
+            )
     finally:
         rotary._ROWS, rotary._BLOCK_LANES = constants
     return lines
@@ -130,6 +153,15 @@ def main(argv=None) -> int:
         help='"rows,lanes;..."; empty: the module\'s own constants',
     )
     parser.add_argument("--mrope", type=int, default=0)
+    parser.add_argument(
+        "--width", type=int, default=0,
+        help="lanes of a head that rotate; 0: the shape's W",
+    )
+    parser.add_argument("--interleave", type=int, default=0)
+    parser.add_argument(
+        "--tail", type=int, default=0,
+        help="lanes of a query head that pass through ahead of the others",
+    )
     parser.add_argument("--calls", type=int, default=10)
     args = parser.parse_args(argv)
 
@@ -141,6 +173,7 @@ def main(argv=None) -> int:
               file=sys.stderr)
         return 3
     batch, seq, heads, width = args.shape.split(",")
+    width = args.width or int(width)
     heads, _, kv_heads = heads.partition("/")
     dtype = jnp.dtype(args.dtype)
     geometries = [
@@ -148,16 +181,19 @@ def main(argv=None) -> int:
         for geometry in args.sweep.split(";") if geometry
     ] or [None]
     for geometry in geometries:
-        for h in filter(None, (heads, kv_heads)):
+        for h, skip in ((heads, args.tail), (kv_heads, 0)):
+            if not h:
+                continue
             try:
                 lines = time_array(
-                    int(batch), int(seq), int(h), int(width), dtype,
+                    int(batch), int(seq), int(h), width, dtype,
                     bool(args.mrope), geometry, args.calls,
                     time_plain=geometry == geometries[0],
+                    interleave=bool(args.interleave), skip=skip,
                 )
             except Exception as ex:  # noqa: BLE001: Mosaic's refusal, reported
                 lines = [{
-                    "array": [int(batch), int(h), int(seq), int(width)],
+                    "array": [int(batch), int(h), int(seq), skip + width],
                     "geometry": geometry,
                     "error": f"{type(ex).__name__}: {ex}"[:600],
                 }]

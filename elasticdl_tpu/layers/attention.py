@@ -502,17 +502,24 @@ class LatentSelfAttention(nn.Module):
         # the regions between the modules, by telemetry/op_scopes.py's names
         with jax.named_scope("rope"):
             positions = jnp.arange(x.shape[1])
-            q_rot = rope(
-                q[..., nope:], positions, self.rope_theta, self.rope_interleave
+            # q whole: its rotating part is the tail of each head, turned
+            # in place (where the shape keeps the plain form, sliced out,
+            # turned and joined back)
+            q = rope(
+                q, positions, self.rope_theta, self.rope_interleave,
+                skip=nope,
             )
             k_rot = rope(
                 latent[..., None, self.kv_lora_rank :], positions,
                 self.rope_theta, self.rope_interleave,
             )
         with jax.named_scope("join"):
-            q = jnp.concatenate([q[..., :nope], q_rot], axis=-1)
             k = jnp.concatenate(
-                [kv[..., :nope], jnp.broadcast_to(k_rot, q_rot.shape)], axis=-1
+                [
+                    kv[..., :nope],
+                    jnp.broadcast_to(k_rot, (*q.shape[:-1], rot)),
+                ],
+                axis=-1,
             )
             v = kv[..., nope:]
         out = attention_ops.attention(q, k, v, causal=self.causal)
@@ -690,12 +697,12 @@ class TransformerBlock(nn.Module):
             return dense(y.shape[-1], "mlp_down")(hidden)
 
 
-def _rope_takes_kernel(x, interleave: bool) -> bool:
+def _rope_takes_kernel(x, skip: int = 0) -> bool:
     """Whether :func:`rope` hands ``x`` to ``ops/rotary.py``'s kernel: the
-    shape tiles (``rotary.rotate_tile``) and no ``sp`` axis shards the
-    sequence, where GSPMD partitions the plain form and the kernel, mapped
-    over the batch alone, would gather it."""
-    if not rotary_ops.rotate_tile(x.shape, interleave):
+    shape tiles (``rotary.rotate_tile``, which says which shapes do) and no
+    ``sp`` axis shards the sequence, where GSPMD partitions the plain form
+    and the kernel, mapped over the batch alone, would gather it."""
+    if not rotary_ops.rotate_tile(x.shape, skip):
         return False
     mesh, sp_axis, _ = on_mesh.get_attention_mesh()
     return not (
@@ -705,16 +712,20 @@ def _rope_takes_kernel(x, interleave: bool) -> bool:
     )
 
 
-def rope(x, positions, rule, interleave: bool = False, sections=()):
-    """Rotary positions (Su et al. 2021) over the whole of ``x``'s last
-    axis (hand it the slice of the head that rotates): ``x`` (batch, seq,
-    heads, d), ``positions`` (seq,).  ``rule`` a float, the base ``theta``:
-    pair ``i`` turns by ``positions * theta^(-2i/d)``; a
-    ``ops/rotary.py::Yarn``: by its blended frequencies, cos and sin scaled
-    (docs/designs/yarn_rope.md).  The pairs are ``(x_i, x_{i+d/2})``, the
-    rotate-half convention of HF ``apply_rotary_pos_emb``, or with
-    ``interleave`` the adjacent ``(x_2i, x_2i+1)`` of the original and of
-    ``rope_interleave``.  Computed in float32.
+def rope(
+    x, positions, rule, interleave: bool = False, sections=(), skip: int = 0
+):
+    """Rotary positions (Su et al. 2021) over ``x``'s last axis behind its
+    first ``skip`` lanes, which pass through (a head whose rotating part is
+    its tail; 0: the whole of it): ``x`` (batch, seq, heads, d),
+    ``positions`` (seq,).  ``rule`` a float, the base ``theta``: pair ``i``
+    of the ``d - skip`` rotating lanes turns by ``positions *
+    theta^(-2i/(d - skip))``; a ``ops/rotary.py::Yarn``: by its blended
+    frequencies, cos and sin scaled (docs/designs/yarn_rope.md).  The pairs
+    are ``(x_i, x_{i+d/2})``, the rotate-half convention of HF
+    ``apply_rotary_pos_emb``, or with ``interleave`` the adjacent ``(x_2i,
+    x_2i+1)`` of the original and of ``rope_interleave``.  Computed in
+    float32.
 
     ``positions`` (batch, components, seq) with ``sections`` (Qwen2-VL's
     multimodal RoPE): frequency ``i`` takes its angle from the component
@@ -723,20 +734,28 @@ def rope(x, positions, rule, interleave: bool = False, sections=()):
     (text) that is the rule above, and (seq,) positions take it.
 
     Which code runs is chosen from the shapes (:func:`_rope_takes_kernel`):
-    rotate-half over heads a whole number of 128-lane tiles wide, with at
-    least one tile of 512 rows and the sequence whole on its device, is
+    rotating lanes that are whole 128-lane tiles, behind whole tiles that
+    pass through, or the 64 lanes of an array's one head, with at least one
+    tile of 512 rows and the sequence whole on its device, is
     ``ops/rotary.py``'s single-pass kernel (the same products and sums a
-    number, in float32; forward, recomputed and backward); everything else
-    (``interleave``, a 64-wide head, a decode step, a small model, a
-    sequence sharded over ``sp``) is :func:`rope_plain`."""
-    if not _rope_takes_kernel(x, interleave):
-        return rope_plain(x, positions, rule, interleave, sections)
+    number, in float32; forward, recomputed and backward), which reads and
+    writes the whole head in place; everything else (a decode step, a small
+    model, a sequence sharded over ``sp``, several 64-wide heads, any other
+    width) is :func:`rope_plain` on the rotating lanes, joined to the
+    others."""
+    if not _rope_takes_kernel(x, skip):
+        if not skip:
+            return rope_plain(x, positions, rule, interleave, sections)
+        turned = rope_plain(
+            x[..., skip:], positions, rule, interleave, sections
+        )
+        return jnp.concatenate([x[..., :skip], turned], axis=-1)
     # one pass of ops/rotary.py's kernel over the folded form the attention
     # kernels take: the two transposes are layouts for XLA to assign
     by_batch = positions.ndim == 3
     out = on_mesh.over_batch(
-        lambda x, positions, interpret: rotary_ops.rotate_half(
-            x, positions, rule, tuple(sections), interpret
+        lambda x, positions, interpret: rotary_ops.rotate(
+            x, positions, rule, tuple(sections), interleave, skip, interpret
         ),
         (x.transpose(0, 2, 1, 3),) + ((positions,) if by_batch else ()),
         () if by_batch else (positions,),

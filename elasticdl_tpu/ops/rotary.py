@@ -1,16 +1,28 @@
-"""Rotary positions in the rotate-half convention as one Pallas pass that
-reads an array once and writes it once (``layers/attention.py::rope`` holds
-the plain form and chooses between the two by shape)::
+"""Rotary positions as one Pallas pass that reads an array once and writes
+it once (``layers/attention.py::rope`` holds the plain form and chooses
+between the two by shape)::
 
-    out = x * C + roll(x, width / 2 lanes) * S      a head, in float32
-    C = [cos | cos]    S = [-sin | sin]             (tokens, width) tables
+    out = x * C + partner(x) * S                    a head, in float32
 
-which is ``x1 * cos - x2 * sin | x2 * cos + x1 * sin`` product for product and
-sum for sum, so the same bits: read in ``x``'s dtype, computed in float32,
-written in ``x``'s dtype.  A head is a whole number of 128-lane tiles wide and
-its partner half a head away, a rotation of whole lanes; a 64-wide head's
-partner is 32 lanes away inside a tile that holds two heads, and the plain
-form keeps it.
+    rotate-half     partner: half a head away       C = [cos | cos]
+                    roll(x, width / 2 lanes)        S = [-sin | sin]
+    adjacent pairs  partner: the pair's other lane  C = each cos on its two lanes
+                    roll by one lane, up or down    S = each sin on its two lanes,
+                    by the lane's parity            -sin first
+
+which is ``x1 * cos - x2 * sin`` and ``x2 * cos + x1 * sin`` product for product
+and sum for sum, so the same bits: read in ``x``'s dtype, computed in float32,
+written in ``x``'s dtype.  The partner is a static permutation of a head's own
+lanes, so a head may be narrower than a lane tile (64 wide: latent
+attention's one rotary key, the indexer's one key) and the rotating lanes may
+be the tail of a wider head whose first ``skip`` lanes, whole lane tiles, are
+copied through (latent attention's q: 128 + 64).  A head narrower than a
+tile is taken only where it is its array's one head: SEVERAL 64-wide heads
+(the indexer's queries, LFM2's q and k) the same body turned bit for bit too,
+but folded their rows are half padding in HBM and the projection that must
+write them lost more than the pass won, so :func:`rotate_tile` keeps the
+plain form for them (``lfm2_24b_a2b_seq4096x4`` -1.4% in PR 62's chip runs:
+PERF.md section 6, PR 63; docs/designs/rotary_kernel.md has the table).
 
 **The layout is the point.**  Where heads are 128 wide the attention kernels
 take ``(batch * heads, tokens, width)`` (``ops/attention.py::
@@ -24,12 +36,15 @@ steps hold no copy of a q-sized array that they did not hold before.  (Handed
 the projection's merged rows ``(batch, tokens, heads * width)`` instead, the
 step of ``trinity_mini_seq16384`` grew 32 float32 copies of q and k: XLA kept
 the projection folded and turned the norm's output into rows through a copy;
-PERF.md section 6, PR 45.)
+PERF.md section 6, PR 45.)  The same holds where a head is 192 wide: the
+flash kernels take it folded at 192 | 128, so the whole head is handed
+folded and the tail is rotated in place: no slice of q and no concatenation
+is left for XLA (PERF.md section 6, PR 63).
 
 A grid step is a tile of rows by a block of heads; the tables' block follows
 the row tile alone and the heads are the grid's innermost dimension, so a
 table block is fetched once a row tile.  The backward is the same body with
-``S`` negated (``roll`` by half a head is its own transpose, and it moves
+``S`` negated (either partner permutation is its own transpose, and it moves
 ``S`` onto ``-S``).  The ``custom_vjp``'s residuals are the positions alone:
 the tables are made again where the backward wants them (XLA shares one pair
 a step where the positions are the tokens' indices), never kept a layer.
@@ -70,18 +85,28 @@ _BLOCK_LANES = 1024
 _f32 = jnp.float32
 
 
-def rotate_tile(shape, interleave: bool = False):
+def rotate_tile(shape, skip: int = 0):
     """``(row tile, heads a block)`` the kernels take ``x`` of ``shape``
-    (batch, tokens, heads, width) with, from what the call can see; None
-    where the plain form stays: adjacent pairs, a head that is no whole
-    number of lane tiles (its partner sits inside a tile), fewer rows than
-    one tile (a decode step, a small model)."""
-    if interleave or len(shape) != 4:
+    (batch, tokens, heads, width) with, the first ``skip`` lanes of a head
+    passing through, from what the call can see; None where the plain form
+    stays: fewer rows than one tile (a decode step, a small model), lanes
+    that pass through and are no whole lane tiles, rotating lanes that are
+    neither whole lane tiles nor half a tile, several heads narrower than a
+    tile (folded, their rows are half padding in HBM: the module's
+    docstring; an array's one head of 64 is taken)."""
+    if len(shape) != 4:
         return None
     _, rows, heads, width = shape
-    if width % _LANES or rows < _ROWS:
+    turning = width - skip
+    if rows < _ROWS or skip % _LANES:
         return None
-    held = max(1, min(heads, _BLOCK_LANES // width))
+    if turning % _LANES and turning != _LANES // 2:
+        return None
+    if width < _LANES and heads > 1:
+        return None
+    # a head's lanes in VMEM: whole tiles
+    padded = -(-width // _LANES) * _LANES
+    held = max(1, min(heads, _BLOCK_LANES // padded))
     while heads % held:
         held -= 1
     return _ROWS, held
@@ -194,43 +219,73 @@ def angles(positions, rule, half: int, sections=()):
     return of_frequency * rate
 
 
-def tables(positions, rule, width: int, sections=()):
-    """``(C, S)`` float32, ``[cos | cos]`` and ``[-sin | sin]`` of
-    :func:`angles` over a head of ``width``, :func:`scaled`."""
+def tables(positions, rule, width: int, sections=(), interleave=False):
+    """``(C, S)`` float32 over a head of ``width``, from :func:`angles`,
+    :func:`scaled`: ``[cos | cos]`` and ``[-sin | sin]``, or with
+    ``interleave`` each frequency on its pair's two lanes, ``-sin`` on the
+    first."""
     turn = angles(positions, rule, width // 2, sections)
     cos, sin = scaled(rule, jnp.cos(turn), jnp.sin(turn))
+    if interleave:
+        return (
+            jnp.repeat(cos, 2, axis=-1),
+            jnp.stack([-sin, sin], axis=-1).reshape(*sin.shape[:-1], width),
+        )
     return (
         jnp.concatenate([cos, cos], axis=-1),
         jnp.concatenate([-sin, sin], axis=-1),
     )
 
 
-def _rotate_kernel(x_ref, cos_ref, sin_ref, out_ref, *, width, backward):
+def _partner(x, interleave):
+    """Each lane's partner in its place, ``x`` (rows, a head's rotating
+    lanes): half a head away, or the other lane of its adjacent pair."""
+    lanes = x.shape[1]
+    if not interleave:
+        return pltpu.roll(x, lanes // 2, 1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    return jnp.where(
+        lane % 2 == 0, pltpu.roll(x, lanes - 1, 1), pltpu.roll(x, 1, 1)
+    )
+
+
+def _rotate_kernel(
+    x_ref, cos_ref, sin_ref, out_ref, *, interleave, skip, backward
+):
     """Some heads of a tile of rows, ``(heads, rows, width)``.  The
-    backward takes ``S`` negated: ``roll`` by half a head is its own
+    backward takes ``S`` negated: the partner permutation is its own
     transpose, and it moves ``S`` onto ``-S``."""
     cos, sin = cos_ref[...], sin_ref[...]
+    # the whole head, or the lanes behind those that pass through
+    lanes = (slice(None), slice(skip, None)) if skip else ()
     for head in range(x_ref.shape[0]):
-        x = x_ref[head].astype(_f32)
-        turned = pltpu.roll(x, width // 2, 1) * sin
+        if skip:
+            out_ref[head, :, :skip] = x_ref[head, :, :skip]
+        x = x_ref[(head, *lanes)].astype(_f32)
+        turned = _partner(x, interleave) * sin
         out = x * cos - turned if backward else x * cos + turned
-        out_ref[head] = out.astype(out_ref.dtype)
+        out_ref[(head, *lanes)] = out.astype(out_ref.dtype)
 
 
-def _rotate(x, cos, sin, interpret, backward=False):
-    """The one ``pallas_call``, over ``x`` (batch, heads, tokens, width)."""
+def _rotate(x, cos, sin, interpret, interleave=False, skip=0, backward=False):
+    """The one ``pallas_call``, over ``x`` (batch, heads, tokens, width);
+    the tables over the ``width - skip`` lanes that rotate."""
     batch, heads, rows, width = x.shape
-    tile, held = rotate_tile((batch, rows, heads, width))
+    tile, held = rotate_tile((batch, rows, heads, width), skip)
     folded = pl.BlockSpec(
         (None, held, tile, width), lambda b, i, h: (b, h, i, 0)
     )
+    turning = width - skip
     table = (
-        pl.BlockSpec((None, tile, width), lambda b, i, h: (b, i, 0))
+        pl.BlockSpec((None, tile, turning), lambda b, i, h: (b, i, 0))
         if cos.ndim == 3
-        else pl.BlockSpec((tile, width), lambda b, i, h: (i, 0))
+        else pl.BlockSpec((tile, turning), lambda b, i, h: (i, 0))
     )
     return pl.pallas_call(
-        functools.partial(_rotate_kernel, width=width, backward=backward),
+        functools.partial(
+            _rotate_kernel, interleave=interleave, skip=skip,
+            backward=backward,
+        ),
         grid=(batch, pl.cdiv(rows, tile), heads // held),
         in_specs=[folded, table, table],
         out_specs=folded,
@@ -243,26 +298,32 @@ def _rotate(x, cos, sin, interpret, backward=False):
     )(x, cos, sin)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
-def rotate_half(x, positions, rule, sections, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5, 6))
+def rotate(x, positions, rule, sections, interleave, skip, interpret):
     """Rotary positions on ``x`` (batch, heads, tokens, width), folded as
-    the attention kernels take it; ``positions``, ``rule`` (hashable: a
-    base or a :class:`Yarn`) and ``sections`` as :func:`tables` takes them.
-    The shape must tile (:func:`rotate_tile`)."""
-    cos, sin = tables(positions, rule, x.shape[3], sections)
-    return _rotate(x, cos, sin, interpret)
+    the attention kernels take it, behind a head's first ``skip`` lanes;
+    ``positions``, ``rule`` (hashable: a base or a :class:`Yarn`),
+    ``sections`` and ``interleave`` as :func:`tables` takes them.  The shape
+    must tile (:func:`rotate_tile`)."""
+    cos, sin = tables(
+        positions, rule, x.shape[3] - skip, sections, interleave
+    )
+    return _rotate(x, cos, sin, interpret, interleave, skip)
 
 
-def _rotate_half_fwd(x, positions, rule, sections, interpret):
-    return rotate_half(x, positions, rule, sections, interpret), positions
+def _rotate_fwd(x, positions, rule, sections, interleave, skip, interpret):
+    out = rotate(x, positions, rule, sections, interleave, skip, interpret)
+    return out, positions
 
 
-def _rotate_half_bwd(rule, sections, interpret, positions, d_out):
-    cos, sin = tables(positions, rule, d_out.shape[3], sections)
-    d_x = _rotate(d_out, cos, sin, interpret, backward=True)
+def _rotate_bwd(rule, sections, interleave, skip, interpret, positions, d_out):
+    cos, sin = tables(
+        positions, rule, d_out.shape[3] - skip, sections, interleave
+    )
+    d_x = _rotate(d_out, cos, sin, interpret, interleave, skip, backward=True)
     if jnp.issubdtype(positions.dtype, jnp.floating):
         return d_x, jnp.zeros_like(positions)
     return d_x, np.zeros(positions.shape, jax.dtypes.float0)
 
 
-rotate_half.defvjp(_rotate_half_fwd, _rotate_half_bwd)
+rotate.defvjp(_rotate_fwd, _rotate_bwd)

@@ -33,3 +33,22 @@ jax.config.update("jax_platforms", "cpu")
 from elasticdl_tpu.data.recordio import build as _codec_build  # noqa: E402
 
 _codec_build.build(quiet=True)
+
+
+import pytest  # noqa: E402
+
+
+@pytest.fixture
+def plain_rope_shapes(monkeypatch):
+    """The shapes ``layers/attention.py::rope_plain`` is traced for while
+    the test runs, in order: what ``rope`` left to the plain form."""
+    from elasticdl_tpu.layers import attention
+
+    plain_form, shapes = attention.rope_plain, []
+
+    def counted(x, *args, **kwargs):
+        shapes.append(x.shape)
+        return plain_form(x, *args, **kwargs)
+
+    monkeypatch.setattr(attention, "rope_plain", counted)
+    return shapes

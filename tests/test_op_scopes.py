@@ -9,6 +9,7 @@ that went through the program store's serialisation is the built one's."""
 import contextlib
 import functools
 import glob
+import hashlib
 import json
 import os
 import re
@@ -365,9 +366,16 @@ def _lowered(family):
     return state, step.lower(state, features, labels, weights)
 
 
+# sha256 of each family's lowered step, kept as ``built`` lowers it
+LOWERED_SHA256 = {}
+
+
 @pytest.fixture(scope="module", params=sorted(FAMILIES))
 def built(request):
     state, lowered = _lowered(request.param)
+    LOWERED_SHA256[request.param] = hashlib.sha256(
+        lowered.as_text().encode()
+    ).hexdigest()
     compiled = lowered.compile()
     return request.param, state, compiled
 
@@ -485,6 +493,44 @@ _METADATA = re.compile(r",? ?metadata=\{[^{}]*\}")
 _TABLES = re.compile(
     r"\n(FileNames|FunctionNames|FileLocations|StackFrames)\n(?:[^\n]+\n)*"
 )
+
+
+# sha256 of ``lowered.as_text()`` of each family's step at the parent of
+# PR 63 (f1ce426), from a ``git archive`` of it through ``_lowered``: PR 63
+# reaches none of these steps at 64 tokens but latent attention's, whose
+# plain rotation joins q back before the rotary key is turned where the
+# parent joined it after (the same operations in another order).  A PR that
+# changes one of these steps on purpose pins its own text here.
+PARENTS_STEP = {
+    "gpt2_block":
+        "fd3469bc5e7d713b7ddec50b02b938e1e1581cade354174852e0c46df4218cda",
+    "gpt2_block_remat":
+        "32bfd8ac88d4d58c679bd93abaee70424b93823c6142adde5aa1bc2c4ff74953",
+    "looped_stack":
+        "5a804e4f060c1a14a92e3ee7a9e75784e0324b9314fb91b3bcbcfa5288acca7f",
+    "mamba_experts_attention":
+        "a53a4b5b762fa2bea956543bd2f35b2bf1dc0549cd0c1dd21d90118ee3de1be3",
+    "olmoe":
+        "b91b006f777744c4ed3b4967cb069ff119056d6828cfd5a2711be81d588ad089",
+    "resnet_first_stage":
+        "a63d2ff069fdc8dc9da8d4bbd04ac24f6da1c1828e558f26f3ebc81aa5bf4e92",
+    "short_conv_attention_experts_tied":
+        "8edc0fbf70e5a44c602f2faea3e075bef189d4c575c09acc3ed962d5dce0ada4",
+    "sparse_attention":
+        "783fb0b0eb6c205c23c91838ccc1fd28af2329e50ae1e0698b44e9c2a706d31b",
+    "window_and_full_attention":
+        "4cfefd218fec71d3cd520698c6141f01c19f04392c48ed4f1aec430af840e8a8",
+    "window_and_yarn_attention":
+        "b3efc15557f21253f9564f47c29d2e1b1751cb267d0be22a6947a6849d61835b",
+}
+
+
+def test_a_step_the_new_forms_do_not_reach_lowers_to_the_parents_text(built):
+    family, _, _ = built
+    if family == "latent_attention_mtp":
+        assert family not in PARENTS_STEP
+    else:
+        assert LOWERED_SHA256[family] == PARENTS_STEP[family]
 
 
 def _stripped(text):
@@ -826,25 +872,45 @@ def test_sparse_attentions_kernels_are_under_their_own_parts(one_chip_mesh):
 
 
 @pytest.mark.parametrize(
-    "config,calls",
+    "config,calls,plain",
     [
         # four window layers rotate q and k (the full layer has no rope),
         # each layer recomputed: 4 x 2 calls a pass
-        ("trinity_mini_26b_a3b", 8),
-        # four sparse layers' main heads, 32 : 4 of 128; the indexer's
-        # 64-wide heads keep the plain form
-        ("keye_vl2_30b_a3b", 8),
-        # adjacent pairs on a 64-wide slice: no call
-        ("joyai_llm_flash_48b_a3b", 0),
+        ("trinity_mini_26b_a3b", {"block/attn/rope": 8}, set()),
+        # four sparse layers' main heads, 32 : 4 of 128, and since PR 63
+        # the indexer's one key of 64, in its own scope: 12 calls more.  Its
+        # 16 queries of 64 keep the plain form
+        (
+            "keye_vl2_30b_a3b",
+            {"block/attn/rope": 8, "block/attn/indexer": 4},
+            {(1, 1024, 16, 64)},
+        ),
+        # adjacent pairs on the last 64 of q's 192 lanes, the whole head in
+        # place, and on the one shared rotary key (PR 63): five latent
+        # layers, dense and expert, and the multi-token-prediction
+        # module's, 2 calls each, 36 a step where the parent had none, and
+        # nothing left to the plain form
+        (
+            "joyai_llm_flash_48b_a3b",
+            {"block/attn/rope": 10, "mtp/block/attn/rope": 2},
+            set(),
+        ),
+        # 32 : 8 heads of 64: several heads narrower than a lane tile keep
+        # the plain form (folded they lost 1.4% end to end: PERF.md section
+        # 6, PR 62), so no call, as at the parent
+        ("lfm2_24b_a2b", {}, {(1, 1024, 32, 64), (1, 1024, 8, 64)}),
     ],
 )
 def test_the_rotary_kernels_calls_in_the_cells_models(
-    one_chip_mesh, config, calls
+    one_chip_mesh, config, calls, plain, plain_rope_shapes
 ):
     """The benchmark's models at their published widths and 1,024 tokens,
     lowered for the described chip: ``ops/rotary.py``'s kernel is called
-    under ``block/attn/rope`` once an array, layer and pass where heads are
-    128 wide and rotate by halves, and nowhere else."""
+    under ``attn/rope`` (the indexer's under ``attn/indexer``) once an
+    array, layer and pass wherever a head's rotating lanes are whole
+    128-lane tiles or the 64 lanes of an array's one head, by halves or by
+    adjacent pairs, and nowhere else; ``rope_plain`` is traced for the
+    arrays of ``plain`` alone."""
     from jax._src.lib import xla_client
 
     from elasticdl_tpu.ops import rotary
@@ -855,6 +921,7 @@ def test_the_rotary_kernels_calls_in_the_cells_models(
     lowered = _lowered_for_the_chip(
         one_chip_mesh, model, lm.loss, lm.optimizer()
     )
+    assert set(plain_rope_shapes) == plain
     # the lowered module as HLO text with its metadata, without the kernels'
     # bodies: what ``scope_map`` reads of a compiled program, before XLA
     options = xla_client._xla.HloPrintOptions()
@@ -867,16 +934,65 @@ def test_the_rotary_kernels_calls_in_the_cells_models(
     for part, phase, kind, _ in scopes.values():
         if kind == "kernel" and "/rope" in part:
             found[part, phase] = found.get((part, phase), 0) + 1
-    fwd, bwd = (
-        f"block/attn/rope/{name}" for name in (rotary.ROPE_FWD, rotary.ROPE_BWD)
+    want = {}
+    for owner, count in calls.items():
+        fwd, bwd = (
+            f"{owner}/{name}"
+            for name in (rotary.ROPE_FWD, rotary.ROPE_BWD)
+        )
+        if owner.endswith("/indexer"):
+            # the indexer is differentiated inside the first pass, under
+            # the scope of the loss that asks for its gradient
+            bwd = f"block/attn/indexer_kl/indexer/TransformerLM/{bwd}"
+        want.update({
+            (fwd, "forward"): count, (fwd, "recompute"): count,
+            (bwd, "backward"): count,
+        })
+    assert found == want
+
+
+@pytest.mark.parametrize(
+    "shape,interleave,skip,components",
+    [
+        ((1, 32, 8192, 192), True, 128, False),  # latent attention's q whole
+        ((1, 1, 8192, 64), True, 0, False),  # its one shared rotary key
+        ((1, 1, 16384, 64), False, 0, True),  # the indexer's one key
+    ],
+    ids=["tail_pairs", "pairs_one_head", "half64_one_head_sections"],
+)
+def test_mosaic_takes_the_rotary_kernels_narrow_and_paired_forms(
+    one_chip_mesh, shape, interleave, skip, components
+):
+    """``rope_fwd`` / ``rope_bwd`` at the cells' shapes PR 63 brought,
+    compiled for the described chip: a roll over 64 lanes of a tile, a
+    slice of a block from lane 128 on, tables 64 wide (interpret mode
+    takes anything)."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from elasticdl_tpu.ops import rotary
+
+    whole = NamedSharding(one_chip_mesh, PartitionSpec())
+    turning = shape[3] - skip
+    sections = (
+        tuple(n * turning // 128 for n in (16, 24, 24)) if components else ()
     )
-    assert found == (
-        {
-            (fwd, "forward"): calls, (fwd, "recompute"): calls,
-            (bwd, "backward"): calls,
-        }
-        if calls else {}
+    positions = jax.ShapeDtypeStruct(
+        (shape[0], 3, shape[2]) if components else (shape[2],), jnp.int32,
+        sharding=whole,
     )
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=whole)
+
+    def both_ways(x, g, positions):
+        out, pull = jax.vjp(
+            lambda x: rotary.rotate(
+                x, positions, 1e4, sections, interleave, skip, False
+            ),
+            x,
+        )
+        return out, pull(g)[0]
+
+    text = jax.jit(both_ways).lower(x, x, positions).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
 
 
 def test_a_window_layer_and_a_yarn_layer_at_published_widths(one_chip_mesh):

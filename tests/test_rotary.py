@@ -3,6 +3,9 @@ plain ``jnp`` form ``layers/attention.py::rope`` was before the kernel
 (kept here as the reference, line for line), and the choice between the two
 that ``rope`` makes from its input's shape."""
 
+import contextlib
+import hashlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -65,10 +68,10 @@ def _operands(batch, seq, heads, width, dtype, components):
     return x, g, positions, sections
 
 
-def _both_ways(form, positions, sections):
+def _both_ways(form, positions, sections=(), **kwargs):
     def run(x, g):
         out, pull = jax.vjp(
-            lambda x: form(x, positions, 1e4, sections=sections), x
+            lambda x: form(x, positions, 1e4, sections=sections, **kwargs), x
         )
         return out, pull(g)[0]
 
@@ -131,27 +134,206 @@ def _lowered(form, x, positions, **kwargs):
     return jax.jit(rotated).lower(x).as_text()
 
 
-@pytest.mark.parametrize(
-    "shape,kwargs",
-    [
-        ((1, 1024, 16, 64), {}),  # the indexer's: a partner 32 lanes away
-        ((1, 1024, 4, 128), {"interleave": True}),  # adjacent pairs
-        ((2, 1, 4, 128), {}),  # a decode step
-        ((2, 64, 4, 128), {}),  # a small model: less than a tile of rows
-        ((1, 1024, 2, 192), {}),  # a head that is no whole number of tiles
-    ],
-    ids=["width64", "interleave", "decode", "few_rows", "width192"],
-)
-def test_every_other_shape_lowers_to_the_text_it_did(shape, kwargs):
-    x = jnp.zeros(shape, jnp.bfloat16)
-    positions = jnp.arange(shape[1])
-    assert _calls(rope, x, positions, **kwargs) == []
-    assert _lowered(rope, x, positions, **kwargs) == _lowered(
-        reference_rope, x, positions, **kwargs
+def reference_tail(x, positions, theta, interleave=False, sections=(), skip=0):
+    """A head whose last lanes rotate, as ``LatentSelfAttention`` spelled
+    it before the kernel took the whole head: slice, turn, join."""
+    if not skip:
+        return reference_rope(x, positions, theta, interleave, sections)
+    turned = reference_rope(
+        x[..., skip:], positions, theta, interleave, sections
+    )
+    return jnp.concatenate([x[..., :skip], turned], axis=-1)
+
+
+# the shapes the kernel takes since PR 63 beside the one it took before:
+# (heads, width, rope()'s keywords, positions of three components).  A head
+# of 64 is taken where it is its array's one head; several of them keep the
+# plain form (``test_every_other_shape_lowers_to_the_text_it_did``)
+FORMS = {
+    # rotate-half with the partner 32 lanes away inside a lane tile: the
+    # indexer's one key, with ``sections`` and without
+    "half64_one_head": (1, 64, {}, False),
+    "half64_one_head_sections": (1, 64, {}, True),
+    # adjacent pairs: the one rotary key latent attention's heads share
+    "pairs64_shared_head": (1, 64, {"interleave": True}, False),
+    "pairs128": (4, 128, {"interleave": True}, False),
+    # the whole 192-wide head, its first 128 lanes passing through
+    "tail_pairs": (8, 192, {"interleave": True, "skip": 128}, False),
+    "tail_two_tiles_sections": (2, 256, {"skip": 128}, True),
+    # what it took before
+    "half128": (4, 128, {}, False),
+}
+
+
+def _in(dtype, array):
+    """``array`` (float64) rounded to float32 and then to ``dtype``, as
+    float32 for comparing."""
+    return np.asarray(
+        jnp.asarray(array.astype(np.float32)).astype(dtype), np.float32
     )
 
 
+def _or_one_product_contracted(got, want, operand, tables, partner, dtype):
+    """``got`` is ``want`` bit for bit, or where it is not, it is ``operand *
+    C + partner(operand) * S`` with one of the two products left unrounded
+    in the sum: XLA's CPU backend contracts a multiply into the add after it
+    (a fused multiply-add) in the interpreted kernel or in the plain form,
+    whichever way the expression came out, where the chip, which has no
+    such instruction, gives the same bits.  The three ways are evaluated
+    here in float64, in which a product of two float32 numbers is exact.
+    Returns the share of elements that are ``want`` bit for bit."""
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    cos, sin = (np.asarray(table, np.float64) for table in tables)
+    x = np.asarray(operand, np.float64)
+    ours, partners = x * cos, partner(x) * sin
+
+    def rounded(product):
+        return product.astype(np.float32).astype(np.float64)
+
+    ways = [
+        _in(dtype, a + b)
+        for a, b in (
+            (rounded(ours), rounded(partners)),
+            (ours, rounded(partners)),
+            (rounded(ours), partners),
+        )
+    ]
+    equal = got == want
+    explained = equal | np.logical_or.reduce([got == way for way in ways])
+    assert explained.all(), np.abs(got - want)[~explained].max()
+    return equal.mean()
+
+
+def _form_operands(form, rows, dtype, batch=2):
+    """``_operands`` of a form of ``FORMS`` and ``rope``'s keywords for it,
+    the sections of three components in proportion to the rotating lanes."""
+    heads, width, kwargs, components = FORMS[form]
+    x, g, positions, _ = _operands(batch, rows, heads, width, dtype, components)
+    if components:
+        turning = width - kwargs.get("skip", 0)
+        kwargs = {
+            **kwargs, "sections": tuple(n * turning // 128 for n in SECTIONS)
+        }
+    return x, g, positions, kwargs
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("form", sorted(FORMS))
+def test_every_form_of_the_kernel_is_the_plain_form(form, dtype):
+    """Values and gradient bit for bit, but for the elements where XLA's
+    CPU backend contracted a product into the sum on one side and not on
+    the other, which are held to exactly that
+    (``_or_one_product_contracted``; on the chip both are equal bit for bit
+    everywhere, ``benchmarks/rope_sweep.py``), and the lanes that pass
+    through untouched both ways; 656 rows, no multiple of the row tile."""
+    x, g, positions, kwargs = _form_operands(form, 656, dtype)
+    skip = kwargs.get("skip", 0)
+    interleave = kwargs.get("interleave", False)
+    assert rotary.rotate_tile(x.shape, skip) is not None
+    assert _calls(rope, x, positions, **kwargs) == [rotary.ROPE_FWD]
+    out, d_x = _both_ways(rope, positions, **kwargs)(x, g)
+    want, want_d_x = _both_ways(reference_tail, positions, **kwargs)(x, g)
+    assert out.dtype == want.dtype == dtype and d_x.dtype == dtype
+    for ours, theirs in ((out, want), (d_x, want_d_x)):
+        np.testing.assert_array_equal(
+            np.asarray(ours[..., :skip], np.float32),
+            np.asarray(theirs[..., :skip], np.float32),
+        )
+    turning = x.shape[-1] - skip
+    cos, sin = (
+        np.asarray(table)[..., None, :]  # over the heads
+        for table in rotary.tables(
+            positions, 1e4, turning, kwargs.get("sections", ()), interleave
+        )
+    )
+
+    def partner(x):
+        # the pair's other lane, or the lane half the rotating lanes away
+        if interleave:
+            pairs = x.reshape(*x.shape[:-1], turning // 2, 2)
+            return pairs[..., ::-1].reshape(x.shape)
+        return np.roll(x, turning // 2, axis=-1)
+
+    # the values nearly everywhere the plain form's own bits ...
+    assert _or_one_product_contracted(
+        out[..., skip:], want[..., skip:], x[..., skip:], (cos, sin),
+        partner, dtype,
+    ) > 0.999
+    # ... and the gradient, the same body with S negated, mostly
+    assert _or_one_product_contracted(
+        d_x[..., skip:], want_d_x[..., skip:], g[..., skip:], (cos, -sin),
+        partner, dtype,
+    ) > 0.7
+
+
+@pytest.mark.parametrize("form", sorted(FORMS))
+def test_every_form_keeps_the_positions_alone_and_calls_once_a_pass(form):
+    x, g, positions, kwargs = _form_operands(form, 512, jnp.bfloat16, batch=1)
+    _, pull = jax.vjp(lambda x: rope(x, positions, 1e4, **kwargs), x)
+    kept = jax.tree_util.tree_leaves(pull)
+    assert max(leaf.size for leaf in kept) <= positions.size
+    text = str(jax.make_jaxpr(lambda g: pull(g)[0])(g))
+    assert text.count(rotary.ROPE_BWD) == 1 and rotary.ROPE_FWD not in text
+
+
+def _sp_mesh():
+    return on_mesh.attention_mesh_scope(
+        MeshConfig.from_string("dp=1,sp=2").create(devices=jax.devices()[:2])
+    )
+
+
+@pytest.mark.parametrize(
+    "shape,kwargs,mesh",
+    [
+        ((2, 1, 4, 128), {}, None),  # a decode step
+        ((2, 1, 16, 64), {}, None),
+        ((2, 1, 4, 192), {"interleave": True, "skip": 128}, None),
+        ((2, 64, 4, 128), {}, None),  # a small model: less than a tile of rows
+        ((2, 496, 16, 64), {}, None),
+        ((2, 496, 32, 64), {"interleave": True}, None),
+        ((2, 496, 32, 192), {"interleave": True, "skip": 128}, None),
+        # several heads narrower than a tile: the indexer's queries,
+        # LFM2's q and k, a rotary slice handed alone
+        ((1, 1024, 16, 64), {}, None),
+        ((4, 1024, 8, 64), {}, None),
+        ((1, 1024, 32, 64), {"interleave": True}, None),
+        ((1, 1024, 2, 192), {}, None),  # a head that is no whole number of tiles
+        ((1, 1024, 4, 96), {}, None),  # nor a half tile
+        ((1, 1024, 4, 96), {"interleave": True}, None),
+        # lanes that pass through and are no whole tiles
+        ((1, 1024, 4, 128), {"skip": 64}, None),
+        ((1, 1024, 4, 160), {"interleave": True, "skip": 96}, None),
+        # a sequence sharded over sp
+        ((1, 1024, 1, 64), {}, _sp_mesh),
+        ((1, 1024, 1, 64), {"interleave": True}, _sp_mesh),
+        ((1, 1024, 32, 192), {"interleave": True, "skip": 128}, _sp_mesh),
+    ],
+    ids=[
+        "decode", "decode_width64", "decode_tail", "few_rows",
+        "few_rows_width64", "few_rows_pairs", "few_rows_tail",
+        "heads_of_64", "grouped_heads_of_64", "paired_heads_of_64", "width192",
+        "width96", "width96_pairs", "skip64", "skip96", "sp_width64",
+        "sp_pairs", "sp_tail",
+    ],
+)
+def test_every_other_shape_lowers_to_the_text_it_did(shape, kwargs, mesh):
+    """What keeps the plain form lowers to the parent's text: the rotating
+    lanes through the lines ``rope`` was before the kernel, a tail sliced
+    out, turned and joined back as ``LatentSelfAttention`` did it."""
+    x = jnp.zeros(shape, jnp.bfloat16)
+    positions = jnp.arange(shape[1])
+    with mesh() if mesh else contextlib.nullcontext():
+        assert _calls(rope, x, positions, **kwargs) == []
+        assert _lowered(rope, x, positions, **kwargs) == _lowered(
+            reference_tail, x, positions, **kwargs
+        )
+
+
 def test_components_on_a_narrow_head_lower_to_the_text_they_did():
+    """The indexer's queries at two tiles of rows, 16 heads of 64 under
+    three components' sections: several heads narrower than a lane tile
+    keep the plain form (its one key of 64 takes the kernel:
+    ``test_every_form_of_the_kernel_is_the_plain_form``)."""
     x = jnp.zeros((2, 1024, 16, 64), jnp.bfloat16)
     positions = jnp.zeros((2, 3, 1024), jnp.int32)
     kwargs = {"sections": (8, 12, 12)}
@@ -159,6 +341,67 @@ def test_components_on_a_narrow_head_lower_to_the_text_they_did():
     assert _lowered(rope, x, positions, **kwargs) == _lowered(
         reference_rope, x, positions, **kwargs
     )
+
+
+# sha256 of what ``rope`` and its gradient lowered to at the parent of PR 63
+# (f1ce426, interpreted kernels, which are ordinary HLO here), by
+# ``_text_both_ways`` below: the shapes that took the kernel before PR 63
+# lower to the same text after it
+PARENTS_TEXT = {
+    "index": "a64e9fd17e2cf81145223cae029cbd34b57a6b77ddd911747bbea6ca642fa714",
+    "mrope": "82ed6cd972823faa5da57145f76637e7f0497d47cdd8c06c6e43d4958bc7550c",
+    "two_tiles": "5a47c4b61dad8efdb5d188a990be6ca12bef1c3c7f28221e0f00bf52f01c08cb",
+}
+
+
+def _text_both_ways(shape, positions, **kwargs):
+    x = jnp.zeros(shape, jnp.bfloat16)
+
+    def run(x, g):
+        out, pull = jax.vjp(lambda x: rope(x, positions, 1e4, **kwargs), x)
+        return out, pull(g)[0]
+
+    return jax.jit(run).lower(x, x).as_text()
+
+
+@pytest.mark.parametrize(
+    "case,shape,components",
+    [
+        ("index", (1, 1024, 4, 128), False),
+        ("mrope", (2, 1024, 32, 128), True),
+        ("two_tiles", (1, 528, 3, 256), False),
+    ],
+)
+def test_whole_tiles_by_halves_lower_to_the_parents_text(
+    case, shape, components
+):
+    positions, kwargs = jnp.arange(shape[1]), {}
+    if components:
+        positions = jnp.zeros((shape[0], 3, shape[1]), jnp.int32)
+        kwargs = {"sections": tuple(n * shape[3] // 128 for n in SECTIONS)}
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    assert _calls(rope, x, positions, **kwargs) == [rotary.ROPE_FWD]
+    text = _text_both_ways(shape, positions, **kwargs)
+    assert hashlib.sha256(text.encode()).hexdigest() == PARENTS_TEXT[case]
+
+
+@pytest.mark.parametrize(
+    "shape,skip,tile",
+    [
+        # the cells' shapes since PR 63, (batch, tokens, heads, width)
+        ((1, 8192, 32, 192), 128, (512, 4)),  # latent attention's q whole
+        ((1, 8192, 1, 64), 0, (512, 1)),  # its one shared rotary key
+        ((1, 16384, 1, 64), 0, (512, 1)),  # the indexer's one key
+        ((1, 16384, 16, 64), 0, None),  # its queries: several narrow heads
+        ((4, 4096, 32, 64), 0, None),  # LFM2's q
+        ((4, 4096, 8, 64), 0, None),  # and k
+        ((1, 8192, 32, 64), 0, None),  # a rotary slice handed alone
+        ((1, 8192, 32, 192), 0, None),  # 192 lanes that all rotate
+        ((1, 8192, 32, 192), 64, None),  # lanes passing through: no tile
+    ],
+)
+def test_the_one_chooser_of_what_the_kernel_takes(shape, skip, tile):
+    assert rotary.rotate_tile(shape, skip) == tile
 
 
 @pytest.mark.parametrize("heads", [32, 4])
