@@ -17,9 +17,11 @@ what a dense flash kernel reads), the causal pairs' index scores for
 operations of the roofline), the selected pairs' two gradient products for
 ``dsa_kl`` (the main attention's scores and the index scores it computes
 again are recomputation).  Every layer is recomputed in the backward pass, so
-``dsa_index`` and ``dsa_fwd`` run twice a layer and step, and ``dsa_kl`` runs
-once for its value (the first forward pass) and once with its gradients (the
-backward pass) under the one name; each is counted once.  All five come out bound by compute (``least_seconds`` says
+``dsa_index`` (the recomputed pass as ``dsa_index_hinted``) and ``dsa_fwd``
+run twice a layer and step; ``dsa_kl`` runs ONCE a layer and step since PR 43
+(the first forward pass makes the indexer's loss and its parameter gradients
+in one call and hands them to the recomputed pass); each is counted once.
+All five come out bound by compute (``least_seconds`` says
 so per kernel); none is near its bound: they are the baseline a perf_opt PR
 starts from."""
 

@@ -4,8 +4,8 @@ the same ``LocalExecutor``, subclassed only to mark task boundaries.
 
 The probe closes every interval with a host readback of ``state.step``,
 which data-depends on every dispatched optimizer step: each counted
-record's update exists on the device when its interval is timed
-(``bench.py::_measure_e2e``'s window rule, applied per interval)."""
+record's update exists on the device when its interval is timed (a
+window closed by a readback of the step counter, applied per interval)."""
 
 from __future__ import annotations
 

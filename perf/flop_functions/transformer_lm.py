@@ -21,11 +21,10 @@ def train_flops_per_token(
 def causal_attention_train_flops_per_token(
     layers: int, d_model: int, seq_len: int
 ) -> float:
-    """``6*L*T*d`` (bench.py's ``_causal_attn_flops`` per token: 6*L*B*T^2*d
-    a step).  Forward QK^T and PV over the causal half are 2*T*d a token
-    and layer; the backward's dQ, dK, dV and dP are twice that.  The flash
-    backward also recomputes the score matrix (another ~T*d): recomputation,
-    so not counted."""
+    """``6*L*T*d`` a token (``6*L*B*T^2*d`` a step).  Forward QK^T and PV
+    over the causal half are 2*T*d a token and layer; the backward's dQ, dK,
+    dV and dP are twice that.  The flash backward also recomputes the score
+    matrix (another ~T*d): recomputation, so not counted."""
     return 6.0 * layers * seq_len * d_model
 
 

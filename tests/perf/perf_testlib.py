@@ -50,6 +50,13 @@ NEW_LAYER = "rehearsal layer (tests/perf only)"
 # that read the manifest are run once more over a copy that a later PR's
 # additions were made to
 MANIFEST_COPY = "PERF_TESTS_MANIFEST_COPY"
+# set by that test's ``run_over`` beside it: ``"plain"`` in a run that leaves
+# the guards out (they would start runs of their own without end), ``"guards"``
+# in the one run over a grown copy that takes them too, each growing its copy
+# again (PR 64).  "My manifest is a copy" and "I am the inner run of a guard"
+# are two things: a pin that sits in a guard is seen only where a guard runs
+# over a manifest that has grown.
+INNER_RUN = "PERF_TESTS_INNER_RUN"
 
 
 def repo_manifest() -> dict:

@@ -1,6 +1,7 @@
 """BENCHMARK.json against the benchmark's contract, as far as a test
 without a chip can hold it, and every cell's files resolved by name."""
 
+import collections
 import copy
 import glob
 import json
@@ -12,6 +13,7 @@ import sys
 import pytest
 from perf_testlib import (
     HBM_READERS,
+    INNER_RUN,
     MANIFEST_COPY,
     NEW_LAYER,
     ROOT,
@@ -209,69 +211,99 @@ def test_an_unknown_name_is_an_error():
 # those out, and takes every other test of every ``test_perf_*.py`` it finds,
 # so a file that a later PR adds is in it without being named anywhere
 LEFT_OUT = "not slow and not compiles_a_model"
-# what the run over the copy counted when PR 59 wrote it (581 passed, the six
-# that are for BENCHMARK.json alone skipped): a later PR's tests add to it
-GROWN_RUN_PASSES = 581
-ADDED_CELL = "added_cell"
-MADE_UP = "made_up_share.added"
+# somewhat under what the run over the copy counted when PR 64 read it (599
+# passed; 581 when PR 59 wrote it; the eight cases of the guards skipped):
+# a later PR's tests add to it
+GROWN_RUN_PASSES = 595
+# the guards: they start runs of their own, so a run that one of them started
+# leaves them out, but for the ONE run that is told to take them (``run_over``)
+GUARD = pytest.mark.skipif(
+    os.environ.get(INNER_RUN) == "plain", reason="this is the inner run of a guard"
+)
+
+
+def suffix_of(name: str) -> str | None:
+    return name.rpartition(".")[2] if "." in name else None
+
+
+def growth(manifest) -> str:
+    """What tells one growth's names from the one before: nothing for the
+    first (``added_cell``, ``made_up_share.lm``), then ``2``, ``3`` ..."""
+    grown = sum(w["name"].startswith("added_cell") for w in manifest["workloads"])
+    return str(grown + 1) if grown else ""
+
+
+def added_cell(manifest) -> str:
+    return "added_cell" + growth(manifest)
 
 
 def made_up_names(manifest) -> list:
     """One made-up ``per_layer`` name for every suffix the list's names carry
     (``.lm``, ``.swa``, ``.conv`` ...), so that a test which finds "its"
-    entries by a suffix, or counts them, meets one more; ``MADE_UP`` last."""
-    suffixes = []
-    for name in (m["name"] for m in manifest["per_layer"]):
-        suffix = name.rpartition(".")[2]
-        if "." in name and suffix not in suffixes:
-            suffixes.append(suffix)
-    return [f"made_up_share.{suffix}" for suffix in suffixes] + [MADE_UP]
+    entries by a suffix, or counts them, meets one more; then two under a
+    suffix no name carries, a new kernel's, the last of them the list's new
+    tail."""
+    tag = growth(manifest)
+    # each suffix once, in the order the list first carries it
+    suffixes = list(dict.fromkeys(
+        filter(None, (suffix_of(m["name"]) for m in manifest["per_layer"]))
+    ))
+    assert "added" + tag not in suffixes
+    return [f"made_up_share{tag}.{suffix}" for suffix in suffixes] + [
+        f"made_up_roofline{tag}.added{tag}", f"made_up_share{tag}.added{tag}",
+    ]
 
 
-def grown_manifest(directory: str, cell: str = "gpt2s_seq8192") -> dict:
-    """``BENCHMARK.json`` as later ``model_config`` PRs leave it: one more
-    configuration and one more cell (copies of ``cell``'s under new names, the
-    cell's name appended to every list the original is on) and made-up
-    ``per_layer`` entries at the end of the list (``made_up_names``), the
-    added cell's alone.  The new files sit in ``directory``, which joins
-    ``paths`` (absolute, in this copy alone)."""
-    manifest = copy.deepcopy(repo_manifest())
+def grown_manifest(directory: str, cell: str = "gpt2s_seq8192", base=None) -> dict:
+    """``base`` (the manifest these tests read, if none is given) as the next
+    ``model_config`` PR leaves it: one more configuration and one more cell
+    (copies of ``cell``'s under new names, the cell's name appended to every
+    list the original is on) and made-up ``per_layer`` entries at the end of
+    the list (``made_up_names``), the added cell's alone.  The new files sit
+    in ``directory``, which joins ``paths`` (absolute, in this copy alone).
+    A grown manifest grows again: the names carry ``growth``'s number."""
+    manifest = copy.deepcopy(repo_manifest() if base is None else base)
+    tag, new_cell, made_up = growth(manifest), added_cell(manifest), made_up_names(manifest)
+    new_config = "added_config" + tag
     source = next(w for w in manifest["workloads"] if w["name"] == cell)
     entry = next(c for c in manifest["configs"] if c["name"] == source["config"])
     config = manifest_lib.load_json(os.path.join(ROOT, entry["file"]))
-    config["name"] = "added_config"
+    config["name"] = new_config
     os.makedirs(os.path.join(directory, "configs"))
     os.makedirs(os.path.join(directory, "layer_metrics"))
-    with open(os.path.join(directory, "configs", "added_config.json"), "w") as f:
+    with open(os.path.join(directory, "configs", new_config + ".json"), "w") as f:
         json.dump(config, f)
     manifest["paths"].append(directory)
     manifest["configs"].append({
-        **entry, "name": "added_config",
-        "file": os.path.join(directory, "configs", "added_config.json"),
+        **entry, "name": new_config,
+        "file": os.path.join(directory, "configs", new_config + ".json"),
     })
-    manifest["workloads"].append({**source, "name": ADDED_CELL, "config": "added_config"})
+    manifest["workloads"].append({**source, "name": new_cell, "config": new_config})
     for metric in manifest["end_to_end"] + manifest["per_layer"]:
         if cell in metric.get("workloads", []):
-            metric["workloads"].append(ADDED_CELL)
+            metric["workloads"].append(new_cell)
     layer = manifest["per_layer"][0]["layer"]
-    for name in made_up_names(manifest):
+    for name in made_up:
         with open(os.path.join(directory, "layer_metrics", name + ".py"), "w") as f:
             f.write("def read(run):\n    return (run.get('made_up') or {}).get('share')\n")
         manifest["per_layer"].append({
             "name": name, "unit": "%", "better": "lower", "source": "device_trace",
             "layer": layer, "moves": config["work"]["rate_metric"],
-            "workloads": [ADDED_CELL],
+            "workloads": [new_cell],
         })
     return manifest
 
 
-def run_over(manifest_path, directory=os.path.join(ROOT, "tests", "perf")):
+def run_over(manifest_path, directory=os.path.join(ROOT, "tests", "perf"), guards=False):
     """Every ``test_perf_*.py`` of ``directory`` but what ``LEFT_OUT`` leaves
-    out, in a process of its own whose manifest is ``manifest_path``."""
+    out, in a process of its own whose manifest is ``manifest_path``.  The
+    guards are left out of it too, unless ``guards`` asks for them and this
+    process is no inner run itself: one level of them, never a second."""
     files = sorted(glob.glob(os.path.join(directory, "test_perf_*.py")))
     assert files, directory
     env = {k: v for k, v in os.environ.items() if k != "BENCH_RUN"}
     env[MANIFEST_COPY] = str(manifest_path)
+    env[INNER_RUN] = "guards" if guards and INNER_RUN not in os.environ else "plain"
     # a file outside tests/perf finds ``perf_testlib`` as those inside do
     env["PYTHONPATH"] = os.pathsep.join(
         [os.path.join(ROOT, "tests", "perf"), ROOT, env.get("PYTHONPATH", "")]
@@ -288,86 +320,142 @@ def passed(done) -> int:
     return int(found.group(1)) if found else 0
 
 
-@pytest.mark.skipif(MANIFEST_COPY in os.environ, reason="this is the run over the copy")
-@pytest.mark.parametrize("source", ["gpt2s_seq8192", "gpt2s_seq1024_dp4"])
-def test_a_grown_manifest_passes_every_test_that_reads_the_manifest(tmp_path, source):
+@GUARD
+@pytest.mark.parametrize(
+    "source,guards", [("gpt2s_seq8192", False), ("gpt2s_seq1024_dp4", True)],
+    ids=["gpt2s_seq8192", "gpt2s_seq1024_dp4_and_the_guards"],
+)
+def test_a_grown_manifest_passes_every_test_that_reads_the_manifest(tmp_path, source, guards):
     """The lists of configurations, cells and ``per_layer`` entries are open
     at their ends: the tests that read the manifest, run over a copy with one
     more of each, pass as they do over ``BENCHMARK.json``.  The files are
     found by their names, so the check holds for a file that a later PR adds;
     a PR that adds a cell runs THIS test first.  Once with a cell on one chip
-    and once with one on four (the quota has room for it)."""
+    and once with one on four (the quota has room for it); **the second run
+    takes the guards too** (PR 64), so each of them runs once over a manifest
+    that has ALREADY grown by a configuration, a cell and a new suffix's
+    entries, and grows it again: the rehearsal of the PR after the next one,
+    which sees a pin that sits in a guard (PR 59's planted literal did, and
+    no run over a copy ever ran the test that held it)."""
+    if guards and INNER_RUN in os.environ:
+        pytest.skip("the guards are taken one level deep")
     path = tmp_path / "BENCHMARK.json"
     path.write_text(json.dumps(grown_manifest(str(tmp_path / "added"), source)))
-    done = run_over(path)
+    done = run_over(path, guards=guards)
     assert done.returncode == 0, done.stdout[-6000:]
     # every case ran, the copy's own among them (the added cell, the added
     # configuration, the made-up entries)
     assert passed(done) >= GROWN_RUN_PASSES, done.stdout.strip().splitlines()[-1]
+    if guards:
+        # the guards' own cases ran and passed, all but the one that is
+        # taken one level deep and no deeper
+        assert passed(done) >= GROWN_RUN_PASSES + 7, done.stdout.strip().splitlines()[-1]
+        assert "1 skipped" in done.stdout.strip().splitlines()[-1]
 
 
-PLANTED_PINS = '''
+PINS = """
 from perf_testlib import repo_manifest
 
 
-def test_the_memory_entries_are_the_last_of_the_list():
-    assert repo_manifest()["per_layer"][-1]["name"] == "hbm_unexplained_gb"
+def test_the_lists_tail_is_where_it_was():
+    assert repo_manifest()["per_layer"][-1]["name"] == {tail!r}
 
 
-def test_there_are_eight_window_entries():
+def test_the_suffix_counts_what_it_counted():
     names = [m["name"] for m in repo_manifest()["per_layer"]]
-    assert len([name for name in names if name.endswith(".swa")]) == 8
-'''
+    assert len([name for name in names if name.endswith({suffix!r})]) == {count}
+"""
+# PR 59's planted file, a literal that had to PASS over ``BENCHMARK.json`` as
+# shipped: no PR but a ``benchmark`` PR could append a ``per_layer`` entry
+# while it stood (PR 60 and PR 61 met it)
+PINS_OF_PR_59 = PINS.format(tail="hbm_unexplained_gb", suffix=".swa", count=8)
 
 
-@pytest.mark.skipif(MANIFEST_COPY in os.environ, reason="this is the run over the copy")
-def test_a_test_that_pins_the_lists_tail_fails_the_run_over_a_grown_manifest(tmp_path):
-    """The guard bites: a test file that holds an entry to the end of
-    ``per_layer``, as ``test_perf_trinity.py`` held the ``.swa`` entries up
-    to PR 59, or counts the entries of one suffix, passes over
-    ``BENCHMARK.json`` and fails the run over the copy, found by the same
-    glob."""
+def planted_pins(manifest) -> str:
+    """A test file that pins what ``manifest`` holds today: the name of its
+    last ``per_layer`` entry, and the count of the suffix it has most entries
+    of.  Made from the manifest the guard is run over, so it passes there
+    whatever a PR appended, and fails over that manifest grown."""
+    names = [m["name"] for m in manifest["per_layer"]]
+    counts = collections.Counter(filter(None, map(suffix_of, names)))
+    suffix, count = counts.most_common(1)[0]
+    return PINS.format(tail=names[-1], suffix="." + suffix, count=count)
+
+
+def plant(tmp_path, text: str) -> str:
     planted = tmp_path / "planted"
     planted.mkdir()
-    (planted / "test_perf_planted_pin.py").write_text(PLANTED_PINS)
+    (planted / "test_perf_planted_pin.py").write_text(text)
+    return str(planted)
+
+
+@GUARD
+@pytest.mark.parametrize("grown_before", [0, 1], ids=["as_it_stands", "grown_once_before"])
+def test_a_test_that_pins_the_lists_tail_fails_the_run_over_a_grown_manifest(
+    tmp_path, grown_before
+):
+    """The guard bites, at any length: a test file that holds an entry to the
+    end of ``per_layer``, as ``test_perf_trinity.py`` held the ``.swa``
+    entries up to PR 59, or counts the entries of one suffix, passes over the
+    manifest it was made from and fails the run over that manifest grown,
+    found by the same glob.  Over the manifest as these tests read it, and
+    over one that a ``model_config`` PR has already appended to."""
+    manifest = repo_manifest()
+    for n in range(grown_before):
+        manifest = grown_manifest(str(tmp_path / f"before{n}"), base=manifest)
+    planted = plant(tmp_path, planted_pins(manifest))
     path = tmp_path / "BENCHMARK.json"
-    path.write_text(json.dumps(repo_manifest()))
-    done = run_over(path, str(planted))
+    path.write_text(json.dumps(manifest))
+    done = run_over(path, planted)
     assert (done.returncode, passed(done)) == (0, 2), done.stdout[-3000:]
-    path.write_text(json.dumps(grown_manifest(str(tmp_path / "added"))))
-    done = run_over(path, str(planted))
+    path.write_text(json.dumps(grown_manifest(str(tmp_path / "added"), base=manifest)))
+    done = run_over(path, planted)
     assert done.returncode == 1 and "2 failed" in done.stdout, done.stdout[-3000:]
-    assert "test_the_memory_entries_are_the_last_of_the_list" in done.stdout
-    assert "test_there_are_eight_window_entries" in done.stdout
+    assert "test_the_lists_tail_is_where_it_was" in done.stdout
+    assert "test_the_suffix_counts_what_it_counted" in done.stdout
 
 
-@pytest.mark.skipif(MANIFEST_COPY in os.environ, reason="this is the run over the copy")
+@GUARD
+def test_the_literal_pr_59_planted_fails_over_a_manifest_grown_once(tmp_path):
+    """The wall PR 64 took down.  PR 59's guard planted its pins as text and
+    needed them to pass over ``BENCHMARK.json``: over a manifest with one
+    entry appended, which is every ``model_config`` PR that brings a kernel,
+    that first half could not pass, and the guard failed the PR for it."""
+    path = tmp_path / "BENCHMARK.json"
+    path.write_text(json.dumps(grown_manifest(str(tmp_path / "added"))))
+    done = run_over(path, plant(tmp_path, PINS_OF_PR_59))
+    assert done.returncode == 1, done.stdout[-3000:]
+    assert "FAILED" in done.stdout and "test_the_lists_tail_is_where_it_was" in done.stdout
+    assert "'hbm_unexplained_gb'" in done.stdout
+
+
+@GUARD
 @pytest.mark.parametrize(
     "source", ["gpt2s_seq8192", "resnet50_imagenet_resident", "lfm2_24b_a2b_seq4096x4"]
 )
 def test_a_cell_added_to_a_grown_manifest_reports_what_its_source_reports(tmp_path, source):
     """The next ``model_config`` PR, rehearsed: ``perf/manifest.py`` loads the
     grown copy, and the added cell reports every metric its source reports,
-    ``peak_hbm_gb``'s five among them, and the entry added after them all."""
+    ``peak_hbm_gb``'s five among them, and the entries added after them all."""
     if source not in CELLS:
         pytest.skip(f"{source} is not a cell of this manifest")
     path = tmp_path / "BENCHMARK.json"
     path.write_text(json.dumps(grown_manifest(str(tmp_path / "added"), source)))
     grown = manifest_lib.load_manifest(str(path))
-    made_up = made_up_names(MANIFEST)
+    made_up, added_name = made_up_names(MANIFEST), added_cell(MANIFEST)
     for group, more in (("configs", 1), ("workloads", 1), ("per_layer", len(made_up))):
         assert len(grown[group]) == len(MANIFEST[group]) + more
-    assert grown["workloads"][-1]["name"] == ADDED_CELL
+    assert grown["workloads"][-1]["name"] == added_name
     assert [m["name"] for m in grown["per_layer"][-len(made_up):]] == made_up
     # nothing that was there moved or changed but by the added cell's name
     for group in ("end_to_end", "per_layer"):
         for old, new in zip(MANIFEST[group], grown[group]):
             lists = new.get("workloads", [])
-            assert {**new, "workloads": [c for c in lists if c != ADDED_CELL]} == {
+            assert {**new, "workloads": [c for c in lists if c != added_name]} == {
                 **old, "workloads": old.get("workloads", [])
             }
-            assert ADDED_CELL not in lists[:-1]
-    added, original = manifest_lib.Cell(grown, ADDED_CELL), manifest_lib.Cell(grown, source)
+            assert added_name not in lists[:-1]
+    added, original = manifest_lib.Cell(grown, added_name), manifest_lib.Cell(grown, source)
     for group in ("end_to_end", "per_layer"):
         theirs = [m["name"] for m in original.metrics(group)]
         mine = [m["name"] for m in added.metrics(group)]

@@ -74,6 +74,15 @@ def test_rehearsal_on_cpu(tmp_path, trace, seed, cell):
         assert info["checks"]["none_failed"]
     # the rate is the window's total, with the readings' median beside it
     assert info["total_over_window"] == info["units"] / info["window_s"]
+    # ``setup_s`` is the set-up the repo's code does, the four parts after
+    # the imports and the runtime's start; those, and the whole figure from
+    # the process's first line, stay on the info line beside it (PR 64)
+    parts = info["setup_parts"]
+    assert list(parts) == ["imports_s", "data_s", "build_s", "warmup_s", "fill_s"]
+    assert all(seconds >= 0 for seconds in parts.values())
+    assert abs(info["setup_s"] - (sum(parts.values()) - parts["imports_s"])) < 1e-3
+    assert abs(info["since_process_start_s"] - sum(parts.values())) < 1e-3
+    assert 0 < info["setup_s"] < info["since_process_start_s"]
     if trace:
         assert info["untraced_rate"] > 0 and info["traced_rate"] > 0
         # the comparison with the plain reference the configuration names
