@@ -152,6 +152,9 @@ class MultiHeadSelfAttention(nn.Module):
     # the heads' merged output times ``sigmoid(gate(x))``, a projection of
     # its width, before the output projection (AFMoE's gated attention)
     output_gate: bool = False
+    # > 0: the rotary positions turn a head's FIRST ``rotary_dim`` lanes, the
+    # others pass through (``partial_rotary_factor`` x the head's width)
+    rotary_dim: int = 0
 
     @nn.compact
     def __call__(self, x, decode_pos=None, positions=None):
@@ -211,8 +214,9 @@ class MultiHeadSelfAttention(nn.Module):
                         else decode_pos + jnp.arange(x.shape[1])
                     )
                 sections = tuple(self.mrope_section)
-                q = rope(q, positions, self.rope_theta, sections=sections)
-                k = rope(k, positions, self.rope_theta, sections=sections)
+                lead = self.rotary_dim
+                q = rope(q, positions, self.rope_theta, sections=sections, lead=lead)
+                k = rope(k, positions, self.rope_theta, sections=sections, lead=lead)
         if self.index_topk and (self.decode or not self.causal):
             raise NotImplementedError(
                 "sparse attention is built for causal training: the "
@@ -545,10 +549,10 @@ def make_norm(kind: str, epsilon: float, dtype, name=None):
 # one mixer OR one feed-forward part under one pre-norm residual
 # (``w``: the attention part under the block's ``window``, a query reading
 # its last ``window`` keys alone; ``*`` beside it reads every earlier key;
-# ``c``: the gated short convolution)
+# ``c``: the gated short convolution; ``d``: the gated delta rule)
 LAYER_KINDS = {
     "*": "attention", "M": "mamba", "E": "experts", "-": "mlp",
-    "w": "attention", "c": "conv",
+    "w": "attention", "c": "conv", "d": "delta",
 }
 
 
@@ -591,6 +595,8 @@ class TransformerBlock(nn.Module):
     moe_fields: Any = ()
     # layers.mamba.Mamba2Mixer's
     mamba_fields: Any = ()
+    # layers.gated_delta.GatedDeltaNet's
+    delta_fields: Any = ()
 
     @nn.compact
     def __call__(
@@ -659,6 +665,19 @@ class TransformerBlock(nn.Module):
             **dict(self.mamba_fields),
         )(y)
 
+    def _delta(self, y, training, decode_pos):
+        from elasticdl_tpu.layers.gated_delta import GatedDeltaNet
+
+        if self.decode:
+            raise NotImplementedError(
+                "decoding through a gated-delta-rule layer's state is not "
+                "built"
+            )
+        return GatedDeltaNet(
+            norm_eps=self.norm_eps, dtype=self.dtype, name="gdn",
+            **dict(self.delta_fields),
+        )(y)
+
     def _conv(self, y, training, decode_pos):
         from elasticdl_tpu.layers.short_conv import ShortConv
 
@@ -713,7 +732,8 @@ def _rope_takes_kernel(x, skip: int = 0) -> bool:
 
 
 def rope(
-    x, positions, rule, interleave: bool = False, sections=(), skip: int = 0
+    x, positions, rule, interleave: bool = False, sections=(), skip: int = 0,
+    lead: int = 0,
 ):
     """Rotary positions (Su et al. 2021) over ``x``'s last axis behind its
     first ``skip`` lanes, which pass through (a head whose rotating part is
@@ -726,6 +746,12 @@ def rope(
     ``apply_rotary_pos_emb``, or with ``interleave`` the adjacent ``(x_2i,
     x_2i+1)`` of the original and of ``rope_interleave``.  Computed in
     float32.
+
+    ``lead`` > 0: the rotating part is the head's FIRST ``lead`` lanes (pair
+    ``i`` by ``theta^(-2i/lead)``) and the lanes behind them pass through
+    (``partial_rotary_factor``); the plain form alone, which
+    ``ops/rotary.py``'s kernel does not take yet
+    (docs/designs/rotary_kernel.md).
 
     ``positions`` (batch, components, seq) with ``sections`` (Qwen2-VL's
     multimodal RoPE): frequency ``i`` takes its angle from the component
@@ -743,6 +769,13 @@ def rope(
     model, a sequence sharded over ``sp``, several 64-wide heads, any other
     width) is :func:`rope_plain` on the rotating lanes, joined to the
     others."""
+    if lead:
+        if skip:
+            raise ValueError("a rotating head and a rotating tail at once")
+        turned = rope_plain(
+            x[..., :lead], positions, rule, interleave, sections
+        )
+        return jnp.concatenate([turned, x[..., lead:]], axis=-1)
     if not _rope_takes_kernel(x, skip):
         if not skip:
             return rope_plain(x, positions, rule, interleave, sections)

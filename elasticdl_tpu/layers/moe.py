@@ -524,6 +524,8 @@ class MoEMLP(nn.Module):
     routed_scaling: float = 1.0
     expert_kind: str = "swiglu"  # | "relu2": down(relu(up(x))^2)
     shared_width: int = 0  # > 0: one expert of this width on every token
+    # the shared expert's output times sigmoid(x w_g), w_g: embed -> 1
+    shared_gated: bool = False
     experts_held: int = 0  # 0: all of them
     first_expert: int = 0
     router_trains: bool = True  # False: the logits are constants of the step
@@ -662,7 +664,10 @@ class MoEMLP(nn.Module):
             )(x)
         else:
             hidden = jnp.square(nn.relu(dense(self.shared_width, "shared_up")(x)))
-        return dense(x.shape[-1], "shared_down")(hidden)
+        y = dense(x.shape[-1], "shared_down")(hidden)
+        if self.shared_gated:
+            y = y * jax.nn.sigmoid(dense(1, "shared_expert_gate")(x))
+        return y
 
 
 def moe_sharding_rules():

@@ -54,7 +54,8 @@ LOOPED_PARTS = ("expected_ce", "exit_entropy")
 # field: the part's own name for it}.  ``attention`` is
 # MultiHeadSelfAttention, ``latent`` LatentSelfAttention (the two kinds of
 # attention part share the heads and the rotary base), ``moe``
-# layers/moe.py::MoEMLP, ``mamba`` layers/mamba.py::Mamba2Mixer
+# layers/moe.py::MoEMLP, ``mamba`` layers/mamba.py::Mamba2Mixer, ``delta``
+# layers/gated_delta.py::GatedDeltaNet
 # (layers/short_conv.py::ShortConv, the ``c`` layers' part, has no field the
 # model sets: its three taps are its own default).  A new field
 # of a part is declared there, as a field of the model below, and on one line
@@ -75,6 +76,7 @@ PART_FIELDS = {
         "index_kl_weight": "index_kl_weight",
         "sliding_window": "window",
         "output_gate": "output_gate",
+        "partial_rotary_factor": "rotary_dim",
     },
     "latent": {
         "num_heads": "num_heads",
@@ -102,6 +104,7 @@ PART_FIELDS = {
         "experts_held": "experts_held",
         "first_expert": "first_expert",
         "router_trains": "router_trains",
+        "shared_expert_gate": "shared_gated",
     },
     "mamba": {
         "mamba_heads": "num_heads",
@@ -110,6 +113,14 @@ PART_FIELDS = {
         "ssm_state": "state_size",
         "conv_kernel": "conv_kernel",
         "ssd_chunk": "chunk",
+    },
+    "delta": {
+        "linear_key_heads": "num_key_heads",
+        "linear_value_heads": "num_value_heads",
+        "linear_key_dim": "key_dim",
+        "linear_value_dim": "value_dim",
+        "conv_kernel": "conv_kernel",
+        "delta_chunk": "chunk",
     },
 }
 
@@ -175,6 +186,15 @@ class TransformerLM(nn.Module):
     ssm_state: int = 128
     conv_kernel: int = 4
     ssd_chunk: int = 128
+    # the gated-delta-rule layers' (layers/gated_delta.py::GatedDeltaNet; the
+    # ``d`` layers of ``layer_pattern``): key heads and value heads of their
+    # own widths, a value head reading key head ``h // (values / keys)``; the
+    # convolution's taps are ``conv_kernel``
+    linear_key_heads: int = 0
+    linear_value_heads: int = 0
+    linear_key_dim: int = 128
+    linear_value_dim: int = 128
+    delta_chunk: int = 128
     # kv_lora_rank > 0: the attention parts are latent attention
     # (layers/attention.py::LatentSelfAttention), fields by their names there
     q_lora_rank: int = 0
@@ -223,6 +243,11 @@ class TransformerLM(nn.Module):
     # projection; a second norm on every part's output (x + norm(part(
     # norm(x)))); the embedding times sqrt(embed_dim) (muP)
     output_gate: bool = False
+    # < 1: the rotary positions turn that share of a head's width, its first
+    # lanes (rotate-half within them), and the rest pass through
+    partial_rotary_factor: float = 1.0
+    # the shared expert's output times sigmoid(x w_g) (layers/moe.py)
+    shared_expert_gate: bool = False
     norm_outputs: bool = False
     scale_embedding: bool = False
     # the head is the token embedding: logits = norm(x) @ tok_embed^T, one
@@ -239,6 +264,19 @@ class TransformerLM(nn.Module):
     # pass's logits
     loop_steps: int = 1
     exit_entropy_weight: float = 0.1
+
+    def _rotary_dim(self) -> int:
+        """The lanes of a head the rotary positions turn, 0 for all."""
+        if self.partial_rotary_factor == 1.0:
+            return 0
+        width = self.head_dim or self.embed_dim // self.num_heads
+        turned = int(width * self.partial_rotary_factor)
+        if not 0 < turned < width or turned % 2:
+            raise ValueError(
+                f"partial_rotary_factor {self.partial_rotary_factor} of a "
+                f"head of {width}"
+            )
+        return turned
 
     def _rope_rules(self) -> dict:
         """What each kind of attention layer turns its positions by: a
@@ -329,7 +367,7 @@ class TransformerLM(nn.Module):
             ].astype(x.dtype)
 
         # what the parts are given: the model's fields under the parts'
-        # names, but for the five values the model decides itself
+        # names, but for the six values the model decides itself
         decided = dict(
             rope_theta=self.rope_theta if self.positions == "rope" else 0.0,
             mrope_section=tuple(self.mrope_section),
@@ -338,9 +376,14 @@ class TransformerLM(nn.Module):
             # over the mean of the layers' losses
             router_aux_weight=self.router_aux_weight / max(1, expert_layers),
             router_z_weight=self.router_z_weight / max(1, expert_layers),
+            # the share of a head's width as its lanes
+            partial_rotary_factor=self._rotary_dim(),
         )
         # a group that is given chooses its part (TransformerBlock)
-        absent = {"latent": not self.kv_lora_rank, "moe": not self.num_experts}
+        absent = {
+            "latent": not self.kv_lora_rank, "moe": not self.num_experts,
+            "delta": not self.linear_value_heads,
+        }
         groups = {
             part + "_fields": tuple(
                 (field, decided.get(name, getattr(self, name)))

@@ -44,6 +44,10 @@ SELECTION_STATS = "selection_stats"
 # the window's trailing edge) and ``skipped`` this step into, over the batch
 # and the heads (``ops/attention.py::flash_block_plan``)
 BLOCK_PLAN = "block_plan"
+# the collection a gated-delta-rule layer (``layers/gated_delta.py``) sows
+# the step's mean decay ``exp(g)`` and mean write strength ``beta`` into, a
+# scalar a name and layer
+DELTA_STATE = "delta_state"
 
 _watched = None
 
@@ -129,6 +133,25 @@ def read_block_plan(model_state=None) -> dict | None:
         out["layers"] += path[-1].key == "visited"
     out["skipped_share"] = out["skipped"] / (out["visited"] + out["skipped"])
     return out
+
+
+def read_delta_state(model_state=None) -> dict | None:
+    """The newest step's gated-delta-rule layers, one host readback:
+    ``{"layers", "decay_mean", "beta_mean"}``, each a mean over the layers
+    (a decay of 0 is a state that forgets everything a step, a ``beta`` of 0
+    one that is never written).  None for a model without such a layer."""
+    stats = (_state(model_state) or {}).get(DELTA_STATE)
+    if not stats:
+        return None
+    found: dict = {}
+    for path, leaf in jax.tree_util.tree_leaves_with_path(
+        jax.device_get(stats)
+    ):
+        found.setdefault(path[-1].key, []).append(float(leaf))
+    return {
+        "layers": len(found["decay_mean"]),
+        **{name: sum(of) / len(of) for name, of in found.items()},
+    }
 
 
 def read(model_state=None) -> dict | None:

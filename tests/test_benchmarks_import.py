@@ -24,6 +24,7 @@ def test_the_kept_drivers():
         "attention_sweep.py",
         "dispatch_overhead_bench.py",
         "expert_rows_sweep.py",
+        "gated_delta_sweep.py",
         "ouro_loop_control.py",
         "ouro_loss_forms.py",
         "preemption_accuracy_bench.py",

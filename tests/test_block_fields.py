@@ -186,6 +186,7 @@ def test_the_defaults_still_build_gpt2_smalls_block():
 
 
 def _parts():
+    from elasticdl_tpu.layers.gated_delta import GatedDeltaNet
     from elasticdl_tpu.layers.mamba import Mamba2Mixer
     from elasticdl_tpu.layers.moe import MoEMLP
 
@@ -194,6 +195,7 @@ def _parts():
         "latent": layers.LatentSelfAttention,
         "moe": MoEMLP,
         "mamba": Mamba2Mixer,
+        "delta": GatedDeltaNet,
     }
 
 
@@ -217,7 +219,7 @@ _MODEL_OWN = {
 }
 
 
-@pytest.mark.parametrize("part", ["attention", "latent", "moe", "mamba"])
+@pytest.mark.parametrize("part", ["attention", "latent", "moe", "mamba", "delta"])
 def test_the_models_table_names_declared_fields_of_the_part(part):
     """Every target of ``PART_FIELDS`` is a field the part's module
     declares, once a part, and with what the block hands to all of them the
@@ -239,16 +241,18 @@ def test_the_models_table_names_declared_fields_of_the_part(part):
 def test_a_models_field_is_its_own_or_in_the_table_and_the_block_declares_no_parts():
     fields = _declared(lm.TransformerLM)
     named = [name for group in lm.PART_FIELDS.values() for name in group]
-    assert len(fields) == 64  # PR 61: rope_parameters, router_trains
+    # PR 65: the delta part's five, partial_rotary_factor, shared_expert_gate
+    assert len(fields) == 71
     assert set(named) | _MODEL_OWN == fields
     assert not set(named) & _MODEL_OWN
-    # once, but for what the two kinds of attention part share
+    # once, but for what the two kinds of attention part share and the taps
+    # of the two parts that convolve
     assert {
         name for name in named if named.count(name) > 1
-    } == {"num_heads", "rope_theta"}
+    } == {"num_heads", "rope_theta", "conv_kernel"}
     assert set(lm.PART_FIELDS) == set(_parts())
     block = _declared(layers.TransformerBlock)
-    assert len(block) <= 18
+    assert len(block) <= 19  # PR 65: delta_fields
     groups = {part + "_fields" for part in _parts()}
     assert groups <= block
     for part, module_class in _parts().items():
@@ -282,11 +286,29 @@ def test_a_field_of_a_part_reaches_it_under_the_parts_name():
         "num_heads": 2, "head_dim": 16, "groups": 1, "state_size": 16,
         "conv_kernel": 4, "chunk": 8,
     }
+    # the delta part's group, the rotating lanes and the shared expert's gate
+    hybrid = lm.custom_model(
+        embed_dim=32, num_heads=2, num_layers=3, layer_pattern="d*E",
+        positions="rope", head_dim=16, partial_rotary_factor=0.25,
+        num_experts=4, shared_expert_width=16, shared_expert_gate=True,
+        linear_key_heads=1, linear_value_heads=2, linear_key_dim=8,
+        linear_value_dim=8, delta_chunk=8,
+    )
+    blocks = _blocks_of(hybrid, tokens)
+    assert dict(blocks["block_0"].delta_fields) == {
+        "num_key_heads": 1, "num_value_heads": 2, "key_dim": 8, "value_dim": 8,
+        "conv_kernel": 4, "chunk": 8,
+    }
+    assert dict(blocks["block_1"].attention_fields)["rotary_dim"] == 4
+    assert dict(blocks["block_2"].moe_fields)["shared_gated"] is True
+    assert dict(blocks["block_0"].attention_fields)["rotary_dim"] == 4
     # positions that are not rotary reach no part, and no group, no part
     plain = lm.custom_model(embed_dim=32, num_heads=2, num_layers=1)
     blocks = _blocks_of(plain, tokens)
     assert dict(blocks["block_0"].attention_fields)["rope_theta"] == 0.0
     assert blocks["block_0"].moe_fields == ()
+    assert blocks["block_0"].delta_fields == ()
+    assert dict(blocks["block_0"].attention_fields)["rotary_dim"] == 0
 
 
 def _blocks_of(model, tokens):

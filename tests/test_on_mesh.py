@@ -14,7 +14,7 @@ from jax.sharding import PartitionSpec as P
 
 from elasticdl_tpu.layers import mamba, moe, short_conv
 from elasticdl_tpu.layers.attention import rope
-from elasticdl_tpu.ops import mamba_passes, on_mesh, rotary, ssd
+from elasticdl_tpu.ops import gated_delta, mamba_passes, on_mesh, rotary, ssd
 from elasticdl_tpu.ops.attention import attention, flash_layout
 from elasticdl_tpu.parallel.mesh import MeshConfig
 
@@ -89,6 +89,24 @@ def _scan(width, states):
     )
 
 
+def _delta_rule():
+    """One key head of 128 serving two value heads of 128, 48 steps in
+    chunks of 32 (the last padded): what the two delta-rule kernels tile."""
+    q, k = (_randn(s, 4, 48, 1, 128) * 0.1 for s in (0, 1))
+    v = _randn(2, 4, 48, 2, 128)
+    g = -jnp.log1p(jnp.exp(_randn(3, 4, 48, 2))) * 0.1
+    beta = jax.nn.sigmoid(_randn(4, 4, 48, 2))
+    assert gated_delta.scan_tile(128, 128, 32)
+
+    def kernels_inside(regions):
+        assert all("pallas_call" in str(eqn.params["jaxpr"]) for eqn in regions)
+
+    return (
+        functools.partial(gated_delta.gated_delta_scan, chunk=32),
+        (q, k, v, g, beta), kernels_inside,
+    )
+
+
 def _conv_silu():
     args = (_randn(0, 4, 48, 384), _randn(1, 4, 384) * 0.5, _randn(2, 384) * 0.1)
     assert mamba_passes.conv_tile(48, 384, 4)
@@ -149,6 +167,7 @@ CALLERS = {
     "flash_folded_grouped_heads-dp=2,tp=2": lambda: _flash(4, 2, 128),
     "scan_kernels-dp=4": lambda: _scan(64, 128),
     "scan_plain-dp=4": lambda: _scan(16, 16),
+    "delta_rule_kernels-dp=4": _delta_rule,
     "conv_silu-dp=4": _conv_silu,
     "short_conv-dp=4": _short_conv,
     "gate_norm-dp=2": _gate_norm,

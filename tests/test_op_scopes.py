@@ -27,6 +27,7 @@ from elasticdl_tpu.ops import attention as attention_ops
 from elasticdl_tpu.ops import grouped_matmul as gmm_ops
 from elasticdl_tpu.ops import mamba_passes
 from elasticdl_tpu.ops import short_conv as short_conv_ops
+from elasticdl_tpu.ops import gated_delta as gated_delta_ops
 from elasticdl_tpu.ops import sparse_attention as sparse_ops
 from elasticdl_tpu.ops import ssd as ssd_ops
 from elasticdl_tpu.parallel.distributed import SPMDTrainer
@@ -47,10 +48,11 @@ KERNELS = {
     attention_ops.WINDOW_DQ, attention_ops.WINDOW_DKV, sparse_ops.INDEX_SELECT,
     sparse_ops.INDEX_SELECT_HINTED, sparse_ops.INDEXER_KL,
     short_conv_ops.SHORT_CONV_FWD, short_conv_ops.SHORT_CONV_BWD,
+    gated_delta_ops.GDN_FWD, gated_delta_ops.GDN_BWD,
 }
 # modules that hold other modules: an op directly under one of these is in
 # a region nobody named
-MIXERS = {"attn", "moe", "mamba", "conv"}
+MIXERS = {"attn", "moe", "mamba", "conv", "gdn"}
 
 STEP = "jit(train_step)/"
 BLOCK = "block_11/block_11._residual/block_11._attention/attn/"
@@ -346,6 +348,7 @@ FAMILIES = {
     "window_and_yarn_attention": lambda: _lm_family("tiny_mellum"),
     "short_conv_attention_experts_tied": lambda: _lm_family("tiny_lfm2"),
     "looped_stack": lambda: _lm_family("tiny_ouro"),
+    "delta_rule_and_gated_attention": lambda: _lm_family("tiny_qwen3_next"),
     "resnet_first_stage": lambda: (
         FirstStage(), _class_loss, optax.sgd(0.1),
         {"image": np.zeros((2, 32, 32, 3), np.float32)},
@@ -443,6 +446,17 @@ def test_every_region_of_the_step_has_a_name(built):
             "block/mamba/gate_norm", "block/mamba/ssd_scan",
             "block/mamba/mamba_conv", "block/moe/dispatch", "block/moe/combine",
         } <= parts | {op_scopes.at_depth(part, 3) for part in parts}
+    if family == "delta_rule_and_gated_attention":
+        # the delta-rule part's projections, its three convolutions, the scan
+        # (16-wide heads: ops/gated_delta.py's plain form, the scope's own
+        # ops), the norm before the gate; the softmax part's gate and its
+        # rotary positions; the shared expert's gate
+        assert {
+            "block/gdn/in_proj_qkvz", "block/gdn/in_proj_ba",
+            "block/gdn/delta_conv", "block/gdn/delta_rule",
+            "block/gdn/norm_gate", "block/gdn/out_proj", "block/attn/gate",
+            "block/attn/rope", "block/moe/shared/shared_expert_gate",
+        } <= parts | {op_scopes.at_depth(part, 3) for part in parts}
     if family == "olmoe":
         assert {"block/attn/qk_norm/q_norm", "block/attn/rope"} <= parts
     if family == "window_and_full_attention":
@@ -527,7 +541,8 @@ PARENTS_STEP = {
 
 def test_a_step_the_new_forms_do_not_reach_lowers_to_the_parents_text(built):
     family, _, _ = built
-    if family == "latent_attention_mtp":
+    if family in ("latent_attention_mtp", "delta_rule_and_gated_attention"):
+        # (the second is PR 65's own family: the parent cannot build it)
         assert family not in PARENTS_STEP
     else:
         assert LOWERED_SHA256[family] == PARENTS_STEP[family]
