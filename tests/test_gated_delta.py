@@ -70,12 +70,12 @@ def inputs(steps, layout=KERNELS, dtype=jnp.float32, decay=1.0, alike=0.0, seed=
 
 
 def value_and_grads(function, args):
-    out = function(*args)
+    out = jax.jit(function)(*args)
     weigh = jnp.asarray(np.random.RandomState(5).randn(*out.shape), jnp.float32)
-    grads = jax.grad(
+    grads = jax.jit(jax.grad(
         lambda *a: jnp.sum(function(*a).astype(jnp.float32) * weigh),
         argnums=tuple(range(len(args))),
-    )(*args)
+    ))(*args)
     return out, grads
 
 

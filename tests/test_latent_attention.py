@@ -322,7 +322,7 @@ def test_last_position_of_the_module_weighs_nothing_and_the_last_label_does():
 def test_a_masked_row_weighs_nothing_in_either_loss():
     features, labels = _batch(rows=2, seed=5)
     model = tiny_mtp_model()
-    variables = model.init(jax.random.PRNGKey(0), features)
+    variables = jax.jit(model.init)(jax.random.PRNGKey(0), features)
 
     def weighted(params, tokens, labels, weights):
         outputs, _ = model.apply(
@@ -332,7 +332,8 @@ def test_a_masked_row_weighs_nothing_in_either_loss():
         parts = weighted_mean_loss(lm.loss.parts, labels, outputs, weights)
         return parts["main"] + parts["mtp"], parts
 
-    grad = jax.grad(lambda *a: weighted(*a)[0])
+    weighted = jax.jit(weighted)
+    grad = jax.jit(jax.grad(lambda *a: weighted(*a)[0]))
     padded = grad(variables["params"], features["tokens"], labels, jnp.array([1.0, 0.0]))
     alone = grad(
         variables["params"], features["tokens"][:1], labels[:1], jnp.array([1.0])

@@ -8,14 +8,10 @@ that the compiles run beside that file's, not after them.)"""
 
 import functools
 
-import jax
-import numpy as np
 import pytest
-from test_op_scopes import FAMILIES, _lowered
+from test_op_scopes import _lowered
 
 from elasticdl_tpu.telemetry import op_scopes
-from elasticdl_tpu.trainer.state import TrainState
-from elasticdl_tpu.trainer.step import build_train_step
 
 # one of each construct the walk reads: an unrolled stack with and without
 # recomputation, a scanned and looped stack (``while``), the expert ladder's
@@ -30,16 +26,7 @@ COMPILED = (
 @functools.lru_cache(maxsize=None)
 def _donating(family):
     """The family's step compiled as a trainer runs it: the state donated."""
-    model, loss, tx, features, labels, _ = FAMILIES[family]()
-    variables = model.init(jax.random.PRNGKey(0), features, training=False)
-    state = TrainState.create(
-        model.apply, variables["params"], tx,
-        {k: v for k, v in variables.items() if k != "params"},
-    )
-    weights = np.ones((labels.shape[0],), np.float32)
-    return build_train_step(loss, donate=True).lower(
-        state, features, labels, weights
-    ).compile()
+    return _lowered(family, donate=True)[1].compile()
 
 
 @pytest.mark.parametrize("family", COMPILED)

@@ -52,11 +52,11 @@ def _scaled_errors(got, want):
 
 def _value_and_grads(function, weigh, *args):
     """The output and the gradients of a weighed sum of it."""
-    out = function(*args)
-    grads = jax.grad(
+    out = jax.jit(function)(*args)
+    grads = jax.jit(jax.grad(
         lambda *a: jnp.sum(weigh * function(*a).astype(jnp.float32)),
         argnums=tuple(range(len(args))),
-    )(*args)
+    ))(*args)
     return out, grads
 
 

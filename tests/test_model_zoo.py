@@ -97,7 +97,7 @@ def test_zoo_model_trains(model_def, gen, records, batch, tmp_path):
     batches = _first_batches(spec, data_dir, batch)
     features, labels = batches[0]
 
-    params, model_state = init_model(model, features)
+    params, model_state = jax.jit(lambda: init_model(model, features))()
     tx = resolve_optimizer(spec.optimizer)
     state = TrainState.create(model.apply, params, tx, model_state)
     train_step = build_train_step(spec.loss, compute_dtype=None)
@@ -131,13 +131,13 @@ def test_resnet50_builds_and_steps(tmp_path):
     )
     model = spec.build_model()
     (features, labels), = _first_batches(spec, data_dir, 2, n=1)
-    params, model_state = init_model(model, features)
+    params, model_state = jax.jit(lambda: init_model(model, features))()
     n_kernels = len(
         [1 for k in jax.tree_util.tree_leaves(params) if k.ndim == 4]
     )
     assert n_kernels == 1 + 16 * 3 + 4  # stem + 16 blocks x3 + 4 shortcuts
     # softmax-probability output contract (the loss consumes probabilities)
-    probs = model.apply({"params": params, **model_state}, features)
+    probs = jax.jit(model.apply)({"params": params, **model_state}, features)
     np.testing.assert_allclose(
         np.asarray(probs).sum(-1), np.ones(2), rtol=1e-5
     )

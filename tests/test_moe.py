@@ -3,6 +3,8 @@ per-expert loop, no token dropped at any routing, both auxiliary losses
 joining the train loss, the router's counters, and expert parallelism over
 ``ep`` on the virtual mesh."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -39,8 +41,11 @@ def dense_loop(variables, x, experts_per_token, norm_topk_prob=False):
 
 
 def init_layer(x, **fields):
+    """The layer and its variables, the init (interpreted kernels) one program."""
     layer = MoEMLP(**{"num_experts": 8, "expert_width": 32, **fields})
-    return layer, layer.init(jax.random.PRNGKey(0), x, training=False)
+    return layer, jax.jit(layer.init, static_argnames="training")(
+        jax.random.PRNGKey(0), x, training=False
+    )
 
 
 @pytest.mark.parametrize(
@@ -68,8 +73,10 @@ def test_moe_is_the_dense_per_expert_loop(fields):
         y = dense_loop({"params": params}, x, fields["experts_per_token"], normed)
         return jnp.sum(jnp.sin(y))
 
-    got = jax.value_and_grad(ours, argnums=(0, 1))(variables["params"], x)
-    want = jax.value_and_grad(loop, argnums=(0, 1))(variables["params"], x)
+    got, want = (
+        jax.jit(jax.value_and_grad(f, argnums=(0, 1)))(variables["params"], x)
+        for f in (ours, loop)
+    )
     for a, b in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
         np.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-6)
 
@@ -102,13 +109,13 @@ def test_moe_aux_losses_join_train_loss_and_counters_ride_out():
         vocab_size=64, num_layers=2, embed_dim=32, num_heads=2,
         num_experts=4, experts_per_token=2,
     )
-    params, model_state = init_model(model, feats)
+    params, model_state = jax.jit(lambda: init_model(model, feats))()
     assert set(model_state) == set(COLLECTIONS)
 
     # before the train step: it donates the original state buffers
-    plain = float(lm.loss(labels, model.apply(
-        {"params": params, **model_state}, feats, training=False
-    )))
+    plain = float(jax.jit(lambda variables: lm.loss(labels, model.apply(
+        variables, feats, training=False
+    )))({"params": params, **model_state}))
     state = TrainState.create(
         model.apply, params, optax.sgd(0.0), model_state
     )
@@ -155,7 +162,7 @@ def test_moe_transformer_trains_on_ep_mesh(mesh_shape):
         spec = trainer.state.params["block_0"]["moe"][name].sharding.spec
         assert "ep" in str(spec), spec
 
-    params, model_state = init_model(model, feats)
+    params, model_state = jax.jit(lambda: init_model(model, feats))()
     one_device = build_train_step(lm.loss, compute_dtype=None)(
         TrainState.create(model.apply, params, optax.adam(3e-3), model_state),
         feats, labels,
@@ -199,8 +206,8 @@ CUT = dict(
 
 
 def cut_layer_loss(x, target, **fields):
-    layer = MoEMLP(**{**CUT, **fields})
-    params = layer.init(jax.random.PRNGKey(0), x, training=False)["params"]
+    layer, variables = init_layer(x, **{**CUT, **fields})
+    params = variables["params"]
 
     def loss(p, x):
         y, stats = layer.apply(
@@ -315,7 +322,9 @@ def value_and_grads(experts, x, weights, stacks):
 
 SHARES = {
     # routed experts, held, slots, pairs a held expert gets
-    "8_of_128": (128, 8, 6, [5, 0, 9, 1, 3, 8, 2, 4]),
+    # (a sixteenth held, top-6: the one ladder of three rungs here;
+    # ``ops/grouped_matmul.py::ladder`` sizes the low rung by that share)
+    "2_of_32": (32, 2, 6, [5, 9]),
     "4_of_16": (16, 4, 2, [9, 0, 17, 3]),
     "2_of_8": (8, 2, 2, [11, 6]),
 }
@@ -358,10 +367,11 @@ def test_low_rung_and_full_rung_agree(share, kind):
     for got in value_and_grads(at(low, False), x, weights, stacks), want:
         for a, b in zip(got, want):
             np.testing.assert_array_equal(a, b)
-    by_rows = value_and_grads(at(low, True), x, weights, stacks)
+    # (one program each, where the calls above run their products one by one)
+    by_rows = jax.jit(functools.partial(value_and_grads, at(low, True)))(
+        x, weights, stacks
+    )
     laddered = value_and_grads(chosen, x, weights, stacks)
-    # (the chosen branch is compiled as one program, so its products may be
-    # fused where the call above runs them one by one)
     for a, b, c in zip(by_rows, laddered, want):
         bound = 4e-7 * float(jnp.abs(c).max())
         np.testing.assert_allclose(a, c, rtol=0, atol=bound)
@@ -383,6 +393,11 @@ CROSSINGS = {
 }
 
 
+_routed_of_128 = jax.jit(
+    lambda *a: routed_experts(*a, num_experts=128, tile_rows=TILE)
+)
+
+
 @pytest.mark.parametrize("kind", ["swiglu", "relu2"])
 @pytest.mark.parametrize("case", CROSSINGS.values(), ids=CROSSINGS.keys())
 def test_rung_follows_the_routing_and_nothing_is_dropped(case, kind):
@@ -396,9 +411,7 @@ def test_rung_follows_the_routing_and_nothing_is_dropped(case, kind):
     top = routing(tokens, slots, routed, sizes, seed=3)
     x, weights, stacks = expert_inputs(tokens, slots, held, kind, seed=3)
     rungs = gmm_ops.ladder(tokens * slots, held, routed, TILE)
-    y, rows_held, buffer_rows = jax.jit(
-        lambda *a: routed_experts(*a, num_experts=routed, tile_rows=TILE)
-    )(x, top, weights, *stacks)
+    y, rows_held, buffer_rows = _routed_of_128(x, top, weights, *stacks)
     assert int(rows_held) == sum(sizes) == int((np.asarray(top) < held).sum())
     assert list(np.asarray(buffer_rows)) == [rungs[rung], rungs[-1]]
     want = jnp.zeros_like(x)
@@ -463,13 +476,17 @@ def test_router_load_reads_the_rung_each_layer_took():
     )
     low, *_, full = gmm_ops.ladder(2048, 4, 16, gmm_ops.TILE_ROWS)
 
-    def read(favoured):
-        kernel = jnp.zeros((16, 16)).at[:, favoured].set(1.0)
+    @jax.jit
+    def counted(kernel):
         params = {**variables["params"], "router": {"kernel": kernel}}
-        _, state = layer.apply(
+        return layer.apply(
             {**variables, "params": params}, x, mutable=COLLECTIONS
+        )[1]
+
+    def read(favoured):
+        return router_load.read(
+            counted(jnp.zeros((16, 16)).at[:, favoured].set(1.0))
         )
-        return router_load.read(state)
 
     here, away = read(jnp.array([0, 1])), read(jnp.array([14, 15]))
     assert (here["held_pairs"], here["buffer_rows"], here["buffer_share"]) == (
@@ -480,7 +497,7 @@ def test_router_load_reads_the_rung_each_layer_took():
     assert here["dropped_pairs"] == away["dropped_pairs"] == 0
 
     whole, variables = init_layer(x, num_experts=16, experts_per_token=2)
-    _, state = whole.apply(variables, x, mutable=COLLECTIONS)
+    _, state = jax.jit(lambda v: whole.apply(v, x, mutable=COLLECTIONS))(variables)
     load = router_load.read(state)
     assert load["buffer_share"] == 1.0
     assert load["buffer_rows"] == gmm_ops.num_rows(2048, 16, gmm_ops.TILE_ROWS)
@@ -503,7 +520,7 @@ def test_ep_ranks_take_the_low_rung_and_train_like_one_device():
         mesh, model, lm.loss, optax.adam(3e-3), feats,
         rules=tuple(lm.sharding_rules(mesh)),
     )
-    params, model_state = init_model(model, feats)
+    params, model_state = jax.jit(lambda: init_model(model, feats))()
     one_device = build_train_step(lm.loss, compute_dtype=None)(
         TrainState.create(model.apply, params, optax.adam(3e-3), model_state),
         feats, labels,

@@ -205,10 +205,11 @@ def _assert_close(got, want):
 @pytest.mark.parametrize("caller", sorted(CALLERS))
 def test_a_caller_is_mapped_once_and_computes_what_it_computes_unmapped(caller):
     call, args, check = CALLERS[caller]()
-    out = call(*args)
+    out = jax.eval_shape(call, *args)
     weigh = _randn(9, *(out[0] if isinstance(out, tuple) else out).shape)
     assert _regions(jax.make_jaxpr(call)(*args).jaxpr) == []  # no mesh
-    want = _value_and_grads(call, args, weigh)
+    # (one program, as on the mesh below)
+    want = jax.jit(functools.partial(_value_and_grads, call, weigh=weigh))(args)
 
     layout = caller.split("-")[1]
     devices = int(np.prod([int(axis.split("=")[1]) for axis in layout.split(",")]))

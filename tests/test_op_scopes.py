@@ -357,14 +357,20 @@ FAMILIES = {
 }
 
 
-def _lowered(family):
+def _lowered(family, donate=False):
+    """The family's train step lowered from the shapes of its state (the
+    text depends on no value), ``state`` being the tree of those shapes."""
     model, loss, tx, features, labels, _ = FAMILIES[family]()
-    variables = model.init(jax.random.PRNGKey(0), features, training=False)
-    state = TrainState.create(
-        model.apply, variables["params"], tx,
-        {k: v for k, v in variables.items() if k != "params"},
-    )
-    step = build_train_step(loss, donate=False)
+
+    def create():
+        variables = model.init(jax.random.PRNGKey(0), features, training=False)
+        return TrainState.create(
+            model.apply, variables["params"], tx,
+            {k: v for k, v in variables.items() if k != "params"},
+        )
+
+    state = jax.eval_shape(create)
+    step = build_train_step(loss, donate=donate)
     weights = np.ones((labels.shape[0],), np.float32)
     return state, step.lower(state, features, labels, weights)
 
@@ -631,6 +637,25 @@ def _lowered_for_the_chip(mesh, model, loss, tx, tokens=1024):
             described(state), described(features), described(labels),
             described(np.ones((1,), np.float32)),
         )
+
+
+def _cell_lowered(mesh, config, tokens=1024):
+    """The scope of every instruction of a cell's model (``perf/configs``)
+    lowered for the described chip: its HLO text with the metadata, without
+    the kernels' bodies, what ``scope_map`` reads of a program, before XLA."""
+    from jax._src.lib import xla_client
+
+    with open(os.path.join(ROOT, "perf", "configs", config + ".json")) as f:
+        params = json.load(f)["run"]["model_params"]
+    lowered = _lowered_for_the_chip(
+        mesh, lm.custom_model(**params), lm.loss, lm.optimizer(), tokens
+    )
+    options = xla_client._xla.HloPrintOptions()
+    options.print_metadata = True
+    options.print_backend_config = False
+    return op_scopes._scope_of_text(
+        lowered.compiler_ir("hlo").as_hlo_module().to_string(options)
+    )
 
 
 def test_the_mixers_passes_are_kernels_under_their_own_parts(one_chip_mesh):
@@ -926,25 +951,10 @@ def test_the_rotary_kernels_calls_in_the_cells_models(
     128-lane tiles or the 64 lanes of an array's one head, by halves or by
     adjacent pairs, and nowhere else; ``rope_plain`` is traced for the
     arrays of ``plain`` alone."""
-    from jax._src.lib import xla_client
-
     from elasticdl_tpu.ops import rotary
 
-    with open(os.path.join(ROOT, "perf", "configs", config + ".json")) as f:
-        params = json.load(f)["run"]["model_params"]
-    model = lm.custom_model(**params)
-    lowered = _lowered_for_the_chip(
-        one_chip_mesh, model, lm.loss, lm.optimizer()
-    )
+    scopes = _cell_lowered(one_chip_mesh, config)
     assert set(plain_rope_shapes) == plain
-    # the lowered module as HLO text with its metadata, without the kernels'
-    # bodies: what ``scope_map`` reads of a compiled program, before XLA
-    options = xla_client._xla.HloPrintOptions()
-    options.print_metadata = True
-    options.print_backend_config = False
-    scopes = op_scopes._scope_of_text(
-        lowered.compiler_ir("hlo").as_hlo_module().to_string(options)
-    )
     found = {}
     for part, phase, kind, _ in scopes.values():
         if kind == "kernel" and "/rope" in part:
@@ -1058,22 +1068,9 @@ def test_mellum2s_cut_names_every_kernel_at_the_cells_shape(one_chip_mesh):
     to one full, 24,576 rows of the vocabulary), lowered for the described
     chip at the cell's 16,384 tokens: every kernel call sits in a named
     region, once a layer and pass."""
-    from jax._src.lib import xla_client
-
     from elasticdl_tpu.ops import rotary
 
-    with open(os.path.join(ROOT, "perf", "configs", "mellum2_12b_a2p5b.json")) as f:
-        params = json.load(f)["run"]["model_params"]
-    lowered = _lowered_for_the_chip(
-        one_chip_mesh, lm.custom_model(**params), lm.loss, lm.optimizer(),
-        tokens=16384,
-    )
-    options = xla_client._xla.HloPrintOptions()
-    options.print_metadata = True
-    options.print_backend_config = False
-    scopes = op_scopes._scope_of_text(
-        lowered.compiler_ir("hlo").as_hlo_module().to_string(options)
-    )
+    scopes = _cell_lowered(one_chip_mesh, "mellum2_12b_a2p5b", tokens=16384)
     found = {}
     for part, phase, kind, _ in scopes.values():
         if kind == "kernel":
@@ -1099,32 +1096,25 @@ def test_mellum2s_cut_names_every_kernel_at_the_cells_shape(one_chip_mesh):
     assert experts and all(part.startswith("block/moe/") for part, _ in experts)
 
 
-@pytest.mark.parametrize("config", ["tiny_nemotron", "tiny_joyai"])
-def test_a_recomputed_layer_without_a_selection_is_nn_remats(config, monkeypatch):
+@pytest.mark.parametrize(
+    "family", ["mamba_experts_attention", "latent_attention_mtp"],
+    ids=["tiny_nemotron", "tiny_joyai"],
+)
+def test_a_recomputed_layer_without_a_selection_is_nn_remats(family, monkeypatch):
     """``layers/recompute.py`` is a sparse layer's alone: a recomputed model
     that sets no ``index_topk`` never reaches it and lowers to the text it
     lowers to with ``nn.remat`` in its place."""
     from elasticdl_tpu.layers import recompute
 
-    def lowered():
-        model, loss, tx, features, labels, remat = _lm_family(config)
-        assert remat
-        variables = model.init(jax.random.PRNGKey(0), features, training=False)
-        state = TrainState.create(
-            model.apply, variables["params"], tx,
-            {k: v for k, v in variables.items() if k != "params"},
-        )
-        return build_train_step(loss, donate=False).lower(
-            state, features, labels, np.ones((labels.shape[0],), np.float32)
-        ).as_text()
+    assert FAMILIES[family]()[-1]  # its layers are recomputed
 
     def never(*args, **kwargs):
         raise AssertionError("a layer without a selection took the hinted remat")
 
-    ours = lowered()
+    ours = _lowered(family)[1].as_text()
     monkeypatch.setattr(lm, "remat_with_findings", never)
     monkeypatch.setattr(recompute, "_lifted", never)
-    assert lowered() == ours
+    assert _lowered(family)[1].as_text() == ours
     assert "checkpoint" in ours or "remat" in ours or "optimization_barrier" in ours
 
 

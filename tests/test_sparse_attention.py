@@ -63,12 +63,11 @@ def test_selected_set_attention_and_the_selection_match_the_materialised_form(
         out, probs = sparse_ops.selected_reference(q, k, v, chosen)
         return jnp.sum(out * jnp.cos(out)), (out, probs)
 
-    (_, (out, lse)), grads = jax.value_and_grad(
-        through_kernels, argnums=(0, 1, 2), has_aux=True
-    )(q, k, v)
-    (_, (want, probs)), want_grads = jax.value_and_grad(
-        materialised, argnums=(0, 1, 2), has_aux=True
-    )(q, k, v)
+    def grads_of(f, **more):  # one program a side
+        return jax.jit(jax.value_and_grad(f, argnums=(0, 1, 2), **more))
+
+    (_, (out, lse)), grads = grads_of(through_kernels, has_aux=True)(q, k, v)
+    (_, (want, probs)), want_grads = grads_of(materialised, has_aux=True)(q, k, v)
     np.testing.assert_allclose(out, want, rtol=2e-5, atol=2e-5)
     for got, wanted, name in zip(grads, want_grads, "qkv"):
         np.testing.assert_allclose(
@@ -87,10 +86,8 @@ def test_selected_set_attention_and_the_selection_match_the_materialised_form(
             probs, sparse_ops.index_scores_reference(qi, ki, w), chosen
         )
 
-    value, kl_grads = jax.value_and_grad(kl_kernel, argnums=(0, 1, 2))(qi, ki, w)
-    wanted, want_kl_grads = jax.value_and_grad(
-        kl_materialised, argnums=(0, 1, 2)
-    )(qi, ki, w)
+    value, kl_grads = grads_of(kl_kernel)(qi, ki, w)
+    wanted, want_kl_grads = grads_of(kl_materialised)(qi, ki, w)
     np.testing.assert_allclose(value, wanted, rtol=1e-5)
     # (the value alone runs the kernel without its gradient half)
     np.testing.assert_allclose(kl_kernel(qi, ki, w), wanted, rtol=1e-5)
@@ -312,10 +309,14 @@ def test_a_recomputed_sparse_layer_is_the_layer(dtype, monkeypatch):
     tokens = np.random.default_rng(0).integers(64, size=(2, 64)).astype(np.int32)
     features = {"tokens": tokens}
 
+    # (recomputation changes no parameter: one init for the three models)
+    variables = jax.jit(
+        zoo.custom_model(**fields).init, static_argnames="training"
+    )(jax.random.PRNGKey(0), features, training=False)
+    state = {k: v for k, v in variables.items() if k != "params"}
+
     def run(remat):
         model = zoo.custom_model(remat_layers=remat, **fields)
-        variables = model.init(jax.random.PRNGKey(0), features, training=False)
-        state = {k: v for k, v in variables.items() if k != "params"}
 
         def loss(params):
             logits, new = model.apply(

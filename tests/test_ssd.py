@@ -85,10 +85,10 @@ def inputs(steps=24, dtype=jnp.float32, decay=1.0, seed=0, layout=KERNELS):
 
 
 def value_and_grads(scan, args, weigh):
-    return jax.value_and_grad(
+    return jax.jit(jax.value_and_grad(
         lambda *args: jnp.sum(weigh * scan(*args).astype(jnp.float32)),
         argnums=tuple(range(6)),
-    )(*args)
+    ))(*args)
 
 
 def scaled_errors(got, want):
@@ -355,7 +355,7 @@ def test_hybrid_stack_trains_on_a_dp_ep_mesh():
     assert moe["w_up"].shape == (4, 32, 16) and "w_gate" not in moe
     assert "ep" in str(moe["w_up"].sharding.spec)
 
-    params, model_state = init_model(model, feats)
+    params, model_state = jax.jit(lambda: init_model(model, feats))()
     one_device = build_train_step(lm.loss, compute_dtype=None)(
         TrainState.create(model.apply, params, optax.adam(3e-3), model_state),
         feats, labels,
